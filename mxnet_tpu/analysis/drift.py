@@ -47,8 +47,8 @@ _INDEX_END = "<!-- mxlint:metric-index:end -->"
 #: does not know and this table does not describe is a finding.
 _ENV_NOTES = {
     "MXTPU_BENCH_CPU":
-        "bench.py only: run the benchmark suite on the CPU backend "
-        "(sanity/dev runs); unset = whatever backend jax selects.",
+        "bench.py only: dry-run the benchmark's control flow on the CPU "
+        "backend; unset = the accelerator, and bench.py fails without one.",
     "MXTPU_CHAOS_STEPS":
         "tools/dist_chaos_worker.py only: total optimisation steps of "
         "the elastic chaos smoke job (default 10).",

@@ -441,7 +441,7 @@ class ShardSpec(object):
                             % (ax, fname or "<lambda>", call.lineno, ax))
 
     # ------------------------------------------- replicated embedding specs
-    def _check_replicated_embedding(self, module):
+    def _check_embedding_is_replicated(self, module):
         for node in ast.walk(module.tree):
             if not isinstance(node, ast.Dict):
                 continue
@@ -486,7 +486,7 @@ class ShardSpec(object):
                 continue
             self._check_literal_axes(module)
             self._check_sites(module)
-            self._check_replicated_embedding(module)
+            self._check_embedding_is_replicated(module)
         return self.findings
 
 

@@ -31,7 +31,10 @@ Default-on graduation gate (``kernels.enabled`` default since round
 16): while the knob sits at its *default*, a routed site only takes the
 Pallas kernel after the search proves bitwise-or-tolerance parity plus
 a measured speedup >= 1.0x; losing sites fall back permanently to the
-XLA lowering (the PR 11 AOT-rejection fallback contract).  On
+XLA lowering.  "Lost" means slower or not bit-close: a candidate that
+fails to lower or compile is an ERROR that propagates out of the search
+and is never recorded, in memory or on disk — a broken kernel must not
+pass for a slow one.  On
 interpreted backends (CPU/GPU) a kernel can never beat the compiled XLA
 lowering, so ``'auto'`` mode routes default-knob programs to XLA
 statically — no measurement, programs byte-identical to the pre-tier
@@ -90,12 +93,13 @@ def enabled():
 
 def cache_path():
     """Resolved tuning-cache file: the ``perf.autotune_cache`` knob, or
-    ``<model_store.root>/autotune.json`` (~/.mxnet by default)."""
+    ``autotune.json`` in the checkout's cache directory (beside the XLA
+    compile cache) — no state from outside the tree decides a route."""
     p = _config.get("perf.autotune_cache")
     if p:
         return os.path.expanduser(p)
-    root = _config.get("model_store.root") or "~/.mxnet"
-    return os.path.join(os.path.expanduser(root), "autotune.json")
+    from . import runtime as _runtime
+    return os.path.join(_runtime.cache_root(), "autotune.json")
 
 
 def config_fingerprint():
@@ -186,20 +190,9 @@ def _write_entries(entries):
                    "apply in-process only" % (path, exc))
 
 
-def _device_kind():
-    from . import perf as _perf
-    kind = _perf.device_kind()
-    if kind:
-        return kind
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend at all
-        return "unknown"
-
-
 def _key(family, site, dtype):
-    return "|".join((family, site, _device_kind(), str(dtype),
+    from . import perf as _perf
+    return "|".join((family, site, _perf.device_kind(), str(dtype),
                      config_fingerprint()))
 
 
@@ -336,22 +329,45 @@ def _parity(got, ref, dtype):
     return verdict
 
 
+def _eager(search):
+    """Run a search on the device NOW, even when the trace-time pick that
+    missed the cache sits inside a jit or export trace.  There every jnp
+    op of the search would be staged into the outer program and the
+    search would time — and try to read back — tracers.  jax's trace
+    state is thread-local, so a fresh thread starts outside any trace;
+    the caller waits for it, and an exception in the search is raised in
+    the caller."""
+    @functools.wraps(search)
+    def run(*args, **kwargs):
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(max_workers=1,
+                                thread_name_prefix="mx-autotune") as pool:
+            return pool.submit(search, *args, **kwargs).result()
+    return run
+
+
 # --------------------------------------------------- attention site search
-def _attention_candidates(S):
+def _attention_candidates(S, Skv):
     """Deduplicated effective block_q candidates for a length-S query:
     each base divides down through the _row_block divisor walk, so two
-    bases that snap to the same divisor measure once."""
+    bases that snap to the same divisor measure once.  Bases whose f32
+    [block_q, Skv] score block would not fit the VMEM budget are left
+    out — every candidate the search compiles has to be one the chip can
+    hold, because a compile failure is an error, not a verdict."""
     from .ops.pallas_kernels import _row_block
-    bases = [64, 128, 256, 512, S]
-    eff = sorted({_row_block(S, 1, budget=min(b, S)) for b in bases if b})
-    return eff
+    fit = max(1, int(_config.get("kernels.vmem_budget")) // (4 * Skv))
+    bases = [b for b in (64, 128, 256, 512, S) if b <= max(fit, 64)]
+    return sorted({_row_block(S, 1, budget=min(b, S)) for b in bases})
 
 
+@_eager
 def search_attention(q_shape, kv_shape, dtype, causal, scale=None):
     """Measure the flash kernel over its block_q candidates against the
     XLA attention lowering at one site signature; persist and return the
     winner.  Gate: parity (bitwise-or-tolerance) AND speedup >= 1.0x —
-    a site that loses either falls back to XLA permanently."""
+    a site that loses either falls back to XLA permanently.  A candidate
+    that fails to lower or compile raises out of the search: nothing is
+    recorded, so the next run meets the same error, not a stale verdict."""
     from .parallel.ring_attention import attention as _xla_attention
     B, H, Sq, D = q_shape
     site = _attention_site(q_shape, kv_shape, causal)
@@ -363,42 +379,37 @@ def search_attention(q_shape, kv_shape, dtype, causal, scale=None):
         return _xla_attention(q, k, v, causal=causal, scale=scale)
 
     entry = {"impl": "xla", "site": site, "causal": bool(causal)}
-    try:
-        ref = None
-        base_ms = _measure_ms(xla_fn, (q, k, v))
-        import jax
-        jit_ref = jax.jit(xla_fn)  # parity reference: jit-vs-jit only
-        ref = jit_ref(q, k, v)
-        cands = {}
-        best_bq, best_ms, best_parity = None, None, None
-        from .ops.pallas_kernels import flash_attention
-        for bq in _attention_candidates(Sq):
-            # bind block_q eagerly (a partial, not a default-arg
-            # closure): the block size is a trace-time static
-            flash_fn = functools.partial(flash_attention, causal=causal,
-                                         scale=scale, block_q=bq)
-            ms = _measure_ms(flash_fn, (q, k, v))
-            jit_cand = jax.jit(flash_fn)
-            par = _parity(jit_cand(q, k, v), ref, dtype)
-            cands["flash/bq=%d" % bq] = round(ms, 4)
-            if par is None:
-                continue
-            if best_ms is None or ms < best_ms:
-                best_bq, best_ms, best_parity = bq, ms, par
-        entry.update(baseline_ms=round(base_ms, 4), candidates=cands)
-        if best_bq is not None:
-            entry.update(block_q=best_bq, best_ms=round(best_ms, 4),
-                         parity=best_parity,
-                         speedup=round(base_ms / best_ms, 4))
-            if best_ms <= base_ms:
-                entry["impl"] = "flash"
-            else:
-                entry["reason"] = "slower than XLA lowering"
+    base_ms = _measure_ms(xla_fn, (q, k, v))
+    import jax
+    jit_ref = jax.jit(xla_fn)  # parity reference: jit-vs-jit only
+    ref = jit_ref(q, k, v)
+    cands = {}
+    best_bq, best_ms, best_parity = None, None, None
+    from .ops.pallas_kernels import flash_attention
+    for bq in _attention_candidates(Sq, kv_shape[2]):
+        # bind block_q eagerly (a partial, not a default-arg
+        # closure): the block size is a trace-time static
+        flash_fn = functools.partial(flash_attention, causal=causal,
+                                     scale=scale, block_q=bq)
+        ms = _measure_ms(flash_fn, (q, k, v))
+        jit_cand = jax.jit(flash_fn)
+        par = _parity(jit_cand(q, k, v), ref, dtype)
+        cands["flash/bq=%d" % bq] = round(ms, 4)
+        if par is None:
+            continue
+        if best_ms is None or ms < best_ms:
+            best_bq, best_ms, best_parity = bq, ms, par
+    entry.update(baseline_ms=round(base_ms, 4), candidates=cands)
+    if best_bq is not None:
+        entry.update(block_q=best_bq, best_ms=round(best_ms, 4),
+                     parity=best_parity,
+                     speedup=round(base_ms / best_ms, 4))
+        if best_ms <= base_ms:
+            entry["impl"] = "flash"
         else:
-            entry["reason"] = "no candidate passed parity"
-    except Exception as exc:  # noqa: BLE001 — a kernel that cannot even
-        # measure loses permanently (the AOT-rejection fallback contract)
-        entry["reason"] = "search failed: %s" % exc
+            entry["reason"] = "slower than XLA lowering"
+    else:
+        entry["reason"] = "no candidate passed parity"
     return record("attention", site, dtype, entry)
 
 
@@ -443,21 +454,16 @@ def attention_pick(q_shape, kv_shape, dtype, causal, scale=None):
 
 
 # ------------------------------------------------- paged-attention search
-def _paged_candidates(BH):
+def _paged_candidates(BH, K, D, kv_itemsize, quantized):
     """Deduplicated effective ``block_bh`` candidates for a BH-row paged
-    decode: bases snapped through the ``_row_block`` divisor walk.  A
-    one-row block is EXCLUDED whenever BH has a larger divisor — XLA
-    lowers the degenerate single-row dot through a differently-ordered
-    reduction (last-ulp drift), and the paged tier rides the bitwise
-    greedy-parity contract."""
-    from .ops.pallas_kernels import _row_block
-    bases = [2, 4, 8, 16, BH]
-    eff = sorted({_row_block(BH, 1, budget=min(b, BH)) for b in bases if b})
-    if BH > 1:
-        eff = [e for e in eff if e > 1]
-        if not eff:
-            eff = [next(r for r in range(2, BH + 1) if BH % r == 0)]
-    return eff
+    decode: bases snapped through the ``_row_block`` divisor walk (the
+    row axis is a leading block dim, so any divisor is a legal block),
+    none larger than the kernel's own VMEM-budget pick — a compile
+    failure is an error, so the search only tries blocks that fit."""
+    from .ops.pallas_kernels import _row_block, paged_block_rows
+    top = paged_block_rows(BH, K, D, kv_itemsize, quantized)
+    return sorted({_row_block(BH, 1, budget=min(b, top), align=1)
+                   for b in (1, 2, 4, 8, 16, top)})
 
 
 def _paged_site(q_shape, kv_shape, quantized):
@@ -466,12 +472,14 @@ def _paged_site(q_shape, kv_shape, quantized):
         B, H, kv_shape[2], D, int(quantized))
 
 
+@_eager
 def search_paged(q_shape, kv_shape, dtype, quantized, scale=None):
     """Measure the Pallas paged-attention kernel over its ``block_bh``
     candidates against the XLA page-gather lowering at one decode site;
     persist and return the winner.  Same gate as the flash search:
     parity (bitwise-or-tolerance) AND speedup >= 1.0x, losers fall back
-    to XLA permanently.  ``quantized`` sites measure with synthetic int8
+    to XLA permanently; a candidate that cannot compile raises.
+    ``quantized`` sites measure with synthetic int8
     K/V pages + per-row scales — the operands the serve path gathers."""
     import numpy as np
     from .kernels import _paged_attention_xla
@@ -500,39 +508,35 @@ def search_paged(q_shape, kv_shape, dtype, quantized, scale=None):
                                     k_scale=ks, v_scale=vs)
 
     entry = {"impl": "xla", "site": site, "quantized": bool(quantized)}
-    try:
-        base_ms = _measure_ms(xla_fn, (q, k, v, valid))
-        import jax
-        jit_ref = jax.jit(xla_fn)  # parity reference: jit-vs-jit only
-        ref = jit_ref(q, k, v, valid)
-        cands = {}
-        best_bb, best_ms, best_parity = None, None, None
-        for bb in _paged_candidates(B * H):
-            paged_fn = functools.partial(pallas_paged_attention,
-                                         scale=scale, k_scale=ks,
-                                         v_scale=vs, block_bh=bb)
-            jit_cand = jax.jit(paged_fn)
-            ms = _measure_ms(paged_fn, (q, k, v, valid))
-            par = _parity(jit_cand(q, k, v, valid), ref, dtype)
-            cands["paged/bh=%d" % bb] = round(ms, 4)
-            if par is None:
-                continue
-            if best_ms is None or ms < best_ms:
-                best_bb, best_ms, best_parity = bb, ms, par
-        entry.update(baseline_ms=round(base_ms, 4), candidates=cands)
-        if best_bb is not None:
-            entry.update(block_bh=best_bb, best_ms=round(best_ms, 4),
-                         parity=best_parity,
-                         speedup=round(base_ms / best_ms, 4))
-            if best_ms <= base_ms:
-                entry["impl"] = "paged"
-            else:
-                entry["reason"] = "slower than XLA lowering"
+    base_ms = _measure_ms(xla_fn, (q, k, v, valid))
+    import jax
+    jit_ref = jax.jit(xla_fn)  # parity reference: jit-vs-jit only
+    ref = jit_ref(q, k, v, valid)
+    cands = {}
+    best_bb, best_ms, best_parity = None, None, None
+    for bb in _paged_candidates(B * H, K, D, k.dtype.itemsize, quantized):
+        paged_fn = functools.partial(pallas_paged_attention,
+                                     scale=scale, k_scale=ks,
+                                     v_scale=vs, block_bh=bb)
+        ms = _measure_ms(paged_fn, (q, k, v, valid))
+        jit_cand = jax.jit(paged_fn)
+        par = _parity(jit_cand(q, k, v, valid), ref, dtype)
+        cands["paged/bh=%d" % bb] = round(ms, 4)
+        if par is None:
+            continue
+        if best_ms is None or ms < best_ms:
+            best_bb, best_ms, best_parity = bb, ms, par
+    entry.update(baseline_ms=round(base_ms, 4), candidates=cands)
+    if best_bb is not None:
+        entry.update(block_bh=best_bb, best_ms=round(best_ms, 4),
+                     parity=best_parity,
+                     speedup=round(base_ms / best_ms, 4))
+        if best_ms <= base_ms:
+            entry["impl"] = "paged"
         else:
-            entry["reason"] = "no candidate passed parity"
-    except Exception as exc:  # noqa: BLE001 — a kernel that cannot even
-        # measure loses permanently (the AOT-rejection fallback contract)
-        entry["reason"] = "search failed: %s" % exc
+            entry["reason"] = "slower than XLA lowering"
+    else:
+        entry["reason"] = "no candidate passed parity"
     return record("paged", site, dtype, entry)
 
 
@@ -580,6 +584,7 @@ def _fused_kind(optimizer):
     return None
 
 
+@_eager
 def search_fused(optimizer):
     """Measure the optimizer's fused Pallas update+cast epilogue against
     its own ``step()`` + astype (the exact pair the trainers route
@@ -607,24 +612,21 @@ def search_fused(optimizer):
         return nw.astype(jnp.bfloat16), nw, ns
 
     entry = {"impl": "xla", "site": site}
-    try:
-        base_ms = _measure_ms(xla_fn, (w, g))
-        fused_ms = _measure_ms(fused_fn, (w, g))
-        jit_fused, jit_base = jax.jit(fused_fn), jax.jit(xla_fn)
-        par = _parity(jit_fused(w, g), jit_base(w, g), "float32")
-        entry.update(baseline_ms=round(base_ms, 4),
-                     best_ms=round(fused_ms, 4),
-                     speedup=round(base_ms / fused_ms, 4))
-        if par is not None:
-            entry["parity"] = par
-            if fused_ms <= base_ms:
-                entry["impl"] = "fused"
-            else:
-                entry["reason"] = "slower than XLA lowering"
+    base_ms = _measure_ms(xla_fn, (w, g))
+    fused_ms = _measure_ms(fused_fn, (w, g))
+    jit_fused, jit_base = jax.jit(fused_fn), jax.jit(xla_fn)
+    par = _parity(jit_fused(w, g), jit_base(w, g), "float32")
+    entry.update(baseline_ms=round(base_ms, 4),
+                 best_ms=round(fused_ms, 4),
+                 speedup=round(base_ms / fused_ms, 4))
+    if par is not None:
+        entry["parity"] = par
+        if fused_ms <= base_ms:
+            entry["impl"] = "fused"
         else:
-            entry["reason"] = "parity failed"
-    except Exception as exc:  # noqa: BLE001 — permanent fallback
-        entry["reason"] = "search failed: %s" % exc
+            entry["reason"] = "slower than XLA lowering"
+    else:
+        entry["reason"] = "parity failed"
     return record("fused_step", site, "float32", entry)
 
 

@@ -713,13 +713,6 @@ register_knob(
     "(batch-1 under light load); raise it to trade p50 latency for "
     "batch fill under bursty traffic.")
 register_knob(
-    "serving.compile_cache_dir", "MXNET_TPU_SERVING_COMPILE_CACHE_DIR",
-    str, "",
-    "persistent XLA compilation-cache directory wired into jax.config at "
-    "Server.start(): bucket programs compiled on a previous run reload "
-    "from disk for near-zero cold start. Empty (default) leaves the "
-    "process-level jax cache settings untouched.")
-register_knob(
     "serving.max_pending", "MXNET_TPU_SERVING_MAX_PENDING", int, 1024,
     "mx.serving admission bound: submit() past this many queued requests "
     "fails fast with ServerOverloadedError (retryable — it subclasses "
@@ -946,11 +939,7 @@ def _apply_embedding_unique_size(value):
 
 _ON_SET["embedding.unique_size"] = _apply_embedding_unique_size
 
-# bench / testing
-register_knob(
-    "bench.timeout_s", "MXTPU_BENCH_TIMEOUT", float, 1650.0,
-    "bench.py watchdog in seconds; the default sits under the driver's "
-    "~1800s kill window so partial results always flush before rc=124.")
+# testing
 register_knob(
     "test.seed", "MXNET_TEST_SEED", int, -1,
     "fixed seed for test_utils randomness; -1 draws a fresh one "

@@ -18,7 +18,8 @@ from typing import Optional
 
 import jax
 
-__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
+__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus",
+           "num_tpus", "accelerator_is_real", "require_accelerator"]
 
 
 class Context:
@@ -111,13 +112,32 @@ _ACCEL_CACHE: Optional[list] = None
 
 
 def _accelerator_devices():
-    """All non-cpu devices; falls back to cpu devices when running CPU-only
-    (e.g. the 8-virtual-device test mesh), so mx.tpu()/mx.gpu() still work."""
+    """All non-cpu devices.  On a CPU-only backend (the virtual-device
+    test mesh) the cpu devices stand in, so mx.tpu()/mx.gpu() still
+    resolve — :func:`accelerator_is_real` tells the two apart."""
     global _ACCEL_CACHE
     if _ACCEL_CACHE is None:
         devs = [d for d in jax.devices() if d.platform != "cpu"]
         _ACCEL_CACHE = devs if devs else list(jax.devices())
     return _ACCEL_CACHE
+
+
+def accelerator_is_real() -> bool:
+    """True when ``mx.tpu(i)`` names an accelerator chip, False when cpu
+    devices are standing in for one.  Answered from the device platform,
+    never from the context's name."""
+    return _accelerator_devices()[0].platform != "cpu"
+
+
+def require_accelerator(what: str):
+    """Fail unless the accelerator is real.  Anything that reports a
+    time, a rate or a utilization calls this first: a number taken on
+    stand-in cpu devices must never pass for a device number."""
+    if not accelerator_is_real():
+        raise MXNetErrorNoDevice(
+            "%s needs an accelerator, but this backend has only %s "
+            "device(s); mx.tpu(0) is a cpu device standing in"
+            % (what, jax.default_backend()))
 
 
 def cpu(device_id: int = 0) -> Context:

@@ -99,6 +99,21 @@ SAMPLING_FORMAT_VERSION = 5
 MAX_SUPPORTED_FORMAT = 5
 
 
+def _load_params(path, meta):
+    """``{name: host array}`` from a params .npz.  numpy has no bfloat16
+    of its own: ``savez`` keeps such an array as raw 2-byte records, so
+    the loader views the bytes back as the dtype the meta recorded."""
+    import jax.numpy as jnp
+    loaded = _np.load(path)
+    names = meta["param_names"]
+    dtypes = meta.get("param_dtypes") or [None] * len(names)
+    out = {}
+    for n, dt in zip(names, dtypes):
+        a = loaded[n]
+        out[n] = a.view(jnp.dtype(dt)) if dt and a.dtype.kind == "V" else a
+    return out
+
+
 def _shape_signature(aval):
     """JSON-safe shape: symbolic dims (batch polymorphism) become None."""
     out = []
@@ -173,6 +188,7 @@ def export_model(block, prefix, example_input, include_params=True,
     paths.append(hlo_path)
     meta = {
         "param_names": names,
+        "param_dtypes": [str(v.dtype) for v in values],
         "input_shape": list(data.shape),
         "input_dtype": str(data.dtype),
         "output_shape": _shape_signature(out_aval),
@@ -249,7 +265,7 @@ class StableHLOPredictor:
         params_path = prefix + "-params.npz"
         self._params = None
         if os.path.exists(params_path):
-            loaded = _np.load(params_path)
+            loaded = _load_params(params_path, self.meta)
             # one-time H2D: params live on device for the predictor's life
             self._params = tuple(
                 _io.ensure_staged(loaded[n], source="deploy")
@@ -542,6 +558,7 @@ def export_generation(model, params, prefix, page_size=None,
 
     meta = {
         "param_names": names,
+        "param_dtypes": [str(v.dtype) for v in values],
         "input_dtype": "int32",
         "format_version": (SAMPLING_FORMAT_VERSION if v5
                            else GENERATE_FORMAT_VERSION),
@@ -626,7 +643,7 @@ class GenerationPredictor:
         params_path = prefix + "-params.npz"
         self._params = None
         if os.path.exists(params_path):
-            loaded = _np.load(params_path)
+            loaded = _load_params(params_path, self.meta)
             # one-time H2D, device-resident for the predictor's life
             self._params = _unflatten_params({
                 n: _io.ensure_staged(loaded[n], source="deploy")

@@ -128,7 +128,23 @@ class TransformerLM:
         # mx.kernels routes to the fused Pallas flash kernel when the
         # tier is on and the shape qualifies; otherwise (and by default)
         # this IS the plain XLA attention lowering
-        return _kernels.attention(q, k, v, causal=self.cfg.causal)
+        attend = partial(_kernels.attention, causal=self.cfg.causal)
+        axes = tuple(a for a in (self._dp, self._tp)
+                     if a is not None and self.mesh.shape[a] > 1)
+        if axes and _kernels.enabled():
+            # a Mosaic kernel cannot be partitioned by the compiler: on a
+            # mesh that splits batch or heads the routed call runs per
+            # shard, and the route is picked at the shard's shape.
+            # Attention is independent per (batch, head): the body holds
+            # no collective, so there is nothing for the varying-axes
+            # check to check (and the Pallas interpreter's own loops trip
+            # it).  Tier off keeps the plain global call.
+            spec = P(self._dp, self._tp, None, None)
+            return jax.shard_map(
+                attend, mesh=self.mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, axis_names=frozenset(axes),
+                check_vma=False)(q, k, v)
+        return attend(q, k, v)
 
     def _qkv(self, x, lp):
         """ln1 + fused QKV projection: x [B,S,D] -> q,k,v [B,H,S,Dh]."""

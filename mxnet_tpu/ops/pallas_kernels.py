@@ -39,27 +39,55 @@ __all__ = ["pallas_row_softmax", "pallas_scale_bias_relu",
 _NEG = -1e30
 
 
-def _row_block(n_rows, row_bytes, budget=None):
-    """Largest divisor of n_rows whose block stays under the VMEM budget
-    (a block must tile the array exactly).  O(sqrt(n)) divisor walk — this
-    runs on the host per eager call, so no linear scans.  ``budget``
-    defaults to the validated ``kernels.vmem_budget`` knob
+_SUBLANES, _LANES = 8, 128   # the TPU's (8, 128) vreg tile
+
+
+def _lane_pad(d):
+    """Width a ``d``-wide minor axis occupies in VMEM: the lane axis is
+    tiled by 128, so a 7-wide row still costs a full 128-lane row."""
+    return -(-int(d) // _LANES) * _LANES
+
+
+def _row_block(n_rows, row_bytes, budget=None, align=_SUBLANES):
+    """Row-block size for a grid that walks ``n_rows`` exactly: the
+    largest divisor of n_rows that is a LEGAL TPU block extent — a
+    multiple of ``align`` or the whole axis — and whose block stays
+    under the VMEM budget.  The Mosaic lowering refuses any other extent
+    on the second-to-last block dim (the sublane axis of the (8, 128)
+    tile); callers whose row axis is a leading, untiled dim pass
+    ``align=1``.  When no legal divisor fits the budget the SMALLEST
+    legal one is returned — the closest the tiling allows; the compiler
+    then decides whether it fits fast memory.  O(sqrt(n)) divisor walk —
+    this runs on the host per eager call, so no linear scans.
+    ``budget`` defaults to the validated ``kernels.vmem_budget`` knob
     (MXNET_TPU_KERNELS_VMEM_BUDGET)."""
     if budget is None:
         from .. import config as _config
         budget = _config.get("kernels.vmem_budget")
     cap = max(1, budget // max(row_bytes, 1))
-    best = 1
+    best, smallest = 0, n_rows
     i = 1
     while i * i <= n_rows:
         if n_rows % i == 0:
-            if i <= cap and i > best:
-                best = i
-            j = n_rows // i
-            if j <= cap and j > best:
-                best = j
+            for j in (i, n_rows // i):
+                if j % align and j != n_rows:
+                    continue
+                if best < j <= cap:
+                    best = j
+                if j < smallest:
+                    smallest = j
         i += 1
-    return best
+    return best or smallest
+
+
+def _mxu_precision(*operands):
+    """Contract precision of an in-kernel MXU product.  16-bit operands
+    take the MXU's native pass — Mosaic has no higher-precision matmul
+    for them, so a process-wide ``jax_default_matmul_precision`` must not
+    reach them; f32 operands follow the ambient setting (None)."""
+    if any(o.dtype.itemsize < 4 for o in operands):  # mxlint: disable=jit.tracer-branch
+        return jax.lax.Precision.DEFAULT             # (a dtype is static)
+    return None
 
 
 # ------------------------------------------------------------ row softmax
@@ -90,7 +118,7 @@ def _softmax_fwd_call(flat):
     from jax.experimental import pallas as pl
     from ..rtc import interpret_mode
     n, d = flat.shape
-    rows = _row_block(n, d * flat.dtype.itemsize)
+    rows = _row_block(n, _lane_pad(d) * flat.dtype.itemsize)
     return pl.pallas_call(
         _row_softmax_kernel,
         out_shape=[jax.ShapeDtypeStruct(flat.shape, flat.dtype),
@@ -108,7 +136,7 @@ def _softmax_bwd_call(x, m, l, dy):
     from jax.experimental import pallas as pl
     from ..rtc import interpret_mode
     n, d = x.shape
-    rows = _row_block(n, d * x.dtype.itemsize)
+    rows = _row_block(n, _lane_pad(d) * x.dtype.itemsize)
     return pl.pallas_call(
         _row_softmax_bwd_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -162,8 +190,9 @@ def _flash_fwd_kernel(scale, causal, block_q, q_ref, k_ref, v_ref,
     materialized in HBM, which is the whole point of flash attention: HBM
     traffic is O(S*D) instead of O(S^2).  Softmax accumulates in f32 on
     chip; the MXU does both matmuls.  The per-row logsumexp lands in a
-    [block_q] residual strip so the backward can rebuild the
-    probabilities without a second max/sum pass.
+    [block_q, 1] residual column (the layout the row reductions already
+    have — a 1-D strip is not a legal TPU block) so the backward can
+    rebuild the probabilities without a second max/sum pass.
     """
     from jax.experimental import pallas as pl
     q = q_ref[0].astype(jnp.float32)                # [bq, D]
@@ -182,9 +211,10 @@ def _flash_fwd_kernel(scale, causal, block_q, q_ref, k_ref, v_ref,
     l = jnp.sum(e, axis=-1, keepdims=True)
     acc = jax.lax.dot_general(e.astype(v.dtype), v,
                               (((1,), (0,)), ((), ())),
+                              precision=_mxu_precision(v),
                               preferred_element_type=jnp.float32)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m[:, 0] + jnp.log(l[:, 0])
+    lse_ref[0] = m + jnp.log(l)
 
 
 def _flash_bwd_dq_kernel(scale, causal, block_q, q_ref, k_ref, v_ref,
@@ -198,8 +228,8 @@ def _flash_bwd_dq_kernel(scale, causal, block_q, q_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)                # [S, D]
     v = v_ref[0].astype(jnp.float32)                # [S, D]
     do = do_ref[0].astype(jnp.float32)              # [bq, D]
-    lse = lse_ref[0]                                # [bq]
-    delta = delta_ref[0]                            # [bq]
+    lse = lse_ref[0]                                # [bq, 1]
+    delta = delta_ref[0]                            # [bq, 1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
@@ -208,10 +238,10 @@ def _flash_bwd_dq_kernel(scale, causal, block_q, q_ref, k_ref, v_ref,
             jnp.int32, s.shape, 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG)
-    p = jnp.exp(s - lse[:, None])                   # [bq, S]
+    p = jnp.exp(s - lse)                            # [bq, S]
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_ref[0] = jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(dq_ref.dtype)
@@ -228,8 +258,8 @@ def _flash_bwd_dkv_kernel(scale, causal, block_k, q_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)                # [bk, D]
     v = v_ref[0].astype(jnp.float32)                # [bk, D]
     do = do_ref[0].astype(jnp.float32)              # [Sq, D]
-    lse = lse_ref[0]                                # [Sq]
-    delta = delta_ref[0]                            # [Sq]
+    lse = lse_ref[0]                                # [1, Sq]
+    delta = delta_ref[0]                            # [1, Sq]
     st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * scale
     if causal:
@@ -238,13 +268,13 @@ def _flash_bwd_dkv_kernel(scale, causal, block_k, q_ref, k_ref, v_ref,
             jnp.int32, st.shape, 0)
         q_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
         st = jnp.where(k_pos <= q_pos, st, _NEG)
-    pt = jnp.exp(st - lse[None, :])                 # [bk, Sq]
+    pt = jnp.exp(st - lse)                          # [bk, Sq]
     dv_ref[0] = jax.lax.dot_general(
         pt, do, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(dv_ref.dtype)
     dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    dst = pt * (dpt - delta[None, :]) * scale
+    dst = pt * (dpt - delta) * scale
     dk_ref[0] = jax.lax.dot_general(
         dst, q, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(dk_ref.dtype)
@@ -255,8 +285,8 @@ def _flash_forward(q, k, v, causal, scale, block_q):
     from ..rtc import interpret_mode
     B, H, S, D = q.shape
     Skv = k.shape[2]
-    # largest divisor of S <= block_q, so an awkward block_q degrades to
-    # the best legal tiling instead of cliff-diving to 1-row blocks
+    # largest legal divisor of S <= block_q (a multiple of 8, or S), so
+    # an awkward block_q degrades to the best legal tiling
     bq = _row_block(S, 1, budget=min(block_q, S))
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, Skv, D)
@@ -265,15 +295,15 @@ def _flash_forward(q, k, v, causal, scale, block_q):
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(qf.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.float32)],
+                   jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32)],
         grid=(B * H, S // bq),
         in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((1, Skv, D), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((1, Skv, D), lambda b, i: (b, 0, 0))],
         out_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, bq), lambda b, i: (b, i))],
+                   pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0))],
         interpret=interpret_mode())(qf, kf, vf)
-    return out.reshape(B, H, S, D), lse
+    return out.reshape(B, H, S, D), lse.reshape(B * H, S)
 
 
 def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
@@ -290,6 +320,12 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
     # delta = rowsum(dO * O) — elementwise O(S*D), cheap in plain XLA
     delta = jnp.sum(dof.astype(jnp.float32) *
                     o.reshape(B * H, S, D).astype(jnp.float32), axis=-1)
+    # the [BH, S] row residuals ride into each kernel in the layout its
+    # broadcast needs, with trailing block dims that are legal TPU
+    # blocks: a [bq, 1] column per q block for dq, the whole [1, S] row
+    # for the transposed strip of dk/dv
+    lse_c, delta_c = lse[:, :, None], delta[:, :, None]
+    lse_r, delta_r = lse[:, None, :], delta[:, None, :]
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale, bool(causal), bq),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
@@ -298,10 +334,10 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
                   pl.BlockSpec((1, Skv, D), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((1, Skv, D), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, bq), lambda b, i: (b, i)),
-                  pl.BlockSpec((1, bq), lambda b, i: (b, i))],
+                  pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
+                  pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0))],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        interpret=interpret_mode())(qf, kf, vf, dof, lse, delta)
+        interpret=interpret_mode())(qf, kf, vf, dof, lse_c, delta_c)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale, bool(causal), bk),
         out_shape=[jax.ShapeDtypeStruct(kf.shape, k.dtype),
@@ -311,11 +347,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
                   pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
                   pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
                   pl.BlockSpec((1, S, D), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, S), lambda b, j: (b, 0)),
-                  pl.BlockSpec((1, S), lambda b, j: (b, 0))],
+                  pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0)),
+                  pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0))],
         out_specs=[pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0))],
-        interpret=interpret_mode())(qf, kf, vf, dof, lse, delta)
+        interpret=interpret_mode())(qf, kf, vf, dof, lse_r, delta_r)
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, Skv, D),
             dv.reshape(B, H, Skv, D))
 
@@ -391,32 +427,51 @@ def _paged_attn_kernel(scale, quant, *refs):
     a decode query is a single row, so the whole gathered context of a
     row block lives in VMEM and the stable (max, sum) accumulation
     happens on chip in f32 in one pass; no partial-block merge is ever
-    needed.  Masked slots pin to the ``-1e30`` floor of
-    ``parallel.ring_attention._block_attn``, so ``exp`` underflows to an
-    EXACT 0.0 in both the denominator and the value sum — the bitwise
-    contract the greedy-parity oracle rides on.  With ``quant`` the K/V
-    blocks arrive int8 and dequantize INSIDE the kernel (one f32
-    broadcast multiply per row), so HBM traffic stays at the int8 byte
-    count — the entire point of int8 KV pages."""
+    needed.  Every operand keeps the (batch, head) row as a LEADING dim
+    ([rows, 1, D] query, [rows, K, D] context, [rows, 1, K] mask and
+    scales), so both matmuls are the batched [1, D] x [K, D] form the
+    MXU lowering takes and the trailing two block dims always span the
+    whole array — legal for any row count.  Masked slots pin to the
+    ``-1e30`` floor of ``parallel.ring_attention._block_attn``, so
+    ``exp`` underflows to an EXACT 0.0 in both the denominator and the
+    value sum.  With ``quant`` the K/V blocks arrive int8 and the per-slot
+    scales fold into the scores and the probabilities (``q.(k*s) ==
+    (q.k)*s``) — they stay K-on-lanes rows, and HBM traffic stays at the
+    int8 byte count, the entire point of int8 KV pages."""
     if quant:
         q_ref, k_ref, v_ref, valid_ref, ks_ref, vs_ref, o_ref = refs
     else:
         q_ref, k_ref, v_ref, valid_ref, o_ref = refs
-    q = q_ref[:]                                    # [rows, D]
+    q = q_ref[:]                                    # [rows, 1, D]
     k = k_ref[:]                                    # [rows, K, D]
     v = v_ref[:]
     if quant:
-        k = k.astype(jnp.float32) * ks_ref[:][..., None]
-        v = v.astype(jnp.float32) * vs_ref[:][..., None]
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid_ref[:], s, _NEG)            # [rows, K]
+        q = q.astype(jnp.float32)
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+    s = jnp.einsum("bqd,bkd->bqk", q, k, precision=_mxu_precision(q, k),
+                   preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * ks_ref[:]
+    s = jnp.where(valid_ref[:] != 0, s, _NEG)       # [rows, 1, K]
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     l = jnp.sum(e, axis=-1, keepdims=True)
-    acc = jax.lax.dot_general(e.astype(v.dtype), v,
-                              (((1,), (1,)), ((0,), (0,))))
-    o_ref[:] = (acc / l.astype(acc.dtype)).astype(o_ref.dtype)
+    p = e * vs_ref[:] if quant else e
+    acc = jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
+                     precision=_mxu_precision(v),
+                     preferred_element_type=jnp.float32)
+    o_ref[:] = (acc / l).astype(o_ref.dtype)
+
+
+def paged_block_rows(BH, K, D, kv_itemsize, quant):
+    """(batch, head) rows per grid step of the paged kernel under the
+    VMEM budget.  Per row the gathered K and V dominate — lane-padded,
+    and int8 pages also hold their f32 working copies; scales, mask and
+    q are noise.  The row axis is a leading block dim, so any divisor of
+    BH is a legal block."""
+    row_bytes = 2 * K * _lane_pad(D) * (kv_itemsize + 4 * int(quant))
+    return _row_block(BH, row_bytes, align=1)
 
 
 def pallas_paged_attention(q, k, v, valid, scale=None, k_scale=None,
@@ -433,11 +488,9 @@ def pallas_paged_attention(q, k, v, valid, scale=None, k_scale=None,
 
     The grid walks blocks of ``block_bh`` (batch, head) rows (None =
     derive from the VMEM budget); each step holds its rows' full
-    gathered K/V in VMEM.  The math is row-independent, so EVERY legal
-    block size computes identical bits — which is why the
-    mx.perf.autotune "paged" search can tune it freely under the bitwise
-    greedy-parity contract.  Routing/fallback policy lives in
-    ``mx.kernels.paged_attention``."""
+    gathered K/V in VMEM.  The math is row-independent, so the
+    mx.perf.autotune "paged" search can tune the block size freely.
+    Routing/fallback policy lives in ``mx.kernels.paged_attention``."""
     from jax.experimental import pallas as pl
     from ..rtc import interpret_mode
     q = jnp.asarray(q)
@@ -454,39 +507,35 @@ def pallas_paged_attention(q, k, v, valid, scale=None, k_scale=None,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
     BH = B * H
-    qf = q.reshape(BH, D)
+    qf = q.reshape(BH, 1, D)
     kf = k.reshape(BH, K, D)
     vf = v.reshape(BH, K, D)
-    validf = jnp.broadcast_to(valid[:, None, :], (B, H, K)).reshape(BH, K)
-    # per-row VMEM: the gathered K/V dominate; scales/mask/q are noise
-    row_bytes = 2 * K * D * k.dtype.itemsize \
-        + K * (1 + 8 * int(quant)) + D * (q.dtype.itemsize + 4)
+    validf = jnp.broadcast_to(valid[:, None, None, :].astype(jnp.int32),
+                              (B, H, 1, K)).reshape(BH, 1, K)
     if block_bh is None:
-        rows = _row_block(BH, row_bytes)
+        rows = paged_block_rows(BH, K, D, k.dtype.itemsize, quant)
     else:
-        rows = _row_block(BH, 1, budget=min(int(block_bh), BH))
-    if rows == 1 and BH > 1:
-        # XLA lowers the degenerate one-row dot_general through a
-        # different reduction than the multi-row form (last-ulp drift),
-        # which would break the bitwise greedy-parity contract — snap up
-        # to the smallest real divisor instead.
-        rows = next(r for r in range(2, BH + 1) if BH % r == 0)
+        rows = _row_block(BH, 1, budget=min(int(block_bh), BH), align=1)
+
+    def row3(i):
+        return (i, 0, 0)
+
     operands = [qf, kf, vf, validf]
-    in_specs = [pl.BlockSpec((rows, D), lambda i: (i, 0)),
-                pl.BlockSpec((rows, K, D), lambda i: (i, 0, 0)),
-                pl.BlockSpec((rows, K, D), lambda i: (i, 0, 0)),
-                pl.BlockSpec((rows, K), lambda i: (i, 0))]
+    in_specs = [pl.BlockSpec((rows, 1, D), row3),
+                pl.BlockSpec((rows, K, D), row3),
+                pl.BlockSpec((rows, K, D), row3),
+                pl.BlockSpec((rows, 1, K), row3)]
     if quant:
-        operands += [jnp.asarray(k_scale, jnp.float32).reshape(BH, K),
-                     jnp.asarray(v_scale, jnp.float32).reshape(BH, K)]
-        in_specs += [pl.BlockSpec((rows, K), lambda i: (i, 0)),
-                     pl.BlockSpec((rows, K), lambda i: (i, 0))]
+        operands += [jnp.asarray(k_scale, jnp.float32).reshape(BH, 1, K),
+                     jnp.asarray(v_scale, jnp.float32).reshape(BH, 1, K)]
+        in_specs += [pl.BlockSpec((rows, 1, K), row3),
+                     pl.BlockSpec((rows, 1, K), row3)]
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale, quant),
-        out_shape=jax.ShapeDtypeStruct((BH, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
         grid=(BH // rows,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows, D), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, 1, D), row3),
         interpret=interpret_mode())(*operands)
     return out.reshape(B, H, 1, D)
 
@@ -533,11 +582,17 @@ def _adam_epilogue_kernel(beta1, beta2, eps, w_ref, g_ref, m_ref, v_ref,
 
 
 def _flat2d(a):
+    """The 2-D view an elementwise kernel streams.  The op is pointwise,
+    so any reshape computes the same bits: take a lane-dense view
+    whenever the size allows.  Keeping the array's own minor axis would
+    tile a conv weight's 3-wide (or a 1x1 conv's 1-wide) rows into
+    128-lane tiles — up to 128x the bytes in HBM and in VMEM."""
+    for lanes in (8 * _LANES, 4 * _LANES, 2 * _LANES, _LANES):
+        if a.size and a.size % lanes == 0:
+            return a.reshape(-1, lanes)
     if a.ndim >= 2:
         return a.reshape(-1, a.shape[-1])
-    if a.ndim == 1:
-        return a.reshape(1, -1)
-    return a.reshape(1, 1)
+    return a.reshape(1, -1)
 
 
 def _epilogue_call(kernel, arrays, scalars, out_dtypes, block_rows=None):
@@ -554,7 +609,7 @@ def _epilogue_call(kernel, arrays, scalars, out_dtypes, block_rows=None):
     flats = [_flat2d(a) for a in arrays]
     n, d = flats[0].shape
     itemsize = max(f.dtype.itemsize for f in flats)
-    row_bytes = d * itemsize * (len(arrays) + len(out_dtypes))
+    row_bytes = _lane_pad(d) * itemsize * (len(arrays) + len(out_dtypes))
     if block_rows is None:
         rows = _row_block(n, row_bytes)
     else:
@@ -635,7 +690,7 @@ def pallas_scale_bias_relu(data, scale, bias, **_):
     n = flat.shape[0]
     s = jnp.asarray(scale).reshape(1, d).astype(x.dtype)
     b = jnp.asarray(bias).reshape(1, d).astype(x.dtype)
-    rows = _row_block(n, d * flat.dtype.itemsize)
+    rows = _row_block(n, _lane_pad(d) * flat.dtype.itemsize)
     out = pl.pallas_call(
         _scale_bias_relu_kernel,
         out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),

@@ -45,7 +45,7 @@ from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardedEmbedding", "dedup_ids", "lookup_unique", "update_unique",

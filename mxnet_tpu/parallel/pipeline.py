@@ -24,29 +24,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.8 moved shard_map out of experimental
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
 __all__ = ["pipeline_apply", "pipeline_sharded", "microbatch",
            "unmicrobatch", "shmap"]
 
 
-import inspect as _inspect
-
-_SHMAP_KW = ({"check_rep": False}
-             if "check_rep" in _inspect.signature(
-                 _shard_map_raw).parameters else {})
-
-
 def shmap(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the experimental API needs
-    check_rep=False for bodies whose collectives confuse its replication
-    checker; the jax>=0.8 API dropped the kwarg (its varying-axis inference
-    handles these bodies)."""
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **_SHMAP_KW)
+    """``jax.shard_map`` with the positional (f, mesh, in, out) order the
+    parallel modules use."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def microbatch(x, n_micro):
@@ -73,8 +59,8 @@ def pipeline_apply(stage_fn, stage_params, x, axis_name="pp",
     contract); the first stage receives the raw microbatch, so embed/head
     asymmetries belong inside stage_fn gated on ``lax.axis_index``.
 
-    vary_axes — mesh axes the activations vary over, for jax>=0.8's
-    varying-manual-axes carry typing.  Defaults to the input's axes plus
+    vary_axes — mesh axes the activations vary over, for the scan
+    carry's varying-manual-axes type.  Defaults to the input's axes plus
     `axis_name`; a stage whose body makes outputs vary over MORE axes
     (e.g. an internal expert-parallel all_to_all) must name them here.
     """
@@ -96,15 +82,12 @@ def pipeline_apply(stage_fn, stage_params, x, axis_name="pp",
         nxt = lax.ppermute(out, axis_name, perm)
         return nxt, out
 
-    act0 = jnp.zeros(x.shape[1:], x.dtype)
-    if hasattr(lax, "pcast"):
-        # jax>=0.8 tracks varying-manual-axes: the carry starts replicated
-        # but turns varying after the first ppermute — mark it up front
-        if vary_axes is None:
-            xv = getattr(jax.typeof(x), "vma", frozenset()) \
-                if hasattr(jax, "typeof") else frozenset()
-            vary_axes = tuple(set(xv) | {axis_name})
-        act0 = lax.pcast(act0, tuple(vary_axes), to="varying")
+    # the carry starts replicated but turns varying after the first
+    # ppermute — mark it up front
+    if vary_axes is None:
+        vary_axes = tuple(set(jax.typeof(x).vma) | {axis_name})
+    act0 = lax.pcast(jnp.zeros(x.shape[1:], x.dtype), tuple(vary_axes),
+                     to="varying")
     _, outs = lax.scan(tick, act0, jnp.arange(ticks))
 
     # microbatch j leaves the last stage at tick j + n_stage - 1

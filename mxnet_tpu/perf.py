@@ -37,11 +37,11 @@ Capture mechanics: the registry wraps each jitted step fn in a
 .compile()``) on its first concrete call — the same single XLA compile
 the lazy ``jit`` path would have done, now with the phase breakdown and
 the ``Compiled`` handle in hand — then dispatches that Compiled
-directly.  Anything the AOT pipeline can't serve (tracer arguments from
-an outer ``jax.vjp``, a shape-signature drift under a cached wrapper)
-falls back to the plain jitted callable (``perf.aot_fallback`` counts
-the permanent ones), so wrapping is behavior-preserving by
-construction: same lowering, same donation, bitwise-identical outputs.
+directly.  Two calls the Compiled can't take go to the plain jitted
+callable instead: tracer arguments from an outer ``jax.vjp``, and a
+shape-signature drift under a cached wrapper (``perf.aot_fallback``
+counts those, permanently).  A failure to trace, lower or compile is NOT
+one of them — it propagates, exactly as it would from the lazy ``jit``.
 
 ``MXNET_TPU_PROFILE=step:N`` adds periodic evidence capture: every N
 steps the next full step runs under a ``jax.profiler`` device trace
@@ -60,77 +60,87 @@ import threading
 import time
 
 __all__ = [
-    "PEAK_BF16_TFLOPS", "DEFAULT_PEAK", "PEAK_HBM_GBPS", "DEFAULT_HBM_GBPS",
+    "DEVICE_PEAKS", "UnknownDeviceError", "known_device",
     "OP_CLASSES", "classify_op", "hlo_op_classes", "device_kind",
     "peak_flops", "peak_bandwidth", "roofline", "register_compiled",
     "programs", "program", "reset", "export", "wrap", "PerfProgram",
     "configure_profile", "cost_analysis", "autotune",
 ]
 
-# ----------------------------------------------------------- peak tables
-# MXU bf16 peak by device kind (TFLOPS).  bench.py keeps a module-level
-# copy (it must not import mxnet_tpu — and so jax — before its patient
-# backend probe); tests/test_perf.py asserts the two stay identical, the
-# same sync contract test_op_sweep.py enforces for the watchdog default.
-PEAK_BF16_TFLOPS = {
-    "TPU v5 lite": 197.0,   # v5e
-    "TPU v5": 459.0,        # v5p
-    "TPU v4": 275.0,
-    "TPU v6 lite": 918.0,   # v6e / Trillium
+# ------------------------------------------------------------ peak table
+# THE peak table: published per-chip peaks keyed by jax's ``device_kind``,
+# as (bf16 MXU TFLOP/s, HBM GB/s).  Source: Google Cloud TPU
+# documentation, the per-generation system-architecture pages ("TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s; "TPU v5p": 459, 2765; "TPU v4": 275, 1228;
+# "TPU v6e": 918, 1640).  bench.py, chip_smoke.py and the tools read this
+# table and keep no copy.  A device that is not here has no peak: every
+# path that would print an MFU or a roofline share for it raises
+# :class:`UnknownDeviceError` instead of assuming some other chip's
+# numbers, and cost records for it carry ``None`` in their place.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197.0, 819.0),   # v5e
+    "TPU v5": (459.0, 2765.0),       # v5p
+    "TPU v4": (275.0, 1228.0),
+    "TPU v6 lite": (918.0, 1640.0),  # v6e / Trillium
 }
-DEFAULT_PEAK = 197.0
-
-# HBM bandwidth by device kind (GB/s) — the roofline's other axis.
-PEAK_HBM_GBPS = {
-    "TPU v5 lite": 819.0,
-    "TPU v5": 2765.0,
-    "TPU v4": 1228.0,
-    "TPU v6 lite": 1640.0,
-}
-DEFAULT_HBM_GBPS = 819.0
 
 # peak scaling per compute dtype: bf16 is the MXU native rate; f32 has no
 # MXU path and runs at roughly half; int8 doubles on chips with int MXU
 # modes.  The basis is recorded next to every MFU number so denominators
-# stay auditable (the bench.py peak_basis convention).
+# stay auditable.
 _DTYPE_PEAK_SCALE = {
     "bfloat16": 1.0, "float16": 1.0, "int8": 2.0,
     "float32": 0.5, "float64": 0.25,
 }
 
 
-def device_kind(default=""):
-    """The local accelerator's ``device_kind`` string, cached (the device
-    set is fixed per process)."""
+class UnknownDeviceError(LookupError):
+    """The device kind has no entry in :data:`DEVICE_PEAKS`."""
+
+
+def device_kind():
+    """The local device's ``device_kind`` string, cached (the device set
+    is fixed per process)."""
     kind = _KIND_CACHE[0]
     if kind is None:
-        try:
-            import jax
-            kind = str(getattr(jax.local_devices()[0], "device_kind", ""))
-        except Exception:  # noqa: BLE001 — no backend, generic peaks
-            kind = ""
-        _KIND_CACHE[0] = kind
-    return kind or default
+        import jax
+        kind = _KIND_CACHE[0] = str(jax.local_devices()[0].device_kind)
+    return kind
 
 
 _KIND_CACHE = [None]
 
 
-def peak_flops(kind=None, dtype="bfloat16"):
-    """Peak FLOP/s for a device kind at a compute dtype (dtype-aware:
-    bf16 MXU basis scaled by ``_DTYPE_PEAK_SCALE``).  Unknown kinds use
-    the v5e default, matching bench.py's MFU denominator."""
+def known_device(kind=None):
+    """True when ``kind`` (default: the local device) has published
+    peaks — i.e. an MFU or a roofline share can be stated for it."""
+    return (device_kind() if kind is None else kind) in DEVICE_PEAKS
+
+
+def _peaks(kind):
     if kind is None:
         kind = device_kind()
-    tf = PEAK_BF16_TFLOPS.get(kind, DEFAULT_PEAK)
-    return tf * _DTYPE_PEAK_SCALE.get(str(dtype), 1.0) * 1e12
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            "no published peaks for device kind %r (known: %s); an MFU or "
+            "roofline share against another chip's peak would be a made-up "
+            "number — add the device to mxnet_tpu.perf.DEVICE_PEAKS with "
+            "its source" % (kind, ", ".join(sorted(DEVICE_PEAKS)))) from None
+
+
+def peak_flops(kind=None, dtype="bfloat16"):
+    """Peak FLOP/s for a device kind at a compute dtype (dtype-aware:
+    bf16 MXU basis scaled by ``_DTYPE_PEAK_SCALE``).  Raises
+    :class:`UnknownDeviceError` for a kind without published peaks."""
+    return _peaks(kind)[0] * _DTYPE_PEAK_SCALE.get(str(dtype), 1.0) * 1e12
 
 
 def peak_bandwidth(kind=None):
-    """Peak HBM bandwidth in bytes/s for a device kind."""
-    if kind is None:
-        kind = device_kind()
-    return PEAK_HBM_GBPS.get(kind, DEFAULT_HBM_GBPS) * 1e9
+    """Peak HBM bandwidth in bytes/s for a device kind; raises
+    :class:`UnknownDeviceError` for a kind without published peaks."""
+    return _peaks(kind)[1] * 1e9
 
 
 # --------------------------------------------------------- op-class map
@@ -292,6 +302,9 @@ def register_compiled(family, key, compiled, phases_ms=None, dtype=None):
         return None
     dtype = dtype or "float32"
     kind = device_kind()
+    # a device without published peaks (the CPU test mesh) still gets its
+    # counts; what needs a peak is None, never another chip's number
+    known = known_device(kind)
     phases = {k: round(float(v), 3)
               for k, v in (phases_ms or {}).items()}
     # the compile-phase breakdown as live timer histograms
@@ -313,12 +326,14 @@ def register_compiled(family, key, compiled, phases_ms=None, dtype=None):
         "memory": memory,
         "phases_ms": phases,
         "op_classes": hlo_op_classes(text),
-        "roofline": roofline(flops, nbytes, kind, dtype),
-        "peak_tflops": round(peak_flops(kind, dtype) / 1e12, 3),
+        "roofline": roofline(flops, nbytes, kind, dtype) if known else None,
+        "peak_tflops": (round(peak_flops(kind, dtype) / 1e12, 3)
+                        if known else None),
         "calls": 0,
         # private: per-dispatch accumulation precomputes flops/peak so
         # the step-exit MFU is one divide (stripped from snapshots)
-        "_flops_over_peak": flops / peak_flops(kind, dtype),
+        "_flops_over_peak": (flops / peak_flops(kind, dtype)
+                             if known else None),
     }
     _telemetry.counter("perf.programs").inc()
     with _REG_LOCK:
@@ -386,7 +401,7 @@ def export(path=None):
         "event": "perf_programs",
         "ts": round(time.time(), 3),
         "device_kind": device_kind(),
-        "default_peak_tflops": DEFAULT_PEAK,
+        "device_peaks": DEVICE_PEAKS.get(device_kind()),
         "programs": programs(),
         "autotune": autotune.export_entries(),
     }
@@ -414,7 +429,7 @@ class PerfProgram:
     inlines into the outer program, exactly as unwrapped; a signature
     drift under the cached wrapper (the Compiled rejects the args)
     permanently falls back to plain jit and counts
-    ``perf.aot_fallback``."""
+    ``perf.aot_fallback``.  Trace, lower and compile errors propagate."""
 
     __slots__ = ("fn", "family", "key", "source", "check_tracers",
                  "_compiled", "_record", "_fellback")
@@ -437,13 +452,15 @@ class PerfProgram:
         src = self.source
         if src is None:
             return
+        over_peak = rec["_flops_over_peak"]
         with _PENDING_LOCK:
             cur = _PENDING.get(src)
             if cur is None:
-                _PENDING[src] = [rec["flops"], rec["_flops_over_peak"]]
+                _PENDING[src] = [rec["flops"], over_peak]
             else:
                 cur[0] += rec["flops"]
-                cur[1] += rec["_flops_over_peak"]
+                if over_peak is not None:  # one device kind per process:
+                    cur[1] += over_peak    # every record has it, or none
 
     def _fallback(self, *args):
         from . import telemetry as _telemetry
@@ -454,15 +471,12 @@ class PerfProgram:
 
     def _capture(self, args):
         t0 = time.perf_counter()
-        try:
-            traced = self.fn.trace(*args)
-            t1 = time.perf_counter()
-            lowered = traced.lower()
-            t2 = time.perf_counter()
-            compiled = lowered.compile()
-            t3 = time.perf_counter()
-        except Exception:  # noqa: BLE001 — AOT can't express this call
-            return None
+        traced = self.fn.trace(*args)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
+        t2 = time.perf_counter()
+        compiled = lowered.compile()
+        t3 = time.perf_counter()
         self._record = register_compiled(
             self.family, self.key, compiled,
             phases_ms={"trace_ms": (t1 - t0) * 1e3,
@@ -481,18 +495,17 @@ class PerfProgram:
             return self.fn(*args)
         compiled = self._compiled
         if compiled is None:
-            compiled = self._capture(args)
-            if compiled is None:
-                return self._fallback(*args)
-            self._compiled = compiled
+            compiled = self._compiled = self._capture(args)
         self._account()
         try:
             return compiled(*args)
-        except Exception:  # noqa: BLE001 — signature drift under the
-            # cached wrapper (shape/dtype/weak-type/sharding changed):
-            # re-dispatch through plain jit, which retraces per
-            # signature like the unwrapped path did.  A genuine runtime
-            # failure re-raises from the plain call unchanged.
+        except (TypeError, ValueError):
+            # signature drift under the cached wrapper: the Compiled
+            # checks its arguments BEFORE it dispatches and rejects a
+            # changed shape/dtype/weak-type/pytree (TypeError) or
+            # sharding (ValueError).  Re-dispatch through plain jit,
+            # which retraces per signature like the unwrapped path did.
+            # What the device raises is a RuntimeError and propagates.
             return self._fallback(*args)
 
 
@@ -509,19 +522,22 @@ def wrap(fn, family, key, source=None, check_tracers=False):
 def _on_step(source, step_idx, wall_s):
     """telemetry.step_scope exit hook: pop the source's dispatched-FLOPs
     accumulator into the live MFU gauges and the step record's
-    ``flops``/``mfu`` fields.  Cost: one dict pop; one divide and two
-    gauge sets when a registered program ran this step."""
+    ``flops``/``mfu`` fields (``mfu`` only on a device with published
+    peaks).  Cost: one dict pop; one divide and two gauge sets when a
+    registered program ran this step."""
     with _PENDING_LOCK:
         acc = _PENDING.pop(source, None)
     extra = None
     if acc is not None and wall_s > 0:
-        from . import telemetry as _telemetry
-        # 6 significant digits, not decimals: a CPU-backend MFU is ~1e-8
-        # and must survive the JSONL round-trip
-        mfu = float("%.6g" % (acc[1] / wall_s))
-        _telemetry.gauge("perf.mfu").set(mfu)
-        _telemetry.gauge("perf.mfu.%s" % source).set(mfu)
-        extra = {"flops": round(acc[0], 1), "mfu": mfu}
+        extra = {"flops": round(acc[0], 1)}
+        if acc[1] is not None:  # None: this device has no published peak
+            from . import telemetry as _telemetry
+            # 6 significant digits, not decimals: a small program's MFU
+            # must survive the JSONL round-trip
+            mfu = float("%.6g" % (acc[1] / wall_s))
+            _telemetry.gauge("perf.mfu").set(mfu)
+            _telemetry.gauge("perf.mfu.%s" % source).set(mfu)
+            extra["mfu"] = mfu
     if _PROFILE["every"] > 0:
         _maybe_profile(source, step_idx)
     return extra

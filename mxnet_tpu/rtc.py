@@ -25,12 +25,11 @@ __all__ = ["PallasModule", "PallasKernel", "register_op", "interpret_mode"]
 
 
 def interpret_mode():
-    """True when kernels must run in the Pallas interpreter (no TPU)."""
+    """True when kernels must run in the Pallas interpreter: the default
+    backend is not a TPU.  A backend that fails to start is an error,
+    not a reason to interpret."""
     import jax
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() != "tpu"
 
 
 class PallasKernel:

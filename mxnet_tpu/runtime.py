@@ -19,9 +19,46 @@ from collections import namedtuple
 
 __all__ = ["Feature", "Features", "feature_list", "is_enabled",
            "scan_stack", "stack_tuning", "stack_candidates",
-           "checkpoint_policy"]
+           "checkpoint_policy", "cache_root", "configure_compile_cache"]
 
 Feature = namedtuple("Feature", ["name", "enabled"])
+
+
+def cache_root():
+    """The checkout's one cache directory (git-ignored): XLA's persistent
+    compile cache and the autotune verdicts live here, so nothing outside
+    the tree decides what a run compiles or routes."""
+    import os
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+
+
+_CACHE_CONFIGURED = [None]  # the directory jax's cache was last reset for
+
+
+def configure_compile_cache():
+    """Turn on jax's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the place is
+    the caller's choice — jax reads it by itself and no directory is set
+    here; where it is not, the cache goes to :func:`cache_root`, a fixed
+    path, because the path is part of the cache key and a directory that
+    moves never hits.  Idempotent; entry points (chip_smoke.py, bench.py,
+    the tools, ``Server.start()``) call it before their first big
+    compile."""
+    import os
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = cache_root()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if _CACHE_CONFIGURED[0] != cache_dir:
+        # jax decides whether the cache is in use on the FIRST compile of
+        # the process; a directory that arrives later (params staged and
+        # models warmed before start()) is ignored until that is undone
+        _cc.reset_cache()
+        _CACHE_CONFIGURED[0] = cache_dir
+    return cache_dir
 
 
 def _detect():

@@ -26,16 +26,19 @@ under a restart supervisor):
 
 Key properties:
 
-  * **Bitwise-stable batching** — each output row of a bucketed dispatch
+  * **Row-stable batching** — each output row of a bucketed dispatch
     equals the row the unbatched ``StableHLOPredictor.predict`` produces
-    (row-independent inference math; ``tools/check_serving.py`` proves it
+    to f32 rounding (row-independent inference math; bit for bit only
+    when the bucket is the request's own shape — XLA promises no two
+    program shapes the same bits; ``tools/check_serving.py`` holds it
     under concurrent ragged traffic, ``tools/check_serving_chaos.py``
     under injected faults).
   * **Zero steady-state compiles** — every ``(model, bucket)`` program is
     compiled eagerly at :meth:`Server.start`; ragged request sizes never
-    reach the compiler.  ``serving.compile_cache_dir`` wires jax's
-    persistent compilation cache so a RESTARTED server skips even those
-    (near-zero cold start).
+    reach the compiler.  ``start()`` turns on jax's persistent
+    compilation cache (``runtime.configure_compile_cache``: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names, else one fixed path
+    in the checkout) so a RESTARTED server skips even those.
   * **Fail-fast under overload** — the pending queue is bounded
     (``serving.max_pending``): a submit past the bound raises a retryable
     :class:`ServerOverloadedError` instead of queuing until memory dies.
@@ -92,7 +95,6 @@ breaker transitions and crash-restart bitwise-deterministically in <5s.
 
 Knobs (config.py): ``serving.max_batch`` (MXNET_TPU_SERVING_MAX_BATCH),
 ``serving.max_queue_delay_ms`` (MXNET_TPU_SERVING_MAX_QUEUE_DELAY_MS),
-``serving.compile_cache_dir`` (MXNET_TPU_SERVING_COMPILE_CACHE_DIR),
 ``serving.max_pending`` (MXNET_TPU_SERVING_MAX_PENDING),
 ``serving.default_deadline_ms`` (MXNET_TPU_SERVING_DEFAULT_DEADLINE_MS),
 ``serving.breaker_threshold`` / ``serving.breaker_cooldown_ms``; the
@@ -115,6 +117,7 @@ import jax
 from . import config as _config
 from . import io as _io
 from . import obs as _obs
+from . import runtime as _runtime
 from . import telemetry as _telemetry
 
 __all__ = ["Server", "ServingError", "ServerOverloadedError",
@@ -340,37 +343,6 @@ class _ModelEntry:
     @property
     def capacity(self):
         return self.buckets[-1]
-
-
-_CACHE_DIR_APPLIED = [None]
-
-
-def _configure_compile_cache():
-    """Wire jax's persistent compilation cache from the
-    ``serving.compile_cache_dir`` knob (idempotent).  With the cache dir
-    set, a restarted server's eager ``start()`` compiles hit disk instead
-    of XLA — the near-zero cold-start contract."""
-    cache_dir = (_config.get("serving.compile_cache_dir") or "").strip()
-    if not cache_dir:
-        return False
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # serving programs are small and fast-compiling on CPU; without these
-    # floors the cache would skip exactly the programs we want to persist
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    if _CACHE_DIR_APPLIED[0] != cache_dir:
-        # jax initializes its cache object on the FIRST compile of the
-        # process; a dir set after that (the common case — params staged
-        # and models warmed before start()) is silently ignored until the
-        # cache is re-initialized
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — older jax: dir applies lazily
-            pass
-        _CACHE_DIR_APPLIED[0] = cache_dir
-    return True
 
 
 class Server:
@@ -688,8 +660,8 @@ class Server:
     # --------------------------------------------------------- lifecycle
     def start(self):
         """Compile every registered ``(model, bucket)`` program eagerly
-        (restart-warm via the persistent compile cache when
-        ``serving.compile_cache_dir`` is set) and start the supervised
+        (restart-warm via the persistent compile cache) and start the
+        supervised
         batcher thread.  Idempotent while running; restartable after
         ``stop`` — unless a previous batcher missed its join deadline and
         is STILL running, in which case this raises instead of racing two
@@ -706,7 +678,7 @@ class Server:
                     "second batcher over the same queue — wait for it to "
                     "exit (then start() again) or recreate the Server")
             self._leaked_thread = None
-        _configure_compile_cache()
+        _runtime.configure_compile_cache()
         with self._cond:
             entries = list(self._models.values())
             engines = list(self._generation.values())
@@ -983,8 +955,9 @@ class Server:
         """Enqueue one prompt on generation model ``name``; returns a
         Future resolving to the generated token ids (np.int32, EOS
         included when hit).  With ``temperature`` 0 (the default) that
-        is bitwise the eager ``greedy_decode`` stream regardless of
-        co-scheduled traffic; ``temperature`` > 0 samples with optional
+        is the eager ``greedy_decode`` stream regardless of co-scheduled
+        traffic (up to argmax flips between near-tied bf16 logits on the
+        chip); ``temperature`` > 0 samples with optional
         ``top_k`` / ``top_p`` truncation under a per-request ``seed``
         (sampling-enabled v5 artifacts only — fresh entropy when the
         seed is None, a fixed seed replays one deterministic stream).

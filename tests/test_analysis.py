@@ -322,7 +322,7 @@ def test_drift_metric_index_both_directions(tmp_path):
 # ----------------------------------------------------------- shard spec
 BAD_SHARD = """\
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     AXES = ("dp",)
@@ -353,7 +353,7 @@ def test_shard_clean_twin(tmp_path):
     # bound by an in_spec, and the embedding spec shards the vocab axis
     clean = """\
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     AXES = ("dp", "tp")

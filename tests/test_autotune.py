@@ -54,6 +54,87 @@ def _count(name):
     return telemetry.counter(name).value
 
 
+# ------------------------------------------- a broken kernel is an error
+class _MosaicSaysNo(RuntimeError):
+    pass
+
+
+def _refuse(*_a, **_k):
+    raise _MosaicSaysNo("block shape (6, 64) is not a legal TPU block")
+
+
+def _pick_attention():
+    return autotune.attention_pick((1, 2, 32, 16), (1, 2, 32, 16),
+                                   "float32", True)
+
+
+def _pick_paged():
+    return autotune.paged_pick((2, 2, 1, 8), (2, 2, 16, 8), "float32", False)
+
+
+def _pick_fused():
+    opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9)
+    opt.step_fused = _refuse
+    return autotune.fused_step_pick(opt)
+
+
+@pytest.mark.parametrize("in_trace", [False, True], ids=["eager", "traced"])
+@pytest.mark.parametrize("kernel, pick", [
+    ("flash_attention", _pick_attention),
+    ("pallas_paged_attention", _pick_paged),
+    (None, _pick_fused),
+], ids=["attention", "paged", "fused"])
+def test_candidate_that_cannot_compile_raises_and_is_never_recorded(
+        monkeypatch, kernel, pick, in_trace):
+    """On a compiled (non-interpreted) backend a cache miss searches; a
+    candidate the compiler refuses is an ERROR out of the pick — from
+    inside a jit trace too — never an "xla won" verdict: nothing lands
+    in memory or on disk, so the next run meets the same error."""
+    from mxnet_tpu.ops import pallas_kernels
+    monkeypatch.setattr(autotune, "_interpreted", lambda: False)
+    if kernel is not None:
+        monkeypatch.setattr(pallas_kernels, kernel, _refuse)
+    path = config.get("perf.autotune_cache")
+
+    def run():
+        if in_trace:
+            return jax.jit(lambda x: (pick(), x)[1])(jnp.zeros(()))
+        return pick()
+
+    for _ in range(2):   # the failure is not memoized as a verdict either
+        with pytest.raises(_MosaicSaysNo, match="not a legal TPU block"):
+            run()
+    assert not os.path.exists(path)
+    state = autotune.export_entries()
+    assert state["entries"] == {} and state["applied"] == {}
+    assert _count("autotune.search") == 0
+
+
+def test_search_from_inside_a_trace_measures_concrete_arrays():
+    """A trace-time pick that misses the cache runs its search outside
+    the ambient trace: it measures and reads back real arrays (under the
+    old catch-all, the tracer error here became a silent "xla" verdict
+    for every site a jitted model routed)."""
+    config.set("perf.autotune", "measure")
+    q, k, v = _qkv()
+    out = jax.jit(lambda q, k, v: kernels.attention(q, k, v, causal=True))(
+        q, k, v)
+    assert out.shape == q.shape
+    assert _count("autotune.search") == 1 and _count("autotune.measure") > 1
+    (entry,) = autotune.export_entries()["entries"].values()
+    assert "reason" not in entry or "failed" not in entry["reason"], entry
+    assert entry["candidates"], entry
+
+
+def test_default_cache_file_lives_in_the_checkout(monkeypatch):
+    """No state from outside the tree decides a route: the default path
+    is <checkout>/.jax_cache/autotune.json, not ~/.mxnet."""
+    config.unset("perf.autotune_cache")
+    monkeypatch.setenv("HOME", "/nonexistent-home")
+    assert autotune.cache_path() == os.path.join(ROOT, ".jax_cache",
+                                                 "autotune.json")
+
+
 # --------------------------------------------------- config primitives
 def test_config_source_tracks_override_env_default(monkeypatch):
     monkeypatch.delenv("MXNET_TPU_REMAT", raising=False)

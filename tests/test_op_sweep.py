@@ -1001,14 +1001,30 @@ def test_op_forward_and_grad(name):
         check_numeric_gradient(f, arrays)
 
 
-def test_bench_watchdog_default_matches_knob():
-    """bench.py reads MXTPU_BENCH_TIMEOUT directly (importing the package
-    there would touch jax before the probe watchdog exists); this pins its
-    hand-written default to the documented bench.timeout_s knob."""
-    import re
+def test_bench_phase_error_fails_the_run(monkeypatch):
+    """bench.py has no watchdog and no record-and-carry-on: a phase that
+    raises fails the run, and no result line is printed.  Without the
+    chip it fails before the first phase unless the cpu is asked for."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    import bench
     import mxnet_tpu.config as cfg
-    src = open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")).read()
-    m = re.search(r'MXTPU_BENCH_TIMEOUT",\s*"([\d.]+)"', src)
-    assert m, "bench.py watchdog default not found"
-    assert float(m.group(1)) == cfg.knobs()["bench.timeout_s"].default
+    assert "bench.timeout_s" not in cfg.knobs()
+    ran = []
+
+    def boom(runs_out, arg):
+        ran.append(arg)
+        raise FloatingPointError("phase blew up")
+
+    monkeypatch.setattr(bench, "_lint_preflight", lambda: None)
+    monkeypatch.setattr(bench, "PHASES", ((boom, ("chip",), ("cpu",)),
+                                          (boom, ("never",), ("never",))))
+    monkeypatch.delenv("MXTPU_BENCH_CPU", raising=False)
+    with pytest.raises(RuntimeError, match="needs an accelerator"):
+        bench.main()
+    assert ran == []
+    monkeypatch.setenv("MXTPU_BENCH_CPU", "1")
+    with pytest.raises(FloatingPointError, match="phase blew up"):
+        bench.main()
+    assert ran == ["cpu"]

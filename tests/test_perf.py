@@ -47,7 +47,14 @@ def _mlp_args(b=8, i=16, h=32, o=4, seed=0):
 
 
 # ---------------------------------------------------------------- registry
-def test_register_compiled_record_schema():
+@pytest.fixture
+def as_v5e(monkeypatch):
+    """Steer the peak lookup from the test: the arithmetic of MFU and
+    roofline is checked against the v5e row on this CPU mesh."""
+    monkeypatch.setattr(perf, "_KIND_CACHE", ["TPU v5 lite"])
+
+
+def test_register_compiled_record_schema(as_v5e):
     fn = _mlp_fn()
     args = _mlp_args()
     compiled = fn.trace(*args).lower().compile()
@@ -116,7 +123,7 @@ def test_roofline_classification():
     assert hi["device_intensity"] == lo["device_intensity"] > 0
     # zero bytes: intensity unknowable, classified compute (no evidence
     # of a bandwidth ceiling)
-    z = perf.roofline(1e9, 0)
+    z = perf.roofline(1e9, 0, kind="TPU v5 lite")
     assert z["arithmetic_intensity"] is None and z["bound"] == "compute"
 
 
@@ -124,18 +131,55 @@ def test_peak_tables_dtype_aware():
     assert perf.peak_flops("TPU v5 lite", "bfloat16") == 197.0e12
     assert perf.peak_flops("TPU v5 lite", "float32") == 197.0e12 * 0.5
     assert perf.peak_flops("TPU v5 lite", "int8") == 197.0e12 * 2.0
-    assert perf.peak_flops("no-such-device") == perf.DEFAULT_PEAK * 1e12
+    assert perf.peak_bandwidth("TPU v5 lite") == 819.0e9
     assert perf.peak_bandwidth("TPU v4") == 1228.0e9
 
 
-def test_bench_peak_tables_stay_in_sync():
-    """bench.py keeps module-level copies (it must not import mxnet_tpu
-    before its backend probe) — the same sync contract test_op_sweep.py
-    enforces for WATCHDOG_S."""
+@pytest.mark.parametrize("call", [
+    lambda: perf.peak_flops("no-such-device"),
+    lambda: perf.peak_bandwidth("no-such-device"),
+    lambda: perf.roofline(1e9, 1e9, kind="no-such-device"),
+    lambda: perf.peak_flops(),       # the CPU test mesh is not in the table
+    lambda: perf.roofline(1e9, 1e9),
+], ids=["flops", "bandwidth", "roofline", "local-flops", "local-roofline"])
+def test_unknown_device_kind_has_no_peak(call):
+    """The single peak table raises on a device it does not list: an MFU
+    or a roofline share is never computed against another chip's peak."""
+    assert not perf.known_device("no-such-device")
+    assert not perf.known_device()
+    with pytest.raises(perf.UnknownDeviceError, match="no published peaks"):
+        call()
+
+
+def test_unknown_device_records_counts_but_no_peak_numbers():
+    """A program compiled on a device without published peaks (this CPU
+    mesh) keeps its counts; what needs a peak is None, not a v5e number."""
+    fn = _mlp_fn()
+    args = _mlp_args()
+    rec = perf.register_compiled("module", "nopeak",
+                                 fn.trace(*args).lower().compile())
+    assert rec["flops"] > 0 and rec["bytes_accessed"] > 0
+    assert rec["roofline"] is None and rec["peak_tflops"] is None
+    telemetry.reset()
+    w = perf.wrap(_mlp_fn(), "module", "nopeak2", source="module")
+    w(*args)
+    fields = perf._on_step("module", 1, 0.01)
+    assert fields == {"flops": pytest.approx(
+        perf.program("module", "nopeak2")["flops"])}
+    assert "perf.mfu" not in telemetry.snapshot()["gauges"]
+
+
+def test_bench_keeps_no_second_peak_table():
+    """bench.py reads mx.perf.DEVICE_PEAKS; a private copy (the old
+    PEAK_BF16_TFLOPS / DEFAULT_PEAK pair) could drift from it, and a
+    default peak would put a v5e number on an unknown chip."""
     sys.path.insert(0, ROOT)
     import bench
-    assert bench.PEAK_BF16_TFLOPS == perf.PEAK_BF16_TFLOPS
-    assert bench.DEFAULT_PEAK == perf.DEFAULT_PEAK
+    for name in ("PEAK_BF16_TFLOPS", "DEFAULT_PEAK", "PEAK_HBM_GBPS"):
+        assert not hasattr(bench, name), name
+    text = open(os.path.join(ROOT, "bench.py")).read()
+    assert "197" not in text and "DEVICE_PEAKS = " not in text
+    assert "peak_flops(" in text
 
 
 # ------------------------------------------------------------ op classes
@@ -218,7 +262,7 @@ def test_wrap_tracer_check_falls_through():
     assert perf.program("gluon", "tr")["calls"] == calls_before
 
 
-def test_step_hook_accounts_and_clears():
+def test_step_hook_accounts_and_clears(as_v5e):
     telemetry.reset()
     w = perf.wrap(_mlp_fn(), "module", "hook", source="module")
     args = _mlp_args()

@@ -1,4 +1,4 @@
-"""mx.serving continuous batching: bitwise batched-vs-unbatched outputs,
+"""mx.serving continuous batching: batched rows equal to unbatched predict,
 bucket-bounded compiles, batching policy (coalescing window, cap-filled
 immediate dispatch), graceful drain, LRU model table, fixed-batch
 artifacts, oversized-request chunking, telemetry-report serving table +
@@ -49,7 +49,10 @@ def _reqs(sizes, seed=0):
             for s in sizes]
 
 
-def test_concurrent_ragged_bitwise_and_flat_compiles(artifact):
+def test_concurrent_ragged_rows_and_flat_compiles(artifact):
+    """Rows served from padded buckets equal the unbatched predict to f32
+    rounding — a bucket is another program shape than the request's own,
+    and XLA promises no two shapes the same bits."""
     pred = deploy.StableHLOPredictor(artifact)
     srv = serving.Server(max_batch=8, max_queue_delay_ms=3.0)
     srv.register("m", artifact)
@@ -76,7 +79,7 @@ def test_concurrent_ragged_bitwise_and_flat_compiles(artifact):
             t.join()
         for rs, es in zip(got, expect):
             for r, e in zip(rs, es):
-                assert np.array_equal(r, e)
+                np.testing.assert_allclose(r, e, rtol=1e-5, atol=1e-7)
         # ragged traffic never reached the compiler
         assert telemetry.counter("serving.compiles").value - c0 == \
             len(buckets)
@@ -200,13 +203,35 @@ def test_submit_validates_shape_and_dtype(artifact):
         srv.stop()
 
 
-def test_compile_cache_dir_persists_bucket_programs(artifact, tmp_path):
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_is_placed_from_outside(artifact, tmp_path,
+                                              monkeypatch, from_env):
+    """Server.start() turns on jax's persistent compile cache: in the
+    directory JAX_COMPILATION_CACHE_DIR names (no directory set in code),
+    else at the one fixed path inside the checkout — never a mkdtemp."""
     import glob
     import jax
-    from mxnet_tpu import config
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from mxnet_tpu import runtime
     cache = str(tmp_path / "xla_cache")
-    os.makedirs(cache)
-    config.set("serving.compile_cache_dir", cache)
+    fixed = str(tmp_path / "checkout" / ".jax_cache")
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    if from_env:
+        # jax reads the variable when it is imported; a process started
+        # with it set sees exactly this config value
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+        jax.config.update("jax_compilation_cache_dir", cache)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(runtime, "cache_root", lambda: fixed)
+    # the test's bucket programs are tiny: lift jax's floors so they
+    # are written at all (the floors are jax's own, not this repo's)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    monkeypatch.setattr(runtime, "_CACHE_CONFIGURED", [None])
     try:
         srv = serving.Server(max_batch=4, max_queue_delay_ms=1.0)
         srv.register("m", artifact)
@@ -216,15 +241,25 @@ def test_compile_cache_dir_persists_bucket_programs(artifact, tmp_path):
                         timeout=30)
         finally:
             srv.stop()
+        want = cache if from_env else fixed
+        assert jax.config.jax_compilation_cache_dir == want
+        assert runtime.configure_compile_cache() == want
         # one persisted XLA binary per bucket program (1, 2, 4)
-        assert len(glob.glob(os.path.join(cache, "*-cache"))) >= 3
+        assert len(glob.glob(os.path.join(want, "*-cache"))) >= 3
+        other = fixed if from_env else cache
+        assert not os.path.exists(other)
     finally:
-        config.set("serving.compile_cache_dir", "")
-        jax.config.update("jax_compilation_cache_dir", None)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
+        for k, v in saved.items():
+            jax.config.update(k, v)
         cc.reset_cache()
-        serving._CACHE_DIR_APPLIED[0] = None
+
+
+def test_default_cache_root_is_fixed_and_inside_the_checkout():
+    from mxnet_tpu import runtime
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert runtime.cache_root() == os.path.join(root, ".jax_cache")
+    ignored = open(os.path.join(root, ".gitignore")).read().split()
+    assert ".jax_cache/" in ignored
 
 
 def test_register_rejects_paramless_artifact(artifact, tmp_path):
@@ -286,7 +321,7 @@ def test_check_serving_smoke():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     report = json.loads(proc.stdout.strip().splitlines()[-1])
     assert report["ok"], report
-    assert report["bitwise"]["mismatches"] == 0
+    assert report["rows"]["mismatches"] == 0
     assert report["compiles"]["compiled"] == \
         len(report["compiles"]["buckets"])
     assert report["drain"]["drained"] == report["drain"]["queued"]

@@ -88,7 +88,7 @@ def setup():
 
 BAD_SHARD = '''\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 AXES = ("dp",)

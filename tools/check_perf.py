@@ -78,6 +78,11 @@ def main():
         import perf_report
         import telemetry_report
         result["backend"] = jax.default_backend()
+        # this smoke checks the ARITHMETIC of the MFU path (flops / (wall
+        # x peak) through the step log, the gauges and both reports) on
+        # the cpu backend, which has no published peak and so no MFU of
+        # its own: name the table row to do the arithmetic with
+        perf._KIND_CACHE[0] = result["peaks_of"] = "TPU v5 lite"
 
         config.set("module.fused_step", "auto")
         config.set("telemetry.sink", "jsonl:" + log_path)

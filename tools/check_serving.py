@@ -3,11 +3,12 @@
 Proves the serving layer end-to-end on the host backend, with one
 parseable JSON line on stdout:
 
-  1. bitwise — N caller threads submit ragged mixed-size requests
-               concurrently; every scattered output row is BITWISE equal
-               to the row the unbatched ``StableHLOPredictor.predict``
-               produces (bucketed pad-batch-scatter never touches the
-               numerics);
+  1. rows    — N caller threads submit ragged mixed-size requests
+               concurrently; every scattered output row equals the row
+               the unbatched ``StableHLOPredictor.predict`` produces to
+               f32 rounding (ROW_RTOL).  Not bitwise: a padded bucket is
+               another program shape than the request's own, and XLA
+               promises no two shapes the same bits;
   2. compiles — ``serving.compiles`` after ``start()`` equals the bucket
                count, and stays FLAT across the ragged traffic (no
                request shape ever reaches the compiler);
@@ -40,6 +41,9 @@ BUDGET_S = 5.0 if (os.cpu_count() or 1) >= 2 else 10.0
 FEATURES = 6
 N_THREADS = 4
 SIZES = (1, 3, 2, 5, 4, 8, 7, 1)   # per-thread ragged request mix
+# a row served from a padded bucket vs the same row predicted alone: two
+# program shapes, equal to a few f32 ulps of the dot's partial sums
+ROW_RTOL, ROW_ATOL = 1e-5, 1e-7
 
 
 def main():
@@ -76,7 +80,7 @@ def main():
             "start() compiled %d programs for %d buckets" % (compiled,
                                                              len(buckets))
 
-        # 1+2: concurrent ragged traffic — bitwise outputs, flat compiles
+        # 1+2: concurrent ragged traffic — same rows, flat compiles
         rng = np.random.RandomState(0)
         inputs = [[rng.uniform(size=(s, FEATURES)).astype(np.float32)
                    for s in SIZES] for _ in range(N_THREADS)]
@@ -99,7 +103,7 @@ def main():
             t.join()
         assert not errors, "submit worker failed: %s" % errors[0]
         mismatch = sum(
-            0 if np.array_equal(r, e) else 1
+            0 if np.allclose(r, e, rtol=ROW_RTOL, atol=ROW_ATOL) else 1
             for rs, es in zip(results, expect) for r, e in zip(rs, es))
         assert mismatch == 0, \
             "%d request outputs diverged from unbatched predict" % mismatch
@@ -108,9 +112,9 @@ def main():
         assert traffic_compiles == len(buckets), \
             "ragged traffic caused %d extra compile(s)" \
             % (traffic_compiles - len(buckets))
-        result["bitwise"] = {"threads": N_THREADS,
-                             "requests": N_THREADS * len(SIZES),
-                             "mismatches": mismatch}
+        result["rows"] = {"threads": N_THREADS,
+                          "requests": N_THREADS * len(SIZES),
+                          "mismatches": mismatch}
         result["compiles"] = {"buckets": list(buckets),
                               "compiled": traffic_compiles,
                               "dispatches": telemetry.counter(
@@ -120,7 +124,8 @@ def main():
         big = rng.uniform(size=(MAX_BATCH * 2 + 3,
                                 FEATURES)).astype(np.float32)
         out = srv.predict("mlp", big, timeout=30)
-        assert np.array_equal(out, pred.predict(big)), \
+        assert np.allclose(out, pred.predict(big), rtol=ROW_RTOL,
+                           atol=ROW_ATOL), \
             "chunked oversized request diverged"
         result["chunking"] = {"rows": int(big.shape[0])}
 

@@ -182,8 +182,13 @@ def main():
         assert shed == 3, "expected 3 shed submits, got %d" % shed
         time.sleep(0.002)  # let the 1ms deadline lapse, batcher still slow
         assert np.array_equal(f_slow.result(timeout=10), expect[9])
-        assert np.array_equal(f_q1.result(timeout=10), expect[10])
-        assert np.array_equal(f_q2.result(timeout=10), expect[11])
+        # q1 and q2 waited out the slow dispatch together and share one
+        # 2-row bucket: another program shape than the 1-row oracle, so
+        # they agree to f32 rounding, not bit for bit
+        assert np.allclose(f_q1.result(timeout=10), expect[10],
+                           rtol=1e-5, atol=1e-7)
+        assert np.allclose(f_q2.result(timeout=10), expect[11],
+                           rtol=1e-5, atol=1e-7)
         exc = f_dl.exception(timeout=10)
         assert isinstance(exc, DeadlineExceededError), \
             "deadline request got %r" % (exc,)
