@@ -277,8 +277,9 @@ register_knob(
     "(step/fwd/bwd/opt-update/prefetch/push/pull/allreduce, with "
     "contextvars-propagated parent/child links that survive thread hops) "
     "as Chrome trace-event JSON; merge with a jax.profiler device capture "
-    "via tools/trace_merge.py. Empty (default) disables — span() is a "
-    "shared no-op when no sink/watchdog/device trace is active.")
+    "via tools/trace_merge.py. Empty (default) disables — span() is then "
+    "only the jax.profiler.TraceAnnotation (no ids, no registry, no lock): "
+    "any profiler session still finds the spans.")
 register_knob(
     "tracing.watchdog", "MXNET_TPU_WATCHDOG", float, 0.0,
     "hang-watchdog deadline in seconds: > 0 starts a daemon thread that, "
@@ -336,8 +337,10 @@ register_knob(
 register_knob(
     "obs.access_log", "MXNET_TPU_OBS_ACCESS_LOG", str, "",
     "per-request access log sink: 'jsonl:<path>' appends one JSON record "
-    "per serving/generation request (request_id = the span trace_id, "
-    "model, queue_ms, dispatch_ms, ttft_ms, tokens, bytes, outcome "
+    "per serving/generation request (request_id = the generation "
+    "engine's own request number, or a one-shot request's span trace_id; "
+    "trace_id where a causal span enclosed a generation submit; model, "
+    "queue_ms, dispatch_ms, ttft_ms, tokens, bytes, outcome "
     "ok|shed|deadline|breaker|error) that joins against the tracing.sink "
     "Chrome trace on trace_id. Empty (default) disables — the serving hot "
     "path gains one predicate per request.")

@@ -95,6 +95,7 @@ Knobs (config.py): ``serving.kv_page_size`` (baked at export),
 """
 from __future__ import annotations
 
+import itertools as _itertools
 import logging
 import math as _math
 import threading
@@ -110,6 +111,7 @@ from . import config as _config
 from . import io as _io
 from . import obs as _obs
 from . import telemetry as _telemetry
+from . import tracing as _tracing
 from .serving import (CircuitOpenError, DeadlineExceededError,
                       ServerOverloadedError, ServingError,
                       _access_outcome)
@@ -129,29 +131,41 @@ class _EngineCrashError(OSError):
     ``resilience.call_with_retry`` drives the restart backoff."""
 
 
+def _access_ids(request_id, trace_id):
+    """``log_access`` identity: the request id, and the trace id as an
+    extra field where a causal span enclosed the submit."""
+    if trace_id is None:
+        return {"request_id": str(request_id)}
+    return {"request_id": str(request_id), "trace_id": str(trace_id)}
+
+
 class _GenRequest:
     """One generation request: prompt + budget + the future its token
     stream resolves, stamped for TTFT / deadline accounting."""
 
     __slots__ = ("prompt", "plen", "max_new", "eos_id", "future",
                  "t_submit", "deadline", "need", "stall_counted",
-                 "trace_id", "temperature", "top_k", "top_p", "key_words",
-                 "prefix_keys")
+                 "request_id", "trace_id", "temperature", "top_k", "top_p",
+                 "key_words", "prefix_keys")
 
     def __init__(self, prompt, max_new, eos_id, deadline_ms, need,
                  trace_id=None, temperature=0.0, top_k=0, top_p=1.0,
-                 seed=0, prefix_keys=()):
+                 seed=0, prefix_keys=(), request_id=None):
         self.prompt = prompt
         self.plen = int(prompt.shape[0])
         self.max_new = int(max_new)
         self.eos_id = eos_id
         self.future = Future()
+        # the engine's own number for the request: on the future, in the
+        # access log and in the serving_generate event, tracing on or off
+        self.request_id = request_id
+        self.future.request_id = request_id
         self.t_submit = _time.perf_counter()
         self.deadline = (self.t_submit + float(deadline_ms) * 1e-3) \
             if deadline_ms and deadline_ms > 0 else None
         self.need = int(need)          # pages for prompt + max_new
         self.stall_counted = False     # kv_pool_exhausted counted once
-        self.trace_id = trace_id       # submit span id for the access log
+        self.trace_id = trace_id       # enclosing span's trace, if any
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.top_p = float(top_p)
@@ -167,6 +181,9 @@ class _GenRequest:
     def expired(self, now):
         return self.deadline is not None and now >= self.deadline
 
+    def access_ids(self):
+        return _access_ids(self.request_id, self.trace_id)
+
 
 class _Slot:
     """One active decode slot: the sequence's pages, cached length and
@@ -175,15 +192,20 @@ class _Slot:
     released through ``_release_pages_locked``, never freed directly)."""
 
     __slots__ = ("req", "pages", "pos", "tokens", "ttft_ms",
-                 "prefix_keys")
+                 "prefix_keys", "t_admit", "t_prefill_start", "token_t")
 
-    def __init__(self, req, pages, prefix_keys=()):
+    def __init__(self, req, pages, t_admit, prefix_keys=()):
         self.req = req
         self.pages = pages
         self.pos = req.plen      # tokens already in the cache
         self.tokens = []
         self.ttft_ms = None
         self.prefix_keys = tuple(prefix_keys)
+        # host-clock stamps (perf_counter): admission, the prefill call,
+        # and one per emitted token — token_t[0] is the first token
+        self.t_admit = t_admit
+        self.t_prefill_start = None
+        self.token_t = []
 
 
 class GenerationEngine:
@@ -245,6 +267,10 @@ class GenerationEngine:
         self._kv = None       # page-pool pytree (2 arrays, 4 when int8)
         self._prefill = {}    # prompt bucket -> compiled program
         self._decode = {}     # page-table width -> compiled program
+        self._iteration = 0   # engine.iteration spans, numbered from 1
+        # request ids, from 1 (next() of a count is atomic: submit runs on
+        # any thread)
+        self._request_ids = _itertools.count(1)
 
     # ----------------------------------------------------------- compile
     def _compile_programs(self):
@@ -254,7 +280,6 @@ class GenerationEngine:
         generation traffic never adds to it (``serving.compiles`` stays
         equal to the family size, the check_generation.py gate)."""
         from . import perf as _perf
-        from . import tracing as _tracing
         gp = self.predictor
         params = gp._params
         pspec = jax.tree_util.tree_map(
@@ -317,7 +342,6 @@ class GenerationEngine:
 
     # --------------------------------------------------------- lifecycle
     def start(self):
-        from . import tracing as _tracing
         with self._cond:
             if self._started:
                 return self
@@ -357,7 +381,6 @@ class GenerationEngine:
                 _telemetry.counter("serving.stop_timeout").inc()
                 _LOG.warning("serving: generation engine %r did not "
                              "drain within %.1fs", self.name, timeout_s)
-        from . import tracing as _tracing
         _tracing.unregister_stall_probe(self._probe_name)
         with self._cond:
             self._started = False
@@ -403,15 +426,17 @@ class GenerationEngine:
                 "%d (serving.kv_pages) — shorten the request or grow the "
                 "pool" % (self.name, need, self.num_pages))
         _telemetry.counter("serving.requests").inc()
-        # the enclosing serving.submit span's trace_id (None when tracing
-        # is off) rides the request so its access record joins the trace
-        from . import tracing as _tracing
+        # the enclosing serving.submit span's trace_id (None unless a
+        # causal span is open) rides the request as an extra field, so its
+        # access record joins the trace
         sp = _tracing.current_span()
         trace_id = sp.trace_id if sp is not None else None
+        request_id = next(self._request_ids)
+        ids = _access_ids(request_id, trace_id)
         breaker = self.breaker
         if breaker is not None and breaker.rejects_submit():
             _telemetry.counter("serving.breaker_rejected").inc()
-            _obs.log_access(self.name, "breaker", request_id=trace_id)
+            _obs.log_access(self.name, "breaker", **ids)
             raise CircuitOpenError(
                 "model %r circuit breaker is OPEN after %d consecutive "
                 "dispatch failure(s); failing fast for %.0fms more"
@@ -431,6 +456,7 @@ class GenerationEngine:
                 for i in range(plen // psz))
         req = _GenRequest(prompt, max_new, eos_id,
                           float(deadline_ms or 0.0), need,
+                          request_id=request_id,
                           trace_id=trace_id, temperature=temperature,
                           top_k=top_k, top_p=top_p, seed=seed,
                           prefix_keys=prefix_keys)
@@ -457,7 +483,7 @@ class GenerationEngine:
             _telemetry.counter("serving.shed_requests").inc()
             _telemetry.counter(
                 "serving.shed_requests.%s" % self.name).inc()
-            _obs.log_access(self.name, "shed", request_id=trace_id)
+            _obs.log_access(self.name, "shed", **ids)
             raise ServerOverloadedError(
                 "generation queue for model %r is at serving.max_pending"
                 "=%d; request shed — back off and retry"
@@ -513,9 +539,9 @@ class GenerationEngine:
                 req.future.set_exception(exc)
                 if _obs.access_log_enabled():
                     _obs.log_access(
-                        self.name, outcome, request_id=req.trace_id,
+                        self.name, outcome,
                         queue_ms=(_time.perf_counter() - req.t_submit)
-                        * 1e3, error=err)
+                        * 1e3, error=err, **req.access_ids())
 
     def _fail_active(self, exc):
         """Fail every in-flight sequence and recycle its pages (the pool
@@ -534,10 +560,9 @@ class GenerationEngine:
                 slot.req.future.set_exception(exc)
                 if _obs.access_log_enabled():
                     _obs.log_access(
-                        self.name, outcome,
-                        request_id=slot.req.trace_id,
-                        ttft_ms=slot.ttft_ms,
-                        tokens=len(slot.tokens), error=err)
+                        self.name, outcome, ttft_ms=slot.ttft_ms,
+                        tokens=len(slot.tokens), error=err,
+                        **slot.req.access_ids())
         with self._cond:
             for slot in released:
                 self._release_pages_locked(slot)
@@ -586,6 +611,9 @@ class GenerationEngine:
         ``serving.kv_pool_exhausted`` per stall episode."""
         admitted = []
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
+        # stamped under the lock: a request submitted after ``now`` was
+        # read is still admitted no earlier than it was submitted
+        t_admit = _time.perf_counter()
         while self._queue and free_slots:
             req = self._queue[0]
             # walk the request's full-prefix pages front-to-back: each
@@ -626,58 +654,77 @@ class GenerationEngine:
                 _telemetry.counter(
                     "serving.prefix_pages_shared").inc(len(shared))
             self._slots[free_slots.pop(0)] = _Slot(
-                req, pages, prefix_keys=req.prefix_keys)
+                req, pages, t_admit, prefix_keys=req.prefix_keys)
             admitted.append(req)
         return admitted
 
     def _loop(self):
         while True:
-            now = _time.perf_counter()
             with self._cond:
-                self._last_iteration = now
+                self._last_iteration = _time.perf_counter()
+                if not self._queue and not self._active():
+                    # nothing to harvest, admit or decode
+                    if self._stopping:
+                        return
+                    with _tracing.span("engine.wait", cat="serving"):
+                        self._cond.wait(timeout=0.05)
+                    continue
+            self._iteration += 1
+            with _tracing.span("engine.iteration", cat="serving",
+                               iteration=self._iteration):
+                if not self._iterate():
+                    return
+
+    def _iterate(self):
+        """One turn of the loop with work in sight: harvest, admit,
+        prefill the admitted, one decode step.  False = the engine is
+        done (stopped and drained, or aborted)."""
+        now = _time.perf_counter()
+        with self._cond:
+            with _tracing.span("engine.admit", cat="serving") as sp:
                 expired = self._harvest_expired_locked(now)
                 admitted = self._admit_locked(now)
-                active = self._active()
-                if not admitted and not active:
-                    if self._stopping and (self._abort
-                                           or not self._queue):
-                        queued = list(self._queue)
-                        self._queue.clear()
-                        abort = self._abort
-                    else:
-                        self._cond.wait(timeout=0.05)
-                        queued = None
-                        abort = False
-                else:
-                    queued = None
-                    abort = False
-            self._expire(expired)
-            if queued is not None:
-                if abort:
-                    self._fail_all(queued, ServingError(
-                        "generation engine stopped without drain"))
-                return
+                sp.set(admitted=len(admitted), queued=len(self._queue),
+                       free_pages=len(self._free))
+            active = self._active()
+            queued = None
+            abort = False
             if not admitted and not active:
-                continue
-            with self._cond:
-                abort = self._abort
-            if abort:
-                with self._cond:
+                # every queued request expired, or the head waits for
+                # pages that only a shared-prefix holder can return
+                if self._stopping and (self._abort or not self._queue):
                     queued = list(self._queue)
                     self._queue.clear()
-                exc = ServingError(
-                    "generation engine stopped without drain")
-                self._fail_all(queued, exc)
-                self._fail_active(exc)
-                return
-            self._gauge_pages()
-            ok = True
-            for req in admitted:
-                if not self._dispatch_prefill(req):
-                    ok = False
-                    break
-            if ok and self._active():
-                self._dispatch_decode()
+                    abort = self._abort
+                else:
+                    with _tracing.span("engine.wait", cat="serving"):
+                        self._cond.wait(timeout=0.05)
+        self._expire(expired)
+        if queued is not None:
+            if abort:
+                self._fail_all(queued, ServingError(
+                    "generation engine stopped without drain"))
+            return False
+        if not admitted and not active:
+            return True
+        with self._cond:
+            abort = self._abort
+        if abort:
+            with self._cond:
+                queued = list(self._queue)
+                self._queue.clear()
+            exc = ServingError(
+                "generation engine stopped without drain")
+            self._fail_all(queued, exc)
+            self._fail_active(exc)
+            return False
+        self._gauge_pages()
+        for req in admitted:
+            if not self._dispatch_prefill(req):
+                return True
+        if self._active():
+            self._dispatch_decode()
+        return True
 
     def _expire(self, reqs):
         for req in reqs:
@@ -690,9 +737,8 @@ class GenerationEngine:
                     "generation request for model %r expired in queue "
                     "before prefill (queued %.1fms, deadline passed)"
                     % (self.name, queued_ms)))
-                _obs.log_access(self.name, "deadline",
-                                request_id=req.trace_id,
-                                queue_ms=queued_ms)
+                _obs.log_access(self.name, "deadline", queue_ms=queued_ms,
+                                **req.access_ids())
 
     def _dispatch_failed(self, exc):
         """Shared failure path: the donated pool is poisoned, so every
@@ -710,9 +756,19 @@ class GenerationEngine:
         request's pages, so in-flight sequences are untouched — the
         mid-flight JOIN) and produces the first token (TTFT)."""
         gp = self.predictor
+        s_bucket = gp.prefill_bucket(req.plen)
+        with _tracing.span("engine.prefill", cat="serving",
+                           request_id=req.request_id, bucket=s_bucket,
+                           prompt_len=req.plen):
+            return self._prefill_one(req, s_bucket)
+
+    def _prefill_one(self, req, s_bucket):
+        gp = self.predictor
         slot_idx = next(i for i, s in enumerate(self._slots)
                         if s is not None and s.req is req)
         slot = self._slots[slot_idx]
+        _telemetry.timer("serving.queue_ms").observe(
+            (slot.t_admit - req.t_submit) * 1e3)
         breaker = self.breaker
         if breaker is not None and not breaker.allow_dispatch():
             self._slots[slot_idx] = None
@@ -723,10 +779,10 @@ class GenerationEngine:
                     "model %r circuit breaker is OPEN; prefill failed "
                     "fast, retry after the cooldown" % (self.name,)))
                 _obs.log_access(
-                    self.name, "breaker", request_id=req.trace_id,
-                    queue_ms=(_time.perf_counter() - req.t_submit) * 1e3)
+                    self.name, "breaker",
+                    queue_ms=(_time.perf_counter() - req.t_submit) * 1e3,
+                    **req.access_ids())
             return True   # engine itself is fine
-        s_bucket = gp.prefill_bucket(req.plen)
         w_s = _math.ceil(s_bucket / gp.page_size)
         sentinel = self.num_pages
         tokens = _np.zeros((1, s_bucket), _np.int32)
@@ -753,25 +809,29 @@ class GenerationEngine:
                     if done and i < w_s:
                         write_table[0, i] = sentinel
         temp, tk, tp, keys = self._sample_arrays([(0, slot)], 1)
-        t0 = _time.perf_counter()
-        try:
-            self._kv, nxt = self._prefill[s_bucket](
-                gp._params, self._kv, tokens,
-                _np.asarray([req.plen], _np.int32), write_table,
-                temp, tk, tp, keys)
-            first = int(nxt[0])
-        except BaseException as exc:  # noqa: BLE001 — pool donated away
-            return self._dispatch_failed(exc)
-        if slot.prefix_keys:
-            with self._cond:
-                for key in slot.prefix_keys:
-                    entry = self._prefix.get(key)
-                    if entry is not None:
-                        entry[2] = True
-        t1 = _time.perf_counter()
+        # the span has the bounds of the serving.prefill_ms timer
+        with _tracing.span("engine.prefill.device", cat="serving"):
+            slot.t_prefill_start = t0 = _time.perf_counter()
+            try:
+                self._kv, nxt = self._prefill[s_bucket](
+                    gp._params, self._kv, tokens,
+                    _np.asarray([req.plen], _np.int32), write_table,
+                    temp, tk, tp, keys)
+                with _tracing.span("engine.prefill.fetch", cat="serving"):
+                    first = int(nxt[0])
+            except BaseException as exc:  # noqa: BLE001 — pool donated
+                return self._dispatch_failed(exc)
+            if slot.prefix_keys:
+                with self._cond:
+                    for key in slot.prefix_keys:
+                        entry = self._prefix.get(key)
+                        if entry is not None:
+                            entry[2] = True
+            t1 = _time.perf_counter()
         if breaker is not None:
             breaker.record_success()
         slot.tokens.append(first)
+        slot.token_t.append(t1)
         slot.ttft_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.prefill_ms").observe((t1 - t0) * 1e3)
         _telemetry.timer("serving.ttft_ms").observe(slot.ttft_ms)
@@ -785,10 +845,18 @@ class GenerationEngine:
         slots ride along on the all-sentinel row (writes drop, output
         ignored) — that is what keeps the compiled set flat while
         sequences EXIT and JOIN mid-flight."""
-        gp = self.predictor
-        B = self.decode_slots
         active = [(i, s) for i, s in enumerate(self._slots)
                   if s is not None]
+        width = _io.pick_bucket(
+            self.predictor.decode_widths,
+            max(len(s.pages) for _, s in active))
+        with _tracing.span("engine.decode", cat="serving", width=width,
+                           rows=len(active)) as sp:
+            self._decode_once(active, width, sp)
+
+    def _decode_once(self, active, width, sp):
+        gp = self.predictor
+        B = self.decode_slots
         breaker = self.breaker
         if breaker is not None and not breaker.allow_dispatch():
             exc = CircuitOpenError(
@@ -800,35 +868,41 @@ class GenerationEngine:
                 if not s.req.future.done():
                     s.req.future.set_exception(exc)
                     _obs.log_access(
-                        self.name, "breaker", request_id=s.req.trace_id,
-                        ttft_ms=s.ttft_ms, tokens=len(s.tokens))
+                        self.name, "breaker", ttft_ms=s.ttft_ms,
+                        tokens=len(s.tokens), **s.req.access_ids())
             with self._cond:
                 for _, s in active:
                     self._release_pages_locked(s)
             self._gauge_pages()
             return
-        width = _io.pick_bucket(
-            gp.decode_widths, max(len(s.pages) for _, s in active))
-        sentinel = self.num_pages
-        token_ids = _np.zeros((B,), _np.int32)
-        positions = _np.zeros((B,), _np.int32)
-        table = _np.full((B, width), sentinel, _np.int32)
-        for i, s in active:
-            token_ids[i] = s.tokens[-1]
-            positions[i] = s.pos
-            k = min(width, len(s.pages))
-            table[i, :k] = s.pages[:k]
-        temp, tk, tp, keys = self._sample_arrays(active, B)
-        t0 = _time.perf_counter()
-        try:
-            self._kv, nxt = self._decode[width](
-                gp._params, self._kv, token_ids, positions, table,
-                temp, tk, tp, keys)
-            nxt = _np.asarray(nxt)
-        except BaseException as exc:  # noqa: BLE001 — pool donated away
-            self._dispatch_failed(exc)
-            return
-        t1 = _time.perf_counter()
+        with _tracing.span("engine.decode.prepare", cat="serving"):
+            sentinel = self.num_pages
+            token_ids = _np.zeros((B,), _np.int32)
+            positions = _np.zeros((B,), _np.int32)
+            table = _np.full((B, width), sentinel, _np.int32)
+            for i, s in active:
+                token_ids[i] = s.tokens[-1]
+                positions[i] = s.pos
+                k = min(width, len(s.pages))
+                table[i, :k] = s.pages[:k]
+            temp, tk, tp, keys = self._sample_arrays(active, B)
+        # tokens the rows hold against tokens the program attends over
+        sp.set(held_tokens=int(positions.sum()),
+               window_tokens=B * width * gp.page_size)
+        # the span has the bounds of the serving.decode_step_ms timer
+        with _tracing.span("engine.decode.device", cat="serving"):
+            t0 = _time.perf_counter()
+            try:
+                self._kv, nxt = self._decode[width](
+                    gp._params, self._kv, token_ids, positions, table,
+                    temp, tk, tp, keys)
+                # what is left of .device outside .fetch is the dispatch
+                with _tracing.span("engine.decode.fetch", cat="serving"):
+                    nxt = _np.asarray(nxt)
+            except BaseException as exc:  # noqa: BLE001 — pool donated
+                self._dispatch_failed(exc)
+                return
+            t1 = _time.perf_counter()
         if breaker is not None:
             breaker.record_success()
         _telemetry.timer("serving.decode_step_ms").observe(
@@ -842,11 +916,17 @@ class GenerationEngine:
                 _telemetry.counter("kernels.paged_attention").inc()
             elif _kernels_enabled():
                 _telemetry.counter("kernels.paged_fallback").inc()
-        self._count_tokens(len(active))
-        for i, s in active:
-            s.tokens.append(int(nxt[i]))
-            s.pos += 1
-            self._maybe_finish(i)
+        with _tracing.span("engine.decode.emit", cat="serving") as emit:
+            self._count_tokens(len(active))
+            gap = _telemetry.timer("serving.token_gap_ms")
+            finished = 0
+            for i, s in active:
+                s.tokens.append(int(nxt[i]))
+                gap.observe((t1 - s.token_t[-1]) * 1e3)
+                s.token_t.append(t1)
+                s.pos += 1
+                finished += self._maybe_finish(i)
+            emit.set(finished=finished)
 
     def _sample_arrays(self, active, B):
         """Per-row sampling operands for a dispatch: active rows carry
@@ -879,7 +959,7 @@ class GenerationEngine:
             req.eos_id is not None
             and slot.tokens[-1] == int(req.eos_id))
         if not done:
-            return
+            return 0
         self._slots[slot_idx] = None
         with self._cond:
             self._release_pages_locked(slot)
@@ -891,21 +971,32 @@ class GenerationEngine:
             req.future.set_result(_np.asarray(slot.tokens, _np.int32))
             if _obs.access_log_enabled():
                 _obs.log_access(
-                    self.name, "ok", request_id=req.trace_id,
+                    self.name, "ok",
+                    queue_ms=(slot.t_admit - req.t_submit) * 1e3,
                     dispatch_ms=wall_ms, ttft_ms=slot.ttft_ms,
                     tokens=len(slot.tokens),
-                    bytes=len(slot.tokens) * 4)
+                    bytes=len(slot.tokens) * 4, **req.access_ids())
         if _telemetry.enabled():
+            t_sub = req.t_submit
             _telemetry.log_event(
                 "serving_generate", model=self.name,
+                request_id=req.request_id, trace_id=req.trace_id,
                 prompt_len=req.plen, new_tokens=len(slot.tokens),
                 max_new=req.max_new, pages=len(slot.pages),
+                queue_ms=round((slot.t_admit - t_sub) * 1e3, 4),
+                prefill_ms=round(
+                    (slot.token_t[0] - slot.t_prefill_start) * 1e3, 4),
                 ttft_ms=round(slot.ttft_ms, 4)
                 if slot.ttft_ms is not None else None,
+                # when each token left the engine, from submit: what a
+                # streaming front end would feel
+                token_ms=[round((t - t_sub) * 1e3, 4)
+                          for t in slot.token_t],
                 wall_ms=round(wall_ms, 4),
                 pool_exhausted_wait=req.stall_counted,
                 breaker=self.breaker.state
                 if self.breaker is not None else "closed")
+        return 1
 
     def _stall_probe(self, interval_s):
         """mx.tracing stall probe (registered in :meth:`start`): reports

@@ -710,6 +710,12 @@ class HybridBlock(Block):
                 self._cached_graph_obj = _CachedGraph(self)
                 return out
             return self._cached_graph_obj(x, *args)
+        if _TRACE_GUARD.active:
+            # inlined into a hybridized / functional program being traced:
+            # the device operations carry the block's own name (a profile
+            # then tells the model's stages apart); not in eager calls
+            with jax.named_scope(self.name):
+                return self._eager_forward(x, *args)
         return self._eager_forward(x, *args)
 
     def export(self, path, epoch=0, remove_amp_cast=True,

@@ -155,9 +155,9 @@ def paged_unsupported_reason(q, k, v, valid, quantized=False):
     """Why the Pallas paged-attention kernel can NOT take this decode
     call, or None if it can.  Trace-time shape/dtype checks only —
     everything here must be static under jit.  A non-None reason routes
-    to the XLA lowering (``kernels.paged_fallback``) and is surfaced on
-    the ``kernels.paged`` tracing span so perf_report can attribute
-    decode time to kernel-vs-XLA."""
+    to the XLA lowering (``kernels.paged_fallback``) and is surfaced in
+    the export route sink (:func:`record_paged_routes`); either lowering
+    runs under the ``mx.paged_attention`` name scope."""
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         return "rank != 4 (got q%s k%s v%s)" % (q.ndim, k.ndim, v.ndim)
     # jax.export shape polymorphism: a symbolic batch/pool dim can't
@@ -274,10 +274,18 @@ def paged_attention(q, k, v, valid, scale=None, k_scale=None,
     lowering when the shape can't take the kernel
     (``kernels.paged_fallback``) or when the default-source gate
     measured the kernel slower / not bit-close
-    (``kernels.gated_fallback``).  The decision and its reason land on a
-    ``kernels.paged`` tracing span and, under
-    :func:`record_paged_routes`, in the export route sink."""
-    from . import tracing as _tracing
+    (``kernels.gated_fallback``).  The decision and its reason land in
+    those counters and, under :func:`record_paged_routes`, in the export
+    route sink.  Whichever lowering runs, its device operations carry the
+    ``mx.paged_attention`` name scope (the Pallas kernel is named
+    ``mx_paged_attention``), so a profile finds them after a route
+    change."""
+    with jax.named_scope("mx.paged_attention"):
+        return _paged_attention_routed(q, k, v, valid, scale, k_scale,
+                                       v_scale)
+
+
+def _paged_attention_routed(q, k, v, valid, scale, k_scale, v_scale):
     quant = k_scale is not None
     if enabled():
         q = jnp.asarray(q)
@@ -292,23 +300,18 @@ def paged_attention(q, k, v, valid, scale=None, k_scale=None,
                 _telemetry.counter("kernels.paged_attention").inc()
                 _note_paged_route("paged", None, quant)
                 bb = pick.get("block_bh") if pick else None
-                with _tracing.span("kernels.paged", cat="kernels",
-                                   impl="paged", quantized=quant):
-                    return pallas_paged_attention(
-                        q, k, v, valid, scale=scale, k_scale=k_scale,
-                        v_scale=v_scale,
-                        block_bh=int(bb) if bb else None)
+                return pallas_paged_attention(
+                    q, k, v, valid, scale=scale, k_scale=k_scale,
+                    v_scale=v_scale, block_bh=int(bb) if bb else None)
             # the measured gate lost (or the platform statically can't
             # win): the XLA lowering IS the winner for this site
             reason = pick.get("reason") or "autotune gate: xla won"
             _telemetry.counter("kernels.gated_fallback").inc()
         else:
             _telemetry.counter("kernels.paged_fallback").inc()
-        with _tracing.span("kernels.paged", cat="kernels", impl="xla",
-                           reason=reason, quantized=quant):
-            _note_paged_route("xla", reason, quant)
-            return _paged_attention_xla(q, k, v, valid, scale=scale,
-                                        k_scale=k_scale, v_scale=v_scale)
+        _note_paged_route("xla", reason, quant)
+        return _paged_attention_xla(q, k, v, valid, scale=scale,
+                                    k_scale=k_scale, v_scale=v_scale)
     _note_paged_route("xla", "tier off", quant)
     return _paged_attention_xla(q, k, v, valid, scale=scale,
                                 k_scale=k_scale, v_scale=v_scale)

@@ -148,31 +148,37 @@ class TransformerLM:
 
     def _qkv(self, x, lp):
         """ln1 + fused QKV projection: x [B,S,D] -> q,k,v [B,H,S,Dh]."""
-        h = _norm(x, lp["ln1"])
-        qkv = jnp.einsum("bsd,dche->bsche", h, lp["wqkv"],
-                         preferred_element_type=jnp.float32).astype(x.dtype)
-        q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))   # [B,H,S,Dh]
-        k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
-        v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
-        return q, k, v
+        with jax.named_scope("mx.qkv"):
+            h = _norm(x, lp["ln1"])
+            qkv = jnp.einsum("bsd,dche->bsche", h, lp["wqkv"],
+                             preferred_element_type=jnp.float32
+                             ).astype(x.dtype)
+            q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))   # [B,H,S,Dh]
+            k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
+            v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
+            return q, k, v
 
     def _attn_mlp(self, x, o, lp):
         """Output projection + residual + MLP half of one layer; ``o`` is
         the attention output [B,H,S,Dh]."""
-        o = jnp.einsum("bhse,hed->bsd", o, lp["wo"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        x = x + o
-        x = self._constrain(x, self._dp, self._sp, None)
+        with jax.named_scope("mx.attn_out"):
+            o = jnp.einsum("bhse,hed->bsd", o, lp["wo"],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+            x = x + o
+            x = self._constrain(x, self._dp, self._sp, None)
 
-        h = _norm(x, lp["ln2"])
-        u = jnp.einsum("bsd,df->bsf", h, lp["w1"],
-                       preferred_element_type=jnp.float32)
-        u = jax.nn.gelu(u).astype(x.dtype)
-        u = self._constrain(u, self._dp, self._sp, self._tp)
-        d = jnp.einsum("bsf,fd->bsd", u, lp["w2"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        x = x + d
-        return self._constrain(x, self._dp, self._sp, None)
+        with jax.named_scope("mx.mlp"):
+            h = _norm(x, lp["ln2"])
+            u = jnp.einsum("bsd,df->bsf", h, lp["w1"],
+                           preferred_element_type=jnp.float32)
+            u = jax.nn.gelu(u).astype(x.dtype)
+            u = self._constrain(u, self._dp, self._sp, self._tp)
+            d = jnp.einsum("bsf,fd->bsd", u, lp["w2"],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+            x = x + d
+            return self._constrain(x, self._dp, self._sp, None)
 
     def _layer(self, x, lp, kv_sink=None):
         q, k, v = self._qkv(x, lp)
@@ -184,7 +190,8 @@ class TransformerLM:
         q = self._constrain(q, self._dp, self._tp, self._sp, None)
         k = self._constrain(k, self._dp, self._tp, self._sp, None)
         v = self._constrain(v, self._dp, self._tp, self._sp, None)
-        o = self._attention(q, k, v)                    # [B,H,S,Dh]
+        with jax.named_scope("mx.attention"):
+            o = self._attention(q, k, v)                # [B,H,S,Dh]
         return self._attn_mlp(x, o, lp)
 
     def run_stack(self, params, x):
@@ -274,9 +281,10 @@ class TransformerLM:
     def _logits_last(self, params, x):
         """Final norm + tied-embedding readout for one position per row:
         x [B, D] -> logits [B, V] f32."""
-        x = _norm(x, params["final_norm"])
-        return jnp.einsum("bd,vd->bv", x, params["embed"],
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("mx.lm_head"):
+            x = _norm(x, params["final_norm"])
+            return jnp.einsum("bd,vd->bv", x, params["embed"],
+                              preferred_element_type=jnp.float32)
 
     def _sample_last(self, params, x, positions, sample):
         """Readout + next-token choice for one position per row.
@@ -294,9 +302,13 @@ class TransformerLM:
         bitwise the greedy readout.  Returns ``(ids [B] i32,
         logits [B, V] f32)`` — raw logits, for the int8 drift gate."""
         logits = self._logits_last(params, x)
+        with jax.named_scope("mx.sample"):
+            return self._choose(logits, positions, sample), logits
+
+    def _choose(self, logits, positions, sample):
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if sample is None:
-            return greedy, logits
+            return greedy
         temp = sample["temperature"].astype(jnp.float32)        # [B]
         top_k = sample["top_k"].astype(jnp.int32)               # [B]
         top_p = sample["top_p"].astype(jnp.float32)             # [B]
@@ -323,7 +335,7 @@ class TransformerLM:
             jax.random.fold_in(kr, pos), (V,), jnp.float32))(
                 keys, positions.astype(jnp.uint32))
         choice = jnp.argmax(masked + gum, axis=-1).astype(jnp.int32)
-        return jnp.where(temp > 0, choice, greedy), logits
+        return jnp.where(temp > 0, choice, greedy)
 
     def prefill(self, params, kv, tokens, lengths, page_table, page_size,
                 sample=None, return_logits=False):
@@ -367,21 +379,26 @@ class TransformerLM:
 
             def sink(k, v):
                 # [B,H,S,Dh] -> [B,S,H,Dh] page-slot scatter
-                kt = jnp.transpose(k, (0, 2, 1, 3))
-                vt = jnp.transpose(v, (0, 2, 1, 3))
-                if quant:
-                    from .. import quantization as _quant
-                    kq, ks = _quant.quantize_rows(kt)
-                    vq, vs = _quant.quantize_rows(vt)
-                    new["k"] = kl.at[pages, slots].set(kq, mode="drop")
-                    new["v"] = vl.at[pages, slots].set(vq, mode="drop")
-                    new["ks"] = ksl.at[pages, slots].set(ks, mode="drop")
-                    new["vs"] = vsl.at[pages, slots].set(vs, mode="drop")
-                else:
-                    new["k"] = kl.at[pages, slots].set(
-                        kt.astype(kl.dtype), mode="drop")
-                    new["v"] = vl.at[pages, slots].set(
-                        vt.astype(vl.dtype), mode="drop")
+                with jax.named_scope("mx.kv_write"):
+                    kt = jnp.transpose(k, (0, 2, 1, 3))
+                    vt = jnp.transpose(v, (0, 2, 1, 3))
+                    if quant:
+                        from .. import quantization as _quant
+                        kq, ks = _quant.quantize_rows(kt)
+                        vq, vs = _quant.quantize_rows(vt)
+                        new["k"] = kl.at[pages, slots].set(
+                            kq, mode="drop")
+                        new["v"] = vl.at[pages, slots].set(
+                            vq, mode="drop")
+                        new["ks"] = ksl.at[pages, slots].set(
+                            ks, mode="drop")
+                        new["vs"] = vsl.at[pages, slots].set(
+                            vs, mode="drop")
+                    else:
+                        new["k"] = kl.at[pages, slots].set(
+                            kt.astype(kl.dtype), mode="drop")
+                        new["v"] = vl.at[pages, slots].set(
+                            vt.astype(vl.dtype), mode="drop")
 
             out = self._layer(carry, lp, kv_sink=sink)
             if quant:
@@ -440,28 +457,39 @@ class TransformerLM:
             else:
                 lp, kl, vl = xs
             q, k, v = self._qkv(carry, lp)                    # [B,H,1,Dh]
-            kt = jnp.transpose(k, (0, 2, 1, 3))               # [B,1,H,Dh]
-            vt = jnp.transpose(v, (0, 2, 1, 3))
             scales = {}
+            # the operations keep the order they always had (a scope is
+            # metadata: the lowered program does not change by it)
+            with jax.named_scope("mx.kv_write"):
+                kt = jnp.transpose(k, (0, 2, 1, 3))           # [B,1,H,Dh]
+                vt = jnp.transpose(v, (0, 2, 1, 3))
+                if quant:
+                    from .. import quantization as _quant
+                    kt, ks = _quant.quantize_rows(kt)
+                    vt, vs = _quant.quantize_rows(vt)
+                    ksl = ksl.at[page, slot].set(ks, mode="drop")
+                    vsl = vsl.at[page, slot].set(vs, mode="drop")
             if quant:
-                from .. import quantization as _quant
-                kt, ks = _quant.quantize_rows(kt)
-                vt, vs = _quant.quantize_rows(vt)
-                ksl = ksl.at[page, slot].set(ks, mode="drop")
-                vsl = vsl.at[page, slot].set(vs, mode="drop")
-                # gathered per-row scales, [B, K] -> [B, H, K]
-                scales["k_scale"] = jnp.transpose(
-                    ksl[page_table].reshape(B, W * psz, H), (0, 2, 1))
-                scales["v_scale"] = jnp.transpose(
-                    vsl[page_table].reshape(B, W * psz, H), (0, 2, 1))
-            kl = kl.at[page, slot].set(kt.astype(kl.dtype), mode="drop")
-            vl = vl.at[page, slot].set(vt.astype(vl.dtype), mode="drop")
+                with jax.named_scope("mx.kv_gather"):
+                    # gathered per-row scales, [B, K] -> [B, H, K]
+                    scales["k_scale"] = jnp.transpose(
+                        ksl[page_table].reshape(B, W * psz, H), (0, 2, 1))
+                    scales["v_scale"] = jnp.transpose(
+                        vsl[page_table].reshape(B, W * psz, H), (0, 2, 1))
+            with jax.named_scope("mx.kv_write"):
+                kl = kl.at[page, slot].set(kt.astype(kl.dtype),
+                                           mode="drop")
+                vl = vl.at[page, slot].set(vt.astype(vl.dtype),
+                                           mode="drop")
             # context through the page table (sentinel entries clip to a
             # real page; `valid` masks them out of the softmax exactly)
-            kc = jnp.transpose(
-                kl[page_table].reshape(B, W * psz, H, Dh), (0, 2, 1, 3))
-            vc = jnp.transpose(
-                vl[page_table].reshape(B, W * psz, H, Dh), (0, 2, 1, 3))
+            with jax.named_scope("mx.kv_gather"):
+                kc = jnp.transpose(
+                    kl[page_table].reshape(B, W * psz, H, Dh),
+                    (0, 2, 1, 3))
+                vc = jnp.transpose(
+                    vl[page_table].reshape(B, W * psz, H, Dh),
+                    (0, 2, 1, 3))
             o = _kernels.paged_attention(q, kc, vc, valid, **scales)
             out = self._attn_mlp(carry, o, lp)
             if quant:

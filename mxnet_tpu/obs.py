@@ -428,7 +428,8 @@ def _drain_access_locked():  # mxlint: holds(_ACCESS_LOCK)
     while True:
         try:
             (ts, model, outcome, request_id, queue_ms, dispatch_ms,
-             ttft_ms, tokens, nbytes, error) = _ACCESS_QUEUE.popleft()
+             ttft_ms, tokens, nbytes, error, trace_id) = \
+                _ACCESS_QUEUE.popleft()
         except IndexError:
             break
         line = ('{"event":"access","ts":%.6f,"request_id":%s,'
@@ -449,6 +450,8 @@ def _drain_access_locked():  # mxlint: holds(_ACCESS_LOCK)
             line += ',"bytes":%d' % nbytes
         if error is not None:
             line += ',"error":%s' % _json_str(error)
+        if trace_id is not None:
+            line += ',"trace_id":%s' % _json_str(trace_id)
         lines.append(line)
     if lines:
         sink.write("}\n".join(lines) + "}\n")
@@ -530,7 +533,7 @@ def flush_access_log():
 def log_access(model, outcome, request_id=None, queue_ms=None,
                dispatch_ms=None, ttft_ms=None, tokens=None,
                bytes=None, error=None,  # noqa: A002 — schema field name
-               _now=time.time, _qlen=_ACCESS_QUEUE.__len__,
+               trace_id=None, _now=time.time, _qlen=_ACCESS_QUEUE.__len__,
                _qput=_ACCESS_QUEUE.append):
     """Enqueue one access record (no-op when the log is off).  One call
     per request terminal outcome — the serving/generation layers own the
@@ -547,14 +550,15 @@ def log_access(model, outcome, request_id=None, queue_ms=None,
         _telemetry.counter("obs.access_dropped").inc()
         return
     _qput((_now(), model, outcome, request_id, queue_ms, dispatch_ms,
-           ttft_ms, tokens, bytes, error))
+           ttft_ms, tokens, bytes, error, trace_id))
 
 
 _ACCESS_REQUIRED = {"event": str, "ts": (int, float), "model": str,
                     "outcome": str}
 _ACCESS_OPTIONAL = {"request_id": str, "queue_ms": (int, float),
                     "dispatch_ms": (int, float), "ttft_ms": (int, float),
-                    "tokens": int, "bytes": int, "error": str}
+                    "tokens": int, "bytes": int, "error": str,
+                    "trace_id": str}
 
 
 def validate_access_record(rec):

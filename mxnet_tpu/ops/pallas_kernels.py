@@ -129,7 +129,7 @@ def _softmax_fwd_call(flat):
         out_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0)),
                    pl.BlockSpec((rows, 1), lambda i: (i, 0)),
                    pl.BlockSpec((rows, 1), lambda i: (i, 0))],
-        interpret=interpret_mode())(flat)
+        interpret=interpret_mode(), name="mx_row_softmax")(flat)
 
 
 def _softmax_bwd_call(x, m, l, dy):
@@ -146,7 +146,7 @@ def _softmax_bwd_call(x, m, l, dy):
                   pl.BlockSpec((rows, 1), lambda i: (i, 0)),
                   pl.BlockSpec((rows, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
-        interpret=interpret_mode())(x, m, l, dy)
+        interpret=interpret_mode(), name="mx_row_softmax_bwd")(x, m, l, dy)
 
 
 @jax.custom_vjp
@@ -302,7 +302,7 @@ def _flash_forward(q, k, v, causal, scale, block_q):
                   pl.BlockSpec((1, Skv, D), lambda b, i: (b, 0, 0))],
         out_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0))],
-        interpret=interpret_mode())(qf, kf, vf)
+        interpret=interpret_mode(), name="mx_attention")(qf, kf, vf)
     return out.reshape(B, H, S, D), lse.reshape(B * H, S)
 
 
@@ -337,7 +337,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
                   pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0))],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        interpret=interpret_mode())(qf, kf, vf, dof, lse_c, delta_c)
+        interpret=interpret_mode(),
+        name="mx_attention_bwd_dq")(qf, kf, vf, dof, lse_c, delta_c)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale, bool(causal), bk),
         out_shape=[jax.ShapeDtypeStruct(kf.shape, k.dtype),
@@ -351,7 +352,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q):
                   pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0))],
         out_specs=[pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0))],
-        interpret=interpret_mode())(qf, kf, vf, dof, lse_r, delta_r)
+        interpret=interpret_mode(),
+        name="mx_attention_bwd_dkv")(qf, kf, vf, dof, lse_r, delta_r)
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, Skv, D),
             dv.reshape(B, H, Skv, D))
 
@@ -536,7 +538,7 @@ def pallas_paged_attention(q, k, v, valid, scale=None, k_scale=None,
         grid=(BH // rows,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((rows, 1, D), row3),
-        interpret=interpret_mode())(*operands)
+        interpret=interpret_mode(), name="mx_paged_attention")(*operands)
     return out.reshape(B, H, 1, D)
 
 
@@ -624,7 +626,7 @@ def _epilogue_call(kernel, arrays, scalars, out_dtypes, block_rows=None):
                  [pl.BlockSpec((1, 1), lambda i: (0, 0)) for _ in scal],
         out_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0))
                    for _ in out_dtypes],
-        interpret=interpret_mode())(*(flats + scal))
+        interpret=interpret_mode(), name="mx_opt_update")(*(flats + scal))
     return [o.reshape(shape) for o in outs]
 
 
@@ -699,5 +701,5 @@ def pallas_scale_bias_relu(data, scale, bias, **_):
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
-        interpret=interpret_mode())(flat, s, b)
+        interpret=interpret_mode(), name="mx_scale_bias_relu")(flat, s, b)
     return out.reshape(x.shape)

@@ -336,6 +336,10 @@ class SPMDTrainer:
         cdt = self.compute_dtype
         hwio = bool(self._hwio_names)
 
+        # device names (docs/OBSERVABILITY.md): the forward and loss trace
+        # under mx.forward (the backward then reads
+        # transpose(jvp(mx.forward))), the update under mx.opt_update
+        @jax.named_scope("mx.forward")
         def loss_of(train_params, aux_params, data, label, key):
             from ..ops import nn as _nn_ops
             param_map = dict(aux_params)  # aux (BN stats) stay f32
@@ -413,7 +417,8 @@ class SPMDTrainer:
                     if ici_axes:
                         # ICI stays full precision: compiler-scheduled
                         # psum at torus bandwidth beats recompression
-                        g = jax.lax.psum(g, ici_axes)
+                        with jax.named_scope("mx.allreduce"):
+                            g = jax.lax.psum(g, ici_axes)
                     # this dcn slice's share of the GLOBAL mean gradient
                     # (the dcn-psum of v is the uncompressed global grad)
                     v = g / n_shards
@@ -422,7 +427,8 @@ class SPMDTrainer:
                     packed = _comp.pack_2bit(codes)
                     # the DCN hop moves 4 codes/byte — 1/16 of the f32
                     # bytes; each shard unpacks the peers' rows and sums
-                    rows = jax.lax.all_gather(packed, "dcn")
+                    with jax.named_scope("mx.allreduce"):
+                        rows = jax.lax.all_gather(packed, "dcn")
                     tot = jnp.zeros((int(v.size),), jnp.int32)
                     for w in range(n_dcn):
                         tot = tot + _comp.unpack_2bit(rows[w], int(v.size))
@@ -473,7 +479,8 @@ class SPMDTrainer:
             # Stochastic optimizers (SGLD noise) must draw from the step's
             # traced key, not bake a trace-time constant into the compiled
             # program — keep a trace key scope open for the update loop.
-            with _random.trace_key_scope(jax.random.fold_in(key, 1)):
+            with _random.trace_key_scope(jax.random.fold_in(key, 1)), \
+                    jax.named_scope("mx.opt_update"):
                 for i, n in enumerate(trainable):
                     g = _preprocess(optimizer, grads[n])
                     if stats is not None:
@@ -594,6 +601,7 @@ class SPMDTrainer:
                 "embedding.sharded=False to train them densely"
                 % (sparse_names, type(optimizer).__name__))
 
+        @jax.named_scope("mx.forward")
         def loss_of(train_params, emb_deltas, aux_params, emb_tables, data,
                     label, key):
             from ..ops import nn as _nn_ops
@@ -665,7 +673,8 @@ class SPMDTrainer:
             new_params = {}
             new_state = {}
             from .. import random as _random
-            with _random.trace_key_scope(jax.random.fold_in(key, 1)):
+            with _random.trace_key_scope(jax.random.fold_in(key, 1)), \
+                    jax.named_scope("mx.opt_update"):
                 for i, n in enumerate(trainable):
                     if n in sparse_meta:
                         uniq = recs.get(n)
@@ -794,23 +803,37 @@ class SPMDTrainer:
         if isinstance(label, NDArray):
             label = label._data
         pad = int(pad or 0)
-        # nanguard escalation check: a dict lookup per step; raises
-        # NonFiniteStepError (after flight-recorder dump + checkpoint)
-        # once the device reported K consecutive bad steps
-        _resilience.maybe_abort_nonfinite("spmd",
-                                          save_fn=self._preempt_save)
-        if _resilience.faults_active("nan") and _resilience.should_inject(
-                "nan", step=self._step_num + 1):
-            data = _resilience.poison_batch(data)
-        with _telemetry.step_scope(
-                "spmd", samples=int(data.shape[0]) - pad if
-                getattr(data, "ndim", 0) else None,
-                shape=tuple(getattr(data, "shape", ())) or None,
-                mesh={n: int(s) for n, s in zip(self.mesh.axis_names,
-                                                self.mesh.devices.shape)},
-                default_path="fused"), \
-                _tracing.span("spmd.step", cat="spmd"):
-            loss = self._step_impl(data, label, lr_scale, pad)
+        # spmd.step holds the whole call; its children shard_batch /
+        # prepare / dispatch / post name the parts, and what is left of it
+        # is this wrapper's own (the checks below, step_scope, the program
+        # lookup)
+        with _tracing.span("spmd.step", cat="spmd",
+                           step=self._step_num + 1):
+            # nanguard escalation check: a dict lookup per step; raises
+            # NonFiniteStepError (after flight-recorder dump + checkpoint)
+            # once the device reported K consecutive bad steps
+            _resilience.maybe_abort_nonfinite("spmd",
+                                              save_fn=self._preempt_save)
+            if _resilience.faults_active("nan") \
+                    and _resilience.should_inject(
+                        "nan", step=self._step_num + 1):
+                data = _resilience.poison_batch(data)
+            with _telemetry.step_scope(
+                    "spmd", samples=int(data.shape[0]) - pad if
+                    getattr(data, "ndim", 0) else None,
+                    shape=tuple(getattr(data, "shape", ())) or None,
+                    mesh={n: int(s) for n, s in zip(
+                        self.mesh.axis_names, self.mesh.devices.shape)},
+                    default_path="fused"):
+                loss = self._step_impl(data, label, lr_scale, pad)
+            with _tracing.span("spmd.post", cat="spmd"):
+                self._after_step()
+        return loss
+
+    def _after_step(self):
+        """The hooks that run between steps: periodic checkpoint, the
+        multi-host preemption agreement, the preemption exit."""
+        from .. import resilience as _resilience
         if self._ckpt_manager is not None:
             self._ckpt_manager.maybe_save(self._step_num,
                                           self.save_checkpoint)
@@ -825,7 +848,6 @@ class SPMDTrainer:
             # the in-flight step is done (save gathers to host, which
             # syncs); checkpoint, flush sinks, exit 0
             _resilience.exit_on_preempt(save_fn=self._preempt_save)
-        return loss
 
     def _step_impl(self, data, label, lr_scale, pad=0):
         from .. import io as _io
@@ -883,48 +905,50 @@ class SPMDTrainer:
                                      source="spmd")
             label = _io.ensure_staged(label, self._batch_sharding,
                                       source="spmd")
-        self._step_num += 1
-        self.optimizer.num_update = self._step_num
-        if not hasattr(self, "_hyper_cache"):
-            self._hyper_cache = {}
-        lrs, wds = _opt_hyper_arrays(self.optimizer, len(self.fn.trainable),
-                                     self._hyper_cache)
-        from .. import random as _random
-        key = _random.new_eager_seed_key()
-        sparse = {n for n in self._sparse_embed if n in self.fn.trainable}
-        train = {n: self.params[n] for n in self.fn.trainable
-                 if n not in sparse}
-        tables = {n: self.params[n] for n in sparse}
-        aux = {n: self.params[n] for n in self.fn.aux}
-        scales = self._hyper_cache.setdefault("scales", {})
-        # cache only plain-number scales (arrays are unhashable and a
-        # dynamic loss-scale would grow the cache unboundedly)
-        cacheable = isinstance(lr_scale, (int, float))
-        sarr = scales.get(lr_scale) if cacheable else None
-        if sarr is None:
-            sarr = jnp.asarray(lr_scale, jnp.float32)
-            if cacheable and len(scales) < 16:
-                scales[lr_scale] = sarr
-        t_arr = jnp.asarray(self._step_num, jnp.int32)
-        if compressed and self._dcn_residuals is None:
-            n_dcn = int(self.mesh.shape["dcn"])
-            rsh = NamedSharding(self.mesh, P("dcn"))
-            self._dcn_residuals = {
-                n: jax.device_put(
-                    jnp.zeros((n_dcn,) + tuple(train[n].shape),
-                              train[n].dtype if jnp.issubdtype(
-                                  train[n].dtype, jnp.inexact)
-                              else jnp.float32), rsh)
-                for n in train}
-        args = (train, aux, self.opt_state) + \
-            ((self._dcn_residuals,) if compressed else ()) + \
-            ((tables,) if sparse else ()) + (data, label, key, t_arr, lrs,
-                                             wds, sarr)
+        with _tracing.span("spmd.prepare", cat="spmd"):
+            self._step_num += 1
+            self.optimizer.num_update = self._step_num
+            if not hasattr(self, "_hyper_cache"):
+                self._hyper_cache = {}
+            lrs, wds = _opt_hyper_arrays(
+                self.optimizer, len(self.fn.trainable), self._hyper_cache)
+            from .. import random as _random
+            key = _random.new_eager_seed_key()
+            sparse = {n for n in self._sparse_embed if n in self.fn.trainable}
+            train = {n: self.params[n] for n in self.fn.trainable
+                     if n not in sparse}
+            tables = {n: self.params[n] for n in sparse}
+            aux = {n: self.params[n] for n in self.fn.aux}
+            scales = self._hyper_cache.setdefault("scales", {})
+            # cache only plain-number scales (arrays are unhashable and a
+            # dynamic loss-scale would grow the cache unboundedly)
+            cacheable = isinstance(lr_scale, (int, float))
+            sarr = scales.get(lr_scale) if cacheable else None
+            if sarr is None:
+                sarr = jnp.asarray(lr_scale, jnp.float32)
+                if cacheable and len(scales) < 16:
+                    scales[lr_scale] = sarr
+            t_arr = jnp.asarray(self._step_num, jnp.int32)
+            if compressed and self._dcn_residuals is None:
+                n_dcn = int(self.mesh.shape["dcn"])
+                rsh = NamedSharding(self.mesh, P("dcn"))
+                self._dcn_residuals = {
+                    n: jax.device_put(
+                        jnp.zeros((n_dcn,) + tuple(train[n].shape),
+                                  train[n].dtype if jnp.issubdtype(
+                                      train[n].dtype, jnp.inexact)
+                                  else jnp.float32), rsh)
+                    for n in train}
+            args = (train, aux, self.opt_state) + \
+                ((self._dcn_residuals,) if compressed else ()) + \
+                ((tables,) if sparse else ()) + (data, label, key, t_arr, lrs,
+                                                 wds, sarr)
         stats = None
         if self._guard_mode:
             if self._nan_streak is None:
                 self._nan_streak = jnp.zeros((), jnp.int32)
-            res = jitted(*args, self._nan_streak)
+            with _tracing.span("spmd.dispatch", cat="spmd"):
+                res = jitted(*args, self._nan_streak)
             if cap:
                 (new_train, new_aux, self.opt_state, loss,
                  self._nan_streak, stats) = res
@@ -962,7 +986,8 @@ class SPMDTrainer:
 
             _numerics.hold_replay("spmd", _replay)
         else:
-            res = jitted(*args)
+            with _tracing.span("spmd.dispatch", cat="spmd"):
+                res = jitted(*args)
             if cap:
                 new_train, new_aux, self.opt_state, loss, stats = res
             elif compressed:

@@ -64,7 +64,8 @@ __all__ = [
     "OP_CLASSES", "classify_op", "hlo_op_classes", "device_kind",
     "peak_flops", "peak_bandwidth", "roofline", "register_compiled",
     "programs", "program", "reset", "export", "wrap", "PerfProgram",
-    "configure_profile", "cost_analysis", "autotune",
+    "configure_profile", "cost_analysis", "autotune", "hlo_op_names",
+    "op_names",
 ]
 
 # ------------------------------------------------------------ peak table
@@ -145,7 +146,7 @@ def peak_bandwidth(kind=None):
 
 # --------------------------------------------------------- op-class map
 # Shared by the registry's HLO instruction table and
-# tools/profile_step.py's device-trace bucketing, so the two cost
+# tools/perf_report.py --trace's device-trace bucketing, so the two cost
 # reports cannot drift.  Input is either a bare HLO opcode ("dot") or a
 # device-trace op name ("%fusion.42", "convolution.7").
 OP_CLASSES = ("matmul", "conv", "elementwise", "reduction", "collective",
@@ -240,6 +241,61 @@ def roofline(flops, bytes_accessed, kind=None, dtype="bfloat16"):
 
 # -------------------------------------------------------------- registry
 _REG_LOCK = threading.Lock()
+# an executed instruction of optimized HLO text with what follows its
+# "=": '  [ROOT ]%fusion.11 = bf16[8,4]{1,0} fusion(...), ..., metadata={...}'
+_HLO_NAMED_RE = re.compile(r"^\s+(ROOT\s+)?%([\w.\-]+) = (.*)$")
+_HLO_SHAPE_RE = re.compile(r"^(.*?) [a-z][\w\-]*\(")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS_RE = re.compile(r"kind=k\w+, calls=%([\w.\-]+)")
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{$")
+_HLO_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)")
+
+
+def hlo_op_names(hlo_text):
+    """``{"module": name, "ops": {instruction: [result shape, op_name]}}``
+    from optimized HLO text: for every instruction the device executes as
+    an operation of its own (fusion bodies left out), the name-stack path
+    jax recorded for it — ``jit(step)/mx.opt_update/mul``.  A fusion
+    without a name of its own takes its root's.  A device profile taken
+    without HLO protos names an event by its instruction alone
+    (``%fusion.11 = ...``); this table is what joins it to the
+    ``jax.named_scope`` / ``pallas_call(name=)`` names of the program."""
+    module = None
+    ops, roots, fused, calls = {}, {}, set(), {}
+    comp = None
+    for line in (hlo_text or "").splitlines():
+        m = _HLO_NAMED_RE.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION_RE.match(line)
+            if c is not None:
+                comp = c.group(1)
+            elif module is None:
+                h = _HLO_MODULE_RE.match(line)
+                if h is not None:
+                    module = h.group(1)
+            continue
+        root, name, rest = m.groups()
+        n = _HLO_OP_NAME_RE.search(rest)
+        op_name = n.group(1) if n is not None else ""
+        if root:
+            roots[comp] = op_name
+        k = _HLO_CALLS_RE.search(rest)
+        if k is not None:
+            fused.add(k.group(1))
+            calls[name] = k.group(1)
+        sh = _HLO_SHAPE_RE.match(rest)
+        ops[name] = [comp, sh.group(1) if sh is not None else "", op_name]
+    out = {}
+    for name, (comp, shape, op_name) in ops.items():
+        if comp in fused:
+            continue
+        if not op_name and name in calls:
+            op_name = roots.get(calls[name], "")
+        if op_name:     # an instruction jax named nothing is in no scope
+            out[name] = [shape, op_name]
+    return {"module": module, "ops": out}
+
+
 _PROGRAMS = {}  # guarded-by[writes]: _REG_LOCK — (family, key) -> record
 
 FAMILIES = ("module", "spmd", "gluon", "serving", "embedding")
@@ -326,6 +382,8 @@ def register_compiled(family, key, compiled, phases_ms=None, dtype=None):
         "memory": memory,
         "phases_ms": phases,
         "op_classes": hlo_op_classes(text),
+        # private (large): instruction -> name-stack path, for op_names()
+        "_op_names": hlo_op_names(text),
         "roofline": roofline(flops, nbytes, kind, dtype) if known else None,
         "peak_tflops": (round(peak_flops(kind, dtype) / 1e12, 3)
                         if known else None),
@@ -383,6 +441,18 @@ def program(family, key):
     with _REG_LOCK:
         rec = _PROGRAMS.get((str(family), str(key)))
     return _public(rec) if rec is not None else None
+
+
+def op_names(family=None):
+    """Per registered program, the instruction -> ``[result shape,
+    op_name]`` table of :func:`hlo_op_names` with the program's
+    ``family``, ``key`` and HLO ``module`` name — what a reader of a
+    device trace joins event names with to find the ``mx.*`` scope (or the
+    Gluon block) an operation ran under."""
+    with _REG_LOCK:
+        recs = list(_PROGRAMS.values())
+    return [dict(rec["_op_names"], family=rec["family"], key=rec["key"])
+            for rec in recs if family is None or rec["family"] == family]
 
 
 def reset():

@@ -44,10 +44,20 @@ def configure_compile_cache():
     path, because the path is part of the cache key and a directory that
     moves never hits.  Idempotent; entry points (chip_smoke.py, bench.py,
     the tools, ``Server.start()``) call it before their first big
-    compile."""
+    compile.
+
+    The key covers the program's metadata too (jax leaves it out by
+    default): a cached executable carries the operation names it was
+    compiled with, and those names — ``jax.named_scope`` paths, kernel
+    names, source lines — are what a profile of it shows.  With a key
+    blind to them, an executable cached by an older checkout comes back
+    under this one's programs with the old names (or none), and
+    ``mx.perf.op_names`` and every device trace read stale scopes.  The
+    price is a recompile after an edit that moves traced source lines."""
     import os
     import jax
     from jax.experimental.compilation_cache import compilation_cache as _cc
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = cache_root()
@@ -181,7 +191,18 @@ def scan_stack(body, carry, xs):
     in ``jax.checkpoint`` with the matching policy; '' applies no wrapper
     at all so default-knob programs stay byte-identical to the
     pre-tuning lowering.
+
+    The stack traces under the ``mx.layers`` name scope: what a device
+    profile shows there and under none of the layer's own scopes is the
+    stacking itself — slicing each layer's ``xs`` out and restacking its
+    ``ys`` (for the paged decode step, the K/V pool).
     """
+    import jax
+    with jax.named_scope("mx.layers"):
+        return _scan_stack(body, carry, xs)
+
+
+def _scan_stack(body, carry, xs):
     import jax
     from jax import lax
     mode, remat = stack_tuning()

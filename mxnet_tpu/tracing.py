@@ -12,16 +12,21 @@ and across which threads":
     so causality survives thread hops: the io.py prefetch worker runs
     under the context captured when the prefetcher started (see
     ``wrap_context``), and its spans carry the parent's ``trace_id``.
-    Every span also enters a ``jax.profiler.TraceAnnotation`` while a
-    device trace is active, so framework phases (fwd/bwd/opt-update/
-    prefetch/push/pull/allreduce) show up nested inside XLA's own profile.
+    Every span is ALSO a ``jax.profiler.TraceAnnotation``, entered
+    unconditionally: the profiler's own activity check decides, so a
+    session started by anyone (``mx.profiler.start``, a bare
+    ``jax.profiler.start_trace``, TensorBoard's capture) finds the
+    framework's phases on the host plane of its ``.xplane.pb``, on the
+    device trace's clock, with the span's keyword arguments as the
+    event's stats.
   * CHROME SINK — ``MXNET_TPU_TRACE=chrome:<path>`` (the ``tracing.sink``
     knob, same pattern as ``telemetry.sink``) streams finished spans as
     Chrome trace-event JSON ("array format": one event per line, so a
     killed job still leaves a loadable file — ``load_trace`` parses both
     complete and truncated traces).  ``tools/trace_merge.py`` aligns this
     host plane with the device-op plane from a jax.profiler capture into
-    one two-plane trace.
+    one two-plane trace (redundant since spans reach the profiler trace
+    by themselves; kept for the sink's users).
   * FLIGHT RECORDER + WATCHDOG — a bounded ring of the last K span/step
     events, plus ``MXNET_TPU_WATCHDOG=<secs>``: a daemon thread that,
     when no train step completes within the deadline, dumps all Python
@@ -30,9 +35,12 @@ and across which threads":
     report — then lets the job keep running.  A silent multi-host hang
     becomes a diagnosable artifact instead of a killed process.
 
-Near-zero overhead when off: ``span()`` returns a shared no-op object
-unless a sink, the watchdog, or a device trace is active — one function
-call and three reads on the hot path.
+Near-zero overhead when off: with no sink and no watchdog ``span()``
+returns a thin object that is only the profiler annotation — no ids, no
+open-span registry, no lock; with no profiler session either, entering
+and leaving it costs about a microsecond (PERF.md section 6, PR 25).
+Arguments must be values the caller already has: nothing is computed for
+a span that nobody records.
 """
 from __future__ import annotations
 
@@ -45,7 +53,7 @@ import time
 import traceback
 from collections import deque
 
-from . import profiler as _profiler
+import jax
 
 __all__ = ["span", "current_span", "wrap_context", "configure_sink",
            "configure_watchdog", "configure_ring", "enabled", "sink_path",
@@ -210,22 +218,18 @@ def ring_events():
 
 
 # ----------------------------------------------------------------- spans
-class _NoopSpan:
-    """Shared do-nothing span: the off-path cost of ``span()``."""
+class _Annotation(jax.profiler.TraceAnnotation):
+    """The off-path ``span()``: the profiler annotation and nothing else.
+    It has no identity (``trace_id`` None, as ``current_span()`` callers
+    expect of an untraced request) and is not the context's current span.
+    ``set(**args)`` adds arguments known only once the work is done."""
 
     __slots__ = ()
     trace_id = None
     span_id = None
     parent_id = None
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
+    set = jax.profiler.TraceAnnotation.set_metadata
 
 
 class Span:
@@ -245,6 +249,12 @@ class Span:
         self._token = None
         self._ann = None
 
+    def set(self, **args):
+        """Add arguments known only once the work is done."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __enter__(self):
         parent = _CURRENT.get()
         if parent is not None and parent.trace_id is not None:
@@ -258,14 +268,10 @@ class Span:
         self._token = _CURRENT.set(self)
         with _OPEN_LOCK:
             _OPEN[self.span_id] = self
-        if _profiler._STATE["running"]:
-            # nest the framework phase inside XLA's own device profile
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:  # noqa: BLE001 — device tracing unavailable
-                self._ann = None
+        # nest the framework phase inside the profiler's own trace; the
+        # profiler decides whether anyone is listening
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -275,12 +281,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
-            self._ann = None
+        self._ann.__exit__(None, None, None)
+        self._ann = None
         with _OPEN_LOCK:
             _OPEN.pop(self.span_id, None)
         _CURRENT.reset(self._token)
@@ -304,12 +306,13 @@ class Span:
 
 
 def span(name, cat="host", **args):
-    """Open a causal span.  Returns a shared no-op unless the Chrome sink,
-    the watchdog, or a device trace is active — the near-zero-overhead
-    contract for instrumented hot paths."""
-    if _SINK is None and _WD_DEADLINE is None \
-            and not _profiler._STATE["running"]:
-        return _NOOP
+    """Open a span.  It always reaches the profiler: with no Chrome sink
+    and no watchdog it IS the ``jax.profiler.TraceAnnotation`` (keyword
+    arguments become the event's stats) and nothing more; with either, the
+    causal :class:`Span` (ids, open-span registry, sink event) wraps the
+    same annotation."""
+    if _SINK is None and _WD_DEADLINE is None:
+        return _Annotation(name, **args)
     return Span(name, cat, args)
 
 
@@ -544,8 +547,11 @@ def dump_watchdog_report(stalled_s=None, path=None, stalls=None):
             + "_%03d" % int((time.time() % 1) * 1000)
         path = os.path.join(_WD_REPORT_DIR or ".",
                             "watchdog_report_%s.json" % stamp)
-    with open(path, "w") as f:
+    # whole or not there: a poller (an operator's script, the tests) that
+    # sees the name can read the report
+    with open(path + ".tmp", "w") as f:
         json.dump(report, f, indent=1, default=str)
+    os.replace(path + ".tmp", path)
     return path
 
 

@@ -12,3 +12,65 @@ def write_convergence_log(record):
     if out:
         with open(out, "a") as f:
             f.write(json.dumps(record) + "\n")
+
+
+def profiled_spans(run, trace_dir, prefixes):
+    """Run ``run()`` inside a profiler session started by
+    ``jax.profiler.start_trace`` directly (NOT ``mx.profiler.start``) and
+    return the host-plane events whose name starts with one of
+    ``prefixes``: ``[(name, start_ns, end_ns, {stat: value}, thread)]``."""
+    import glob
+    import os
+
+    import jax
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(tuple(prefixes)):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats), line.name))
+    return out
+
+
+def assert_spans_nest(spans, child, parent):
+    """Every ``child`` span lies inside a ``parent`` span of its thread."""
+    parents = [s for s in spans if s[0] == parent]
+    children = [s for s in spans if s[0] == child]
+    assert children and parents, (child, parent, sorted({s[0] for s in spans}))
+    for _, start, end, _, thread in children:
+        assert any(p[1] <= start and end <= p[2] and p[4] == thread
+                   for p in parents), (child, "outside every", parent)
+
+
+def lowered_with_and_without_scopes(lower, monkeypatch):
+    """``lower()`` -> a ``jax.stages.Lowered``.  Returns its text with
+    debug info (where ``jax.named_scope`` names show) after checking that
+    the program itself — the text without locations — is byte-identical to
+    the one lowered with every ``jax.named_scope`` a no-op: a scope is
+    metadata, it moves no operation and invalidates no executable."""
+    import contextlib
+
+    import jax
+    named = lower()
+
+    @contextlib.contextmanager
+    def no_scope(name):
+        yield
+
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "named_scope", no_scope)
+        bare = lower()
+    assert named.as_text() == bare.as_text()
+    assert "mx." not in bare.as_text(debug_info=True)
+    return named.as_text(debug_info=True)

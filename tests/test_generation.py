@@ -289,6 +289,151 @@ def test_report_render_includes_generation(capsys):
     assert "tokens/s" in out and "ttft_p50ms" in out
 
 
+# ------------------------------------- spans, scopes, per-request record
+ENGINE_SPANS = ("engine.iteration", "engine.admit", "engine.prefill",
+                "engine.prefill.device", "engine.prefill.fetch",
+                "engine.decode", "engine.decode.prepare",
+                "engine.decode.device", "engine.decode.fetch",
+                "engine.decode.emit")
+
+
+def _three_requests(pred, name="m"):
+    """Three requests of different lengths through a fresh engine; the
+    futures, resolved."""
+    eng = generation.GenerationEngine(name, pred, num_pages=16,
+                                      decode_slots=2).start()
+    try:
+        rng = np.random.default_rng(11)
+        futs = [eng.submit(rng.integers(0, VOCAB, size=plen).astype(np.int32),
+                           max_new)
+                for plen, max_new in ((3, 5), (7, 4), (4, 6))]
+        for f in futs:
+            f.result(timeout=60)
+    finally:
+        eng.stop()
+    return futs
+
+
+def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
+    """A session started by ``jax.profiler.start_trace`` alone (no sink,
+    no watchdog, no ``mx.profiler``) finds the engine loop's spans on the
+    host plane: children inside parents, the decode span carrying the
+    counts the per-layer metrics read, and as many ``engine.decode.device``
+    spans as the ``serving.decode_step_ms`` timer counted."""
+    from _util import assert_spans_nest, profiled_spans
+    prefix, _, _ = artifact
+    pred = deploy.load_generator(prefix)
+    telemetry.reset()
+    spans = profiled_spans(lambda: _three_requests(pred), tmp_path,
+                           ("engine.",))
+    names = {s[0] for s in spans}
+    assert set(ENGINE_SPANS) <= names, sorted(names)
+    for child, parent in (("engine.admit", "engine.iteration"),
+                          ("engine.prefill", "engine.iteration"),
+                          ("engine.decode", "engine.iteration"),
+                          ("engine.prefill.device", "engine.prefill"),
+                          ("engine.prefill.fetch", "engine.prefill.device"),
+                          ("engine.decode.fetch", "engine.decode.device"),
+                          ("engine.decode.prepare", "engine.decode"),
+                          ("engine.decode.device", "engine.decode"),
+                          ("engine.decode.emit", "engine.decode")):
+        assert_spans_nest(spans, child, parent)
+    decodes = [s[3] for s in spans if s[0] == "engine.decode"]
+    for args in decodes:
+        assert {"width", "rows", "held_tokens", "window_tokens"} <= set(args)
+        assert 0 < int(args["held_tokens"]) <= int(args["window_tokens"])
+        assert 1 <= int(args["rows"]) <= 2
+        assert int(args["window_tokens"]) == 2 * int(args["width"]) * PAGE
+    prefills = [s[3] for s in spans if s[0] == "engine.prefill"]
+    assert sorted(int(a["request_id"]) for a in prefills) == [1, 2, 3]
+    assert sorted(int(a["prompt_len"]) for a in prefills) == [3, 4, 7]
+    assert {int(a["bucket"]) for a in prefills} == {4, 8}
+    admits = [s[3] for s in spans if s[0] == "engine.admit"]
+    assert sum(int(a["admitted"]) for a in admits) == 3
+    emits = [s[3] for s in spans if s[0] == "engine.decode.emit"]
+    assert sum(int(a["finished"]) for a in emits) == 3
+    snap = telemetry.snapshot()["timers"]
+    assert snap["serving.decode_step_ms"]["count"] == sum(
+        s[0] == "engine.decode.device" for s in spans)
+    assert snap["serving.prefill_ms"]["count"] == sum(
+        s[0] == "engine.prefill.device" for s in spans) == 3
+
+
+def test_serving_generate_event_is_the_request_record(artifact, tmp_path):
+    """Tracing off: every request has the engine's own id — on the future
+    and in its ``serving_generate`` event — with its queue wait, prefill
+    time and one stamp per emitted token."""
+    from mxnet_tpu import tracing
+    prefix, _, _ = artifact
+    pred = deploy.load_generator(prefix)
+    assert not tracing.enabled()
+    telemetry.reset()
+    sink = str(tmp_path / "events.jsonl")
+    telemetry.configure_sink("jsonl:" + sink)
+    try:
+        futs = _three_requests(pred)
+    finally:
+        telemetry.configure_sink("")
+    with open(sink) as f:
+        events = [e for e in map(json.loads, f)
+                  if e.get("event") == "serving_generate"]
+    assert sorted(e["request_id"] for e in events) == [1, 2, 3]
+    assert sorted(f.request_id for f in futs) == [1, 2, 3]
+    by_id = {e["request_id"]: e for e in events}
+    for fut in futs:
+        ev = by_id[fut.request_id]
+        assert ev["trace_id"] is None
+        assert ev["new_tokens"] == len(fut.result())
+        assert ev["queue_ms"] >= 0 and ev["prefill_ms"] > 0
+        stamps = ev["token_ms"]
+        assert len(stamps) == ev["new_tokens"]
+        assert all(a < b for a, b in zip(stamps, stamps[1:])), stamps
+        assert stamps[0] == ev["ttft_ms"]
+        assert ev["queue_ms"] + ev["prefill_ms"] <= ev["ttft_ms"] + 1e-3
+        assert stamps[-1] <= ev["wall_ms"] + 1e-3
+    timers = telemetry.snapshot()["timers"]
+    assert timers["serving.queue_ms"]["count"] == 3
+    # one gap per decoded token: every token but each request's first
+    assert timers["serving.token_gap_ms"]["count"] == sum(
+        e["new_tokens"] - 1 for e in events)
+
+
+@pytest.mark.parametrize("program, scopes", [
+    ("decode", ("mx.layers", "mx.qkv", "mx.kv_write", "mx.kv_gather",
+                "mx.paged_attention", "mx.attn_out", "mx.mlp",
+                "mx.lm_head", "mx.sample")),
+    ("prefill", ("mx.layers", "mx.qkv", "mx.kv_write", "mx.attention",
+                 "mx.attn_out", "mx.mlp", "mx.lm_head", "mx.sample")),
+])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32kv", "int8kv"])
+def test_generation_programs_carry_scopes_as_metadata_only(
+        program, scopes, quantized, monkeypatch):
+    """The decode step and the prefill name their layers with
+    ``jax.named_scope`` — and are, locations aside, the programs they were
+    without the names."""
+    import jax
+    import jax.numpy as jnp
+    from _util import lowered_with_and_without_scopes
+    model, params = _tiny_lm()
+    kv = model.init_kv_pages(8, PAGE, quantized=quantized)
+    i32 = jnp.int32
+
+    def lower():
+        if program == "decode":
+            return jax.jit(lambda p, c, t, pos, tab: model.decode_step(
+                p, c, t, pos, tab, PAGE)).lower(
+                    params, kv, jnp.zeros((4,), i32), jnp.zeros((4,), i32),
+                    jnp.zeros((4, 2), i32))
+        return jax.jit(lambda p, c, t, n, tab: model.prefill(
+            p, c, t, n, tab, PAGE)).lower(
+                params, kv, jnp.zeros((1, 8), i32), jnp.ones((1,), i32),
+                jnp.zeros((1, 2), i32))
+
+    text = lowered_with_and_without_scopes(lower, monkeypatch)
+    for scope in scopes:
+        assert scope + "/" in text or scope + '"' in text, scope
+
+
 # ------------------------------------------------------- smoke wrapper
 
 def test_check_generation_smoke():

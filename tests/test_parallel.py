@@ -444,3 +444,81 @@ def test_ring_attention_gradient_matches_full():
         for a, b in zip(g_full, g_ring):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=3e-5, rtol=3e-5)
+
+
+# ------------------------------------------------- spans and device names
+def _small_trainer(optimizer="sgd", mesh=None):
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.gluon.loss import L2Loss
+    net = nn.HybridSequential(prefix="net_")
+    with net.name_scope():
+        net.add(nn.Dense(8, activation="relu", in_units=4), nn.Dense(1))
+    net.initialize(mx.init.Xavier())
+    rng = np.random.RandomState(1)
+    x = rng.uniform(-1, 1, size=(16, 4)).astype(np.float32)
+    y = x.sum(axis=1, keepdims=True).astype(np.float32)
+    kw = {"learning_rate": 0.01}
+    if optimizer == "sgd":
+        kw["momentum"] = 0.9
+    return SPMDTrainer(net, L2Loss(), optimizer, kw, mesh=mesh), x, y
+
+
+def test_trainer_spans_reach_a_bare_profiler_session(tmp_path):
+    """``jax.profiler.start_trace`` alone finds ``spmd.step`` and its four
+    children on the host plane, one of each per step."""
+    from _util import assert_spans_nest, profiled_spans
+    tr, x, y = _small_trainer()
+    tr.step(x, y)       # compile outside the session
+
+    def run():
+        for _ in range(3):
+            tr.step(x, y)
+        jax.block_until_ready(tr.params)
+
+    spans = profiled_spans(run, tmp_path, ("spmd.",))
+    children = ("spmd.shard_batch", "spmd.prepare", "spmd.dispatch",
+                "spmd.post")
+    for child in children:
+        assert_spans_nest(spans, child, "spmd.step")
+    for name in ("spmd.step",) + children:
+        assert sum(s[0] == name for s in spans) == 3, name
+    steps = sorted(int(s[3]["step"]) for s in spans if s[0] == "spmd.step")
+    assert steps == [2, 3, 4]
+    # the children come in the order of the work
+    one = sorted((s for s in spans if s[0] in children),
+                 key=lambda s: s[1])[:4]
+    assert tuple(s[0] for s in one) == children
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+@pytest.mark.parametrize("devices", [1, 2], ids=["one", "dp2"])
+def test_step_program_carries_scopes_as_metadata_only(optimizer, devices,
+                                                      monkeypatch):
+    """The step program names its forward (the backward then reads
+    ``transpose(jvp(mx.forward))``), its optimizer epilogue and the Gluon
+    blocks it inlines — and is, locations aside, the program it was
+    without the names."""
+    from _util import lowered_with_and_without_scopes
+    from mxnet_tpu import perf
+    tr, x, y = _small_trainer(
+        optimizer, data_parallel_mesh(jax.devices()[:devices]))
+    captured = []
+    capture = perf.PerfProgram._capture
+    monkeypatch.setattr(
+        perf.PerfProgram, "_capture",
+        lambda self, args: captured.append((self, args))
+        or capture(self, args))
+
+    def lower():
+        # a fresh build each time: a cached trace would keep its names
+        tr._jitted.clear()
+        del captured[:]
+        tr.step(x, y)
+        (prog, args), = captured
+        return prog.fn.lower(*args)
+
+    text = lowered_with_and_without_scopes(lower, monkeypatch)
+    for scope in ("jvp(mx.forward)/", "transpose(jvp(mx.forward))/",
+                  "mx.opt_update/", "jvp(mx.forward)/net_dense0/",
+                  "transpose(jvp(mx.forward))/net_dense1/"):
+        assert scope in text, scope

@@ -216,6 +216,67 @@ ENTRY main {
     assert counts == {"elementwise": 1, "matmul": 1}, counts
 
 
+def test_hlo_op_names_joins_instructions_to_name_paths():
+    """What a reader of a device trace joins event names with: every
+    instruction that runs as an operation of its own, with the name path
+    jax recorded for it.  Fusion bodies are left out; a fusion jax named
+    nothing takes its root's name; what nobody named is absent."""
+    text = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.3 (param_0: f32[8,4]) -> f32[8,4] {
+  %param_0 = f32[8,4]{1,0} parameter(0)
+  ROOT %mul.7 = f32[8,4]{1,0} multiply(%param_0, %param_0), metadata={op_name="jit(step)/mx.opt_update/mul" stack_frame_id=2}
+}
+
+%body.1 (arg: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+  %arg = (s32[], f32[8,4]{1,0}) parameter(0)
+  %mx_paged_attention.4 = f32[8,4]{1,0} custom-call(f32[8,4]{1,0} %x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/mx.layers/while/body/mx.paged_attention/mx_paged_attention/pallas_call"}
+  ROOT %tuple.2 = (s32[], f32[8,4]{1,0}) tuple(%i, %mx_paged_attention.4)
+}
+
+ENTRY %main.9 (p: f32[8,4]) -> f32[8,4] {
+  %p = f32[8,4]{1,0} parameter(0), metadata={op_name="x"}
+  %while.2 = (s32[], f32[8,4]{1,0}) while(%t), condition=%cond.1, body=%body.1, metadata={op_name="jit(step)/mx.layers/while"}
+  %copy.5 = f32[8,4]{0,1} copy(f32[8,4]{1,0} %p)
+  ROOT %fusion.11 = f32[8,4]{1,0} fusion(f32[8,4]{1,0} %p), kind=kLoop, calls=%fused_computation.3
+}
+"""
+    table = perf.hlo_op_names(text)
+    assert table["module"] == "jit_step"
+    assert table["ops"] == {
+        "mx_paged_attention.4": [
+            "f32[8,4]{1,0}",
+            "jit(step)/mx.layers/while/body/mx.paged_attention/"
+            "mx_paged_attention/pallas_call"],
+        "p": ["f32[8,4]{1,0}", "x"],
+        "while.2": ["(s32[], f32[8,4]{1,0})", "jit(step)/mx.layers/while"],
+        "fusion.11": ["f32[8,4]{1,0}", "jit(step)/mx.opt_update/mul"]}
+    assert perf.hlo_op_names("") == {"module": None, "ops": {}}
+
+
+def test_op_names_of_registered_programs():
+    """``mx.perf.op_names()``: per registered program its table with
+    family, key and HLO module name — and the ``jax.named_scope`` names of
+    the program in it; kept out of ``programs()`` and ``export()``."""
+    def step(w, x):
+        with jax.named_scope("mx.forward"):
+            y = jnp.tanh(x @ w)
+        with jax.named_scope("mx.opt_update"):
+            return w - 0.1 * y.sum()
+
+    args = (jnp.ones((4, 4), jnp.float32), jnp.ones((2, 4), jnp.float32))
+    perf.register_compiled("spmd", "named",
+                           jax.jit(step).trace(*args).lower().compile())
+    (table,) = perf.op_names("spmd")
+    assert (table["family"], table["key"]) == ("spmd", "named")
+    assert table["module"] == "jit_step"
+    paths = " ".join(path for _, path in table["ops"].values())
+    assert "mx.forward/" in paths and "mx.opt_update/" in paths
+    assert perf.op_names("serving") == []
+    assert "_op_names" not in perf.program("spmd", "named")
+    assert "_op_names" not in json.dumps(perf.export())
+
+
 # ------------------------------------------------------- wrapper semantics
 def test_wrap_bitwise_noop():
     """Wrapped dispatch must be byte-identical to the plain jit path —

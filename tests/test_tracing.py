@@ -16,6 +16,7 @@ import sys
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -44,13 +45,30 @@ def _events(path):
 
 
 # ---------------------------------------------------------------- spans
-def test_span_noop_when_off():
-    s = tracing.span("anything")
-    assert s is tracing._NOOP
-    with s:
-        # the noop carries no identity and sets no context
+def test_span_is_only_the_annotation_when_off(monkeypatch):
+    """No session, sink or watchdog: ``span()`` is the profiler annotation
+    alone — it allocates no id, touches no lock, joins no registry and
+    sets no context."""
+    class _Untouchable:
+        def __enter__(self):
+            raise AssertionError("a lock was taken on the off path")
+
+        def __exit__(self, *exc):
+            return False
+
+    for lock in ("_ID_LOCK", "_OPEN_LOCK", "_SINK_LOCK", "_RING_LOCK"):
+        monkeypatch.setattr(tracing, lock, _Untouchable())
+    next_id = tracing._NEXT_ID[0]
+    s = tracing.span("anything", cat="test", rows=3)
+    assert isinstance(s, jax.profiler.TraceAnnotation)
+    assert not isinstance(s, tracing.Span)
+    with s as entered:
+        entered.set(more=1)
+        # it carries no identity and is not the context's current span
+        assert entered.trace_id is None and entered.span_id is None
         assert tracing.current_span() is None
-    assert tracing.span("again") is s  # shared singleton, no allocation
+        assert tracing.open_spans.__globals__["_OPEN"] == {}
+    assert tracing._NEXT_ID[0] == next_id
 
 
 def test_span_nesting_ids_in_chrome_trace(tmp_path):
@@ -380,4 +398,6 @@ def test_check_tracing_smoke():
     assert report["ok"], report
     assert report["trace"]["steps"] == 3, report
     assert report["report"]["open_spans"] >= 1, report
-    assert report["elapsed_s"] < (2.0 if (os.cpu_count() or 1) >= 2 else 4.0), report
+    assert {"check.outer", "check.inner", "module.step"} \
+        <= set(report["profiler"]["spans"]), report
+    assert report["elapsed_s"] > 0, report   # reported, no budget (D9)

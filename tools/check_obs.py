@@ -1,5 +1,5 @@
-"""Fast CPU smoke for the mx.obs operational plane (< 5s on a >=2-core
-box; a single-core runner compiles serially and gets a doubled budget).
+"""Fast CPU smoke for the mx.obs operational plane (about 5s on an idle
+>=2-core box; ``elapsed_s`` is reported, not asserted).
 
 Proves the exporter + access log + SLO tracker end-to-end on the host
 backend, with one parseable JSON line on stdout:
@@ -68,11 +68,6 @@ VOCAB = 89
 MAX_CONTEXT = 16
 OVERHEAD_RECORDS = 20000
 OVERHEAD_LIMIT_PCT = 2.0
-# The wall-clock contract is calibrated for the normal >=2-core CI box
-# (~4s measured).  A single-core runner pays every XLA compile serially
-# (the generation plane alone costs ~3s of backend_compile) and gets 2x.
-BUDGET_S = 5.0 if (os.cpu_count() or 1) >= 2 else 10.0
-
 _SAMPLE_RE = re.compile(
     r"^([a-zA-Z_:][a-zA-Z0-9_:]*)(?:\{([^}]*)\})? (\S+)$")
 
@@ -347,8 +342,14 @@ def main():
                     if isinstance(e.get("args"), dict)
                     and "trace_id" in e["args"]}
         assert len(served) == expect_ok + 2, len(served)
+        # a one-shot record's request_id IS its submit span's trace_id; a
+        # generation record carries the engine's own request number and
+        # the span's trace_id beside it
+        assert all(r["request_id"].isdigit() and "trace_id" in r
+                   for r in gen_recs), gen_recs
+        assert len({r["request_id"] for r in gen_recs}) == len(gen_recs)
         orphans = [r["request_id"] for r in served
-                   if r["request_id"] not in span_ids]
+                   if r.get("trace_id", r["request_id"]) not in span_ids]
         assert not orphans, \
             "access records with no Chrome-trace span: %s" % orphans[:5]
         result["access"] = {"records": len(records), "outcomes": tally,
@@ -381,10 +382,9 @@ def main():
         result["slo"] = {"fast_page_burn": 20.0, "window_split": burn}
 
         srv.stop()
+        # reported, not asserted: a loaded machine is not a fault of the
+        # pipeline (ROADMAP D9)
         result["elapsed_s"] = round(time.perf_counter() - t_main, 3)
-        assert result["elapsed_s"] < BUDGET_S, \
-            "smoke exceeded the %.0fs budget: %.3fs" \
-            % (BUDGET_S, result["elapsed_s"])
         result["ok"] = True
     except Exception as exc:  # noqa: BLE001 — the JSON line IS the report
         result["error"] = "%s: %s" % (type(exc).__name__, exc)

@@ -92,9 +92,16 @@ def main():
             [r["compiles"] for r in steps]
         assert [r["step"] for r in steps] == list(range(1, STEPS + 1))
 
-        # 2. report: clean fixed-shape run flags nothing
+        # 2. report: a clean fixed-shape run flags nothing it counts
+        # (recompiles, sync transfers).  What it times — a p99 against a
+        # p50 over twenty sub-millisecond steps — is the machine's load,
+        # not the pipeline's: reported, not asserted (ROADMAP D9)
         summary = telemetry_report.summarize(records)
-        assert summary["anomalies"] == [], summary["anomalies"]
+        timed = ("latency_blowup", "falling_throughput", "mfu_collapse")
+        result["timing_anomalies"] = [a for a in summary["anomalies"]
+                                      if a["kind"] in timed]
+        counted = [a for a in summary["anomalies"] if a["kind"] not in timed]
+        assert counted == [], counted
         assert summary["sources"]["module"]["steps"] == STEPS
         result["summary"] = summary["sources"]["module"]
 
