@@ -1103,15 +1103,6 @@ def generation_config(runs_out, requests):
                              page_size=PAGE, max_context=CTX,
                              prompt_buckets=(32,))
     opt_prefix = os.path.join(gen_dir, "opt")
-    # measure the decode site's block_bh first so the explicit-kernel
-    # export bakes the tuned block (the default conservative block pays
-    # one grid step per 2 rows — real overhead at decode_batch=8)
-    from mxnet_tpu import autotune as _autotune
-    W2 = math.ceil(CTX / PAGE)
-    _autotune.search_paged(
-        (SLOTS2, spec["num_heads"], 1, spec["head_dim"]),
-        (SLOTS2, spec["num_heads"], W2 * PAGE, spec["head_dim"]),
-        "float32", True)
     mx.config.set("kernels.enabled", True)
     try:
         deploy.export_generation(model, params, opt_prefix,

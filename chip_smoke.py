@@ -509,25 +509,35 @@ def phase_kernels(args, sz):
 
     cfg = _lm(sz).cfg
     B, H, D = sz["decode_batch"], cfg.num_heads, cfg.head_dim
+    psz = 16
     for K in sz["paged_k"]:
+        # rows of ragged lengths up to K tokens, their pages scattered
+        # through a pool half again as large; one row is an idle slot
+        # (all sentinel, one position)
+        W = K // psz
+        pool = B * W * 3 // 2
         lens = rng.randint(1, K + 1, (B,))
-        valid = jnp.asarray(np.arange(K)[None, :] < lens[:, None])
+        table = rng.permutation(pool)[:B * W].reshape(B, W)
+        table[np.arange(W)[None, :] * psz >= lens[:, None]] = pool
+        table[-1], lens[-1] = pool, 1
+        table, lens = jnp.asarray(table, jnp.int32), \
+            jnp.asarray(lens, jnp.int32)
+        wide = (pool, psz, H * D)
         for dt in (f32, bf16):
-            q, k, v = rand((B, H, 1, D), dt), rand((B, H, K, D), dt), \
-                rand((B, H, K, D), dt)
             check("paged_%s/K=%d" % (jnp.dtype(dt).name, K),
                   pk.pallas_paged_attention, kernels._paged_attention_xla,
-                  (q, k, v, valid), MXU)
-        k8, v8 = (jnp.asarray(rng.randint(-127, 128, (B, H, K, D)), jnp.int8)
+                  (rand((B, H, 1, D), dt), rand(wide, dt), rand(wide, dt),
+                   table, lens), MXU)
+        k8, v8 = (jnp.asarray(rng.randint(-127, 128, wide), jnp.int8)
                   for _ in range(2))
-        ks, vs = (jnp.asarray(rng.uniform(1e-3, 2e-2, (B, H, K)), f32)
+        ks, vs = (jnp.asarray(rng.uniform(1e-3, 2e-2, wide[:2] + (H,)), f32)
                   for _ in range(2))
         check("paged_int8/K=%d" % K,
-              lambda q, k, v, m, ks, vs: pk.pallas_paged_attention(
-                  q, k, v, m, k_scale=ks, v_scale=vs),
-              lambda q, k, v, m, ks, vs: kernels._paged_attention_xla(
-                  q, k, v, m, k_scale=ks, v_scale=vs),
-              (rand((B, H, 1, D), bf16), k8, v8, valid, ks, vs), MXU)
+              lambda q, k, v, t, n, ks, vs: pk.pallas_paged_attention(
+                  q, k, v, t, n, k_scale=ks, v_scale=vs),
+              lambda q, k, v, t, n, ks, vs: kernels._paged_attention_xla(
+                  q, k, v, t, n, k_scale=ks, v_scale=vs),
+              (rand((B, H, 1, D), bf16), k8, v8, table, lens, ks, vs), MXU)
 
     w, g, m1 = (rand(sz["epilogue"], f32) for _ in range(3))
     m2 = rand(sz["epilogue"], f32, lo=0.0)

@@ -63,8 +63,8 @@ from . import telemetry as _telemetry
 
 __all__ = ["enabled", "mode", "cache_path", "config_fingerprint",
            "generation", "reset", "lookup", "record", "attention_pick",
-           "paged_pick", "fused_step_pick", "stack_pick",
-           "search_attention", "search_paged", "search_fused",
+           "fused_step_pick", "stack_pick",
+           "search_attention", "search_fused",
            "search_step", "search_stack",
            "export_entries", "CACHE_VERSION"]
 
@@ -450,124 +450,6 @@ def attention_pick(q_shape, kv_shape, dtype, causal, scale=None):
         # still applies when the search measured one
         return {"impl": "flash", "block_q": int(pick.get("block_q")
                                                 or 128)}
-    return pick
-
-
-# ------------------------------------------------- paged-attention search
-def _paged_candidates(BH, K, D, kv_itemsize, quantized):
-    """Deduplicated effective ``block_bh`` candidates for a BH-row paged
-    decode: bases snapped through the ``_row_block`` divisor walk (the
-    row axis is a leading block dim, so any divisor is a legal block),
-    none larger than the kernel's own VMEM-budget pick — a compile
-    failure is an error, so the search only tries blocks that fit."""
-    from .ops.pallas_kernels import _row_block, paged_block_rows
-    top = paged_block_rows(BH, K, D, kv_itemsize, quantized)
-    return sorted({_row_block(BH, 1, budget=min(b, top), align=1)
-                   for b in (1, 2, 4, 8, 16, top)})
-
-
-def _paged_site(q_shape, kv_shape, quantized):
-    B, H, Sq, D = q_shape
-    return "paged/b%d/h%d/k%d/d%d/quant=%d" % (
-        B, H, kv_shape[2], D, int(quantized))
-
-
-@_eager
-def search_paged(q_shape, kv_shape, dtype, quantized, scale=None):
-    """Measure the Pallas paged-attention kernel over its ``block_bh``
-    candidates against the XLA page-gather lowering at one decode site;
-    persist and return the winner.  Same gate as the flash search:
-    parity (bitwise-or-tolerance) AND speedup >= 1.0x, losers fall back
-    to XLA permanently; a candidate that cannot compile raises.
-    ``quantized`` sites measure with synthetic int8
-    K/V pages + per-row scales — the operands the serve path gathers."""
-    import numpy as np
-    from .kernels import _paged_attention_xla
-    from .ops.pallas_kernels import pallas_paged_attention
-    B, H, Sq, D = q_shape
-    K = kv_shape[2]
-    site = _paged_site(q_shape, kv_shape, quantized)
-    q = _synth(q_shape, dtype)
-    rng = np.random.RandomState(1)
-    import jax.numpy as jnp
-    if quantized:
-        k = jnp.asarray(rng.randint(-127, 128, kv_shape), jnp.int8)
-        v = jnp.asarray(rng.randint(-127, 128, kv_shape), jnp.int8)
-        ks = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, H, K)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, H, K)), jnp.float32)
-    else:
-        k = _synth(kv_shape, dtype)
-        v = _synth(kv_shape, dtype)
-        ks = vs = None
-    # a realistic decode mask: ragged lengths, never empty
-    lens = rng.randint(1, K + 1, (B,))
-    valid = jnp.asarray(np.arange(K)[None, :] < lens[:, None])
-
-    def xla_fn(q, k, v, valid):
-        return _paged_attention_xla(q, k, v, valid, scale=scale,
-                                    k_scale=ks, v_scale=vs)
-
-    entry = {"impl": "xla", "site": site, "quantized": bool(quantized)}
-    base_ms = _measure_ms(xla_fn, (q, k, v, valid))
-    import jax
-    jit_ref = jax.jit(xla_fn)  # parity reference: jit-vs-jit only
-    ref = jit_ref(q, k, v, valid)
-    cands = {}
-    best_bb, best_ms, best_parity = None, None, None
-    for bb in _paged_candidates(B * H, K, D, k.dtype.itemsize, quantized):
-        paged_fn = functools.partial(pallas_paged_attention,
-                                     scale=scale, k_scale=ks,
-                                     v_scale=vs, block_bh=bb)
-        ms = _measure_ms(paged_fn, (q, k, v, valid))
-        jit_cand = jax.jit(paged_fn)
-        par = _parity(jit_cand(q, k, v, valid), ref, dtype)
-        cands["paged/bh=%d" % bb] = round(ms, 4)
-        if par is None:
-            continue
-        if best_ms is None or ms < best_ms:
-            best_bb, best_ms, best_parity = bb, ms, par
-    entry.update(baseline_ms=round(base_ms, 4), candidates=cands)
-    if best_bb is not None:
-        entry.update(block_bh=best_bb, best_ms=round(best_ms, 4),
-                     parity=best_parity,
-                     speedup=round(base_ms / best_ms, 4))
-        if best_ms <= base_ms:
-            entry["impl"] = "paged"
-        else:
-            entry["reason"] = "slower than XLA lowering"
-    else:
-        entry["reason"] = "no candidate passed parity"
-    return record("paged", site, dtype, entry)
-
-
-def paged_pick(q_shape, kv_shape, dtype, quantized, scale=None):
-    """Trace-time pick for one paged-attention decode site (consumed by
-    ``mx.kernels.paged_attention``).  Mirrors ``attention_pick``: None =
-    no autotune opinion (kernel wherever feasible); default-source knob
-    on an interpreted backend statically routes to XLA; an explicit
-    ``kernels.enabled`` forces the kernel with the tuned ``block_bh``
-    when the search measured one."""
-    if not enabled():
-        return None
-    explicit = _config.source("kernels.enabled") != "default"
-    site = _paged_site(tuple(q_shape), tuple(kv_shape), quantized)
-    dtype = str(dtype)
-    pick = lookup("paged", site, dtype)
-    if pick is None:
-        if mode() == "auto" and _interpreted():
-            if explicit:
-                return None
-            pick = _remember("paged", site, dtype,
-                             {"impl": "xla", "reason": "interpreted",
-                              "static": True})
-        else:
-            pick = search_paged(tuple(q_shape), tuple(kv_shape),
-                                dtype, quantized, scale)
-    if explicit and pick.get("impl") != "paged":
-        forced = {"impl": "paged"}
-        if pick.get("block_bh"):
-            forced["block_bh"] = int(pick["block_bh"])
-        return forced
     return pick
 
 

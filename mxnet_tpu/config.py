@@ -822,7 +822,8 @@ register_knob(
     "(ops/pallas_kernels.py _row_block): block row counts are the "
     "largest divisor of n_rows whose block fits the budget; flash "
     "attention also checks one head's full K/V against it before "
-    "engaging. Must be > 0; ~16MB/core is the hardware ceiling, the "
+    "engaging, and the paged decode kernel sizes its double-buffered "
+    "K/V page tiles to it (128 tokens at most). Must be > 0; ~16MB/core is the hardware ceiling, the "
     "2MB default leaves headroom for double buffering.")
 
 

@@ -63,10 +63,15 @@ Format history (``meta["format_version"]``):
       ``quant.error_budget`` knob, not the bitwise oracle).  A concrete
       ``decode_batch`` pins the decode batch dim so the Pallas
       paged-attention kernel (mx.kernels routing) can bake into the
-      decode programs — the routing verdict per width lands in
-      ``meta["paged"]`` at export, since an AOT artifact can never
-      re-route at serve time.  v4 artifacts keep loading through the
-      same ``load_generator`` with greedy-only semantics.
+      decode programs: its grid walks the batch.  The pool's page count
+      stays symbolic there too (Pallas' dynamic-shape export) — the
+      routing verdict per width lands in ``meta["paged"]`` at export,
+      since an AOT artifact can never re-route at serve time.  v4
+      artifacts keep loading through the same ``load_generator`` with
+      greedy-only semantics.  The pool keeps a page as ``[page_size,
+      heads*head_dim]`` rows (``meta["kv"]["row_width"]``); an artifact
+      of a build whose pages were ``[page_size, heads, head_dim]`` is
+      refused at load, in words.
 """
 from __future__ import annotations
 
@@ -377,6 +382,25 @@ _KV_KEYS = ("k", "v")
 _KV_KEYS_QUANT = ("k", "v", "k_scale", "v_scale")
 
 
+def _kv_pool_specs(kv, num_pages):
+    """ShapeDtypeStructs of the page pool a ``meta["kv"]`` dict describes,
+    in ``_KV_KEYS`` order: K and V ``[L, num_pages, page_size, H*Dh]``
+    (a page is one lane-exact, contiguous block on the device), plus the
+    ``[L, num_pages, page_size, H]`` f32 scale pools of an int8 pool.
+    ``num_pages`` may be a symbolic dimension."""
+    import jax
+    import jax.numpy as jnp
+    rows = (kv["num_layers"], num_pages, kv["page_size"])
+    wide = rows + (kv["row_width"],)
+    if kv.get("quantized"):
+        return (jax.ShapeDtypeStruct(wide, jnp.int8),
+                jax.ShapeDtypeStruct(wide, jnp.int8),
+                jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32),
+                jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32))
+    dt = jnp.dtype(kv["dtype"])
+    return (jax.ShapeDtypeStruct(wide, dt), jax.ShapeDtypeStruct(wide, dt))
+
+
 def export_generation(model, params, prefix, page_size=None,
                       max_context=None, prompt_buckets=None,
                       include_params=True, sampling=False,
@@ -402,8 +426,14 @@ def export_generation(model, params, prefix, page_size=None,
     int8 payload + per-row f32 scale pools (half the HBM per token);
     ``decode_batch`` pins the decode programs' batch dim to a CONCRETE
     size so trace-time kernel routing (``mx.kernels.paged_attention``)
-    can bake the Pallas paged kernel in — the per-width routing verdict
-    is recorded in ``meta["paged"]``.  Returns the list of written
+    can bake the Pallas paged kernel in.  That is all it bakes: the
+    kernel takes each layer's page pool whole, and the pool's page count
+    stays symbolic inside it (the decode programs are exported under
+    Pallas' dynamic-shape lowering), so ``serving.kv_pages`` remains the
+    server's choice.  A decode program that cannot take the kernel —
+    symbolic batch, tier off, a jax without that lowering — runs the XLA
+    twin, and either way the per-width verdict and its reason are
+    recorded in ``meta["paged"]``.  Returns the list of written
     paths."""
     import jax
     from jax import export as jexport
@@ -446,14 +476,14 @@ def export_generation(model, params, prefix, page_size=None,
     pspec = jax.tree_util.tree_map(
         lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), param_tree)
     spec = model.kv_spec(quantized=kv_quantized) if v5 else model.kv_spec()
-    L, H, Dh = spec["num_layers"], spec["num_heads"], spec["head_dim"]
-    kv_dtype = jnp.dtype(spec["dtype"])
 
     paths = []
     paged_routes = {}
 
     def _export_one(fn, arg_specs, path, route_key=None):
-        with _kernels.record_paged_routes() as routes:
+        # the pool's page count stays symbolic inside the paged kernel too
+        with _kernels.record_paged_routes() as routes, \
+                _kernels.pallas_dynamic_shapes():
             exp = jexport.export(jax.jit(fn))(*arg_specs)
         if route_key is not None:
             # one paged_attention route per scanned stack trace; the scan
@@ -473,14 +503,7 @@ def export_generation(model, params, prefix, page_size=None,
         return b, p
 
     def _kv_specs(p):
-        shape = (L, p, psz, H, Dh)
-        if kv_quantized:
-            return (jax.ShapeDtypeStruct(shape, jnp.int8),
-                    jax.ShapeDtypeStruct(shape, jnp.int8),
-                    jax.ShapeDtypeStruct(shape[:-1], jnp.float32),
-                    jax.ShapeDtypeStruct(shape[:-1], jnp.float32))
-        return (jax.ShapeDtypeStruct(shape, kv_dtype),
-                jax.ShapeDtypeStruct(shape, kv_dtype))
+        return _kv_pool_specs(dict(spec, page_size=psz), p)
 
     i32 = jnp.int32
 
@@ -615,6 +638,12 @@ class GenerationPredictor:
                 "deploy.load_model(prefix) — load_generator only accepts "
                 "v4 artifacts written by deploy.export_generation"
                 % (prefix, self.format_version))
+        if "row_width" not in self.meta["kv"]:
+            raise ValueError(
+                "artifact %r keeps its K/V pages as [page_size, heads, "
+                "head_dim], the layout of an older build; this build's "
+                "programs take [page_size, heads*head_dim] rows — export "
+                "it again with deploy.export_generation" % (prefix,))
         self.page_size = int(self.meta["kv"]["page_size"])
         self.max_context = int(self.meta["max_context"])
         self.prompt_buckets = tuple(self.meta["prompt_buckets"])
@@ -725,33 +754,13 @@ class GenerationPredictor:
         ``(k, v)`` or, for int8-KV artifacts, ``(k, v, k_scale,
         v_scale)`` (int8 payloads + per-row f32 scales)."""
         import jax.numpy as jnp
-        kv = self.meta["kv"]
-        shape = (kv["num_layers"], int(num_pages), self.page_size,
-                 kv["num_heads"], kv["head_dim"])
-        if self.kv_quantized:
-            return (jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(shape[:-1], jnp.float32),
-                    jnp.zeros(shape[:-1], jnp.float32))
-        dt = jnp.dtype(kv["dtype"])
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+        return tuple(jnp.zeros(s.shape, s.dtype)
+                     for s in self.kv_pool_specs(num_pages))
 
     def kv_pool_specs(self, num_pages):
         """ShapeDtypeStruct tuple matching :meth:`make_kv` — what the
         serving engine AOT-traces its programs against."""
-        import jax
-        import jax.numpy as jnp
-        kv = self.meta["kv"]
-        shape = (kv["num_layers"], int(num_pages), self.page_size,
-                 kv["num_heads"], kv["head_dim"])
-        if self.kv_quantized:
-            return (jax.ShapeDtypeStruct(shape, jnp.int8),
-                    jax.ShapeDtypeStruct(shape, jnp.int8),
-                    jax.ShapeDtypeStruct(shape[:-1], jnp.float32),
-                    jax.ShapeDtypeStruct(shape[:-1], jnp.float32))
-        dt = jnp.dtype(kv["dtype"])
-        return (jax.ShapeDtypeStruct(shape, dt),
-                jax.ShapeDtypeStruct(shape, dt))
+        return _kv_pool_specs(self.meta["kv"], int(num_pages))
 
     def sample_arrays(self, temperature, top_k, top_p, seeds):
         """Host-side per-row sampling operand build: lists/arrays of

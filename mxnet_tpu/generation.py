@@ -886,9 +886,16 @@ class GenerationEngine:
                 k = min(width, len(s.pages))
                 table[i, :k] = s.pages[:k]
             temp, tk, tp, keys = self._sample_arrays(active, B)
-        # tokens the rows hold against tokens the program attends over
-        sp.set(held_tokens=int(positions.sum()),
-               window_tokens=B * width * gp.page_size)
+        # tokens the rows hold against token slots the routed program
+        # reads: each active row's own pages where the kernel reads them
+        # in place, the whole table's window where the twin gathers it
+        psz = gp.page_size
+        route = gp.paged_routes.get(str(width))
+        if route is not None and route.get("impl") == "paged":
+            window = sum(-(-(s.pos + 1) // psz) for _, s in active) * psz
+        else:
+            window = B * width * psz
+        sp.set(held_tokens=int(positions.sum()), window_tokens=window)
         # the span has the bounds of the serving.decode_step_ms timer
         with _tracing.span("engine.decode.device", cat="serving"):
             t0 = _time.perf_counter()
@@ -907,7 +914,6 @@ class GenerationEngine:
             breaker.record_success()
         _telemetry.timer("serving.decode_step_ms").observe(
             (t1 - t0) * 1e3)
-        route = gp.paged_routes.get(str(width))
         if route is not None:
             # serve-side mirror of the export-time routing verdict: every
             # decode iteration that ran through the Pallas paged kernel

@@ -45,7 +45,8 @@ from .ops.pallas_kernels import (flash_attention, fused_adam_step,
 
 __all__ = ["enabled", "attention", "paged_attention",
            "flash_unsupported_reason", "paged_unsupported_reason",
-           "record_paged_routes", "fused_step_enabled",
+           "record_paged_routes", "pallas_dynamic_shapes",
+           "fused_step_enabled",
            "flash_attention", "pallas_paged_attention",
            "fused_sgd_step", "fused_adam_step", "measure"]
 
@@ -151,50 +152,81 @@ def attention(q, k, v, causal=False, scale=None):
     return _xla_attention(q, k, v, causal=causal, scale=scale)
 
 
-def paged_unsupported_reason(q, k, v, valid, quantized=False):
+def paged_unsupported_reason(q, k_pages, v_pages, page_table, lengths,
+                             quantized=False):
     """Why the Pallas paged-attention kernel can NOT take this decode
     call, or None if it can.  Trace-time shape/dtype checks only —
     everything here must be static under jit.  A non-None reason routes
-    to the XLA lowering (``kernels.paged_fallback``) and is surfaced in
-    the export route sink (:func:`record_paged_routes`); either lowering
-    runs under the ``mx.paged_attention`` name scope."""
-    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
-        return "rank != 4 (got q%s k%s v%s)" % (q.ndim, k.ndim, v.ndim)
-    # jax.export shape polymorphism: a symbolic batch/pool dim can't
-    # answer the block/budget arithmetic below — decode programs that
-    # want the kernel export with a concrete decode_batch (deploy v5)
-    if not all(isinstance(d, int)
-               for d in tuple(q.shape) + tuple(k.shape) + tuple(v.shape)
-               + tuple(valid.shape)):
-        return "symbolic shape (q%s kv%s)" % (q.shape, k.shape)
+    to the XLA twin (``kernels.paged_fallback``) and is surfaced in the
+    export route sink (:func:`record_paged_routes`)."""
+    if q.ndim != 4 or k_pages.ndim != 3 or v_pages.ndim != 3:
+        return "rank: q%s pools %s/%s, want 4 and 3/3" % (
+            q.ndim, k_pages.ndim, v_pages.ndim)
+    if page_table.ndim != 2 or lengths.ndim != 1:
+        return "rank: page_table%s lengths%s, want 2 and 1" % (
+            page_table.ndim, lengths.ndim)
+    # jax.export shape polymorphism: the grid walks the batch, so it has
+    # to be concrete (export_generation's decode_batch); the pool's page
+    # count may stay symbolic where Pallas lowers with dynamic shapes
+    fixed = tuple(q.shape) + tuple(k_pages.shape[1:]) \
+        + tuple(page_table.shape) + tuple(lengths.shape)
+    if not all(isinstance(d, int) for d in fixed):
+        return "symbolic shape (q%s table%s)" % (q.shape, page_table.shape)
+    if not isinstance(k_pages.shape[0], int) \
+            and not _pallas_dynamic_shapes():
+        return "symbolic page count %s outside a dynamic-shape export" % (
+            k_pages.shape[0],)
     if q.shape[2] != 1:
         return "needs one query row per sequence, got Sq=%d" % q.shape[2]
-    if k.shape != v.shape:
-        return "k/v shapes differ: %s vs %s" % (k.shape, v.shape)
-    if q.shape[:2] != k.shape[:2]:
-        return "q/kv batch-head mismatch: %s vs %s" % (
-            q.shape[:2], k.shape[:2])
-    if q.shape[3] != k.shape[3]:
-        return "q/kv head dim mismatch: %d vs %d" % (
-            q.shape[3], k.shape[3])
-    if valid.shape != (q.shape[0], k.shape[2]):
-        return "valid mask shape %s != (B, K)=%s" % (
-            tuple(valid.shape), (q.shape[0], k.shape[2]))
+    if k_pages.shape != v_pages.shape:
+        return "k/v pool shapes differ: %s vs %s" % (
+            k_pages.shape, v_pages.shape)
+    if k_pages.shape[2] != q.shape[1] * q.shape[3]:
+        return "pool row width %d != heads x head dim %d x %d" % (
+            k_pages.shape[2], q.shape[1], q.shape[3])
+    if page_table.shape[0] != q.shape[0] \
+            or lengths.shape[0] != q.shape[0]:
+        return "page_table%s / lengths%s do not match the batch %d" % (
+            tuple(page_table.shape), tuple(lengths.shape), q.shape[0])
     if q.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         return "unsupported dtype %s" % q.dtype
     if quantized:
-        if k.dtype != jnp.int8:
-            return "quantized pages must be int8, got %s" % k.dtype
-    elif k.dtype != q.dtype:
-        return "q/kv dtype mismatch: %s vs %s" % (q.dtype, k.dtype)
+        if k_pages.dtype != jnp.int8:
+            return "quantized pages must be int8, got %s" % k_pages.dtype
+    elif k_pages.dtype != q.dtype:
+        return "q/kv dtype mismatch: %s vs %s" % (q.dtype, k_pages.dtype)
     if q.shape[3] > _MAX_HEAD_DIM:
         return "head dim %d > %d" % (q.shape[3], _MAX_HEAD_DIM)
-    # one (batch, head) row keeps its full gathered K and V resident
-    kv_bytes = 2 * k.shape[2] * k.shape[3] * k.dtype.itemsize
-    budget = _config.get("kernels.vmem_budget")
-    if kv_bytes > budget:
-        return "kv slice %d bytes > vmem budget %d" % (kv_bytes, budget)
     return None
+
+
+def _pallas_core():
+    """Pallas' tracing state, or None where this jax keeps it elsewhere
+    (the dynamic-shape export is experimental in jax 0.9)."""
+    try:
+        from jax._src.pallas import core
+    except ImportError:
+        return None
+    names = ("dynamic_shapes_export_enabled", "pallas_export_experimental")
+    return core if all(hasattr(core, n) for n in names) else None
+
+
+def _pallas_dynamic_shapes():
+    """True while tracing under :func:`pallas_dynamic_shapes`."""
+    core = _pallas_core()
+    return core is not None and bool(core.dynamic_shapes_export_enabled())
+
+
+def pallas_dynamic_shapes():
+    """Context for one ``jax.export``: Pallas kernels lower with symbolic
+    dimensions left symbolic, so a paged kernel takes a page pool whose
+    page count the artifact leaves open.  Nothing where this jax has no
+    such lowering: a symbolic page count then routes decode to the XLA
+    twin, with its reason (:func:`paged_unsupported_reason`)."""
+    core = _pallas_core()
+    if core is None:
+        return contextlib.nullcontext()
+    return core.pallas_export_experimental(True)
 
 
 # Export-time route capture: deploy.export_generation traces the decode
@@ -223,98 +255,110 @@ def _note_paged_route(impl, reason, quantized):
                        "quantized": bool(quantized)})
 
 
-def _paged_attention_xla(q, k, v, valid, scale=None, k_scale=None,
-                         v_scale=None):
-    """The XLA paged-attention lowering — the pre-kernel-tier op
-    sequence, byte-identical to what every release before the paged
-    kernel traced.  The math mirrors ``parallel.ring_attention
+def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
+                         scale=None, k_scale=None, v_scale=None):
+    """The XLA twin of the paged kernel: gather each row's pages through
+    its page table (under ``mx.kv_gather``; a sentinel id clips to a real
+    page), then the masked one-pass softmax every release traced (under
+    ``mx.paged_attention``).  The math mirrors ``parallel.ring_attention
     ._block_attn``: masked scores pin to the same ``-1e30`` floor, so
     masked keys contribute an EXACT ``0.0`` to both the softmax
     denominator and the value sum.  With ``k_scale``/``v_scale`` the
     int8 pages dequantize up front (one f32 broadcast multiply), the
-    same f32 operands the kernel reconstructs in VMEM."""
-    d = q.shape[-1]
+    same f32 operands the kernel reconstructs in VMEM.  A row of length
+    0 answers 0, as the kernel does."""
+    B, H, _, d = q.shape
+    K = page_table.shape[1] * k_pages.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if k_scale is not None:
-        k = k.astype(jnp.float32) * k_scale[..., None]
-        v = v.astype(jnp.float32) * v_scale[..., None]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    o = jnp.einsum("bhqk,bhkd->bhqd", e.astype(v.dtype), v)
-    return (o / l.astype(o.dtype)).astype(q.dtype)
+
+    def window(pages, last):
+        # [P, psz, H*last] -> this batch's [B, H, K, last]
+        return jnp.transpose(
+            pages[page_table].reshape(B, K, H, last), (0, 2, 1, 3))
+
+    with jax.named_scope("mx.kv_gather"):
+        k = window(k_pages, d)
+        v = window(v_pages, d)
+        if k_scale is not None:
+            k_scale = window(k_scale, 1)[..., 0]            # [B, H, K]
+            v_scale = window(v_scale, 1)[..., 0]
+    with jax.named_scope("mx.paged_attention"):
+        if k_scale is not None:
+            k = k.astype(jnp.float32) * k_scale[..., None]
+            v = v.astype(jnp.float32) * v_scale[..., None]
+        valid = jnp.arange(K, dtype=jnp.int32)[None, :] < lengths[:, None]
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, None, :], s, -1e30)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        o = jnp.einsum("bhqk,bhkd->bhqd", e.astype(v.dtype), v)
+        o = (o / l.astype(o.dtype)).astype(q.dtype)
+        return jnp.where((lengths > 0)[:, None, None, None], o, 0)
 
 
-def paged_attention(q, k, v, valid, scale=None, k_scale=None,
-                    v_scale=None):
-    """Decode-step attention over a page-gathered context window.
+def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
+                    k_scale=None, v_scale=None):
+    """Decode-step attention over the pages a page table names.
 
-    ``q`` is the single new query ``[B, H, 1, Dh]``; ``k``/``v`` are the
-    context gathered through a request's page table ``[B, H, K, Dh]``
-    (``K = page_table_width * page_size``, so slots past the sequence's
-    true length hold stale or clipped-sentinel data); ``valid`` ``[B, K]``
-    masks exactly the real positions.  With ``k_scale``/``v_scale``
-    (``[B, H, K]`` f32 per-row scales from ``mx.quantization
-    .quantize_rows``) the K/V operands are int8 KV pages and dequantize
-    in the consumer — inside the kernel's VMEM pass, or up front on the
-    XLA path.  Both lowerings pin masked scores to the ``-1e30`` floor
-    of ``parallel.ring_attention._block_attn`` and track an unpadded
-    forward bitwise-closely enough for greedy token parity
+    ``q`` is the single new query ``[B, H, 1, Dh]``; ``k_pages`` /
+    ``v_pages`` are ONE layer's page pool ``[P, psz, H*Dh]``;
+    ``page_table`` ``[B, W]`` int32 names each row's pages in order (an
+    id >= P is the sentinel: it reads a real page that ``lengths``
+    masks); ``lengths`` ``[B]`` int32 is how many positions each row
+    attends over, at most ``W * psz``.  With ``k_scale``/``v_scale``
+    (``[P, psz, H]`` f32 per-row scale pages from ``mx.quantization
+    .quantize_rows``) the pools are int8 and dequantize in the consumer.
+    Both routes pin masked scores to the ``-1e30`` floor of
+    ``parallel.ring_attention._block_attn`` and track an unpadded
+    forward closely enough for greedy token parity
     (tools/check_generation.py enforces it).
 
-    Routing (mirrors :func:`attention`): tier off → the plain XLA
-    lowering, traced identically to the pre-kernel-tier program.  Tier
-    on → the Pallas paged kernel when the shape qualifies
-    (``kernels.paged_attention`` counter; the tuned ``block_bh`` applies
-    when mx.perf.autotune has a "paged" winner for this site), the XLA
-    lowering when the shape can't take the kernel
-    (``kernels.paged_fallback``) or when the default-source gate
-    measured the kernel slower / not bit-close
-    (``kernels.gated_fallback``).  The decision and its reason land in
-    those counters and, under :func:`record_paged_routes`, in the export
-    route sink.  Whichever lowering runs, its device operations carry the
-    ``mx.paged_attention`` name scope (the Pallas kernel is named
-    ``mx_paged_attention``), so a profile finds them after a route
-    change."""
-    with jax.named_scope("mx.paged_attention"):
-        return _paged_attention_routed(q, k, v, valid, scale, k_scale,
-                                       v_scale)
-
-
-def _paged_attention_routed(q, k, v, valid, scale, k_scale, v_scale):
+    Routing: tier on and shape feasible → the Pallas kernel
+    (``ops.pallas_kernels.pallas_paged_attention``, named
+    ``mx_paged_attention``, under the ``mx.paged_attention`` scope),
+    which reads each row's ``ceil(length / psz)`` pages where they lie;
+    counter ``kernels.paged_attention``.  Otherwise → the XLA twin,
+    which gathers the whole ``W * psz`` window (under ``mx.kv_gather``)
+    and attends over it (under ``mx.paged_attention``): tier off, a
+    shape the kernel cannot take (``kernels.paged_fallback``), or the
+    default-source knob on an interpreted backend, where a kernel cannot
+    win (``kernels.gated_fallback``).  The decision and its reason land
+    in those counters and, under :func:`record_paged_routes`, in the
+    export route sink."""
     quant = k_scale is not None
-    if enabled():
-        q = jnp.asarray(q)
-        k = jnp.asarray(k)
-        v = jnp.asarray(v)
-        reason = paged_unsupported_reason(q, k, v, valid, quantized=quant)
-        if reason is None:
-            from . import autotune as _autotune
-            pick = _autotune.paged_pick(tuple(q.shape), tuple(k.shape),
-                                        str(q.dtype), quant, scale)
-            if pick is None or pick.get("impl") == "paged":
-                _telemetry.counter("kernels.paged_attention").inc()
-                _note_paged_route("paged", None, quant)
-                bb = pick.get("block_bh") if pick else None
-                return pallas_paged_attention(
-                    q, k, v, valid, scale=scale, k_scale=k_scale,
-                    v_scale=v_scale, block_bh=int(bb) if bb else None)
-            # the measured gate lost (or the platform statically can't
-            # win): the XLA lowering IS the winner for this site
-            reason = pick.get("reason") or "autotune gate: xla won"
-            _telemetry.counter("kernels.gated_fallback").inc()
-        else:
-            _telemetry.counter("kernels.paged_fallback").inc()
-        _note_paged_route("xla", reason, quant)
-        return _paged_attention_xla(q, k, v, valid, scale=scale,
-                                    k_scale=k_scale, v_scale=v_scale)
-    _note_paged_route("xla", "tier off", quant)
-    return _paged_attention_xla(q, k, v, valid, scale=scale,
-                                k_scale=k_scale, v_scale=v_scale)
+    reason = _paged_route_reason(q, k_pages, v_pages, page_table, lengths,
+                                 quant)
+    if reason is None:
+        _telemetry.counter("kernels.paged_attention").inc()
+        _note_paged_route("paged", None, quant)
+        with jax.named_scope("mx.paged_attention"):
+            return pallas_paged_attention(
+                q, k_pages, v_pages, page_table, lengths, scale=scale,
+                k_scale=k_scale, v_scale=v_scale)
+    _note_paged_route("xla", reason, quant)
+    return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
+                                scale=scale, k_scale=k_scale,
+                                v_scale=v_scale)
+
+
+def _paged_route_reason(q, k_pages, v_pages, page_table, lengths, quant):
+    """None when this decode site takes the kernel, else why not."""
+    if not enabled():
+        return "tier off"
+    from .rtc import interpret_mode
+    if _config.source("kernels.enabled") == "default" and interpret_mode():
+        # the graduated default: interpreted, a kernel can never beat
+        # the compiled twin
+        _telemetry.counter("kernels.gated_fallback").inc()
+        return "interpreted"
+    reason = paged_unsupported_reason(q, k_pages, v_pages, page_table,
+                                      lengths, quantized=quant)
+    if reason is not None:
+        _telemetry.counter("kernels.paged_fallback").inc()
+    return reason
 
 
 def measure(key, fn, *args):

@@ -243,20 +243,26 @@ class TransformerLM:
     # cache is a POOL of fixed-size pages shared by every in-flight
     # sequence; each sequence owns a page-table row of page ids.  Position
     # t of a sequence lives in page ``table[t // page_size]`` at slot
-    # ``t % page_size``.  A page id >= num_pages is the SENTINEL: writes
-    # through it drop (jax scatter mode="drop") and gathers through it
-    # clip to a real page whose rows the position mask then zeroes out —
-    # padded table entries and inactive decode slots are branch-free.
+    # ``t % page_size``, as one row of all heads' K (or V): a page is
+    # ``[page_size, H*Dh]``, lane-exact and contiguous on the device, so
+    # the decode kernel copies it where it lies.  A page id >= num_pages
+    # is the SENTINEL: writes through it drop (jax scatter mode="drop")
+    # and reads through it clip to a real page whose rows the row's
+    # length then masks out — padded table entries and inactive decode
+    # slots are branch-free.
 
     def kv_spec(self, quantized=False):
         """Static description of one model's page pool — what deploy.py
         stamps into the v4/v5 meta so a server can allocate the pool
         without reconstructing the model.  ``quantized`` describes int8
         KV pages: int8 payload pools plus per-(slot, head) f32 scale
-        pools, HALF the HBM per cached token."""
+        pools, HALF the HBM per cached token.  ``row_width`` says a slot
+        is one ``H*Dh`` row (artifacts of older builds, whose pages were
+        ``[page_size, H, Dh]``, lack it and are refused at load)."""
         cfg = self.cfg
         spec = {"num_layers": cfg.num_layers, "num_heads": cfg.num_heads,
                 "head_dim": cfg.head_dim,
+                "row_width": cfg.num_heads * cfg.head_dim,
                 "dtype": jnp.dtype(cfg.dtype).name}
         if quantized:
             spec["quantized"] = True
@@ -264,17 +270,18 @@ class TransformerLM:
 
     def init_kv_pages(self, num_pages, page_size, quantized=False):
         """Zeroed device page pool: {"k","v"} of
-        [L, num_pages, page_size, H, Dh] in the model dtype; with
+        [L, num_pages, page_size, H*Dh] in the model dtype; with
         ``quantized`` the payload is int8 and per-row scales ride along
         as {"k_scale","v_scale"} of [L, num_pages, page_size, H] f32."""
         cfg = self.cfg
         shape = (cfg.num_layers, int(num_pages), int(page_size),
-                 cfg.num_heads, cfg.head_dim)
+                 cfg.num_heads * cfg.head_dim)
         if quantized:
+            scales = shape[:-1] + (cfg.num_heads,)
             return {"k": jnp.zeros(shape, jnp.int8),
                     "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-                    "v_scale": jnp.zeros(shape[:-1], jnp.float32)}
+                    "k_scale": jnp.zeros(scales, jnp.float32),
+                    "v_scale": jnp.zeros(scales, jnp.float32)}
         return {"k": jnp.zeros(shape, cfg.dtype),
                 "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -378,27 +385,22 @@ class TransformerLM:
             new = {}
 
             def sink(k, v):
-                # [B,H,S,Dh] -> [B,S,H,Dh] page-slot scatter
+                # [B,H,S,Dh] -> [B,S,H*Dh] page-slot scatter
                 with jax.named_scope("mx.kv_write"):
                     kt = jnp.transpose(k, (0, 2, 1, 3))
                     vt = jnp.transpose(v, (0, 2, 1, 3))
                     if quant:
                         from .. import quantization as _quant
-                        kq, ks = _quant.quantize_rows(kt)
-                        vq, vs = _quant.quantize_rows(vt)
-                        new["k"] = kl.at[pages, slots].set(
-                            kq, mode="drop")
-                        new["v"] = vl.at[pages, slots].set(
-                            vq, mode="drop")
+                        kt, ks = _quant.quantize_rows(kt)
+                        vt, vs = _quant.quantize_rows(vt)
                         new["ks"] = ksl.at[pages, slots].set(
                             ks, mode="drop")
                         new["vs"] = vsl.at[pages, slots].set(
                             vs, mode="drop")
-                    else:
-                        new["k"] = kl.at[pages, slots].set(
-                            kt.astype(kl.dtype), mode="drop")
-                        new["v"] = vl.at[pages, slots].set(
-                            vt.astype(vl.dtype), mode="drop")
+                    new["k"] = kl.at[pages, slots].set(
+                        kt.astype(kl.dtype).reshape(B, S, -1), mode="drop")
+                    new["v"] = vl.at[pages, slots].set(
+                        vt.astype(vl.dtype).reshape(B, S, -1), mode="drop")
 
             out = self._layer(carry, lp, kv_sink=sink)
             if quant:
@@ -426,30 +428,30 @@ class TransformerLM:
 
         token_ids [B] int32 (the token to append), positions [B] int32
         (its position = tokens already cached), page_table [B, W] int32.
-        Appends each token's K/V to its page, attends through the page
-        table over positions <= its own, and returns
+        Appends each token's K/V to its page, then attends over the row's
+        ``positions + 1`` tokens through ``kernels.paged_attention``, which
+        is handed each layer's page pool, the page table and those
+        lengths: on the kernel route the pages are read where they lie,
+        each row's and no more; on the XLA twin's route the table's whole
+        window is gathered first (``mx.kv_gather``).  Returns
         ``(new_kv, next_token[B] int32)``.  Inactive slots pass the
-        sentinel page everywhere: their write drops and their output is
-        garbage the scheduler ignores.  With an int8 pool the appended
-        row quantizes into the pages and the gathered context carries its
-        per-row scales into ``kernels.paged_attention``, which
-        dequantizes in the consumer (inside the Pallas kernel's VMEM
-        pass on the kernel route).  ``sample``/``return_logits`` as in
+        sentinel page everywhere and position 0: their write drops, they
+        read one clamped page, and their output is garbage the scheduler
+        ignores.  With an int8 pool the appended row quantizes into the
+        pages and the pools' per-row scale pages go along, to dequantize
+        in the consumer (inside the Pallas kernel's VMEM pass on the
+        kernel route).  ``sample``/``return_logits`` as in
         :meth:`prefill`.
         """
         cfg = self.cfg
         B = token_ids.shape[0]
-        W = page_table.shape[1]
         psz = int(page_size)
-        H, Dh = cfg.num_heads, cfg.head_dim
         quant = "k_scale" in kv
         x = (params["embed"][token_ids]
              + params["pos_embed"][positions]).astype(cfg.dtype)[:, None]
         page = jnp.take_along_axis(
             page_table, (positions // psz)[:, None], axis=1)  # [B,1]
         slot = (positions % psz)[:, None]                     # [B,1]
-        valid = jnp.arange(W * psz, dtype=jnp.int32)[None, :] \
-            <= positions[:, None]                             # [B, K]
 
         def body(carry, xs):
             if quant:
@@ -458,8 +460,6 @@ class TransformerLM:
                 lp, kl, vl = xs
             q, k, v = self._qkv(carry, lp)                    # [B,H,1,Dh]
             scales = {}
-            # the operations keep the order they always had (a scope is
-            # metadata: the lowered program does not change by it)
             with jax.named_scope("mx.kv_write"):
                 kt = jnp.transpose(k, (0, 2, 1, 3))           # [B,1,H,Dh]
                 vt = jnp.transpose(v, (0, 2, 1, 3))
@@ -469,28 +469,14 @@ class TransformerLM:
                     vt, vs = _quant.quantize_rows(vt)
                     ksl = ksl.at[page, slot].set(ks, mode="drop")
                     vsl = vsl.at[page, slot].set(vs, mode="drop")
-            if quant:
-                with jax.named_scope("mx.kv_gather"):
-                    # gathered per-row scales, [B, K] -> [B, H, K]
-                    scales["k_scale"] = jnp.transpose(
-                        ksl[page_table].reshape(B, W * psz, H), (0, 2, 1))
-                    scales["v_scale"] = jnp.transpose(
-                        vsl[page_table].reshape(B, W * psz, H), (0, 2, 1))
-            with jax.named_scope("mx.kv_write"):
-                kl = kl.at[page, slot].set(kt.astype(kl.dtype),
-                                           mode="drop")
-                vl = vl.at[page, slot].set(vt.astype(vl.dtype),
-                                           mode="drop")
-            # context through the page table (sentinel entries clip to a
-            # real page; `valid` masks them out of the softmax exactly)
-            with jax.named_scope("mx.kv_gather"):
-                kc = jnp.transpose(
-                    kl[page_table].reshape(B, W * psz, H, Dh),
-                    (0, 2, 1, 3))
-                vc = jnp.transpose(
-                    vl[page_table].reshape(B, W * psz, H, Dh),
-                    (0, 2, 1, 3))
-            o = _kernels.paged_attention(q, kc, vc, valid, **scales)
+                    scales = {"k_scale": ksl, "v_scale": vsl}
+                kl = kl.at[page, slot].set(
+                    kt.astype(kl.dtype).reshape(B, 1, -1), mode="drop")
+                vl = vl.at[page, slot].set(
+                    vt.astype(vl.dtype).reshape(B, 1, -1), mode="drop")
+            # after the write, so the row attends over its own new token
+            o = _kernels.paged_attention(q, kl, vl, page_table,
+                                         positions + 1, **scales)
             out = self._attn_mlp(carry, o, lp)
             if quant:
                 return out, (kl, vl, ksl, vsl)

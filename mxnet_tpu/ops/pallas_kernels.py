@@ -421,124 +421,205 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None, block_q=128,
 
 
 # ------------------------------------------------------- paged attention
-def _paged_attn_kernel(scale, quant, *refs):
-    """One row block of single-query paged attention: ``rows`` (batch,
-    head) pairs, each attending its page-gathered context of K slots.
-
-    This is the online-softmax attend in its degenerate one-block form —
-    a decode query is a single row, so the whole gathered context of a
-    row block lives in VMEM and the stable (max, sum) accumulation
-    happens on chip in f32 in one pass; no partial-block merge is ever
-    needed.  Every operand keeps the (batch, head) row as a LEADING dim
-    ([rows, 1, D] query, [rows, K, D] context, [rows, 1, K] mask and
-    scales), so both matmuls are the batched [1, D] x [K, D] form the
-    MXU lowering takes and the trailing two block dims always span the
-    whole array — legal for any row count.  Masked slots pin to the
-    ``-1e30`` floor of ``parallel.ring_attention._block_attn``, so
-    ``exp`` underflows to an EXACT 0.0 in both the denominator and the
-    value sum.  With ``quant`` the K/V blocks arrive int8 and the per-slot
-    scales fold into the scores and the probabilities (``q.(k*s) ==
-    (q.k)*s``) — they stay K-on-lanes rows, and HBM traffic stays at the
-    int8 byte count, the entire point of int8 KV pages."""
-    if quant:
-        q_ref, k_ref, v_ref, valid_ref, ks_ref, vs_ref, o_ref = refs
-    else:
-        q_ref, k_ref, v_ref, valid_ref, o_ref = refs
-    q = q_ref[:]                                    # [rows, 1, D]
-    k = k_ref[:]                                    # [rows, K, D]
-    v = v_ref[:]
-    if quant:
-        q = q.astype(jnp.float32)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-    s = jnp.einsum("bqd,bkd->bqk", q, k, precision=_mxu_precision(q, k),
-                   preferred_element_type=jnp.float32) * scale
-    if quant:
-        s = s * ks_ref[:]
-    s = jnp.where(valid_ref[:] != 0, s, _NEG)       # [rows, 1, K]
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    p = e * vs_ref[:] if quant else e
-    acc = jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
-                     precision=_mxu_precision(v),
-                     preferred_element_type=jnp.float32)
-    o_ref[:] = (acc / l).astype(o_ref.dtype)
+_PAGED_TILE_TOKENS = 128     # one MXU pass wide: the tile's score row
 
 
-def paged_block_rows(BH, K, D, kv_itemsize, quant):
-    """(batch, head) rows per grid step of the paged kernel under the
-    VMEM budget.  Per row the gathered K and V dominate — lane-padded,
-    and int8 pages also hold their f32 working copies; scales, mask and
-    q are noise.  The row axis is a leading block dim, so any divisor of
-    BH is a legal block."""
-    row_bytes = 2 * K * _lane_pad(D) * (kv_itemsize + 4 * int(quant))
-    return _row_block(BH, row_bytes, align=1)
+def paged_tile_pages(psz, width, kv_itemsize, table_width):
+    """Pages the paged kernel copies per step: a 128-token tile where the
+    two double-buffered K and V tiles (``4 * pages * psz * width``
+    elements) fit the ``kernels.vmem_budget`` knob, fewer where they do
+    not or the page table is narrower, never less than one."""
+    from .. import config as _config
+    fit = int(_config.get("kernels.vmem_budget")) \
+        // (4 * psz * width * kv_itemsize)
+    return max(1, min(_PAGED_TILE_TOKENS // psz, fit, table_width))
 
 
-def pallas_paged_attention(q, k, v, valid, scale=None, k_scale=None,
-                           v_scale=None, block_bh=None):
-    """Paged-attention decode kernel: one query row per (batch, head)
-    against its page-gathered context.
+def _paged_attn_kernel(scale, quant, tile, nh, lengths_ref, table_ref,
+                       q_ref, k_hbm, v_hbm, *refs):
+    """One decode row of single-query attention over its K/V pages, read
+    where they lie.
 
-    q [B, H, 1, Dh]; k/v [B, H, K, Dh] gathered through a page table
-    (slots past the true length hold stale or clipped-sentinel data);
-    valid [B, K] masks exactly the real positions.  With
-    ``k_scale``/``v_scale`` ([B, H, K] f32 per-row scales from
-    ``mx.quantization.quantize_rows``) the K/V operands are int8 pages
-    and dequantize inside the kernel.
-
-    The grid walks blocks of ``block_bh`` (batch, head) rows (None =
-    derive from the VMEM budget); each step holds its rows' full
-    gathered K/V in VMEM.  The math is row-independent, so the
-    mx.perf.autotune "paged" search can tune the block size freely.
-    Routing/fallback policy lives in ``mx.kernels.paged_attention``."""
+    ``k_hbm``/``v_hbm`` are one layer's whole page pool ``[P, psz, H*Dh]``
+    left in HBM; the row's page ids and length arrive by scalar prefetch.
+    The row walks ``ceil(length / psz)`` pages and no more, ``tile`` pages
+    per step: each page is one contiguous copy into a double-buffered
+    VMEM tile (the next tile is in flight while this one is attended), and
+    an online softmax (``m``, ``l``, ``acc`` in f32) carries across tiles.
+    All heads of a page are attended at once: the query row becomes a
+    block-diagonal ``[H, H*Dh]`` matrix (head h keeps only its own Dh
+    lanes), so ``Qbd . K^T`` is every head's score row in one MXU product
+    whose off-block zeros add an exact 0.0; ``P . V`` yields ``[H, H*Dh]``
+    of which head h's answer is block h of row h.  Positions past the
+    length inside the last page pin to the ``-1e30`` floor of
+    ``parallel.ring_attention._block_attn``, so ``exp`` underflows to an
+    EXACT 0.0 in both the denominator and the value sum; the V tiles are
+    zeroed once, so the value a masked slot's 0.0 multiplies is always
+    finite (zero, or older pool rows).  Page ids arrive clamped to the
+    pool (the caller does it: the pool's size may be symbolic, and the
+    kernel never asks for it); a row of length 0 reads nothing and
+    answers 0.  With ``quant`` the pages are int8 and their ``[P, psz,
+    H]`` f32 scale pages are fetched the same way and fold into the
+    scores and the probabilities (``q.(k*s) == (q.k)*s``), so HBM traffic
+    stays at the int8 byte count."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    if quant:
+        ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems = refs
+    else:
+        o_ref, kbuf, vbuf, sems = refs
+    b = pl.program_id(0)
+    _, psz, width = k_hbm.shape
+    W = table_ref.shape[0] // lengths_ref.shape[0]
+    tokens = tile * psz
+    length = lengths_ref[b]
+    n_pages = jnp.minimum((length + psz - 1) // psz, W)
+    n_tiles = (n_pages + tile - 1) // tile
+
+    pools = [(k_hbm, kbuf), (v_hbm, vbuf)]
+    if quant:
+        pools += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
+
+    def each_page(t, slot, act):
+        """``act`` on every copy of tile ``t``'s live pages into ``slot``."""
+        for j in range(tile):
+            i = t * tile + j
+
+            @pl.when(i < n_pages)
+            def _(i=i, j=j):
+                page = table_ref[b * W + i]
+                for n, (src, dst) in enumerate(pools):
+                    act(pltpu.make_async_copy(
+                        src.at[page], dst.at[slot, j], sems.at[slot, n]))
+
+    def start(t, slot):
+        each_page(t, slot, lambda cp: cp.start())
+
+    def wait(t, slot):
+        each_page(t, slot, lambda cp: cp.wait())
+
+    @pl.when(b == 0)
+    def _():
+        # scratch is not initialised, and a masked slot's probability of
+        # 0.0 must meet a finite value (its score is replaced, not scaled)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        if quant:
+            vsbuf[...] = jnp.zeros_like(vsbuf)
+
+    @pl.when(n_tiles > 0)
+    def _():
+        start(0, 0)
+
+    dh = width // nh
+    cdt = q_ref.dtype if quant else kbuf.dtype      # the products' dtype
+    head = jax.lax.broadcasted_iota(jnp.int32, (nh, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nh, width), 1)
+    own = lane // dh == head                        # head h's Dh lanes
+    # (masks have the 32-bit layout: select in f32, then narrow)
+    qbd = jnp.where(own, q_ref[...].astype(jnp.float32), 0.0).astype(cdt)
+    prec = _mxu_precision(qbd)
+    col = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1)
+
+    def scales(buf, slot):
+        """One tile's per-(token, head) scales as [H, tokens] (the pages
+        arrive [psz, lanes] with the heads on the first H lanes)."""
+        return buf[slot].reshape(tokens, buf.shape[-1]).T[:nh]
+
+    def body(t, carry):
+        m, l, acc = carry
+        slot = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            start(t + 1, 1 - slot)
+
+        wait(t, slot)
+        # (widen first: a 16-row int8 page is half a packed tile)
+        k = kbuf[slot].astype(cdt).reshape(tokens, width)
+        v = vbuf[slot].astype(cdt).reshape(tokens, width)
+        s = jax.lax.dot_general(
+            qbd, k, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) * scale     # [H, tokens]
+        if quant:
+            s = s * scales(ksbuf, slot)
+        s = jnp.where(t * tokens + col < length, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(e, axis=-1, keepdims=True)
+        p = e * scales(vsbuf, slot) if quant else e
+        acc = alpha * acc + jax.lax.dot_general(
+            p.astype(cdt), v, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)             # [H, H*Dh]
+        return m_new, l, acc
+
+    m0 = jnp.full((nh, 1), _NEG, jnp.float32)
+    l0 = jnp.zeros((nh, 1), jnp.float32)
+    acc0 = jnp.zeros((nh, width), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, acc0))
+    out = jnp.where(own, acc / jnp.where(l == 0.0, 1.0, l), 0.0)
+    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
+                           scale=None, k_scale=None, v_scale=None):
+    """Paged-attention decode kernel: one query row per sequence against
+    the K/V pages its page table names, read in place.
+
+    q [B, H, 1, Dh]; k_pages/v_pages [P, psz, H*Dh], one layer's page
+    pool; page_table [B, W] int32 (ids >= P are the sentinel and clamp to
+    a real page, which the length then masks); lengths [B] int32, the
+    positions each row attends over (at most W * psz).  With
+    ``k_scale``/``v_scale`` ([P, psz, H] f32 per-row scale pages from
+    ``mx.quantization.quantize_rows``) the pools are int8 and dequantize
+    inside the kernel.  The grid walks the B rows; what a row costs
+    follows from its length, not from W.  Routing/fallback policy lives
+    in ``mx.kernels.paged_attention``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
     from ..rtc import interpret_mode
     q = jnp.asarray(q)
-    k = jnp.asarray(k)
-    v = jnp.asarray(v)
     B, H, Sq, D = q.shape
     if Sq != 1:
         raise ValueError("paged attention takes one query row per "
                          "sequence, got Sq=%d" % Sq)
-    K = k.shape[2]
-    if v.shape != k.shape:
-        raise ValueError("k and v shapes differ: %s vs %s"
-                         % (k.shape, v.shape))
+    P, psz, width = k_pages.shape
+    if v_pages.shape != k_pages.shape or width != H * D:
+        raise ValueError("page pools must both be [P, psz, H*Dh=%d], got "
+                         "%s and %s" % (H * D, k_pages.shape, v_pages.shape))
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
-    BH = B * H
-    qf = q.reshape(BH, 1, D)
-    kf = k.reshape(BH, K, D)
-    vf = v.reshape(BH, K, D)
-    validf = jnp.broadcast_to(valid[:, None, None, :].astype(jnp.int32),
-                              (B, H, 1, K)).reshape(BH, 1, K)
-    if block_bh is None:
-        rows = paged_block_rows(BH, K, D, k.dtype.itemsize, quant)
-    else:
-        rows = _row_block(BH, 1, budget=min(int(block_bh), BH), align=1)
-
-    def row3(i):
-        return (i, 0, 0)
-
-    operands = [qf, kf, vf, validf]
-    in_specs = [pl.BlockSpec((rows, 1, D), row3),
-                pl.BlockSpec((rows, K, D), row3),
-                pl.BlockSpec((rows, K, D), row3),
-                pl.BlockSpec((rows, 1, K), row3)]
+    tile = paged_tile_pages(psz, width, k_pages.dtype.itemsize,
+                            page_table.shape[1])
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    row = pl.BlockSpec((None, 1, width), lambda b, *_: (b, 0, 0))
+    operands = [q.reshape(B, 1, width), k_pages, v_pages]
+    in_specs = [row, any_space, any_space]
+    scratch = [pltpu.VMEM((2, tile, psz, width), k_pages.dtype),
+               pltpu.VMEM((2, tile, psz, width), v_pages.dtype)]
     if quant:
-        operands += [jnp.asarray(k_scale, jnp.float32).reshape(BH, 1, K),
-                     jnp.asarray(v_scale, jnp.float32).reshape(BH, 1, K)]
-        in_specs += [pl.BlockSpec((rows, 1, K), row3),
-                     pl.BlockSpec((rows, 1, K), row3)]
+        # a page of scales is copied whole, and a copy's minor axis is a
+        # multiple of the 128 lanes: pad the heads up to it
+        lanes = _lane_pad(H)
+        operands += [jnp.pad(jnp.asarray(s, jnp.float32),
+                             ((0, 0), (0, 0), (0, lanes - H)))
+                     for s in (k_scale, v_scale)]
+        in_specs += [any_space, any_space]
+        scratch += [pltpu.VMEM((2, tile, psz, lanes), jnp.float32),
+                    pltpu.VMEM((2, tile, psz, lanes), jnp.float32)]
+    scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quant else 2)))
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale, quant),
-        out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
-        grid=(BH // rows,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows, 1, D), row3),
-        interpret=interpret_mode(), name="mx_paged_attention")(*operands)
+        functools.partial(_paged_attn_kernel, scale, quant, tile, H),
+        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
+            out_specs=row, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(), name="mx_paged_attention")(
+            jnp.asarray(lengths, jnp.int32),
+            jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+            .reshape(-1), *operands)
     return out.reshape(B, H, 1, D)
 
 

@@ -68,10 +68,6 @@ def _pick_attention():
                                    "float32", True)
 
 
-def _pick_paged():
-    return autotune.paged_pick((2, 2, 1, 8), (2, 2, 16, 8), "float32", False)
-
-
 def _pick_fused():
     opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9)
     opt.step_fused = _refuse
@@ -81,9 +77,8 @@ def _pick_fused():
 @pytest.mark.parametrize("in_trace", [False, True], ids=["eager", "traced"])
 @pytest.mark.parametrize("kernel, pick", [
     ("flash_attention", _pick_attention),
-    ("pallas_paged_attention", _pick_paged),
     (None, _pick_fused),
-], ids=["attention", "paged", "fused"])
+], ids=["attention", "fused"])
 def test_candidate_that_cannot_compile_raises_and_is_never_recorded(
         monkeypatch, kernel, pick, in_trace):
     """On a compiled (non-interpreted) backend a cache miss searches; a
@@ -456,7 +451,5 @@ def test_check_autotune_smoke():
     assert report["ok"], report
     assert report["attention"]["impl"] in ("flash", "xla"), report
     assert report["attention"]["parity"] in ("bitwise", "tolerance"), report
-    assert report["paged"]["impl"] in ("paged", "xla"), report
-    assert report["paged"]["parity"] in ("bitwise", "tolerance"), report
     assert report["reload"]["measure"] == 0, report
-    assert report["reload"]["cache_hit"] >= 3, report
+    assert report["reload"]["cache_hit"] >= 2, report

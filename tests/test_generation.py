@@ -359,6 +359,43 @@ def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
         s[0] == "engine.prefill.device" for s in spans) == 3
 
 
+def test_decode_span_counts_what_the_in_place_route_reads(tmp_path):
+    """Where the decode programs took the Pallas kernel (tier on, a
+    concrete decode batch), ``window_tokens`` is the pages the rows' own
+    lengths reach — whole pages, one token or more past what they held —
+    not slots x width x page size; and the same requests are served the
+    same tokens as on the gathering route."""
+    from _util import profiled_spans
+    model, params = _tiny_lm()
+    prefix = str(tmp_path / "lm")
+    config.set("kernels.enabled", True)
+    try:
+        deploy.export_generation(model, params, prefix, page_size=PAGE,
+                                 max_context=CTX, prompt_buckets=(4, 8),
+                                 decode_batch=2)
+    finally:
+        config.unset("kernels.enabled")
+    pred = deploy.load_generator(prefix)
+    assert {r["impl"] for r in pred.paged_routes.values()} == {"paged"}
+    telemetry.reset()
+    futs = []
+    spans = profiled_spans(lambda: futs.extend(_three_requests(pred)),
+                           tmp_path, ("engine.decode",))
+    decodes = [s[3] for s in spans if s[0] == "engine.decode"]
+    assert decodes
+    for args in decodes:
+        held, window, rows = (int(args[k]) for k in
+                              ("held_tokens", "window_tokens", "rows"))
+        assert window % PAGE == 0
+        assert held + rows <= window < held + rows + rows * PAGE
+    assert telemetry.counter("kernels.paged_attention").value == len(decodes)
+    rng = np.random.default_rng(11)
+    for fut, (plen, max_new) in zip(futs, ((3, 5), (7, 4), (4, 6))):
+        prompt = rng.integers(0, VOCAB, size=plen).astype(np.int32)
+        np.testing.assert_array_equal(
+            fut.result(), model.greedy_decode(params, prompt, max_new))
+
+
 def test_serving_generate_event_is_the_request_record(artifact, tmp_path):
     """Tracing off: every request has the engine's own id — on the future
     and in its ``serving_generate`` event — with its queue wait, prefill
@@ -402,6 +439,9 @@ def test_serving_generate_event_is_the_request_record(artifact, tmp_path):
     ("decode", ("mx.layers", "mx.qkv", "mx.kv_write", "mx.kv_gather",
                 "mx.paged_attention", "mx.attn_out", "mx.mlp",
                 "mx.lm_head", "mx.sample")),
+    ("decode_kernel", ("mx.layers", "mx.qkv", "mx.kv_write",
+                       "mx.paged_attention", "mx.attn_out", "mx.mlp",
+                       "mx.lm_head", "mx.sample")),
     ("prefill", ("mx.layers", "mx.qkv", "mx.kv_write", "mx.attention",
                  "mx.attn_out", "mx.mlp", "mx.lm_head", "mx.sample")),
 ])
@@ -410,10 +450,15 @@ def test_generation_programs_carry_scopes_as_metadata_only(
         program, scopes, quantized, monkeypatch):
     """The decode step and the prefill name their layers with
     ``jax.named_scope`` — and are, locations aside, the programs they were
-    without the names."""
+    without the names.  ``mx.kv_gather`` is the XLA twin's: the decode
+    step on the kernel's route (``decode_kernel``: the tier switched on)
+    reads its pages in place and has no such scope."""
     import jax
     import jax.numpy as jnp
     from _util import lowered_with_and_without_scopes
+    if program == "decode_kernel":
+        program = "decode"
+        mx.config.set("kernels.enabled", True)
     model, params = _tiny_lm()
     kv = model.init_kv_pages(8, PAGE, quantized=quantized)
     i32 = jnp.int32
@@ -429,9 +474,15 @@ def test_generation_programs_carry_scopes_as_metadata_only(
                 params, kv, jnp.zeros((1, 8), i32), jnp.ones((1,), i32),
                 jnp.zeros((1, 2), i32))
 
-    text = lowered_with_and_without_scopes(lower, monkeypatch)
+    try:
+        text = lowered_with_and_without_scopes(lower, monkeypatch)
+    finally:
+        mx.config.unset("kernels.enabled")
     for scope in scopes:
         assert scope + "/" in text or scope + '"' in text, scope
+    if "mx.kv_gather" not in scopes and "mx.paged_attention" in scopes:
+        assert "mx.kv_gather" not in text
+        assert "mx_paged_attention" in text
 
 
 # ------------------------------------------------------- smoke wrapper

@@ -259,94 +259,194 @@ def test_routed_attention_runs_per_shard_on_a_mesh():
 
 
 # --------------------------------------------------- paged decode kernel
-def _paged_case(B=2, H=2, K=16, D=8, seed=7, quant=False):
+PSZ, HEADS, DH = 4, 2, 8
+POOL_DTYPES = {"f32": (jnp.float32, jnp.float32, 2e-6),
+               "bf16": (jnp.bfloat16, jnp.bfloat16, 2e-2),
+               "int8": (jnp.int8, jnp.float32, 1e-5)}
+#: one batch each; psz = 4, so 8 and 9 are k*psz and k*psz + 1
+LENGTHS = {"ragged": [11, 3, 30, 18], "page_multiples": [4, 8, 9, 5],
+           "one_token": [1, 1, 7, 1], "inactive_rows": [6, 0, 13, 0]}
+
+
+def _pool_case(lengths, width, pool="f32", pages=48, seed=7, order="mixed"):
+    """A page pool, a batch of rows that hold ``lengths`` tokens, and the
+    page table that finds them.  A row's pages lie scattered through the
+    pool out of order (``order="mixed"``) or in a run (``"run"``), the
+    same K/V rows either way; a row of length 0 is an inactive decode
+    slot: all sentinel.  Pages no row owns hold noise."""
+    kv_dt, q_dt, _ = POOL_DTYPES[pool]
     rng = np.random.RandomState(seed)
-    q = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
-    lens = np.asarray([K - 5, K][:B])
-    valid = jnp.asarray(np.arange(K)[None, :] < lens[:, None])
-    if quant:
-        k = jnp.asarray(rng.randint(-127, 128, (B, H, K, D)), jnp.int8)
-        v = jnp.asarray(rng.randint(-127, 128, (B, H, K, D)), jnp.int8)
-        ks = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, H, K)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, H, K)), jnp.float32)
-        return q, k, v, valid, ks, vs
-    k = jnp.asarray(rng.randn(B, H, K, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, H, K, D), jnp.float32)
-    return q, k, v, valid, None, None
+    B, W = len(lengths), width
+    q = jnp.asarray(rng.randn(B, HEADS, 1, DH), q_dt)
+    need = [-(-n // PSZ) for n in lengths]
+    ids = rng.permutation(pages) if order == "mixed" else np.arange(pages)
+    table = np.full((B, W), pages, np.int32)
+    rows = {}                     # the rows' own K/V, independent of order
+    for b, n in enumerate(need):
+        table[b, :n], ids = ids[:n], ids[n:]
+        rows[b] = np.random.RandomState(100 + b).randn(
+            2, max(n, 1), PSZ, HEADS * DH)
+    wide = (pages, PSZ, HEADS * DH)
+    if pool == "int8":
+        kp = rng.randint(-127, 128, wide).astype(np.int8)
+        vp = rng.randint(-127, 128, wide).astype(np.int8)
+        scales = [jnp.asarray(rng.uniform(1e-3, 2e-2, wide[:2] + (HEADS,)),
+                              jnp.float32) for _ in range(2)]
+    else:
+        kp, vp = rng.randn(*wide), rng.randn(*wide)
+        scales = [None, None]
+        for b, n in enumerate(need):
+            kp[table[b, :n]], vp[table[b, :n]] = rows[b][0][:n], \
+                rows[b][1][:n]
+    return (q, jnp.asarray(kp, kv_dt), jnp.asarray(vp, kv_dt),
+            jnp.asarray(table), jnp.asarray(lengths, jnp.int32), *scales)
 
 
-@pytest.mark.parametrize("block_bh", [None, 1, 2, 4])
-def test_paged_kernel_matches_xla(block_bh):
-    """The one-query-row online-softmax kernel agrees with the static
-    XLA lowering to f32 rounding at every row block (jit-vs-jit).  Not
-    bitwise: a one-row block reduces in another order than the batched
-    form, and on the MXU no two shapes are promised equal bits."""
-    import functools
-    q, k, v, valid, _, _ = _paged_case()
-    got = jax.jit(functools.partial(
-        pallas_paged_attention, block_bh=block_bh))(q, k, v, valid)
-    want = jax.jit(kernels._paged_attention_xla)(q, k, v, valid)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-6, atol=2e-6)
+def _both(case):
+    q, kp, vp, table, lengths, ks, vs = case
+    run = lambda fn: np.asarray(jax.jit(  # noqa: E731
+        lambda *a: fn(*a[:5], k_scale=a[5], v_scale=a[6]))(*case),
+        np.float32)
+    return run(pallas_paged_attention), run(kernels._paged_attention_xla)
 
 
-def test_paged_kernel_int8_dequant_matches_xla():
-    """int8 KV pages dequantize INSIDE the kernel — the per-slot scales
-    fold into the scores and probabilities, which is dequantize-then-XLA
-    up to f32 rounding order, so the quant error budget stays the only
-    real drift source."""
-    q, k, v, valid, ks, vs = _paged_case(quant=True)
-    got = jax.jit(lambda *a: pallas_paged_attention(
-        a[0], a[1], a[2], a[3], k_scale=a[4], v_scale=a[5]))(
-        q, k, v, valid, ks, vs)
-    want = jax.jit(lambda *a: kernels._paged_attention_xla(
-        a[0], a[1], a[2], a[3], k_scale=a[4], v_scale=a[5]))(
-        q, k, v, valid, ks, vs)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+@pytest.mark.parametrize("pool", list(POOL_DTYPES))
+@pytest.mark.parametrize("lengths", list(LENGTHS))
+def test_paged_kernel_matches_twin(lengths, pool):
+    """The in-place kernel agrees with the twin that gathers the whole
+    window, to the rounding of the pool's precision (jit-vs-jit; not
+    bitwise: an online softmax sums in another order): lengths ragged
+    within one batch, lengths of k*psz and k*psz + 1, rows of one token,
+    and inactive rows whose table is all sentinel."""
+    served = [max(n, 1) for n in LENGTHS[lengths]]   # an idle slot: pos 0
+    case = list(_pool_case(LENGTHS[lengths], 8, pool))
+    case[4] = jnp.asarray(served, jnp.int32)
+    got, want = _both(case)
+    tol = POOL_DTYPES[pool][2]
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("budget", [VMEM_DEFAULT, 1024],
+                         ids=["tile_of_32_pages", "tile_of_1_page"])
+def test_paged_kernel_carries_its_softmax_across_tiles(budget):
+    """Rows longer than one tile (128 tokens; one page when the VMEM
+    budget allows no more) and rows that end on and just past a tile's
+    edge: the running max, sum and accumulator carry over."""
+    config.set("kernels.vmem_budget", budget)
+    got, want = _both(_pool_case([128, 129, 150, 7], 40, pages=160))
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
+def test_paged_kernel_table_width_does_not_change_a_row():
+    """Page tables of width 1 and 128 give the same answer for the same
+    rows (to f32 rounding: the tile, and so the order of a sum, follows
+    the narrower of 128 tokens and the table): what a row reads and
+    computes follows from its length."""
+    narrow = _pool_case([3, 4, 1, 2], 1, order="run")
+    wide = _pool_case([3, 4, 1, 2], 128, order="run", pages=48)
+    np.testing.assert_array_equal(np.asarray(narrow[3]),
+                                  np.asarray(wide[3][:, :1]))
+    got_n, want_n = _both(narrow)
+    got_w, want_w = _both(wide)
+    np.testing.assert_allclose(got_n, got_w, rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(want_n, want_w, rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(got_w, want_w, rtol=2e-6, atol=2e-6)
+
+
+def test_paged_kernel_finds_pages_scattered_out_of_order():
+    """The same rows, their pages in a run or scattered through the pool
+    out of order: the same bits."""
+    run, _ = _both(_pool_case(LENGTHS["ragged"], 8, order="run"))
+    mixed, want = _both(_pool_case(LENGTHS["ragged"], 8, order="mixed"))
+    np.testing.assert_array_equal(run, mixed)
+    np.testing.assert_allclose(mixed, want, rtol=2e-6, atol=2e-6)
+
+
+def test_paged_row_of_length_zero_reads_nothing_and_answers_zero():
+    """Length 0 walks no page (its table may hold anything, here ids far
+    outside the pool) and answers 0 on both routes; its neighbours are
+    untouched by it."""
+    q, kp, vp, table, lengths, _, _ = _pool_case([9, 0, 5], 4)
+    table = table.at[1].set(10 ** 6)
+    got, want = _both((q, kp, vp, table, lengths, None, None))
+    assert not got[1].any() and not want[1].any()
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
 
 
 def test_paged_routing_explicit_vs_default():
     """Explicit tier-on routes decode through the Pallas kernel (counter
     + route record); the graduated default on the interpreter backend
-    takes the measured gate's static-XLA fallback — bitwise identical
-    output either way."""
-    q, k, v, valid, _, _ = _paged_case()
+    and the tier switched off take the twin, each with its reason — the
+    same answer every way."""
+    case = _pool_case(LENGTHS["ragged"], 8)[:5]
     telemetry.reset()
-    config.set("kernels.enabled", True)       # explicit source
-    with kernels.record_paged_routes() as routes:
-        out_k = jax.jit(lambda *a: kernels.paged_attention(*a))(
-            q, k, v, valid)
-    assert routes and routes[0]["impl"] == "paged"
-    assert telemetry.counter("kernels.paged_attention").value == 1
-    config.unset("kernels.enabled")           # graduated default
-    with kernels.record_paged_routes() as routes2:
-        out_x = jax.jit(lambda *a: kernels.paged_attention(*a))(
-            q, k, v, valid)
-    assert routes2 and routes2[0]["impl"] == "xla"
-    assert telemetry.counter("kernels.paged_attention").value == 1
-    assert np.array_equal(np.asarray(out_k), np.asarray(out_x))
+    outs = []
+    for setting, impl, reason in ((True, "paged", None),
+                                  (None, "xla", "interpreted"),
+                                  (False, "xla", "tier off")):
+        if setting is None:
+            config.unset("kernels.enabled")       # graduated default
+        else:
+            config.set("kernels.enabled", setting)    # explicit source
+        with kernels.record_paged_routes() as routes:
+            # (a new function each time: jit would reuse a trace)
+            outs.append(np.asarray(jax.jit(
+                lambda *a: kernels.paged_attention(*a))(*case)))
+        assert routes == [{"impl": impl, "reason": reason,
+                           "quantized": False}]
+        assert telemetry.counter("kernels.paged_attention").value == 1
+    assert telemetry.counter("kernels.gated_fallback").value == 1
+    assert telemetry.counter("kernels.paged_fallback").value == 0
+    assert np.array_equal(outs[1], outs[2])
+    np.testing.assert_allclose(outs[0], outs[1], rtol=2e-6, atol=2e-6)
 
 
 def test_paged_unsupported_reasons():
-    q, k, v, valid, _, ks = _paged_case()
-    assert kernels.paged_unsupported_reason(q, k, v, valid) is None
+    q, kp, vp, table, lengths, _, _ = _pool_case(LENGTHS["ragged"], 8)
+    reason = kernels.paged_unsupported_reason
+    assert reason(q, kp, vp, table, lengths) is None
     # multi-row query: prefill shapes never take the decode kernel
     q2 = jnp.concatenate([q, q], axis=2)
-    assert "query row" in kernels.paged_unsupported_reason(
-        q2, k, v, valid)
+    assert "query row" in reason(q2, kp, vp, table, lengths)
+    # a pool of another build's [P, psz, H, Dh] pages is refused, typed
+    assert "rank" in reason(q, kp.reshape(48, PSZ, HEADS, DH), vp, table,
+                            lengths)
+    assert "row width" in reason(q, kp[..., :DH], vp[..., :DH], table,
+                                 lengths)
+    assert "batch" in reason(q, kp, vp, table[:2], lengths)
     # int8 pages without the quantized contract are refused
-    assert kernels.paged_unsupported_reason(
-        q, k.astype(jnp.int8), v, valid) is not None
-    assert kernels.paged_unsupported_reason(
-        q, k.astype(jnp.int8), v.astype(jnp.int8), valid,
-        quantized=True) is None
-    # a kv slice over the VMEM budget is infeasible, typed
-    config.set("kernels.vmem_budget", 64)
-    reason = kernels.paged_unsupported_reason(q, k, v, valid)
-    assert reason is not None and "vmem" in reason.lower()
-    config.set("kernels.vmem_budget", VMEM_DEFAULT)
-    assert kernels.paged_unsupported_reason(q, k, v, valid) is None
+    assert reason(q, kp.astype(jnp.int8), vp, table, lengths) is not None
+    assert reason(q, kp.astype(jnp.int8), vp.astype(jnp.int8), table,
+                  lengths, quantized=True) is None
+    # tier on, shape refused: the twin serves it and the reason is kept
+    config.set("kernels.enabled", True)
+    telemetry.reset()
+    with kernels.record_paged_routes() as routes:
+        kernels.paged_attention(q, kp.astype(jnp.float16), vp, table,
+                                lengths)
+    assert routes[0]["impl"] == "xla" and "dtype" in routes[0]["reason"]
+    assert telemetry.counter("kernels.paged_fallback").value == 1
+
+
+@pytest.mark.parametrize("in_trace", [False, True], ids=["eager", "traced"])
+def test_paged_kernel_the_compiler_refuses_raises(monkeypatch, in_trace):
+    """A kernel that cannot compile is an ERROR out of the routed call —
+    from inside a jit trace too — never a quiet route to the twin."""
+    class MosaicSaysNo(Exception):
+        pass
+
+    def refuse(*_a, **_k):
+        raise MosaicSaysNo("block shape (6, 64) is not a legal TPU block")
+
+    monkeypatch.setattr(kernels, "pallas_paged_attention", refuse)
+    config.set("kernels.enabled", True)
+    case = _pool_case(LENGTHS["ragged"], 8)[:5]
+    fn = jax.jit(lambda *a: kernels.paged_attention(*a)) if in_trace \
+        else kernels.paged_attention
+    with kernels.record_paged_routes() as routes:
+        with pytest.raises(MosaicSaysNo, match="not a legal TPU block"):
+            fn(*case)
+    assert [r["impl"] for r in routes] == ["paged"]
 
 
 # ----------------------------------------------------------- row softmax

@@ -114,44 +114,48 @@ def test_every_flash_candidate_of_the_search_compiles(compile_for_chip, S):
 
 
 # --------------------------------------------------------- paged decode
-def _paged_specs(spec, K, kv_dtype, q_dtype):
-    specs = [spec((B, H, 1, D), q_dtype), spec((B, H, K, D), kv_dtype),
-             spec((B, H, K, D), kv_dtype), spec((B, K), jnp.bool_)]
+# the serving cell's shapes (benchmarks/configs/opt_1p3b.json): 32 rows,
+# 1,536 pages of 16 tokens, 32 heads x 64
+CELL = dict(B=32, P=1536, psz=16, H=32, D=64)
+
+
+def _paged_specs(spec, W, kv_dtype, q_dtype, B, P, psz, H, D):
+    specs = [spec((B, H, 1, D), q_dtype), spec((P, psz, H * D), kv_dtype),
+             spec((P, psz, H * D), kv_dtype), spec((B, W), jnp.int32),
+             spec((B,), jnp.int32)]
     if kv_dtype == I8:
-        specs += [spec((B, H, K), F32)] * 2
+        specs += [spec((P, psz, H), F32)] * 2
     return specs
 
 
-def _paged(block_bh=None):
-    def fn(q, k, v, valid, *scales):
-        kw = dict(zip(("k_scale", "v_scale"), scales))
-        return pk.pallas_paged_attention(q, k, v, valid, block_bh=block_bh,
-                                         **kw)
-    return fn
+def _paged(q, k, v, table, lengths, *scales):
+    return pk.pallas_paged_attention(
+        q, k, v, table, lengths, **dict(zip(("k_scale", "v_scale"), scales)))
 
 
-@pytest.mark.parametrize("K", [1024, 2048])
+@pytest.mark.parametrize("W", [128, 8])
 @pytest.mark.parametrize("kv_dtype, q_dtype",
                          [(F32, F32), (BF16, BF16), (I8, BF16)],
                          ids=["f32", "bf16", "int8"])
-def test_paged_attention_compiles(compile_for_chip, K, kv_dtype, q_dtype):
-    text = compile_for_chip(
-        _paged(), *_paged_specs(compile_for_chip.spec, K, kv_dtype, q_dtype))
+def test_paged_attention_compiles_at_the_cells_shapes(
+        compile_for_chip, W, kv_dtype, q_dtype):
+    """The in-place kernel: pools left in HBM, table and lengths by scalar
+    prefetch, 64 KiB page copies into double-buffered VMEM tiles.  The
+    pools reach it as they are: no copy of a pool-sized array."""
+    text = compile_for_chip(_paged, *_paged_specs(
+        compile_for_chip.spec, W, kv_dtype, q_dtype, **CELL))
     assert _kernel_count(text) == 1
+    pool = "[%d,%d,%d]" % (CELL["P"], CELL["psz"], CELL["H"] * CELL["D"])
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and pool in ln.split(" copy(")[0]]
 
 
-@pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
-def test_every_paged_candidate_of_the_search_compiles(compile_for_chip,
-                                                      kv_dtype):
-    K = 2048
-    cands = autotune._paged_candidates(B * H, K, D,
-                                       jnp.dtype(kv_dtype).itemsize,
-                                       kv_dtype == I8)
-    assert cands[0] == 1
-    for bb in cands:
-        text = compile_for_chip(_paged(bb), *_paged_specs(
-            compile_for_chip.spec, K, kv_dtype, BF16))
-        assert _kernel_count(text) == 1, bb
+def test_paged_attention_compiles_for_the_default_config(compile_for_chip):
+    """12 heads x 64: a 768-lane row, six lane tiles."""
+    text = compile_for_chip(_paged, *_paged_specs(
+        compile_for_chip.spec, 128, BF16, BF16, B=B, P=512, psz=16, H=H,
+        D=D))
+    assert _kernel_count(text) == 1
 
 
 # -------------------------------------------------- optimizer epilogues
@@ -219,17 +223,17 @@ def _default_lm(mesh=None):
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_decode_step_program_of_the_default_config_compiles(
         compile_for_chip, kernel_tier_on, quantized):
-    """One whole decode iteration as the server exports it: 12 scanned
-    layers, page-table gather, the paged kernel baked in."""
+    """One whole decode iteration: 12 scanned layers, the paged kernel
+    baked in on the layer's slice of the pool, and no gather."""
     spec = compile_for_chip.spec
     model, shapes = _default_lm()
     cfg = model.cfg
     psz, pool, width = 16, 512, 128       # width 128: a 2048-slot window
-    page = (cfg.num_layers, pool, psz, cfg.num_heads, cfg.head_dim)
-    kv = {n: spec(page, I8 if quantized else cfg.dtype) for n in "kv"}
-    if quantized:
-        kv.update({n: spec(page[:-1], F32) for n in ("k_scale", "v_scale")})
-    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), shapes)
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    kv = on_chip(jax.eval_shape(
+        lambda: model.init_kv_pages(pool, psz, quantized=quantized)))
+    params = on_chip(shapes)
     with kernels.record_paged_routes() as routes:
         text = compile_for_chip(
             lambda ps, kv, tok, pos, table: model.decode_step(
@@ -238,6 +242,54 @@ def test_decode_step_program_of_the_default_config_compiles(
             spec((B, width), jnp.int32))
     assert routes and routes[0]["impl"] == "paged", routes
     assert _kernel_count(text) == 1       # the scan body holds it once
+    assert "mx.kv_gather" not in text and "mx.paged_attention" in text
+
+
+def test_decode_programs_exported_as_the_benchmark_does_take_the_kernel(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``export_generation(sampling=True, decode_batch=32)`` as
+    ``benchmarks/drivers/serve_lm.py`` calls it, lowered for the TPU: the
+    pool's page count is symbolic in the artifact, and every decode width
+    still records route "paged"; reloaded and compiled for the described
+    chip at the cell's pool, widths 128 and 8 hold the kernel and no
+    gather.  (Two layers and a small vocabulary: the kernel's shapes are
+    the cell's.)"""
+    import json
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    spec = compile_for_chip.spec
+    model = TransformerLM(TransformerLMConfig(
+        dtype=BF16, vocab_size=512, num_layers=2, d_model=2048,
+        num_heads=32, d_ff=256, max_len=2048))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    deploy.export_generation(
+        model, params, prefix, sampling=True, decode_batch=32,
+        prompt_buckets=[128], max_context=2048, page_size=16,
+        include_params=False)
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    assert sorted(meta["paged"], key=int) == [
+        "1", "2", "4", "8", "16", "32", "64", "128"]
+    assert all(r["impl"] == "paged" for r in meta["paged"].values()), \
+        meta["paged"]
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows = 32
+    for width in (128, 8):
+        with open("%s-decode-w%d.stablehlo" % (prefix, width), "rb") as f:
+            exp = jexport.deserialize(f.read())
+        text = compile_for_chip(
+            exp.call, on_chip(params),
+            on_chip(deploy._kv_pool_specs(meta["kv"], CELL["P"])),
+            spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+            spec((rows, width), jnp.int32), spec((rows,), F32),
+            spec((rows,), jnp.int32), spec((rows,), F32),
+            spec((rows, 2), jnp.uint32))
+        assert _kernel_count(text) == 1, width
+        assert "mx.kv_gather" not in text, width
 
 
 def test_routed_attention_compiles_per_shard_on_a_2x2_mesh(

@@ -17,14 +17,10 @@ no TPU), with one parseable JSON line on stdout:
                  scanned stack's value_and_grad and persists the
                  fastest (mode, remat), which ``runtime.stack_tuning``
                  then reports while both knobs sit at defaults;
-  4. paged     — a default-source ``kernels.paged_attention`` call
-                 (the generation decode seam) searches the paged
-                 block-size space against the XLA lowering and persists
-                 a ``paged|`` entry with a parity verdict;
-  5. reload    — after ``autotune.reset()`` (the in-process stand-in
+  4. reload    — after ``autotune.reset()`` (the in-process stand-in
                  for a fresh process; tests/test_autotune.py does the
-                 real subprocess round-trip) the same lookups — the
-                 paged decode pick included — come back from disk:
+                 real subprocess round-trip) the same lookups come back
+                 from disk:
                  ``autotune.cache_hit`` > 0 and ZERO new
                  ``autotune.measure`` — the applied pick is the
                  persisted winner, re-measured never.
@@ -131,30 +127,7 @@ def main():
         result["stack"] = {"winner": sentry["impl"],
                            "candidates": sentry["candidates"]}
 
-        # 4. paged decode seam: a default-source paged_attention call
-        # triggers the block-size search once and persists the verdict
-        B, H, K, D = 2, 2, 16, 8
-        pq = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
-        pk = jnp.asarray(rng.randn(B, H, K, D), jnp.float32)
-        pv = jnp.asarray(rng.randn(B, H, K, D), jnp.float32)
-        pvalid = jnp.arange(K)[None, :] < jnp.asarray([[9], [K]])[:, 0:1]
-        pvalid = jnp.broadcast_to(pvalid, (B, K))
-        pout = kernels.paged_attention(pq, pk, pv, pvalid)
-        jax.block_until_ready(pout)
-        with open(cache) as f:
-            persisted = json.load(f)
-        pkeys = [kk for kk in persisted["entries"]
-                 if kk.startswith("paged|")]
-        assert pkeys, persisted["entries"].keys()
-        pentry = persisted["entries"][pkeys[0]]
-        assert pentry["impl"] in ("paged", "xla"), pentry
-        assert pentry.get("parity") in ("bitwise", "tolerance"), pentry
-        result["paged"] = {"impl": pentry["impl"],
-                           "block_bh": pentry.get("block_bh"),
-                           "speedup": pentry.get("speedup"),
-                           "parity": pentry.get("parity")}
-
-        # 5. reload: fresh in-memory state, same cache file — every pick
+        # 4. reload: fresh in-memory state, same cache file — every pick
         # comes back from disk with ZERO new measurements
         autotune.reset()
         telemetry.reset()
@@ -162,15 +135,11 @@ def main():
         jax.block_until_ready(out2)
         fused_on2 = kernels.fused_step_enabled(opt)
         assert fused_on2 == fused_on, (fused_on2, fused_on)
-        pout2 = kernels.paged_attention(pq, pk, pv, pvalid)
-        jax.block_until_ready(pout2)
-        np.testing.assert_array_equal(np.asarray(pout2),
-                                      np.asarray(pout))
         hits = telemetry.counter("autotune.cache_hit").value
         measures2 = telemetry.counter("autotune.measure").value
         searches2 = telemetry.counter("autotune.search").value
         applied = telemetry.counter("autotune.applied").value
-        assert hits >= 3, hits
+        assert hits >= 2, hits
         assert measures2 == 0, measures2
         assert searches2 == 0, searches2
         assert applied >= 2, applied
