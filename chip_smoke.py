@@ -12,9 +12,13 @@ one process; nothing here starts a child once jax is touched):
            ``export_generation`` ON THE CHIP, ``Server.register(...,
            generate=True)``, ragged prompts some sharing a prefix, streams
            checked against ``TransformerLM.greedy_decode``
+  serve_hybrid  the same server over the second kind of cache: a two-
+           period ``HybridLM`` (Mamba-2, held experts, grouped-query
+           attention), K/V pages beside per-slot recurrent state, more
+           requests than slots, the paged kernel's route counted
   kernels  every Pallas kernel on those paths, compiled, against its XLA twin
 
-``--chips 4`` runs ``device`` and, instead of the three phases above, the
+``--chips 4`` runs ``device`` and, instead of the phases above, the
 paths that exist only across chips: ResNet-50 on a dp=4 mesh and one
 TransformerLM loss+grad step on a dp=2 x tp=2 mesh, each against the same
 computation on a one-device mesh in the same process.
@@ -102,6 +106,16 @@ def _sizes(rehearse):
             prompts=(4, 9, 20, 33, 40, 47, 64, 90), shared=(2, 3, 4),
             prompt_buckets=(8, 32, 64, 128),
             prefix=16, new_tokens=4, decode_batch=8, kv_pages=96,
+            hybrid=dict(vocab_size=256, pattern="MEM*EM*E", d_model=64,
+                        num_heads=4, num_kv_heads=2, head_dim=16,
+                        ssm_heads=8, ssm_head_dim=8, ssm_groups=2,
+                        ssm_state=16, chunk=8, num_experts=16, top_k=3,
+                        moe_latent=32, expert_ff=48, shared_ff=96,
+                        route_scale=5.0, experts_held=8, expert_offset=4,
+                        max_len=128),
+            hybrid_serve=dict(prompts=(4, 9, 20, 30, 7, 13),
+                              prompt_buckets=(8, 32), new_tokens=6,
+                              decode_batch=4, page=16, kv_pages=32),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
             epilogue=(24, 40), softmax=(16, 48), sbr=(16, 40),
             dp_batch=16, lm_batch=4, lm_seq=32)
@@ -115,6 +129,19 @@ def _sizes(rehearse):
         # and that sort alone is ~20 s of TPU compile per program
         prompt_buckets=(32, 128, 256, 512, 1024),
         prefix=128, new_tokens=32, decode_batch=8, kv_pages=512,
+        # two periods of a Mamba-2 / experts / attention pattern, toy
+        # widths with the lanes of real ones (heads of 128, state 128)
+        hybrid=dict(vocab_size=8192, pattern="MEM*EM*E", d_model=1024,
+                    num_heads=16, num_kv_heads=2, head_dim=128,
+                    ssm_heads=32, ssm_head_dim=64, ssm_groups=4,
+                    ssm_state=128, chunk=128, num_experts=32, top_k=4,
+                    moe_latent=256, expert_ff=512, shared_ff=1024,
+                    route_scale=5.0, experts_held=16, expert_offset=8,
+                    max_len=1024),
+        hybrid_serve=dict(prompts=(40, 100, 200, 384, 130, 61, 250, 17,
+                                   300, 90),
+                          prompt_buckets=(128, 512), new_tokens=24,
+                          decode_batch=8, page=128, kv_pages=64),
         flash=((8, 12, 1024, 64), (2, 12, 200, 64)), paged_k=(1024, 2048),
         epilogue=(768, 3072), softmax=(4096, 1024), sbr=(4096, 768),
         dp_batch=512, lm_batch=4, lm_seq=1024)
@@ -426,6 +453,99 @@ def phase_serve(args, sz):
              "stop() left the engine running or pages in use: %s" % gstats)
 
 
+def phase_serve_hybrid(args, sz):
+    """The second kind of cache: a hybrid decoder (Mamba-2, held experts,
+    grouped-query attention) behind the same server — K/V pages AND
+    per-slot recurrent state, more requests than slots so that slots are
+    used again, the paged kernel at several queries a K/V head."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    hs = sz["hybrid_serve"]
+    model = HybridLM(HybridLMConfig(**sz["hybrid"]))
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+    rng = np.random.RandomState(args.seed)
+    prompts = [rng.randint(0, model.cfg.vocab_size, n).astype(np.int32)
+               for n in hs["prompts"]]
+    new = hs["new_tokens"]
+    names = ("kernels.paged_attention", "kernels.paged_fallback",
+             "serving.compiles", "serving.prefix_share_refused") \
+        + tuple("serving." + n for n in model.decode_stats)
+
+    def counts():
+        return {n: telemetry.counter(n).value for n in names}
+
+    with _knobs(kernels__enabled=True, serving__kv_pages=hs["kv_pages"]), \
+            tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+        prefix = os.path.join(tmp, "hybrid")
+        width = model.cfg.max_len // hs["page"]
+        mx.deploy.export_generation(
+            model, params, prefix, sampling=True,
+            decode_batch=hs["decode_batch"], page_size=hs["page"],
+            prompt_buckets=hs["prompt_buckets"], decode_widths=[width])
+        with open(prefix + "-meta.json") as f:
+            meta = json.load(f)
+        before = counts()
+        srv = mx.serving.Server()
+        engine = srv.register("hybrid", prefix, generate=True)
+        srv.start()
+        started = counts()
+        steps0 = telemetry.timer("serving.decode_step_ms").count
+        t0 = time.perf_counter()
+        futs = [srv.submit_generate("hybrid", p, new) for p in prompts]
+        served = [np.asarray(f.result(timeout=900)) for f in futs]
+        traffic_s = time.perf_counter() - t0
+        steps = telemetry.timer("serving.decode_step_ms").count - steps0
+        after = counts()
+        state_touched = [bool(np.asarray(a).any()) for a in engine._kv[2:]]
+        srv.stop()
+    with _knobs(kernels__enabled=False):
+        gaps = _teacher_forced_gaps(model, params, prompts, served)
+    delta = {n: after[n] - started[n] for n in names}
+    # wider than the dense model's 4 ulps: bf16 activations move the
+    # router's scores, a token whose last chosen expert swaps with the next
+    # one moves by more than rounding, and only such tokens do (PERF.md
+    # section 6, PR 27) — so few tokens may flip, and none by much
+    tol = 16 * 2.0 ** -8 * max(g["logit_absmax"] for g in gaps)
+    worst = max(g["max_gap"] for g in gaps)
+    flipped = sum(g["flips"] for g in gaps)
+    _emit("serve_hybrid", pattern=model.cfg.pattern,
+          prompt_lens=list(hs["prompts"]),
+          new_tokens=new, decode_slots=hs["decode_batch"],
+          paged_routes={w: r.get("impl") for w, r in meta["paged"].items()},
+          state_arrays=[s["name"] for s in meta["kv"]["state"]],
+          traffic_s=round(traffic_s, 3), decode_iterations=steps,
+          counters=delta, tokens_flipped=flipped,
+          tokens_total=new * len(prompts), worst_logit_gap=round(worst, 6),
+          logit_gap_tolerance=round(tol, 6),
+          peak_bytes=_peak_bytes(jax.devices()[0]))
+    _require(all(s.shape == (new,) for s in served),
+             "a request did not return %d tokens" % new)
+    _require(worst <= tol and flipped <= 0.25 * new * len(prompts),
+             "served tokens leave the oracle: worst logit gap %.5f > "
+             "tolerance %.5f, or %d tokens flipped" % (worst, tol, flipped))
+    _require(steps > 0 and delta["kernels.paged_attention"] == steps
+             and delta["kernels.paged_fallback"] == 0,
+             "the paged kernel ran %d of %d decode iterations"
+             % (delta["kernels.paged_attention"], steps))
+    _require(all(state_touched) and len(state_touched)
+             == 2 * model.cfg.pattern.count("M"),
+             "a state array was never written: %s" % state_touched)
+    _require(delta["serving.moe_pairs"] > 0
+             and delta["serving.moe_experts_hit"] > 0,
+             "no expert pair was counted: %s" % delta)
+    _require(delta["serving.compiles"] == 0,
+             "traffic compiled %d program(s) after start()"
+             % delta["serving.compiles"])
+    _require(started["serving.prefix_share_refused"]
+             == before["serving.prefix_share_refused"] + 1,
+             "prefix sharing was not refused for a model with state")
+
+
 def _teacher_forced_gaps(model, params, prompts, served):
     """For each request, one oracle forward over prompt + served tokens:
     per generated position, how far the oracle's logit for the served
@@ -721,7 +841,7 @@ def main(argv=None):
     t0 = time.perf_counter()
     phase_device(args)
     phases = [phase_four_chips] if args.chips == 4 else \
-        [phase_train, phase_serve, phase_kernels]
+        [phase_train, phase_serve, phase_serve_hybrid, phase_kernels]
     for phase in phases:
         phase(args, sz)
     import mxnet_tpu as mx

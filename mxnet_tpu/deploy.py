@@ -71,7 +71,15 @@ Format history (``meta["format_version"]``):
       greedy-only semantics.  The pool keeps a page as ``[page_size,
       heads*head_dim]`` rows (``meta["kv"]["row_width"]``); an artifact
       of a build whose pages were ``[page_size, heads, head_dim]`` is
-      refused at load, in words.
+      refused at load, in words.  ``meta["kv"]`` describes the cache in
+      typed regions: the PAGES (``num_layers`` counts the layers that
+      attend, ``num_heads`` the K/V heads) and, for a model that keeps
+      one (``models.HybridLM``), a STATE region — ``meta["kv"]["state"]``,
+      per decode slot arrays such as a recurrent state — that rides the
+      same cache pytree behind the pools; such an artifact's prefill
+      programs take the slot to leave the prompt's state in, its batch
+      dims are concrete, and ``meta["decode_stats"]`` names the counts a
+      decode step returns behind its tokens.
 """
 from __future__ import annotations
 
@@ -382,12 +390,16 @@ _KV_KEYS = ("k", "v")
 _KV_KEYS_QUANT = ("k", "v", "k_scale", "v_scale")
 
 
-def _kv_pool_specs(kv, num_pages):
-    """ShapeDtypeStructs of the page pool a ``meta["kv"]`` dict describes,
-    in ``_KV_KEYS`` order: K and V ``[L, num_pages, page_size, H*Dh]``
-    (a page is one lane-exact, contiguous block on the device), plus the
-    ``[L, num_pages, page_size, H]`` f32 scale pools of an int8 pool.
-    ``num_pages`` may be a symbolic dimension."""
+def _kv_pool_specs(kv, num_pages, slots=None):
+    """ShapeDtypeStructs of the cache a ``meta["kv"]`` dict describes.
+    Its PAGES region, in ``_KV_KEYS`` order: K and V ``[L, num_pages,
+    page_size, row_width]`` over the ``L`` layers that attend (a page is
+    one lane-exact, contiguous block on the device; ``row_width`` is K/V
+    heads x head size), plus the ``[L, num_pages, page_size, H]`` f32
+    scale pools of an int8 pool.  Then its STATE region, if the model
+    keeps one (``kv["state"]``: ``{"name", "shape", "dtype"}`` each): one
+    ``[slots, *shape]`` array apiece, a row per decode slot.
+    ``num_pages`` and ``slots`` may be symbolic dimensions."""
     import jax
     import jax.numpy as jnp
     rows = (kv["num_layers"], num_pages, kv["page_size"])
@@ -398,14 +410,19 @@ def _kv_pool_specs(kv, num_pages):
                 jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32),
                 jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32))
     dt = jnp.dtype(kv["dtype"])
-    return (jax.ShapeDtypeStruct(wide, dt), jax.ShapeDtypeStruct(wide, dt))
+    return (jax.ShapeDtypeStruct(wide, dt), jax.ShapeDtypeStruct(wide, dt)) \
+        + tuple(jax.ShapeDtypeStruct((slots,) + tuple(st["shape"]),
+                                     jnp.dtype(st["dtype"]))
+                for st in kv.get("state", ()))
 
 
 def export_generation(model, params, prefix, page_size=None,
                       max_context=None, prompt_buckets=None,
                       include_params=True, sampling=False,
-                      kv_quantized=False, decode_batch=None):
-    """Serialize a generation-capable model (``models.TransformerLM``) to
+                      kv_quantized=False, decode_batch=None,
+                      decode_widths=None, replay=False):
+    """Serialize a generation-capable model (``models.TransformerLM``,
+    ``models.HybridLM``) to
     a v4/v5 artifact: one PREFILL program per prompt-length bucket and
     one single-token DECODE-step program per page-table width, both over
     a block-paged KV cache whose pool size — and the batch dim — stay
@@ -433,10 +450,33 @@ def export_generation(model, params, prefix, page_size=None,
     server's choice.  A decode program that cannot take the kernel —
     symbolic batch, tier off, a jax without that lowering — runs the XLA
     twin, and either way the per-width verdict and its reason are
-    recorded in ``meta["paged"]``.  Returns the list of written
-    paths."""
+    recorded in ``meta["paged"]``.  ``decode_widths`` keeps a subset
+    of the page-table widths (the widest always): on the kernel's route a
+    row costs what it holds, not the table's width, so one program may do.
+
+    A model whose ``kv_spec()`` has a ``state`` region (per-slot arrays
+    beside the pages: a recurrent state) is written as v5 whatever the
+    flags: its programs take and return pages and state as the one
+    cache pytree, a prefill program also takes ``slots`` [B] int32 —
+    the state row each prompt's final state is left in — and a decode
+    program's row b is slot b.  Where the model counts things in a decode
+    step (``model.decode_stats``), the int32 counts ride behind the
+    tokens in the one array a step returns (``meta["decode_stats"]``).
+    A model that says ``symbolic_batch = False`` (its grouped products
+    run over token rows, and the shape refinement of a reloaded artifact
+    cannot carry a symbolic row count through them) gets concrete batch
+    dims: ``decode_batch`` is then required, and the prefill programs are
+    written at the batch of one the engine runs them at
+    (``meta["prefill_batch"]``).  ``replay`` (a model that says ``replay =
+    True``) makes every program also say what replaying a request
+    elsewhere needs — the log-probability of each token it produced and
+    the experts each token it was fed chose in each mixture-of-experts
+    block — behind the tokens and the counts in the same int32 array (the
+    float32 bits as they are; ``meta["replay"]``: ``{"layers",
+    "top_k"}``): what ``submit_generate(return_replay=True)`` is answered
+    from.  Returns the list of written paths."""
     import jax
-    from jax import export as jexport
+    from jax import export as jexport, lax
     import jax.numpy as jnp
     from . import config as _config
     from . import kernels as _kernels
@@ -461,21 +501,55 @@ def export_generation(model, params, prefix, page_size=None,
             "prompt_buckets %r must be non-empty and fit max_context %d"
             % (prompt_buckets, max_context))
     widths = _pow2_family(_math.ceil(max_context / psz))
-    v5 = bool(sampling or kv_quantized or decode_batch is not None)
+    if decode_widths is not None:
+        keep = {int(w) for w in decode_widths} | {widths[-1]}
+        if keep - set(widths):
+            raise ValueError("decode_widths %r are not all of the family "
+                             "%r" % (sorted(keep), widths))
+        widths = tuple(w for w in widths if w in keep)
+    spec = model.kv_spec(quantized=True) if kv_quantized \
+        else model.kv_spec()
+    state_names = tuple(st["name"] for st in spec.get("state", ()))
+    stat_names = tuple(getattr(model, "decode_stats", ()))
+    v5 = bool(sampling or kv_quantized or decode_batch is not None
+              or state_names)
     if decode_batch is not None:
         decode_batch = int(decode_batch)
         if decode_batch < 1:
             raise ValueError("decode_batch must be >= 1, got %d"
                              % decode_batch)
-    kv_keys = _KV_KEYS_QUANT if kv_quantized else _KV_KEYS
+    kv_keys = (_KV_KEYS_QUANT if kv_quantized else _KV_KEYS) + state_names
+    if replay and not getattr(model, "replay", False):
+        raise ValueError("%s's programs do not say what a replay needs"
+                         % type(model).__name__)
+    replay_kw = {"return_replay": True} if replay else {}
+    routed_shape = []       # [E blocks, top_k], as the programs return it
+
+    def _behind(nxt, more):
+        # one array a call: counts, then the produced tokens' float32
+        # log-probabilities (their bits), then the experts chosen
+        more = list(more)
+        if replay_kw:
+            routed, logprob = more[-2:]
+            more[-2:] = [lax.bitcast_convert_type(logprob, jnp.int32),
+                         routed]
+        return jnp.concatenate([nxt] + [
+            m.reshape(-1).astype(nxt.dtype) for m in more]) if more else nxt
+
+    fixed_batch = not getattr(model, "symbolic_batch", True)
+    if fixed_batch and decode_batch is None:
+        raise ValueError(
+            "%s's programs take concrete batch dims: give decode_batch"
+            % type(model).__name__)
 
     flat = _flatten_params(params)
     names = [n for n, _ in flat]
-    values = [jnp.asarray(v) for _, v in flat]
+    # (shapes alone will do where no params file is written)
+    values = [v if isinstance(v, jax.ShapeDtypeStruct) and not include_params
+              else jnp.asarray(v) for _, v in flat]
     param_tree = _unflatten_params(dict(zip(names, values)))
     pspec = jax.tree_util.tree_map(
         lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), param_tree)
-    spec = model.kv_spec(quantized=kv_quantized) if v5 else model.kv_spec()
 
     paths = []
     paged_routes = {}
@@ -500,10 +574,12 @@ def export_generation(model, params, prefix, page_size=None,
         scope = jexport.SymbolicScope()
         (b,) = jexport.symbolic_shape("b", scope=scope)
         (p,) = jexport.symbolic_shape("p", scope=scope)
-        return b, p
+        if not state_names:
+            return b, p, None
+        return (b, p) + jexport.symbolic_shape("s", scope=scope)
 
-    def _kv_specs(p):
-        return _kv_pool_specs(dict(spec, page_size=psz), p)
+    def _kv_specs(p, slots=None):
+        return _kv_pool_specs(dict(spec, page_size=psz), p, slots)
 
     i32 = jnp.int32
 
@@ -515,22 +591,27 @@ def export_generation(model, params, prefix, page_size=None,
 
     for s_bucket in prompt_buckets:
         w_s = _math.ceil(s_bucket / psz)
-        b, p = _dims()
+        b, p, n_slots = _dims()
+        if fixed_batch:
+            b = 1
         if v5:
-            def prefill_fn(ps, kv, tokens, lengths, table,
-                           temp, top_k, top_p, keys):
+            def prefill_fn(ps, kv, tokens, lengths, table, *rest):
+                # (a model with a state region is also told its slots)
+                *slots, temp, top_k, top_p, keys = rest
                 sample = {"temperature": temp, "top_k": top_k,
                           "top_p": top_p, "key": keys}
-                nkv, nxt = model.prefill(ps, dict(zip(kv_keys, kv)),
-                                         tokens, lengths, table, psz,
-                                         sample=sample)
-                return tuple(nkv[k] for k in kv_keys), nxt
+                nkv, nxt, *more = model.prefill(
+                    ps, dict(zip(kv_keys, kv)), tokens, lengths, table, psz,
+                    sample=sample, **({"slots": slots[0]} if slots else {}),
+                    **replay_kw)
+                return tuple(nkv[k] for k in kv_keys), _behind(nxt, more)
 
-            specs = (pspec, _kv_specs(p),
+            specs = (pspec, _kv_specs(p, n_slots),
                      jax.ShapeDtypeStruct((b, s_bucket), i32),
                      jax.ShapeDtypeStruct((b,), i32),
                      jax.ShapeDtypeStruct((b, w_s), i32)) \
-                + _sample_specs(b)
+                + ((jax.ShapeDtypeStruct((b,), i32),) if state_names
+                   else ()) + _sample_specs(b)
         else:
             def prefill_fn(ps, kk, vv, tokens, lengths, table):
                 kv, nxt = model.prefill(ps, {"k": kk, "v": vv}, tokens,
@@ -546,19 +627,23 @@ def export_generation(model, params, prefix, page_size=None,
                     "%s-prefill-s%d.stablehlo" % (prefix, s_bucket))
 
     for width in widths:
-        b, p = _dims()
+        b, p, _ = _dims()
         bd = decode_batch if decode_batch is not None else b
         if v5:
             def decode_fn(ps, kv, token_ids, positions, table,
                           temp, top_k, top_p, keys):
                 sample = {"temperature": temp, "top_k": top_k,
                           "top_p": top_p, "key": keys}
-                nkv, nxt = model.decode_step(ps, dict(zip(kv_keys, kv)),
-                                             token_ids, positions, table,
-                                             psz, sample=sample)
-                return tuple(nkv[k] for k in kv_keys), nxt
+                nkv, nxt, *more = model.decode_step(
+                    ps, dict(zip(kv_keys, kv)), token_ids, positions, table,
+                    psz, sample=sample, **replay_kw,
+                    **({"return_stats": True} if stat_names else {}))
+                if replay_kw:
+                    routed_shape[:] = [more[-2].shape[0], more[-2].shape[-1]]
+                return tuple(nkv[k] for k in kv_keys), _behind(nxt, more)
 
-            specs = (pspec, _kv_specs(p),
+            # a decode program's row b is state slot b
+            specs = (pspec, _kv_specs(p, bd),
                      jax.ShapeDtypeStruct((bd,), i32),
                      jax.ShapeDtypeStruct((bd,), i32),
                      jax.ShapeDtypeStruct((bd, width), i32)) \
@@ -597,6 +682,12 @@ def export_generation(model, params, prefix, page_size=None,
         meta["sampling"] = True
         if decode_batch is not None:
             meta["decode_batch"] = decode_batch
+        if stat_names:
+            meta["decode_stats"] = list(stat_names)
+        if fixed_batch:
+            meta["prefill_batch"] = 1
+        if replay:
+            meta["replay"] = dict(zip(("layers", "top_k"), routed_shape))
     meta_path = prefix + "-meta.json"
     with open(meta_path, "w") as f:
         json.dump(meta, f)
@@ -619,7 +710,7 @@ class GenerationPredictor:
     scheduler; :meth:`generate` is the OFFLINE single-sequence
     convenience loop (and the shape the parity tests drive)."""
 
-    def __init__(self, prefix):
+    def __init__(self, prefix, params=None):
         import jax
         from jax import export as jexport
         from . import io as _io
@@ -658,7 +749,16 @@ class GenerationPredictor:
         #: program can never re-route at serve time)
         self.paged_routes = dict(self.meta.get("paged", {}))
         self._v5 = self.format_version >= SAMPLING_FORMAT_VERSION
-        self._kv_keys = _KV_KEYS_QUANT if self.kv_quantized else _KV_KEYS
+        #: the cache's state region (per-slot arrays beside the pages) and
+        #: the names of the counts a decode step returns behind its tokens
+        self.state = tuple(self.meta["kv"].get("state", ()))
+        self.decode_stats = tuple(self.meta.get("decode_stats", ()))
+        #: ``{"layers", "top_k"}`` (of the experts chosen) where every
+        #: program also returns what a replay needs, else None
+        self.replay = self.meta.get("replay")
+        self._kv_keys = (_KV_KEYS_QUANT if self.kv_quantized
+                         else _KV_KEYS) \
+            + tuple(st["name"] for st in self.state)
         self._prefill_exp = {}
         self._decode_exp = {}
         for s_bucket in self.prompt_buckets:
@@ -671,7 +771,21 @@ class GenerationPredictor:
                 self._decode_exp[width] = jexport.deserialize(f.read())
         params_path = prefix + "-params.npz"
         self._params = None
-        if os.path.exists(params_path):
+        if params is not None:
+            # the exporting process hands over what it exported: arrays
+            # already on the device take no trip through a file
+            given = dict(_flatten_params(params))
+            want = list(zip(self.meta["param_names"],
+                            self.meta["param_dtypes"]))
+            if sorted(given) != sorted(self.meta["param_names"]) or any(
+                    str(given[n].dtype) != d for n, d in want):
+                raise ValueError(
+                    "artifact %r: the params given are not the ones it "
+                    "was exported with (names or dtypes differ)" % (prefix,))
+            self._params = _unflatten_params({
+                n: _io.ensure_staged(given[n], source="deploy")
+                for n, _ in want})
+        elif os.path.exists(params_path):
             loaded = _load_params(params_path, self.meta)
             # one-time H2D, device-resident for the predictor's life
             self._params = _unflatten_params({
@@ -706,18 +820,18 @@ class GenerationPredictor:
     def prefill_fn(self, s_bucket):
         """Cached jit wrapper for one prefill bucket, UNIFORM across
         formats: ``fn(ps, kv_tuple, tokens, lengths, table, temp, top_k,
-        top_p, keys) -> (kv_tuple, next_ids)``.  The KV pool pytree is
-        DONATED so the appended-to cache aliases in place; v4 programs
-        ignore the sampling args (greedy is the only lowering they
-        carry)."""
+        top_p, keys) -> (kv_tuple, next_ids)``; a model with a state
+        region takes ``slots`` [B] int32 after ``table``.  The cache
+        pytree is DONATED so the appended-to cache aliases in place; v4
+        programs ignore the sampling args (greedy is the only lowering
+        they carry)."""
         fn = self._prefill_call.get(s_bucket)
         if fn is None:
             exp = self._prefill_exp[s_bucket]
             if self._v5:
                 fn = self._jax.jit(
-                    lambda ps, kv, tokens, lengths, table, temp, tk, tp,
-                    keys: exp.call(ps, kv, tokens, lengths, table,
-                                   temp, tk, tp, keys),
+                    lambda ps, kv, tokens, lengths, table, *rest: exp.call(
+                        ps, kv, tokens, lengths, table, *rest),
                     donate_argnums=(1,))
             else:
                 def fn_v4(ps, kv, tokens, lengths, table, temp, tk, tp,
@@ -749,18 +863,23 @@ class GenerationPredictor:
             self._decode_call[width] = fn
         return fn
 
-    def make_kv(self, num_pages):
-        """Zeroed page pool tuple sized for this artifact's KV spec —
+    def make_kv(self, num_pages, slots=None):
+        """Zeroed cache tuple sized for this artifact's KV spec —
         ``(k, v)`` or, for int8-KV artifacts, ``(k, v, k_scale,
-        v_scale)`` (int8 payloads + per-row f32 scales)."""
+        v_scale)`` (int8 payloads + per-row f32 scales), followed by the
+        state region's ``[slots, ...]`` arrays where the model has one."""
         import jax.numpy as jnp
         return tuple(jnp.zeros(s.shape, s.dtype)
-                     for s in self.kv_pool_specs(num_pages))
+                     for s in self.kv_pool_specs(num_pages, slots))
 
-    def kv_pool_specs(self, num_pages):
+    def kv_pool_specs(self, num_pages, slots=None):
         """ShapeDtypeStruct tuple matching :meth:`make_kv` — what the
         serving engine AOT-traces its programs against."""
-        return _kv_pool_specs(self.meta["kv"], int(num_pages))
+        if self.state and slots is None:
+            raise ValueError("this artifact's cache has a state region: "
+                             "say how many decode slots it serves")
+        return _kv_pool_specs(self.meta["kv"], int(num_pages),
+                              None if slots is None else int(slots))
 
     def sample_arrays(self, temperature, top_k, top_p, seeds):
         """Host-side per-row sampling operand build: lists/arrays of
@@ -812,7 +931,13 @@ class GenerationPredictor:
                 "%d" % (plen, max_new, self.max_context))
         psz = self.page_size
         need = _math.ceil((plen + max_new) / psz)
-        kv = self.make_kv(need)
+        # a concrete decode_batch pins the decode batch dim: row 0 is
+        # the live sequence (and state slot 0), the pad rows run against
+        # an all-sentinel table (their writes drop, their outputs are
+        # ignored)
+        Bd = self.decode_batch or 1
+        kv = self.make_kv(need, Bd if self.state else None)
+        slot0 = (jnp.zeros((1,), jnp.int32),) if self.state else ()
         pages = _np.arange(need, dtype=_np.int32)
         sentinel = need
         s_bucket = self.prefill_bucket(plen)
@@ -825,13 +950,10 @@ class GenerationPredictor:
                                    [int(seed)])
         kv, nxt = self.prefill_fn(s_bucket)(
             ps, kv, jnp.asarray(tokens),
-            jnp.asarray([plen], jnp.int32), jnp.asarray(table), *samp1)
+            jnp.asarray([plen], jnp.int32), jnp.asarray(table), *slot0,
+            *samp1)
         out = [int(nxt[0])]
         pos = plen
-        # a concrete decode_batch pins the decode batch dim: row 0 is
-        # the live sequence, the pad rows run against an all-sentinel
-        # table (their writes drop, their outputs are ignored)
-        Bd = self.decode_batch or 1
         sampB = self.sample_arrays(
             [temperature] + [0.0] * (Bd - 1), [int(top_k)] + [0] * (Bd - 1),
             [float(top_p)] + [1.0] * (Bd - 1), [int(seed)] + [0] * (Bd - 1))
@@ -852,9 +974,11 @@ class GenerationPredictor:
         return _np.asarray(out, _np.int32)
 
 
-def load_generator(prefix):
+def load_generator(prefix, params=None):
     """Reload a v4/v5 generation artifact (prefill + decode-step program
     families over a paged KV cache; v5 adds sampling controls, int8 KV
     pages and/or a pinned decode batch).  Refuses one-shot v1–v3
-    artifacts — those load with :func:`load_model`."""
-    return GenerationPredictor(prefix)
+    artifacts — those load with :func:`load_model`.  ``params`` (the
+    pytree the artifact was exported with) takes the place of the
+    ``-params.npz`` file, which an ``include_params=False`` export lacks."""
+    return GenerationPredictor(prefix, params=params)

@@ -146,11 +146,12 @@ class _GenRequest:
     __slots__ = ("prompt", "plen", "max_new", "eos_id", "future",
                  "t_submit", "deadline", "need", "stall_counted",
                  "request_id", "trace_id", "temperature", "top_k", "top_p",
-                 "key_words", "prefix_keys")
+                 "key_words", "prefix_keys", "want_replay")
 
     def __init__(self, prompt, max_new, eos_id, deadline_ms, need,
                  trace_id=None, temperature=0.0, top_k=0, top_p=1.0,
-                 seed=0, prefix_keys=(), request_id=None):
+                 seed=0, prefix_keys=(), request_id=None,
+                 want_replay=False):
         self.prompt = prompt
         self.plen = int(prompt.shape[0])
         self.max_new = int(max_new)
@@ -177,6 +178,7 @@ class _GenRequest:
         # key i covers tokens [0, (i+1)*page_size) — admission maps them
         # to shared physical pages
         self.prefix_keys = tuple(prefix_keys)
+        self.want_replay = bool(want_replay)
 
     def expired(self, now):
         return self.deadline is not None and now >= self.deadline
@@ -192,7 +194,8 @@ class _Slot:
     released through ``_release_pages_locked``, never freed directly)."""
 
     __slots__ = ("req", "pages", "pos", "tokens", "ttft_ms",
-                 "prefix_keys", "t_admit", "t_prefill_start", "token_t")
+                 "prefix_keys", "t_admit", "t_prefill_start", "token_t",
+                 "routed", "logprobs")
 
     def __init__(self, req, pages, t_admit, prefix_keys=()):
         self.req = req
@@ -206,6 +209,11 @@ class _Slot:
         self.t_admit = t_admit
         self.t_prefill_start = None
         self.token_t = []
+        # where the request asked for a replay: the experts chosen, [E
+        # blocks, n, top_k] for the prompt and then [E blocks, 1, top_k]
+        # a fed token; a log-probability a produced token
+        self.routed = []
+        self.logprobs = []
 
 
 class GenerationEngine:
@@ -245,6 +253,15 @@ class GenerationEngine:
                 "model %r: serving.kv_pages=%d cannot hold one page"
                 % (name, self.num_pages))
         self._share = bool(_config.get("serving.shared_prefix"))
+        if self._share and predictor.state:
+            # a shared page skips the prefill that would have built the
+            # sharer's recurrent state: no sharing for such a model
+            self._share = False
+            _telemetry.counter("serving.prefix_share_refused").inc()
+            _LOG.warning(
+                "serving: model %r keeps per-slot state beside its K/V "
+                "pages (%d arrays); serving.shared_prefix is refused for "
+                "it", name, len(predictor.state))
         # Cross-thread state (submit side vs engine thread) — the same
         # lock-discipline contract tools/mxlint.py checks on the Server.
         self._queue = deque()            # guarded-by: _cond
@@ -264,7 +281,9 @@ class GenerationEngine:
         # Engine-thread-only state: the page pool arrays and decode slots
         # are touched exclusively by the engine loop — no lock.
         self._slots = [None] * self.decode_slots
-        self._kv = None       # page-pool pytree (2 arrays, 4 when int8)
+        # the cache pytree: the page pool (2 arrays, 4 when int8), then the
+        # state region's per-slot arrays where the model keeps one
+        self._kv = None
         self._prefill = {}    # prompt bucket -> compiled program
         self._decode = {}     # page-table width -> compiled program
         self._iteration = 0   # engine.iteration spans, numbered from 1
@@ -284,8 +303,10 @@ class GenerationEngine:
         params = gp._params
         pspec = jax.tree_util.tree_map(
             lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), params)
-        kvspec = gp.kv_pool_specs(self.num_pages)
+        kvspec = gp.kv_pool_specs(self.num_pages, self._state_slots())
         i32 = _np.int32
+        # a prefill of a model with a state region is told its slot
+        slot_spec = (jax.ShapeDtypeStruct((1,), i32),) if gp.state else ()
 
         def sample_specs(b):
             # the uniform program wrappers take the sampling operands in
@@ -326,7 +347,7 @@ class GenerationEngine:
                  jax.ShapeDtypeStruct((1, s_bucket), i32),
                  jax.ShapeDtypeStruct((1,), i32),
                  jax.ShapeDtypeStruct((1, w_s), i32))
-                + sample_specs(1),
+                + slot_spec + sample_specs(1),
                 "prefill-s%d" % s_bucket)
         for width in gp.decode_widths:
             if width in self._decode:
@@ -346,7 +367,7 @@ class GenerationEngine:
             if self._started:
                 return self
         self._compile_programs()
-        self._kv = self.predictor.make_kv(self.num_pages)
+        self._kv = self._make_kv()
         with self._cond:
             self._stopping = False
             self._abort = False
@@ -389,14 +410,26 @@ class GenerationEngine:
     # ------------------------------------------------------------ submit
     def submit(self, prompt, max_new_tokens, eos_id=None,
                deadline_ms=None, temperature=0.0, top_k=0, top_p=1.0,
-               seed=None):
+               seed=None, return_replay=False):
         """Enqueue one prompt; returns a Future resolving to the
         generated token ids (np.int32, EOS included when hit).  With
         ``temperature`` 0 (default) that is the bitwise
         ``greedy_decode`` stream; ``temperature`` > 0 samples with
         optional ``top_k`` / ``top_p`` truncation under a per-request
-        ``seed`` (fresh entropy when None) — v5 artifacts only."""
+        ``seed`` (fresh entropy when None) — v5 artifacts only.  With
+        ``return_replay`` (an artifact exported with ``replay=True``) the
+        future resolves to ``(ids, replay)``, what replaying the request
+        elsewhere needs: ``replay["logprobs"]`` [len(ids)] float32, each
+        token's log-probability, and ``replay["routed_experts"]`` [E
+        blocks, tokens fed, top_k] int16, the experts every token the
+        model was fed chose — the prompt, then each generated token but
+        the last."""
         gp = self.predictor
+        if return_replay and not gp.replay:
+            raise ValueError(
+                "model %r: the artifact's programs do not return what a "
+                "replay needs — re-export with export_generation(..., "
+                "replay=True)" % (self.name,))
         prompt = _np.asarray(prompt, _np.int32).reshape(-1)
         plen = int(prompt.shape[0])
         max_new = int(max_new_tokens)
@@ -459,7 +492,8 @@ class GenerationEngine:
                           request_id=request_id,
                           trace_id=trace_id, temperature=temperature,
                           top_k=top_k, top_p=top_p, seed=seed,
-                          prefix_keys=prefix_keys)
+                          prefix_keys=prefix_keys,
+                          want_replay=return_replay)
         with self._cond:
             if self._dead is not None:
                 exc = self._dead
@@ -574,7 +608,17 @@ class GenerationEngine:
             self._prefix.clear()
             self._cond.notify_all()
         self._gauge_pages()
-        self._kv = self.predictor.make_kv(self.num_pages)
+        self._kv = self._make_kv()
+
+    def _state_slots(self):
+        """Rows of the cache's state region: one per decode slot, none
+        for a model whose whole cache is pages."""
+        return self.decode_slots if self.predictor.state else None
+
+    def _make_kv(self):
+        """The cache, zeroed: pages and, where the model keeps one, the
+        state region (so a rebuilt cache carries no request's state)."""
+        return self.predictor.make_kv(self.num_pages, self._state_slots())
 
     def _release_pages_locked(self, slot):  # mxlint: holds(_cond)
         """Return a slot's pages to the free list — shared-prefix pages
@@ -809,6 +853,9 @@ class GenerationEngine:
                     if done and i < w_s:
                         write_table[0, i] = sentinel
         temp, tk, tp, keys = self._sample_arrays([(0, slot)], 1)
+        # where the cache has a state region, the prompt's final state is
+        # left in this request's slot, over whatever the slot held
+        where = (_np.asarray([slot_idx], _np.int32),) if gp.state else ()
         # the span has the bounds of the serving.prefill_ms timer
         with _tracing.span("engine.prefill.device", cat="serving"):
             slot.t_prefill_start = t0 = _time.perf_counter()
@@ -816,8 +863,13 @@ class GenerationEngine:
                 self._kv, nxt = self._prefill[s_bucket](
                     gp._params, self._kv, tokens,
                     _np.asarray([req.plen], _np.int32), write_table,
-                    temp, tk, tp, keys)
+                    *where, temp, tk, tp, keys)
                 with _tracing.span("engine.prefill.fetch", cat="serving"):
+                    if req.want_replay:
+                        nxt = _np.asarray(nxt)
+                        slot.logprobs.append(nxt[1:2].view(_np.float32)[0])
+                        slot.routed.append(self._routed(nxt[2:], s_bucket)
+                                           [:, :req.plen])
                     first = int(nxt[0])
             except BaseException as exc:  # noqa: BLE001 — pool donated
                 return self._dispatch_failed(exc)
@@ -896,6 +948,9 @@ class GenerationEngine:
         else:
             window = B * width * psz
         sp.set(held_tokens=int(positions.sum()), window_tokens=window)
+        if gp.state:
+            sp.set(state_rows=len(active))
+            _telemetry.gauge("serving.state_slots").set(len(active))
         # the span has the bounds of the serving.decode_step_ms timer
         with _tracing.span("engine.decode.device", cat="serving"):
             t0 = _time.perf_counter()
@@ -910,6 +965,22 @@ class GenerationEngine:
                 self._dispatch_failed(exc)
                 return
             t1 = _time.perf_counter()
+        n_stats = len(gp.decode_stats)
+        if n_stats:
+            # what the model counted in this step rode behind the tokens
+            counts = dict(zip(gp.decode_stats,
+                              nxt[B:B + n_stats].tolist()))
+            sp.set(**counts)
+            for stat, value in counts.items():
+                _telemetry.counter("serving." + stat).inc(value)
+        if any(s.req.want_replay for _, s in active):
+            # ... and behind those what a replay needs
+            logprobs = nxt[B + n_stats:2 * B + n_stats].view(_np.float32)
+            routed = self._routed(nxt[2 * B + n_stats:], B)
+            for i, s in active:
+                if s.req.want_replay:
+                    s.logprobs.append(logprobs[i])
+                    s.routed.append(routed[:, i, None])
         if breaker is not None:
             breaker.record_success()
         _telemetry.timer("serving.decode_step_ms").observe(
@@ -933,6 +1004,13 @@ class GenerationEngine:
                 s.pos += 1
                 finished += self._maybe_finish(i)
             emit.set(finished=finished)
+
+    def _routed(self, flat, tokens):
+        """The tail of a program's returned array as ``[E blocks, tokens,
+        top_k]`` int16."""
+        shape = self.predictor.replay
+        return flat.astype(_np.int16).reshape(shape["layers"], tokens,
+                                              shape["top_k"])
 
     def _sample_arrays(self, active, B):
         """Per-row sampling operands for a dispatch: active rows carry
@@ -974,7 +1052,13 @@ class GenerationEngine:
         wall_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.generate_request_ms").observe(wall_ms)
         if not req.future.done():
-            req.future.set_result(_np.asarray(slot.tokens, _np.int32))
+            ids = _np.asarray(slot.tokens, _np.int32)
+            # the last token was never fed: its step's choice is left out
+            req.future.set_result((ids, {
+                "logprobs": _np.asarray(slot.logprobs, _np.float32),
+                "routed_experts": _np.concatenate(slot.routed, axis=1)[
+                    :, :req.plen + len(ids) - 1]})
+                if req.want_replay else ids)
             if _obs.access_log_enabled():
                 _obs.log_access(
                     self.name, "ok",

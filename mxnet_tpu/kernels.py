@@ -153,37 +153,42 @@ def attention(q, k, v, causal=False, scale=None):
 
 
 def paged_unsupported_reason(q, k_pages, v_pages, page_table, lengths,
-                             quantized=False):
+                             quantized=False, layer=None):
     """Why the Pallas paged-attention kernel can NOT take this decode
     call, or None if it can.  Trace-time shape/dtype checks only —
     everything here must be static under jit.  A non-None reason routes
     to the XLA twin (``kernels.paged_fallback``) and is surfaced in the
     export route sink (:func:`record_paged_routes`)."""
-    if q.ndim != 4 or k_pages.ndim != 3 or v_pages.ndim != 3:
-        return "rank: q%s pools %s/%s, want 4 and 3/3" % (
-            q.ndim, k_pages.ndim, v_pages.ndim)
+    rank = 3 if layer is None else 4     # [P, psz, W], or [L, ...] whole
+    if q.ndim != 4 or k_pages.ndim != rank or v_pages.ndim != rank:
+        return "rank: q%s pools %s/%s, want 4 and %d/%d" % (
+            q.ndim, k_pages.ndim, v_pages.ndim, rank, rank)
     if page_table.ndim != 2 or lengths.ndim != 1:
         return "rank: page_table%s lengths%s, want 2 and 1" % (
             page_table.ndim, lengths.ndim)
     # jax.export shape polymorphism: the grid walks the batch, so it has
     # to be concrete (export_generation's decode_batch); the pool's page
     # count may stay symbolic where Pallas lowers with dynamic shapes
-    fixed = tuple(q.shape) + tuple(k_pages.shape[1:]) \
+    fixed = tuple(q.shape) + tuple(k_pages.shape[-2:]) \
         + tuple(page_table.shape) + tuple(lengths.shape)
     if not all(isinstance(d, int) for d in fixed):
         return "symbolic shape (q%s table%s)" % (q.shape, page_table.shape)
-    if not isinstance(k_pages.shape[0], int) \
+    if not isinstance(k_pages.shape[-3], int) \
             and not _pallas_dynamic_shapes():
         return "symbolic page count %s outside a dynamic-shape export" % (
-            k_pages.shape[0],)
+            k_pages.shape[-3],)
     if q.shape[2] != 1:
         return "needs one query row per sequence, got Sq=%d" % q.shape[2]
     if k_pages.shape != v_pages.shape:
         return "k/v pool shapes differ: %s vs %s" % (
             k_pages.shape, v_pages.shape)
-    if k_pages.shape[2] != q.shape[1] * q.shape[3]:
-        return "pool row width %d != heads x head dim %d x %d" % (
-            k_pages.shape[2], q.shape[1], q.shape[3])
+    kvh, rest = divmod(k_pages.shape[-1], q.shape[3])
+    if rest or kvh < 1 or q.shape[1] % kvh:
+        return "pool row width %d is not K/V heads x head dim %d, with " \
+            "the %d query heads a multiple of them" % (
+                k_pages.shape[-1], q.shape[3], q.shape[1])
+    if quantized and (kvh != q.shape[1] or layer is not None):
+        return "int8 pages take equal head counts and one layer's pool"
     if page_table.shape[0] != q.shape[0] \
             or lengths.shape[0] != q.shape[0]:
         return "page_table%s / lengths%s do not match the batch %d" % (
@@ -256,7 +261,8 @@ def _note_paged_route(impl, reason, quantized):
 
 
 def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
-                         scale=None, k_scale=None, v_scale=None):
+                         scale=None, k_scale=None, v_scale=None,
+                         layer=None):
     """The XLA twin of the paged kernel: gather each row's pages through
     its page table (under ``mx.kv_gather``; a sentinel id clips to a real
     page), then the masked one-pass softmax every release traced (under
@@ -266,8 +272,15 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
     denominator and the value sum.  With ``k_scale``/``v_scale`` the
     int8 pages dequantize up front (one f32 broadcast multiply), the
     same f32 operands the kernel reconstructs in VMEM.  A row of length
-    0 answers 0, as the kernel does."""
+    0 answers 0, as the kernel does.  Fewer K/V heads than query heads
+    (pool rows ``KVH*Dh`` wide): query head h reads K/V head ``h // (H //
+    KVH)``; ``layer`` picks one layer of whole ``[L, P, psz, W]`` pools."""
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     B, H, _, d = q.shape
+    if k_pages.shape[2] != H * d:
+        return _paged_attention_xla_grouped(q, k_pages, v_pages,
+                                            page_table, lengths, scale)
     K = page_table.shape[1] * k_pages.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -299,8 +312,33 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
         return jnp.where((lengths > 0)[:, None, None, None], o, 0)
 
 
+def _paged_attention_xla_grouped(q, k_pages, v_pages, page_table, lengths,
+                                 scale):
+    """The twin at fewer K/V heads than query heads: the same gather,
+    floor and one-pass softmax, with the query heads of one K/V head
+    kept together (``[B, KVH, G, d]``) so K and V are not repeated."""
+    B, H, _, d = q.shape
+    kvh = k_pages.shape[2] // d
+    K = page_table.shape[1] * k_pages.shape[1]
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    with jax.named_scope("mx.kv_gather"):
+        k, v = (jnp.transpose(p[page_table].reshape(B, K, kvh, d),
+                              (0, 2, 1, 3)) for p in (k_pages, v_pages))
+    with jax.named_scope("mx.paged_attention"):
+        valid = jnp.arange(K, dtype=jnp.int32)[None, :] < lengths[:, None]
+        s = jnp.einsum("bngd,bnkd->bngk", q.reshape(B, kvh, H // kvh, d), k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, None, :], s, -1e30)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        o = jnp.einsum("bngk,bnkd->bngd", e.astype(v.dtype), v)
+        o = (o / l.astype(o.dtype)).astype(q.dtype).reshape(B, H, 1, d)
+        return jnp.where((lengths > 0)[:, None, None, None], o, 0)
+
+
 def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, layer=None):
     """Decode-step attention over the pages a page table names.
 
     ``q`` is the single new query ``[B, H, 1, Dh]``; ``k_pages`` /
@@ -311,6 +349,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     attends over, at most ``W * psz``.  With ``k_scale``/``v_scale``
     (``[P, psz, H]`` f32 per-row scale pages from ``mx.quantization
     .quantize_rows``) the pools are int8 and dequantize in the consumer.
+    The pool rows may hold fewer K/V heads than ``q`` has heads (``[P,
+    psz, KVH*Dh]``: query head h reads K/V head ``h // (H // KVH)``), and
+    with ``layer`` (a static index) the pools are every layer's ``[L, P,
+    psz, KVH*Dh]``, handed to the kernel whole so nothing is sliced.
     Both routes pin masked scores to the ``-1e30`` floor of
     ``parallel.ring_attention._block_attn`` and track an unpadded
     forward closely enough for greedy token parity
@@ -330,21 +372,22 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     export route sink."""
     quant = k_scale is not None
     reason = _paged_route_reason(q, k_pages, v_pages, page_table, lengths,
-                                 quant)
+                                 quant, layer)
     if reason is None:
         _telemetry.counter("kernels.paged_attention").inc()
         _note_paged_route("paged", None, quant)
         with jax.named_scope("mx.paged_attention"):
             return pallas_paged_attention(
                 q, k_pages, v_pages, page_table, lengths, scale=scale,
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale=k_scale, v_scale=v_scale, layer=layer)
     _note_paged_route("xla", reason, quant)
     return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                 scale=scale, k_scale=k_scale,
-                                v_scale=v_scale)
+                                v_scale=v_scale, layer=layer)
 
 
-def _paged_route_reason(q, k_pages, v_pages, page_table, lengths, quant):
+def _paged_route_reason(q, k_pages, v_pages, page_table, lengths, quant,
+                        layer=None):
     """None when this decode site takes the kernel, else why not."""
     if not enabled():
         return "tier off"
@@ -355,7 +398,7 @@ def _paged_route_reason(q, k_pages, v_pages, page_table, lengths, quant):
         _telemetry.counter("kernels.gated_fallback").inc()
         return "interpreted"
     reason = paged_unsupported_reason(q, k_pages, v_pages, page_table,
-                                      lengths, quantized=quant)
+                                      lengths, quantized=quant, layer=layer)
     if reason is not None:
         _telemetry.counter("kernels.paged_fallback").inc()
     return reason

@@ -1,0 +1,616 @@
+"""HybridLM — a decoder whose blocks are each ONE mixer, of three kinds.
+
+The stack is a pattern string, one letter a block (the ``nemotron_h``
+family's ``hybrid_override_pattern``):
+
+  ``M``  Mamba-2 (state-space duality): a gated, depthwise-convolved
+         recurrence with a fixed-size state per sequence
+  ``E``  a mixture of experts that live in a latent width, chosen by a
+         sigmoid-scored top-k router, beside one shared expert
+  ``*``  causal softmax attention with fewer K/V heads than query heads
+
+``h <- h + Mixer(RMSNorm(h))`` per block, no positional table (the
+recurrence orders the tokens), an output head of its own.  The class has
+``TransformerLM``'s generation protocol (``cfg``, ``init``, ``apply``,
+``kv_spec``, ``init_kv_pages``, ``prefill``, ``decode_step``,
+``greedy_decode``) and so goes through ``deploy.export_generation`` and
+the generation server unchanged; what differs is the cache it describes:
+K/V pages for the layers that attend, and per decode slot a float32
+recurrent state and a convolution tail for every ``M`` layer
+(``kv_spec()["state"]``).  A prefill leaves the prompt's final state in
+the slot it is told; a decode step advances every slot's.
+
+One chip's share of an expert-parallel deployment is a configuration, not
+another code path: ``experts_held`` / ``expert_offset`` say which routed
+experts live here (the router stays ``num_experts`` wide), and what the
+absent ones would add is left out (``parallel.moe.dropless_experts``).
+
+Parameters are a dict per block (``params["layers"]["07"]``), not stacked
+per kind: a block's weights go whole into the products that read them (the
+grouped product of the experts is a kernel, and a slice of a stacked array
+handed to a kernel is a copy of it, 0.7 GB a layer at the published
+sizes), and each state array is donated and rewritten whole, in place.
+The blocks are walked in Python, so no pool or state rides a scan.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .. import kernels as _kernels
+from ..parallel import moe as _moe
+from .transformer import TransformerLM, _norm
+
+__all__ = ["HybridLMConfig", "HybridLM"]
+
+KINDS = "ME*"
+#: what ``parallel.moe.dropless_experts`` counts, summed over the E blocks
+_STATS = ("pairs", "experts_hit", "max_load")
+
+
+class HybridLMConfig:
+    def __init__(self, vocab_size=32000, pattern="MEM*E", d_model=512,
+                 num_heads=8, num_kv_heads=2, head_dim=64, ssm_heads=16,
+                 ssm_head_dim=64, ssm_groups=2, ssm_state=64,
+                 conv_kernel=4, chunk=128, num_experts=16, top_k=2,
+                 moe_latent=128, expert_ff=256, shared_ff=512,
+                 route_scale=1.0, experts_held=None, expert_offset=0,
+                 max_len=2048, dtype=jnp.bfloat16, eps=1e-5, depth=None):
+        if not pattern or set(pattern) - set(KINDS):
+            raise ValueError("pattern %r: one of %r per block"
+                             % (pattern, KINDS))
+        if num_heads % num_kv_heads or ssm_heads % ssm_groups:
+            raise ValueError("query heads must be a multiple of the K/V "
+                             "heads, and the state heads of their groups")
+        self.vocab_size = vocab_size
+        self.pattern = pattern
+        self.num_layers = len(pattern)
+        self.d_model = d_model
+        self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = head_dim
+        self.ssm_heads = ssm_heads
+        self.ssm_head_dim = ssm_head_dim
+        self.ssm_groups = ssm_groups
+        self.ssm_state = ssm_state
+        self.ssm_inner = ssm_heads * ssm_head_dim
+        #: the convolved stream: X and the groups' B and C
+        self.conv_width = self.ssm_inner + 2 * ssm_groups * ssm_state
+        self.conv_kernel = conv_kernel
+        self.chunk = chunk
+        self.num_experts = num_experts
+        self.top_k = top_k
+        self.moe_latent = moe_latent
+        self.expert_ff = expert_ff
+        self.shared_ff = shared_ff
+        self.route_scale = route_scale
+        self.experts_held = num_experts if experts_held is None \
+            else int(experts_held)
+        self.expert_offset = int(expert_offset)
+        if not 0 <= self.expert_offset \
+                <= num_experts - self.experts_held:
+            raise ValueError("held experts %d+%d lie outside the %d routed"
+                             % (self.expert_offset, self.experts_held,
+                                num_experts))
+        self.max_len = max_len
+        self.dtype = dtype
+        self.eps = eps
+        self.causal = True
+        #: blocks in the whole stack, where ``pattern`` is one pipeline
+        #: stage of a deeper one: ``init`` scales by it
+        self.depth = len(pattern) if depth is None else int(depth)
+
+
+def _normal(key, shape, std, dtype):
+    """Normal values in ``dtype``; a large stack is drawn a slab at a time
+    so no float32 copy of the whole is ever held."""
+    if len(shape) > 2 and math.prod(shape) > 1 << 24:
+        return lax.map(lambda k: _normal(k, shape[1:], std, dtype),
+                       jax.random.split(key, shape[0]))
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+class HybridLM:
+    """Pattern-built hybrid decoder; see the module text."""
+
+    #: what ``decode_step(..., return_stats=True)`` appends, in order
+    decode_stats = tuple("moe_" + n for n in _STATS)
+    #: ``prefill`` / ``decode_step`` can say what replaying a served
+    #: sequence elsewhere needs (``return_replay``): the experts every
+    #: token chose in every ``E`` block, and the log-probability of the
+    #: token each row produced
+    replay = True
+    #: the experts' grouped products run over token rows: an exported
+    #: program of this model has concrete batch dims
+    symbolic_batch = False
+
+    def __init__(self, config):
+        self.cfg = config
+        self.kinds = tuple(config.pattern)
+        self.names = tuple("%02d" % i for i in range(len(self.kinds)))
+        # which attention layer (pool index) / state layer a block is
+        self.attn_index = {n: i for i, n in enumerate(
+            n for n, k in zip(self.names, self.kinds) if k == "*")}
+        self.ssm_index = {n: i for i, n in enumerate(
+            n for n, k in zip(self.names, self.kinds) if k == "M")}
+
+    # -------------------------------------------------------------- params
+    def init(self, key):
+        cfg = self.cfg
+        D, dt = cfg.d_model, cfg.dtype
+        keys = iter(jax.random.split(key, 16 * len(self.kinds) + 4))
+
+        def mk(shape, fan_in, scale=1.0):
+            # 0.02 at fan-in D, as TransformerLM
+            return _normal(next(keys), shape,
+                           scale * 0.02 / math.sqrt(fan_in / D), dt)
+
+        # the family's ``rescale_prenorm_residual``: every block's last
+        # matrix over sqrt(depth), so the whole stack moves a unit-variance
+        # embedding by a fraction of its norm
+        out = 1.0 / math.sqrt(cfg.depth)
+
+        ones = lambda n: jnp.ones((n,), dt)   # noqa: E731
+        layers = {}
+        for name, kind in zip(self.names, self.kinds):
+            if kind == "*":
+                H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+                lp = {"ln": ones(D), "wq": mk((D, H, Dh), D),
+                      "wk": mk((D, KV, Dh), D), "wv": mk((D, KV, Dh), D),
+                      "wo": mk((H, Dh, D), H * Dh, out)}
+            elif kind == "M":
+                Hm, I, C = cfg.ssm_heads, cfg.ssm_inner, cfg.conv_width
+                u = jax.random.uniform(next(keys), (2, Hm), jnp.float32)
+                # step sizes log-uniform in [1e-3, 1e-1], stored through
+                # the inverse of softplus; decay rates A in [1, 16]
+                step = jnp.exp(u[0] * (math.log(0.1) - math.log(1e-3))
+                               + math.log(1e-3))
+                lp = {"ln": ones(D),
+                      "w_in": mk((D, I + C + Hm), D),
+                      "conv_w": _normal(next(keys), (C, cfg.conv_kernel),
+                                        0.5, dt),
+                      "conv_b": _normal(next(keys), (C,), 0.1, dt),
+                      "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                      "a_log": jnp.log(1.0 + 15.0 * u[1]),
+                      "d": jnp.ones((Hm,), jnp.float32),
+                      "norm": ones(I), "w_out": mk((I, D), I, out)}
+            else:
+                E, Eh, Z = cfg.num_experts, cfg.experts_held, cfg.moe_latent
+                F, Fs = cfg.expert_ff, cfg.shared_ff
+                # (a selection bias evens the experts' loads: a small
+                # spread, so that it still decides near-ties and no
+                # expert is preferred by much)
+                lp = {"ln": ones(D), "router": mk((D, E), D),
+                      "select_bias": jax.random.normal(
+                          next(keys), (E,), jnp.float32) * 0.01,
+                      "w_down": mk((D, Z), D), "w_up": mk((Z, D), Z, out),
+                      "w1": mk((Eh, Z, F), Z), "w2": mk((Eh, F, Z), F),
+                      "v1": mk((D, Fs), D), "v2": mk((Fs, D), Fs, out)}
+            layers[name] = lp
+        return {"embed": _normal(next(keys), (cfg.vocab_size, D), 1.0, dt),
+                "head": mk((cfg.vocab_size, D), D),
+                "final_norm": ones(D), "layers": layers}
+
+    # ----------------------------------------------------------- attention
+    def _qkv(self, x, lp):
+        """RMSNorm + projections: x [B,S,D] -> q [B,H,S,Dh], k and v
+        [B,KVH,S,Dh]; no rotary embedding, no bias."""
+        with jax.named_scope("mx.qkv"):
+            h = _norm(x, lp["ln"], self.cfg.eps)
+            q, k, v = (jnp.einsum("bsd,dhe->bhse", h, lp[w],
+                                  preferred_element_type=jnp.float32
+                                  ).astype(x.dtype)
+                       for w in ("wq", "wk", "wv"))
+            return q, k, v
+
+    def _attn_out(self, o, lp):
+        with jax.named_scope("mx.attn_out"):
+            return jnp.einsum("bhse,hed->bsd", o, lp["wo"],
+                              preferred_element_type=jnp.float32
+                              ).astype(o.dtype)
+
+    def _attend(self, q, k, v):
+        """Causal attention over a whole bucket; query head i reads K/V
+        head ``i // (H // KVH)``."""
+        rep = self.cfg.num_heads // self.cfg.num_kv_heads
+        with jax.named_scope("mx.attention"):
+            return _kernels.attention(q, jnp.repeat(k, rep, axis=1),
+                                      jnp.repeat(v, rep, axis=1),
+                                      causal=True)
+
+    # -------------------------------------------------------------- Mamba-2
+    def _ssm_split(self, x, lp):
+        """RMSNorm + the input projection: x [..., D] -> z [..., I], the
+        stream to convolve [..., C], step logits [..., Hm]."""
+        cfg = self.cfg
+        h = _norm(x, lp["ln"], cfg.eps)
+        zxd = jnp.einsum("...d,de->...e", h, lp["w_in"],
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        I, C = cfg.ssm_inner, cfg.conv_width
+        return zxd[..., :I], zxd[..., I:I + C], zxd[..., I + C:]
+
+    def _ssm_parts(self, xbc, dt, lp):
+        """The convolved, activated stream and step logits -> X
+        [..., G, R, P], B and C [..., G, N], steps and log-decays
+        [..., G, R] in float32 (head ``g*R + r`` uses group g)."""
+        cfg = self.cfg
+        G, N, P = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_head_dim
+        R = cfg.ssm_heads // G
+        I = cfg.ssm_inner
+        lead = xbc.shape[:-1]
+        X = xbc[..., :I].reshape(lead + (G, R, P))
+        Bm = xbc[..., I:I + G * N].reshape(lead + (G, N))
+        Cm = xbc[..., I + G * N:].reshape(lead + (G, N))
+        step = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        step = step.reshape(lead + (G, R))
+        rate = -jnp.exp(lp["a_log"]).reshape(G, R)
+        return X, Bm, Cm, step, rate
+
+    def _ssm_finish(self, Y, X, z, lp):
+        """Skip term, gate, grouped RMSNorm (one group per B/C group) and
+        the output projection.  Y, X [..., G, R, P]; z [..., I]."""
+        cfg = self.cfg
+        G = cfg.ssm_groups
+        lead = z.shape[:-1]
+        Y = Y + lp["d"].reshape(G, -1)[..., None] * X.astype(jnp.float32)
+        y = Y.reshape(lead + (G, -1)) * jax.nn.silu(
+            z.astype(jnp.float32)).reshape(lead + (G, -1))
+        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + cfg.eps)
+        y = y.reshape(lead + (-1,)).astype(z.dtype) * lp["norm"]
+        return jnp.einsum("...i,id->...d", y, lp["w_out"],
+                          preferred_element_type=jnp.float32
+                          ).astype(z.dtype)
+
+    def _conv(self, window, lp):
+        """Depthwise causal convolution + SiLU at the last column of each
+        ``conv_kernel``-wide window: window [..., K, C] -> [..., C]."""
+        acc = jnp.einsum("...kc,ck->...c", window.astype(jnp.float32),
+                         lp["conv_w"].astype(jnp.float32))
+        return jax.nn.silu(acc + lp["conv_b"].astype(jnp.float32)
+                           ).astype(window.dtype)
+
+    def _ssm_sequence(self, x, lp, lengths=None):
+        """A whole sequence from the zero state, in the block (SSD) form:
+        x [B,S,D] -> (out [B,S,D], final state [B,G,R,P,N] f32, conv tail
+        [B,K-1,C]: the last K-1 pre-activation columns).  Positions at or
+        past ``lengths`` take a zero step, so they neither decay nor feed
+        the state, and the tail ends at the last real column."""
+        cfg = self.cfg
+        S = x.shape[1]
+        K, Q = cfg.conv_kernel, min(cfg.chunk, S)
+        with jax.named_scope("mx.ssm_conv"):
+            z, xbc, dt = self._ssm_split(x, lp)
+            padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            window = jnp.stack([padded[:, j:j + S] for j in range(K)],
+                               axis=2)                      # [B,S,K,C]
+            if lengths is None:
+                tail = padded[:, S:]
+            else:
+                # padded column lengths+j is position lengths-(K-1)+j
+                take = lengths[:, None] + jnp.arange(K - 1)[None, :]
+                tail = jnp.take_along_axis(padded, take[..., None], axis=1)
+            conv = self._conv(window, lp)
+        with jax.named_scope("mx.ssm_scan"):
+            X, Bm, Cm, step, rate = self._ssm_parts(conv, dt, lp)
+            if lengths is not None:
+                real = jnp.arange(S)[None, :] < lengths[:, None]
+                step = jnp.where(real[..., None, None], step, 0.0)
+            pad = -S % Q
+            if pad:
+                X, Bm, Cm, step = (
+                    jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                    for a in (X, Bm, Cm, step))
+            Y, state = _ssd(X, Bm, Cm, step, rate, Q)
+            Y, X = Y[:, :S], X[:, :S]
+        out = self._ssm_finish(Y, X, z, lp)
+        return out, state, tail
+
+    def _ssm_step(self, x, lp, state, tail):
+        """One token a row: x [B,D], state [B,G,R,P,N] f32, tail
+        [B,K-1,C] -> (out [B,D], new state, new tail)."""
+        with jax.named_scope("mx.ssm_conv"):
+            z, xbc, dt = self._ssm_split(x, lp)
+            window = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)],
+                                     axis=1)
+            conv = self._conv(window, lp)
+        with jax.named_scope("mx.ssm_update"):
+            X, Bm, Cm, step, rate = self._ssm_parts(conv, dt, lp)
+            decay = jnp.exp(step * rate)                     # [B,G,R]
+            xd = X.astype(jnp.float32) * step[..., None]     # [B,G,R,P]
+            state = decay[..., None, None] * state \
+                + xd[..., None] * Bm.astype(jnp.float32)[:, :, None, None]
+            Y = jnp.sum(state * Cm.astype(jnp.float32)[:, :, None, None],
+                        axis=-1)                             # [B,G,R,P]
+        return self._ssm_finish(Y, X, z, lp), state, window[:, 1:]
+
+    # ------------------------------------------------- mixture of experts
+    def _moe(self, x, lp, rows_valid=None):
+        """x [T, D] -> (out [T, D], the held experts' routing stats, the
+        experts each row chose [T, top_k] int32)."""
+        cfg = self.cfg
+        dt = x.dtype
+
+        def dot(a, w):
+            return jnp.einsum("td,df->tf", a, w,
+                              preferred_element_type=jnp.float32)
+
+        h = _norm(x, lp["ln"], cfg.eps)
+        with jax.named_scope("mx.moe_router"):
+            experts, weights = _moe.sigmoid_top_k(
+                h, lp["router"], lp["select_bias"], cfg.top_k,
+                cfg.route_scale)
+        u = dot(h, lp["w_down"]).astype(dt)
+        with jax.named_scope("mx.moe_experts"):
+            y, stats = _moe.dropless_experts(
+                u, experts, weights, lp["w1"], lp["w2"],
+                expert_offset=cfg.expert_offset, rows_valid=rows_valid)
+        out = dot(y.astype(dt), lp["w_up"])
+        with jax.named_scope("mx.moe_shared"):
+            out = out + dot(_relu2(dot(h, lp["v1"])).astype(dt), lp["v2"])
+        return out.astype(dt), stats, experts
+
+    # -------------------------------------------------------------- forward
+    def apply(self, params, tokens):
+        """tokens [B, S] int32 -> logits [B, S, V] (fp32): the whole
+        sequence from empty state, no cache."""
+        x = params["embed"][tokens].astype(self.cfg.dtype)
+        x, _, _, _ = self._run(params, x)
+        x = _norm(x, params["final_norm"], self.cfg.eps)
+        return jnp.einsum("bsd,vd->bsv", x, params["head"],
+                          preferred_element_type=jnp.float32)
+
+    def _run(self, params, x, lengths=None, kv_sink=None):
+        """The blocks over whole sequences x [B,S,D].  Returns (x, {block:
+        (state, tail)} of the ``M`` blocks, summed expert stats, the
+        experts chosen [E blocks, B, S, top_k]); ``kv_sink(block, k, v)``
+        sees every attention block's K/V."""
+        B, S, D = x.shape
+        valid = None if lengths is None else \
+            (jnp.arange(S)[None, :] < lengths[:, None]).reshape(-1)
+        states, stats, routed = {}, [], []
+        for name, kind in zip(self.names, self.kinds):
+            lp = params["layers"][name]
+            if kind == "*":
+                q, k, v = self._qkv(x, lp)
+                if kv_sink is not None:
+                    kv_sink(name, k, v)
+                x = x + self._attn_out(self._attend(q, k, v), lp)
+            elif kind == "M":
+                with jax.named_scope("mx.ssm"):
+                    out, state, tail = self._ssm_sequence(x, lp, lengths)
+                    states[name] = (state, tail)
+                    x = x + out
+            else:
+                with jax.named_scope("mx.moe"):
+                    out, st, chosen = self._moe(x.reshape(B * S, D), lp,
+                                                valid)
+                    stats.append(st)
+                    routed.append(chosen.reshape(B, S, -1))
+                    x = x + out.reshape(B, S, D)
+        return x, states, _sum_stats(stats), _stack_routed(routed, (B, S))
+
+    # --------------------------------------- generation (pages and state)
+    def kv_spec(self, quantized=False):
+        """The cache this model keeps, for ``deploy.export_generation``:
+        K/V pages of the layers that attend (``row_width`` = K/V heads x
+        head size) and, under ``state``, the arrays every decode slot
+        holds a row of — per ``M`` block the float32 recurrent state and
+        the convolution tail — as ``{"name", "shape" (of one slot's row),
+        "dtype"}``; an array is ``[slots, *shape]``."""
+        if quantized:
+            raise ValueError("HybridLM keeps no int8 K/V pages")
+        cfg = self.cfg
+        G, R = cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
+        state = []
+        for name in self.ssm_index:
+            state.append({"name": "ssm" + name, "dtype": "float32",
+                          "shape": [G, R, cfg.ssm_head_dim, cfg.ssm_state]})
+            state.append({"name": "conv" + name,
+                          "dtype": jnp.dtype(cfg.dtype).name,
+                          "shape": [cfg.conv_kernel - 1, cfg.conv_width]})
+        return {"num_layers": len(self.attn_index),
+                "num_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+                "row_width": cfg.num_kv_heads * cfg.head_dim,
+                "dtype": jnp.dtype(cfg.dtype).name, "state": state}
+
+    def init_kv_pages(self, num_pages, page_size, slots=1):
+        """Zeroed cache: ``k`` / ``v`` ``[attention layers, num_pages,
+        page_size, KVH*Dh]`` and every state array ``[slots, ...]``."""
+        spec = self.kv_spec()
+        shape = (spec["num_layers"], int(num_pages), int(page_size),
+                 spec["row_width"])
+        kv = {"k": jnp.zeros(shape, self.cfg.dtype),
+              "v": jnp.zeros(shape, self.cfg.dtype)}
+        for s in spec["state"]:
+            kv[s["name"]] = jnp.zeros((int(slots),) + tuple(s["shape"]),
+                                      s["dtype"])
+        return kv
+
+    def _logits_last(self, params, x):
+        with jax.named_scope("mx.lm_head"):
+            x = _norm(x, params["final_norm"], self.cfg.eps)
+            return jnp.einsum("bd,vd->bv", x, params["head"],
+                              preferred_element_type=jnp.float32)
+
+    # readout, sampling and the cache-free oracle are TransformerLM's own
+    _sample_last = TransformerLM._sample_last
+    _choose = TransformerLM._choose
+    greedy_decode = TransformerLM.greedy_decode
+
+    def prefill(self, params, kv, tokens, lengths, page_table, page_size,
+                sample=None, return_logits=False, slots=None,
+                return_replay=False):
+        """Whole prompts: tokens [B,S] (padded past ``lengths``), page_table
+        [B,W].  Every attention block's K/V goes into the pages as in
+        ``TransformerLM.prefill``; every ``M`` block runs from the zero
+        state in the block form and leaves the prompt's final state and
+        convolution tail in row ``slots[b]`` (default: row b) of its state
+        arrays, whatever that row held.  Padded positions take no step
+        and route to no expert.  Returns ``(new_kv, next_token [B])``;
+        with ``return_replay`` (:attr:`replay`) two more come last: the
+        experts every position chose ``[E blocks, B, S, top_k]`` int32 and
+        the next token's log-probability ``[B]`` float32."""
+        B, S = tokens.shape
+        psz = int(page_size)
+        pool = kv["k"].shape[1]
+        iota = jnp.arange(S, dtype=jnp.int32)
+        pages = jnp.where(iota[None, :] < lengths[:, None],
+                          page_table[:, iota // psz], pool)
+        offs = jnp.broadcast_to(iota % psz, (B, S))
+        nkv = dict(kv)
+
+        def sink(name, k, v):
+            with jax.named_scope("mx.kv_write"):
+                a = self.attn_index[name]
+                for key, val in (("k", k), ("v", v)):
+                    rows = jnp.transpose(val, (0, 2, 1, 3)).reshape(B, S, -1)
+                    nkv[key] = nkv[key].at[a, pages, offs].set(
+                        rows.astype(nkv[key].dtype), mode="drop")
+
+        x = params["embed"][tokens].astype(self.cfg.dtype)
+        x, states, _, routed = self._run(params, x, lengths, sink)
+        rows = jnp.arange(B, dtype=jnp.int32) if slots is None else slots
+        for name, (state, tail) in states.items():
+            nkv["ssm" + name] = kv["ssm" + name].at[rows].set(state)
+            nkv["conv" + name] = kv["conv" + name].at[rows].set(
+                tail.astype(kv["conv" + name].dtype))
+        last = jnp.take_along_axis(
+            x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32),
+            axis=1)[:, 0]
+        ids, logits = self._sample_last(params, last, lengths, sample)
+        out = (nkv, ids, logits) if return_logits else (nkv, ids)
+        return out + (routed, _logprob(logits, ids)) if return_replay \
+            else out
+
+    def decode_step(self, params, kv, token_ids, positions, page_table,
+                    page_size, sample=None, return_logits=False,
+                    return_stats=False, return_replay=False):
+        """One token for every decode slot: row b of the batch IS slot b
+        of the state arrays.  Attention blocks append K/V and attend
+        through ``kernels.paged_attention`` (handed the whole pool and the
+        layer's index); ``M`` blocks advance their state in float32.  A
+        slot with no request (position 0, as the engine passes it) routes
+        to no expert; its state row is advanced like any other and is
+        overwritten by the next prefill into it.  With ``return_stats``
+        the int32 sums over the ``E`` blocks of :attr:`decode_stats` come
+        next: pairs computed on held experts, distinct held experts hit,
+        the largest load on one expert; with ``return_replay`` the experts
+        every row chose ``[E blocks, B, top_k]`` int32 and the produced
+        tokens' log-probabilities ``[B]`` float32 come last."""
+        B = token_ids.shape[0]
+        psz = int(page_size)
+        page = jnp.take_along_axis(page_table, (positions // psz)[:, None],
+                                   axis=1)
+        slot = (positions % psz)[:, None]
+        active = positions > 0
+        x = params["embed"][token_ids].astype(self.cfg.dtype)   # [B,D]
+        nkv = dict(kv)
+        stats, routed = [], []
+        for name, kind in zip(self.names, self.kinds):
+            lp = params["layers"][name]
+            if kind == "*":
+                a = self.attn_index[name]
+                q, k, v = self._qkv(x[:, None], lp)
+                with jax.named_scope("mx.kv_write"):
+                    for key, val in (("k", k), ("v", v)):
+                        row = jnp.transpose(val, (0, 2, 1, 3)) \
+                            .reshape(B, 1, -1)
+                        nkv[key] = nkv[key].at[a, page, slot].set(
+                            row.astype(nkv[key].dtype), mode="drop")
+                o = _kernels.paged_attention(q, nkv["k"], nkv["v"],
+                                             page_table, positions + 1,
+                                             layer=a)
+                x = x + self._attn_out(o, lp)[:, 0]
+            elif kind == "M":
+                with jax.named_scope("mx.ssm"):
+                    out, nkv["ssm" + name], tail = self._ssm_step(
+                        x, lp, kv["ssm" + name], kv["conv" + name])
+                    nkv["conv" + name] = tail
+                    x = x + out
+            else:
+                with jax.named_scope("mx.moe"):
+                    out, st, chosen = self._moe(x, lp, active)
+                    stats.append(st)
+                    routed.append(chosen)
+                    x = x + out
+        ids, logits = self._sample_last(params, x, positions + 1, sample)
+        out = (nkv, ids)
+        if return_logits:
+            out += (logits,)
+        if return_stats:
+            st = _sum_stats(stats)
+            out += (jnp.stack([st[n] for n in _STATS]),)
+        if return_replay:
+            out += (_stack_routed(routed, (B,)), _logprob(logits, ids))
+        return out
+
+
+def _logprob(logits, ids):
+    """log softmax(logits)[ids], float32: logits [B, V], ids [B]."""
+    chosen = jnp.take_along_axis(logits, ids[:, None], axis=-1)[:, 0]
+    return chosen - jax.nn.logsumexp(logits, axis=-1)
+
+
+def _stack_routed(routed, lead):
+    return jnp.stack(routed) if routed \
+        else jnp.zeros((0,) + lead + (0,), jnp.int32)
+
+
+def _sum_stats(stats):
+    zero = jnp.zeros((), jnp.int32)
+    return {n: sum((s[n] for s in stats), zero) for n in _STATS}
+
+
+def _ssd(X, Bm, Cm, step, rate, Q):
+    """The recurrence ``S_t = exp(step_t rate) S_{t-1} + step_t X_t (x)
+    B_t``, ``Y_t = S_t . C_t`` from ``S = 0``, in chunks of ``Q`` (the
+    state-space-duality block form): inside a chunk a masked, decayed
+    ``(C B^T) X`` product; between chunks the state, carried in float32.
+
+    X [B,S,G,R,P]; Bm, Cm [B,S,G,N]; step [B,S,G,R] f32 (0 where nothing
+    is to happen); rate [G,R] f32 (< 0).  S a multiple of Q.  Returns
+    ``(Y [B,S,G,R,P] f32, final state [B,G,R,P,N] f32)``."""
+    B, S, G, R, P = X.shape
+    N = Bm.shape[-1]
+    c = S // Q
+    f32 = jnp.float32
+    xd = (X.astype(f32) * step[..., None]).astype(X.dtype) \
+        .reshape(B, c, Q, G, R, P)
+    Bc = Bm.reshape(B, c, Q, G, N)
+    Cc = Cm.reshape(B, c, Q, G, N)
+    cum = jnp.cumsum((step * rate).reshape(B, c, Q, G, R), axis=2)
+    # inside a chunk: position i hears j <= i, decayed by what lies between
+    cb = jnp.einsum("bcign,bcjgn->bcijg", Cc, Bc,
+                    preferred_element_type=f32)
+    seg = cum[:, :, :, None] - cum[:, :, None, :]           # [B,c,i,j,G,R]
+    low = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None, None]
+    mix = cb[..., None] * jnp.exp(jnp.where(low, seg, -jnp.inf))
+    Y = jnp.einsum("bcijgr,bcjgrp->bcigrp", mix.astype(X.dtype), xd,
+                   preferred_element_type=f32)
+    # what each chunk adds to the state by its end
+    to_end = jnp.exp(cum[:, :, -1:] - cum)                  # [B,c,Q,G,R]
+    grown = jnp.einsum("bcjgn,bcjgrp->bcgrpn", Bc,
+                       (xd.astype(f32) * to_end[..., None]).astype(X.dtype),
+                       preferred_element_type=f32)
+    whole = jnp.exp(cum[:, :, -1])                          # [B,c,G,R]
+
+    def carry(state, xs):
+        g, w = xs
+        return w[..., None, None] * state + g, state
+
+    state, entering = lax.scan(
+        carry, jnp.zeros((B, G, R, P, N), f32),
+        (jnp.moveaxis(grown, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)                 # [B,c,G,R,P,N]
+    Y = Y + jnp.einsum("bcign,bcgrpn->bcigrp", Cc.astype(f32), entering,
+                       preferred_element_type=f32) \
+        * jnp.exp(cum)[..., None]
+    return Y.reshape(B, S, G, R, P), state

@@ -435,8 +435,8 @@ def paged_tile_pages(psz, width, kv_itemsize, table_width):
     return max(1, min(_PAGED_TILE_TOKENS // psz, fit, table_width))
 
 
-def _paged_attn_kernel(scale, quant, tile, nh, lengths_ref, table_ref,
-                       q_ref, k_hbm, v_hbm, *refs):
+def _paged_attn_kernel(scale, quant, tile, nh, group, lengths_ref,
+                       table_ref, q_ref, k_hbm, v_hbm, *refs):
     """One decode row of single-query attention over its K/V pages, read
     where they lie.
 
@@ -461,7 +461,13 @@ def _paged_attn_kernel(scale, quant, tile, nh, lengths_ref, table_ref,
     answers 0.  With ``quant`` the pages are int8 and their ``[P, psz,
     H]`` f32 scale pages are fetched the same way and fold into the
     scores and the probabilities (``q.(k*s) == (q.k)*s``), so HBM traffic
-    stays at the int8 byte count."""
+    stays at the int8 byte count.
+
+    Grouped queries (``group`` > 1 query heads per K/V head, fewer K/V
+    heads than query heads): the pool row is ``KVH*Dh`` wide, ``q_ref``
+    and ``o_ref`` are ``[H, Dh]``, query head h keeps its ``Dh`` lanes in
+    K/V head ``h // group``'s block, and its answer is that block of row
+    h."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     if quant:
@@ -510,13 +516,18 @@ def _paged_attn_kernel(scale, quant, tile, nh, lengths_ref, table_ref,
     def _():
         start(0, 0)
 
-    dh = width // nh
+    kvh = nh // group
+    dh = width // kvh
     cdt = q_ref.dtype if quant else kbuf.dtype      # the products' dtype
     head = jax.lax.broadcasted_iota(jnp.int32, (nh, width), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (nh, width), 1)
-    own = lane // dh == head                        # head h's Dh lanes
+    # head h keeps the Dh lanes of its K/V head's block
+    own = lane // dh == (head // group if group > 1 else head)
+    q_rows = q_ref[...].astype(jnp.float32)
+    if group > 1:
+        q_rows = jnp.concatenate([q_rows] * kvh, axis=1)    # [H, width]
     # (masks have the 32-bit layout: select in f32, then narrow)
-    qbd = jnp.where(own, q_ref[...].astype(jnp.float32), 0.0).astype(cdt)
+    qbd = jnp.where(own, q_rows, 0.0).astype(cdt)
     prec = _mxu_precision(qbd)
     col = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1)
 
@@ -558,11 +569,17 @@ def _paged_attn_kernel(scale, quant, tile, nh, lengths_ref, table_ref,
     acc0 = jnp.zeros((nh, width), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, acc0))
     out = jnp.where(own, acc / jnp.where(l == 0.0, 1.0, l), 0.0)
+    if group > 1:
+        # row h holds its answer in block h // group: fold the blocks
+        o_ref[...] = sum(out[:, g * dh:(g + 1) * dh]
+                         for g in range(kvh)).astype(o_ref.dtype)
+        return
     o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
-                           scale=None, k_scale=None, v_scale=None):
+                           scale=None, k_scale=None, v_scale=None,
+                           layer=None):
     """Paged-attention decode kernel: one query row per sequence against
     the K/V pages its page table names, read in place.
 
@@ -573,8 +590,15 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     ``k_scale``/``v_scale`` ([P, psz, H] f32 per-row scale pages from
     ``mx.quantization.quantize_rows``) the pools are int8 and dequantize
     inside the kernel.  The grid walks the B rows; what a row costs
-    follows from its length, not from W.  Routing/fallback policy lives
-    in ``mx.kernels.paged_attention``."""
+    follows from its length, not from W.  The pools may hold fewer K/V
+    heads than ``q`` has heads (``[P, psz, KVH*Dh]``, H a multiple of
+    KVH: query head h reads K/V head ``h // (H // KVH)``), and with
+    ``layer`` (a static index) they are every layer's pool ``[L, P, psz,
+    KVH*Dh]`` handed over whole: they are VIEWED as ``L*P`` pages and
+    the layer's page ids offset by ``layer * P``, so no pool is sliced
+    (a slice handed to a kernel is a copy) and the kernel sees what it
+    sees of one layer's pool.  Routing/fallback policy lives in
+    ``mx.kernels.paged_attention``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ..rtc import interpret_mode
@@ -583,17 +607,31 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     if Sq != 1:
         raise ValueError("paged attention takes one query row per "
                          "sequence, got Sq=%d" % Sq)
-    P, psz, width = k_pages.shape
-    if v_pages.shape != k_pages.shape or width != H * D:
-        raise ValueError("page pools must both be [P, psz, H*Dh=%d], got "
-                         "%s and %s" % (H * D, k_pages.shape, v_pages.shape))
+    P, psz, width = k_pages.shape[-3:]
+    kvh = width // D
+    if v_pages.shape != k_pages.shape or width != kvh * D or H % kvh \
+            or k_pages.ndim != (3 if layer is None else 4):
+        raise ValueError("page pools must both be [%sP, psz, KVH*Dh] with "
+                         "H=%d a multiple of KVH and Dh=%d, got %s and %s"
+                         % ("" if layer is None else "L, ", H, D,
+                            k_pages.shape, v_pages.shape))
+    group = H // kvh
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
+    if quant and (group > 1 or layer is not None):
+        raise ValueError("int8 pages take equal head counts and one "
+                         "layer's pool")
+    if layer is not None:
+        k_pages, v_pages = (p.reshape((-1, psz, width))
+                            for p in (k_pages, v_pages))
     tile = paged_tile_pages(psz, width, k_pages.dtype.itemsize,
                             page_table.shape[1])
     any_space = pl.BlockSpec(memory_space=pl.ANY)
-    row = pl.BlockSpec((None, 1, width), lambda b, *_: (b, 0, 0))
-    operands = [q.reshape(B, 1, width), k_pages, v_pages]
+    # one block a decode row: all heads on the lanes at equal head counts,
+    # [H, Dh] where several query heads share a K/V head
+    rows, lanes_q = (1, width) if group == 1 else (H, D)
+    row = pl.BlockSpec((None, rows, lanes_q), lambda b, *_: (b, 0, 0))
+    operands = [q.reshape(B, rows, lanes_q), k_pages, v_pages]
     in_specs = [row, any_space, any_space]
     scratch = [pltpu.VMEM((2, tile, psz, width), k_pages.dtype),
                pltpu.VMEM((2, tile, psz, width), v_pages.dtype)]
@@ -608,18 +646,20 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
         scratch += [pltpu.VMEM((2, tile, psz, lanes), jnp.float32),
                     pltpu.VMEM((2, tile, psz, lanes), jnp.float32)]
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quant else 2)))
+    page_table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+    if layer is not None:
+        page_table = page_table + int(layer) * P
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale, quant, tile, H),
-        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
+        functools.partial(_paged_attn_kernel, scale, quant, tile, H, group),
+        out_shape=jax.ShapeDtypeStruct((B, rows, lanes_q), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
             out_specs=row, scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(), name="mx_paged_attention")(
-            jnp.asarray(lengths, jnp.int32),
-            jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
-            .reshape(-1), *operands)
+            jnp.asarray(lengths, jnp.int32), page_table.reshape(-1),
+            *operands)
     return out.reshape(B, H, 1, D)
 
 

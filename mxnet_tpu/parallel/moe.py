@@ -18,6 +18,15 @@ Layout contract (inside shard_map over `axis_name`):
   gate_w   — [d, E] replicated router weights (E = global expert count)
   w1/b1/w2/b2 — THIS device's expert shard: [E_loc, ...], E = E_loc * n_ep
   returns  — ([T_loc, d] combined outputs, scalar aux loss)
+
+Beside the capacity layer stands the DROPLESS one a served model takes
+(:func:`sigmoid_top_k` + :func:`dropless_experts`): no capacity and no
+``[T, E, C]`` tensor — the (token, expert) pairs are sorted by expert and
+go through one grouped matrix product (``lax.ragged_dot``), and the layer
+is TOLD which experts it holds (``expert_offset`` and the leading extent
+of its weights): it routes over all of them and computes the part of the
+result its own experts give, which is what one chip of an expert-parallel
+deployment does before the exchange (that exchange is not here).
 """
 from __future__ import annotations
 
@@ -28,7 +37,8 @@ from jax.sharding import PartitionSpec as P
 
 from .pipeline import shmap
 
-__all__ = ["moe_ffn", "moe_ffn_sharded", "top_k_routing"]
+__all__ = ["moe_ffn", "moe_ffn_sharded", "top_k_routing",
+           "sigmoid_top_k", "dropless_experts"]
 
 
 def top_k_routing(logits, k, capacity):
@@ -128,3 +138,73 @@ def moe_ffn_sharded(mesh, gate_w, w1, b1, w2, b2, x, axis_name="ep",
     shmapped = shmap(fn, mesh, (P(), espec, espec, espec, espec, tok),
                      (tok, P()))
     return shmapped(gate_w, w1, b1, w2, b2, x)
+
+
+_ROW_TILE = 256
+
+
+def sigmoid_top_k(x, router_w, select_bias, k, scale):
+    """Sigmoid-scored top-k routing over ALL experts, in float32.
+
+    x [T, d]; router_w [d, E]; select_bias [E] f32 joins the scores for
+    the CHOICE only.  Returns ``(experts [T, k] int32, weights [T, k]
+    f32)``: the k largest of ``s + select_bias`` with ``s =
+    sigmoid(x . router_w)``, weighted ``scale * s_e / sum of the chosen
+    s``."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32)))
+    _, experts = lax.top_k(s + select_bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, experts, axis=-1)
+    weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def dropless_experts(u, experts, weights, w1, w2, expert_offset=0,
+                     rows_valid=None):
+    """The held experts' part of a top-k mixture, no token dropped.
+
+    u [T, z] (the experts' input, already in their width); experts /
+    weights [T, k] from :func:`sigmoid_top_k`; w1 [E_held, z, f] and
+    w2 [E_held, f, z] are the experts this caller HOLDS, global ids
+    ``expert_offset .. expert_offset + E_held``; expert e computes
+    ``relu(u . w1_e)**2 . w2_e``.  A (token, expert) pair whose expert
+    lives elsewhere adds nothing here; ``rows_valid`` [T] bool drops the
+    pairs of rows that carry no request.  The held pairs are sorted by
+    expert (stable, so the order is a pure function of the routing) and
+    run as two grouped products over the experts' row groups; the
+    weighted sum over each token's pairs is taken in z.
+
+    Returns ``(y [T, z] f32, stats)`` with ``stats`` int32 scalars:
+    ``pairs`` computed here, ``experts_hit`` (held experts with at least
+    one row) and ``max_load`` (the most rows on one expert)."""
+    t, k = experts.shape
+    e_held = w1.shape[0]
+    local = experts - expert_offset
+    held = (local >= 0) & (local < e_held)
+    if rows_valid is not None:
+        held &= rows_valid[:, None]
+    # pairs of absent experts sort behind every group and join none
+    key = jnp.where(held, local, e_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((e_held + 1,), jnp.int32).at[key].add(1)[:e_held]
+    rows = jnp.take(u, order // k, axis=0)                  # [T*k, z]
+    # whole row tiles for the grouped product: on the chip a row count
+    # off the tile gave wrong products (PERF.md section 6, PR 27); the
+    # rows added join no group
+    rows = jnp.pad(rows, ((0, -(t * k) % _ROW_TILE), (0, 0)))
+    h = lax.ragged_dot(rows, w1, sizes,
+                       preferred_element_type=jnp.float32)
+    h = jnp.square(jax.nn.relu(h)).astype(u.dtype)
+    out = lax.ragged_dot(h, w2, sizes,
+                         preferred_element_type=jnp.float32)[:t * k]
+    # back to [T, k, z]: row i of the sorted list is pair order[i]
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    out = jnp.take(out, back, axis=0).reshape(t, k, -1)
+    # (a row past the groups is whatever the product left there)
+    y = jnp.sum(jnp.where(held[..., None], out * weights[..., None], 0.0),
+                axis=1)
+    stats = {"pairs": jnp.sum(held, dtype=jnp.int32),
+             "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+             "max_load": jnp.max(sizes)}
+    return y, stats

@@ -422,7 +422,8 @@ class Server:
         # 'off' (natural shapes) degenerates to the single full bucket
         return sizes or (cap,)
 
-    def register(self, name, prefix, quantized=False, generate=False):
+    def register(self, name, prefix, quantized=False, generate=False,
+                 params=None):
         """Load the ``mx.deploy`` artifact at ``prefix`` under ``name``:
         params go device-resident now; bucket programs compile now if the
         server is already started (else at :meth:`start`).  Re-registering
@@ -450,7 +451,11 @@ class Server:
         :meth:`submit_generate` / :meth:`generate`; plain :meth:`submit`
         refuses it.  Generation models sit outside the one-shot LRU
         table (an engine holds live sequences — evicting it mid-flight
-        would kill them) and are removed by :meth:`unregister`."""
+        would kill them) and are removed by :meth:`unregister`.
+        ``params`` (generation only): the pytree the artifact was
+        exported with, for a process that exports and serves — arrays
+        already on the device are served as they are, and the artifact
+        may have been written with ``include_params=False``."""
         from . import deploy as _deploy
         if generate:
             if quantized:
@@ -460,7 +465,11 @@ class Server:
                     "at EXPORT time (export_generation(..., "
                     "kv_quantized=True), int8 KV pages), not applied at "
                     "register" % (name,))
-            return self._register_generation(name, prefix)
+            return self._register_generation(name, prefix, params)
+        if params is not None:
+            raise ServingError(
+                "model %r: params= is for generate=True artifacts"
+                % (name,))
         predictor = _deploy.StableHLOPredictor(prefix, quantized=quantized)
         if predictor._params is None:
             raise ServingError(
@@ -494,10 +503,10 @@ class Server:
             self._compile_entry(entry)
         return entry
 
-    def _register_generation(self, name, prefix):
+    def _register_generation(self, name, prefix, params=None):
         from . import deploy as _deploy
         from .generation import GenerationEngine
-        predictor = _deploy.load_generator(prefix)
+        predictor = _deploy.load_generator(prefix, params=params)
         if predictor._params is None:
             raise ServingError(
                 "model %r: artifact %r was exported with "
@@ -951,10 +960,16 @@ class Server:
     # -------------------------------------------------------- generation
     def submit_generate(self, name, prompt, max_new_tokens, eos_id=None,
                         deadline_ms=None, temperature=0.0, top_k=0,
-                        top_p=1.0, seed=None):
+                        top_p=1.0, seed=None, return_replay=False):
         """Enqueue one prompt on generation model ``name``; returns a
         Future resolving to the generated token ids (np.int32, EOS
-        included when hit).  With ``temperature`` 0 (the default) that
+        included when hit) — or, with ``return_replay`` (an artifact
+        exported with ``replay=True``), to ``(ids, replay)``: each
+        token's log-probability (``replay["logprobs"]``) and the experts
+        each prompt token and each generated token but the last chose
+        (``replay["routed_experts"]`` [E blocks, tokens fed, top_k]
+        int16), so the sequence can be replayed elsewhere with the same
+        routing and compared number for number.  With ``temperature`` 0 (the default) that
         is the eager ``greedy_decode`` stream regardless of co-scheduled
         traffic (up to argmax flips between near-tied bf16 logits on the
         chip); ``temperature`` > 0 samples with optional
@@ -975,7 +990,8 @@ class Server:
             return self._engine(name).submit(
                 prompt, max_new_tokens, eos_id=eos_id,
                 deadline_ms=deadline_ms, temperature=temperature,
-                top_k=top_k, top_p=top_p, seed=seed)
+                top_k=top_k, top_p=top_p, seed=seed,
+                return_replay=return_replay)
 
     def generate(self, name, prompt, max_new_tokens, eos_id=None,
                  timeout=None, deadline_ms=None, temperature=0.0,

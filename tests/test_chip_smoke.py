@@ -71,10 +71,16 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert _no_success_line(lines)
     assert [line.get("phase") for line in lines] == [
-        "device", "train", "serve", "kernels", "done", None]
+        "device", "train", "serve", "serve_hybrid", "kernels", "done",
+        None]
     by = {line["phase"]: line for line in lines[:-1]}
     assert by["train"]["compiles_after_warmup"] == 0
     assert by["train"]["timing_end"]["block_until_ready_waits"] is True
+    hybrid = by["serve_hybrid"]
+    assert hybrid["counters"]["kernels.paged_attention"] == \
+        hybrid["decode_iterations"] > 0
+    assert len(hybrid["state_arrays"]) == 6 and hybrid["tokens_flipped"] == 0
+    assert hybrid["counters"]["serving.moe_experts_hit"] > 0
     serve = by["serve"]
     assert serve["counters"]["kernels.paged_attention"] == \
         serve["decode_iterations"] > 0
@@ -86,6 +92,7 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert {"flash_bwd", "paged_int8", "fused_adam_step"} <= {
         name.split("/")[0] for name in by["kernels"]["kernels"]}
     assert lines[-1]["rehearsed"] == ["phase_train", "phase_serve",
+                                      "phase_serve_hybrid",
                                       "phase_kernels"]
     assert lines[-1]["device"]["platform"] == "cpu"
 

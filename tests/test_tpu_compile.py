@@ -310,3 +310,82 @@ def test_routed_attention_compiles_per_shard_on_a_2x2_mesh(
     text = compile_for_chip(jax.value_and_grad(model.loss), params, tok, tok)
     assert _kernel_count(text) == 3
     assert "all-gather(" not in text
+
+
+# ------------------------------------------------ the hybrid decoder's cell
+def test_hybrid_programs_at_the_cells_sizes_compile_into_the_chip(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``benchmarks/configs/nemotron3_super_ep4.json`` as its driver
+    exports it — published widths, 128 of 512 experts, 128 decode slots,
+    K/V pages beside per-slot state — lowered for the TPU, reloaded and
+    compiled for the described chip: the one decode program holds the
+    paged kernel at 16 queries a K/V head and no gather, rewrites cache
+    and state in place (all of it aliased), and weights, cache and the
+    largest program's temporaries fit one chip's 15.75 GiB.  (Weights are
+    shapes only: nothing is made.)"""
+    import json
+    import os
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron3_super_ep4.json")) as f:
+        cell = json.load(f)
+    sz = cell["sizes"]
+    model = HybridLM(HybridLMConfig(dtype=BF16, **sz["lm"]))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    # (the suite's full-precision products are the cpu's: the chip's
+    # grouped product takes bf16 operands at the default precision)
+    with jax.default_matmul_precision(None):
+        deploy.export_generation(
+            model, shapes, prefix, sampling=True,
+            decode_batch=sz["decode_batch"], prompt_buckets=[128, 1024],
+            max_context=2048, page_size=sz["page_tokens"],
+            decode_widths=sz["decode_widths"], include_params=False,
+            replay=sz["replay"])
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    assert meta["replay"] == {"layers": 5, "top_k": 22}
+    width = sz["decode_widths"][-1]
+    assert meta["paged"] == {str(width): {
+        "impl": "paged", "reason": None, "quantized": False}}
+    spec = compile_for_chip.spec
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows, pages = sz["decode_batch"], cell["knobs"]["serving.kv_pages"]
+    cache = on_chip(deploy._kv_pool_specs(meta["kv"], pages, rows))
+
+    def sample(b):
+        return (spec((b,), F32), spec((b,), jnp.int32), spec((b,), F32),
+                spec((b, 2), jnp.uint32))
+
+    def compiled(path, *specs):
+        with open(path, "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return jax.jit(exp.call, donate_argnums=(1,)).lower(
+            on_chip(shapes), cache, *specs).compile()
+
+    decode = compiled("%s-decode-w%d.stablehlo" % (prefix, width),
+                      spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                      spec((rows, width), jnp.int32), *sample(rows))
+    # tokens, three counts, log-probabilities, experts [5, rows, 22]
+    assert decode.out_info[1].shape == (2 * rows + 3 + 5 * rows * 22,)
+    text = decode.as_text()
+    assert text.count("mx_paged_attention") >= 1
+    assert "mx.kv_gather" not in text
+    need = []
+    for program in (decode, compiled(
+            "%s-prefill-s1024.stablehlo" % prefix,
+            spec((1, 1024), jnp.int32), spec((1,), jnp.int32),
+            spec((1, -(-1024 // sz["page_tokens"])), jnp.int32),
+            spec((1,), jnp.int32), *sample(1))):
+        mem = program.memory_analysis()
+        cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
+        assert mem.alias_size_in_bytes >= cache_bytes       # in place
+        need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    assert max(need) < 15.75 * 2 ** 30, need
+    assert min(need) > 0.6 * 15.75 * 2 ** 30    # and the chip is filled
