@@ -53,10 +53,12 @@ Key properties:
   in 256 at the default config, 0.013 below the oracle's best logit
   (``chip_smoke.py``, PR 21), which checks the gap, not the bits.
 * **Donated pool** — the page pool is donated into every program call
-  (it is the only O(pool) buffer); a dispatch failure therefore poisons
-  it, so the engine fails every in-flight sequence with the causal
-  error, rebuilds the pool zeroed, feeds the model's circuit breaker and
-  keeps serving.
+  and comes back in the buffer it went in: the programs carry it whole
+  through their layers and write the new rows in place, so it is the
+  only O(pool) buffer, with no second copy among a program's
+  temporaries.  A dispatch failure therefore poisons it, so the engine
+  fails every in-flight sequence with the causal error, rebuilds the
+  pool zeroed, feeds the model's circuit breaker and keeps serving.
 * **Shared-prefix pages** (``serving.shared_prefix``) — full prompt-
   prefix pages are content-hashed at admission; concurrent requests with
   a common prefix (the system-prompt case) map to the SAME physical

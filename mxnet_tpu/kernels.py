@@ -187,8 +187,8 @@ def paged_unsupported_reason(q, k_pages, v_pages, page_table, lengths,
         return "pool row width %d is not K/V heads x head dim %d, with " \
             "the %d query heads a multiple of them" % (
                 k_pages.shape[-1], q.shape[3], q.shape[1])
-    if quantized and (kvh != q.shape[1] or layer is not None):
-        return "int8 pages take equal head counts and one layer's pool"
+    if quantized and kvh != q.shape[1]:
+        return "int8 pages take equal head counts"
     if page_table.shape[0] != q.shape[0] \
             or lengths.shape[0] != q.shape[0]:
         return "page_table%s / lengths%s do not match the batch %d" % (
@@ -274,9 +274,16 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
     same f32 operands the kernel reconstructs in VMEM.  A row of length
     0 answers 0, as the kernel does.  Fewer K/V heads than query heads
     (pool rows ``KVH*Dh`` wide): query head h reads K/V head ``h // (H //
-    KVH)``; ``layer`` picks one layer of whole ``[L, P, psz, W]`` pools."""
+    KVH)``; ``layer`` (static or traced) picks one layer of whole ``[L,
+    P, psz, W]`` pools, which are gathered from as ``L*P`` pages at the
+    layer's ids: a traced ``k_pages[layer]`` would copy the layer's pool
+    out first."""
     if layer is not None:
-        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        P = k_pages.shape[1]
+        page_table = jnp.clip(page_table, 0, P - 1) + layer * P
+        k_pages, v_pages, k_scale, v_scale = (
+            p if p is None else p.reshape((-1,) + p.shape[2:])
+            for p in (k_pages, v_pages, k_scale, v_scale))
     B, H, _, d = q.shape
     if k_pages.shape[2] != H * d:
         return _paged_attention_xla_grouped(q, k_pages, v_pages,
@@ -342,7 +349,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     """Decode-step attention over the pages a page table names.
 
     ``q`` is the single new query ``[B, H, 1, Dh]``; ``k_pages`` /
-    ``v_pages`` are ONE layer's page pool ``[P, psz, H*Dh]``;
+    ``v_pages`` are a page pool ``[P, psz, H*Dh]`` (see ``layer``);
     ``page_table`` ``[B, W]`` int32 names each row's pages in order (an
     id >= P is the sentinel: it reads a real page that ``lengths``
     masks); ``lengths`` ``[B]`` int32 is how many positions each row
@@ -351,8 +358,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     .quantize_rows``) the pools are int8 and dequantize in the consumer.
     The pool rows may hold fewer K/V heads than ``q`` has heads (``[P,
     psz, KVH*Dh]``: query head h reads K/V head ``h // (H // KVH)``), and
-    with ``layer`` (a static index) the pools are every layer's ``[L, P,
-    psz, KVH*Dh]``, handed to the kernel whole so nothing is sliced.
+    with ``layer`` (a Python int, or the int32 scalar a layer scan
+    traces) the pools are every layer's ``[L, P, psz, KVH*Dh]`` (scale
+    pools ``[L, P, psz, H]``), handed over whole and read as ``L*P``
+    pages at the layer's ids, so no pool is sliced.
     Both routes pin masked scores to the ``-1e30`` floor of
     ``parallel.ring_attention._block_attn`` and track an unpadded
     forward closely enough for greedy token parity

@@ -54,6 +54,29 @@ def _norm(x, scale, eps=1e-6):
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def _write_kv(pools, layer, pages, slots, k, v):
+    """One layer's new K/V ``[B, H, S, Dh]`` into the page pools, in place:
+    position ``[b, s]`` becomes row ``[layer, pages[b, s], slots[b, s]]``
+    of the whole ``[L, P, psz, H*Dh]`` pools (the sentinel page drops).
+    Int8 pools (``"k_scale" in pools``) quantize each row on the way in
+    and their ``[L, P, psz, H]`` scale pools take the scales.  Returns
+    the pools, a new dict."""
+    B, _, S, _ = k.shape
+    pools = dict(pools)
+    with jax.named_scope("mx.kv_write"):
+        for key, val in (("k", k), ("v", v)):
+            rows = jnp.transpose(val, (0, 2, 1, 3))           # [B,S,H,Dh]
+            if key + "_scale" in pools:
+                from .. import quantization as _quant
+                rows, scales = _quant.quantize_rows(rows)
+                pools[key + "_scale"] = pools[key + "_scale"].at[
+                    layer, pages, slots].set(scales, mode="drop")
+            pools[key] = pools[key].at[layer, pages, slots].set(
+                rows.astype(pools[key].dtype).reshape(B, S, -1),
+                mode="drop")
+    return pools
+
+
 class TransformerLM:
     """Decoder-only transformer; params stacked over layers and scanned."""
 
@@ -344,6 +367,18 @@ class TransformerLM:
         choice = jnp.argmax(masked + gum, axis=-1).astype(jnp.int32)
         return jnp.where(temp > 0, choice, greedy)
 
+    def _scan_layers_over_pools(self, body, x, params, kv):
+        """``body((x, pools), (layer_params, layer)) -> ((x, pools), None)``
+        over the layers; returns ``(x, pools)``.  The page pools ride the
+        scan as its carry, whole, and each layer writes its rows in place
+        at its index: as a scanned input and a stacked output every
+        layer's pool would be sliced out and written back, and the stack
+        copied besides."""
+        layers = jnp.arange(self.cfg.num_layers, dtype=jnp.int32)
+        (x, kv), _ = _runtime.scan_stack(body, (x, kv),
+                                         (params["layers"], layers))
+        return x, kv
+
     def prefill(self, params, kv, tokens, lengths, page_table, page_size,
                 sample=None, return_logits=False):
         """Process whole prompts and seed the paged cache.
@@ -365,7 +400,6 @@ class TransformerLM:
         B, S = tokens.shape
         psz = int(page_size)
         pool = kv["k"].shape[1]
-        quant = "k_scale" in kv
         x = (params["embed"][tokens]
              + params["pos_embed"][:S][None]).astype(cfg.dtype)
         x = self._constrain(x, self._dp, self._sp, None)
@@ -378,42 +412,14 @@ class TransformerLM:
         slots = jnp.broadcast_to(iota % psz, (B, S))
 
         def body(carry, xs):
-            if quant:
-                lp, kl, vl, ksl, vsl = xs
-            else:
-                lp, kl, vl = xs
+            x, pools = carry
+            lp, layer = xs
             new = {}
+            out = self._layer(x, lp, kv_sink=lambda k, v: new.update(
+                _write_kv(pools, layer, pages, slots, k, v)))
+            return (out, new), None
 
-            def sink(k, v):
-                # [B,H,S,Dh] -> [B,S,H*Dh] page-slot scatter
-                with jax.named_scope("mx.kv_write"):
-                    kt = jnp.transpose(k, (0, 2, 1, 3))
-                    vt = jnp.transpose(v, (0, 2, 1, 3))
-                    if quant:
-                        from .. import quantization as _quant
-                        kt, ks = _quant.quantize_rows(kt)
-                        vt, vs = _quant.quantize_rows(vt)
-                        new["ks"] = ksl.at[pages, slots].set(
-                            ks, mode="drop")
-                        new["vs"] = vsl.at[pages, slots].set(
-                            vs, mode="drop")
-                    new["k"] = kl.at[pages, slots].set(
-                        kt.astype(kl.dtype).reshape(B, S, -1), mode="drop")
-                    new["v"] = vl.at[pages, slots].set(
-                        vt.astype(vl.dtype).reshape(B, S, -1), mode="drop")
-
-            out = self._layer(carry, lp, kv_sink=sink)
-            if quant:
-                return out, (new["k"], new["v"], new["ks"], new["vs"])
-            return out, (new["k"], new["v"])
-
-        xs = (params["layers"], kv["k"], kv["v"])
-        if quant:
-            xs += (kv["k_scale"], kv["v_scale"])
-        x, ys = _runtime.scan_stack(body, x, xs)
-        nkv = {"k": ys[0], "v": ys[1]}
-        if quant:
-            nkv["k_scale"], nkv["v_scale"] = ys[2], ys[3]
+        x, nkv = self._scan_layers_over_pools(body, x, params, kv)
         last = jnp.take_along_axis(
             x, jnp.maximum(lengths - 1, 0)[:, None, None]
             .astype(jnp.int32), axis=1)[:, 0]                 # [B, D]
@@ -430,10 +436,12 @@ class TransformerLM:
         (its position = tokens already cached), page_table [B, W] int32.
         Appends each token's K/V to its page, then attends over the row's
         ``positions + 1`` tokens through ``kernels.paged_attention``, which
-        is handed each layer's page pool, the page table and those
-        lengths: on the kernel route the pages are read where they lie,
-        each row's and no more; on the XLA twin's route the table's whole
-        window is gathered first (``mx.kv_gather``).  Returns
+        is handed the whole pool, the layer's index, the page table and
+        those lengths: on the kernel route the pages are read where they
+        lie, each row's and no more; on the XLA twin's route the table's
+        whole window is gathered first (``mx.kv_gather``).  The pool is
+        the layer scan's carry, written in place (donated, it comes back
+        in the buffer it came in).  Returns
         ``(new_kv, next_token[B] int32)``.  Inactive slots pass the
         sentinel page everywhere and position 0: their write drops, they
         read one clamped page, and their output is garbage the scheduler
@@ -444,9 +452,7 @@ class TransformerLM:
         :meth:`prefill`.
         """
         cfg = self.cfg
-        B = token_ids.shape[0]
         psz = int(page_size)
-        quant = "k_scale" in kv
         x = (params["embed"][token_ids]
              + params["pos_embed"][positions]).astype(cfg.dtype)[:, None]
         page = jnp.take_along_axis(
@@ -454,41 +460,18 @@ class TransformerLM:
         slot = (positions % psz)[:, None]                     # [B,1]
 
         def body(carry, xs):
-            if quant:
-                lp, kl, vl, ksl, vsl = xs
-            else:
-                lp, kl, vl = xs
-            q, k, v = self._qkv(carry, lp)                    # [B,H,1,Dh]
-            scales = {}
-            with jax.named_scope("mx.kv_write"):
-                kt = jnp.transpose(k, (0, 2, 1, 3))           # [B,1,H,Dh]
-                vt = jnp.transpose(v, (0, 2, 1, 3))
-                if quant:
-                    from .. import quantization as _quant
-                    kt, ks = _quant.quantize_rows(kt)
-                    vt, vs = _quant.quantize_rows(vt)
-                    ksl = ksl.at[page, slot].set(ks, mode="drop")
-                    vsl = vsl.at[page, slot].set(vs, mode="drop")
-                    scales = {"k_scale": ksl, "v_scale": vsl}
-                kl = kl.at[page, slot].set(
-                    kt.astype(kl.dtype).reshape(B, 1, -1), mode="drop")
-                vl = vl.at[page, slot].set(
-                    vt.astype(vl.dtype).reshape(B, 1, -1), mode="drop")
+            x, pools = carry
+            lp, layer = xs
+            q, k, v = self._qkv(x, lp)                        # [B,H,1,Dh]
+            pools = _write_kv(pools, layer, page, slot, k, v)
             # after the write, so the row attends over its own new token
-            o = _kernels.paged_attention(q, kl, vl, page_table,
-                                         positions + 1, **scales)
-            out = self._attn_mlp(carry, o, lp)
-            if quant:
-                return out, (kl, vl, ksl, vsl)
-            return out, (kl, vl)
+            o = _kernels.paged_attention(
+                q, pools["k"], pools["v"], page_table, positions + 1,
+                k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
+                layer=layer)
+            return (self._attn_mlp(x, o, lp), pools), None
 
-        xs = (params["layers"], kv["k"], kv["v"])
-        if quant:
-            xs += (kv["k_scale"], kv["v_scale"])
-        x, ys = _runtime.scan_stack(body, x, xs)
-        nkv = {"k": ys[0], "v": ys[1]}
-        if quant:
-            nkv["k_scale"], nkv["v_scale"] = ys[2], ys[3]
+        x, nkv = self._scan_layers_over_pools(body, x, params, kv)
         ids, logits = self._sample_last(params, x[:, 0], positions + 1,
                                         sample)
         if return_logits:

@@ -435,13 +435,15 @@ def paged_tile_pages(psz, width, kv_itemsize, table_width):
     return max(1, min(_PAGED_TILE_TOKENS // psz, fit, table_width))
 
 
-def _paged_attn_kernel(scale, quant, tile, nh, group, lengths_ref,
-                       table_ref, q_ref, k_hbm, v_hbm, *refs):
+def _paged_attn_kernel(scale, quant, tile, nh, group, scale_ids,
+                       lengths_ref, table_ref, q_ref, k_hbm, v_hbm, *refs):
     """One decode row of single-query attention over its K/V pages, read
     where they lie.
 
-    ``k_hbm``/``v_hbm`` are one layer's whole page pool ``[P, psz, H*Dh]``
-    left in HBM; the row's page ids and length arrive by scalar prefetch.
+    ``k_hbm``/``v_hbm`` are a whole page pool ``[P, psz, H*Dh]`` (one
+    layer's, or every layer's viewed as ``L*P`` pages with the ids offset
+    to the layer's) left in HBM; the row's page ids and length arrive by
+    scalar prefetch.
     The row walks ``ceil(length / psz)`` pages and no more, ``tile`` pages
     per step: each page is one contiguous copy into a double-buffered
     VMEM tile (the next tile is in flight while this one is attended), and
@@ -461,7 +463,9 @@ def _paged_attn_kernel(scale, quant, tile, nh, group, lengths_ref,
     answers 0.  With ``quant`` the pages are int8 and their ``[P, psz,
     H]`` f32 scale pages are fetched the same way and fold into the
     scores and the probabilities (``q.(k*s) == (q.k)*s``), so HBM traffic
-    stays at the int8 byte count.
+    stays at the int8 byte count.  With ``scale_ids`` the scale pools are
+    one layer's own beside every layer's K/V pool, and the table carries
+    their ids (the same pages, not offset) behind the K/V ids.
 
     Grouped queries (``group`` > 1 query heads per K/V head, fewer K/V
     heads than query heads): the pool row is ``KVH*Dh`` wide, ``q_ref``
@@ -476,7 +480,7 @@ def _paged_attn_kernel(scale, quant, tile, nh, group, lengths_ref,
         o_ref, kbuf, vbuf, sems = refs
     b = pl.program_id(0)
     _, psz, width = k_hbm.shape
-    W = table_ref.shape[0] // lengths_ref.shape[0]
+    W = table_ref.shape[0] // lengths_ref.shape[0] // (2 if scale_ids else 1)
     tokens = tile * psz
     length = lengths_ref[b]
     n_pages = jnp.minimum((length + psz - 1) // psz, W)
@@ -494,9 +498,12 @@ def _paged_attn_kernel(scale, quant, tile, nh, group, lengths_ref,
             @pl.when(i < n_pages)
             def _(i=i, j=j):
                 page = table_ref[b * W + i]
+                own = table_ref[table_ref.shape[0] // 2 + b * W + i] \
+                    if scale_ids else page
                 for n, (src, dst) in enumerate(pools):
                     act(pltpu.make_async_copy(
-                        src.at[page], dst.at[slot, j], sems.at[slot, n]))
+                        src.at[page if n < 2 else own], dst.at[slot, j],
+                        sems.at[slot, n]))
 
     def start(t, slot):
         each_page(t, slot, lambda cp: cp.start())
@@ -583,8 +590,8 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     """Paged-attention decode kernel: one query row per sequence against
     the K/V pages its page table names, read in place.
 
-    q [B, H, 1, Dh]; k_pages/v_pages [P, psz, H*Dh], one layer's page
-    pool; page_table [B, W] int32 (ids >= P are the sentinel and clamp to
+    q [B, H, 1, Dh]; k_pages/v_pages [P, psz, H*Dh], a page pool;
+    page_table [B, W] int32 (ids >= P are the sentinel and clamp to
     a real page, which the length then masks); lengths [B] int32, the
     positions each row attends over (at most W * psz).  With
     ``k_scale``/``v_scale`` ([P, psz, H] f32 per-row scale pages from
@@ -593,12 +600,13 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     follows from its length, not from W.  The pools may hold fewer K/V
     heads than ``q`` has heads (``[P, psz, KVH*Dh]``, H a multiple of
     KVH: query head h reads K/V head ``h // (H // KVH)``), and with
-    ``layer`` (a static index) they are every layer's pool ``[L, P, psz,
-    KVH*Dh]`` handed over whole: they are VIEWED as ``L*P`` pages and
-    the layer's page ids offset by ``layer * P``, so no pool is sliced
-    (a slice handed to a kernel is a copy) and the kernel sees what it
-    sees of one layer's pool.  Routing/fallback policy lives in
-    ``mx.kernels.paged_attention``."""
+    ``layer`` (a Python int, or an int32 scalar traced in a layer scan)
+    they are every layer's pool ``[L, P, psz, KVH*Dh]`` handed over
+    whole (the scale pools ``[L, P, psz, H]`` too): they are VIEWED as
+    ``L*P`` pages and the layer's page ids offset by ``layer * P``, so
+    no K/V pool is sliced (a slice handed to a kernel is a copy) and the
+    kernel sees what it sees of one layer's pool.  Routing/fallback
+    policy lives in ``mx.kernels.paged_attention``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ..rtc import interpret_mode
@@ -618,9 +626,8 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     group = H // kvh
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
-    if quant and (group > 1 or layer is not None):
-        raise ValueError("int8 pages take equal head counts and one "
-                         "layer's pool")
+    if quant and group > 1:
+        raise ValueError("int8 pages take equal head counts")
     if layer is not None:
         k_pages, v_pages = (p.reshape((-1, psz, width))
                             for p in (k_pages, v_pages))
@@ -637,8 +644,13 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
                pltpu.VMEM((2, tile, psz, width), v_pages.dtype)]
     if quant:
         # a page of scales is copied whole, and a copy's minor axis is a
-        # multiple of the 128 lanes: pad the heads up to it
+        # multiple of the 128 lanes: pad the heads up to it.  Of every
+        # layer's scales only this layer's are padded (L times fewer
+        # bytes): they keep their own page ids, behind the offset ones
         lanes = _lane_pad(H)
+        if layer is not None:
+            k_scale, v_scale = (jax.lax.dynamic_index_in_dim(
+                s, layer, keepdims=False) for s in (k_scale, v_scale))
         operands += [jnp.pad(jnp.asarray(s, jnp.float32),
                              ((0, 0), (0, 0), (0, lanes - H)))
                      for s in (k_scale, v_scale)]
@@ -647,10 +659,14 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
                     pltpu.VMEM((2, tile, psz, lanes), jnp.float32)]
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quant else 2)))
     page_table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+    ids = page_table.reshape(-1)
     if layer is not None:
-        page_table = page_table + int(layer) * P
+        ids = (page_table + layer * P).reshape(-1)
+        if quant:
+            ids = jnp.concatenate([ids, page_table.reshape(-1)])
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale, quant, tile, H, group),
+        functools.partial(_paged_attn_kernel, scale, quant, tile, H, group,
+                          quant and layer is not None),
         out_shape=jax.ShapeDtypeStruct((B, rows, lanes_q), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
@@ -658,8 +674,7 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(), name="mx_paged_attention")(
-            jnp.asarray(lengths, jnp.int32), page_table.reshape(-1),
-            *operands)
+            jnp.asarray(lengths, jnp.int32), ids, *operands)
     return out.reshape(B, H, 1, D)
 
 

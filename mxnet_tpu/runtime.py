@@ -195,7 +195,11 @@ def scan_stack(body, carry, xs):
     The stack traces under the ``mx.layers`` name scope: what a device
     profile shows there and under none of the layer's own scopes is the
     stacking itself — slicing each layer's ``xs`` out and restacking its
-    ``ys`` (for the paged decode step, the K/V pool).
+    ``ys``.  So what is large and updated a layer at a time belongs in
+    the ``carry``, whole, and is written in place at the layer's index
+    (the K/V page pools of ``models.TransformerLM``'s serving programs:
+    as ``xs``/``ys`` each layer's 100 MB pool was sliced out and written
+    back, and the stack copied besides).
     """
     import jax
     with jax.named_scope("mx.layers"):
@@ -223,8 +227,7 @@ def _scan_stack(body, carry, xs):
             ys.append(y)
         if ys and ys[0] is None:
             return carry, None
-        # stack per-layer outputs like lax.scan does (the paged KV-cache
-        # writes of models/transformer.py ride the layer scan as ys)
+        # stack per-layer outputs like lax.scan does
         return carry, jax.tree_util.tree_map(
             lambda *ls: jnp.stack(ls), *ys)
     return lax.scan(body, carry, xs)

@@ -485,6 +485,116 @@ def test_generation_programs_carry_scopes_as_metadata_only(
         assert "mx_paged_attention" in text
 
 
+# ------------------------------------------- the pool as the scan's carry
+
+def _eager_kv(model, params, tokens):
+    """K and V rows of whole sequences, layer by layer in Python on the
+    plain causal stack: two ``[L, B, S, H*Dh]`` arrays."""
+    import jax
+    B, S = tokens.shape
+    x = params["embed"][tokens] + params["pos_embed"][:S][None]
+    ks, vs = [], []
+    for i in range(model.cfg.num_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
+        _, k, v = model._qkv(x, lp)
+        ks.append(np.asarray(k).transpose(0, 2, 1, 3).reshape(B, S, -1))
+        vs.append(np.asarray(v).transpose(0, 2, 1, 3).reshape(B, S, -1))
+        x = model._layer(x, lp)
+    return np.stack(ks), np.stack(vs)
+
+
+@pytest.mark.parametrize("case", ["all_rows", "inactive_slot"])
+@pytest.mark.parametrize("stack_mode", ["scan", "unroll"])
+@pytest.mark.parametrize("route", ["twin", "kernel"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32kv", "int8kv"])
+def test_prefill_and_decode_steps_carry_the_pool_to_the_oracles(
+        quantized, route, stack_mode, case):
+    """``prefill`` then N x ``decode_step`` (fed the oracle's tokens)
+    with the whole ``[L, P, psz, W]`` pools as the layer scan's carry:
+    every step's token is the eager greedy oracle's (an int8 pool: its
+    logits stay near the full forward's), the pages hold the K/V rows of
+    the eager stack at the positions the tables name, and nothing else
+    was written — padded prompt positions, table entries past a row's
+    pages (the sentinel id) and a slot with no request (sentinel pages,
+    position 0) all drop."""
+    import jax
+    import jax.numpy as jnp
+    model, params = _tiny_lm()
+    L, N, P, rows = model.cfg.num_layers, 5, 12, 3
+    rng = np.random.default_rng(11)
+    lens = np.array([3, 7, 5], np.int32)
+    prompts = [rng.integers(0, VOCAB, size=n).astype(np.int32) for n in lens]
+    want = [model.greedy_decode(params, p, N + 1) for p in prompts]
+    full = np.zeros((rows, CTX), np.int32)
+    for b, (p, w) in enumerate(zip(prompts, want)):
+        full[b, :len(p) + N] = np.concatenate([p, w[:N]])
+    ref_logits = np.asarray(model.apply(params, jnp.asarray(full)))
+    ref_k, ref_v = _eager_kv(model, params, jnp.asarray(full))
+    # each row owns the pages it needs and no more; the rest of its table
+    # is the sentinel
+    table = np.full((rows, CTX // PAGE), P, np.int32)
+    free = list(rng.permutation(P))
+    for b in range(rows):
+        for w in range(-(-(lens[b] + N) // PAGE)):
+            table[b, w] = free.pop()
+    idle = 1 if case == "inactive_slot" else None   # after its prefill
+
+    config.set("runtime.stack_mode", stack_mode)
+    if route == "kernel":
+        config.set("kernels.enabled", True)
+    try:
+        prefill = jax.jit(lambda ps, kv, t, n, tab: model.prefill(
+            ps, kv, t, n, tab, PAGE, return_logits=True))
+        decode = jax.jit(lambda ps, kv, t, pos, tab: model.decode_step(
+            ps, kv, t, pos, tab, PAGE, return_logits=True))
+        kv = model.init_kv_pages(P, PAGE, quantized=quantized)
+        kv, ids, _ = prefill(params, kv, jnp.asarray(full[:, :8]),
+                             jnp.asarray(lens), jnp.asarray(table[:, :2]))
+        # (the first token never sees the pages, whatever they hold)
+        assert np.array_equal(ids, [w[0] for w in want])
+        held = lens.copy()
+        for j in range(N):
+            pos, tab = lens + j, table.copy()
+            tok = full[np.arange(rows), pos]
+            if idle is not None:
+                pos[idle], tok[idle], tab[idle] = 0, 0, P
+            kv, ids, logits = decode(params, kv, jnp.asarray(tok),
+                                     jnp.asarray(pos), jnp.asarray(tab))
+            for b in range(rows):
+                if b == idle:
+                    continue
+                held[b] = pos[b] + 1
+                ref = ref_logits[b, pos[b]]
+                if quantized:
+                    assert np.abs(np.asarray(logits[b]) - ref).max() \
+                        <= 0.05 * np.abs(ref).max(), (j, b)
+                else:
+                    assert int(ids[b]) == want[b][j + 1], (j, b)
+    finally:
+        config.unset("runtime.stack_mode")
+        config.unset("kernels.enabled")
+
+    for key, ref in (("k", ref_k), ("v", ref_v)):
+        pool = np.asarray(kv[key], np.float32)
+        if quantized:
+            pool = (pool.reshape(pool.shape[:3] + (model.cfg.num_heads, -1))
+                    * np.asarray(kv[key + "_scale"])[..., None]
+                    ).reshape(pool.shape)
+        written = np.zeros(pool.shape[:3], bool)
+        for b in range(rows):
+            for t in range(held[b]):
+                at = (slice(None), table[b, t // PAGE], t % PAGE)
+                written[at] = True
+                tol = (np.abs(ref[:, b, t]).max() / 100 if quantized
+                       else 1e-6)
+                assert np.abs(pool[at] - ref[:, b, t]).max() <= tol, \
+                    (key, b, t)
+        assert written.sum() == L * held.sum()
+        assert not pool[~written].any(), key
+        if quantized:
+            assert not np.asarray(kv[key + "_scale"])[~written].any()
+
+
 # ------------------------------------------------------- smoke wrapper
 
 def test_check_generation_smoke():

@@ -474,3 +474,78 @@ def test_hybrid_programs_carry_scopes_as_metadata_only(program, scopes,
         assert scope + "/" in text or scope + '"' in text, scope
     if program == "decode":
         assert "mx.kv_gather" not in text and "mx_paged_attention" in text
+
+
+def _without_kernel_locations(text):
+    """A lowered program's text with each Mosaic kernel's serialized body
+    (which carries source lines) replaced by its MLIR text without
+    locations."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+#: sha256 of the programs' text at the commit before the pool left
+#: ``TransformerLM``'s layer scan (17977ce): ``layer`` as a Python int
+#: lowers as it did
+PARENT_PROGRAMS = {
+    ("decode", "chip"):
+        "d27f24381eee0635149f7b65805a0b16e445307b6b6217c55725408dc26b986e",
+    ("decode", "interpreter"):
+        "f2f7696e2a15761f4c8bce959645af64fd0aa2dea06aea4ac7bce7018f4c33df",
+    ("prefill", "chip"):
+        "1f54d69affbf15ea120a198a040baa8843f511680e3b3239c0ce01e23228a7ba",
+    ("prefill", "interpreter"):
+        "d79f34b3cd8c1a7606751f2eabc202b81ca664b61e4366026887dad62602a675",
+}
+
+
+@pytest.mark.parametrize("program,lowered_for", list(PARENT_PROGRAMS))
+def test_hybrid_programs_are_the_parents(program, lowered_for, monkeypatch):
+    """``HybridLM`` walks its blocks in Python and hands the paged kernel
+    a static ``layer``: its decode and prefill programs on the kernel's
+    route — lowered for the TPU as the cell's artifact is (the Mosaic
+    kernel's body compared without its locations), and for the
+    interpreter — are, locations aside, byte for byte the parent's."""
+    import hashlib
+    from mxnet_tpu import rtc
+    mx.config.set("kernels.enabled", True)
+    mx.config.set("perf.autotune", "off")
+    if lowered_for == "chip":
+        monkeypatch.setattr(rtc, "interpret_mode", lambda: False)
+    platforms = {"chip": ("tpu",), "interpreter": None}[lowered_for]
+    model, params = _tiny()
+    kv = model.init_kv_pages(8, PAGE, slots=4)
+    i32 = jnp.int32
+    try:
+        if program == "decode":
+            traced = jax.jit(lambda p, c, t, pos, tab: model.decode_step(
+                p, c, t, pos, tab, PAGE, return_stats=True)).trace(
+                    params, kv, jnp.zeros((4,), i32), jnp.ones((4,), i32),
+                    jnp.zeros((4, 2), i32))
+        else:
+            traced = jax.jit(lambda p, c, t, n, tab, s: model.prefill(
+                p, c, t, n, tab, PAGE, slots=s)).trace(
+                    params, kv, jnp.zeros((1, 8), i32), jnp.ones((1,), i32),
+                    jnp.zeros((1, 2), i32), jnp.zeros((1,), i32))
+        text = traced.lower(lowering_platforms=platforms).as_text()
+    finally:
+        mx.config.unset("kernels.enabled")
+        mx.config.unset("perf.autotune")
+    if program == "decode":
+        assert ("tpu_custom_call" in text) == (lowered_for == "chip")
+    text = _without_kernel_locations(text)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PARENT_PROGRAMS[program, lowered_for]

@@ -373,6 +373,41 @@ def test_paged_row_of_length_zero_reads_nothing_and_answers_zero():
     np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("route", ["kernel", "twin"])
+def test_paged_layer_of_whole_pools_traced_or_static_is_that_layers_pool(
+        route, pool):
+    """Every layer's pool ``[L, P, psz, W]`` (and scale pools ``[L, P,
+    psz, H]``) handed over whole with ``layer`` a traced int32 scalar —
+    as a layer scan hands it — or a Python int: the answer of the
+    one-layer call on ``pool[layer]`` (to the rounding of a program fused
+    otherwise), for every layer, sentinel ids included."""
+    fn = pallas_paged_attention if route == "kernel" \
+        else kernels._paged_attention_xla
+    layers = [_pool_case(LENGTHS["inactive_rows"], 8, pool, seed=7 + i)
+              for i in range(2)]
+    q, _, _, table, lengths = layers[0][:5]
+    tol = POOL_DTYPES[pool][2]
+    stack = [None if layers[0][i] is None
+             else jnp.stack([c[i] for c in layers]) for i in (1, 2, 5, 6)]
+
+    def whole(layer):
+        return fn(q, stack[0], stack[1], table, lengths, k_scale=stack[2],
+                  v_scale=stack[3], layer=layer)
+
+    one = jax.jit(lambda kp, vp, ks, vs: fn(
+        q, kp, vp, table, lengths, k_scale=ks, v_scale=vs))
+    traced = jax.jit(whole)
+    for i, (_, kp, vp, _, _, ks, vs) in enumerate(layers):
+        want = np.asarray(one(kp, vp, ks, vs), np.float32)
+        assert np.abs(want).max() > 0
+        for got in (traced(jnp.int32(i)), jax.jit(lambda: whole(i))()):
+            np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                       rtol=tol, atol=tol)
+    assert np.abs(want - np.asarray(traced(jnp.int32(0)), np.float32)).max() \
+        > 10 * tol                            # the layers do differ
+
+
 def test_paged_routing_explicit_vs_default():
     """Explicit tier-on routes decode through the Pallas kernel (counter
     + route record); the graduated default on the interpreter backend

@@ -62,13 +62,18 @@ def compile_for_chip(one_chip, monkeypatch):
     program: ``interpret_mode`` asks the backend, which is the cpu."""
     monkeypatch.setattr(rtc, "interpret_mode", lambda: False)
 
+    def program(fn, *specs, donate_argnums=()):
+        return jax.jit(fn, donate_argnums=donate_argnums).lower(
+            *specs).compile()
+
     def run(fn, *specs):
-        return jax.jit(fn).lower(*specs).compile().as_text()
+        return program(fn, *specs).as_text()
 
     def spec(shape, dtype, sharding=one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     run.spec = spec
+    run.program = program
     return run
 
 
@@ -220,29 +225,77 @@ def _default_lm(mesh=None):
     return model, jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
 
+def _assert_pools_stay_where_they_are(program, pools):
+    """``pools``: the specs of the donated cache of a compiled serving
+    ``program``, the K and V page pools ``[L, P, psz, W]`` the largest.
+    All of it comes back in the buffers it came in (aliased), the program's
+    temporaries are smaller than one pool, and no instruction makes an
+    array the size of the whole K or V pool or of one layer's share of it
+    — a copy, a slice out of the stack, an update back into it — but the
+    write of the new rows, which is in place (a scatter whose fusion
+    aliases its operand).  (An int8 pool's scale pools, a thirty-second
+    of the bytes, the compiler may move to faster memory for the loop.)"""
+    import math
+    import re
+    pools = sorted(pools, reverse=True,
+                   key=lambda p: math.prod(p.shape) * p.dtype.itemsize)
+    mem = program.memory_analysis()
+    sizes = [math.prod(p.shape) * p.dtype.itemsize for p in pools]
+    assert mem.alias_size_in_bytes >= sum(sizes)
+    assert mem.temp_size_in_bytes < sizes[0], mem.temp_size_in_bytes
+    pools = pools[:2]
+    dtypes = {"bf16": BF16, "f32": F32, "s8": I8}
+    watched = {(p.dtype, math.prod(p.shape) // whole)
+               for p in pools for whole in (1, p.shape[0])}
+    made = re.compile(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+    for line in program.as_text().splitlines():
+        m = made.match(line)
+        if not m or m.group(1) not in dtypes:
+            continue
+        dtype, dims, opcode = m.groups()
+        size = math.prod(int(d) for d in dims.split(",") if d)
+        if (dtypes[dtype], size) not in watched or opcode in (
+                "parameter", "get-tuple-element", "bitcast", "scatter"):
+            continue
+        assert opcode == "fusion" and '"aliasing_operands":{"lists":[{' \
+            in line, line[:300]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_decode_step_program_of_the_default_config_compiles(
-        compile_for_chip, kernel_tier_on, quantized):
-    """One whole decode iteration: 12 scanned layers, the paged kernel
-    baked in on the layer's slice of the pool, and no gather."""
+        compile_for_chip, kernel_tier_on, quantized, program):
+    """One whole decode iteration: 12 scanned layers that carry the whole
+    pool, the paged kernel baked in once on the pool and the layer's
+    index, no gather, and the pool written where it lies; the prefill of
+    a 512-token prompt beside it writes it there too."""
     spec = compile_for_chip.spec
     model, shapes = _default_lm()
-    cfg = model.cfg
     psz, pool, width = 16, 512, 128       # width 128: a 2048-slot window
     on_chip = functools.partial(jax.tree_util.tree_map,
                                 lambda a: spec(a.shape, a.dtype))
     kv = on_chip(jax.eval_shape(
         lambda: model.init_kv_pages(pool, psz, quantized=quantized)))
     params = on_chip(shapes)
+    if program == "prefill":
+        compiled = compile_for_chip.program(
+            lambda ps, kv, tok, n, table: model.prefill(
+                ps, kv, tok, n, table, psz),
+            params, kv, spec((1, 512), jnp.int32), spec((1,), jnp.int32),
+            spec((1, 512 // psz), jnp.int32), donate_argnums=(1,))
+        _assert_pools_stay_where_they_are(compiled, kv.values())
+        return
     with kernels.record_paged_routes() as routes:
-        text = compile_for_chip(
+        compiled = compile_for_chip.program(
             lambda ps, kv, tok, pos, table: model.decode_step(
                 ps, kv, tok, pos, table, psz),
             params, kv, spec((B,), jnp.int32), spec((B,), jnp.int32),
-            spec((B, width), jnp.int32))
+            spec((B, width), jnp.int32), donate_argnums=(1,))
+    text = compiled.as_text()
     assert routes and routes[0]["impl"] == "paged", routes
     assert _kernel_count(text) == 1       # the scan body holds it once
     assert "mx.kv_gather" not in text and "mx.paged_attention" in text
+    _assert_pools_stay_where_they_are(compiled, kv.values())
 
 
 def test_decode_programs_exported_as_the_benchmark_does_take_the_kernel(
@@ -251,9 +304,10 @@ def test_decode_programs_exported_as_the_benchmark_does_take_the_kernel(
     ``benchmarks/drivers/serve_lm.py`` calls it, lowered for the TPU: the
     pool's page count is symbolic in the artifact, and every decode width
     still records route "paged"; reloaded and compiled for the described
-    chip at the cell's pool, widths 128 and 8 hold the kernel and no
-    gather.  (Two layers and a small vocabulary: the kernel's shapes are
-    the cell's.)"""
+    chip at the cell's pool of 1,536 pages, donated as the server donates
+    it, widths 128 and 8 hold the kernel and no gather, and they and the
+    prefill bucket leave the pool where it lies.  (Two layers and a small
+    vocabulary: the kernel's shapes are the cell's.)"""
     import json
     from jax import export as jexport
     from mxnet_tpu import deploy
@@ -277,19 +331,28 @@ def test_decode_programs_exported_as_the_benchmark_does_take_the_kernel(
         meta["paged"]
     on_chip = functools.partial(jax.tree_util.tree_map,
                                 lambda a: spec(a.shape, a.dtype))
+    pools = on_chip(deploy._kv_pool_specs(meta["kv"], CELL["P"]))
+
+    def compiled(program, rows, *specs):
+        with open("%s-%s.stablehlo" % (prefix, program), "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return compile_for_chip.program(
+            exp.call, on_chip(params), pools, *specs, spec((rows,), F32),
+            spec((rows,), jnp.int32), spec((rows,), F32),
+            spec((rows, 2), jnp.uint32), donate_argnums=(1,))
+
     rows = 32
     for width in (128, 8):
-        with open("%s-decode-w%d.stablehlo" % (prefix, width), "rb") as f:
-            exp = jexport.deserialize(f.read())
-        text = compile_for_chip(
-            exp.call, on_chip(params),
-            on_chip(deploy._kv_pool_specs(meta["kv"], CELL["P"])),
-            spec((rows,), jnp.int32), spec((rows,), jnp.int32),
-            spec((rows, width), jnp.int32), spec((rows,), F32),
-            spec((rows,), jnp.int32), spec((rows,), F32),
-            spec((rows, 2), jnp.uint32))
+        decode = compiled(
+            "decode-w%d" % width, rows, spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), spec((rows, width), jnp.int32))
+        text = decode.as_text()
         assert _kernel_count(text) == 1, width
         assert "mx.kv_gather" not in text, width
+        _assert_pools_stay_where_they_are(decode, pools)
+    _assert_pools_stay_where_they_are(compiled(
+        "prefill-s128", 1, spec((1, 128), jnp.int32), spec((1,), jnp.int32),
+        spec((1, 128 // 16), jnp.int32)), pools)
 
 
 def test_routed_attention_compiles_per_shard_on_a_2x2_mesh(
