@@ -117,7 +117,7 @@ def _sizes(rehearse):
                               prompt_buckets=(8, 32), new_tokens=6,
                               decode_batch=4, page=16, kv_pages=32),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
-            epilogue=(24, 40), softmax=(16, 48), sbr=(16, 40),
+            softmax=(16, 48), sbr=(16, 40),
             dp_batch=16, lm_batch=4, lm_seq=32)
     return dict(
         vision="resnet50_v1", classes=1000, image=224, batch=128,
@@ -143,7 +143,7 @@ def _sizes(rehearse):
                           prompt_buckets=(128, 512), new_tokens=24,
                           decode_batch=8, page=128, kv_pages=64),
         flash=((8, 12, 1024, 64), (2, 12, 200, 64)), paged_k=(1024, 2048),
-        epilogue=(768, 3072), softmax=(4096, 1024), sbr=(4096, 768),
+        softmax=(4096, 1024), sbr=(4096, 768),
         dp_batch=512, lm_batch=4, lm_seq=1024)
 
 
@@ -235,7 +235,7 @@ def phase_train(args, sz):
     import jax.numpy as jnp
     import numpy as np
     import mxnet_tpu as mx
-    from mxnet_tpu import autotune, profiler
+    from mxnet_tpu import profiler
     from mxnet_tpu.parallel import make_mesh
 
     dev = jax.devices()[0]
@@ -301,10 +301,7 @@ def phase_train(args, sz):
           compiles_after_warmup=profiler.counters()["fused_compiles"]
           - compiles0,
           programs_after_warmup=len(mx.perf.programs("spmd")) - programs0,
-          params_on_device=on_dev, peak_bytes=_peak_bytes(dev),
-          autotune={k.split("|")[1]: {f: v.get(f) for f in (
-              "impl", "baseline_ms", "best_ms", "parity", "reason")}
-              for k, v in autotune.export_entries()["entries"].items()})
+          params_on_device=on_dev, peak_bytes=_peak_bytes(dev))
     _require(all(np.isfinite(host)), "non-finite loss: %s" % host)
     recent = min(host[len(host) // 2:])
     _require(recent < host[0], "loss did not fall: first %.4f, best of the "
@@ -314,8 +311,8 @@ def phase_train(args, sz):
              and len(mx.perf.programs("spmd")) == programs0,
              "the step recompiled after warm-up")
     _require(waits, "block_until_ready returned before the device was done "
-             "(fetch after it took %.4fs, a step %.4fs): bench.py's timing "
-             "regions do not end" % (fetch_after_block_s, step_ms / 1e3))
+             "(fetch after it took %.4fs, a step %.4fs): timing regions "
+             "that end in it do not end" % (fetch_after_block_s, step_ms / 1e3))
 
 
 # ------------------------------------------------------------------ serve
@@ -659,25 +656,6 @@ def phase_kernels(args, sz):
                   q, k, v, t, n, k_scale=ks, v_scale=vs),
               (rand((B, H, 1, D), bf16), k8, v8, table, lens, ks, vs), MXU)
 
-    w, g, m1 = (rand(sz["epilogue"], f32) for _ in range(3))
-    m2 = rand(sz["epilogue"], f32, lo=0.0)
-    sgd = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9)
-    adam = mx.optimizer.create("adam", learning_rate=1e-3)
-
-    def master(opt):  # the step()+astype pair the fused epilogue replaces
-        def twin(w, g, *state):
-            st = state[0] if len(state) == 1 else tuple(state)
-            nw, ns = opt.step(w, g, st, 0.1, 1e-4, 3)
-            return nw.astype(bf16), nw, ns
-        return twin
-    check("fused_sgd_step",
-          lambda w, g, m: sgd.step_fused(w, g, m, 0.1, 1e-4, 3,
-                                         out_dtype=bf16),
-          master(sgd), (w, g, m1), POINTWISE)
-    check("fused_adam_step",
-          lambda w, g, m, v: adam.step_fused(w, g, (m, v), 0.1, 1e-4, 3,
-                                             out_dtype=bf16),
-          master(adam), (w, g, m1, m2), POINTWISE)
     x = rand(sz["softmax"], f32)
     check("pallas_row_softmax", pk.pallas_row_softmax,
           lambda x: jax.nn.softmax(x, axis=-1), (x,), POINTWISE)
@@ -797,11 +775,7 @@ def phase_four_chips(args, sz):
                   batch_on=_on_devices(tok4), tp_leaves_on=tp_leaves,
                   tp_shard_shapes={
                       n: list(sharded["layers"][n].addressable_shards[0]
-                              .data.shape) for n in tp_leaves},
-                  attention_routes={n: v.get("impl") for n, v in
-                                    mx.perf.autotune.export_entries()[
-                                        "entries"].items()
-                                    if n.startswith("attention")}),
+                              .data.shape) for n in tp_leaves}),
           peak_bytes=[_peak_bytes(d) for d in devs])
     _require(runs["dp4"]["batch_on"] == want and _on_devices(tok4) == want
              and all(v == want for v in tp_leaves.values()),

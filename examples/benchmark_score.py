@@ -5,8 +5,8 @@ each network and batch size, time the forward pass and print img/s (the
 corpus behind the reference's perf.md inference tables).
 
 TPU-native: each (model, batch) pair is one jitted forward with
-device-resident inputs and forced-fetch timing (same methodology as
-bench.py).  --dtype bfloat16 casts params+inputs for the MXU rate.
+device-resident inputs and forced-fetch timing.  --dtype bfloat16 casts
+params+inputs for the MXU rate.
 """
 from __future__ import annotations
 

@@ -46,9 +46,6 @@ _INDEX_END = "<!-- mxlint:metric-index:end -->"
 #: --fix-docs folds these into ENV_VARS.md.  An env read the registry
 #: does not know and this table does not describe is a finding.
 _ENV_NOTES = {
-    "MXTPU_BENCH_CPU":
-        "bench.py only: dry-run the benchmark's control flow on the CPU "
-        "backend; unset = the accelerator, and bench.py fails without one.",
     "MXTPU_CHAOS_STEPS":
         "tools/dist_chaos_worker.py only: total optimisation steps of "
         "the elastic chaos smoke job (default 10).",
@@ -76,7 +73,8 @@ _ENV_VARS_HEADER = """\
 Generated from `mx.config.describe()` — the env_var.md analog
 (reference: docs/static_site/src/pages/api/faq/env_var.md).  Every
 knob is typed; `mx.config.set(name, value)` overrides at runtime and
-invalidates compiled-program caches when the value changes.
+invalidates compiled-program caches when the value or its source
+(default, env, override) changes.
 
 Regenerate this file with `python tools/mxlint.py --fix-docs`;
 `tools/mxlint.py` (the `drift` pass, docs/ANALYSIS.md) fails CI when

@@ -2,13 +2,12 @@
 
 Everything here is plain-stdlib (ast/tokenize/re/json): the passes must
 run in tools/mxlint.py without importing jax or the framework itself,
-so a full-tree lint stays well under a second and can gate CI and the
-bench preflight.
+so a full-tree lint stays well under a second and can gate CI.
 
 The pieces the passes build on:
 
 * ``Repo`` — parses every framework source file once (``mxnet_tpu/``,
-  ``tools/``, ``bench.py``) into ``SourceModule`` records and resolves
+  ``tools/``) into ``SourceModule`` records and resolves
   cross-module references through each module's import-alias table, so
   a pass can follow ``_resilience.select_tree`` from a traced step body
   into ``mxnet_tpu/resilience.py``.
@@ -55,7 +54,7 @@ HOLDS_RE = re.compile(r"mxlint:\s*holds\((?P<lock>[A-Za-z_]\w*)\)")
 DISABLE_RE = re.compile(r"mxlint:\s*disable=(?P<rules>[\w.,-]+)")
 
 #: directories/files a Repo scans, relative to the repo root.
-DEFAULT_TARGETS = ("mxnet_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("mxnet_tpu", "tools")
 
 
 def dotted_name(node):

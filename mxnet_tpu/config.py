@@ -69,10 +69,9 @@ def source(name):
     """Where the current value of ``name`` comes from: ``'override'``
     (programmatic set()), ``'env'`` (its environment variable) or
     ``'default'`` (the registry default).  Policy code uses this to
-    distinguish an operator's explicit choice from a shipped default —
-    e.g. the kernel tier's default-on graduation gates routing on
-    measured wins only when ``kernels.enabled`` is still at its
-    default, while an explicit on/off is honored verbatim."""
+    distinguish an operator's explicit choice from a shipped default:
+    the kernel tier keeps interpreted backends on the XLA lowering only
+    while ``kernels.enabled`` is still at its default."""
     knob = _KNOBS[name]
     if name in _OVERRIDES:
         return "override"
@@ -90,17 +89,15 @@ def set(name, value):  # noqa: A001 — reference-parity name
     parsed = _parse(knob, value) if isinstance(value, str) \
         else knob.type(value)
     hook = _ON_SET.get(name)
-    if parsed == get(name):
-        # no-op set (same as current override/env/default): don't
-        # invalidate compiled-program caches — but DO re-fire the side-
-        # effect hook, so external state a hook mirrors (jax_enable_x64)
-        # re-syncs even if someone flipped it behind the knob's back
-        _OVERRIDES[name] = parsed
-        if hook is not None:
-            hook(parsed)
-        return
+    # a set that changes neither the value nor its source() (the same
+    # override again) keeps compiled-program caches; one that makes a
+    # default or env value an explicit choice retraces, since routing
+    # reads the source (kernels._route_reason).  The side-effect hook
+    # re-fires either way, so external state it mirrors (jax_enable_x64)
+    # re-syncs even if someone flipped it behind the knob's back
+    changed = parsed != get(name) or name not in _OVERRIDES
     _OVERRIDES[name] = parsed
-    if name not in _EPOCH_NEUTRAL:
+    if changed and name not in _EPOCH_NEUTRAL:
         global _EPOCH
         _EPOCH += 1
     if hook is not None:
@@ -109,11 +106,9 @@ def set(name, value):  # noqa: A001 — reference-parity name
 
 def unset(name):
     """Drop a programmatic override so ``name`` falls back to its env
-    var / registry default — including its *source* (mx.perf.autotune's
-    knob-space search restores knobs this way, so a sweep can never
-    leave a default-source knob looking explicitly set).  Bumps the
-    epoch and re-fires the side-effect hook only when the effective
-    value actually changes."""
+    var / registry default — including its *source*, so dropping an
+    override bumps the epoch; the side-effect hook re-fires only when
+    the effective value actually changes."""
     if name not in _KNOBS:
         raise KeyError("unknown knob %r (see mx.config.describe())" % name)
     if name not in _OVERRIDES:
@@ -121,17 +116,16 @@ def unset(name):
     old = get(name)
     del _OVERRIDES[name]
     new = get(name)
-    if new == old:
-        return
     if name not in _EPOCH_NEUTRAL:
         global _EPOCH
         _EPOCH += 1
     hook = _ON_SET.get(name)
-    if hook is not None:
+    if new != old and hook is not None:
         hook(new)
 
 
-# Bumped by every set(): compiled-program caches that bake knob values in at
+# Bumped by every set() / unset() that changes a knob's value or its
+# source(): compiled-program caches that bake knob values in at
 # trace time (Executor forward programs, _CachedGraph) key on epoch() so a
 # knob change invalidates them instead of silently not applying.
 _EPOCH = 0
@@ -219,7 +213,7 @@ def enable_x64(flag=True):
 # NCHW dimension numbers; "NHWC" transposes inside the Convolution lowering
 # so channels ride the TPU lane dimension (XLA cancels the transposes
 # between adjacent convs).  Knob-gated because the win is model-shape
-# dependent; bench.py sweeps both.
+# dependent.
 register_knob(
     "conv.internal_layout", "MXTPU_CONV_LAYOUT", str, "native",
     "internal conv layout: native (NCHW dimension numbers) or NHWC "
@@ -799,22 +793,14 @@ _ON_SET["serving.decode_slots"] = _positive_int_knob("serving.decode_slots")
 # Pallas kernel tier (docs/PERF_NOTES.md "Kernel tier")
 register_knob(
     "kernels.enabled", "MXNET_TPU_KERNELS", bool, True,
-    "route the training hot path through the Pallas kernel tier "
-    "(mx.kernels): fused flash-attention fwd+bwd under the transformer/"
-    "BERT stack and the fused optimizer+cast epilogue inside the fused "
-    "train steps (module fused_step_fn, SPMDTrainer, eager "
-    "multi-precision updates). Shapes/optimizers the kernels cannot "
-    "serve fall back to the XLA lowering per call site "
-    "(kernels.fallback counts them). On (the default since round 16) is "
-    "GATED: while the knob sits at its default, each routed site only "
-    "takes a kernel after mx.perf.autotune proves parity plus a "
-    "measured speedup >= 1.0x on this device (kernels.gated_fallback "
-    "counts the sites that lose); setting the knob explicitly (env or "
-    "set()) bypasses the gate — on routes kernels wherever feasible, "
-    "off keeps every traced program byte-identical to the pre-kernel "
-    "paths. On CPU/GPU the kernels run through the Pallas interpreter "
-    "(same numerics, no speedup), so the gate statically routes "
-    "default-knob programs to the XLA lowering there.")
+    "the Pallas kernel tier (mx.kernels). One static rule routes "
+    "attention and paged decode attention at trace time: off -> the XLA "
+    "lowering, byte-identical to a program without the tier; at its "
+    "default on a backend that interprets Pallas (CPU/GPU) -> the XLA "
+    "lowering (kernels.gated_fallback); a shape the kernel cannot take "
+    "-> the XLA lowering (kernels.fallback / kernels.paged_fallback); "
+    "else the kernel. Set explicitly on (env or set()) the kernels also "
+    "run in the interpreter.")
 register_knob(
     "kernels.vmem_budget", "MXNET_TPU_KERNELS_VMEM_BUDGET", int,
     2097152,  # 2 MiB — a literal, so static doc/drift tooling can read it
@@ -839,39 +825,6 @@ def _apply_kernels_vmem_budget(value):
 
 _ON_SET["kernels.vmem_budget"] = _apply_kernels_vmem_budget
 
-# measured config search over the kernel tier (mx.perf.autotune,
-# docs/PERF_NOTES.md "Autotune")
-register_knob(
-    "perf.autotune", "MXNET_TPU_AUTOTUNE", str, "auto",
-    "mx.perf.autotune mode. 'auto' (default): apply persisted winners "
-    "at trace time; on a cache miss, measure once and write through on "
-    "TPU, or statically route to the XLA lowering on interpreted "
-    "backends (CPU/GPU) where a Pallas kernel can never win. 'measure': "
-    "always run the measured search on a miss, even interpreted (what "
-    "tools/check_autotune.py and bench.py use). 'off': no search, no "
-    "cache — legacy routing (kernels wherever feasible when the tier "
-    "is on).")
-register_knob(
-    "perf.autotune_cache", "MXNET_TPU_AUTOTUNE_CACHE", str, "",
-    "path of the persisted tuning cache (JSON). Empty (default) = "
-    "<model_store.root>/autotune.json, i.e. ~/.mxnet/autotune.json. "
-    "Entries are keyed by program family/site + device kind + dominant "
-    "dtype + a fingerprint of the knob VALUES the kernels lower "
-    "against (notably kernels.vmem_budget), so a stale budget can "
-    "never resurrect block picks sized for a different VMEM window.")
-
-
-def _apply_perf_autotune(value):
-    v = (value or "").strip().lower()
-    if v not in ("off", "auto", "measure"):
-        # reject at set() time and revert (the nanguard pattern)
-        _OVERRIDES.pop("perf.autotune", None)
-        raise ValueError("perf.autotune must be 'off', 'auto' or "
-                         "'measure', got %r" % (value,))
-
-
-_ON_SET["perf.autotune"] = _apply_perf_autotune
-
 # transformer layer-stack program tuning (runtime.scan_stack,
 # docs/PERF_NOTES.md "Kernel tier")
 register_knob(
@@ -879,7 +832,7 @@ register_knob(
     "layer-stack program shape for runtime.scan_stack: 'scan' (default) "
     "traces the layer body ONCE under lax.scan so trace/compile time "
     "stays flat in depth; 'unroll' inlines every layer (the A/B "
-    "baseline bench.py measures perf.trace_ms/compile_ms against).")
+    "baseline tools/check_kernels.py builds against).")
 register_knob(
     "runtime.remat", "MXNET_TPU_REMAT", str, "",
     "selective rematerialization wrapped around the scanned layer body "
@@ -919,9 +872,8 @@ register_knob(
     "inside SPMDTrainer's fused step: table sharded on the vocab axis, "
     "ids deduplicated per batch, only touched rows of the table and "
     "optimizer state rewritten. False = dense gradients + dense "
-    "optimizer step (the full-table-gradient baseline bench.py's "
-    "dlrm_embedding_throughput measures against). Read when a trainer "
-    "is constructed/materialized.")
+    "optimizer step (the full-table-gradient baseline). Read when a "
+    "trainer is constructed/materialized.")
 register_knob(
     "embedding.unique_size", "MXNET_TPU_EMBEDDING_UNIQUE_SIZE", int, 0,
     "static per-batch unique-id capacity for the deduplicated embedding "
@@ -958,6 +910,11 @@ register_knob(
     "xla.autotune", "MXNET_CUDNN_AUTOTUNE_DEFAULT", int, 0,
     "cuDNN autotune (env_var.md:234) maps to XLA's internal autotuning; "
     "value is informational.")
+register_knob(
+    "perf.autotune", "MXNET_TPU_AUTOTUNE", str, "auto",
+    "retired: the timed kernel search it steered is gone (mx.kernels "
+    "routes by one static rule). Still accepted ('off', 'auto', "
+    "'measure') because benchmark configs set it; read by nothing.")
 register_knob(
     "bn_two_pass_stats", "MXTPU_BN_TWO_PASS_STATS", bool, False,
     "BatchNorm training statistics: False (default) = single-pass "

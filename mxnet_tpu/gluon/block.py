@@ -534,13 +534,11 @@ class _CachedGraph:
         return jitted
 
     def __call__(self, *args):
-        from .. import autotune as _autotune
         from .. import config as _config
         training = autograd.is_training()
-        # knob values AND mx.perf.autotune picks bake in at trace — the
-        # epoch tracks config.set, the generation tracks freshly
-        # recorded tuning winners; either moving retraces
-        key = (training, (_config.epoch(), _autotune.generation()))
+        # knob values bake in at trace: a config mutation (the epoch)
+        # retraces
+        key = (training, _config.epoch())
         if key not in self._jitted:
             # evict programs compiled under superseded knob epochs
             self._jitted = {k: v for k, v in self._jitted.items()
@@ -550,7 +548,7 @@ class _CachedGraph:
             # into the outer trace via the plain jit fn, unaccounted
             self._jitted[key] = _perf.wrap(
                 self._build(training), "gluon",
-                "%s/train=%s/e%d" % (self.block.name, training, key[1][0]),
+                "%s/train=%s/e%d" % (self.block.name, training, key[1]),
                 source="gluon", check_tracers=True)
         fn = self._jitted[key]
         self._ensure_params()
@@ -850,9 +848,8 @@ class SymbolBlock(HybridBlock):
         training = autograd.is_training()
         names = list(inputs.keys()) + list(arg_vals.keys())
         nds = list(inputs.values()) + list(arg_vals.values())
-        from .. import autotune as _autotune
-        # knobs + autotune picks bake in at trace (see _CachedGraph)
-        cache_key = (training, (_config.epoch(), _autotune.generation()))
+        # knobs bake in at trace (see _CachedGraph)
+        cache_key = (training, _config.epoch())
         if getattr(self, "_taped_cache", None) is None:
             self._taped_cache = {}
         if cache_key not in self._taped_cache:

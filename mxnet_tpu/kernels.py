@@ -1,35 +1,27 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module owns WHEN they run.  Reference analog: the graph optimizer
-deciding when to swap a library op for a hand-fused RTC kernel
-(src/common/rtc.cc + graph passes) — here the decision is an explicit
-config knob plus a shape/platform feasibility check, because silent
-kernel swaps are how frameworks grow haunted performance.
+this module alone decides WHEN they run.  Both routed sites
+(:func:`attention`, :func:`paged_attention`) ask one rule
+(:func:`_route_reason`), which reads three things it can see at trace
+time and nothing else — nothing is timed, persisted or remembered:
 
-Routing contract (docs/PERF_NOTES.md "Kernel tier" + "Autotune"):
+1. ``kernels.enabled`` off → the XLA lowering, traced as if the tier
+   did not exist;
+2. the knob at its *default* on a backend that interprets Pallas
+   (``rtc.interpret_mode()``: CPU, GPU) → the XLA lowering, since an
+   interpreted kernel cannot beat a compiled program
+   (``kernels.gated_fallback``); an explicit on still takes the kernel
+   there, which is how the parity tests run it;
+3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
+   :func:`paged_unsupported_reason`) → the XLA lowering
+   (``kernels.fallback`` / ``kernels.paged_fallback``), never an error;
+4. else the kernel (``kernels.flash_attention`` /
+   ``kernels.paged_attention``), at constant block sizes.
 
-* the tier is ON by default since round 16, but a *default-source* knob
-  is GATED: each routed site only takes a kernel after mx.perf.autotune
-  proves bitwise-or-tolerance parity plus a measured speedup >= 1.0x on
-  this device (``kernels.gated_fallback`` counts losing sites, which
-  fall back to the XLA lowering permanently — the PR 11 AOT-rejection
-  contract).  On interpreted backends the gate statically routes to
-  XLA, so default-knob CPU programs stay byte-identical to the
-  pre-tier lowering;
-* an EXPLICIT ``kernels.enabled`` (env var or ``config.set``) bypasses
-  the gate: off traces the exact pre-tier XLA ops (byte-identical
-  programs); on routes supported shapes through the Pallas kernel
-  (``kernels.flash_attention`` counter) with tuned block sizes when a
-  winner is cached, falling back only on infeasible shapes
-  (``kernels.fallback`` counter) — never an error;
-* the decision is trace-time python, so a jitted program contains one
-  path only; toggling the knob or landing a new autotune winner
-  retraces (config epoch / autotune generation in the cache keys).
-
-On CPU the kernels run through the Pallas interpreter — same numerics,
-no TPU needed — which is what the parity gates in
-``tools/check_kernels.py`` rely on.
+The decision is trace-time python, so a jitted program contains one
+path only; a knob change retraces (``config.epoch()`` in the program
+cache keys, which moves when a knob's value or its source does).
 """
 from __future__ import annotations
 
@@ -40,15 +32,12 @@ import contextlib
 
 from . import config as _config
 from . import telemetry as _telemetry
-from .ops.pallas_kernels import (flash_attention, fused_adam_step,
-                                 fused_sgd_step, pallas_paged_attention)
+from .ops.pallas_kernels import flash_attention, pallas_paged_attention
 
 __all__ = ["enabled", "attention", "paged_attention",
            "flash_unsupported_reason", "paged_unsupported_reason",
            "record_paged_routes", "pallas_dynamic_shapes",
-           "fused_step_enabled",
-           "flash_attention", "pallas_paged_attention",
-           "fused_sgd_step", "fused_adam_step", "measure"]
+           "flash_attention", "pallas_paged_attention"]
 
 # one-row VMEM feasibility: a q block keeps its head's full K and V
 # resident, so 2 * Skv * D * itemsize must fit the budget
@@ -61,25 +50,22 @@ def enabled():
     return bool(_config.get("kernels.enabled"))
 
 
-def fused_step_enabled(optimizer):
-    """True when ``optimizer`` should update through its fused
-    Pallas epilogue: tier on + the optimizer implements ``step_fused``
-    + its step math is jit-safe + the autotune gate agrees (a
-    default-source tier only fuses where the measured epilogue won;
-    see mx.perf.autotune)."""
-    if not (enabled()
-            and getattr(optimizer, "fused_step", False)
-            and getattr(optimizer, "jit_safe", True)):
-        return False
-    from . import autotune as _autotune
-    pick = _autotune.fused_step_pick(optimizer)
-    return pick is None or pick.get("impl") == "fused"
-
-
-def note_fused_step():
-    """Count one fused optimizer-epilogue launch (trace-time — counts
-    program builds, not steps; the per-step signal is the program key)."""
-    _telemetry.counter("kernels.fused_step").inc()
+def _route_reason(unsupported_reason, fallback):
+    """None when a routed site takes its kernel, else why it takes the
+    XLA lowering (the module docstring's rule).  ``unsupported_reason``
+    is the site's shape check, called only when the knob and the backend
+    allow the kernel; ``fallback`` is the counter of the shapes it
+    refuses."""
+    if not enabled():
+        return "tier off"
+    from .rtc import interpret_mode
+    if _config.source("kernels.enabled") == "default" and interpret_mode():
+        _telemetry.counter("kernels.gated_fallback").inc()
+        return "interpreted"
+    reason = unsupported_reason()
+    if reason is not None:
+        fallback.inc()
+    return reason
 
 
 def flash_unsupported_reason(q, k, v, causal):
@@ -119,36 +105,23 @@ def flash_unsupported_reason(q, k, v, causal):
 
 
 def attention(q, k, v, causal=False, scale=None):
-    """Dot-product attention with kernel routing.
-
-    Tier off → the plain XLA lowering (parallel.ring_attention.attention),
-    traced identically to the pre-kernel-tier program.  Tier on →
-    the fused Pallas flash kernel when the shape qualifies
-    (``kernels.flash_attention`` counter; the tuned ``block_q`` applies
-    when mx.perf.autotune has a winner for this site), the XLA lowering
-    when the shape can't take the kernel (``kernels.fallback``) or when
-    the default-source gate measured the kernel slower / not bit-close
-    (``kernels.gated_fallback``)."""
+    """Dot-product attention with kernel routing (the module docstring's
+    rule): the Pallas flash kernel where the tier is on and the shape
+    qualifies (``kernels.flash_attention``), at the kernel's own default
+    ``block_q=128`` — the one value every benchmark cell has run; else
+    the plain XLA lowering (``parallel.ring_attention.attention``) — tier
+    off, the default knob on an interpreted backend
+    (``kernels.gated_fallback``), or a shape the kernel cannot take
+    (``kernels.fallback``)."""
     from .parallel.ring_attention import attention as _xla_attention
     if enabled():
-        q = jnp.asarray(q)
-        k = jnp.asarray(k)
-        v = jnp.asarray(v)
-        reason = flash_unsupported_reason(q, k, v, causal)
-        if reason is None:
-            from . import autotune as _autotune
-            pick = _autotune.attention_pick(tuple(q.shape), tuple(k.shape),
-                                            str(q.dtype), causal, scale)
-            if pick is None or pick.get("impl") == "flash":
-                _telemetry.counter("kernels.flash_attention").inc()
-                bq = int(pick.get("block_q") or 128) if pick else 128
-                return flash_attention(q, k, v, causal=causal,
-                                       scale=scale, block_q=bq)
-            # the measured gate lost (or the platform statically can't
-            # win): the XLA lowering IS the winner for this site
-            _telemetry.counter("kernels.gated_fallback").inc()
-        else:
-            _telemetry.counter("kernels.fallback").inc()
+        q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
+    reason = _route_reason(
+        lambda: flash_unsupported_reason(q, k, v, causal),
+        _telemetry.counter("kernels.fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.flash_attention").inc()
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     return _xla_attention(q, k, v, causal=causal, scale=scale)
 
 
@@ -373,15 +346,17 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     which reads each row's ``ceil(length / psz)`` pages where they lie;
     counter ``kernels.paged_attention``.  Otherwise → the XLA twin,
     which gathers the whole ``W * psz`` window (under ``mx.kv_gather``)
-    and attends over it (under ``mx.paged_attention``): tier off, a
-    shape the kernel cannot take (``kernels.paged_fallback``), or the
-    default-source knob on an interpreted backend, where a kernel cannot
-    win (``kernels.gated_fallback``).  The decision and its reason land
-    in those counters and, under :func:`record_paged_routes`, in the
-    export route sink."""
+    and attends over it (under ``mx.paged_attention``): tier off, the
+    default knob on an interpreted backend (``kernels.gated_fallback``),
+    or a shape the kernel cannot take (``kernels.paged_fallback``).  The
+    decision and its reason land in those counters and, under
+    :func:`record_paged_routes`, in the export route sink."""
     quant = k_scale is not None
-    reason = _paged_route_reason(q, k_pages, v_pages, page_table, lengths,
-                                 quant, layer)
+    reason = _route_reason(
+        lambda: paged_unsupported_reason(q, k_pages, v_pages, page_table,
+                                         lengths, quantized=quant,
+                                         layer=layer),
+        _telemetry.counter("kernels.paged_fallback"))
     if reason is None:
         _telemetry.counter("kernels.paged_attention").inc()
         _note_paged_route("paged", None, quant)
@@ -393,33 +368,3 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
     return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                 scale=scale, k_scale=k_scale,
                                 v_scale=v_scale, layer=layer)
-
-
-def _paged_route_reason(q, k_pages, v_pages, page_table, lengths, quant,
-                        layer=None):
-    """None when this decode site takes the kernel, else why not."""
-    if not enabled():
-        return "tier off"
-    from .rtc import interpret_mode
-    if _config.source("kernels.enabled") == "default" and interpret_mode():
-        # the graduated default: interpreted, a kernel can never beat
-        # the compiled twin
-        _telemetry.counter("kernels.gated_fallback").inc()
-        return "interpreted"
-    reason = paged_unsupported_reason(q, k_pages, v_pages, page_table,
-                                      lengths, quantized=quant, layer=layer)
-    if reason is not None:
-        _telemetry.counter("kernels.paged_fallback").inc()
-    return reason
-
-
-def measure(key, fn, *args):
-    """Register ``fn(*args)`` with mx.perf under the "kernels" family and
-    run it once: returns ``(outputs, program_record)`` where the record
-    carries cost_analysis FLOPs, phase times and the roofline bound.
-    This is how bench/opperf secondaries report achieved FLOPs per op."""
-    from . import perf as _perf
-    wrapped = _perf.wrap(jax.jit(fn), "kernels", key)
-    out = wrapped(*args)
-    jax.block_until_ready(out)
-    return out, _perf.program("kernels", key)

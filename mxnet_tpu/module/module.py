@@ -38,12 +38,8 @@ class Module(BaseModule):
     launches the fused program; lr/wd are evaluated eagerly each step and
     fed as device arrays, so lr schedulers keep working instead of
     constant-folding into the compiled step.  The fused program bakes in
-    the kernel-tier routing AND any mx.perf.autotune winners at trace
-    time (Executor.fused_step_fn keys on the config epoch and the
-    autotune generation, so a knob flip or a freshly recorded tuning
-    winner retraces exactly once); with ``kernels.enabled`` at its
-    round-16 default the fused Pallas optimizer epilogue only engages
-    where the measured gate won (see docs/PERF_NOTES.md "Autotune").
+    the kernel-tier routing at trace time (Executor.fused_step_fn keys on
+    the config epoch, so a knob flip retraces exactly once).
 
     The stage-at-a-time eager path (forward, backward, then a per-parameter
     updater loop outside jit — the reference's per-batch structure) remains

@@ -47,8 +47,7 @@ all.  This module is the fused-era replacement:
 Overhead contract: with capture off the tap sites cost literally zero
 (the plain variant never calls into this module inside the trace); at
 ``step:N`` cadence the instrumented variant runs every Nth step only,
-so the amortized overhead is the instrumented-step delta / N —
-``bench.py numerics_overhead`` measures it ≤ 2% at ``step:10``.
+so the amortized overhead is the instrumented-step delta / N.
 
 Schema, forensics record layout and the drift math live in
 docs/OBSERVABILITY.md ("Numerics plane").

@@ -38,8 +38,8 @@ Four pieces, each off by default and independently togglable:
 
   * the windowed ``p50_1m``/``p99_1m`` quantiles themselves live in
     ``telemetry.Timer`` — the only cost this plane adds while both knobs
-    are off (one timestamp compare per observation; bench.py
-    ``obs_overhead`` proves the ≤2% bound with everything ON).
+    are off (one timestamp compare per observation;
+    tools/check_obs.py holds the ≤2% bound with everything ON).
 
 Access-record schema::
 

@@ -14,12 +14,9 @@ kernels without a TPU.
 Kernel tier (docs/PERF_NOTES.md "Kernel tier"): flash attention is a
 full training kernel — the tiled online-softmax forward saves per-row
 logsumexp residuals and a Pallas backward (recompute-style, two kernels:
-dq over q blocks, dk/dv over kv blocks) rides ``jax.custom_vjp``.  The
-fused optimizer epilogues (``fused_sgd_step``/``fused_adam_step``) fold
-the whole elementwise update chain plus the low-precision cast into ONE
-kernel so bf16 params never round-trip through a separate f32 master
-copy program.  Routing and fallback live in ``mx.kernels``; the raw
-kernels here stay policy-free.
+dq over q blocks, dk/dv over kv blocks) rides ``jax.custom_vjp``.
+Routing and fallback live in ``mx.kernels``; the raw kernels here stay
+policy-free.
 """
 from __future__ import annotations
 
@@ -33,8 +30,7 @@ from .registry import register
 
 __all__ = ["pallas_row_softmax", "pallas_scale_bias_relu",
            "pallas_flash_attention", "flash_attention",
-           "pallas_paged_attention",
-           "fused_sgd_step", "fused_adam_step"]
+           "pallas_paged_attention"]
 
 _NEG = -1e30
 
@@ -676,137 +672,6 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
         interpret=interpret_mode(), name="mx_paged_attention")(
             jnp.asarray(lengths, jnp.int32), ids, *operands)
     return out.reshape(B, H, 1, D)
-
-
-# ------------------------------------------- fused optimizer+cast epilogue
-def _sgd_epilogue_kernel(momentum, w_ref, g_ref, mom_ref, lr_ref, wd_ref,
-                         lp_ref, w_out_ref, mom_out_ref):
-    """SGD+momentum update and low-precision cast in one VMEM pass: the
-    f32 master row block is read once, the new master, momentum and cast
-    weight are written — no intermediate HBM arrays between the stages."""
-    w = w_ref[:]
-    g = g_ref[:] + wd_ref[0, 0] * w
-    mom = momentum * mom_ref[:] + lr_ref[0, 0] * g
-    nw = w - mom
-    w_out_ref[:] = nw
-    mom_out_ref[:] = mom
-    lp_ref[:] = nw.astype(lp_ref.dtype)
-
-
-def _sgd_nomom_epilogue_kernel(w_ref, g_ref, lr_ref, wd_ref, lp_ref,
-                               w_out_ref):
-    w = w_ref[:]
-    g = g_ref[:] + wd_ref[0, 0] * w
-    nw = w - lr_ref[0, 0] * g
-    w_out_ref[:] = nw
-    lp_ref[:] = nw.astype(lp_ref.dtype)
-
-
-def _adam_epilogue_kernel(beta1, beta2, eps, w_ref, g_ref, m_ref, v_ref,
-                          lr_t_ref, wd_ref, lp_ref, w_out_ref, m_out_ref,
-                          v_out_ref):
-    """Adam update + cast in one VMEM pass; the bias-corrected lr_t is
-    precomputed outside (it depends on the traced step count, not the
-    row block) and rides in as a (1,1) scalar block."""
-    w = w_ref[:]
-    g = g_ref[:] + wd_ref[0, 0] * w
-    m = beta1 * m_ref[:] + (1.0 - beta1) * g
-    v = beta2 * v_ref[:] + (1.0 - beta2) * g * g
-    nw = w - lr_t_ref[0, 0] * m / (jnp.sqrt(v) + eps)
-    w_out_ref[:] = nw
-    m_out_ref[:] = m
-    v_out_ref[:] = v
-    lp_ref[:] = nw.astype(lp_ref.dtype)
-
-
-def _flat2d(a):
-    """The 2-D view an elementwise kernel streams.  The op is pointwise,
-    so any reshape computes the same bits: take a lane-dense view
-    whenever the size allows.  Keeping the array's own minor axis would
-    tile a conv weight's 3-wide (or a 1x1 conv's 1-wide) rows into
-    128-lane tiles — up to 128x the bytes in HBM and in VMEM."""
-    for lanes in (8 * _LANES, 4 * _LANES, 2 * _LANES, _LANES):
-        if a.size and a.size % lanes == 0:
-            return a.reshape(-1, lanes)
-    if a.ndim >= 2:
-        return a.reshape(-1, a.shape[-1])
-    return a.reshape(1, -1)
-
-
-def _epilogue_call(kernel, arrays, scalars, out_dtypes, block_rows=None):
-    """Launch an elementwise epilogue kernel over same-shape operands:
-    arrays flatten to 2-D and stream through shared row blocks; scalars
-    ride as (1,1) blocks pinned to every grid step.  ``block_rows``
-    overrides the VMEM-budget row-block derivation (mx.perf.autotune
-    passes measured winners through); it still snaps to the largest
-    divisor of n that fits, so an awkward tuned value can never break
-    the exact-tiling requirement."""
-    from jax.experimental import pallas as pl
-    from ..rtc import interpret_mode
-    shape = arrays[0].shape
-    flats = [_flat2d(a) for a in arrays]
-    n, d = flats[0].shape
-    itemsize = max(f.dtype.itemsize for f in flats)
-    row_bytes = _lane_pad(d) * itemsize * (len(arrays) + len(out_dtypes))
-    if block_rows is None:
-        rows = _row_block(n, row_bytes)
-    else:
-        rows = _row_block(n, 1, budget=min(int(block_rows), n))
-    scal = [jnp.asarray(s, jnp.float32).reshape(1, 1) for s in scalars]
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=[jax.ShapeDtypeStruct((n, d), dt) for dt in out_dtypes],
-        grid=(n // rows,),
-        in_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0))
-                  for _ in flats] +
-                 [pl.BlockSpec((1, 1), lambda i: (0, 0)) for _ in scal],
-        out_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0))
-                   for _ in out_dtypes],
-        interpret=interpret_mode(), name="mx_opt_update")(*(flats + scal))
-    return [o.reshape(shape) for o in outs]
-
-
-def fused_sgd_step(weight, grad, state, lr, wd, momentum, out_dtype=None,
-                   block_rows=None):
-    """Single-kernel SGD(+momentum) update with cast epilogue.
-
-    ``weight`` is the f32 master; returns
-    ``(weight_cast[out_dtype], new_master, new_state)`` — identical math
-    and op order to ``SGD.step`` followed by ``astype``, so the result is
-    bitwise-equal to the master-copy round trip it replaces.
-    ``block_rows`` is the tunable row-block size (None = derive from the
-    VMEM budget); the math is row-wise, so any block size computes the
-    same bits."""
-    weight = jnp.asarray(weight)
-    grad = jnp.asarray(grad)
-    out_dtype = jnp.dtype(out_dtype) if out_dtype is not None \
-        else weight.dtype
-    if momentum == 0.0:
-        lp, nw = _epilogue_call(
-            _sgd_nomom_epilogue_kernel, [weight, grad], [lr, wd],
-            [out_dtype, weight.dtype], block_rows=block_rows)
-        return lp, nw, None
-    lp, nw, mom = _epilogue_call(
-        functools.partial(_sgd_epilogue_kernel, momentum),
-        [weight, grad, state], [lr, wd],
-        [out_dtype, weight.dtype, state.dtype], block_rows=block_rows)
-    return lp, nw, mom
-
-
-def fused_adam_step(weight, grad, m, v, lr_t, wd, beta1, beta2, eps,
-                    out_dtype=None, block_rows=None):
-    """Single-kernel Adam update with cast epilogue (see
-    ``fused_sgd_step``); ``lr_t`` is the bias-corrected learning rate the
-    caller computes from the traced step count."""
-    weight = jnp.asarray(weight)
-    grad = jnp.asarray(grad)
-    out_dtype = jnp.dtype(out_dtype) if out_dtype is not None \
-        else weight.dtype
-    lp, nw, nm, nv = _epilogue_call(
-        functools.partial(_adam_epilogue_kernel, beta1, beta2, eps),
-        [weight, grad, m, v], [lr_t, wd],
-        [out_dtype, weight.dtype, m.dtype, v.dtype], block_rows=block_rows)
-    return lp, nw, (nm, nv)
 
 
 # ------------------------------------------------------- fused elementwise

@@ -184,19 +184,6 @@ class Optimizer:
         """Pure update: jax arrays in, (new_weight, new_state) out."""
         raise NotImplementedError
 
-    # fused Pallas update+cast epilogue (mx.kernels); subclasses that
-    # implement step_fused flip this flag — routing honors it only when
-    # kernels.enabled is on (kernels.fused_step_enabled)
-    fused_step = False
-
-    def step_fused(self, weight, grad, state, lr, wd, t, out_dtype=None):
-        """Single-kernel update + low-precision cast:
-        ``(weight_cast[out_dtype], new_master_f32, new_state)`` —
-        bitwise-equal to ``step`` followed by ``astype`` when both run
-        inside the same jitted program."""
-        raise NotImplementedError(
-            "%s has no fused step kernel" % type(self).__name__)
-
     def _preprocess_grad(self, grad):
         g = grad * self.rescale_grad
         return _clip(g, self.clip_gradient)
@@ -245,23 +232,11 @@ class Optimizer:
             wd = self._get_wd(index)
             t = self._index_update_count[index]
             g = self._preprocess_grad(jnp.asarray(grad._data, jnp.float32))
-            from .. import kernels as _kernels
-            if _kernels.fused_step_enabled(self):
-                # one fused kernel: update the f32 master AND emit the
-                # low-precision weight — no separate astype program
-                lp, new_w, new_state = self.step_fused(
-                    master._data, g, _state_data(real_state), lr, wd, t,
-                    out_dtype=weight._data.dtype)
-                _kernels.note_fused_step()
-                master._set_data(new_w)
-                weight._set_data(lp)
-            else:
-                new_w, new_state = self.step(master._data, g,
-                                             _state_data(real_state),
-                                             lr, wd, t)
-                master._set_data(new_w)
-                weight._set_data(jnp.asarray(new_w,
-                                             dtype=weight._data.dtype))
+            new_w, new_state = self.step(master._data, g,
+                                         _state_data(real_state),
+                                         lr, wd, t)
+            master._set_data(new_w)
+            weight._set_data(jnp.asarray(new_w, dtype=weight._data.dtype))
             _state_write(real_state, new_state)
         else:
             self.update(index, weight, grad, state)
@@ -336,13 +311,6 @@ class SGD(Optimizer):
             return weight - lr * g, None
         mom = self.momentum * state + lr * g
         return weight - mom, mom
-
-    fused_step = True
-
-    def step_fused(self, weight, grad, state, lr, wd, t, out_dtype=None):
-        from ..ops.pallas_kernels import fused_sgd_step
-        return fused_sgd_step(weight, grad, state, lr, wd,
-                              self.momentum, out_dtype=out_dtype)
 
     def step_rows(self, weight, rows, grad_rows, state, lr, wd, t):
         """Lazy row_sparse step: touch ONLY `rows` (reference
@@ -606,20 +574,6 @@ class Adam(Optimizer):
         v = self.beta2 * v + (1.0 - self.beta2) * g * g
         w = weight - lr_t * m / (jnp.sqrt(v) + self.epsilon)
         return w, (m, v)
-
-    fused_step = True
-
-    def step_fused(self, weight, grad, state, lr, wd, t, out_dtype=None):
-        from ..ops.pallas_kernels import fused_adam_step
-        m, v = state
-        # bias correction depends on the (possibly traced) step count, so
-        # it stays outside the kernel — exact same expressions as step()
-        coef1 = 1.0 - self.beta1 ** t
-        coef2 = 1.0 - self.beta2 ** t
-        lr_t = lr * jnp.sqrt(coef2) / coef1
-        return fused_adam_step(weight, grad, m, v, lr_t, wd, self.beta1,
-                               self.beta2, self.epsilon,
-                               out_dtype=out_dtype)
 
     def step_rows(self, weight, rows, grad_rows, state, lr, wd, t):
         """Lazy row_sparse Adam: moments and weights update ONLY on `rows`

@@ -380,10 +380,6 @@ class SPMDTrainer:
             return loss, (new_aux, out)
 
         guard = self._guard_mode
-        from .. import kernels as _kernels
-        fused_opt = _kernels.fused_step_enabled(optimizer)
-        if fused_opt:
-            _kernels.note_fused_step()
 
         # compressed DCN gradient sync (docs/RESILIENCE.md "Multi-host
         # elasticity"): grads crossing the 'dcn' mesh axis ride as packed
@@ -485,17 +481,6 @@ class SPMDTrainer:
                     g = _preprocess(optimizer, grads[n])
                     if stats is not None:
                         _numerics.record(stats, "grad." + n, g)
-                    if fused_opt and \
-                            train_params[n].dtype == jnp.float32:
-                        # fused Pallas epilogue: update + cast in one
-                        # kernel (bitwise-equal to the step/astype pair)
-                        w, _m, s = optimizer.step_fused(
-                            train_params[n], g, opt_state[n],
-                            lrs[i] * lr_scale, wds[i], t,
-                            out_dtype=train_params[n].dtype)
-                        new_params[n] = w
-                        new_state[n] = s
-                        continue
                     w, s = optimizer.step(train_params[n], g,
                                           opt_state[n], lrs[i] * lr_scale,
                                           wds[i], t)
@@ -649,10 +634,6 @@ class SPMDTrainer:
             return loss, (new_aux, out, ctx.records)
 
         guard = self._guard_mode
-        from .. import kernels as _kernels
-        fused_opt = _kernels.fused_step_enabled(optimizer)
-        if fused_opt:
-            _kernels.note_fused_step()
 
         def step(train_params, aux_params, opt_state, emb_tables, data,
                  label, key, t, lrs, wds, lr_scale, streak=None):
@@ -700,15 +681,6 @@ class SPMDTrainer:
                     g = _preprocess(optimizer, grads[n])
                     if stats is not None:
                         _numerics.record(stats, "grad." + n, g)
-                    if fused_opt and \
-                            train_params[n].dtype == jnp.float32:
-                        w, _m, s = optimizer.step_fused(
-                            train_params[n], g, opt_state[n],
-                            lrs[i] * lr_scale, wds[i], t,
-                            out_dtype=train_params[n].dtype)
-                        new_params[n] = w
-                        new_state[n] = s
-                        continue
                     w, s = optimizer.step(train_params[n], g,
                                           opt_state[n], lrs[i] * lr_scale,
                                           wds[i], t)
@@ -758,14 +730,10 @@ class SPMDTrainer:
         from .. import perf as _perf
         # kernels=on earns its own program key; the OFF key is
         # unchanged from earlier rounds so perf artifacts stay
-        # comparable across releases.  A program built after an
-        # autotune winner landed gets its own key too, so the tuned
-        # and untuned registrations coexist in perf exports.
+        # comparable across releases
         pkey = "pad=%d/guard=%s" % (pad, self._guard_mode)
         if self._kernel_mode:
             pkey += "/kernels=on"
-        if getattr(self, "_autotune_gen", 0):
-            pkey += "/at%d" % self._autotune_gen
         if instrument:
             pkey += "/numerics"
         elif self._dcn_compress_active(pad):
@@ -862,23 +830,16 @@ class SPMDTrainer:
         # the traced step bodies bake in config-derived constants beyond
         # the guard/kernels knobs (the sparse path sizes its dedup
         # buffers from embedding.unique_size), so any config mutation —
-        # tracked by the epoch counter — invalidates the program cache;
-        # likewise a fresh mx.perf.autotune winner (generation counter)
-        # must retrace so the tuned pick bakes in
-        from .. import autotune as _autotune
+        # tracked by the epoch counter — invalidates the program cache
         epoch = _config.epoch()
-        agen = _autotune.generation()
         if self._jitted and (guard != self._guard_mode or
                              kmode != getattr(self, "_kernel_mode", kmode)
                              or epoch != getattr(self, "_config_epoch",
-                                                 epoch)
-                             or agen != getattr(self, "_autotune_gen",
-                                                agen)):
+                                                 epoch)):
             self._jitted.clear()  # knob flip: rebuild with/without the guard
         self._guard_mode = guard
         self._kernel_mode = kmode
         self._config_epoch = epoch
-        self._autotune_gen = agen
         # numerics cadence (mx.numerics): on a capture step the program
         # cache serves the instrumented VARIANT — its own (pad, token)
         # entry, so off-cadence steps replay the plain program unchanged

@@ -64,7 +64,7 @@ __all__ = [
     "OP_CLASSES", "classify_op", "hlo_op_classes", "device_kind",
     "peak_flops", "peak_bandwidth", "roofline", "register_compiled",
     "programs", "program", "reset", "export", "wrap", "PerfProgram",
-    "configure_profile", "cost_analysis", "autotune", "hlo_op_names",
+    "configure_profile", "cost_analysis", "hlo_op_names",
     "op_names",
 ]
 
@@ -73,8 +73,8 @@ __all__ = [
 # as (bf16 MXU TFLOP/s, HBM GB/s).  Source: Google Cloud TPU
 # documentation, the per-generation system-architecture pages ("TPU v5e":
 # 197 TFLOP/s bf16, 819 GB/s; "TPU v5p": 459, 2765; "TPU v4": 275, 1228;
-# "TPU v6e": 918, 1640).  bench.py, chip_smoke.py and the tools read this
-# table and keep no copy.  A device that is not here has no peak: every
+# "TPU v6e": 918, 1640).  chip_smoke.py and the tools read this table
+# and keep no copy.  A device that is not here has no peak: every
 # path that would print an MFU or a roofline share for it raises
 # :class:`UnknownDeviceError` instead of assuming some other chip's
 # numbers, and cost records for it carry ``None`` in their place.
@@ -473,7 +473,6 @@ def export(path=None):
         "device_kind": device_kind(),
         "device_peaks": DEVICE_PEAKS.get(device_kind()),
         "programs": programs(),
-        "autotune": autotune.export_entries(),
     }
     if path:
         with open(path, "w") as f:
@@ -714,11 +713,6 @@ def _load_trace_merge():
 # on telemetry rather than an import so telemetry stays dependency-free.
 from . import config as _config  # noqa: E402
 from . import telemetry as _telemetry_mod  # noqa: E402
-
-# mx.perf.autotune — the measured config search rides on this module's
-# namespace (it measures through the same jit machinery PerfProgram
-# captures); autotune imports perf lazily, so the cycle is benign.
-from . import autotune  # noqa: E402,F401
 
 _telemetry_mod._PERF_STEP_HOOK = _on_step
 

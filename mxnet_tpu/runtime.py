@@ -18,16 +18,16 @@ from __future__ import annotations
 from collections import namedtuple
 
 __all__ = ["Feature", "Features", "feature_list", "is_enabled",
-           "scan_stack", "stack_tuning", "stack_candidates",
-           "checkpoint_policy", "cache_root", "configure_compile_cache"]
+           "scan_stack", "checkpoint_policy", "cache_root",
+           "configure_compile_cache"]
 
 Feature = namedtuple("Feature", ["name", "enabled"])
 
 
 def cache_root():
     """The checkout's one cache directory (git-ignored): XLA's persistent
-    compile cache and the autotune verdicts live here, so nothing outside
-    the tree decides what a run compiles or routes."""
+    compile cache lives here, so nothing outside the tree decides what a
+    run compiles."""
     import os
     return os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), ".jax_cache")
@@ -42,9 +42,8 @@ def configure_compile_cache():
     the caller's choice — jax reads it by itself and no directory is set
     here; where it is not, the cache goes to :func:`cache_root`, a fixed
     path, because the path is part of the cache key and a directory that
-    moves never hits.  Idempotent; entry points (chip_smoke.py, bench.py,
-    the tools, ``Server.start()``) call it before their first big
-    compile.
+    moves never hits.  Idempotent; entry points (chip_smoke.py, the
+    tools, ``Server.start()``) call it before their first big compile.
 
     The key covers the program's metadata too (jax leaves it out by
     default): a cached executable carries the operation names it was
@@ -140,29 +139,6 @@ def is_enabled(feature_name):
 
 
 # --------------------------------------------------------- program tuning
-def stack_candidates():
-    """The discrete (mode, remat) grid mx.perf.autotune measures over:
-    every legal combination of the two validated knobs.  'unroll' pairs
-    with remat-off only — rematerializing an inlined stack re-traces
-    every layer body, which the scan path exists to avoid."""
-    return (("scan", ""), ("scan", "dots"), ("scan", "full"),
-            ("unroll", ""))
-
-
-def stack_tuning():
-    """The active (mode, remat) pair: the validated knobs
-    ``runtime.stack_mode`` (scan|unroll) and ``runtime.remat``
-    (''|dots|full) — or, while BOTH knobs sit at their defaults, a
-    persisted mx.perf.autotune winner for the layer stack (measured by
-    ``autotune.search_stack``; an explicit knob always wins)."""
-    from . import autotune as _autotune
-    from . import config as _config
-    tuned = _autotune.stack_pick()
-    if tuned is not None:
-        return tuned
-    return _config.get("runtime.stack_mode"), _config.get("runtime.remat")
-
-
 def checkpoint_policy(name):
     """Resolve a remat policy name to a ``jax.checkpoint`` policy:
     '' -> None (no remat), 'dots' -> save matmul results and recompute
@@ -209,7 +185,9 @@ def scan_stack(body, carry, xs):
 def _scan_stack(body, carry, xs):
     import jax
     from jax import lax
-    mode, remat = stack_tuning()
+    from . import config as _config
+    mode = _config.get("runtime.stack_mode")
+    remat = _config.get("runtime.remat")
     if remat:
         policy = checkpoint_policy(remat)
         if policy == "full":

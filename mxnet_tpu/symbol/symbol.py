@@ -961,12 +961,10 @@ class Executor:
                 for n in self._symbol.list_arguments()]
 
     def _fwd_fn(self, training):
-        from .. import autotune as _autotune
         from .. import config as _config
-        # knob values AND mx.perf.autotune picks bake in at trace: the
-        # epoch tracks config mutations, the generation tracks freshly
-        # recorded tuning winners — either moving retraces
-        cache_key = (training, (_config.epoch(), _autotune.generation()))
+        # knob values bake in at trace: a config mutation (the epoch)
+        # retraces
+        cache_key = (training, _config.epoch())
         if cache_key not in self._fwd_cache:
             # evict programs compiled under superseded knob epochs
             self._fwd_cache = {k: v for k, v in self._fwd_cache.items()
@@ -1020,10 +1018,9 @@ class Executor:
         forward + backward fuse into a single XLA executable (replacing the
         reference's separate backward graph executor,
         src/executor/graph_executor.cc:91)."""
-        from .. import autotune as _autotune
         from .. import config as _config
-        # knobs + autotune picks bake in at trace (see _fwd_fn)
-        key_sig = (tuple(wrt), (_config.epoch(), _autotune.generation()))
+        # knobs bake in at trace (see _fwd_fn)
+        key_sig = (tuple(wrt), _config.epoch())
         if key_sig not in self._bwd_cache:
             # evict programs compiled under superseded knob epochs (same
             # invalidation contract as _fwd_fn: a config.set between calls
@@ -1095,10 +1092,9 @@ class Executor:
         # the program closes over the optimizer, so its identity (and the
         # scalars baked in at trace time) is part of the key; cached entries
         # keep their optimizer alive, so id() stays unambiguous
-        from .. import autotune as _autotune
         key_sig = (id(optimizer), rescale, clip, wrt_t, feed_sig, guard) \
             + _numerics.capture_token(instrument) \
-            + ((_config.epoch(), _autotune.generation()),)
+            + (_config.epoch(),)
         fn = self._fused_cache.get(key_sig)
         if fn is not None:
             return fn
@@ -1106,13 +1102,6 @@ class Executor:
         # invalidation contract as _fwd_cache/_bwd_cache)
         self._fused_cache = {k: v for k, v in self._fused_cache.items()
                              if k[-1] == key_sig[-1]}
-        # fused Pallas optimizer epilogue (mx.kernels): trace-time
-        # decision; a kernels-knob flip bumps the config epoch, so the
-        # key above already forces the retrace
-        from .. import kernels as _kernels
-        fused_opt = _kernels.fused_step_enabled(optimizer)
-        if fused_opt:
-            _kernels.note_fused_step()
 
         def run(wrt_vals, opt_state, rest_env, feeds, key, t, lrs, wds,
                 streak=None):
@@ -1150,13 +1139,6 @@ class Executor:
                         g = jnp.clip(g, -clip, clip)
                     if stats is not None:
                         _numerics.record(stats, "grad." + n, g)
-                    if fused_opt and wrt_vals[n].dtype == jnp.float32:
-                        w, _m, s = optimizer.step_fused(
-                            wrt_vals[n], g, opt_state[n], lrs[i], wds[i],
-                            t, out_dtype=wrt_vals[n].dtype)
-                        new_w[n] = w
-                        new_s[n] = s
-                        continue
                     w, s = optimizer.step(wrt_vals[n], g, opt_state[n],
                                           lrs[i], wds[i], t)
                     new_w[n] = w.astype(wrt_vals[n].dtype)

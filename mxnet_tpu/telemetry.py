@@ -15,7 +15,7 @@ This module is that one place for the TPU port:
     hot-path seams (Module/SPMDTrainer/gluon.Trainer steps, Executor eager
     replays, io batch fetch, kvstore push/pull) feed it unconditionally —
     one perf_counter pair and one lock per observation, noise-level next to
-    a train step (bench.py records the measured overhead).
+    a train step.
   * a STRUCTURED STEP LOG — one JSONL record per train step (schema below),
     enabled by ``MXNET_TPU_TELEMETRY=jsonl:<path>`` (the ``telemetry.sink``
     knob in config.py).  When the sink is off, ``step_scope`` skips record

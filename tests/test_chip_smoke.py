@@ -89,7 +89,7 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert set(serve["paged_routes"].values()) == {"paged"}
     assert serve["worst_logit_gap"] <= serve["logit_gap_tolerance"]
     assert by["kernels"]["interpreted"] is True
-    assert {"flash_bwd", "paged_int8", "fused_adam_step"} <= {
+    assert {"flash_bwd", "paged_int8", "pallas_row_softmax"} <= {
         name.split("/")[0] for name in by["kernels"]["kernels"]}
     assert lines[-1]["rehearsed"] == ["phase_train", "phase_serve",
                                       "phase_serve_hybrid",

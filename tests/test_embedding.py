@@ -465,4 +465,3 @@ def test_check_embedding_smoke():
     assert report["sharded"]["bitwise"] and report["trainer"]["bitwise"]
     assert report["compiles"]["flat"]
     assert 0.0 < report["dedup"]["unique_ratio"] < 1.0
-    assert report["elapsed_s"] < (5.0 if (os.cpu_count() or 1) >= 2 else 10.0), report

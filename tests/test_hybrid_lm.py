@@ -247,7 +247,6 @@ def served(tmp_path):
     registered with a started server (kernel tier on: the paged kernel
     runs in the interpreter)."""
     mx.config.set("kernels.enabled", True)
-    mx.config.set("perf.autotune", "off")
     mx.config.set("serving.kv_pages", 64)
     mx.config.set("serving.decode_slots", 4)
     model, params = _tiny(pattern="MEM*EM*E")
@@ -264,7 +263,7 @@ def served(tmp_path):
         yield model, params, prefix, srv, engine, refused
     finally:
         srv.stop()
-        for knob in ("kernels.enabled", "perf.autotune", "serving.kv_pages",
+        for knob in ("kernels.enabled", "serving.kv_pages",
                      "serving.decode_slots"):
             mx.config.unset(knob)
 
@@ -522,7 +521,6 @@ def test_hybrid_programs_are_the_parents(program, lowered_for, monkeypatch):
     import hashlib
     from mxnet_tpu import rtc
     mx.config.set("kernels.enabled", True)
-    mx.config.set("perf.autotune", "off")
     if lowered_for == "chip":
         monkeypatch.setattr(rtc, "interpret_mode", lambda: False)
     platforms = {"chip": ("tpu",), "interpreter": None}[lowered_for]
@@ -543,7 +541,6 @@ def test_hybrid_programs_are_the_parents(program, lowered_for, monkeypatch):
         text = traced.lower(lowering_platforms=platforms).as_text()
     finally:
         mx.config.unset("kernels.enabled")
-        mx.config.unset("perf.autotune")
     if program == "decode":
         assert ("tpu_custom_call" in text) == (lowered_for == "chip")
     text = _without_kernel_locations(text)

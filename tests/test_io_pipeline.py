@@ -555,4 +555,3 @@ def test_check_io_pipeline_smoke():
     assert report["overlap"]["sync_h2d_on"] == 0
     assert report["drain"]["leaked"] == 0
     assert report["decode"]["retries"] == 2
-    assert report["elapsed_s"] < (5.0 if (os.cpu_count() or 1) >= 2 else 10.0), report

@@ -85,7 +85,7 @@ def test_capture_knob_rejected_value_reverts():
 
 def test_capture_knob_is_epoch_neutral():
     """Toggling capture must NOT bump the config epoch — epoch-keyed
-    program caches (fused step, embedding, autotune) would otherwise be
+    program caches (fused step, embedding) would otherwise be
     evicted by an observability toggle."""
     e0 = config.epoch()
     config.set("numerics.capture", "step:4")

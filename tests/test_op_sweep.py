@@ -9,8 +9,6 @@ Coverage is ENFORCED: an op registered without a sweep spec (and not in the
 reasoned exemption table) fails test_every_op_has_spec — nothing is skipped
 silently.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -999,32 +997,3 @@ def test_op_forward_and_grad(name):
             r = apply_op(op, *nds, **s["attrs"])
             return r[0] if isinstance(r, (list, tuple)) else r
         check_numeric_gradient(f, arrays)
-
-
-def test_bench_phase_error_fails_the_run(monkeypatch):
-    """bench.py has no watchdog and no record-and-carry-on: a phase that
-    raises fails the run, and no result line is printed.  Without the
-    chip it fails before the first phase unless the cpu is asked for."""
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    monkeypatch.syspath_prepend(root)
-    import bench
-    import mxnet_tpu.config as cfg
-    assert "bench.timeout_s" not in cfg.knobs()
-    ran = []
-
-    def boom(runs_out, arg):
-        ran.append(arg)
-        raise FloatingPointError("phase blew up")
-
-    monkeypatch.setattr(bench, "_lint_preflight", lambda: None)
-    monkeypatch.setattr(bench, "PHASES", ((boom, ("chip",), ("cpu",)),
-                                          (boom, ("never",), ("never",))))
-    monkeypatch.delenv("MXTPU_BENCH_CPU", raising=False)
-    with pytest.raises(RuntimeError, match="needs an accelerator"):
-        bench.main()
-    assert ran == []
-    monkeypatch.setenv("MXTPU_BENCH_CPU", "1")
-    with pytest.raises(FloatingPointError, match="phase blew up"):
-        bench.main()
-    assert ran == ["cpu"]

@@ -2,7 +2,7 @@
 
 Covers the registry record schema (cost_analysis / memory_analysis /
 phase breakdown / HLO op-class table), the roofline classifier and peak
-tables (incl. the bench.py sync contract), the PerfProgram wrapper's
+tables, the PerfProgram wrapper's
 bitwise no-op + fallback semantics, step-record flops/mfu schema, the
 MXNET_TPU_PROFILE knob validation, and the perf_report / check_perf
 tool wiring.
@@ -167,19 +167,6 @@ def test_unknown_device_records_counts_but_no_peak_numbers():
     assert fields == {"flops": pytest.approx(
         perf.program("module", "nopeak2")["flops"])}
     assert "perf.mfu" not in telemetry.snapshot()["gauges"]
-
-
-def test_bench_keeps_no_second_peak_table():
-    """bench.py reads mx.perf.DEVICE_PEAKS; a private copy (the old
-    PEAK_BF16_TFLOPS / DEFAULT_PEAK pair) could drift from it, and a
-    default peak would put a v5e number on an unknown chip."""
-    sys.path.insert(0, ROOT)
-    import bench
-    for name in ("PEAK_BF16_TFLOPS", "DEFAULT_PEAK", "PEAK_HBM_GBPS"):
-        assert not hasattr(bench, name), name
-    text = open(os.path.join(ROOT, "bench.py")).read()
-    assert "197" not in text and "DEVICE_PEAKS = " not in text
-    assert "peak_flops(" in text
 
 
 # ------------------------------------------------------------ op classes

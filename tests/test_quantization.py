@@ -365,4 +365,3 @@ def test_check_quantization_smoke():
     assert report["int8"]["hlo_has_i8"]
     assert report["serving"]["compiled"] == \
         len(report["serving"]["buckets"])
-    assert report["elapsed_s"] < (5.0 if (os.cpu_count() or 1) >= 2 else 10.0), report

@@ -325,4 +325,3 @@ def test_check_serving_smoke():
     assert report["compiles"]["compiled"] == \
         len(report["compiles"]["buckets"])
     assert report["drain"]["drained"] == report["drain"]["queued"]
-    assert report["elapsed_s"] < (5.0 if (os.cpu_count() or 1) >= 2 else 10.0), report

@@ -391,4 +391,3 @@ def test_check_serving_chaos_smoke():
     assert report["crash"]["restarted"]
     assert report["overload"] == {"shed": 3, "deadline_exceeded": 1}
     assert report["futures"]["hung"] == 0
-    assert report["elapsed_s"] < (5.0 if (os.cpu_count() or 1) >= 2 else 10.0), report

@@ -120,6 +120,55 @@ def test_config_registry():
         config.set("not.a.knob", 1)
 
 
+def test_config_source_tracks_override_env_default(monkeypatch):
+    from mxnet_tpu import config
+    monkeypatch.delenv("MXNET_TPU_REMAT", raising=False)
+    config.unset("runtime.remat")
+    assert config.source("runtime.remat") == "default"
+    monkeypatch.setenv("MXNET_TPU_REMAT", "dots")
+    assert config.source("runtime.remat") == "env"
+    assert config.get("runtime.remat") == "dots"
+    config.set("runtime.remat", "full")
+    assert config.source("runtime.remat") == "override"
+    config.unset("runtime.remat")
+    assert config.source("runtime.remat") == "env"
+
+
+def test_config_unset_restores_default_and_bumps_epoch():
+    import pytest
+    from mxnet_tpu import config
+    config.unset("runtime.stack_mode")
+    e0 = config.epoch()
+    config.unset("runtime.stack_mode")      # no override: no-op
+    assert config.epoch() == e0
+    config.set("runtime.stack_mode", "unroll")
+    config.unset("runtime.stack_mode")
+    assert config.get("runtime.stack_mode") == "scan"
+    assert config.source("runtime.stack_mode") == "default"
+    assert config.epoch() > e0
+    with pytest.raises(KeyError):
+        config.unset("no.such.knob")
+
+
+def test_config_epoch_follows_the_source():
+    """Making a default an explicit choice moves the epoch even when the
+    value stays (routing reads the source: kernels._route_reason), and so
+    does dropping the override; the same override again does not."""
+    from mxnet_tpu import config
+    config.unset("kernels.enabled")
+    default = config.get("kernels.enabled")
+    e0 = config.epoch()
+    config.set("kernels.enabled", default)
+    assert config.source("kernels.enabled") == "override"
+    e1 = config.epoch()
+    assert e1 > e0
+    config.set("kernels.enabled", default)
+    assert config.epoch() == e1
+    config.unset("kernels.enabled")
+    assert config.get("kernels.enabled") == default
+    assert config.epoch() > e1
+
+
 def test_subgraph_stacked_matches():
     """relu(a + relu(b + c)) — stacked matches must form ONE well-formed
     group whose externals are exactly the outside inputs (regression: the

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu import autotune, config, kernels, rtc
+from mxnet_tpu import config, kernels, rtc
 from mxnet_tpu.models.transformer import TransformerLM, TransformerLMConfig
 from mxnet_tpu.ops import pallas_kernels as pk
 
@@ -106,16 +106,18 @@ def test_flash_backward_compiles(compile_for_chip, shape):
 
 
 @pytest.mark.parametrize("S", [1024, 2048])
-def test_every_flash_candidate_of_the_search_compiles(compile_for_chip, S):
-    """A candidate that fails to compile is an error out of the autotune
-    search, so the search may only propose blocks the chip can hold."""
+def test_flash_compiles_at_the_block_the_router_passes(compile_for_chip, S):
+    """``kernels.attention`` hands the flash kernel one constant query
+    block: the chip has to hold it at the widths the serving cells
+    prefill at."""
     q = compile_for_chip.spec((1, 12, S, 64), BF16)
-    cands = autotune._attention_candidates(S, S)
-    assert len(cands) >= 2
-    for bq in cands:
+    config.set("kernels.enabled", True)
+    try:
         text = compile_for_chip(functools.partial(
-            pk.flash_attention, causal=True, block_q=bq), q, q, q)
-        assert _kernel_count(text) == 1, bq
+            kernels.attention, causal=True), q, q, q)
+    finally:
+        config.unset("kernels.enabled")
+    assert _kernel_count(text) == 1
 
 
 # --------------------------------------------------------- paged decode
@@ -163,33 +165,6 @@ def test_paged_attention_compiles_for_the_default_config(compile_for_chip):
     assert _kernel_count(text) == 1
 
 
-# -------------------------------------------------- optimizer epilogues
-EPILOGUE_SHAPES = [(768, 3072),        # an MLP weight of the LM
-                   (512, 512, 3, 3),   # ResNet-50: a 3-wide minor axis
-                   (2048, 512, 1, 1),  # ... a 1-wide one
-                   (1000, 2048), (64,), (1000,)]
-
-
-@pytest.mark.parametrize("shape", EPILOGUE_SHAPES, ids=str)
-def test_fused_sgd_step_compiles(compile_for_chip, shape):
-    w = compile_for_chip.spec(shape, F32)
-    text = compile_for_chip(
-        lambda w, g, m: pk.fused_sgd_step(w, g, m, 0.1, 1e-4, 0.9,
-                                          out_dtype=BF16), w, w, w)
-    assert _kernel_count(text) == 1
-
-
-@pytest.mark.parametrize("shape", [(768, 3072), (12, 768, 3, 12, 64),
-                                   (768,)], ids=str)
-def test_fused_adam_step_compiles(compile_for_chip, shape):
-    w = compile_for_chip.spec(shape, F32)
-    text = compile_for_chip(
-        lambda w, g, m, v: pk.fused_adam_step(
-            w, g, m, v, 1e-3, 0.01, 0.9, 0.999, 1e-8, out_dtype=BF16),
-        w, w, w, w)
-    assert _kernel_count(text) == 1
-
-
 # ------------------------------------------------------------ row kernels
 @pytest.mark.parametrize("shape", [(4096, 1024), (100, 1000)], ids=str)
 def test_row_softmax_forward_and_backward_compile(compile_for_chip, shape):
@@ -211,13 +186,10 @@ def test_scale_bias_relu_compiles(compile_for_chip, shape):
 # ---------------------------------------------------------- whole programs
 @pytest.fixture
 def kernel_tier_on():
-    """The explicit knob: kernels wherever feasible, no measured gate (a
-    search would have to run them, and nothing runs here)."""
+    """The explicit knob: kernels wherever feasible."""
     config.set("kernels.enabled", True)
-    config.set("perf.autotune", "off")
     yield
     config.unset("kernels.enabled")
-    config.unset("perf.autotune")
 
 
 def _default_lm(mesh=None):

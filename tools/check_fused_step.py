@@ -8,7 +8,7 @@ the host backend, with one parseable JSON line on stdout:
   2. numerics  — fused weights match an eager twin trained from the same
                  init/data (the stage-at-a-time reference path);
   3. speed     — fused step throughput beats eager on the benchmark MLP
-                 (informational here; bench.py records the real number).
+                 (informational: a CPU timing).
 
 Usage: JAX_PLATFORMS=cpu python tools/check_fused_step.py
 Wired as a `not slow` test in tests/test_fused_step.py.

@@ -9,16 +9,13 @@ with one parseable JSON line on stdout:
                 causal and non-causal f32 problems;
   2. softmax  — pallas_row_softmax grads match jnp.softmax grads (the
                 custom_vjp reuses the saved row max/sum);
-  3. fused    — SGD(+momentum) and Adam fused epilogues are BITWISE
-                equal to step()+astype when both run jitted (the only
-                honest comparison: XLA fuses multiply-add chains
-                differently across separately-compiled eager ops);
-  4. routing  — kernels.attention counts kernels.flash_attention on a
+  3. routing  — kernels.attention counts kernels.flash_attention on a
                 supported shape and kernels.fallback (with XLA-equal
                 output) when the kv slice exceeds the VMEM budget;
-  5. perf     — kernels.measure registers a "kernels"-family program
-                whose record carries cost_analysis FLOPs;
-  6. stack    — runtime.scan_stack builds the 8-layer transformer loss
+  4. perf     — a routed attention wrapped by mx.perf registers a
+                "kernels"-family program whose record carries
+                cost_analysis FLOPs;
+  5. stack    — runtime.scan_stack builds the 8-layer transformer loss
                 with less trace+compile time under scan than unroll, at
                 equal loss.
 
@@ -48,7 +45,6 @@ def main():
         import jax
         jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
-        import mxnet_tpu as mx
         from mxnet_tpu import config as _cfg
         from mxnet_tpu import kernels, perf, telemetry
         from mxnet_tpu.models.transformer import (TransformerLM,
@@ -109,33 +105,7 @@ def main():
         assert sm_diff < 2e-6, sm_diff
         result["softmax"] = {"bwd_maxdiff": sm_diff}
 
-        # 3. fused optimizer epilogues: bitwise vs step()+astype, jitted
-        w = jnp.asarray(rng.randn(33, 7), jnp.float32)
-        g = jnp.asarray(rng.randn(33, 7), jnp.float32)
-        fused = {}
-        for name, opt, state in (
-                ("sgd", mx.optimizer.create("sgd", learning_rate=0.1,
-                                            momentum=0.9),
-                 jnp.zeros_like(w)),
-                ("adam", mx.optimizer.create("adam", learning_rate=1e-3),
-                 (jnp.zeros_like(w), jnp.zeros_like(w)))):
-            def master(w, g, state, _o=opt):
-                nw, ns = _o.step(w, g, state, 0.1, 0.01, 3)
-                return nw.astype(jnp.bfloat16), nw, ns
-
-            def kernel(w, g, state, _o=opt):
-                return _o.step_fused(w, g, state, 0.1, 0.01, 3,
-                                     out_dtype=jnp.bfloat16)
-
-            ref = jax.jit(master)(w, g, state)
-            got = jax.jit(kernel)(w, g, state)
-            for a, b in zip(jax.tree_util.tree_leaves(ref),
-                            jax.tree_util.tree_leaves(got)):
-                assert a.dtype == b.dtype and bool(jnp.all(a == b)), name
-            fused[name] = "bitwise"
-        result["fused"] = fused
-
-        # 4. routing counters: supported → flash, over-budget kv → XLA
+        # 3. routing counters: supported → flash, over-budget kv → XLA
         flash_ctr = telemetry.counter("kernels.flash_attention")
         fb_ctr = telemetry.counter("kernels.fallback")
         f0, b0 = flash_ctr.value, fb_ctr.value
@@ -151,16 +121,17 @@ def main():
         result["routing"] = {"flash_count": flash_ctr.value,
                              "fallback_count": fb_ctr.value}
 
-        # 5. perf: the "kernels" family registers with compiler FLOPs
-        (_, rec) = kernels.measure(
-            "smoke/attention",
-            lambda q, k, v: kernels.attention(q, k, v, causal=True),
-            q, k, v)
+        # 4. perf: the "kernels" family registers with compiler FLOPs
+        attn = perf.wrap(
+            jax.jit(lambda q, k, v: kernels.attention(q, k, v, causal=True)),
+            "kernels", "smoke/attention")
+        jax.block_until_ready(attn(q, k, v))
+        rec = perf.program("kernels", "smoke/attention")
         assert rec is not None and rec["family"] == "kernels", rec
         assert rec["flops"] > 0 and rec["phases_ms"], rec
         result["perf"] = {"flops": rec["flops"]}
 
-        # 6. scan beats unroll on trace+compile, at equal loss
+        # 5. scan beats unroll on trace+compile, at equal loss
         _cfg.set("kernels.enabled", False)
         deep = TransformerLMConfig(vocab_size=64, num_layers=8,
                                    d_model=32, num_heads=2, d_ff=64,

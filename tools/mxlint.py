@@ -31,7 +31,7 @@ burn-down ledger for ROADMAP item 3 uses this).
 
 The pass package lives at mxnet_tpu/analysis/ but is loaded here
 *without* importing ``mxnet_tpu`` itself (which would pull in jax): a
-full-tree lint stays fast enough for the bench preflight and CI smoke
+full-tree lint stays fast enough for the CI smoke
 (tools/check_analysis.py).
 """
 from __future__ import annotations
@@ -89,8 +89,7 @@ def _changed_files(root, ref, ap):
         name = name.strip()
         if not name.endswith(".py"):
             continue
-        if name == "bench.py" or \
-                name.split("/")[0] in ("mxnet_tpu", "tools"):
+        if name.split("/")[0] in ("mxnet_tpu", "tools"):
             out.append(name)
     return out
 

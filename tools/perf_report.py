@@ -27,12 +27,6 @@ Anomaly flags (report content, not errors; ``--strict`` gates CI):
     median of all captured programs (and over a 250ms floor): a
     pathological program shape or a cache miss that should have hit.
 
-When the registry dump carries an ``autotune`` section (mx.perf.export
-does since round 16) the report appends a tuned-vs-default delta table:
-one row per tuned site with the measured baseline, the winner, and the
-speedup the persisted pick buys — the evidence behind each graduation
-verdict.  Dumps from older rounds render exactly as before.
-
 Usage:
   python tools/perf_report.py --programs PROG.json RUN.jsonl
   python tools/perf_report.py --programs PROG.json --json
@@ -64,45 +58,6 @@ def load_programs(path):
     return [p for p in progs if isinstance(p, dict)], dump
 
 
-# knob-space searches measure every candidate; the repo-default combo's
-# label doubles as their baseline when the entry has no baseline_ms
-_STEP_DEFAULT_LABELS = ("remat=/stack_mode=scan",)
-
-
-def autotune_table(section):
-    """tuned-vs-default delta rows from a perf dump's ``autotune``
-    section (one per persisted winner, grouped by program family), or []
-    for pre-round-16 dumps that don't carry one."""
-    rows = []
-    for key, entry in sorted((section or {}).get("entries", {}).items()):
-        if not isinstance(entry, dict):
-            continue
-        parts = key.split("|")
-        family = parts[0] if parts else "?"
-        base = entry.get("baseline_ms")
-        if base is None:
-            cands = entry.get("candidates") or {}
-            for label in _STEP_DEFAULT_LABELS:
-                if label in cands:
-                    base = cands[label]
-                    break
-        best = entry.get("best_ms")
-        speedup = entry.get("speedup")
-        if speedup is None and base and best:
-            speedup = round(float(base) / float(best), 4)
-        rows.append({
-            "family": family,
-            "site": entry.get("site") or (parts[1] if len(parts) > 1 else "?"),
-            "impl": entry.get("impl", "?"),
-            "default_ms": base,
-            "tuned_ms": best,
-            "speedup": speedup,
-            "parity": entry.get("parity"),
-            "verdict": entry.get("reason") or "graduated",
-        })
-    return rows
-
-
 def _mfu_series(records):
     """source -> [per-step mfu] in log order (compile steps excluded —
     their wall time measures XLA, not the program)."""
@@ -121,7 +76,7 @@ def _windows(vals, k):
             for i in range(0, len(vals), k) if vals[i:i + k]]
 
 
-def summarize(progs, records, trace_classes=None, autotune=None):
+def summarize(progs, records, trace_classes=None):
     anomalies = []
 
     # ------------------------------------------------- program cost table
@@ -202,8 +157,6 @@ def summarize(progs, records, trace_classes=None, autotune=None):
            "mfu": mfu, "anomalies": anomalies}
     if trace_classes is not None:
         out["device_trace_op_classes"] = trace_classes
-    if autotune is not None:
-        out["autotune"] = autotune_table(autotune)
     return out
 
 
@@ -262,22 +215,6 @@ def render(summary):
                             else t["mfu_last_window"],
                             "-" if t["mfu_best_window"] is None
                             else t["mfu_best_window"]))
-    tuned = summary.get("autotune")
-    if tuned:
-        lines.append("")
-        ah = ("%-10s %-30s %-7s %11s %9s %8s %-9s %s"
-              % ("family", "site", "impl", "default_ms", "tuned_ms",
-                 "speedup", "parity", "verdict"))
-        lines.append(ah)
-        lines.append("-" * len(ah))
-        for r in tuned:
-            lines.append("%-10s %-30s %-7s %11s %9s %8s %-9s %s"
-                         % (r["family"], r["site"][:30], r["impl"],
-                            "-" if r["default_ms"] is None
-                            else r["default_ms"],
-                            "-" if r["tuned_ms"] is None else r["tuned_ms"],
-                            "-" if r["speedup"] is None else r["speedup"],
-                            r["parity"] or "-", r["verdict"]))
     trace = summary.get("device_trace_op_classes")
     if trace:
         lines.append("")
@@ -317,8 +254,7 @@ def main(argv=None):
     progs, dump = load_programs(args.programs)
     records, bad = load_records(args.log) if args.log else ([], 0)
     trace_classes = trace_op_classes(args.trace) if args.trace else None
-    summary = summarize(progs, records, trace_classes,
-                        autotune=dump.get("autotune"))
+    summary = summarize(progs, records, trace_classes)
     if args.json:
         summary["malformed_lines"] = bad
         print(json.dumps(summary))
