@@ -110,14 +110,15 @@ def _sizes(rehearse):
                         num_heads=4, num_kv_heads=2, head_dim=16,
                         ssm_heads=8, ssm_head_dim=8, ssm_groups=2,
                         ssm_state=16, chunk=8, num_experts=16, top_k=3,
-                        moe_latent=32, expert_ff=48, shared_ff=96,
+                        # (experts of whole lanes: the grouped kernel's)
+                        moe_latent=128, expert_ff=128, shared_ff=96,
                         route_scale=5.0, experts_held=8, expert_offset=4,
                         max_len=128),
             hybrid_serve=dict(prompts=(4, 9, 20, 30, 7, 13),
                               prompt_buckets=(8, 32), new_tokens=6,
                               decode_batch=4, page=16, kv_pages=32),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
-            softmax=(16, 48), sbr=(16, 40),
+            grouped=(200, 8, 128, 256), softmax=(16, 48), sbr=(16, 40),
             dp_batch=16, lm_batch=4, lm_seq=32)
     return dict(
         vision="resnet50_v1", classes=1000, image=224, batch=128,
@@ -143,7 +144,10 @@ def _sizes(rehearse):
                           prompt_buckets=(128, 512), new_tokens=24,
                           decode_batch=8, page=128, kv_pages=64),
         flash=((8, 12, 1024, 64), (2, 12, 200, 64)), paged_k=(1024, 2048),
-        softmax=(4096, 1024), sbr=(4096, 768),
+        # rows, groups, K, N: a decode step's pairs over a quarter of the
+        # hybrid cell's held experts, at their widths
+        grouped=(2816, 32, 1024, 2688), softmax=(4096, 1024),
+        sbr=(4096, 768),
         dp_batch=512, lm_batch=4, lm_seq=1024)
 
 
@@ -470,6 +474,7 @@ def phase_serve_hybrid(args, sz):
                for n in hs["prompts"]]
     new = hs["new_tokens"]
     names = ("kernels.paged_attention", "kernels.paged_fallback",
+             "kernels.grouped_matmul", "kernels.grouped_fallback",
              "serving.compiles", "serving.prefix_share_refused") \
         + tuple("serving." + n for n in model.decode_stats)
 
@@ -514,6 +519,8 @@ def phase_serve_hybrid(args, sz):
           prompt_lens=list(hs["prompts"]),
           new_tokens=new, decode_slots=hs["decode_batch"],
           paged_routes={w: r.get("impl") for w, r in meta["paged"].items()},
+          grouped_routes={p: r.get("impl")
+                          for p, r in meta["grouped"].items()},
           state_arrays=[s["name"] for s in meta["kv"]["state"]],
           traffic_s=round(traffic_s, 3), decode_iterations=steps,
           counters=delta, tokens_flipped=flipped,
@@ -529,6 +536,10 @@ def phase_serve_hybrid(args, sz):
              and delta["kernels.paged_fallback"] == 0,
              "the paged kernel ran %d of %d decode iterations"
              % (delta["kernels.paged_attention"], steps))
+    _require(delta["kernels.grouped_matmul"] == steps + len(prompts)
+             and delta["kernels.grouped_fallback"] == 0,
+             "the grouped kernel ran %d of %d prefill and decode dispatches"
+             % (delta["kernels.grouped_matmul"], steps + len(prompts)))
     _require(all(state_touched) and len(state_touched)
              == 2 * model.cfg.pattern.count("M"),
              "a state array was never written: %s" % state_touched)
@@ -655,6 +666,20 @@ def phase_kernels(args, sz):
               lambda q, k, v, t, n, ks, vs: kernels._paged_attention_xla(
                   q, k, v, t, n, k_scale=ks, v_scale=vs),
               (rand((B, H, 1, D), bf16), k8, v8, table, lens, ks, vs), MXU)
+
+    # rows sorted by group: a third of them behind the last group, some
+    # groups empty, most lying across a row tile's edge
+    m, e, k, n = sz["grouped"]
+    sizes = rng.multinomial(2 * m // 3, rng.dirichlet(np.ones(e)))
+    sizes[rng.randint(0, e, e // 4)] = 0
+    held = int(sizes.sum())
+    for dt in (f32, bf16):
+        check("grouped_%s" % jnp.dtype(dt).name,
+              lambda x, w, s: pk.pallas_grouped_matmul(x, w, s)[:held],
+              lambda x, w, s: kernels._grouped_matmul_xla(
+                  x, w, s, None, f32)[:held],
+              (rand((m, k), dt), rand((e, k, n), dt),
+               jnp.asarray(sizes, jnp.int32)), MXU)
 
     x = rand(sz["softmax"], f32)
     check("pallas_row_softmax", pk.pallas_row_softmax,

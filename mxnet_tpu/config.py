@@ -794,11 +794,13 @@ _ON_SET["serving.decode_slots"] = _positive_int_knob("serving.decode_slots")
 register_knob(
     "kernels.enabled", "MXNET_TPU_KERNELS", bool, True,
     "the Pallas kernel tier (mx.kernels). One static rule routes "
-    "attention and paged decode attention at trace time: off -> the XLA "
+    "attention, paged decode attention and the held experts' grouped "
+    "product at trace time: off -> the XLA "
     "lowering, byte-identical to a program without the tier; at its "
     "default on a backend that interprets Pallas (CPU/GPU) -> the XLA "
     "lowering (kernels.gated_fallback); a shape the kernel cannot take "
-    "-> the XLA lowering (kernels.fallback / kernels.paged_fallback); "
+    "-> the XLA lowering (kernels.fallback / kernels.paged_fallback / "
+    "kernels.grouped_fallback); "
     "else the kernel. Set explicitly on (env or set()) the kernels also "
     "run in the interpreter.")
 register_knob(
@@ -808,8 +810,10 @@ register_knob(
     "(ops/pallas_kernels.py _row_block): block row counts are the "
     "largest divisor of n_rows whose block fits the budget; flash "
     "attention also checks one head's full K/V against it before "
-    "engaging, and the paged decode kernel sizes its double-buffered "
-    "K/V page tiles to it (128 tokens at most). Must be > 0; ~16MB/core is the hardware ceiling, the "
+    "engaging, the paged decode kernel sizes its double-buffered "
+    "K/V page tiles to it (128 tokens at most), and the grouped product "
+    "streams the widest column tile of a group's [K, N] matrix whose "
+    "[K, tile] block fits it. Must be > 0; ~16MB/core is the hardware ceiling, the "
     "2MB default leaves headroom for double buffering.")
 
 

@@ -553,12 +553,21 @@ def export_generation(model, params, prefix, page_size=None,
 
     paths = []
     paged_routes = {}
+    grouped_routes = {}
 
-    def _export_one(fn, arg_specs, path, route_key=None):
+    def _export_one(fn, arg_specs, program, route_key=None):
         # the pool's page count stays symbolic inside the paged kernel too
         with _kernels.record_paged_routes() as routes, \
+                _kernels.record_grouped_routes() as grouped, \
                 _kernels.pallas_dynamic_shapes():
             exp = jexport.export(jax.jit(fn))(*arg_specs)
+        if grouped:
+            # a program's grouped products (two an expert block) share
+            # their shapes' verdict; one that fell back names the program
+            refused = [r for r in grouped if r["impl"] != "grouped"]
+            grouped_routes[program] = dict((refused or grouped)[0],
+                                           sites=len(grouped))
+        path = "%s-%s.stablehlo" % (prefix, program)
         if route_key is not None:
             # one paged_attention route per scanned stack trace; the scan
             # body compiles once, so one entry describes the whole program
@@ -623,8 +632,7 @@ def export_generation(model, params, prefix, page_size=None,
                      jax.ShapeDtypeStruct((b, s_bucket), i32),
                      jax.ShapeDtypeStruct((b,), i32),
                      jax.ShapeDtypeStruct((b, w_s), i32))
-        _export_one(prefill_fn, specs,
-                    "%s-prefill-s%d.stablehlo" % (prefix, s_bucket))
+        _export_one(prefill_fn, specs, "prefill-s%d" % s_bucket)
 
     for width in widths:
         b, p, _ = _dims()
@@ -660,8 +668,7 @@ def export_generation(model, params, prefix, page_size=None,
                      jax.ShapeDtypeStruct((bd,), i32),
                      jax.ShapeDtypeStruct((bd,), i32),
                      jax.ShapeDtypeStruct((bd, width), i32))
-        _export_one(decode_fn, specs,
-                    "%s-decode-w%d.stablehlo" % (prefix, width),
+        _export_one(decode_fn, specs, "decode-w%d" % width,
                     route_key=str(width))
 
     meta = {
@@ -677,6 +684,7 @@ def export_generation(model, params, prefix, page_size=None,
         "decode_widths": list(widths),
         "kv": dict(spec, page_size=psz),
         "paged": paged_routes,
+        "grouped": grouped_routes,
     }
     if v5:
         meta["sampling"] = True
@@ -748,6 +756,9 @@ class GenerationPredictor:
         #: per-width kernel routing verdict recorded at export (an AOT
         #: program can never re-route at serve time)
         self.paged_routes = dict(self.meta.get("paged", {}))
+        #: the same for the grouped products, by program ("decode-w16",
+        #: "prefill-s128"); a program without one has no entry
+        self.grouped_routes = dict(self.meta.get("grouped", {}))
         self._v5 = self.format_version >= SAMPLING_FORMAT_VERSION
         #: the cache's state region (per-slot arrays beside the pages) and
         #: the names of the counts a decode step returns behind its tokens

@@ -889,9 +889,22 @@ class GenerationEngine:
         slot.ttft_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.prefill_ms").observe((t1 - t0) * 1e3)
         _telemetry.timer("serving.ttft_ms").observe(slot.ttft_ms)
+        self._count_grouped_route("prefill-s%d" % s_bucket)
         self._count_tokens(1)
         self._maybe_finish(slot_idx)
         return True
+
+    def _count_grouped_route(self, program):
+        """Serve-side mirror of the export-time verdict on a program's
+        grouped products, once a dispatch: the Pallas kernel ran them, or
+        ``lax.ragged_dot`` did while the kernel tier was on."""
+        route = self.predictor.grouped_routes.get(program)
+        if route is None:
+            return
+        if route.get("impl") == "grouped":
+            _telemetry.counter("kernels.grouped_matmul").inc()
+        elif _kernels_enabled():
+            _telemetry.counter("kernels.grouped_fallback").inc()
 
     def _dispatch_decode(self):
         """One decode iteration for every active slot.  The page-table
@@ -995,6 +1008,7 @@ class GenerationEngine:
                 _telemetry.counter("kernels.paged_attention").inc()
             elif _kernels_enabled():
                 _telemetry.counter("kernels.paged_fallback").inc()
+        self._count_grouped_route("decode-w%d" % width)
         with _tracing.span("engine.decode.emit", cat="serving") as emit:
             self._count_tokens(len(active))
             gap = _telemetry.timer("serving.token_gap_ms")

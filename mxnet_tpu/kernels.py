@@ -1,8 +1,9 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  Both routed sites
-(:func:`attention`, :func:`paged_attention`) ask one rule
+this module alone decides WHEN they run.  The three routed sites
+(:func:`attention`, :func:`paged_attention`, :func:`grouped_matmul`) ask
+one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -14,10 +15,12 @@ time and nothing else — nothing is timed, persisted or remembered:
    (``kernels.gated_fallback``); an explicit on still takes the kernel
    there, which is how the parity tests run it;
 3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
-   :func:`paged_unsupported_reason`) → the XLA lowering
-   (``kernels.fallback`` / ``kernels.paged_fallback``), never an error;
+   :func:`paged_unsupported_reason`, :func:`grouped_unsupported_reason`)
+   → the XLA lowering (``kernels.fallback`` / ``kernels.paged_fallback``
+   / ``kernels.grouped_fallback``), never an error;
 4. else the kernel (``kernels.flash_attention`` /
-   ``kernels.paged_attention``), at constant block sizes.
+   ``kernels.paged_attention`` / ``kernels.grouped_matmul``), at block
+   sizes that are constants or functions of the shapes.
 
 The decision is trace-time python, so a jitted program contains one
 path only; a knob change retraces (``config.epoch()`` in the program
@@ -25,19 +28,24 @@ cache keys, which moves when a knob's value or its source does).
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
-
-import contextlib
+from jax import lax
 
 from . import config as _config
 from . import telemetry as _telemetry
-from .ops.pallas_kernels import flash_attention, pallas_paged_attention
+from .ops.pallas_kernels import (flash_attention, grouped_col_tile,
+                                 pallas_grouped_matmul,
+                                 pallas_paged_attention)
 
-__all__ = ["enabled", "attention", "paged_attention",
+__all__ = ["enabled", "attention", "paged_attention", "grouped_matmul",
            "flash_unsupported_reason", "paged_unsupported_reason",
-           "record_paged_routes", "pallas_dynamic_shapes",
-           "flash_attention", "pallas_paged_attention"]
+           "grouped_unsupported_reason", "record_paged_routes",
+           "record_grouped_routes", "pallas_dynamic_shapes",
+           "flash_attention", "pallas_paged_attention",
+           "pallas_grouped_matmul"]
 
 # one-row VMEM feasibility: a q block keeps its head's full K and V
 # resident, so 2 * Skv * D * itemsize must fit the budget
@@ -207,30 +215,41 @@ def pallas_dynamic_shapes():
     return core.pallas_export_experimental(True)
 
 
-# Export-time route capture: deploy.export_generation traces the decode
-# program family under record_paged_routes() and lands the impl/reason of
-# every routed paged site in the artifact meta — the serve path then
-# counts kernels.paged_attention / paged_fallback per dispatch without
+# Export-time route capture: deploy.export_generation traces every
+# program under record_paged_routes() / record_grouped_routes() and lands
+# the impl/reason of the routed sites in the artifact meta — the serve
+# path then counts kernels.paged_attention / paged_fallback and
+# kernels.grouped_matmul / grouped_fallback per dispatch without
 # re-tracing (the program is AOT; trace-time counters fire at export).
-_PAGED_ROUTE_SINK = []
+_ROUTE_SINKS = {"paged": [], "grouped": []}
 
 
 @contextlib.contextmanager
-def record_paged_routes():
-    """Collect ``{"impl", "reason", "quantized"}`` dicts for every paged
-    route decision made while tracing under this context."""
+def _record_routes(site):
     routes = []
-    _PAGED_ROUTE_SINK.append(routes)
+    _ROUTE_SINKS[site].append(routes)
     try:
         yield routes
     finally:
-        _PAGED_ROUTE_SINK.remove(routes)
+        _ROUTE_SINKS[site].remove(routes)
 
 
-def _note_paged_route(impl, reason, quantized):
-    for routes in _PAGED_ROUTE_SINK:
-        routes.append({"impl": impl, "reason": reason,
-                       "quantized": bool(quantized)})
+def record_paged_routes():
+    """Collect ``{"impl", "reason", "quantized"}`` dicts for every paged
+    route decision made while tracing under this context."""
+    return _record_routes("paged")
+
+
+def record_grouped_routes():
+    """Collect ``{"impl", "reason"}`` dicts (``impl`` "grouped" or "xla")
+    for every grouped-product route decision made while tracing under
+    this context."""
+    return _record_routes("grouped")
+
+
+def _note_route(site, **route):
+    for routes in _ROUTE_SINKS[site]:
+        routes.append(dict(route))
 
 
 def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
@@ -359,12 +378,95 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
         _telemetry.counter("kernels.paged_fallback"))
     if reason is None:
         _telemetry.counter("kernels.paged_attention").inc()
-        _note_paged_route("paged", None, quant)
+        _note_route("paged", impl="paged", reason=None, quantized=quant)
         with jax.named_scope("mx.paged_attention"):
             return pallas_paged_attention(
                 q, k_pages, v_pages, page_table, lengths, scale=scale,
                 k_scale=k_scale, v_scale=v_scale, layer=layer)
-    _note_paged_route("xla", reason, quant)
+    _note_route("paged", impl="xla", reason=reason, quantized=quant)
     return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                 scale=scale, k_scale=k_scale,
                                 v_scale=v_scale, layer=layer)
+
+
+# ------------------------------------------------------- grouped product
+# XLA's grouped product wants whole row tiles: on the chip a row count
+# off the tile gave wrong products (PERF.md section 6, PR 27: 4,532 rows,
+# every token off by 20% of the logits' scale)
+_XLA_GROUPED_ROW_TILE = 256
+
+
+def grouped_unsupported_reason(rows, w, sizes):
+    """Why the Pallas grouped product can NOT take this call, or None if
+    it can.  Trace-time shape/dtype checks only.  A non-None reason
+    routes to ``lax.ragged_dot`` (``kernels.grouped_fallback``) and is
+    surfaced in the export route sink (:func:`record_grouped_routes`)."""
+    if rows.ndim != 2 or w.ndim != 3 or sizes.ndim != 1:
+        return "rank: rows%s w%s sizes%s, want 2, 3 and 1" % (
+            rows.ndim, w.ndim, sizes.ndim)
+    dims = tuple(rows.shape) + tuple(w.shape) + tuple(sizes.shape)
+    if not all(isinstance(d, int) for d in dims):
+        return "symbolic shape (rows%s w%s)" % (rows.shape, w.shape)
+    if w.shape[:2] != (sizes.shape[0], rows.shape[1]):
+        return "w%s is not [groups %d, K %d, N]" % (
+            tuple(w.shape), sizes.shape[0], rows.shape[1])
+    if rows.dtype != w.dtype or rows.dtype not in (jnp.float32,
+                                                   jnp.bfloat16):
+        return "operands %s and %s, want both float32 or both bfloat16" \
+            % (rows.dtype, w.dtype)
+    k, n = w.shape[1:]
+    if k % 128 or n % 128:
+        return "K=%d and N=%d must be multiples of 128" % (k, n)
+    if grouped_col_tile(k, n, w.dtype.itemsize) is None:
+        return "a [K=%d, 128] block of the weights exceeds the vmem " \
+            "budget %d" % (k, _config.get("kernels.vmem_budget"))
+    return None
+
+
+def _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype):
+    """The XLA twin of the grouped product: ``lax.ragged_dot`` over the
+    rows padded to whole tiles of :data:`_XLA_GROUPED_ROW_TILE` (the rows
+    added join no group), accumulated in float32."""
+    m = rows.shape[0]
+    rows = jnp.pad(rows, ((0, -m % _XLA_GROUPED_ROW_TILE), (0, 0)))
+    out = lax.ragged_dot(rows, w, sizes,
+                         preferred_element_type=jnp.float32)[:m]
+    if epilogue is not None:
+        out = epilogue(out)
+    return out.astype(out_dtype)
+
+
+def grouped_matmul(rows, w, sizes, epilogue=None, out_dtype=jnp.float32):
+    """Grouped matrix product with kernel routing: ``rows [M, K]`` lie
+    sorted by group, group ``g`` owns the next ``sizes[g]`` of them and
+    multiplies them by ``w[g] [K, N]``; the float32 product goes through
+    ``epilogue`` (an elementwise function, if given) and is cast to
+    ``out_dtype``: ``[M, N]``.  A row behind the last group is whatever
+    the product left there.
+
+    Routing: tier on and shape feasible (float32 or bfloat16 operands,
+    ``K`` and ``N`` multiples of 128, a ``[K, 128]`` block inside
+    ``kernels.vmem_budget``) → the Pallas kernel
+    (``ops.pallas_kernels.pallas_grouped_matmul``, named
+    ``mx_grouped_matmul``), which reads the groups that have rows, each
+    once, multiplies row tiles sized from the shapes and applies the
+    epilogue to the rows it walks; counter ``kernels.grouped_matmul``.
+    Otherwise → ``lax.ragged_dot`` and the epilogue over every row: tier
+    off, the default knob on an interpreted backend
+    (``kernels.gated_fallback``), or a shape the kernel cannot take
+    (``kernels.grouped_fallback``).  Both round where the other does:
+    float32 accumulators, the epilogue in float32, one cast.  The
+    decision and its reason land in those counters and, under
+    :func:`record_grouped_routes`, in the export route sink.  The call
+    carries no scope of its own: the caller's (``mx.moe_experts``) names
+    the kernel's device time."""
+    reason = _route_reason(
+        lambda: grouped_unsupported_reason(rows, w, sizes),
+        _telemetry.counter("kernels.grouped_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.grouped_matmul").inc()
+        _note_route("grouped", impl="grouped", reason=None)
+        return pallas_grouped_matmul(rows, w, sizes, epilogue=epilogue,
+                                     out_dtype=out_dtype)
+    _note_route("grouped", impl="xla", reason=reason)
+    return _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype)

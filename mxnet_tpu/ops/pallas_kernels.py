@@ -30,7 +30,7 @@ from .registry import register
 
 __all__ = ["pallas_row_softmax", "pallas_scale_bias_relu",
            "pallas_flash_attention", "flash_attention",
-           "pallas_paged_attention"]
+           "pallas_paged_attention", "pallas_grouped_matmul"]
 
 _NEG = -1e30
 
@@ -672,6 +672,153 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
         interpret=interpret_mode(), name="mx_paged_attention")(
             jnp.asarray(lengths, jnp.int32), ids, *operands)
     return out.reshape(B, H, 1, D)
+
+
+# ------------------------------------------------------- grouped product
+_GROUPED_ROW_TILE = 128          # one MXU pass tall
+
+
+def grouped_row_tile(m, itemsize):
+    """Rows a tile of the grouped product holds: one MXU pass (128), or
+    all ``m`` rows, rounded up to the dtype's sublane packing (8 rows of
+    f32, 16 of bf16), where there are fewer.  A step's cost is its
+    weight tile's fetch, not its rows (the MXU loads a 128-row weight
+    tile whatever it streams past it), so a tile holds as many groups as
+    it can: the fewer groups lie across a tile's edge, the fewer steps
+    run with no fetch in flight (on the chip, 2,816 rows over 128 groups
+    of ~5: 2.33 ms an expert layer at 16 rows, 2.15 at 64 and at 128,
+    2.34 at 256; PERF.md section 6, PR 30)."""
+    pack = _SUBLANES * 4 // itemsize
+    return min(_GROUPED_ROW_TILE, -(-m // pack) * pack)
+
+
+def grouped_col_tile(k, n, itemsize):
+    """Columns of one group's ``[k, n]`` matrix a step of the grouped
+    product streams: the widest multiple of the 128 lanes that divides
+    ``n`` and whose ``[k, tile]`` block fits ``kernels.vmem_budget`` (the
+    pipeline holds two), or None where not even the narrowest does."""
+    from .. import config as _config
+    tile = _row_block(n, k * itemsize, align=_LANES)
+    return tile if tile * k * itemsize \
+        <= _config.get("kernels.vmem_budget") else None
+
+
+def _grouped_work_items(sizes, row_tiles, tm):
+    """The steps a grouped product walks, from the groups' sizes: one for
+    every (group, row tile) pair in which the group has rows, in group
+    order (``sizes`` int32).  Returns ``(group [I], tile [I], start [E],
+    end [E], count [1])`` int32 with ``I = row_tiles + E - 1``, the most
+    there can be
+    (each group after the first adds at most the tile it shares with its
+    predecessor); the ``I - count`` steps past the last pair repeat it,
+    so the pipeline that walks all ``I`` fetches nothing for them."""
+    e = sizes.shape[0]
+    end = jnp.cumsum(sizes)
+    start = end - sizes
+    first = start // tm
+    tiles = jnp.where(sizes > 0, (end + tm - 1) // tm - first, 0)
+    item_end = jnp.cumsum(tiles)
+    i = jnp.arange(row_tiles + e - 1, dtype=jnp.int32)
+    i = jnp.minimum(i, jnp.maximum(item_end[-1] - 1, 0))
+    # the group of step i is the first whose steps end behind it
+    group = jnp.minimum(jnp.sum(item_end[None, :] <= i[:, None], axis=1,
+                                dtype=jnp.int32), e - 1)
+    tile = first[group] + i - (item_end - tiles)[group]
+    return (group, jnp.clip(tile, 0, row_tiles - 1), start, end,
+            item_end[-1:])
+
+
+def _grouped_matmul_kernel(tm, epilogue, group_ref, tile_ref, start_ref,
+                           end_ref, count_ref, x_ref, w_ref, o_ref):
+    """One (group, row tile) pair of a grouped product: the tile's ``tm``
+    rows times one column tile of the group's matrix, kept for the rows
+    the group owns.  Row tiles lie on multiples of ``tm`` whatever the
+    groups' offsets, so a tile that several groups share is walked once a
+    group (consecutive steps, the output block staying in VMEM between
+    them) and each keeps its own rows; rows of no group keep what the
+    buffer held.  The group and tile of a step arrive by scalar prefetch
+    and pick its blocks: consecutive steps of one group name the same
+    weight block, which is then fetched once, and the steps past the last
+    pair (``count_ref``) name the last pair's blocks and compute nothing.
+    ``epilogue`` (elementwise, on the float32 product) runs before the
+    cast to the output's dtype."""
+    from jax.experimental import pallas as pl
+    i = pl.program_id(1)
+
+    @pl.when(i < count_ref[0])
+    def _():
+        g = group_ref[i]
+        x, w = x_ref[...], w_ref[...]
+        acc = jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())), precision=_mxu_precision(x, w),
+            preferred_element_type=jnp.float32)
+        if epilogue is not None:
+            acc = epilogue(acc)
+        row = tile_ref[i] * tm + jax.lax.broadcasted_iota(
+            jnp.int32, acc.shape, 0)
+        mine = (row >= start_ref[g]) & (row < end_ref[g])
+        # (masks have the 32-bit layout: select in f32, then narrow)
+        o_ref[...] = jnp.where(mine, acc, o_ref[...].astype(jnp.float32)
+                               ).astype(o_ref.dtype)
+
+
+def pallas_grouped_matmul(rows, w, sizes, epilogue=None,
+                          out_dtype=jnp.float32):
+    """Grouped matrix product: ``rows [M, K]`` lie sorted by group, group
+    ``g`` owns the next ``sizes[g]`` of them and multiplies them by ``w[g]
+    [K, N]``.  Returns ``[M, N]`` of ``out_dtype``: 16-bit operands take
+    the MXU's native pass, the product accumulates in float32, and
+    ``epilogue`` (an elementwise function, if given) is applied to it
+    before the cast.  A row behind the last group is left as it lay,
+    whatever that is.
+
+    The row tile follows from the static shapes (:func:`grouped_row_tile`)
+    and the groups' sizes decide at run time which (group, tile) pairs
+    there are (:func:`_grouped_work_items`, by scalar prefetch): a group
+    without rows is never read, one with rows is read once, a column tile
+    at a time (:func:`grouped_col_tile`) through the pipeline's two
+    buffers, and what is multiplied is a tile of rows a pair, not a tile
+    a group.  The grid is the most pairs there can be; the steps past the
+    last pair fetch and compute nothing (a grid cut to the pairs there
+    are, which Pallas can do, cannot be traced inside the export's
+    dynamic-shape lowering that the paged kernel needs).  ``K`` and ``N``
+    are multiples of 128 and a ``[K, 128]`` block fits
+    ``kernels.vmem_budget``: routing/fallback policy lives in
+    ``mx.kernels.grouped_matmul``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    m, k = rows.shape
+    e, _, n = w.shape
+    tm = grouped_row_tile(m, rows.dtype.itemsize)
+    tn = grouped_col_tile(k, n, w.dtype.itemsize)
+    if w.shape[1] != k or k % _LANES or n % _LANES or tn is None \
+            or sizes.shape != (e,):
+        raise ValueError(
+            "grouped product takes rows [M, K], w [E, K, N] and sizes [E] "
+            "with K and N multiples of %d and a [K, %d] block inside "
+            "kernels.vmem_budget, got %s, %s and %s"
+            % (_LANES, _LANES, rows.shape, w.shape, sizes.shape))
+    rows = jnp.pad(rows, ((0, -m % tm), (0, 0)))
+    row_tiles = rows.shape[0] // tm
+    items = _grouped_work_items(jnp.asarray(sizes, jnp.int32), row_tiles, tm)
+    out = pl.pallas_call(
+        functools.partial(_grouped_matmul_kernel, tm, epilogue),
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(n // tn, row_tiles + e - 1),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, i, group, tile, *_:
+                             (tile[i], 0)),
+                pl.BlockSpec((None, k, tn), lambda j, i, group, tile, *_:
+                             (group[i], 0, j))],
+            out_specs=pl.BlockSpec((tm, tn), lambda j, i, group, tile, *_:
+                                   (tile[i], j))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret_mode(), name="mx_grouped_matmul")(
+            *items, rows, w)
+    return out[:m]
 
 
 # ------------------------------------------------------- fused elementwise

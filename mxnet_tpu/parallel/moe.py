@@ -22,11 +22,13 @@ Layout contract (inside shard_map over `axis_name`):
 Beside the capacity layer stands the DROPLESS one a served model takes
 (:func:`sigmoid_top_k` + :func:`dropless_experts`): no capacity and no
 ``[T, E, C]`` tensor — the (token, expert) pairs are sorted by expert and
-go through one grouped matrix product (``lax.ragged_dot``), and the layer
-is TOLD which experts it holds (``expert_offset`` and the leading extent
-of its weights): it routes over all of them and computes the part of the
-result its own experts give, which is what one chip of an expert-parallel
-deployment does before the exchange (that exchange is not here).
+go through two grouped matrix products (``mx.kernels.grouped_matmul``:
+the Pallas kernel ``mx_grouped_matmul`` on a TPU, ``lax.ragged_dot``
+elsewhere), and the layer is TOLD which experts it holds
+(``expert_offset`` and the leading extent of its weights): it routes over
+all of them and computes the part of the result its own experts give,
+which is what one chip of an expert-parallel deployment does before the
+exchange (that exchange is not here).
 """
 from __future__ import annotations
 
@@ -35,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from .. import kernels as _kernels
 from .pipeline import shmap
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "top_k_routing",
@@ -140,9 +143,6 @@ def moe_ffn_sharded(mesh, gate_w, w1, b1, w2, b2, x, axis_name="ep",
     return shmapped(gate_w, w1, b1, w2, b2, x)
 
 
-_ROW_TILE = 256
-
-
 def sigmoid_top_k(x, router_w, select_bias, k, scale):
     """Sigmoid-scored top-k routing over ALL experts, in float32.
 
@@ -171,8 +171,11 @@ def dropless_experts(u, experts, weights, w1, w2, expert_offset=0,
     lives elsewhere adds nothing here; ``rows_valid`` [T] bool drops the
     pairs of rows that carry no request.  The held pairs are sorted by
     expert (stable, so the order is a pure function of the routing) and
-    run as two grouped products over the experts' row groups; the
-    weighted sum over each token's pairs is taken in z.
+    run as two grouped products over the experts' row groups
+    (``mx.kernels.grouped_matmul``, which routes each to the Pallas
+    kernel or to ``lax.ragged_dot``: float32 accumulators and results on
+    both, ``h`` rounded to ``u``'s dtype between them); the weighted sum
+    over each token's pairs is taken in z.
 
     Returns ``(y [T, z] f32, stats)`` with ``stats`` int32 scalars:
     ``pairs`` computed here, ``experts_hit`` (held experts with at least
@@ -188,15 +191,10 @@ def dropless_experts(u, experts, weights, w1, w2, expert_offset=0,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((e_held + 1,), jnp.int32).at[key].add(1)[:e_held]
     rows = jnp.take(u, order // k, axis=0)                  # [T*k, z]
-    # whole row tiles for the grouped product: on the chip a row count
-    # off the tile gave wrong products (PERF.md section 6, PR 27); the
-    # rows added join no group
-    rows = jnp.pad(rows, ((0, -(t * k) % _ROW_TILE), (0, 0)))
-    h = lax.ragged_dot(rows, w1, sizes,
-                       preferred_element_type=jnp.float32)
-    h = jnp.square(jax.nn.relu(h)).astype(u.dtype)
-    out = lax.ragged_dot(h, w2, sizes,
-                         preferred_element_type=jnp.float32)[:t * k]
+    h = _kernels.grouped_matmul(
+        rows, w1, sizes, epilogue=lambda a: jnp.square(jax.nn.relu(a)),
+        out_dtype=u.dtype)
+    out = _kernels.grouped_matmul(h, w2, sizes)
     # back to [T, k, z]: row i of the sorted list is pair order[i]
     back = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))

@@ -74,3 +74,24 @@ def lowered_with_and_without_scopes(lower, monkeypatch):
     assert named.as_text() == bare.as_text()
     assert "mx." not in bare.as_text(debug_info=True)
     return named.as_text(debug_info=True)
+
+
+def without_kernel_locations(text):
+    """A lowered program's text with each Mosaic kernel's serialized body
+    (which carries source lines) replaced by its MLIR text without
+    locations."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)

@@ -81,6 +81,9 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
         hybrid["decode_iterations"] > 0
     assert len(hybrid["state_arrays"]) == 6 and hybrid["tokens_flipped"] == 0
     assert hybrid["counters"]["serving.moe_experts_hit"] > 0
+    assert hybrid["counters"]["kernels.grouped_matmul"] \
+        == hybrid["decode_iterations"] + len(hybrid["prompt_lens"])
+    assert set(hybrid["grouped_routes"].values()) == {"grouped"}
     serve = by["serve"]
     assert serve["counters"]["kernels.paged_attention"] == \
         serve["decode_iterations"] > 0
@@ -89,7 +92,8 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert set(serve["paged_routes"].values()) == {"paged"}
     assert serve["worst_logit_gap"] <= serve["logit_gap_tolerance"]
     assert by["kernels"]["interpreted"] is True
-    assert {"flash_bwd", "paged_int8", "pallas_row_softmax"} <= {
+    assert {"flash_bwd", "paged_int8", "grouped_bfloat16",
+            "pallas_row_softmax"} <= {
         name.split("/")[0] for name in by["kernels"]["kernels"]}
     assert lines[-1]["rehearsed"] == ["phase_train", "phase_serve",
                                       "phase_serve_hybrid",

@@ -203,6 +203,81 @@ def test_rows_without_a_request_route_to_no_expert():
     assert not np.asarray(y[1::2]).any()
 
 
+@pytest.mark.parametrize("holes", [False, True], ids=["all_rows", "holes"])
+@pytest.mark.parametrize("tokens", [8, 40], ids=["decode", "prefill"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_held_experts_are_equal_on_both_routes(dtype, tokens, holes):
+    """``dropless_experts`` at widths the Pallas grouped product takes
+    (interpreter, explicit knob) against the ``lax.ragged_dot`` route
+    (tier off): 6 of 16 experts held, so most pairs trail behind the
+    groups, at a decode step's handful of rows a group and at a
+    prefill's, with and without rows that carry no request; the counters
+    say which route ran."""
+    lp, x = _expert_layer(seed=5, T=tokens, D=32, E=16, Z=128, F=256)
+    u = (x @ lp["w_down"]).astype(dtype)
+    experts, weights = moe.sigmoid_top_k(
+        hybrid._norm(x, lp["ln"], 1e-5), lp["router"], lp["select_bias"],
+        5, 2.5)
+    valid = jnp.arange(tokens) % 3 != 1 if holes else None
+    w1, w2 = (lp[n][3:9].astype(dtype) for n in ("w1", "w2"))
+
+    def run():
+        return jax.jit(lambda: moe.dropless_experts(
+            u, experts, weights, w1, w2, expert_offset=3,
+            rows_valid=valid))()
+
+    names = ("kernels.grouped_matmul", "kernels.grouped_fallback",
+             "kernels.gated_fallback")
+    outs = {}
+    try:
+        for route, setting, moved in (("kernel", True, names[0]),
+                                      ("xla", False, None),
+                                      ("gated", None, names[2])):
+            if setting is None:
+                mx.config.unset("kernels.enabled")
+            else:
+                mx.config.set("kernels.enabled", setting)
+            telemetry.reset()
+            outs[route] = run()
+            assert {n: telemetry.counter(n).value for n in names} \
+                == {n: 2 * (n == moved) for n in names}, route
+    finally:
+        mx.config.unset("kernels.enabled")
+    (y, stats), (want, want_stats) = outs["kernel"], outs["xla"]
+    assert {k: int(v) for k, v in stats.items()} \
+        == {k: int(v) for k, v in want_stats.items()}
+    assert 0 < int(stats["pairs"]) < tokens * 5
+    assert np.abs(np.asarray(want)).max() > 0
+    _close(y, want, tol=2e-5 if dtype == jnp.float32 else 1e-2)
+    assert np.array_equal(np.asarray(outs["gated"][0]), np.asarray(want))
+    if holes:
+        assert not np.asarray(y)[1::3].any()
+
+
+def test_widths_the_kernel_cannot_take_fall_back_counted():
+    """The tiny model's experts (16 and 24 wide) are no multiples of 128:
+    tier on, every grouped product of a decode step takes
+    ``lax.ragged_dot`` and ``kernels.grouped_fallback`` says so."""
+    model, params = _tiny()
+    kv = model.init_kv_pages(8, PAGE, slots=2)
+    mx.config.set("kernels.enabled", True)
+    telemetry.reset()
+    try:
+        with mx.kernels.record_grouped_routes() as routes:
+            jax.jit(lambda: model.decode_step(
+                params, kv, jnp.zeros((2,), jnp.int32),
+                jnp.ones((2,), jnp.int32), jnp.zeros((2, 2), jnp.int32),
+                PAGE))()
+    finally:
+        mx.config.unset("kernels.enabled")
+    blocks = model.kinds.count("E")
+    assert [r["impl"] for r in routes] == ["xla"] * 2 * blocks
+    assert all("multiples of 128" in r["reason"] for r in routes)
+    assert telemetry.counter("kernels.grouped_fallback").value == 2 * blocks
+    assert telemetry.counter("kernels.grouped_matmul").value == 0
+
+
 # -------------------------------------------------------- the paged kernel
 @pytest.mark.parametrize("heads,kv_heads,whole_pool", [
     (32, 2, True), (32, 2, False), (8, 4, True), (4, 4, False),
@@ -288,12 +363,28 @@ def test_export_round_trip_serves_the_oracles_tokens(served):
                for n in (5, 11, 3, 16, 7, 9, 12)]
     before = {n: telemetry.counter("serving." + n).value
               for n in model.decode_stats}
+    # (the oracle first: tracing it counts its own routes)
+    oracle = [model.greedy_decode(params, p, 9) for p in prompts]
+    dispatches = lambda: sum(  # noqa: E731
+        telemetry.timer("serving." + t).count
+        for t in ("prefill_ms", "decode_step_ms"))
+    fell_back = telemetry.counter("kernels.grouped_fallback").value \
+        - dispatches()
+    by_kernel = telemetry.counter("kernels.grouped_matmul").value
     futures = [srv.submit_generate("lm", p, 9) for p in prompts]
-    for p, f in zip(prompts, futures):
-        assert (f.result(timeout=300)
-                == model.greedy_decode(params, p, 9)).all()
+    for want, f in zip(oracle, futures):
+        assert (f.result(timeout=300) == want).all()
     for n in model.decode_stats:
         assert telemetry.counter("serving." + n).value > before[n]
+    # the experts are too narrow for the grouped kernel: every program
+    # says so, and every dispatch is counted as a fallback
+    assert meta["grouped"] == {p: {
+        "impl": "xla", "sites": 2 * model.kinds.count("E"),
+        "reason": "K=16 and N=24 must be multiples of 128"}
+        for p in ("prefill-s8", "prefill-s16", "decode-w16")}
+    assert telemetry.counter("kernels.grouped_matmul").value == by_kernel
+    assert telemetry.counter("kernels.grouped_fallback").value \
+        == fell_back + dispatches() > fell_back
     gp = mx.deploy.load_generator(prefix)
     assert (gp.generate(prompts[1], 9)
             == model.greedy_decode(params, prompts[1], 9)).all()
@@ -475,39 +566,21 @@ def test_hybrid_programs_carry_scopes_as_metadata_only(program, scopes,
         assert "mx.kv_gather" not in text and "mx_paged_attention" in text
 
 
-def _without_kernel_locations(text):
-    """A lowered program's text with each Mosaic kernel's serialized body
-    (which carries source lines) replaced by its MLIR text without
-    locations."""
-    import base64
-    import re
-    from jax._src.interpreters import mlir as jax_mlir
-    from jax._src.lib import tpu
-    from jax._src.lib.mlir import ir
-
-    def body(match):
-        ctx = jax_mlir.make_ir_context()
-        tpu.register_dialect(ctx)
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            module = ir.Module.parse(base64.b64decode(match.group(1)))
-            return module.operation.get_asm(enable_debug_info=False)
-
-    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
-
-
-#: sha256 of the programs' text at the commit before the pool left
-#: ``TransformerLM``'s layer scan (17977ce): ``layer`` as a Python int
-#: lowers as it did
+#: sha256 of the programs' text at the commit that gave the held experts
+#: a grouped product of the program's own (PR 30; until then the hashes
+#: were 17977ce's, the commit before the pool left ``TransformerLM``'s
+#: layer scan).  At these widths (16 and 24) the products take the
+#: ``lax.ragged_dot`` twin, which now slices each product back to its
+#: rows: the paged kernel's call and everything else lower as they did
 PARENT_PROGRAMS = {
     ("decode", "chip"):
-        "d27f24381eee0635149f7b65805a0b16e445307b6b6217c55725408dc26b986e",
+        "cce4daf4add2508a188559de30bfe5f5ad0e3267c900c59610f7191188df71f0",
     ("decode", "interpreter"):
-        "f2f7696e2a15761f4c8bce959645af64fd0aa2dea06aea4ac7bce7018f4c33df",
+        "32d5f5b27b224791b731153b5568f2925d3a1783dd06d0d2601282bb790ef326",
     ("prefill", "chip"):
-        "1f54d69affbf15ea120a198a040baa8843f511680e3b3239c0ce01e23228a7ba",
+        "0e423326dc42450523b624e77bc736b3f807b31f55fe99698f36e26e7dd98ff6",
     ("prefill", "interpreter"):
-        "d79f34b3cd8c1a7606751f2eabc202b81ca664b61e4366026887dad62602a675",
+        "4189b73884b8383098fe0cc6d981e46e6aeb400e816fc8e344bc1e6b9437b2e7",
 }
 
 
@@ -517,7 +590,8 @@ def test_hybrid_programs_are_the_parents(program, lowered_for, monkeypatch):
     a static ``layer``: its decode and prefill programs on the kernel's
     route — lowered for the TPU as the cell's artifact is (the Mosaic
     kernel's body compared without its locations), and for the
-    interpreter — are, locations aside, byte for byte the parent's."""
+    interpreter — are, locations aside, byte for byte the pinned ones
+    (a change that means to move them pins them again and says why)."""
     import hashlib
     from mxnet_tpu import rtc
     mx.config.set("kernels.enabled", True)
@@ -543,6 +617,7 @@ def test_hybrid_programs_are_the_parents(program, lowered_for, monkeypatch):
         mx.config.unset("kernels.enabled")
     if program == "decode":
         assert ("tpu_custom_call" in text) == (lowered_for == "chip")
-    text = _without_kernel_locations(text)
+    from _util import without_kernel_locations
+    text = without_kernel_locations(text)
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PARENT_PROGRAMS[program, lowered_for]

@@ -28,6 +28,9 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import config, kernels, profiler, telemetry
 from mxnet_tpu.ops.pallas_kernels import (_row_block, flash_attention,
+                                          grouped_col_tile,
+                                          grouped_row_tile,
+                                          pallas_grouped_matmul,
                                           pallas_paged_attention,
                                           pallas_row_softmax)
 from mxnet_tpu.parallel.ring_attention import attention as xla_attention
@@ -487,6 +490,159 @@ def test_paged_kernel_the_compiler_refuses_raises(monkeypatch, in_trace):
     assert [r["impl"] for r in routes] == ["paged"]
 
 
+# ------------------------------------------------------- grouped product
+#: rows, groups: a decode step's regime (groups of a few rows, several a
+#: tile), a prefill's (groups of tens to hundreds) and fewer rows than a
+#: tile
+GROUPED_SHAPES = {"decode": (352, 16), "prefill": (1408, 8), "short": (40, 4)}
+
+
+def _group_sizes(pattern, m, e, tm):
+    """``(sizes [e], rows)`` for a named pattern at row tile ``tm``;
+    ``rows`` may be off every tile (the kernel pads them)."""
+    rng = np.random.RandomState(len(pattern))
+    rest = [0] * (e - 4)
+    if pattern == "one_group_alone":
+        return [0, 0, min(tm + 3, m), 0] + rest, m
+    if pattern == "one_row_groups":
+        return [1] * e, m
+    if pattern == "larger_than_a_tile":     # (or all of the only tile)
+        return [2, max(tm + 5, m // 2) if tm < m else m - 3, 0, 1] + rest, m
+    if pattern == "ends_on_a_tile_edge":    # (or with the rows)
+        return ([tm, 0, tm - 3, 3] if 2 * tm <= m
+                else [m - 3, 0, 3, 0]) + rest, m
+    if pattern == "trailing_rows_of_no_group":
+        return list(rng.randint(0, m // (4 * e) + 1, size=e)), m
+    if pattern == "rows_off_every_tile":
+        sizes = list(rng.randint(1, m // e, size=e))
+        return sizes, sum(sizes) + 3
+    if pattern == "no_group_has_a_row":
+        return [0] * e, m
+    assert pattern == "every_row_in_a_group"
+    return list(rng.multinomial(m, np.ones(e) / e)), m
+
+
+GROUP_PATTERNS = ["one_group_alone", "one_row_groups", "larger_than_a_tile",
+                  "ends_on_a_tile_edge", "trailing_rows_of_no_group",
+                  "rows_off_every_tile", "no_group_has_a_row",
+                  "every_row_in_a_group"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", list(GROUPED_SHAPES))
+@pytest.mark.parametrize("pattern", GROUP_PATTERNS)
+def test_grouped_kernel_is_ragged_dot(pattern, shape, dtype):
+    """The Pallas grouped product (interpreter) against ``lax.ragged_dot``
+    on every row that lies in a group, over the ways groups can lie on
+    the row tiles; the first product's epilogue and cast ride along."""
+    m, e = GROUPED_SHAPES[shape]
+    k, n = 256, 384
+    tm = grouped_row_tile(m, jnp.dtype(dtype).itemsize)
+    sizes, m = _group_sizes(pattern, m, e, tm)
+    assert sum(sizes) <= m
+    rng = np.random.RandomState(7)
+    rows = jnp.asarray(rng.randn(m, k), dtype)
+    w = jnp.asarray(rng.randn(e, k, n) / 16, dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = jax.lax.ragged_dot(rows, w, sizes,
+                              preferred_element_type=jnp.float32)
+    got = jax.jit(pallas_grouped_matmul)(rows, w, sizes)
+    held = int(sizes.sum())
+    assert got.shape == (m, n) and got.dtype == jnp.float32
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got)[:held],
+                               np.asarray(want)[:held], rtol=tol, atol=tol)
+    relu2 = lambda a: jnp.square(jax.nn.relu(a))  # noqa: E731
+    got = jax.jit(lambda *a: pallas_grouped_matmul(
+        *a, epilogue=relu2, out_dtype=dtype))(rows, w, sizes)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[:held],
+        np.asarray(relu2(want).astype(dtype), np.float32)[:held],
+        rtol=4 * tol, atol=4 * tol)
+
+
+def test_grouped_tiles_follow_from_the_shapes():
+    """The row tile is a function of the rows and the dtype's packing
+    (one MXU pass, or every row where there are fewer), the column tile
+    of the weights' shape and the VMEM budget; nothing else is asked."""
+    assert grouped_row_tile(2816, 2) == 128            # 128 rows x top 22
+    assert grouped_row_tile(22528, 2) == 128           # a 1,024 prefill
+    assert grouped_row_tile(40, 2) == 48               # whole packed
+    assert grouped_row_tile(40, 4) == 40               # sublane tiles
+    assert grouped_row_tile(3, 2) == 16
+    assert grouped_col_tile(1024, 2688, 2) == 896      # 1.75 of 2 MiB
+    assert grouped_col_tile(2688, 1024, 2) == 256
+    assert grouped_col_tile(2688, 1024, 4) == 128
+    config.set("kernels.vmem_budget", 64 * 1024)
+    assert grouped_col_tile(2688, 1024, 2) is None
+    assert grouped_col_tile(128, 1024, 2) == 256
+
+
+def _grouped_case(k=128, n=256, dtype=jnp.float32):
+    rng = np.random.RandomState(5)
+    sizes = jnp.asarray([3, 0, 9, 1], jnp.int32)
+    return (jnp.asarray(rng.randn(40, k), dtype),
+            jnp.asarray(rng.randn(4, k, n) / 8, dtype), sizes)
+
+
+def test_grouped_routing_explicit_vs_default():
+    """Explicit tier-on routes the grouped product through the Pallas
+    kernel (counter + route record); the graduated default on the
+    interpreter backend and the tier switched off take ``lax.ragged_dot``,
+    each with its reason: the same answer every way, epilogue and cast
+    included."""
+    case = _grouped_case()
+    telemetry.reset()
+    outs = []
+    for setting, impl, reason in ((True, "grouped", None),
+                                  (None, "xla", "interpreted"),
+                                  (False, "xla", "tier off")):
+        if setting is None:
+            config.unset("kernels.enabled")       # graduated default
+        else:
+            config.set("kernels.enabled", setting)    # explicit source
+        with kernels.record_grouped_routes() as routes, \
+                kernels.record_paged_routes() as paged:
+            outs.append(np.asarray(jax.jit(
+                lambda *a: kernels.grouped_matmul(
+                    *a, epilogue=jnp.tanh, out_dtype=jnp.bfloat16))(*case),
+                np.float32)[:13])
+        assert routes == [{"impl": impl, "reason": reason}] and not paged
+        assert telemetry.counter("kernels.grouped_matmul").value == 1
+    assert telemetry.counter("kernels.gated_fallback").value == 1
+    assert telemetry.counter("kernels.grouped_fallback").value == 0
+    assert np.array_equal(outs[1], outs[2])
+    np.testing.assert_allclose(outs[0], outs[1], rtol=1e-2, atol=1e-2)
+
+
+def test_grouped_unsupported_reasons():
+    rows, w, sizes = _grouped_case()
+    reason = kernels.grouped_unsupported_reason
+    assert reason(rows, w, sizes) is None
+    assert reason(*_grouped_case(dtype=jnp.bfloat16)) is None
+    assert "rank" in reason(rows[None], w, sizes)
+    assert "groups" in reason(rows, w, sizes[:3])
+    assert "groups" in reason(rows[:, :64], w, sizes)
+    assert "multiples of 128" in reason(*_grouped_case(k=96))
+    assert "multiples of 128" in reason(*_grouped_case(n=200))
+    assert "float32" in reason(rows.astype(jnp.bfloat16), w, sizes)
+    assert "float32" in reason(*_grouped_case(dtype=jnp.float16))
+    config.set("kernels.vmem_budget", 1024)
+    assert "vmem budget" in reason(rows, w, sizes)
+    config.set("kernels.vmem_budget", VMEM_DEFAULT)
+    # tier on, shape refused: ragged_dot serves it and the reason is kept
+    config.set("kernels.enabled", True)
+    telemetry.reset()
+    with kernels.record_grouped_routes() as routes:
+        got = kernels.grouped_matmul(*_grouped_case(k=96))
+    assert routes[0]["impl"] == "xla" and "128" in routes[0]["reason"]
+    assert telemetry.counter("kernels.grouped_fallback").value == 1
+    assert telemetry.counter("kernels.grouped_matmul").value == 0
+    assert got.shape == (40, 256) and got.dtype == jnp.float32
+
+
 # ----------------------------------------------------------- row softmax
 def test_pallas_softmax_grads_match_jnp():
     """The op is differentiable now — its custom_vjp reuses the saved
@@ -528,6 +684,14 @@ def _paged_site(infeasible=False):
             "kernels.paged_attention", "kernels.paged_fallback", q)
 
 
+def _grouped_site(infeasible=False):
+    rows, w, sizes = _grouped_case(k=96 if infeasible else 128)
+    return (lambda: kernels.grouped_matmul(rows, w, sizes),
+            "pallas_grouped_matmul", ("mxnet_tpu.kernels",
+                                      "_grouped_matmul_xla"),
+            "kernels.grouped_matmul", "kernels.grouped_fallback", rows)
+
+
 #: knob setting, backend, shape -> implementation and the counter that moves
 ROUTES = {
     "default-interpreter": (None, True, False, "xla",
@@ -542,17 +706,18 @@ ROUTES = {
 
 
 @pytest.mark.parametrize("case", list(ROUTES))
-@pytest.mark.parametrize("site", ["attention", "paged"])
+@pytest.mark.parametrize("site", ["attention", "paged", "grouped"])
 def test_route_is_static(site, case, monkeypatch, tmp_path):
     """Which implementation a routed site takes follows from the knob,
     whether the backend interprets Pallas, and the shape — through the
-    one rule both sites ask, with nothing timed, read or written."""
+    one rule every site asks, with nothing timed, read or written."""
     import builtins
     import importlib
     from mxnet_tpu import rtc, runtime
     knob, interpreted, infeasible, impl, moved = ROUTES[case]
     call, kernel_name, (twin_mod, twin_name), kernel_ctr, fallback_ctr, q = \
-        {"attention": _attention_site, "paged": _paged_site}[site](infeasible)
+        {"attention": _attention_site, "paged": _paged_site,
+         "grouped": _grouped_site}[site](infeasible)
     if moved == "fallback":
         moved = fallback_ctr
     elif impl == "kernel":
@@ -602,10 +767,92 @@ def test_route_is_static(site, case, monkeypatch, tmp_path):
     assert asked == [1]
     counters = {n: telemetry.counter(n).value for n in (
         "kernels.flash_attention", "kernels.paged_attention",
-        "kernels.fallback", "kernels.paged_fallback",
+        "kernels.grouped_matmul", "kernels.fallback",
+        "kernels.paged_fallback", "kernels.grouped_fallback",
         "kernels.gated_fallback")}
     assert counters == {n: int(n == moved) for n in counters}
     assert opened == [] and not os.path.exists(cache)
+
+
+# ------------------- programs without a grouped product did not move
+def _lowered_for_the_chip(which, monkeypatch):
+    """The StableHLO of one program, lowered for the TPU with the kernels
+    off the interpreter and the tier on: a dense transformer's decode
+    step and prefill, and an ``SPMDTrainer`` step of the model zoo's
+    ResNet-18 (trace and lowering only: nothing compiles or runs)."""
+    from mxnet_tpu import perf, rtc
+    monkeypatch.setattr(rtc, "interpret_mode", lambda: False)
+    config.set("kernels.enabled", True)
+    i32 = jnp.int32
+    if which == "resnet_step":
+        from mxnet_tpu import gluon
+        from mxnet_tpu.gluon.model_zoo import vision
+        from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+
+        class Lowered(Exception):
+            pass
+
+        def capture(self, args):
+            raise Lowered(self.fn.trace(*args).lower(
+                lowering_platforms=("tpu",)).as_text())
+
+        monkeypatch.setattr(perf.PerfProgram, "_capture", capture)
+        mx.random.seed(7)
+        net = vision.resnet18_v1(classes=10)
+        net.initialize(mx.init.Xavier())
+        tr = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                         {"learning_rate": 0.1, "momentum": 0.9},
+                         mesh=make_mesh({"dp": 1}, jax.devices()[:1]))
+        with pytest.raises(Lowered) as step:
+            tr.step(np.zeros((2, 3, 32, 32), np.float32),
+                    np.zeros((2,), np.float32))
+        return str(step.value)
+    from mxnet_tpu.models.transformer import TransformerLM, \
+        TransformerLMConfig
+    model = TransformerLM(TransformerLMConfig(
+        vocab_size=61, num_layers=2, d_model=32, num_heads=4, d_ff=64,
+        max_len=16, dtype=jnp.float32))
+    params = model.init(jax.random.PRNGKey(0))
+    kv = model.init_kv_pages(8, 4)
+    if which == "lm_decode":
+        traced = jax.jit(lambda p, c, t, pos, tab: model.decode_step(
+            p, c, t, pos, tab, 4)).trace(
+                params, kv, jnp.zeros((4,), i32), jnp.zeros((4,), i32),
+                jnp.zeros((4, 2), i32))
+    else:
+        traced = jax.jit(lambda p, c, t, n, tab: model.prefill(
+            p, c, t, n, tab, 4)).trace(
+                params, kv, jnp.zeros((1, 8), i32), jnp.ones((1,), i32),
+                jnp.zeros((1, 2), i32))
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+#: sha256 of the programs' text at the commit before the grouped product
+#: became a kernel (2468edf, PR 29's): no program without one moved
+PROGRAMS_BEFORE_THE_GROUPED_KERNEL = {
+    "lm_decode":
+        "1f8717d9b861d52e2515828215a9d97d124fcaec4bd152aa61eed2440dfc60b7",
+    "lm_prefill":
+        "26a258ead8526e935eb9880b55bd59455bfc49a309bf8d3f17f5f251957f53a7",
+    "resnet_step":
+        "79d82527531aaeac0242ae610846cefa48f1c7d64522ec2e6fa98d46e168c090",
+}
+
+
+@pytest.mark.parametrize("which", list(PROGRAMS_BEFORE_THE_GROUPED_KERNEL))
+def test_programs_without_a_grouped_product_are_the_parents(which,
+                                                            monkeypatch):
+    """The grouped product's route, counters and export sink reach only
+    programs that hold one: a dense transformer's served programs (the
+    paged kernel inside the decode step) and a ResNet training step
+    lower for the TPU, locations aside, byte for byte as they did."""
+    import hashlib
+    from _util import without_kernel_locations
+    text = without_kernel_locations(_lowered_for_the_chip(which, monkeypatch))
+    assert ("mx_paged_attention" in text) == (which == "lm_decode")
+    assert "mx_grouped_matmul" not in text and "ragged" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_BEFORE_THE_GROUPED_KERNEL[which]
 
 
 # ----------------------------- the update is one program across the tier

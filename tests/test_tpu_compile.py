@@ -165,6 +165,33 @@ def test_paged_attention_compiles_for_the_default_config(compile_for_chip):
     assert _kernel_count(text) == 1
 
 
+# ------------------------------------------------------ grouped product
+# the hybrid cell's shapes (benchmarks/configs/nemotron3_super_ep4.json):
+# 128 held experts of 2,688 in a 1,024-wide latent, top 22 of 128 decode
+# rows or of a 1,024-token prompt
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("k, n", [(1024, 2688), (2688, 1024)],
+                         ids=["first", "second"])
+@pytest.mark.parametrize("rows", [128 * 22, 1024 * 22, 100],
+                         ids=["decode", "prefill1024", "off_tile"])
+def test_grouped_matmul_compiles_at_the_cells_shapes(compile_for_chip, rows,
+                                                     k, n, dtype):
+    """Mosaic takes the grouped product's blocks at the cell's widths:
+    the first product with its ``relu(.)**2`` epilogue and the cast to
+    the activations' dtype, the second in float32, at a decode step's
+    rows, a long prefill's and a row count off every tile."""
+    spec = compile_for_chip.spec
+    first = n > k
+    text = compile_for_chip(
+        lambda x, w, sizes: pk.pallas_grouped_matmul(
+            x, w, sizes,
+            epilogue=(lambda a: jnp.square(jax.nn.relu(a))) if first
+            else None, out_dtype=dtype if first else F32),
+        spec((rows, k), dtype), spec((128, k, n), dtype),
+        spec((128,), jnp.int32))
+    assert _kernel_count(text) == 1 and "mx_grouped_matmul" in text
+
+
 # ------------------------------------------------------------ row kernels
 @pytest.mark.parametrize("shape", [(4096, 1024), (100, 1000)], ids=str)
 def test_row_softmax_forward_and_backward_compile(compile_for_chip, shape):
@@ -388,6 +415,12 @@ def test_hybrid_programs_at_the_cells_sizes_compile_into_the_chip(
     width = sz["decode_widths"][-1]
     assert meta["paged"] == {str(width): {
         "impl": "paged", "reason": None, "quantized": False}}
+    # every program's ten grouped products (two an E block) are the
+    # program's own kernel
+    assert meta["grouped"] == {program: {
+        "impl": "grouped", "reason": None, "sites": 10}
+        for program in ("prefill-s128", "prefill-s1024",
+                        "decode-w%d" % width)}
     spec = compile_for_chip.spec
     on_chip = functools.partial(jax.tree_util.tree_map,
                                 lambda a: spec(a.shape, a.dtype))
@@ -412,12 +445,19 @@ def test_hybrid_programs_at_the_cells_sizes_compile_into_the_chip(
     text = decode.as_text()
     assert text.count("mx_paged_attention") >= 1
     assert "mx.kv_gather" not in text
+    prefill = compiled(
+        "%s-prefill-s1024.stablehlo" % prefix,
+        spec((1, 1024), jnp.int32), spec((1,), jnp.int32),
+        spec((1, -(-1024 // sz["page_tokens"])), jnp.int32),
+        spec((1,), jnp.int32), *sample(1))
+    for program in (text, prefill.as_text()):
+        # the experts' products are the program's kernel, under the scope
+        # that names their device time; XLA's grouped product is gone
+        assert program.count("mx_grouped_matmul") >= 10
+        assert "mx.moe_experts" in program
+        assert "ragged" not in program
     need = []
-    for program in (decode, compiled(
-            "%s-prefill-s1024.stablehlo" % prefix,
-            spec((1, 1024), jnp.int32), spec((1,), jnp.int32),
-            spec((1, -(-1024 // sz["page_tokens"])), jnp.int32),
-            spec((1,), jnp.int32), *sample(1))):
+    for program in (decode, prefill):
         mem = program.memory_analysis()
         cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
         assert mem.alias_size_in_bytes >= cache_bytes       # in place
