@@ -16,6 +16,10 @@ one process; nothing here starts a child once jax is touched):
            period ``HybridLM`` (Mamba-2, held experts, grouped-query
            attention), K/V pages beside per-slot recurrent state, more
            requests than slots, the paged kernel's route counted
+  retention  the third kind of cache row: one power-retention block at
+           toy widths with real lanes (heads of 128), its chunked prefill
+           and its decode update, compiled, against the quadratic form
+           that defines the layer
   kernels  every Pallas kernel on those paths, compiled, against its XLA twin
 
 ``--chips 4`` runs ``device`` and, instead of the phases above, the
@@ -117,6 +121,11 @@ def _sizes(rehearse):
             hybrid_serve=dict(prompts=(4, 9, 20, 30, 7, 13),
                               prompt_buckets=(8, 32), new_tokens=6,
                               decode_batch=4, page=16, kv_pages=32),
+            retention=dict(
+                lm=dict(vocab_size=64, pattern="RF", d_model=64, num_heads=4,
+                        num_kv_heads=2, head_dim=16, mlp_ff=96, chunk=8,
+                        max_len=64),
+                rows=2, prompt=11, steps=3),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
             grouped=(200, 8, 128, 256), softmax=(16, 48), sbr=(16, 40),
             dp_batch=16, lm_batch=4, lm_seq=32)
@@ -143,6 +152,14 @@ def _sizes(rehearse):
                                    300, 90),
                           prompt_buckets=(128, 512), new_tokens=24,
                           decode_batch=8, page=128, kv_pages=64),
+        # one retention block at toy widths with the lanes of real ones
+        # (heads of 128: a state of 8,256 x 128 a K/V head), a prompt off
+        # the chunk's edge
+        retention=dict(
+            lm=dict(vocab_size=1024, pattern="RF", d_model=1024, num_heads=10,
+                    num_kv_heads=2, head_dim=128, mlp_ff=2048, chunk=128,
+                    max_len=512),
+            rows=4, prompt=300, steps=12),
         flash=((8, 12, 1024, 64), (2, 12, 200, 64)), paged_k=(1024, 2048),
         # rows, groups, K, N: a decode step's pairs over a quarter of the
         # hybrid cell's held experts, at their widths
@@ -554,6 +571,63 @@ def phase_serve_hybrid(args, sz):
              "prefix sharing was not refused for a model with state")
 
 
+def phase_retention(args, sz):
+    """The third kind of cache row: a power-retention block's chunked
+    prefill and then its decode update, compiled, the float32 state donated
+    and rewritten in place, against the QUADRATIC form that defines the
+    layer (``A[t,s] = (q_t . k_s)^2 prod g``, ``y = A v / sum A``) computed
+    in float32 at full precision from the same q, k, v and gates."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    rs = sz["retention"]
+    model = HybridLM(HybridLMConfig(**rs["lm"]))
+    lp = model.init(jax.random.PRNGKey(args.seed))["layers"]["00"]
+    B, S, T = rs["rows"], rs["prompt"], rs["steps"]
+    x = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
+                          (B, S + T, model.cfg.d_model), model.cfg.dtype)
+
+    @jax.jit
+    def quadratic(lp, x):
+        positions = jnp.broadcast_to(jnp.arange(S + T), (B, S + T))
+        q, k, v, logg = model._ret_parts(x, lp, positions)
+        with jax.default_matmul_precision("highest"):
+            q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+            cum = jnp.cumsum(logg, axis=1)
+            decay = jnp.exp(jnp.where(
+                jnp.tril(jnp.ones((S + T, S + T), bool))[None, :, :, None],
+                cum[:, :, None] - cum[:, None, :], -jnp.inf))  # [B,t,s,KV]
+            a = jnp.square(jnp.einsum("bthre,bshe->bhrts", q, k)) \
+                * jnp.moveaxis(decay, -1, 1)[:, :, None]
+            y = jnp.einsum("bhrts,bshe->bthre", a, v) \
+                / jnp.moveaxis(a.sum(-1), -1, 1)[..., None]
+        return model._ret_out(y.astype(x.dtype), lp)
+
+    prefill = jax.jit(lambda lp, x: model._ret_sequence(x, lp))
+    step = jax.jit(lambda lp, x, pos, state, z: model._ret_step(
+        x, lp, pos, state, z), donate_argnums=(3, 4))
+    want = np.asarray(quadratic(lp, x), np.float32)
+    out, state, z = prefill(lp, x[:, :S])
+    got = [np.asarray(out, np.float32)]
+    for t in range(S, S + T):
+        out, state, z = step(lp, x[:, t], jnp.full((B,), t, jnp.int32),
+                             state, z)
+        got.append(np.asarray(out, np.float32)[:, None])
+    got = np.concatenate(got, axis=1)
+    err = _scaled_err(got[:, :S], want[:, :S])[1], \
+        _scaled_err(got[:, S:], want[:, S:])[1]
+    # an MXU product rounds its inputs to bf16 whatever their dtype
+    tol = 2.0 ** -6
+    _emit("retention", rows=B, prompt=S, steps=T,
+          state_shape=list(state.shape), scan_err=err[0], update_err=err[1],
+          tolerance=tol)
+    _require(np.isfinite(got).all(), "retention output is not finite")
+    _require(max(err) <= tol, "retention against the quadratic form: "
+             "scan %.3g, update %.3g over %.3g" % (err + (tol,)))
+
+
 def _teacher_forced_gaps(model, params, prompts, served):
     """For each request, one oracle forward over prompt + served tokens:
     per generated position, how far the oracle's logit for the served
@@ -840,7 +914,8 @@ def main(argv=None):
     t0 = time.perf_counter()
     phase_device(args)
     phases = [phase_four_chips] if args.chips == 4 else \
-        [phase_train, phase_serve, phase_serve_hybrid, phase_kernels]
+        [phase_train, phase_serve, phase_serve_hybrid, phase_retention,
+         phase_kernels]
     for phase in phases:
         phase(args, sz)
     import mxnet_tpu as mx

@@ -462,6 +462,10 @@ def export_generation(model, params, prefix, page_size=None,
     program's row b is slot b.  Where the model counts things in a decode
     step (``model.decode_stats``), the int32 counts ride behind the
     tokens in the one array a step returns (``meta["decode_stats"]``).
+    A model none of whose layers attends (``kv_spec()["num_layers"] ==
+    0``: all of its cache is state) gets ONE decode program, at a
+    one-column page table that names no page, and no paged route is
+    recorded for it; the pools it is handed have no layer and no byte.
     A model that says ``symbolic_batch = False`` (its grouped products
     run over token rows, and the shape refinement of a reloaded artifact
     cannot carry a symbolic row count through them) gets concrete batch
@@ -500,15 +504,19 @@ def export_generation(model, params, prefix, page_size=None,
         raise ValueError(
             "prompt_buckets %r must be non-empty and fit max_context %d"
             % (prompt_buckets, max_context))
-    widths = _pow2_family(_math.ceil(max_context / psz))
+    spec = model.kv_spec(quantized=True) if kv_quantized \
+        else model.kv_spec()
+    # a model none of whose layers attends keeps no page: its decode
+    # programs take a one-column table that names none, so the page-table
+    # width says nothing and one program serves every length
+    paged = spec["num_layers"] > 0
+    widths = _pow2_family(_math.ceil(max_context / psz)) if paged else (1,)
     if decode_widths is not None:
         keep = {int(w) for w in decode_widths} | {widths[-1]}
         if keep - set(widths):
             raise ValueError("decode_widths %r are not all of the family "
                              "%r" % (sorted(keep), widths))
         widths = tuple(w for w in widths if w in keep)
-    spec = model.kv_spec(quantized=True) if kv_quantized \
-        else model.kv_spec()
     state_names = tuple(st["name"] for st in spec.get("state", ()))
     stat_names = tuple(getattr(model, "decode_stats", ()))
     v5 = bool(sampling or kv_quantized or decode_batch is not None
@@ -568,7 +576,7 @@ def export_generation(model, params, prefix, page_size=None,
             grouped_routes[program] = dict((refused or grouped)[0],
                                            sites=len(grouped))
         path = "%s-%s.stablehlo" % (prefix, program)
-        if route_key is not None:
+        if route_key is not None and paged:
             # one paged_attention route per scanned stack trace; the scan
             # body compiles once, so one entry describes the whole program
             paged_routes[route_key] = (
@@ -763,6 +771,9 @@ class GenerationPredictor:
         #: the cache's state region (per-slot arrays beside the pages) and
         #: the names of the counts a decode step returns behind its tokens
         self.state = tuple(self.meta["kv"].get("state", ()))
+        #: False for a model none of whose layers attends: the pools have
+        #: no layer, a request needs no page and the tables name none
+        self.paged = int(self.meta["kv"]["num_layers"]) > 0
         self.decode_stats = tuple(self.meta.get("decode_stats", ()))
         #: ``{"layers", "top_k"}`` (of the experts chosen) where every
         #: program also returns what a replay needs, else None
@@ -941,16 +952,17 @@ class GenerationPredictor:
                 "prompt (%d) + max_new_tokens (%d) exceeds max_context "
                 "%d" % (plen, max_new, self.max_context))
         psz = self.page_size
-        need = _math.ceil((plen + max_new) / psz)
+        need = _math.ceil((plen + max_new) / psz) if self.paged else 0
         # a concrete decode_batch pins the decode batch dim: row 0 is
         # the live sequence (and state slot 0), the pad rows run against
         # an all-sentinel table (their writes drop, their outputs are
         # ignored)
         Bd = self.decode_batch or 1
-        kv = self.make_kv(need, Bd if self.state else None)
+        pool = max(need, 1)
+        kv = self.make_kv(pool, Bd if self.state else None)
         slot0 = (jnp.zeros((1,), jnp.int32),) if self.state else ()
         pages = _np.arange(need, dtype=_np.int32)
-        sentinel = need
+        sentinel = pool
         s_bucket = self.prefill_bucket(plen)
         w_s = _math.ceil(s_bucket / psz)
         tokens = _np.zeros((1, s_bucket), _np.int32)
@@ -970,7 +982,7 @@ class GenerationPredictor:
             [float(top_p)] + [1.0] * (Bd - 1), [int(seed)] + [0] * (Bd - 1))
         while len(out) < max_new and (eos_id is None
                                       or out[-1] != int(eos_id)):
-            width = self.decode_width(pos // psz + 1)
+            width = self.decode_width(pos // psz + 1 if self.paged else 1)
             table = _np.full((Bd, width), sentinel, _np.int32)
             table[0, :min(width, need)] = pages[:width]
             toks = _np.zeros((Bd,), _np.int32)

@@ -454,7 +454,9 @@ class GenerationEngine:
                 "artifact's max_context %d"
                 % (self.name, plen, max_new, gp.max_context))
         gp.prefill_bucket(plen)   # raises if no bucket fits
-        need = _math.ceil((plen + max_new) / gp.page_size)
+        # (a model that keeps no page needs none: decode slots alone bound
+        # its admission)
+        need = _math.ceil((plen + max_new) / gp.page_size) if gp.paged else 0
         if need > self.max_need:
             raise ValueError(
                 "model %r: request needs %d KV pages but the pool holds "
@@ -620,7 +622,12 @@ class GenerationEngine:
     def _make_kv(self):
         """The cache, zeroed: pages and, where the model keeps one, the
         state region (so a rebuilt cache carries no request's state)."""
-        return self.predictor.make_kv(self.num_pages, self._state_slots())
+        gp = self.predictor
+        kv = gp.make_kv(self.num_pages, self._state_slots())
+        if gp.state:
+            _telemetry.gauge("serving.state_bytes").set(
+                sum(int(a.nbytes) for a in kv[-len(gp.state):]))
+        return kv
 
     def _release_pages_locked(self, slot):  # mxlint: holds(_cond)
         """Return a slot's pages to the free list — shared-prefix pages

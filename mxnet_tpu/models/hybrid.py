@@ -1,24 +1,35 @@
-"""HybridLM — a decoder whose blocks are each ONE mixer, of three kinds.
+"""HybridLM — a decoder whose blocks are each ONE mixer, of five kinds.
 
 The stack is a pattern string, one letter a block (the ``nemotron_h``
-family's ``hybrid_override_pattern``):
+family's ``hybrid_override_pattern``, and two letters of this module's
+own):
 
   ``M``  Mamba-2 (state-space duality): a gated, depthwise-convolved
          recurrence with a fixed-size state per sequence
   ``E``  a mixture of experts that live in a latent width, chosen by a
          sigmoid-scored top-k router, beside one shared expert
   ``*``  causal softmax attention with fewer K/V heads than query heads
+  ``R``  power retention of degree 2 (Gelada, Buckman et al.,
+         arXiv:2507.04239): attention weights ``(q.k)^2`` under a learned
+         per-K/V-head decay, normalised to sum to one, computed as a
+         recurrence over the symmetric square ``phi(k)`` of the key — a
+         fixed-size state per K/V head that its query heads all read;
+         per-head RMSNorm of q and k, rotary positions
+  ``F``  a gated (SwiGLU) MLP
 
 ``h <- h + Mixer(RMSNorm(h))`` per block, no positional table (the
-recurrence orders the tokens), an output head of its own.  The class has
+recurrences order the tokens; ``R`` rotates q and k by their positions),
+an output head of its own.  The class has
 ``TransformerLM``'s generation protocol (``cfg``, ``init``, ``apply``,
 ``kv_spec``, ``init_kv_pages``, ``prefill``, ``decode_step``,
 ``greedy_decode``) and so goes through ``deploy.export_generation`` and
 the generation server unchanged; what differs is the cache it describes:
 K/V pages for the layers that attend, and per decode slot a float32
-recurrent state and a convolution tail for every ``M`` layer
+recurrent state and a convolution tail for every ``M`` layer and a
+float32 retention state and its normaliser for every ``R`` layer
 (``kv_spec()["state"]``).  A prefill leaves the prompt's final state in
-the slot it is told; a decode step advances every slot's.
+the slot it is told; a decode step advances every slot's.  A pattern
+without ``*`` keeps no page at all (``kv_spec()["num_layers"] == 0``).
 
 One chip's share of an expert-parallel deployment is a configuration, not
 another code path: ``experts_held`` / ``expert_offset`` say which routed
@@ -34,7 +45,10 @@ The blocks are walked in Python, so no pool or state rides a scan.
 """
 from __future__ import annotations
 
+import functools
 import math
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +60,7 @@ from .transformer import TransformerLM, _norm
 
 __all__ = ["HybridLMConfig", "HybridLM"]
 
-KINDS = "ME*"
+KINDS = "ME*RF"
 #: what ``parallel.moe.dropless_experts`` counts, summed over the E blocks
 _STATS = ("pairs", "experts_hit", "max_load")
 
@@ -58,7 +72,8 @@ class HybridLMConfig:
                  conv_kernel=4, chunk=128, num_experts=16, top_k=2,
                  moe_latent=128, expert_ff=256, shared_ff=512,
                  route_scale=1.0, experts_held=None, expert_offset=0,
-                 max_len=2048, dtype=jnp.bfloat16, eps=1e-5, depth=None):
+                 max_len=2048, dtype=jnp.bfloat16, eps=1e-5, depth=None,
+                 rope_theta=1e6, mlp_ff=1024):
         if not pattern or set(pattern) - set(KINDS):
             raise ValueError("pattern %r: one of %r per block"
                              % (pattern, KINDS))
@@ -102,6 +117,13 @@ class HybridLMConfig:
         #: blocks in the whole stack, where ``pattern`` is one pipeline
         #: stage of a deeper one: ``init`` scales by it
         self.depth = len(pattern) if depth is None else int(depth)
+        #: ``R`` blocks: the rotary base, and the width of one K/V head's
+        #: state (the upper triangle of a head_dim x head_dim square: the
+        #: retention's degree is 2)
+        self.rope_theta = float(rope_theta)
+        self.ret_width = head_dim * (head_dim + 1) // 2
+        #: ``F`` blocks: the gated MLP's inner width
+        self.mlp_ff = mlp_ff
 
 
 def _normal(key, shape, std, dtype):
@@ -135,11 +157,9 @@ class HybridLM:
         self.cfg = config
         self.kinds = tuple(config.pattern)
         self.names = tuple("%02d" % i for i in range(len(self.kinds)))
-        # which attention layer (pool index) / state layer a block is
+        # which attention layer (pool index) a block is
         self.attn_index = {n: i for i, n in enumerate(
             n for n, k in zip(self.names, self.kinds) if k == "*")}
-        self.ssm_index = {n: i for i, n in enumerate(
-            n for n, k in zip(self.names, self.kinds) if k == "M")}
 
     # -------------------------------------------------------------- params
     def init(self, key):
@@ -181,6 +201,22 @@ class HybridLM:
                       "a_log": jnp.log(1.0 + 15.0 * u[1]),
                       "d": jnp.ones((Hm,), jnp.float32),
                       "norm": ones(I), "w_out": mk((I, D), I, out)}
+            elif kind == "R":
+                H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+                # decays log-uniform in [0.9, 0.999], where trained gates
+                # lie, stored through the inverse of the sigmoid
+                u = jax.random.uniform(next(keys), (KV,), jnp.float32)
+                g = jnp.exp(math.log(0.9) + u * (math.log(0.999)
+                                                 - math.log(0.9)))
+                lp = {"ln": ones(D), "wq": mk((D, H, Dh), D),
+                      "wk": mk((D, KV, Dh), D), "wv": mk((D, KV, Dh), D),
+                      "wg": mk((D, KV), D), "bg": jnp.log(g) - jnp.log1p(-g),
+                      "qn": ones(Dh), "kn": ones(Dh),
+                      "wo": mk((H, Dh, D), H * Dh, out)}
+            elif kind == "F":
+                F = cfg.mlp_ff
+                lp = {"ln": ones(D), "w_gate": mk((D, F), D),
+                      "w_up": mk((D, F), D), "w_down": mk((F, D), F, out)}
             else:
                 E, Eh, Z = cfg.num_experts, cfg.experts_held, cfg.moe_latent
                 F, Fs = cfg.expert_ff, cfg.shared_ff
@@ -331,6 +367,93 @@ class HybridLM:
                         axis=-1)                             # [B,G,R,P]
         return self._ssm_finish(Y, X, z, lp), state, window[:, 1:]
 
+    # ------------------------------------------------------ power retention
+    def _ret_parts(self, x, lp, positions):
+        """RMSNorm + projections, per-head RMSNorm of q and k, rotary at
+        ``positions``: x [B,S,D], positions [B,S] -> q [B,S,KVH,R,Dh]
+        (query head ``j*R + r`` reads K/V head j), k and v [B,S,KVH,Dh] in
+        x's dtype, log-decays [B,S,KVH] float32 (one gate a K/V head)."""
+        cfg = self.cfg
+        KV, Dh = cfg.num_kv_heads, cfg.head_dim
+        f32 = jnp.float32
+        with jax.named_scope("mx.qkv"):
+            h = _norm(x, lp["ln"], cfg.eps)
+            q, k, v = (jnp.einsum("bsd,dhe->bshe", h, lp[w],
+                                  preferred_element_type=f32).astype(x.dtype)
+                       for w in ("wq", "wk", "wv"))
+            logg = jax.nn.log_sigmoid(
+                jnp.einsum("bsd,dh->bsh", h, lp["wg"],
+                           preferred_element_type=f32) + lp["bg"])
+        with jax.named_scope("mx.rope"):
+            q = _rope(_norm(q, lp["qn"], cfg.eps), positions, cfg.rope_theta)
+            k = _rope(_norm(k, lp["kn"], cfg.eps), positions, cfg.rope_theta)
+        return q.reshape(q.shape[:2] + (KV, -1, Dh)), k, v, logg
+
+    def _ret_out(self, y, lp):
+        """The read-outs [..., KVH, R, Dh] through the output projection."""
+        with jax.named_scope("mx.attn_out"):
+            y = y.reshape(y.shape[:-3] + (-1, y.shape[-1]))
+            return jnp.einsum("...he,hed->...d", y, lp["wo"],
+                              preferred_element_type=jnp.float32
+                              ).astype(y.dtype)
+
+    def _ret_sequence(self, x, lp, lengths=None):
+        """A whole sequence from the zero state, in chunks: x [B,S,D] ->
+        (out [B,S,D], final state [B,KVH,N,Dh] f32, final normaliser
+        [B,KVH,N] f32).  Positions at or past ``lengths`` have a zero key
+        and a gate of one: they neither feed nor decay the state."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        Q = min(cfg.chunk, S)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        q, k, v, logg = self._ret_parts(x, lp, positions)
+        with jax.named_scope("mx.retention_scan"):
+            if lengths is not None:
+                real = positions < lengths[:, None]
+                k = jnp.where(real[..., None, None], k, 0)
+                logg = jnp.where(real[..., None], logg, 0.0)
+            pad = -S % Q
+            if pad:
+                q, k, v, logg = (
+                    jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                    for a in (q, k, v, logg))
+            y, state, z = _retention_scan(q, k, v, logg, Q)
+            y = y[:, :S].astype(x.dtype)
+        return self._ret_out(y, lp), state, z
+
+    def _ret_step(self, x, lp, positions, state, z):
+        """One token a row: x [B,D], positions [B], state [B,KVH,N,Dh] and
+        z [B,KVH,N] f32 -> (out [B,D], new state, new z): ``S <- g S +
+        phi(k) v^T`` in float32, then the query heads of each K/V head
+        read the new state (a sum over its rows, not an MXU product: the
+        state is not rounded on the way)."""
+        f32 = jnp.float32
+        q, k, v, logg = (a[:, 0] for a in self._ret_parts(
+            x[:, None], lp, positions[:, None]))
+        with jax.named_scope("mx.retention_update"):
+            pq, pk = _phi(q), _phi(k)            # [B,KVH,R,N], [B,KVH,N]
+            g = jnp.exp(logg)
+            state = g[..., None, None] * state \
+                + pk[..., None] * v.astype(f32)[:, :, None, :]
+            z = g[..., None] * z + pk
+            num = jnp.sum(pq[..., None] * state[:, :, None], axis=3)
+            den = jnp.sum(pq * z[:, :, None], axis=-1)
+            y = (num / den[..., None]).astype(x.dtype)   # [B,KVH,R,Dh]
+        return self._ret_out(y, lp), state, z
+
+    # ------------------------------------------------------------ gated MLP
+    def _mlp(self, x, lp):
+        """``W_down(silu(W_gate m) * (W_up m))``, m = RMSNorm(x)."""
+        with jax.named_scope("mx.mlp"):
+            h = _norm(x, lp["ln"], self.cfg.eps)
+
+            def dot(a, w):
+                return jnp.einsum("...d,df->...f", a, w,
+                                  preferred_element_type=jnp.float32)
+
+            mid = jax.nn.silu(dot(h, lp["w_gate"])) * dot(h, lp["w_up"])
+            return dot(mid.astype(x.dtype), lp["w_down"]).astype(x.dtype)
+
     # ------------------------------------------------- mixture of experts
     def _moe(self, x, lp, rows_valid=None):
         """x [T, D] -> (out [T, D], the held experts' routing stats, the
@@ -368,10 +491,11 @@ class HybridLM:
                           preferred_element_type=jnp.float32)
 
     def _run(self, params, x, lengths=None, kv_sink=None):
-        """The blocks over whole sequences x [B,S,D].  Returns (x, {block:
-        (state, tail)} of the ``M`` blocks, summed expert stats, the
-        experts chosen [E blocks, B, S, top_k]); ``kv_sink(block, k, v)``
-        sees every attention block's K/V."""
+        """The blocks over whole sequences x [B,S,D].  Returns (x, the
+        final per-sequence state of the ``M`` and ``R`` blocks by its name
+        in the cache (:meth:`kv_spec`), summed expert stats, the experts
+        chosen [E blocks, B, S, top_k]); ``kv_sink(block, k, v)`` sees
+        every attention block's K/V."""
         B, S, D = x.shape
         valid = None if lengths is None else \
             (jnp.arange(S)[None, :] < lengths[:, None]).reshape(-1)
@@ -385,9 +509,16 @@ class HybridLM:
                 x = x + self._attn_out(self._attend(q, k, v), lp)
             elif kind == "M":
                 with jax.named_scope("mx.ssm"):
-                    out, state, tail = self._ssm_sequence(x, lp, lengths)
-                    states[name] = (state, tail)
+                    out, states["ssm" + name], states["conv" + name] = \
+                        self._ssm_sequence(x, lp, lengths)
                     x = x + out
+            elif kind == "R":
+                with jax.named_scope("mx.retention"):
+                    out, states["ret" + name], states["retz" + name] = \
+                        self._ret_sequence(x, lp, lengths)
+                    x = x + out
+            elif kind == "F":
+                x = x + self._mlp(x, lp)
             else:
                 with jax.named_scope("mx.moe"):
                     out, st, chosen = self._moe(x.reshape(B * S, D), lp,
@@ -403,19 +534,33 @@ class HybridLM:
         K/V pages of the layers that attend (``row_width`` = K/V heads x
         head size) and, under ``state``, the arrays every decode slot
         holds a row of — per ``M`` block the float32 recurrent state and
-        the convolution tail — as ``{"name", "shape" (of one slot's row),
-        "dtype"}``; an array is ``[slots, *shape]``."""
+        the convolution tail, per ``R`` block the float32 retention state
+        (``ret_width`` = head_dim (head_dim + 1) / 2 rows a K/V head: the
+        exact upper triangle of the key's square, row-major, off-diagonal
+        entries weighted sqrt 2) and its normaliser — as ``{"name",
+        "shape" (of one slot's row), "dtype"}``; an array is ``[slots,
+        *shape]``.  ``num_layers`` counts the layers that attend: 0 is a
+        model that keeps no page."""
         if quantized:
             raise ValueError("HybridLM keeps no int8 K/V pages")
         cfg = self.cfg
         G, R = cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
+        KV, N = cfg.num_kv_heads, cfg.ret_width
         state = []
-        for name in self.ssm_index:
-            state.append({"name": "ssm" + name, "dtype": "float32",
-                          "shape": [G, R, cfg.ssm_head_dim, cfg.ssm_state]})
-            state.append({"name": "conv" + name,
-                          "dtype": jnp.dtype(cfg.dtype).name,
-                          "shape": [cfg.conv_kernel - 1, cfg.conv_width]})
+        for name, kind in zip(self.names, self.kinds):
+            if kind == "M":
+                state.append({"name": "ssm" + name, "dtype": "float32",
+                              "shape": [G, R, cfg.ssm_head_dim,
+                                        cfg.ssm_state]})
+                state.append({"name": "conv" + name,
+                              "dtype": jnp.dtype(cfg.dtype).name,
+                              "shape": [cfg.conv_kernel - 1,
+                                        cfg.conv_width]})
+            elif kind == "R":
+                state.append({"name": "ret" + name, "dtype": "float32",
+                              "shape": [KV, N, cfg.head_dim]})
+                state.append({"name": "retz" + name, "dtype": "float32",
+                              "shape": [KV, N]})
         return {"num_layers": len(self.attn_index),
                 "num_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
                 "row_width": cfg.num_kv_heads * cfg.head_dim,
@@ -450,11 +595,11 @@ class HybridLM:
                 return_replay=False):
         """Whole prompts: tokens [B,S] (padded past ``lengths``), page_table
         [B,W].  Every attention block's K/V goes into the pages as in
-        ``TransformerLM.prefill``; every ``M`` block runs from the zero
-        state in the block form and leaves the prompt's final state and
-        convolution tail in row ``slots[b]`` (default: row b) of its state
-        arrays, whatever that row held.  Padded positions take no step
-        and route to no expert.  Returns ``(new_kv, next_token [B])``;
+        ``TransformerLM.prefill``; every ``M`` and ``R`` block runs from
+        the zero state in its chunked form and leaves the prompt's final
+        state (and convolution tail, or normaliser) in row ``slots[b]``
+        (default: row b) of its state arrays, whatever that row held.
+        Padded positions take no step and route to no expert.  Returns ``(new_kv, next_token [B])``;
         with ``return_replay`` (:attr:`replay`) two more come last: the
         experts every position chose ``[E blocks, B, S, top_k]`` int32 and
         the next token's log-probability ``[B]`` float32."""
@@ -478,10 +623,8 @@ class HybridLM:
         x = params["embed"][tokens].astype(self.cfg.dtype)
         x, states, _, routed = self._run(params, x, lengths, sink)
         rows = jnp.arange(B, dtype=jnp.int32) if slots is None else slots
-        for name, (state, tail) in states.items():
-            nkv["ssm" + name] = kv["ssm" + name].at[rows].set(state)
-            nkv["conv" + name] = kv["conv" + name].at[rows].set(
-                tail.astype(kv["conv" + name].dtype))
+        for key, state in states.items():
+            nkv[key] = kv[key].at[rows].set(state.astype(kv[key].dtype))
         last = jnp.take_along_axis(
             x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32),
             axis=1)[:, 0]
@@ -496,7 +639,8 @@ class HybridLM:
         """One token for every decode slot: row b of the batch IS slot b
         of the state arrays.  Attention blocks append K/V and attend
         through ``kernels.paged_attention`` (handed the whole pool and the
-        layer's index); ``M`` blocks advance their state in float32.  A
+        layer's index); ``M`` and ``R`` blocks advance their state in
+        float32, ``R`` blocks rotating q and k by ``positions``.  A
         slot with no request (position 0, as the engine passes it) routes
         to no expert; its state row is advanced like any other and is
         overwritten by the next prefill into it.  With ``return_stats``
@@ -535,6 +679,14 @@ class HybridLM:
                         x, lp, kv["ssm" + name], kv["conv" + name])
                     nkv["conv" + name] = tail
                     x = x + out
+            elif kind == "R":
+                with jax.named_scope("mx.retention"):
+                    out, nkv["ret" + name], nkv["retz" + name] = \
+                        self._ret_step(x, lp, positions, kv["ret" + name],
+                                       kv["retz" + name])
+                    x = x + out
+            elif kind == "F":
+                x = x + self._mlp(x, lp)
             else:
                 with jax.named_scope("mx.moe"):
                     out, st, chosen = self._moe(x, lp, active)
@@ -567,6 +719,111 @@ def _stack_routed(routed, lead):
 def _sum_stats(stats):
     zero = jnp.zeros((), jnp.int32)
     return {n: sum((s[n] for s in stats), zero) for n in _STATS}
+
+
+def _rope(x, positions, theta):
+    """Rotary positions over the whole head, rotate-half: x [B,S,heads,Dh],
+    positions [B,S] -> x's shape and dtype, rotated in float32."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions[..., None, None].astype(jnp.float32) * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _triangle(Dh):
+    """The upper triangle ``a <= b`` of a Dh x Dh square in row-major
+    order: the one-hot picks of a and of b ``[Dh, N]`` bool, and the
+    entries' weights ``[N]`` float32 (1 on the diagonal, sqrt 2 off it)."""
+    ia, ib = np.triu_indices(Dh)
+    rows = np.arange(Dh)[:, None]
+    return rows == ia[None, :], rows == ib[None, :], \
+        np.where(ia == ib, 1.0, math.sqrt(2.0)).astype(np.float32)
+
+
+def _phi(u, dtype=jnp.float32):
+    """The symmetric square of the last axis, u [..., Dh] -> [..., Dh (Dh +
+    1) / 2] in ``dtype``: ``u_a u_b`` over the upper triangle
+    (:func:`_triangle`), off-diagonal entries times sqrt 2, so that ``phi(q)
+    . phi(k) = (q . k)^2`` exactly.  The two factors are picked by one-hot
+    products (exact in u's own dtype: one term a sum), which the MXU does
+    and a gather along the minor axis does not; their product is float32,
+    rounded once where ``dtype`` is narrower."""
+    first, second, weight = _triangle(u.shape[-1])
+    a, b = (jnp.einsum("...e,en->...n", u, pick.astype(u.dtype)
+                       ).astype(jnp.float32) for pick in (first, second))
+    return (a * b * weight).astype(dtype)
+
+
+def _retention_scan(q, k, v, logg, Q):
+    """Power retention of degree 2 from an empty state, in chunks of ``Q``:
+    ``S_t = g_t S_{t-1} + phi(k_t) v_t^T``, ``z_t = g_t z_{t-1} +
+    phi(k_t)``, ``y_t = phi(q_t)^T S_t / phi(q_t)^T z_t``.  Inside a chunk
+    the masked, decayed ``(q k^T)^2`` product (no expansion); between
+    chunks the state and its normaliser, carried in float32 by a scan that
+    expands one chunk's ``phi`` at a time.  ``_ssd``'s recurrence with ``B
+    = phi(k)``, ``C = phi(q)``, written beside it: the in-chunk product is
+    another, several query heads read one state, and every chunk's
+    ``phi(q)`` at once would be gigabytes.
+
+    q [B,S,KVH,R,Dh]; k, v [B,S,KVH,Dh]; logg [B,S,KVH] f32 (<= 0; 0 with
+    a zero key where nothing is to happen).  S a multiple of Q.  Returns
+    ``(y [B,S,KVH,R,Dh] f32, final state [B,KVH,N,Dh] f32, final
+    normaliser [B,KVH,N] f32)``."""
+    B, S, KV, R, Dh = q.shape
+    c = S // Q
+    f32 = jnp.float32
+    # the query side rides as [B, c, KVH, Q, R, ...]: a K/V head's Q x R
+    # queries are then the rows of one plain batched product
+    qc = jnp.moveaxis(q.reshape(B, c, Q, KV, R, Dh), 3, 2)
+    kc = k.reshape(B, c, Q, KV, Dh)
+    vc = v.reshape(B, c, Q, KV, Dh)
+    cum = jnp.moveaxis(jnp.cumsum(logg.reshape(B, c, Q, KV), axis=2), -1, 2)
+    # inside a chunk: position i hears j <= i, decayed by what lies between
+    qk = jnp.einsum("bchire,bcjhe->bchirj", qc, kc,
+                    preferred_element_type=f32)
+    seg = cum[..., :, None] - cum[..., None, :]              # [B,c,KVH,i,j]
+    low = jnp.tril(jnp.ones((Q, Q), bool))
+    mix = (jnp.square(qk) * jnp.exp(jnp.where(low, seg, -jnp.inf)
+                                    )[:, :, :, :, None]).astype(v.dtype)
+    num = jnp.einsum("bchirj,bcjhe->bchire", mix, vc,
+                     preferred_element_type=f32)
+    den = jnp.sum(mix.astype(f32), axis=-1)                  # [B,c,KVH,i,R]
+
+    def chunk(carry, xs):
+        state, z = carry
+        qx, kx, vx, into, to_end, whole = xs
+        # (phi(q) in the activations' dtype, 169 MB a chunk in float32 at
+        # the published widths: the MXU would round it on the way in, and
+        # what a prefill costs is the bytes of these expansions)
+        pq = _phi(qx.reshape(B, KV, Q * R, Dh), qx.dtype)    # [B,KVH,QR,N]
+        pk = _phi(kx) * to_end[..., None]                    # [B,Q,KVH,N]
+        # what the state this chunk enters with adds at each position
+        n_in = jnp.einsum("bhmn,bhne->bhme", pq, state.astype(qx.dtype),
+                          preferred_element_type=f32)
+        d_in = jnp.sum(pq * z[:, :, None], axis=-1)
+        # ... and what the chunk adds to the state by its end
+        state = whole[..., None, None] * state + jnp.einsum(
+            "bjhn,bjhe->bhne", pk.astype(vx.dtype), vx,
+            preferred_element_type=f32)
+        z = whole[..., None] * z + jnp.sum(pk, axis=1)
+        return (state, z), (
+            n_in.reshape(B, KV, Q, R, Dh) * into[..., None, None],
+            d_in.reshape(B, KV, Q, R) * into[..., None])
+
+    N = Dh * (Dh + 1) // 2
+    (state, z), (n_in, d_in) = lax.scan(
+        chunk, (jnp.zeros((B, KV, N, Dh), f32), jnp.zeros((B, KV, N), f32)),
+        tuple(jnp.moveaxis(a, 1, 0) for a in (
+            qc, kc, vc, jnp.exp(cum),
+            jnp.moveaxis(jnp.exp(cum[..., -1:] - cum), 2, -1),
+            jnp.exp(cum[..., -1]))))
+    y = (num + jnp.moveaxis(n_in, 0, 1)) \
+        / (den + jnp.moveaxis(d_in, 0, 1))[..., None]
+    return jnp.moveaxis(y, 2, 3).reshape(B, S, KV, R, Dh), state, z
 
 
 def _ssd(X, Bm, Cm, step, rate, Q):

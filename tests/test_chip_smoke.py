@@ -71,8 +71,8 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert _no_success_line(lines)
     assert [line.get("phase") for line in lines] == [
-        "device", "train", "serve", "serve_hybrid", "kernels", "done",
-        None]
+        "device", "train", "serve", "serve_hybrid", "retention", "kernels",
+        "done", None]
     by = {line["phase"]: line for line in lines[:-1]}
     assert by["train"]["compiles_after_warmup"] == 0
     assert by["train"]["timing_end"]["block_until_ready_waits"] is True
@@ -84,6 +84,10 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert hybrid["counters"]["kernels.grouped_matmul"] \
         == hybrid["decode_iterations"] + len(hybrid["prompt_lens"])
     assert set(hybrid["grouped_routes"].values()) == {"grouped"}
+    retention = by["retention"]
+    assert retention["state_shape"] == [2, 2, 136, 16]
+    assert max(retention["scan_err"], retention["update_err"]) \
+        <= retention["tolerance"]
     serve = by["serve"]
     assert serve["counters"]["kernels.paged_attention"] == \
         serve["decode_iterations"] > 0
@@ -97,7 +101,7 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
         name.split("/")[0] for name in by["kernels"]["kernels"]}
     assert lines[-1]["rehearsed"] == ["phase_train", "phase_serve",
                                       "phase_serve_hybrid",
-                                      "phase_kernels"]
+                                      "phase_retention", "phase_kernels"]
     assert lines[-1]["device"]["platform"] == "cpu"
 
 

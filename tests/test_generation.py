@@ -619,5 +619,7 @@ def test_check_generation_smoke():
     assert report["sampling"]["distinct_of_8"] >= 2
     assert report["int8_kv"]["logit_drift"] <= \
         report["int8_kv"]["error_budget"]
-    assert report["elapsed_s"] < (40.0 if (os.cpu_count() or 1) >= 2
-                                  else 90.0), report
+    # time is judged by the tool's own CPU seconds (its budget, held
+    # inside it): a wall clock here measures the other xdist workers
+    assert 0 < report["cpu_s"] < (90.0 if (os.cpu_count() or 1) >= 2
+                                  else 180.0), report

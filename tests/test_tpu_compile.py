@@ -464,3 +464,87 @@ def test_hybrid_programs_at_the_cells_sizes_compile_into_the_chip(
         need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
     assert max(need) < 15.75 * 2 ** 30, need
     assert min(need) > 0.6 * 15.75 * 2 ** 30    # and the chip is filled
+
+
+# ------------------------------------------- the retention decoder's cell
+def test_retention_programs_at_the_cells_sizes_compile_into_the_chip(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``benchmarks/configs/brumby_14b_pp5.json`` as its driver exports it
+    — published widths, eight ``RF`` layers, the whole vocabulary, 16
+    decode slots of 272.6 MB of float32 state each and NO K/V page —
+    lowered for the TPU, reloaded and compiled for the described chip: one
+    decode program at a one-column table with no paged site, every state
+    array rewritten in place (all 4.06 GiB aliased), and weights, state and
+    the largest prefill bucket's temporaries inside one chip's 15.75 GiB.
+    (Weights are shapes only: nothing is made.)"""
+    import json
+    import os
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "brumby_14b_pp5.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "offline_reasoning_s16.json")) as f:
+        traffic = json.load(f)
+    sz = cell["sizes"]
+    bucket = traffic["prompt_buckets"][-1]
+    model = HybridLM(HybridLMConfig(dtype=BF16, **sz["lm"]))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    with jax.default_matmul_precision(None):
+        deploy.export_generation(
+            model, shapes, prefix, sampling=True,
+            decode_batch=sz["decode_batch"], prompt_buckets=[bucket],
+            max_context=traffic["max_context"],
+            page_size=sz["page_tokens"], include_params=False,
+            replay=sz["replay"])
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    assert meta["decode_widths"] == [1] and meta["paged"] == {}
+    assert meta["kv"]["num_layers"] == 0 and meta["grouped"] == {}
+    assert meta["replay"] == {"layers": 0, "top_k": 0}
+    spec = compile_for_chip.spec
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows = sz["decode_batch"]
+    cache = on_chip(deploy._kv_pool_specs(
+        meta["kv"], cell["knobs"]["serving.kv_pages"], rows))
+
+    def sample(b):
+        return (spec((b,), F32), spec((b,), jnp.int32), spec((b,), F32),
+                spec((b, 2), jnp.uint32))
+
+    def compiled(path, *specs):
+        with open(path, "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return jax.jit(exp.call, donate_argnums=(1,)).lower(
+            on_chip(shapes), cache, *specs).compile()
+
+    decode = compiled("%s-decode-w1.stablehlo" % prefix,
+                      spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                      spec((rows, 1), jnp.int32), *sample(rows))
+    # tokens, three counts (all zero: no expert), log-probabilities
+    assert decode.out_info[1].shape == (2 * rows + 3,)
+    text = decode.as_text()
+    assert "mx.retention_update" in text and "mx.rope" in text
+    assert "mx_paged_attention" not in text
+    prefill = compiled(
+        "%s-prefill-s%d.stablehlo" % (prefix, bucket),
+        spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
+        spec((1, -(-bucket // sz["page_tokens"])), jnp.int32),
+        spec((1,), jnp.int32), *sample(1))
+    assert "mx.retention_scan" in prefill.as_text()
+    cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
+    assert round(cache_bytes / 2 ** 30, 2) == 4.06
+    need = []
+    for program in (decode, prefill):
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes       # in place
+        need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    assert max(need) < 15.75 * 2 ** 30, need
+    assert min(need) > 0.7 * 15.75 * 2 ** 30    # and the chip is filled

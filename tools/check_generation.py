@@ -54,7 +54,11 @@ VOCAB = 89
 # budget calibrated for the normal >=2-core CI box doubles there.
 # (Six decode programs here hold the paged kernel, and the interpreter's
 # program for its page copies takes ~1.5 s to build each.)
-BUDGET_S = 60.0 if (os.cpu_count() or 1) >= 2 else 120.0
+# The budget is held on the process's own CPU seconds (all threads; ~36 on
+# an idle 8-core box, where the wall clock reads ~21): the driver runs the
+# wrapper test beside five other xdist workers, and a wall clock then
+# measures the neighbours.
+BUDGET_S = 90.0 if (os.cpu_count() or 1) >= 2 else 180.0
 PAGE_SIZE = 8
 MAX_CONTEXT = 16
 #: (prompt_len, max_new) mix: ragged lengths across two prefill buckets,
@@ -64,7 +68,7 @@ PROMPT_BUCKETS = (4, 8)
 
 
 def main():
-    t_main = time.perf_counter()
+    t_main, c_main = time.perf_counter(), time.process_time()
     import numpy as np
     result = {"ok": False}
     tmpdir = tempfile.mkdtemp(prefix="mxtpu_generation_")
@@ -292,9 +296,12 @@ def main():
         ttft = telemetry.timer("serving.ttft_ms").stats()
         result["ttft_ms_p50"] = round(ttft["p50"], 3)
         result["elapsed_s"] = round(time.perf_counter() - t_main, 3)
-        assert result["elapsed_s"] < BUDGET_S, \
-            "smoke exceeded the %.0fs budget: %.3fs" \
-            % (BUDGET_S, result["elapsed_s"])
+        # the process's own CPU seconds, all threads: what the smoke cost
+        # whatever else shares the machine
+        result["cpu_s"] = round(time.process_time() - c_main, 3)
+        assert result["cpu_s"] < BUDGET_S, \
+            "smoke exceeded the %.0fs cpu budget: %.3fs" \
+            % (BUDGET_S, result["cpu_s"])
         result["ok"] = True
     except Exception as exc:  # noqa: BLE001 — the JSON line IS the report
         result["error"] = "%s: %s" % (type(exc).__name__, exc)
