@@ -114,6 +114,7 @@ from . import io as _io
 from . import obs as _obs
 from . import telemetry as _telemetry
 from . import tracing as _tracing
+from .models.transformer import SAMPLE_TIERS, sample_tier
 from .serving import (CircuitOpenError, DeadlineExceededError,
                       ServerOverloadedError, ServingError,
                       _access_outcome)
@@ -812,10 +813,10 @@ class GenerationEngine:
         s_bucket = gp.prefill_bucket(req.plen)
         with _tracing.span("engine.prefill", cat="serving",
                            request_id=req.request_id, bucket=s_bucket,
-                           prompt_len=req.plen):
-            return self._prefill_one(req, s_bucket)
+                           prompt_len=req.plen) as sp:
+            return self._prefill_one(req, s_bucket, sp)
 
-    def _prefill_one(self, req, s_bucket):
+    def _prefill_one(self, req, s_bucket, sp):
         gp = self.predictor
         slot_idx = next(i for i, s in enumerate(self._slots)
                         if s is not None and s.req is req)
@@ -861,7 +862,7 @@ class GenerationEngine:
                 for i, done in enumerate(populated):
                     if done and i < w_s:
                         write_table[0, i] = sentinel
-        temp, tk, tp, keys = self._sample_arrays([(0, slot)], 1)
+        temp, tk, tp, keys = self._sample_arrays([(0, slot)], 1, sp)
         # where the cache has a state region, the prompt's final state is
         # left in this request's slot, over whatever the slot held
         where = (_np.asarray([slot_idx], _np.int32),) if gp.state else ()
@@ -959,7 +960,7 @@ class GenerationEngine:
                 positions[i] = s.pos
                 k = min(width, len(s.pages))
                 table[i, :k] = s.pages[:k]
-            temp, tk, tp, keys = self._sample_arrays(active, B)
+            temp, tk, tp, keys = self._sample_arrays(active, B, sp)
         # tokens the rows hold against token slots the routed program
         # reads: each active row's own pages where the kernel reads them
         # in place, the whole table's window where the twin gathers it
@@ -1035,11 +1036,14 @@ class GenerationEngine:
         return flat.astype(_np.int16).reshape(shape["layers"], tokens,
                                               shape["top_k"])
 
-    def _sample_arrays(self, active, B):
+    def _sample_arrays(self, active, B, sp):
         """Per-row sampling operands for a dispatch: active rows carry
         their request's temperature / top-k / top-p / PRNG key words;
         padding rows ride greedy with a zero key (their output is
-        discarded, but every operand must still be well-formed)."""
+        discarded, but every operand must still be well-formed).  The
+        tier of work they ask of the program's next-token choice — the
+        branch it takes on the device, by the same predicate — is counted
+        (``serving.sample_tier.*``) and set on the dispatch's span."""
         temp = _np.zeros((B,), _np.float32)
         tk = _np.zeros((B,), _np.int32)
         tp = _np.ones((B,), _np.float32)
@@ -1050,6 +1054,9 @@ class GenerationEngine:
             tk[i] = req.top_k
             tp[i] = req.top_p
             keys[i] = req.key_words
+        tier = SAMPLE_TIERS[int(sample_tier(temp, tk, tp))]
+        _telemetry.counter("serving.sample_tier." + tier).inc()
+        sp.set(sample_tier=tier)
         return temp, tk, tp, keys
 
     def _count_tokens(self, n):

@@ -30,6 +30,21 @@ from ..parallel.ring_attention import ring_self_attention_sharded
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
 
+#: what a dispatch's next-token choice runs, by :func:`sample_tier`
+SAMPLE_TIERS = ("argmax", "gumbel", "sorted")
+
+
+def sample_tier(temperature, top_k, top_p):
+    """Index into ``SAMPLE_TIERS`` of the work a batch's sampling controls
+    ask for: 0 where no row samples (``temperature`` 0 throughout), 1
+    where rows sample and none of THEM truncates, 2 where a sampled row
+    has ``top_k > 0`` or ``top_p < 1``.  Written once for the device
+    (``jnp`` arrays: the branch ``TransformerLM._choose`` takes) and the
+    host (``numpy``: what the engine counts), so the two cannot drift."""
+    sampled = temperature > 0
+    truncates = sampled & ((top_k > 0) | (top_p < 1))
+    return sampled.any().astype("int32") + truncates.any().astype("int32")
+
 
 class TransformerLMConfig:
     def __init__(self, vocab_size=32000, num_layers=12, d_model=768,
@@ -344,27 +359,42 @@ class TransformerLM:
         top_p = sample["top_p"].astype(jnp.float32)             # [B]
         keys = sample["key"].astype(jnp.uint32)                 # [B, 2]
         V = logits.shape[-1]
-        safe_t = jnp.where(temp > 0, temp, 1.0)
-        scaled = logits / safe_t[:, None]
-        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-        # top-k: the kth-largest scaled logit is the row threshold
-        k_idx = jnp.clip(top_k - 1, 0, V - 1)
-        kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-        keep = jnp.where((top_k > 0)[:, None], scaled >= kth, True)
-        # top-p (nucleus): keep the smallest sorted prefix whose
-        # probability mass reaches p — token i survives while the mass
-        # BEFORE it is < p, so the first token always survives
-        probs = jax.nn.softmax(sorted_desc, axis=-1)
-        csum = jnp.cumsum(probs, axis=-1)
-        in_nucleus = (csum - probs) < top_p[:, None]
-        thr = jnp.min(jnp.where(in_nucleus, sorted_desc, jnp.inf),
-                      axis=-1, keepdims=True)
-        keep &= jnp.where((top_p < 1.0)[:, None], scaled >= thr, True)
-        masked = jnp.where(keep, scaled, -jnp.inf)
-        gum = jax.vmap(lambda kr, pos: jax.random.gumbel(
-            jax.random.fold_in(kr, pos), (V,), jnp.float32))(
-                keys, positions.astype(jnp.uint32))
-        choice = jnp.argmax(masked + gum, axis=-1).astype(jnp.int32)
+
+        def scale():
+            return logits / jnp.where(temp > 0, temp, 1.0)[:, None]
+
+        def draw(masked):
+            gum = jax.vmap(lambda kr, pos: jax.random.gumbel(
+                jax.random.fold_in(kr, pos), (V,), jnp.float32))(
+                    keys, positions.astype(jnp.uint32))
+            return jnp.argmax(masked + gum, axis=-1).astype(jnp.int32)
+
+        def truncated():
+            scaled = scale()
+            # (lax.sort is the operation jnp.sort lowers to, without the
+            # jit of its own that drops the scope's name)
+            sorted_desc = lax.sort(scaled, dimension=-1)[:, ::-1]
+            # top-k: the kth-largest scaled logit is the row threshold
+            k_idx = jnp.clip(top_k - 1, 0, V - 1)
+            kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+            keep = jnp.where((top_k > 0)[:, None], scaled >= kth, True)
+            # top-p (nucleus): keep the smallest sorted prefix whose
+            # probability mass reaches p — token i survives while the mass
+            # BEFORE it is < p, so the first token always survives
+            probs = jax.nn.softmax(sorted_desc, axis=-1)
+            csum = jnp.cumsum(probs, axis=-1)
+            in_nucleus = (csum - probs) < top_p[:, None]
+            thr = jnp.min(jnp.where(in_nucleus, sorted_desc, jnp.inf),
+                          axis=-1, keepdims=True)
+            keep &= jnp.where((top_p < 1.0)[:, None], scaled >= thr, True)
+            return draw(jnp.where(keep, scaled, -jnp.inf))
+
+        # the batch pays for the tier its own controls ask for (SAMPLE_TIERS):
+        # no sampled row, the arg-max alone; none of them truncating, no
+        # sort either (every token is kept, so the mask is the identity)
+        choice = lax.switch(sample_tier(temp, top_k, top_p),
+                            (lambda: greedy, lambda: draw(scale()),
+                             truncated))
         return jnp.where(temp > 0, choice, greedy)
 
     def _scan_layers_over_pools(self, body, x, params, kv):
