@@ -79,7 +79,15 @@ Format history (``meta["format_version"]``):
       same cache pytree behind the pools; such an artifact's prefill
       programs take the slot to leave the prompt's state in, its batch
       dims are concrete, and ``meta["decode_stats"]`` names the counts a
-      decode step returns behind its tokens.
+      decode step returns behind its tokens.  The PAGES region is K and V
+      pools unless the model names its own (``meta["kv"]["pools"]``): a
+      model of latent attention keeps ONE pool, ``kv``, whose row is a
+      token's latent beside its rotary key and whose pages hold their
+      tokens on the lanes (``meta["kv"]["page_layout"] == "lanes"``: a
+      page is ``[row_width, page_size]``); :func:`kv_pool_names` and
+      ``_kv_pool_specs`` build the cache from that description, and the
+      decode route recorded in ``meta["paged"]`` is then the latent
+      kernel's (``impl`` "latent").
 """
 from __future__ import annotations
 
@@ -91,7 +99,7 @@ import numpy as _np
 
 __all__ = ["export_model", "load_model", "StableHLOPredictor",
            "export_generation", "load_generator", "GenerationPredictor",
-           "FORMAT_VERSION", "GENERATE_FORMAT_VERSION",
+           "kv_pool_names", "FORMAT_VERSION", "GENERATE_FORMAT_VERSION",
            "SAMPLING_FORMAT_VERSION"]
 
 FORMAT_VERSION = 2
@@ -390,27 +398,46 @@ _KV_KEYS = ("k", "v")
 _KV_KEYS_QUANT = ("k", "v", "k_scale", "v_scale")
 
 
+def kv_pool_names(kv):
+    """The names of the cache arrays a ``kv_spec()`` / ``meta["kv"]`` dict
+    describes, in the order every program takes and returns them: its page
+    pools (``kv["pools"]``; K and V where the description names none, with
+    their scale pools where it is quantized), then its state region's."""
+    pools = tuple(kv.get("pools", _KV_KEYS))
+    if kv.get("quantized"):
+        pools = _KV_KEYS_QUANT
+    return pools + tuple(st["name"] for st in kv.get("state", ()))
+
+
 def _kv_pool_specs(kv, num_pages, slots=None):
-    """ShapeDtypeStructs of the cache a ``meta["kv"]`` dict describes.
-    Its PAGES region, in ``_KV_KEYS`` order: K and V ``[L, num_pages,
-    page_size, row_width]`` over the ``L`` layers that attend (a page is
-    one lane-exact, contiguous block on the device; ``row_width`` is K/V
-    heads x head size), plus the ``[L, num_pages, page_size, H]`` f32
-    scale pools of an int8 pool.  Then its STATE region, if the model
-    keeps one (``kv["state"]``: ``{"name", "shape", "dtype"}`` each): one
-    ``[slots, *shape]`` array apiece, a row per decode slot.
-    ``num_pages`` and ``slots`` may be symbolic dimensions."""
+    """ShapeDtypeStructs of the cache a ``meta["kv"]`` dict describes, in
+    :func:`kv_pool_names` order.
+    Its PAGES region: one pool a name in ``kv["pools"]`` (K and V where
+    there is none), ``[L, num_pages, page_size, row_width]`` over the ``L``
+    layers that attend (a page is one lane-exact, contiguous block on the
+    device; ``row_width`` is K/V heads x head size) or, where
+    ``kv["page_layout"]`` is ``"lanes"``, ``[L, num_pages, row_width,
+    page_size]`` (a page's tokens on the lanes: latent pages, whose row
+    width is no multiple of the lanes), plus the ``[L, num_pages,
+    page_size, H]`` f32 scale pools of an int8 pool.  Then its STATE
+    region, if the model keeps one (``kv["state"]``: ``{"name", "shape",
+    "dtype"}`` each): one ``[slots, *shape]`` array apiece, a row per
+    decode slot.  ``num_pages`` and ``slots`` may be symbolic
+    dimensions."""
     import jax
     import jax.numpy as jnp
     rows = (kv["num_layers"], num_pages, kv["page_size"])
     wide = rows + (kv["row_width"],)
+    if kv.get("page_layout") == "lanes":
+        wide = rows[:2] + (kv["row_width"], kv["page_size"])
     if kv.get("quantized"):
         return (jax.ShapeDtypeStruct(wide, jnp.int8),
                 jax.ShapeDtypeStruct(wide, jnp.int8),
                 jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32),
                 jax.ShapeDtypeStruct(rows + (kv["num_heads"],), jnp.float32))
     dt = jnp.dtype(kv["dtype"])
-    return (jax.ShapeDtypeStruct(wide, dt), jax.ShapeDtypeStruct(wide, dt)) \
+    return tuple(jax.ShapeDtypeStruct(wide, dt)
+                 for _ in kv.get("pools", _KV_KEYS)) \
         + tuple(jax.ShapeDtypeStruct((slots,) + tuple(st["shape"]),
                                      jnp.dtype(st["dtype"]))
                 for st in kv.get("state", ()))
@@ -520,13 +547,13 @@ def export_generation(model, params, prefix, page_size=None,
     state_names = tuple(st["name"] for st in spec.get("state", ()))
     stat_names = tuple(getattr(model, "decode_stats", ()))
     v5 = bool(sampling or kv_quantized or decode_batch is not None
-              or state_names)
+              or state_names or "pools" in spec)
     if decode_batch is not None:
         decode_batch = int(decode_batch)
         if decode_batch < 1:
             raise ValueError("decode_batch must be >= 1, got %d"
                              % decode_batch)
-    kv_keys = (_KV_KEYS_QUANT if kv_quantized else _KV_KEYS) + state_names
+    kv_keys = kv_pool_names(spec)
     if replay and not getattr(model, "replay", False):
         raise ValueError("%s's programs do not say what a replay needs"
                          % type(model).__name__)
@@ -778,9 +805,7 @@ class GenerationPredictor:
         #: ``{"layers", "top_k"}`` (of the experts chosen) where every
         #: program also returns what a replay needs, else None
         self.replay = self.meta.get("replay")
-        self._kv_keys = (_KV_KEYS_QUANT if self.kv_quantized
-                         else _KV_KEYS) \
-            + tuple(st["name"] for st in self.state)
+        self._kv_keys = kv_pool_names(self.meta["kv"])
         self._prefill_exp = {}
         self._decode_exp = {}
         for s_bucket in self.prompt_buckets:

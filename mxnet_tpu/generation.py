@@ -255,6 +255,8 @@ class GenerationEngine:
             raise ServingError(
                 "model %r: serving.kv_pages=%d cannot hold one page"
                 % (name, self.num_pages))
+        #: a model of latent pages (one pool, the latent site's counters)
+        self._latent = predictor.meta["kv"].get("page_layout") == "lanes"
         self._share = bool(_config.get("serving.shared_prefix"))
         if self._share and predictor.state:
             # a shared page skips the prefill that would have built the
@@ -966,11 +968,15 @@ class GenerationEngine:
         # in place, the whole table's window where the twin gathers it
         psz = gp.page_size
         route = gp.paged_routes.get(str(width))
-        if route is not None and route.get("impl") == "paged":
+        if route is not None and route.get("impl") in ("paged", "latent"):
             window = sum(-(-(s.pos + 1) // psz) for _, s in active) * psz
         else:
             window = B * width * psz
         sp.set(held_tokens=int(positions.sum()), window_tokens=window)
+        if self._latent:
+            # a model of latent pages: did this iteration take the kernel
+            sp.set(latent_kernel=int(route is not None
+                                     and route.get("impl") == "latent"))
         if gp.state:
             sp.set(state_rows=len(active))
             _telemetry.gauge("serving.state_slots").set(len(active))
@@ -1012,10 +1018,17 @@ class GenerationEngine:
             # serve-side mirror of the export-time routing verdict: every
             # decode iteration that ran through the Pallas paged kernel
             # (or fell back while the kernel tier was on) is counted
-            if route.get("impl") == "paged":
-                _telemetry.counter("kernels.paged_attention").inc()
+            # (a model of latent pages has the latent site's counters)
+            took, fell_back = (
+                _telemetry.counter("kernels.latent_paged"),
+                _telemetry.counter("kernels.latent_fallback")) \
+                if self._latent else (
+                _telemetry.counter("kernels.paged_attention"),
+                _telemetry.counter("kernels.paged_fallback"))
+            if route.get("impl") in ("paged", "latent"):
+                took.inc()
             elif _kernels_enabled():
-                _telemetry.counter("kernels.paged_fallback").inc()
+                fell_back.inc()
         self._count_grouped_route("decode-w%d" % width)
         with _tracing.span("engine.decode.emit", cat="serving") as emit:
             self._count_tokens(len(active))

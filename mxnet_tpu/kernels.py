@@ -1,9 +1,9 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  The three routed sites
-(:func:`attention`, :func:`paged_attention`, :func:`grouped_matmul`) ask
-one rule
+this module alone decides WHEN they run.  The four routed sites
+(:func:`attention`, :func:`paged_attention`,
+:func:`latent_paged_attention`, :func:`grouped_matmul`) ask one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -15,12 +15,15 @@ time and nothing else — nothing is timed, persisted or remembered:
    (``kernels.gated_fallback``); an explicit on still takes the kernel
    there, which is how the parity tests run it;
 3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
-   :func:`paged_unsupported_reason`, :func:`grouped_unsupported_reason`)
-   → the XLA lowering (``kernels.fallback`` / ``kernels.paged_fallback``
-   / ``kernels.grouped_fallback``), never an error;
+   :func:`paged_unsupported_reason`, :func:`latent_unsupported_reason`,
+   :func:`grouped_unsupported_reason`) → the XLA lowering
+   (``kernels.fallback`` / ``kernels.paged_fallback`` /
+   ``kernels.latent_fallback`` / ``kernels.grouped_fallback``), never an
+   error;
 4. else the kernel (``kernels.flash_attention`` /
-   ``kernels.paged_attention`` / ``kernels.grouped_matmul``), at block
-   sizes that are constants or functions of the shapes.
+   ``kernels.paged_attention`` / ``kernels.latent_paged`` /
+   ``kernels.grouped_matmul``), at block sizes that are constants or
+   functions of the shapes.
 
 The decision is trace-time python, so a jitted program contains one
 path only; a knob change retraces (``config.epoch()`` in the program
@@ -29,6 +32,7 @@ cache keys, which moves when a knob's value or its source does).
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,15 +40,19 @@ from jax import lax
 
 from . import config as _config
 from . import telemetry as _telemetry
-from .ops.pallas_kernels import (flash_attention, grouped_col_tile,
-                                 pallas_grouped_matmul,
+from .ops.pallas_kernels import (flash_attention, flash_attention_tiled,
+                                 grouped_col_tile, pallas_grouped_matmul,
+                                 pallas_latent_paged_attention,
                                  pallas_paged_attention)
 
-__all__ = ["enabled", "attention", "paged_attention", "grouped_matmul",
-           "flash_unsupported_reason", "paged_unsupported_reason",
+__all__ = ["enabled", "attention", "paged_attention",
+           "latent_paged_attention", "grouped_matmul",
+           "flash_unsupported_reason", "tiled_unsupported_reason",
+           "paged_unsupported_reason", "latent_unsupported_reason",
            "grouped_unsupported_reason", "record_paged_routes",
            "record_grouped_routes", "pallas_dynamic_shapes",
-           "flash_attention", "pallas_paged_attention",
+           "flash_attention", "flash_attention_tiled",
+           "pallas_paged_attention", "pallas_latent_paged_attention",
            "pallas_grouped_matmul"]
 
 # one-row VMEM feasibility: a q block keeps its head's full K and V
@@ -112,21 +120,84 @@ def flash_unsupported_reason(q, k, v, causal):
     return None
 
 
+def tiled_unsupported_reason(q, k, v, causal):
+    """Why the K/V-tiled flash kernel can NOT take this call, or None if
+    it can.  It is asked only after :func:`flash_unsupported_reason` has
+    refused, and takes what that kernel has no form for: value rows of
+    another width than the query/key rows (at any length: it keeps no
+    head's K/V resident).  Equal widths stay the resident kernel's or the
+    XLA lowering's, as they were."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        return "rank != 4 (got q%s k%s v%s)" % (q.ndim, k.ndim, v.ndim)
+    if q.shape[3] == v.shape[3]:
+        return "value rows as wide as the keys' (%d)" % q.shape[3]
+    if not all(isinstance(d, int)
+               for d in tuple(q.shape) + tuple(k.shape) + tuple(v.shape)):
+        return "symbolic shape (q%s kv%s)" % (q.shape, k.shape)
+    if k.shape[:3] != v.shape[:3] or q.shape[:2] != k.shape[:2] \
+            or q.shape[3] != k.shape[3]:
+        return "q%s k%s v%s do not share batch, heads, K/V length and " \
+            "query/key width" % (q.shape, k.shape, v.shape)
+    if causal and q.shape[2] != k.shape[2]:
+        return "causal needs Sq == Skv, got %d vs %d" % (
+            q.shape[2], k.shape[2])
+    if not (q.dtype == k.dtype == v.dtype) \
+            or q.dtype not in (jnp.float32, jnp.bfloat16):
+        return "operands %s/%s/%s, want all float32 or all bfloat16" % (
+            q.dtype, k.dtype, v.dtype)
+    if max(q.shape[3], v.shape[3]) > _MAX_HEAD_DIM:
+        return "head dim %d > %d" % (max(q.shape[3], v.shape[3]),
+                                     _MAX_HEAD_DIM)
+    if q.shape[2] % 8 or k.shape[2] % 8:
+        return "lengths %d/%d are not multiples of 8" % (
+            q.shape[2], k.shape[2])
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _tiled_attention(causal, scale):
+    """The tiled kernel as a differentiable function: its backward is the
+    XLA lowering's (the kernel has none of its own; what routes here is a
+    prefill)."""
+    from .parallel.ring_attention import attention as _xla_attention
+
+    @jax.custom_vjp
+    def f(q, k, v):
+        return flash_attention_tiled(q, k, v, causal=causal, scale=scale)
+
+    def bwd(res, do):
+        return jax.vjp(functools.partial(_xla_attention, causal=causal,
+                                         scale=scale), *res)[1](do)
+
+    f.defvjp(lambda q, k, v: (f(q, k, v), (q, k, v)), bwd)
+    return f
+
+
 def attention(q, k, v, causal=False, scale=None):
     """Dot-product attention with kernel routing (the module docstring's
     rule): the Pallas flash kernel where the tier is on and the shape
     qualifies (``kernels.flash_attention``), at the kernel's own default
-    ``block_q=128`` — the one value every benchmark cell has run; else
-    the plain XLA lowering (``parallel.ring_attention.attention``) — tier
-    off, the default knob on an interpreted backend
-    (``kernels.gated_fallback``), or a shape the kernel cannot take
-    (``kernels.fallback``)."""
+    ``block_q=128`` — the one value every benchmark cell has run; where
+    the value rows have another width than the keys' and the K/V-tiled
+    kernel can take the call (:func:`tiled_unsupported_reason`) that
+    kernel (``kernels.flash_attention_tiled``); else the plain XLA
+    lowering (``parallel.ring_attention.attention``) — tier off, the
+    default knob on an interpreted backend (``kernels.gated_fallback``),
+    or a shape neither kernel can take (``kernels.fallback``)."""
     from .parallel.ring_attention import attention as _xla_attention
     if enabled():
         q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
+    # (None where the resident kernel takes the call, else the tiled
+    # kernel's verdict: None again where that one does)
     reason = _route_reason(
-        lambda: flash_unsupported_reason(q, k, v, causal),
+        lambda: flash_unsupported_reason(q, k, v, causal)
+        and tiled_unsupported_reason(q, k, v, causal),
         _telemetry.counter("kernels.fallback"))
+    if reason is None \
+            and flash_unsupported_reason(q, k, v, causal) is not None:
+        _telemetry.counter("kernels.flash_attention_tiled").inc()
+        return _tiled_attention(bool(causal), None if scale is None
+                                else float(scale))(q, k, v)
     if reason is None:
         _telemetry.counter("kernels.flash_attention").inc()
         return flash_attention(q, k, v, causal=causal, scale=scale)
@@ -222,6 +293,8 @@ def pallas_dynamic_shapes():
 # kernels.grouped_matmul / grouped_fallback per dispatch without
 # re-tracing (the program is AOT; trace-time counters fire at export).
 _ROUTE_SINKS = {"paged": [], "grouped": []}
+# (a latent site's route rides in the paged sink: a model keeps one kind
+# of page, so a decode program has one kind of paged site)
 
 
 @contextlib.contextmanager
@@ -389,6 +462,126 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
                                 v_scale=v_scale, layer=layer)
 
 
+# ------------------------------------------------ latent paged attention
+def latent_unsupported_reason(q, pages, page_table, lengths, value_width,
+                              layer=None):
+    """Why the Pallas latent-attention kernel can NOT take this decode
+    call, or None if it can.  Trace-time shape/dtype checks only.  A
+    non-None reason routes to the XLA twin (``kernels.latent_fallback``)
+    and is surfaced in the export route sink
+    (:func:`record_paged_routes`)."""
+    rank = 3 if layer is None else 4     # [P, width, psz], or [L, ...]
+    if q.ndim != 3 or pages.ndim != rank:
+        return "rank: q%s pages %s, want 3 and %d" % (
+            q.ndim, pages.ndim, rank)
+    if page_table.ndim != 2 or lengths.ndim != 1:
+        return "rank: page_table%s lengths%s, want 2 and 1" % (
+            page_table.ndim, lengths.ndim)
+    fixed = tuple(q.shape) + tuple(pages.shape[-2:]) \
+        + tuple(page_table.shape) + tuple(lengths.shape)
+    if not all(isinstance(d, int) for d in fixed):
+        return "symbolic shape (q%s table%s)" % (q.shape, page_table.shape)
+    if not isinstance(pages.shape[-3], int) \
+            and not _pallas_dynamic_shapes():
+        return "symbolic page count %s outside a dynamic-shape export" % (
+            pages.shape[-3],)
+    width, psz = pages.shape[-2:]
+    if q.shape[2] != width:
+        return "query rows are %d wide, the pages' rows %d" % (
+            q.shape[2], width)
+    if page_table.shape[0] != q.shape[0] \
+            or lengths.shape[0] != q.shape[0]:
+        return "page_table%s / lengths%s do not match the batch %d" % (
+            tuple(page_table.shape), tuple(lengths.shape), q.shape[0])
+    if q.dtype != pages.dtype or q.dtype not in (jnp.float32, jnp.bfloat16):
+        return "operands %s and %s, want both float32 or both bfloat16" \
+            % (q.dtype, pages.dtype)
+    pack = 32 // q.dtype.itemsize        # rows of one packed sublane tile
+    if psz % 128 or width % pack or value_width % pack \
+            or not 0 < value_width <= width:
+        return "a page [width %d, psz %d] with values in its first %d " \
+            "rows: psz must be a multiple of 128, the widths of %d" % (
+                width, psz, value_width, pack)
+    return None
+
+
+def _latent_paged_attention_xla(q, pages, page_table, lengths, scale,
+                                value_width, layer=None):
+    """The XLA twin of the latent kernel: gather each row's pages through
+    its page table (under ``mx.kv_gather``), then the masked one-pass
+    softmax of :func:`_paged_attention_xla` over the rows' whole width
+    with the values their first ``value_width`` components (under
+    ``mx.latent_attention``).  A row of length 0 answers 0."""
+    if layer is not None:
+        P = pages.shape[1]
+        page_table = jnp.clip(page_table, 0, P - 1) + layer * P
+        pages = pages.reshape((-1,) + pages.shape[2:])
+    B, H, _ = q.shape
+    W, psz = page_table.shape[1], pages.shape[2]
+    with jax.named_scope("mx.kv_gather"):
+        rows = pages[page_table]                        # [B, W, width, psz]
+    with jax.named_scope("mx.latent_attention"):
+        valid = jnp.arange(W * psz, dtype=jnp.int32)[None, :] \
+            < lengths[:, None]
+        s = jnp.einsum("bhc,bwcp->bhwp", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, :], s.reshape(B, H, W * psz), -1e30)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        o = jnp.einsum("bhwp,bwcp->bhc",
+                       e.astype(rows.dtype).reshape(B, H, W, psz),
+                       rows[:, :, :value_width],
+                       preferred_element_type=jnp.float32)
+        o = (o / l).astype(q.dtype)
+        return jnp.where((lengths > 0)[:, None, None], o, 0)
+
+
+def latent_paged_attention(q, pages, page_table, lengths, scale,
+                           value_width, layer=None):
+    """Decode-step attention over LATENT pages: a token keeps one cache
+    row that every query head reads, for its score (over the whole row)
+    and for its value (the row's first ``value_width`` components) — the
+    absorbed form of multi-head latent attention (DeepSeek-V2,
+    arXiv:2405.04434 section 2.1), where the caller has folded the keys'
+    up-projection into the queries and un-folds the values' afterwards.
+
+    ``q`` ``[B, H, width]`` is each head's absorbed query beside its
+    rotary part; ``pages`` a pool ``[P, width, psz]`` whose pages hold
+    their ``psz`` tokens on the lanes (row c of a page is component c of
+    its tokens' cache rows), or with ``layer`` (a Python int or a traced
+    int32 scalar) every layer's ``[L, P, width, psz]`` handed over whole;
+    ``page_table`` ``[B, W]`` int32 (an id >= P is the sentinel);
+    ``lengths`` ``[B]`` int32; ``scale`` multiplies the scores.  Returns
+    ``[B, H, value_width]`` in q's dtype.  Both routes pin masked scores
+    to ``-1e30`` and answer 0 for a row of length 0.
+
+    Routing as :func:`paged_attention`: the Pallas kernel
+    (``ops.pallas_kernels.pallas_latent_paged_attention``, named
+    ``mx_latent_paged_attention``; counter ``kernels.latent_paged``),
+    which streams a row's ``ceil(length / psz)`` pages once for all heads,
+    or the XLA twin, which gathers the whole window (``mx.kv_gather``):
+    tier off, the default knob on an interpreted backend
+    (``kernels.gated_fallback``), or a shape the kernel cannot take
+    (``kernels.latent_fallback``).  Either runs under the
+    ``mx.latent_attention`` scope; the decision and its reason land in
+    those counters and, as ``impl`` "latent" or "xla", in the export route
+    sink of :func:`record_paged_routes`."""
+    reason = _route_reason(
+        lambda: latent_unsupported_reason(q, pages, page_table, lengths,
+                                          value_width, layer=layer),
+        _telemetry.counter("kernels.latent_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.latent_paged").inc()
+        _note_route("paged", impl="latent", reason=None, quantized=False)
+        with jax.named_scope("mx.latent_attention"):
+            return pallas_latent_paged_attention(
+                q, pages, page_table, lengths, scale, value_width,
+                layer=layer)
+    _note_route("paged", impl="xla", reason=reason, quantized=False)
+    return _latent_paged_attention_xla(q, pages, page_table, lengths, scale,
+                                       value_width, layer=layer)
+
+
 # ------------------------------------------------------- grouped product
 # XLA's grouped product wants whole row tiles: on the chip a row count
 # off the tile gave wrong products (PERF.md section 6, PR 27: 4,532 rows,
@@ -396,7 +589,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, scale=None,
 _XLA_GROUPED_ROW_TILE = 256
 
 
-def grouped_unsupported_reason(rows, w, sizes):
+def grouped_unsupported_reason(rows, w, sizes, w_b=None):
     """Why the Pallas grouped product can NOT take this call, or None if
     it can.  Trace-time shape/dtype checks only.  A non-None reason
     routes to ``lax.ragged_dot`` (``kernels.grouped_fallback``) and is
@@ -414,35 +607,44 @@ def grouped_unsupported_reason(rows, w, sizes):
                                                    jnp.bfloat16):
         return "operands %s and %s, want both float32 or both bfloat16" \
             % (rows.dtype, w.dtype)
+    if w_b is not None and (w_b.shape != w.shape or w_b.dtype != w.dtype):
+        return "the second matrix %s %s is not like the first %s %s" % (
+            tuple(w_b.shape), w_b.dtype, tuple(w.shape), w.dtype)
     k, n = w.shape[1:]
     if k % 128 or n % 128:
         return "K=%d and N=%d must be multiples of 128" % (k, n)
-    if grouped_col_tile(k, n, w.dtype.itemsize) is None:
+    if grouped_col_tile(k * (1 if w_b is None else 2), n,
+                        w.dtype.itemsize) is None:
         return "a [K=%d, 128] block of the weights exceeds the vmem " \
             "budget %d" % (k, _config.get("kernels.vmem_budget"))
     return None
 
 
-def _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype):
+def _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype, w_b=None):
     """The XLA twin of the grouped product: ``lax.ragged_dot`` over the
     rows padded to whole tiles of :data:`_XLA_GROUPED_ROW_TILE` (the rows
-    added join no group), accumulated in float32."""
+    added join no group), accumulated in float32; once a matrix where a
+    group has two."""
     m = rows.shape[0]
     rows = jnp.pad(rows, ((0, -m % _XLA_GROUPED_ROW_TILE), (0, 0)))
-    out = lax.ragged_dot(rows, w, sizes,
-                         preferred_element_type=jnp.float32)[:m]
-    if epilogue is not None:
-        out = epilogue(out)
+    outs = [lax.ragged_dot(rows, mat, sizes,
+                           preferred_element_type=jnp.float32)[:m]
+            for mat in ((w,) if w_b is None else (w, w_b))]
+    out = outs[0] if epilogue is None else epilogue(*outs)
     return out.astype(out_dtype)
 
 
-def grouped_matmul(rows, w, sizes, epilogue=None, out_dtype=jnp.float32):
+def grouped_matmul(rows, w, sizes, epilogue=None, out_dtype=jnp.float32,
+                   w_b=None):
     """Grouped matrix product with kernel routing: ``rows [M, K]`` lie
     sorted by group, group ``g`` owns the next ``sizes[g]`` of them and
     multiplies them by ``w[g] [K, N]``; the float32 product goes through
     ``epilogue`` (an elementwise function, if given) and is cast to
     ``out_dtype``: ``[M, N]``.  A row behind the last group is whatever
-    the product left there.
+    the product left there.  With ``w_b`` (a second matrix a group, like
+    ``w``: the gate and up matrices of a gated expert) the rows multiply
+    both and ``epilogue(a, b)`` folds the two float32 products into the
+    one result.
 
     Routing: tier on and shape feasible (float32 or bfloat16 operands,
     ``K`` and ``N`` multiples of 128, a ``[K, 128]`` block inside
@@ -461,12 +663,12 @@ def grouped_matmul(rows, w, sizes, epilogue=None, out_dtype=jnp.float32):
     carries no scope of its own: the caller's (``mx.moe_experts``) names
     the kernel's device time."""
     reason = _route_reason(
-        lambda: grouped_unsupported_reason(rows, w, sizes),
+        lambda: grouped_unsupported_reason(rows, w, sizes, w_b),
         _telemetry.counter("kernels.grouped_fallback"))
     if reason is None:
         _telemetry.counter("kernels.grouped_matmul").inc()
         _note_route("grouped", impl="grouped", reason=None)
         return pallas_grouped_matmul(rows, w, sizes, epilogue=epilogue,
-                                     out_dtype=out_dtype)
+                                     out_dtype=out_dtype, w_b=w_b)
     _note_route("grouped", impl="xla", reason=reason)
-    return _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype)
+    return _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype, w_b)

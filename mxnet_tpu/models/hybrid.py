@@ -1,4 +1,4 @@
-"""HybridLM — a decoder whose blocks are each ONE mixer, of five kinds.
+"""HybridLM — a decoder whose blocks are each ONE mixer, of seven kinds.
 
 The stack is a pattern string, one letter a block (the ``nemotron_h``
 family's ``hybrid_override_pattern``, and two letters of this module's
@@ -16,10 +16,25 @@ own):
          fixed-size state per K/V head that its query heads all read;
          per-head RMSNorm of q and k, rotary positions
   ``F``  a gated (SwiGLU) MLP
+  ``L``  multi-head latent attention (DeepSeek-V2, arXiv:2405.04434
+         section 2.1): queries through a low-rank path with its own
+         RMSNorm, keys and values through ONE low-rank latent a token
+         (its own RMSNorm) beside ONE rotary key that all heads share;
+         each head's query and key have an un-rotated and a rotary part,
+         its value a width of its own.  The cache keeps the latent and the
+         rotary key, not K and V: a whole prompt attends in the EXPANDED
+         form (keys and values up-projected, one flash pass), a decode
+         step in the ABSORBED one (the keys' up-projection folded into the
+         query, the values' applied to the attended latent) over the rows
+         the pages hold (``kernels.latent_paged_attention``)
+  ``G``  a mixture of GATED experts at the model's full width (DeepSeek-V3,
+         arXiv:2412.19437 section 2.1): ``W_down(silu(W_gate x) * W_up
+         x)`` an expert, chosen by ``E``'s sigmoid-scored, bias-selected,
+         normalised top-k router, beside one shared gated expert
 
 ``h <- h + Mixer(RMSNorm(h))`` per block, no positional table (the
-recurrences order the tokens; ``R`` rotates q and k by their positions),
-an output head of its own.  The class has
+recurrences order the tokens; ``R`` and ``L`` rotate q and k by their
+positions), an output head of its own.  The class has
 ``TransformerLM``'s generation protocol (``cfg``, ``init``, ``apply``,
 ``kv_spec``, ``init_kv_pages``, ``prefill``, ``decode_step``,
 ``greedy_decode``) and so goes through ``deploy.export_generation`` and
@@ -29,7 +44,16 @@ recurrent state and a convolution tail for every ``M`` layer and a
 float32 retention state and its normaliser for every ``R`` layer
 (``kv_spec()["state"]``).  A prefill leaves the prompt's final state in
 the slot it is told; a decode step advances every slot's.  A pattern
-without ``*`` keeps no page at all (``kv_spec()["num_layers"] == 0``).
+without ``*`` or ``L`` keeps no page at all (``kv_spec()["num_layers"] ==
+0``).  ``L`` layers keep ONE pool, ``kv`` ``[L layers, pages, kv_rank +
+rope_dim, page_size]``: a token's row is its normalised latent beside its
+rotated shared key (576 bf16 at the published widths, where K and V of 32
+heads would be 10,240), and a page holds its tokens ON THE LANES (row c of
+a page is component c of its tokens: a 576-wide minor axis would be padded
+to 640 lanes, or laid out this way by the compiler behind a copy into
+every kernel call); ``kv_spec()`` says so (``pools``, ``page_layout``) and
+``deploy`` / the engine build the cache from that description.  A model
+keeps one kind of page: a pattern may not mix ``*`` and ``L``.
 
 One chip's share of an expert-parallel deployment is a configuration, not
 another code path: ``experts_held`` / ``expert_offset`` say which routed
@@ -60,8 +84,9 @@ from .transformer import TransformerLM, _norm
 
 __all__ = ["HybridLMConfig", "HybridLM"]
 
-KINDS = "ME*RF"
-#: what ``parallel.moe.dropless_experts`` counts, summed over the E blocks
+KINDS = "ME*RFLG"
+#: what ``parallel.moe.dropless_experts`` counts, summed over the E and G
+#: blocks
 _STATS = ("pairs", "experts_hit", "max_load")
 
 
@@ -73,7 +98,8 @@ class HybridLMConfig:
                  moe_latent=128, expert_ff=256, shared_ff=512,
                  route_scale=1.0, experts_held=None, expert_offset=0,
                  max_len=2048, dtype=jnp.bfloat16, eps=1e-5, depth=None,
-                 rope_theta=1e6, mlp_ff=1024):
+                 rope_theta=1e6, mlp_ff=1024, q_rank=128, kv_rank=64,
+                 nope_dim=32, rope_dim=16, v_dim=32):
         if not pattern or set(pattern) - set(KINDS):
             raise ValueError("pattern %r: one of %r per block"
                              % (pattern, KINDS))
@@ -124,6 +150,18 @@ class HybridLMConfig:
         self.ret_width = head_dim * (head_dim + 1) // 2
         #: ``F`` blocks: the gated MLP's inner width
         self.mlp_ff = mlp_ff
+        #: ``L`` blocks: the ranks of the query and key/value latents, a
+        #: head's un-rotated and rotary query/key widths and its value
+        #: width (``num_heads`` heads; ``rope_theta`` rotates)
+        self.q_rank = q_rank
+        self.kv_rank = kv_rank
+        self.nope_dim = nope_dim
+        self.rope_dim = rope_dim
+        self.v_dim = v_dim
+        if "L" in pattern and "*" in pattern:
+            raise ValueError(
+                "pattern %r mixes '*' (K and V pages) and 'L' (latent "
+                "pages): a model keeps one kind of page" % (pattern,))
 
 
 def _normal(key, shape, std, dtype):
@@ -159,7 +197,11 @@ class HybridLM:
         self.names = tuple("%02d" % i for i in range(len(self.kinds)))
         # which attention layer (pool index) a block is
         self.attn_index = {n: i for i, n in enumerate(
-            n for n, k in zip(self.names, self.kinds) if k == "*")}
+            n for n, k in zip(self.names, self.kinds) if k in "*L")}
+        self.latent = "L" in self.kinds
+        #: ``L`` blocks: scores over a head's whole query/key width, in the
+        #: expanded and in the absorbed form alike
+        self._mla_scale = 1.0 / math.sqrt(config.nope_dim + config.rope_dim)
 
     # -------------------------------------------------------------- params
     def init(self, key):
@@ -217,6 +259,28 @@ class HybridLM:
                 F = cfg.mlp_ff
                 lp = {"ln": ones(D), "w_gate": mk((D, F), D),
                       "w_up": mk((D, F), D), "w_down": mk((F, D), F, out)}
+            elif kind == "L":
+                H, Rq, Rkv = cfg.num_heads, cfg.q_rank, cfg.kv_rank
+                dn, dr, dv = cfg.nope_dim, cfg.rope_dim, cfg.v_dim
+                # (the rotary columns of w_uq and w_dkv are stored in the
+                # rotate-half order; the keys' and values' up-projections
+                # apart, as the absorbed form reads them)
+                lp = {"ln": ones(D), "w_dq": mk((D, Rq), D),
+                      "q_norm": ones(Rq), "w_uq": mk((Rq, H, dn + dr), Rq),
+                      "w_dkv": mk((D, Rkv + dr), D), "kv_norm": ones(Rkv),
+                      "w_uk": mk((Rkv, H, dn), Rkv),
+                      "w_uv": mk((Rkv, H, dv), Rkv),
+                      "wo": mk((H, dv, D), H * dv, out)}
+            elif kind == "G":
+                E, Eh = cfg.num_experts, cfg.experts_held
+                F, Fs = cfg.expert_ff, cfg.shared_ff
+                lp = {"ln": ones(D), "router": mk((D, E), D),
+                      "select_bias": jax.random.normal(
+                          next(keys), (E,), jnp.float32) * 0.01,
+                      "w_gate": mk((Eh, D, F), D), "w_up": mk((Eh, D, F), D),
+                      "w_down": mk((Eh, F, D), F, out),
+                      "v_gate": mk((D, Fs), D), "v_up": mk((D, Fs), D),
+                      "v_down": mk((Fs, D), Fs, out)}
             else:
                 E, Eh, Z = cfg.num_experts, cfg.experts_held, cfg.moe_latent
                 F, Fs = cfg.expert_ff, cfg.shared_ff
@@ -260,6 +324,88 @@ class HybridLM:
             return _kernels.attention(q, jnp.repeat(k, rep, axis=1),
                                       jnp.repeat(v, rep, axis=1),
                                       causal=True)
+
+    # ----------------------------------------------------- latent attention
+    def _mla_parts(self, x, lp, positions):
+        """RMSNorm, the two low-rank paths with their norms, rotary at
+        ``positions``: x [B,S,D], positions [B,S] -> q_nope [B,S,H,dn],
+        q_rope [B,S,H,dr] (rotated), the normalised latent c [B,S,Rkv] and
+        the rotated key k_rope [B,S,dr] that every head shares, in x's
+        dtype.  ``[c | k_rope]`` is the row a token keeps."""
+        cfg = self.cfg
+        Rkv, dn = cfg.kv_rank, cfg.nope_dim
+        f32 = jnp.float32
+        n = _norm(x, lp["ln"], cfg.eps)
+        cq = _norm(jnp.einsum("bsd,dr->bsr", n, lp["w_dq"],
+                              preferred_element_type=f32).astype(x.dtype),
+                   lp["q_norm"], cfg.eps)
+        q = jnp.einsum("bsr,rhe->bshe", cq, lp["w_uq"],
+                       preferred_element_type=f32).astype(x.dtype)
+        ckr = jnp.einsum("bsd,dr->bsr", n, lp["w_dkv"],
+                         preferred_element_type=f32).astype(x.dtype)
+        c = _norm(ckr[..., :Rkv], lp["kv_norm"], cfg.eps)
+        q_rope = _rope(q[..., dn:], positions, cfg.rope_theta)
+        k_rope = _rope(ckr[..., None, Rkv:], positions,
+                       cfg.rope_theta)[:, :, 0]
+        return q[..., :dn], q_rope, c, k_rope
+
+    def _mla_out(self, o, lp):
+        """The heads' outputs [..., H, dv] through the output projection."""
+        return jnp.einsum("...he,hed->...d", o, lp["wo"],
+                          preferred_element_type=jnp.float32
+                          ).astype(o.dtype)
+
+    def _mla_sequence(self, x, lp):
+        """A whole sequence in the EXPANDED form: every head's keys and
+        values up-projected from the latent, the shared rotary key beside
+        each head's own, one causal flash pass at query/key width ``dn +
+        dr`` and value width ``dv``.  x [B,S,D] -> (out [B,S,D], the rows
+        to keep [B,S,Rkv+dr])."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        f32 = jnp.float32
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        with jax.named_scope("mx.mla_proj"):
+            q_nope, q_rope, c, k_rope = self._mla_parts(x, lp, positions)
+            k_nope = jnp.einsum("bsr,rhe->bhse", c, lp["w_uk"],
+                                preferred_element_type=f32).astype(x.dtype)
+            v = jnp.einsum("bsr,rhe->bhse", c, lp["w_uv"],
+                           preferred_element_type=f32).astype(x.dtype)
+            q = jnp.transpose(jnp.concatenate([q_nope, q_rope], axis=-1),
+                              (0, 2, 1, 3))
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope[:, None], k_nope.shape[:3] + k_rope.shape[-1:])],
+                axis=-1)
+        with jax.named_scope("mx.attention"):
+            o = _kernels.attention(q, k, v, causal=True,
+                                   scale=self._mla_scale)
+        with jax.named_scope("mx.mla_proj"):
+            out = self._mla_out(jnp.transpose(o, (0, 2, 1, 3)), lp)
+        return out, jnp.concatenate([c, k_rope], axis=-1)
+
+    def _mla_absorb(self, x, lp, positions):
+        """One token a row, the ABSORBED form's first half: x [B,D],
+        positions [B] -> (each head's query over a cache row [B, H,
+        Rkv+dr]: the keys' up-projection folded in, ``q_nope W_uk^T``, a
+        latent-wide query a head, beside its rotary part; the tokens' own
+        rows to keep [B, Rkv+dr]), so the pages are read as they lie."""
+        with jax.named_scope("mx.mla_proj"):
+            q_nope, q_rope, c, k_rope = (a[:, 0] for a in self._mla_parts(
+                x[:, None], lp, positions[:, None]))
+            q_abs = jnp.einsum("bhe,rhe->bhr", q_nope, lp["w_uk"],
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+            return jnp.concatenate([q_abs, q_rope], axis=-1), \
+                jnp.concatenate([c, k_rope], axis=-1)
+
+    def _mla_unabsorb(self, ctx, lp):
+        """... and its second half: each head's attended latent [B, H,
+        Rkv] through the values' up-projection and the output one."""
+        with jax.named_scope("mx.mla_proj"):
+            o = jnp.einsum("bhr,rhe->bhe", ctx, lp["w_uv"],
+                           preferred_element_type=jnp.float32
+                           ).astype(ctx.dtype)
+            return self._mla_out(o, lp)
 
     # -------------------------------------------------------------- Mamba-2
     def _ssm_split(self, x, lp):
@@ -480,6 +626,33 @@ class HybridLM:
             out = out + dot(_relu2(dot(h, lp["v1"])).astype(dt), lp["v2"])
         return out.astype(dt), stats, experts
 
+    def _gated_moe(self, x, lp, rows_valid=None):
+        """``G``: x [T, D] -> (out [T, D], the held experts' routing stats,
+        the experts each row chose [T, top_k] int32).  The routed experts
+        and the shared one are gated and read the block's normalised input
+        at full width."""
+        cfg = self.cfg
+        dt = x.dtype
+
+        def dot(a, w):
+            return jnp.einsum("td,df->tf", a, w,
+                              preferred_element_type=jnp.float32)
+
+        h = _norm(x, lp["ln"], cfg.eps)
+        with jax.named_scope("mx.moe_router"):
+            experts, weights = _moe.sigmoid_top_k(
+                h, lp["router"], lp["select_bias"], cfg.top_k,
+                cfg.route_scale)
+        with jax.named_scope("mx.moe_experts"):
+            out, stats = _moe.dropless_experts(
+                h, experts, weights, lp["w_up"], lp["w_down"],
+                expert_offset=cfg.expert_offset, rows_valid=rows_valid,
+                w_gate=lp["w_gate"])
+        with jax.named_scope("mx.moe_shared"):
+            mid = jax.nn.silu(dot(h, lp["v_gate"])) * dot(h, lp["v_up"])
+            out = out + dot(mid.astype(dt), lp["v_down"])
+        return out.astype(dt), stats, experts
+
     # -------------------------------------------------------------- forward
     def apply(self, params, tokens):
         """tokens [B, S] int32 -> logits [B, S, V] (fp32): the whole
@@ -494,8 +667,9 @@ class HybridLM:
         """The blocks over whole sequences x [B,S,D].  Returns (x, the
         final per-sequence state of the ``M`` and ``R`` blocks by its name
         in the cache (:meth:`kv_spec`), summed expert stats, the experts
-        chosen [E blocks, B, S, top_k]); ``kv_sink(block, k, v)`` sees
-        every attention block's K/V."""
+        chosen [E and G blocks, B, S, top_k]); ``kv_sink(block, k, v)``
+        sees every attention block's K/V, and every latent block's rows
+        (``kv_sink(block, rows)``)."""
         B, S, D = x.shape
         valid = None if lengths is None else \
             (jnp.arange(S)[None, :] < lengths[:, None]).reshape(-1)
@@ -519,10 +693,15 @@ class HybridLM:
                     x = x + out
             elif kind == "F":
                 x = x + self._mlp(x, lp)
+            elif kind == "L":
+                out, rows = self._mla_sequence(x, lp)
+                if kv_sink is not None:
+                    kv_sink(name, rows)
+                x = x + out
             else:
                 with jax.named_scope("mx.moe"):
-                    out, st, chosen = self._moe(x.reshape(B * S, D), lp,
-                                                valid)
+                    mix = self._moe if kind == "E" else self._gated_moe
+                    out, st, chosen = mix(x.reshape(B * S, D), lp, valid)
                     stats.append(st)
                     routed.append(chosen.reshape(B, S, -1))
                     x = x + out.reshape(B, S, D)
@@ -540,10 +719,21 @@ class HybridLM:
         entries weighted sqrt 2) and its normaliser — as ``{"name",
         "shape" (of one slot's row), "dtype"}``; an array is ``[slots,
         *shape]``.  ``num_layers`` counts the layers that attend: 0 is a
-        model that keeps no page."""
+        model that keeps no page.  A model of ``L`` blocks keeps LATENT
+        pages: ``pools`` names its one pool (``kv``; K and V pools ``k``
+        and ``v`` where the key is absent), ``row_width`` is the latent's
+        rank + the rotary key's width of which the first ``value_width``
+        are the values, and ``page_layout`` ``"lanes"`` says a page is
+        ``[row_width, page_size]``, its tokens on the lanes (absent: a page
+        is ``[page_size, row_width]``)."""
         if quantized:
             raise ValueError("HybridLM keeps no int8 K/V pages")
         cfg = self.cfg
+        if "*" in self.kinds and self.latent:
+            raise ValueError(
+                "pattern %r mixes '*' (K and V pages) and 'L' (latent "
+                "pages): a model keeps one kind of page, and kv_spec "
+                "describes one" % (cfg.pattern,))
         G, R = cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
         KV, N = cfg.num_kv_heads, cfg.ret_width
         state = []
@@ -561,23 +751,27 @@ class HybridLM:
                               "shape": [KV, N, cfg.head_dim]})
                 state.append({"name": "retz" + name, "dtype": "float32",
                               "shape": [KV, N]})
-        return {"num_layers": len(self.attn_index),
+        spec = {"num_layers": len(self.attn_index),
                 "num_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
                 "row_width": cfg.num_kv_heads * cfg.head_dim,
                 "dtype": jnp.dtype(cfg.dtype).name, "state": state}
+        if self.latent:
+            width = cfg.kv_rank + cfg.rope_dim
+            spec.update(num_heads=1, head_dim=width, row_width=width,
+                        value_width=cfg.kv_rank, pools=["kv"],
+                        page_layout="lanes")
+        return spec
 
     def init_kv_pages(self, num_pages, page_size, slots=1):
-        """Zeroed cache: ``k`` / ``v`` ``[attention layers, num_pages,
-        page_size, KVH*Dh]`` and every state array ``[slots, ...]``."""
-        spec = self.kv_spec()
-        shape = (spec["num_layers"], int(num_pages), int(page_size),
-                 spec["row_width"])
-        kv = {"k": jnp.zeros(shape, self.cfg.dtype),
-              "v": jnp.zeros(shape, self.cfg.dtype)}
-        for s in spec["state"]:
-            kv[s["name"]] = jnp.zeros((int(slots),) + tuple(s["shape"]),
-                                      s["dtype"])
-        return kv
+        """Zeroed cache, as :meth:`kv_spec` describes it: ``k`` / ``v``
+        ``[attention layers, num_pages, page_size, KVH*Dh]`` or the one
+        latent pool ``kv`` ``[L layers, num_pages, kv_rank + rope_dim,
+        page_size]``, and every state array ``[slots, ...]``."""
+        from ..deploy import _kv_pool_specs, kv_pool_names
+        spec = dict(self.kv_spec(), page_size=int(page_size))
+        return {name: jnp.zeros(s.shape, s.dtype) for name, s in zip(
+            kv_pool_names(spec),
+            _kv_pool_specs(spec, int(num_pages), int(slots)))}
 
     def _logits_last(self, params, x):
         with jax.named_scope("mx.lm_head"):
@@ -595,7 +789,9 @@ class HybridLM:
                 return_replay=False):
         """Whole prompts: tokens [B,S] (padded past ``lengths``), page_table
         [B,W].  Every attention block's K/V goes into the pages as in
-        ``TransformerLM.prefill``; every ``M`` and ``R`` block runs from
+        ``TransformerLM.prefill`` (every latent block's rows a whole page
+        at a time: the expanded K and V are never kept); every ``M`` and
+        ``R`` block runs from
         the zero state in its chunked form and leaves the prompt's final
         state (and convolution tail, or normaliser) in row ``slots[b]``
         (default: row b) of its state arrays, whatever that row held.
@@ -605,16 +801,31 @@ class HybridLM:
         the next token's log-probability ``[B]`` float32."""
         B, S = tokens.shape
         psz = int(page_size)
-        pool = kv["k"].shape[1]
+        pool = kv["kv" if self.latent else "k"].shape[1]
         iota = jnp.arange(S, dtype=jnp.int32)
         pages = jnp.where(iota[None, :] < lengths[:, None],
                           page_table[:, iota // psz], pool)
         offs = jnp.broadcast_to(iota % psz, (B, S))
         nkv = dict(kv)
 
-        def sink(name, k, v):
+        def sink(name, k, v=None):
             with jax.named_scope("mx.kv_write"):
                 a = self.attn_index[name]
+                if v is None:
+                    # latent rows [B,S,width] go in a page at a time, its
+                    # tokens onto the lanes; a page with no real token
+                    # (or one the table withholds) is dropped, a last
+                    # page's tail is rows no length reaches
+                    n = -(-S // psz)
+                    rows = jnp.pad(k, ((0, 0), (0, n * psz - S), (0, 0)))
+                    rows = jnp.transpose(rows.reshape(B, n, psz, -1),
+                                         (0, 1, 3, 2))
+                    ids = jnp.where(
+                        jnp.arange(n)[None, :] * psz < lengths[:, None],
+                        page_table[:, :n], pool)
+                    nkv["kv"] = nkv["kv"].at[a, ids].set(
+                        rows.astype(nkv["kv"].dtype), mode="drop")
+                    return
                 for key, val in (("k", k), ("v", v)):
                     rows = jnp.transpose(val, (0, 2, 1, 3)).reshape(B, S, -1)
                     nkv[key] = nkv[key].at[a, pages, offs].set(
@@ -639,7 +850,9 @@ class HybridLM:
         """One token for every decode slot: row b of the batch IS slot b
         of the state arrays.  Attention blocks append K/V and attend
         through ``kernels.paged_attention`` (handed the whole pool and the
-        layer's index); ``M`` and ``R`` blocks advance their state in
+        layer's index), latent blocks append a row and attend in the
+        absorbed form through ``kernels.latent_paged_attention``; ``M`` and
+        ``R`` blocks advance their state in
         float32, ``R`` blocks rotating q and k by ``positions``.  A
         slot with no request (position 0, as the engine passes it) routes
         to no expert; its state row is advanced like any other and is
@@ -687,9 +900,31 @@ class HybridLM:
                     x = x + out
             elif kind == "F":
                 x = x + self._mlp(x, lp)
+            elif kind == "L":
+                a = self.attn_index[name]
+                q, row = self._mla_absorb(x, lp, positions)
+                # a row is a COLUMN of its page (tokens lie on the lanes):
+                # the page is fetched, the column put in and the page put
+                # back whole, so nothing is scattered across a page's rows
+                # (a column scatter has the compiler turn the whole pool
+                # round, twice a step)
+                with jax.named_scope("mx.kv_write"):
+                    pool = nkv["kv"]
+                    at = jnp.minimum(page[:, 0], pool.shape[1] - 1)
+                    lane = jnp.arange(psz, dtype=jnp.int32)
+                    pages = jnp.where(
+                        lane[None, None, :] == slot[:, :, None],
+                        row.astype(pool.dtype)[:, :, None], pool[a, at])
+                    nkv["kv"] = pool.at[a, page[:, 0]].set(pages,
+                                                           mode="drop")
+                ctx = _kernels.latent_paged_attention(
+                    q, nkv["kv"], page_table, positions + 1,
+                    self._mla_scale, self.cfg.kv_rank, layer=a)
+                x = x + self._mla_unabsorb(ctx, lp)
             else:
                 with jax.named_scope("mx.moe"):
-                    out, st, chosen = self._moe(x, lp, active)
+                    mix = self._moe if kind == "E" else self._gated_moe
+                    out, st, chosen = mix(x, lp, active)
                     stats.append(st)
                     routed.append(chosen)
                     x = x + out

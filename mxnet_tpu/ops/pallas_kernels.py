@@ -30,7 +30,8 @@ from .registry import register
 
 __all__ = ["pallas_row_softmax", "pallas_scale_bias_relu",
            "pallas_flash_attention", "flash_attention",
-           "pallas_paged_attention", "pallas_grouped_matmul"]
+           "flash_attention_tiled", "pallas_paged_attention",
+           "pallas_latent_paged_attention", "pallas_grouped_matmul"]
 
 _NEG = -1e30
 
@@ -416,6 +417,106 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                            block_q=block_q)
 
 
+# ---------------------------------------------- flash attention, K/V tiled
+_TILED_BLOCK = 512           # q rows and K/V rows a step: four MXU passes
+
+
+def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, o_ref,
+                        m_ref, l_ref, acc_ref):
+    """One q block against one K/V block of its (batch, head) slice; the
+    K/V blocks are the grid's last axis and an online softmax (``m``,
+    ``l``, ``acc`` in float32 scratch) carries across them, so neither the
+    score matrix nor a whole head's K/V is ever resident.  The value rows
+    may be narrower or wider than the query/key rows.  Under ``causal`` a
+    K/V block wholly above the diagonal is skipped (its block index is
+    clamped to the last one the q block needs, so nothing is fetched for
+    it either)."""
+    from jax.experimental import pallas as pl
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=_mxu_precision(q, k),
+            preferred_element_type=jnp.float32) * scale       # [bq, bk]
+        if causal:
+            q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos <= q_pos, s, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(e, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=_mxu_precision(v), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    if causal:
+        pl.when(j * bk <= i * bq + bq - 1)(step)
+    else:
+        step()
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def flash_attention_tiled(q, k, v, causal=False, scale=None,
+                          block=_TILED_BLOCK):
+    """Flash attention FORWARD with the keys and values tiled as well as
+    the queries: q, k ``[B, H, S, Dqk]``, v ``[B, H, Skv, Dv]`` (``Dv``
+    need not be ``Dqk``) -> ``[B, H, S, Dv]``.  The grid walks (batch x
+    heads, q blocks, K/V blocks); a step holds one block of each
+    (``block`` rows, or the largest legal divisor of the lengths below
+    it), so what fits does not depend on the sequence length, where
+    :func:`flash_attention` keeps a head's whole K/V resident.  A causal
+    call computes the blocks on and under the diagonal only.  No backward
+    of its own: ``mx.kernels.attention`` routes here and differentiates
+    through the XLA lowering."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    B, H, S, D = q.shape
+    Skv, Dv = v.shape[2], v.shape[3]
+    if k.shape != (B, H, Skv, D) or v.shape[:2] != (B, H) \
+            or (causal and Skv != S):
+        raise ValueError("tiled flash attention takes q [B,H,S,D], k "
+                         "[B,H,Skv,D], v [B,H,Skv,Dv] (Skv == S if causal),"
+                         " got %s, %s, %s" % (q.shape, k.shape, v.shape))
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
+    bq = _row_block(S, 1, budget=min(block, S))
+    bk = _row_block(Skv, 1, budget=min(block, Skv))
+    # (under the diagonal's clamp a skipped step names the block before it)
+    last = (lambda i: (i * bq + bq - 1) // bk) if causal \
+        else (lambda i: Skv // bk - 1)
+    kv_at = lambda b, i, j: (b, jnp.minimum(j, last(i)), 0)   # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_flash_tiled_kernel, scale, bool(causal), bq, bk),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
+        grid=(B * H, S // bq, Skv // bk),
+        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, bk, D), kv_at),
+                  pl.BlockSpec((1, bk, Dv), kv_at)],
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret_mode(), name="mx_attention_tiled")(
+            q.reshape(B * H, S, D), k.reshape(B * H, Skv, D),
+            v.reshape(B * H, Skv, Dv))
+    return out.reshape(B, H, S, Dv)
+
+
 # ------------------------------------------------------- paged attention
 _PAGED_TILE_TOKENS = 128     # one MXU pass wide: the tile's score row
 
@@ -674,6 +775,158 @@ def pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     return out.reshape(B, H, 1, D)
 
 
+# ------------------------------------------------ latent paged attention
+_LATENT_TILE_TOKENS = 512
+
+
+def latent_tile_pages(psz, width, itemsize, table_width):
+    """Pages the latent kernel copies per step: 512 tokens' worth where
+    the double-buffered tile (``2 * pages * width * psz`` elements) fits
+    the ``kernels.vmem_budget`` knob, fewer where it does not or the page
+    table is narrower, never less than one."""
+    from .. import config as _config
+    fit = int(_config.get("kernels.vmem_budget")) \
+        // (2 * psz * width * itemsize)
+    return max(1, min(_LATENT_TILE_TOKENS // psz, fit, table_width))
+
+
+def _latent_attn_kernel(scale, tile, dv, lengths_ref, table_ref, q_ref,
+                        pool_hbm, o_ref, buf, sems):
+    """One decode row of absorbed latent attention over its pages, read
+    where they lie.
+
+    ``pool_hbm`` is a pool of latent pages ``[P, width, psz]`` left in HBM
+    (one layer's, or every layer's viewed as ``L*P`` pages with the ids
+    offset): a page holds ``psz`` tokens ON THE LANES, row c of it being
+    component c of their cache rows.  All ``H`` query heads read the SAME
+    row of a token: ``q_ref`` is ``[H, width]`` (each head's absorbed
+    query beside its rotary part), so a page's scores are ONE product
+    ``q . page -> [H, psz]`` over the whole row, and its values are the
+    page's first ``dv`` rows, ``p . page[:dv]^T -> [H, dv]``, from the same
+    VMEM tile: a page is copied once for every head, its scores and its
+    values.  As in the paged kernel the row walks ``ceil(length / psz)``
+    pages, ``tile`` a step through a double-buffered tile, under a float32
+    online softmax; positions past the length pin to ``-1e30`` (``exp``
+    gives an exact 0.0) and the tile is zeroed once, so what a masked
+    slot's 0.0 multiplies is finite; page ids arrive clamped; a row of
+    length 0 reads nothing and answers 0."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b = pl.program_id(0)
+    _, width, psz = pool_hbm.shape
+    W = table_ref.shape[0] // lengths_ref.shape[0]
+    tokens = tile * psz
+    length = lengths_ref[b]
+    n_pages = jnp.minimum((length + psz - 1) // psz, W)
+    n_tiles = (n_pages + tile - 1) // tile
+
+    def each_page(t, slot, act):
+        for j in range(tile):
+            i = t * tile + j
+
+            @pl.when(i < n_pages)
+            def _(i=i, j=j):
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[table_ref[b * W + i]], buf.at[slot, j],
+                    sems.at[slot]))
+
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(n_tiles > 0)
+    def _():
+        each_page(0, 0, lambda cp: cp.start())
+
+    q = q_ref[...].astype(buf.dtype)                        # [H, width]
+    nh = q.shape[0]
+    prec = _mxu_precision(q)
+    col = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1)
+
+    def body(t, carry):
+        m, l, acc = carry
+        slot = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            each_page(t + 1, 1 - slot, lambda cp: cp.start())
+
+        each_page(t, slot, lambda cp: cp.wait())
+        s = jnp.concatenate([jax.lax.dot_general(
+            q, buf[slot, j], (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) for j in range(tile)],
+            axis=1) * scale                                 # [H, tokens]
+        s = jnp.where(t * tokens + col < length, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(e, axis=-1, keepdims=True)
+        p = e.astype(buf.dtype)
+        pv = sum(jax.lax.dot_general(
+            p[:, j * psz:(j + 1) * psz], buf[slot, j, :dv, :],
+            (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) for j in range(tile))
+        return m_new, l, alpha * acc + pv                   # [H, dv]
+
+    m0 = jnp.full((nh, 1), _NEG, jnp.float32)
+    l0 = jnp.zeros((nh, 1), jnp.float32)
+    acc0 = jnp.zeros((nh, dv), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, acc0))
+    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def pallas_latent_paged_attention(q, pages, page_table, lengths, scale,
+                                  value_width, layer=None):
+    """Absorbed latent-attention decode kernel: one query row per
+    sequence, every head reading the one cache row a token keeps.
+
+    q [B, H, width] (head h's absorbed query beside its rotary part);
+    pages [P, width, psz], a pool of latent pages with the tokens on the
+    lanes, or with ``layer`` (a Python int or a traced int32 scalar) every
+    layer's ``[L, P, width, psz]`` handed over whole and read as ``L*P``
+    pages at the layer's ids; page_table [B, W] int32 (ids >= P clamp to a
+    real page that the length masks); lengths [B] int32.  Scores run over
+    a row's whole ``width``, values are its first ``value_width``
+    components: returns ``[B, H, value_width]`` in q's dtype.  ``psz`` is
+    a multiple of the 128 lanes, ``width`` and ``value_width`` of the
+    pool dtype's sublane packing (16 rows of bf16): routing/fallback
+    policy lives in ``mx.kernels.latent_paged_attention``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    q = jnp.asarray(q)
+    B, H, width = q.shape
+    P, wide, psz = pages.shape[-3:]
+    dv = int(value_width)
+    if wide != width or not 0 < dv <= width \
+            or pages.ndim != (3 if layer is None else 4):
+        raise ValueError("latent pages must be [%sP, width=%d, psz] with "
+                         "0 < value_width <= width, got %s and %d"
+                         % ("" if layer is None else "L, ", width,
+                            pages.shape, dv))
+    if layer is not None:
+        pages = pages.reshape((-1, width, psz))
+    tile = latent_tile_pages(psz, width, pages.dtype.itemsize,
+                             page_table.shape[1])
+    ids = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+    if layer is not None:
+        ids = ids + layer * P
+    return pl.pallas_call(
+        functools.partial(_latent_attn_kernel, float(scale), tile, dv),
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, width), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, dv), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, tile, width, psz), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(), name="mx_latent_paged_attention")(
+            jnp.asarray(lengths, jnp.int32), ids.reshape(-1), q, pages)
+
+
 # ------------------------------------------------------- grouped product
 _GROUPED_ROW_TILE = 128          # one MXU pass tall
 
@@ -729,7 +982,7 @@ def _grouped_work_items(sizes, row_tiles, tm):
 
 
 def _grouped_matmul_kernel(tm, epilogue, group_ref, tile_ref, start_ref,
-                           end_ref, count_ref, x_ref, w_ref, o_ref):
+                           end_ref, count_ref, x_ref, w_ref, *refs):
     """One (group, row tile) pair of a grouped product: the tile's ``tm``
     rows times one column tile of the group's matrix, kept for the rows
     the group owns.  Row tiles lie on multiples of ``tm`` whatever the
@@ -741,19 +994,22 @@ def _grouped_matmul_kernel(tm, epilogue, group_ref, tile_ref, start_ref,
     weight block, which is then fetched once, and the steps past the last
     pair (``count_ref``) name the last pair's blocks and compute nothing.
     ``epilogue`` (elementwise, on the float32 product) runs before the
-    cast to the output's dtype."""
+    cast to the output's dtype.  With a second matrix of the group (a
+    ``wb_ref`` before ``o_ref``) the same rows multiply both and
+    ``epilogue`` takes the two float32 products."""
     from jax.experimental import pallas as pl
+    *wb_ref, o_ref = refs
     i = pl.program_id(1)
 
     @pl.when(i < count_ref[0])
     def _():
         g = group_ref[i]
-        x, w = x_ref[...], w_ref[...]
-        acc = jax.lax.dot_general(
+        x = x_ref[...]
+        accs = [jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())), precision=_mxu_precision(x, w),
             preferred_element_type=jnp.float32)
-        if epilogue is not None:
-            acc = epilogue(acc)
+            for w in [r[...] for r in [w_ref] + wb_ref]]
+        acc = accs[0] if epilogue is None else epilogue(*accs)
         row = tile_ref[i] * tm + jax.lax.broadcasted_iota(
             jnp.int32, acc.shape, 0)
         mine = (row >= start_ref[g]) & (row < end_ref[g])
@@ -763,14 +1019,17 @@ def _grouped_matmul_kernel(tm, epilogue, group_ref, tile_ref, start_ref,
 
 
 def pallas_grouped_matmul(rows, w, sizes, epilogue=None,
-                          out_dtype=jnp.float32):
+                          out_dtype=jnp.float32, w_b=None):
     """Grouped matrix product: ``rows [M, K]`` lie sorted by group, group
     ``g`` owns the next ``sizes[g]`` of them and multiplies them by ``w[g]
     [K, N]``.  Returns ``[M, N]`` of ``out_dtype``: 16-bit operands take
     the MXU's native pass, the product accumulates in float32, and
     ``epilogue`` (an elementwise function, if given) is applied to it
     before the cast.  A row behind the last group is left as it lay,
-    whatever that is.
+    whatever that is.  With ``w_b`` (a second matrix a group, of ``w``'s
+    shape: a gated expert's two input matrices) every step multiplies its
+    rows by both column tiles and ``epilogue(a, b)`` folds the two float32
+    products into the one that is written.
 
     The row tile follows from the static shapes (:func:`grouped_row_tile`)
     and the groups' sizes decide at run time which (group, tile) pairs
@@ -791,13 +1050,17 @@ def pallas_grouped_matmul(rows, w, sizes, epilogue=None,
     m, k = rows.shape
     e, _, n = w.shape
     tm = grouped_row_tile(m, rows.dtype.itemsize)
-    tn = grouped_col_tile(k, n, w.dtype.itemsize)
+    pair = w_b is not None
+    tn = grouped_col_tile(k * (2 if pair else 1), n, w.dtype.itemsize)
     if w.shape[1] != k or k % _LANES or n % _LANES or tn is None \
-            or sizes.shape != (e,):
+            or sizes.shape != (e,) or (pair and (
+                w_b.shape != w.shape or w_b.dtype != w.dtype
+                or epilogue is None)):
         raise ValueError(
             "grouped product takes rows [M, K], w [E, K, N] and sizes [E] "
             "with K and N multiples of %d and a [K, %d] block inside "
-            "kernels.vmem_budget, got %s, %s and %s"
+            "kernels.vmem_budget (w_b, if given, like w and folded by an "
+            "epilogue of two), got %s, %s and %s"
             % (_LANES, _LANES, rows.shape, w.shape, sizes.shape))
     rows = jnp.pad(rows, ((0, -m % tm), (0, 0)))
     row_tiles = rows.shape[0] // tm
@@ -809,15 +1072,15 @@ def pallas_grouped_matmul(rows, w, sizes, epilogue=None,
             num_scalar_prefetch=5, grid=(n // tn, row_tiles + e - 1),
             in_specs=[
                 pl.BlockSpec((tm, k), lambda j, i, group, tile, *_:
-                             (tile[i], 0)),
+                             (tile[i], 0))] + [
                 pl.BlockSpec((None, k, tn), lambda j, i, group, tile, *_:
-                             (group[i], 0, j))],
+                             (group[i], 0, j))] * (2 if pair else 1),
             out_specs=pl.BlockSpec((tm, tn), lambda j, i, group, tile, *_:
                                    (tile[i], j))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret_mode(), name="mx_grouped_matmul")(
-            *items, rows, w)
+            *items, rows, w, *((w_b,) if pair else ()))
     return out[:m]
 
 

@@ -160,14 +160,18 @@ def sigmoid_top_k(x, router_w, select_bias, k, scale):
 
 
 def dropless_experts(u, experts, weights, w1, w2, expert_offset=0,
-                     rows_valid=None):
+                     rows_valid=None, w_gate=None):
     """The held experts' part of a top-k mixture, no token dropped.
 
     u [T, z] (the experts' input, already in their width); experts /
     weights [T, k] from :func:`sigmoid_top_k`; w1 [E_held, z, f] and
     w2 [E_held, f, z] are the experts this caller HOLDS, global ids
     ``expert_offset .. expert_offset + E_held``; expert e computes
-    ``relu(u . w1_e)**2 . w2_e``.  A (token, expert) pair whose expert
+    ``relu(u . w1_e)**2 . w2_e`` or, GATED (``w_gate`` [E_held, z, f], a
+    third matrix an expert), ``(silu(u . w_gate_e) * (u . w1_e)) . w2_e``
+    with ``w1`` the up and ``w2`` the down matrix: the first grouped
+    product then multiplies the rows by both input matrices and folds the
+    two in its epilogue.  A (token, expert) pair whose expert
     lives elsewhere adds nothing here; ``rows_valid`` [T] bool drops the
     pairs of rows that carry no request.  The held pairs are sorted by
     expert (stable, so the order is a pure function of the routing) and
@@ -191,9 +195,14 @@ def dropless_experts(u, experts, weights, w1, w2, expert_offset=0,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((e_held + 1,), jnp.int32).at[key].add(1)[:e_held]
     rows = jnp.take(u, order // k, axis=0)                  # [T*k, z]
-    h = _kernels.grouped_matmul(
-        rows, w1, sizes, epilogue=lambda a: jnp.square(jax.nn.relu(a)),
-        out_dtype=u.dtype)
+    if w_gate is None:
+        h = _kernels.grouped_matmul(
+            rows, w1, sizes, epilogue=lambda a: jnp.square(jax.nn.relu(a)),
+            out_dtype=u.dtype)
+    else:
+        h = _kernels.grouped_matmul(
+            rows, w_gate, sizes, epilogue=lambda g, a: jax.nn.silu(g) * a,
+            out_dtype=u.dtype, w_b=w1)
     out = _kernels.grouped_matmul(h, w2, sizes)
     # back to [T, k, z]: row i of the sorted list is pair order[i]
     back = jnp.zeros((t * k,), jnp.int32).at[order].set(

@@ -548,3 +548,105 @@ def test_retention_programs_at_the_cells_sizes_compile_into_the_chip(
         need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
     assert max(need) < 15.75 * 2 ** 30, need
     assert min(need) > 0.7 * 15.75 * 2 ** 30    # and the chip is filled
+
+
+# --------------------------------------- the latent-attention decoder's cell
+def test_latent_programs_at_the_cells_sizes_compile_into_the_chip(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``benchmarks/configs/joyai_flash_pp8.json`` as its driver exports it
+    — published widths, layers 0-4 (``LF`` + ``LG`` x 4), all 256 gated
+    experts and the whole vocabulary, 64 decode slots over 4,864 latent
+    pages of 128 tokens — lowered for the TPU, reloaded and compiled for
+    the described chip: the one decode program holds the latent kernel at
+    every ``L`` layer and no gather, the gated grouped products are the
+    program's kernel, the prefill at the largest bucket attends through
+    the K/V-tiled flash kernel, the ONE pool is rewritten in place where it
+    lies (its pages' tokens on the lanes, no pool-sized copy or turn), and
+    weights, pool and the largest prefill's temporaries fit one chip's
+    15.75 GiB.  (Weights are shapes only: nothing is made.)"""
+    import json
+    import os
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "joyai_flash_pp8.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "offline_docreason_s64.json")) as f:
+        traffic = json.load(f)
+    sz = cell["sizes"]
+    bucket = traffic["prompt_buckets"][-1]
+    model = HybridLM(HybridLMConfig(dtype=BF16, **sz["lm"]))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    with jax.default_matmul_precision(None):
+        deploy.export_generation(
+            model, shapes, prefix, sampling=True,
+            decode_batch=sz["decode_batch"], prompt_buckets=[bucket],
+            max_context=traffic["max_context"],
+            page_size=sz["page_tokens"], decode_widths=sz["decode_widths"],
+            include_params=False, replay=sz["replay"])
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    width = sz["decode_widths"][-1]
+    assert meta["decode_widths"] == [width] == [76]
+    assert meta["paged"] == {str(width): {
+        "impl": "latent", "reason": None, "quantized": False}}
+    assert meta["grouped"] == {program: {
+        "impl": "grouped", "reason": None, "sites": 8}
+        for program in ("prefill-s%d" % bucket, "decode-w%d" % width)}
+    assert meta["replay"] == {"layers": 4, "top_k": 8}
+    assert meta["kv"]["pools"] == ["kv"] and meta["kv"]["row_width"] == 576
+    spec = compile_for_chip.spec
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows, pages = sz["decode_batch"], cell["knobs"]["serving.kv_pages"]
+    cache = on_chip(deploy._kv_pool_specs(meta["kv"], pages, rows))
+    assert [c.shape for c in cache] == [(5, 4864, 576, 128)]
+
+    def sample(b):
+        return (spec((b,), F32), spec((b,), jnp.int32), spec((b,), F32),
+                spec((b, 2), jnp.uint32))
+
+    def compiled(path, *specs):
+        with open(path, "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return jax.jit(exp.call, donate_argnums=(1,)).lower(
+            on_chip(shapes), cache, *specs).compile()
+
+    decode = compiled("%s-decode-w%d.stablehlo" % (prefix, width),
+                      spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                      spec((rows, width), jnp.int32), *sample(rows))
+    # tokens, three counts, log-probabilities, experts [4, rows, 8]
+    assert decode.out_info[1].shape == (2 * rows + 3 + 4 * rows * 8,)
+    text = decode.as_text()
+    assert text.count("mx_latent_paged_attention") >= 5
+    assert "mx.kv_gather" not in text and "mx_paged_attention" not in text
+    prefill = compiled(
+        "%s-prefill-s%d.stablehlo" % (prefix, bucket),
+        spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
+        spec((1, -(-bucket // sz["page_tokens"])), jnp.int32), *sample(1))
+    assert prefill.as_text().count("mx_attention_tiled") >= 5
+    pool = "bf16[5,4864,576,128]"
+    for program in (text, prefill.as_text()):
+        # two grouped products a G block, under the scope that names them
+        assert program.count("mx_grouped_matmul") >= 8
+        assert "mx.moe_experts" in program and "ragged" not in program
+        # the pool keeps the layout it is handed in: no copy turns it
+        assert pool + "{3,2,1,0" in program
+        assert not [ln for ln in program.splitlines()
+                    if pool in ln.split("=")[0] and " copy(" in ln]
+        assert pool + "{2,3,1,0" not in program
+    cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
+    assert round(cache_bytes / 2 ** 30, 2) == 3.34
+    need = []
+    for program in (decode, prefill):
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes       # in place
+        need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    assert max(need) < 15.5 * 2 ** 30, need     # 15.75 less what is reserved
+    assert min(need) > 0.85 * 15.75 * 2 ** 30   # and the chip is filled
