@@ -19,7 +19,8 @@ one process; nothing here starts a child once jax is touched):
   retention  the third kind of cache row: one power-retention block at
            toy widths with real lanes (heads of 128), its chunked prefill
            and its decode update, compiled, against the quadratic form
-           that defines the layer
+           that defines the layer; the update's kernel against its twin,
+           and behind the server, its route counted
   kernels  every Pallas kernel on those paths, compiled, against its XLA twin
 
 ``--chips 4`` runs ``device`` and, instead of the phases above, the
@@ -125,7 +126,12 @@ def _sizes(rehearse):
                 lm=dict(vocab_size=64, pattern="RF", d_model=64, num_heads=4,
                         num_kv_heads=2, head_dim=16, mlp_ff=96, chunk=8,
                         max_len=64),
-                rows=2, prompt=11, steps=3),
+                rows=2, prompt=11, steps=3,
+                # (the update's kernel wants whole lanes: rows, K/V heads,
+                # query heads a K/V head, head width)
+                update=(1, 1, 2, 128),
+                serve=dict(prompts=(5, 11, 3), bucket=16, new_tokens=3,
+                           page=4)),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
             grouped=(200, 8, 128, 256), softmax=(16, 48), sbr=(16, 40),
             dp_batch=16, lm_batch=4, lm_seq=32)
@@ -159,7 +165,9 @@ def _sizes(rehearse):
             lm=dict(vocab_size=1024, pattern="RF", d_model=1024, num_heads=10,
                     num_kv_heads=2, head_dim=128, mlp_ff=2048, chunk=128,
                     max_len=512),
-            rows=4, prompt=300, steps=12),
+            rows=4, prompt=300, steps=12, update=(4, 2, 5, 128),
+            serve=dict(prompts=(40, 300, 130, 7, 61), bucket=512,
+                       new_tokens=12, page=128)),
         flash=((8, 12, 1024, 64), (2, 12, 200, 64)), paged_k=(1024, 2048),
         # rows, groups, K, N: a decode step's pairs over a quarter of the
         # hybrid cell's held experts, at their widths
@@ -576,10 +584,15 @@ def phase_retention(args, sz):
     prefill and then its decode update, compiled, the float32 state donated
     and rewritten in place, against the QUADRATIC form that defines the
     layer (``A[t,s] = (q_t . k_s)^2 prod g``, ``y = A v / sum A``) computed
-    in float32 at full precision from the same q, k, v and gates."""
+    in float32 at full precision from the same q, k, v and gates; the
+    update's Pallas kernel against its XLA twin at heads of whole lanes;
+    and the block behind the server, the kernel's counter held to the
+    decode dispatches."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import kernels, telemetry
     from mxnet_tpu.models import HybridLM, HybridLMConfig
 
     rs = sz["retention"]
@@ -609,23 +622,95 @@ def phase_retention(args, sz):
     step = jax.jit(lambda lp, x, pos, state, z: model._ret_step(
         x, lp, pos, state, z), donate_argnums=(3, 4))
     want = np.asarray(quadratic(lp, x), np.float32)
-    out, state, z = prefill(lp, x[:, :S])
-    got = [np.asarray(out, np.float32)]
-    for t in range(S, S + T):
-        out, state, z = step(lp, x[:, t], jnp.full((B,), t, jnp.int32),
-                             state, z)
-        got.append(np.asarray(out, np.float32)[:, None])
-    got = np.concatenate(got, axis=1)
-    err = _scaled_err(got[:, :S], want[:, :S])[1], \
-        _scaled_err(got[:, S:], want[:, S:])[1]
-    # an MXU product rounds its inputs to bf16 whatever their dtype
-    tol = 2.0 ** -6
+    names = ("kernels.retention_update", "kernels.retention_fallback")
+
+    def counts():
+        return {n: telemetry.counter(n).value for n in names}
+
+    # heads of whole lanes take the kernel, narrower ones its twin
+    route = "xla" if model.cfg.head_dim % 128 else "retention"
+    with _knobs(kernels__enabled=True):
+        out, state, z = prefill(lp, x[:, :S])
+        got = [np.asarray(out, np.float32)]
+        for t in range(S, S + T):
+            out, state, z = step(lp, x[:, t], jnp.full((B,), t, jnp.int32),
+                                 state, z)
+            got.append(np.asarray(out, np.float32)[:, None])
+        got = np.concatenate(got, axis=1)
+        err = _scaled_err(got[:, :S], want[:, :S])[1], \
+            _scaled_err(got[:, S:], want[:, S:])[1]
+
+        # the kernel against its twin: random state, one update
+        b, kvh, r, dh = rs["update"]
+        n = dh * (dh + 1) // 2
+        keys = jax.random.split(jax.random.PRNGKey(args.seed + 2), 6)
+        case = (jax.random.normal(keys[0], (b, kvh, n, dh)),
+                1.0 + jax.random.uniform(keys[1], (b, kvh, n)),
+                jnp.square(jax.random.normal(keys[2], (b, kvh, n))),
+                jnp.square(jax.random.normal(keys[3], (b, kvh, r, n))),
+                jax.random.uniform(keys[4], (b, kvh), minval=0.9,
+                                   maxval=0.999),
+                jax.random.normal(keys[5], (b, kvh, dh), jnp.bfloat16))
+        twin = jax.jit(kernels._retention_update_xla)(*case)
+        with kernels.record_retention_routes() as routes:
+            ours = jax.jit(kernels.retention_update)(*case)
+        kernel_err = max(_scaled_err(a, w)[1] for a, w in zip(ours, twin))
+
+        # the block behind the server: one update an ``R`` block a dispatch
+        sv = rs["serve"]
+        rng = np.random.RandomState(args.seed)
+        prompts = [rng.randint(0, model.cfg.vocab_size, p).astype(np.int32)
+                   for p in sv["prompts"]]
+        params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+        with _knobs(serving__kv_pages=1), \
+                tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+            prefix = os.path.join(tmp, "retention")
+            mx.deploy.export_generation(
+                model, params, prefix, sampling=True, decode_batch=B,
+                prompt_buckets=[sv["bucket"]], page_size=sv["page"],
+                max_context=model.cfg.max_len)
+            with open(prefix + "-meta.json") as f:
+                meta = json.load(f)
+            srv = mx.serving.Server()
+            srv.register("retention", prefix, generate=True)
+            srv.start()
+            started = counts()
+            steps0 = telemetry.timer("serving.decode_step_ms").count
+            futs = [srv.submit_generate("retention", p, sv["new_tokens"])
+                    for p in prompts]
+            served = [np.asarray(f.result(timeout=900)) for f in futs]
+            steps = telemetry.timer("serving.decode_step_ms").count - steps0
+            delta = {n: v - started[n] for n, v in counts().items()}
+            srv.stop()
+    blocks = model.cfg.pattern.count("R")
+    taken, other = names if route == "retention" else names[::-1]
+    # an MXU product rounds its inputs to bf16 whatever their dtype; the
+    # update's kernel keeps float32 products and sums, as its twin does
+    tol, f32_tol = 2.0 ** -6, 1e-5
     _emit("retention", rows=B, prompt=S, steps=T,
           state_shape=list(state.shape), scan_err=err[0], update_err=err[1],
-          tolerance=tol)
+          tolerance=tol, update_shape=list(rs["update"]),
+          kernel_route=routes[0]["impl"], kernel_err=kernel_err,
+          kernel_tolerance=f32_tol,
+          served_routes={p: r.get("impl")
+                         for p, r in meta["retention"].items()},
+          decode_iterations=steps, counters=delta)
     _require(np.isfinite(got).all(), "retention output is not finite")
     _require(max(err) <= tol, "retention against the quadratic form: "
              "scan %.3g, update %.3g over %.3g" % (err + (tol,)))
+    _require(routes[0]["impl"] == "retention" and kernel_err <= f32_tol,
+             "the update's kernel against its twin: route %s, error %.3g "
+             "over %.3g" % (routes[0], kernel_err, f32_tol))
+    _require(all(s.shape == (sv["new_tokens"],) for s in served),
+             "a request did not return %d tokens" % sv["new_tokens"])
+    _require(set(r["impl"] for r in meta["retention"].values()) == {route},
+             "the served decode program's update is %s, want %s"
+             % (meta["retention"], route))
+    _require(steps > 0 and delta[taken] == steps * blocks
+             and delta[other] == 0,
+             "%s counted %d over %d decode iterations of %d R block(s), "
+             "%s %d" % (taken, delta[taken], steps, blocks, other,
+                        delta[other]))
 
 
 def _teacher_forced_gaps(model, params, prompts, served):

@@ -794,13 +794,13 @@ _ON_SET["serving.decode_slots"] = _positive_int_knob("serving.decode_slots")
 register_knob(
     "kernels.enabled", "MXNET_TPU_KERNELS", bool, True,
     "the Pallas kernel tier (mx.kernels). One static rule routes "
-    "attention, paged decode attention and the held experts' grouped "
-    "product at trace time: off -> the XLA "
+    "attention, paged decode attention, the held experts' grouped "
+    "product and the retention update at trace time: off -> the XLA "
     "lowering, byte-identical to a program without the tier; at its "
     "default on a backend that interprets Pallas (CPU/GPU) -> the XLA "
     "lowering (kernels.gated_fallback); a shape the kernel cannot take "
     "-> the XLA lowering (kernels.fallback / kernels.paged_fallback / "
-    "kernels.grouped_fallback); "
+    "kernels.grouped_fallback / kernels.retention_fallback); "
     "else the kernel. Set explicitly on (env or set()) the kernels also "
     "run in the interpreter.")
 register_knob(

@@ -589,19 +589,25 @@ def export_generation(model, params, prefix, page_size=None,
     paths = []
     paged_routes = {}
     grouped_routes = {}
+    retention_routes = {}
 
     def _export_one(fn, arg_specs, program, route_key=None):
         # the pool's page count stays symbolic inside the paged kernel too
         with _kernels.record_paged_routes() as routes, \
                 _kernels.record_grouped_routes() as grouped, \
+                _kernels.record_retention_routes() as retention, \
                 _kernels.pallas_dynamic_shapes():
             exp = jexport.export(jax.jit(fn))(*arg_specs)
-        if grouped:
-            # a program's grouped products (two an expert block) share
-            # their shapes' verdict; one that fell back names the program
-            refused = [r for r in grouped if r["impl"] != "grouped"]
-            grouped_routes[program] = dict((refused or grouped)[0],
-                                           sites=len(grouped))
+        # a program's grouped products (two an expert block) share their
+        # shapes' verdict, as its retention updates (one an ``R`` block)
+        # do; one that fell back names the program
+        for sites, kernel, by_program in (
+                (grouped, "grouped", grouped_routes),
+                (retention, "retention", retention_routes)):
+            if sites:
+                refused = [r for r in sites if r["impl"] != kernel]
+                by_program[program] = dict((refused or sites)[0],
+                                           sites=len(sites))
         path = "%s-%s.stablehlo" % (prefix, program)
         if route_key is not None and paged:
             # one paged_attention route per scanned stack trace; the scan
@@ -720,6 +726,7 @@ def export_generation(model, params, prefix, page_size=None,
         "kv": dict(spec, page_size=psz),
         "paged": paged_routes,
         "grouped": grouped_routes,
+        "retention": retention_routes,
     }
     if v5:
         meta["sampling"] = True
@@ -794,6 +801,8 @@ class GenerationPredictor:
         #: the same for the grouped products, by program ("decode-w16",
         #: "prefill-s128"); a program without one has no entry
         self.grouped_routes = dict(self.meta.get("grouped", {}))
+        #: and for the retention updates (a decode program's alone)
+        self.retention_routes = dict(self.meta.get("retention", {}))
         self._v5 = self.format_version >= SAMPLING_FORMAT_VERSION
         #: the cache's state region (per-slot arrays beside the pages) and
         #: the names of the counts a decode step returns behind its tokens

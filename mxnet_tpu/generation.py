@@ -899,22 +899,31 @@ class GenerationEngine:
         slot.ttft_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.prefill_ms").observe((t1 - t0) * 1e3)
         _telemetry.timer("serving.ttft_ms").observe(slot.ttft_ms)
-        self._count_grouped_route("prefill-s%d" % s_bucket)
+        self._count_program_routes("prefill-s%d" % s_bucket)
         self._count_tokens(1)
         self._maybe_finish(slot_idx)
         return True
 
-    def _count_grouped_route(self, program):
+    def _count_program_routes(self, program):
         """Serve-side mirror of the export-time verdict on a program's
         grouped products, once a dispatch: the Pallas kernel ran them, or
-        ``lax.ragged_dot`` did while the kernel tier was on."""
+        ``lax.ragged_dot`` did while the kernel tier was on; and on its
+        retention updates, once an ``R`` block a dispatch
+        (``kernels.retention_update`` / ``kernels.retention_fallback``)."""
         route = self.predictor.grouped_routes.get(program)
-        if route is None:
-            return
-        if route.get("impl") == "grouped":
-            _telemetry.counter("kernels.grouped_matmul").inc()
-        elif _kernels_enabled():
-            _telemetry.counter("kernels.grouped_fallback").inc()
+        if route is not None:
+            if route.get("impl") == "grouped":
+                _telemetry.counter("kernels.grouped_matmul").inc()
+            elif _kernels_enabled():
+                _telemetry.counter("kernels.grouped_fallback").inc()
+        route = self.predictor.retention_routes.get(program)
+        if route is not None:
+            if route.get("impl") == "retention":
+                _telemetry.counter("kernels.retention_update").inc(
+                    route["sites"])
+            elif _kernels_enabled():
+                _telemetry.counter("kernels.retention_fallback").inc(
+                    route["sites"])
 
     def _dispatch_decode(self):
         """One decode iteration for every active slot.  The page-table
@@ -1029,7 +1038,7 @@ class GenerationEngine:
                 took.inc()
             elif _kernels_enabled():
                 fell_back.inc()
-        self._count_grouped_route("decode-w%d" % width)
+        self._count_program_routes("decode-w%d" % width)
         with _tracing.span("engine.decode.emit", cat="serving") as emit:
             self._count_tokens(len(active))
             gap = _telemetry.timer("serving.token_gap_ms")

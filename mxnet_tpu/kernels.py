@@ -1,9 +1,10 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  The four routed sites
+this module alone decides WHEN they run.  The five routed sites
 (:func:`attention`, :func:`paged_attention`,
-:func:`latent_paged_attention`, :func:`grouped_matmul`) ask one rule
+:func:`latent_paged_attention`, :func:`grouped_matmul`,
+:func:`retention_update`) ask one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -16,14 +17,15 @@ time and nothing else — nothing is timed, persisted or remembered:
    there, which is how the parity tests run it;
 3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
    :func:`paged_unsupported_reason`, :func:`latent_unsupported_reason`,
-   :func:`grouped_unsupported_reason`) → the XLA lowering
+   :func:`grouped_unsupported_reason`,
+   :func:`retention_unsupported_reason`) → the XLA lowering
    (``kernels.fallback`` / ``kernels.paged_fallback`` /
-   ``kernels.latent_fallback`` / ``kernels.grouped_fallback``), never an
-   error;
+   ``kernels.latent_fallback`` / ``kernels.grouped_fallback`` /
+   ``kernels.retention_fallback``), never an error;
 4. else the kernel (``kernels.flash_attention`` /
    ``kernels.paged_attention`` / ``kernels.latent_paged`` /
-   ``kernels.grouped_matmul``), at block sizes that are constants or
-   functions of the shapes.
+   ``kernels.grouped_matmul`` / ``kernels.retention_update``), at block
+   sizes that are constants or functions of the shapes.
 
 The decision is trace-time python, so a jitted program contains one
 path only; a knob change retraces (``config.epoch()`` in the program
@@ -43,17 +45,20 @@ from . import telemetry as _telemetry
 from .ops.pallas_kernels import (flash_attention, flash_attention_tiled,
                                  grouped_col_tile, pallas_grouped_matmul,
                                  pallas_latent_paged_attention,
-                                 pallas_paged_attention)
+                                 pallas_paged_attention,
+                                 pallas_retention_update,
+                                 retention_row_tile)
 
 __all__ = ["enabled", "attention", "paged_attention",
-           "latent_paged_attention", "grouped_matmul",
+           "latent_paged_attention", "grouped_matmul", "retention_update",
            "flash_unsupported_reason", "tiled_unsupported_reason",
            "paged_unsupported_reason", "latent_unsupported_reason",
-           "grouped_unsupported_reason", "record_paged_routes",
-           "record_grouped_routes", "pallas_dynamic_shapes",
+           "grouped_unsupported_reason", "retention_unsupported_reason",
+           "record_paged_routes", "record_grouped_routes",
+           "record_retention_routes", "pallas_dynamic_shapes",
            "flash_attention", "flash_attention_tiled",
            "pallas_paged_attention", "pallas_latent_paged_attention",
-           "pallas_grouped_matmul"]
+           "pallas_grouped_matmul", "pallas_retention_update"]
 
 # one-row VMEM feasibility: a q block keeps its head's full K and V
 # resident, so 2 * Skv * D * itemsize must fit the budget
@@ -287,12 +292,14 @@ def pallas_dynamic_shapes():
 
 
 # Export-time route capture: deploy.export_generation traces every
-# program under record_paged_routes() / record_grouped_routes() and lands
-# the impl/reason of the routed sites in the artifact meta — the serve
-# path then counts kernels.paged_attention / paged_fallback and
-# kernels.grouped_matmul / grouped_fallback per dispatch without
-# re-tracing (the program is AOT; trace-time counters fire at export).
-_ROUTE_SINKS = {"paged": [], "grouped": []}
+# program under record_paged_routes() / record_grouped_routes() /
+# record_retention_routes() and lands the impl/reason of the routed
+# sites in the artifact meta — the serve path then counts
+# kernels.paged_attention / paged_fallback, kernels.grouped_matmul /
+# grouped_fallback and kernels.retention_update / retention_fallback per
+# dispatch without re-tracing (the program is AOT; trace-time counters
+# fire at export).
+_ROUTE_SINKS = {"paged": [], "grouped": [], "retention": []}
 # (a latent site's route rides in the paged sink: a model keeps one kind
 # of page, so a decode program has one kind of paged site)
 
@@ -318,6 +325,13 @@ def record_grouped_routes():
     for every grouped-product route decision made while tracing under
     this context."""
     return _record_routes("grouped")
+
+
+def record_retention_routes():
+    """Collect ``{"impl", "reason"}`` dicts (``impl`` "retention" or
+    "xla") for every retention-update route decision made while tracing
+    under this context."""
+    return _record_routes("retention")
 
 
 def _note_route(site, **route):
@@ -672,3 +686,87 @@ def grouped_matmul(rows, w, sizes, epilogue=None, out_dtype=jnp.float32,
                                      out_dtype=out_dtype, w_b=w_b)
     _note_route("grouped", impl="xla", reason=reason)
     return _grouped_matmul_xla(rows, w, sizes, epilogue, out_dtype, w_b)
+
+
+# ------------------------------------------------------ retention update
+def retention_unsupported_reason(state, z, pk, pq, g, v):
+    """Why the Pallas retention update can NOT take this call, or None if
+    it can.  Trace-time shape/dtype checks only.  A non-None reason
+    routes to the XLA twin (``kernels.retention_fallback``) and is
+    surfaced in the export route sink (:func:`record_retention_routes`)."""
+    ranks = tuple(a.ndim for a in (state, z, pk, pq, g, v))
+    if ranks != (4, 3, 3, 4, 2, 3):
+        return "rank: state, z, pk, pq, g, v %s, want (4, 3, 3, 4, 2, 3)" \
+            % (ranks,)
+    dims = sum((tuple(a.shape) for a in (state, z, pk, pq, g, v)), ())
+    if not all(isinstance(d, int) for d in dims):
+        return "symbolic shape (state%s pq%s)" % (state.shape, pq.shape)
+    b, kvh, n, dh = state.shape
+    if z.shape != (b, kvh, n) or pk.shape != z.shape or g.shape != (b, kvh) \
+            or v.shape != (b, kvh, dh) or pq.shape[:2] + pq.shape[3:] \
+            != (b, kvh, n):
+        return "z%s pk%s pq%s g%s v%s do not match the state %s" % (
+            tuple(z.shape), tuple(pk.shape), tuple(pq.shape),
+            tuple(g.shape), tuple(v.shape), tuple(state.shape))
+    if state.dtype != jnp.float32:
+        return "the state is %s, want float32" % state.dtype
+    if dh % 128:
+        return "Dh=%d must be a multiple of 128" % dh
+    if retention_row_tile(n, dh) is None:
+        return "no row tile divides N=%d in multiples of 8 inside the " \
+            "vmem budget %d" % (n, _config.get("kernels.vmem_budget"))
+    return None
+
+
+def _retention_update_xla(state, z, pk, pq, g, v):
+    """The XLA twin of the retention update: the decayed state plus the
+    token's outer product, the normaliser beside it, and each query head's
+    read-out of the new state as a sum over its rows (not an MXU product:
+    the state is not rounded on the way), all float32."""
+    f32 = jnp.float32
+    state = g[..., None, None] * state \
+        + pk[..., None] * v.astype(f32)[:, :, None, :]
+    z = g[..., None] * z + pk
+    num = jnp.sum(pq[..., None] * state[:, :, None], axis=3)
+    den = jnp.sum(pq * z[:, :, None], axis=-1)
+    return state, z, num, den
+
+
+def retention_update(state, z, pk, pq, g, v):
+    """One token a row through a power-retention layer's state, with
+    kernel routing: ``S <- g S + phi(k) v^T`` and ``z <- g z + phi(k)``
+    in float32, then the ``R`` query heads of each K/V head read the new
+    state, ``num = phi(q)^T S`` and ``den = phi(q) . z`` (the layer's
+    output is ``num / den``).
+
+    ``state`` ``[B, KVH, N, Dh]`` and ``z`` ``[B, KVH, N]`` float32;
+    ``pk`` ``[B, KVH, N]`` and ``pq`` ``[B, KVH, R, N]`` the expanded key
+    and queries, float32; ``g`` ``[B, KVH]`` the gates; ``v`` ``[B, KVH,
+    Dh]``.  Returns ``(state, z, num [B, KVH, R, Dh], den [B, KVH, R])``.
+    A zero key under a gate of one leaves state and normaliser as they
+    were, bit for bit, on both routes.
+
+    Routing: tier on and shape feasible (concrete dims, a float32 state,
+    ``Dh`` a multiple of 128, a row tile that divides ``N`` inside
+    ``kernels.vmem_budget``) → the Pallas kernel
+    (``ops.pallas_kernels.pallas_retention_update``, named
+    ``mx_retention_update``), which streams each head's state through
+    on-chip memory once — update, write back, read-outs of the resident
+    tile; counter ``kernels.retention_update``.  Otherwise → the XLA twin,
+    two fusions that read the state twice: tier off, the default knob on
+    an interpreted backend (``kernels.gated_fallback``), or a shape the
+    kernel cannot take (``kernels.retention_fallback``).  Both keep
+    float32 products and sums; the read-outs differ by the order of their
+    sums.  The decision and its reason land in those counters and, under
+    :func:`record_retention_routes`, in the export route sink.  The call
+    carries no scope of its own: the caller's (``mx.retention_update``)
+    names the kernel's device time."""
+    reason = _route_reason(
+        lambda: retention_unsupported_reason(state, z, pk, pq, g, v),
+        _telemetry.counter("kernels.retention_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.retention_update").inc()
+        _note_route("retention", impl="retention", reason=None)
+        return pallas_retention_update(state, z, pk, pq, g, v)
+    _note_route("retention", impl="xla", reason=reason)
+    return _retention_update_xla(state, z, pk, pq, g, v)

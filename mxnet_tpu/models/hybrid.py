@@ -571,19 +571,15 @@ class HybridLM:
         """One token a row: x [B,D], positions [B], state [B,KVH,N,Dh] and
         z [B,KVH,N] f32 -> (out [B,D], new state, new z): ``S <- g S +
         phi(k) v^T`` in float32, then the query heads of each K/V head
-        read the new state (a sum over its rows, not an MXU product: the
-        state is not rounded on the way)."""
-        f32 = jnp.float32
+        read the new state, the state not rounded on the way
+        (``kernels.retention_update``: one Pallas pass over the state, or
+        its XLA twin)."""
         q, k, v, logg = (a[:, 0] for a in self._ret_parts(
             x[:, None], lp, positions[:, None]))
         with jax.named_scope("mx.retention_update"):
             pq, pk = _phi(q), _phi(k)            # [B,KVH,R,N], [B,KVH,N]
-            g = jnp.exp(logg)
-            state = g[..., None, None] * state \
-                + pk[..., None] * v.astype(f32)[:, :, None, :]
-            z = g[..., None] * z + pk
-            num = jnp.sum(pq[..., None] * state[:, :, None], axis=3)
-            den = jnp.sum(pq * z[:, :, None], axis=-1)
+            state, z, num, den = _kernels.retention_update(
+                state, z, pk, pq, jnp.exp(logg), v)
             y = (num / den[..., None]).astype(x.dtype)   # [B,KVH,R,Dh]
         return self._ret_out(y, lp), state, z
 

@@ -31,7 +31,8 @@ from .registry import register
 __all__ = ["pallas_row_softmax", "pallas_scale_bias_relu",
            "pallas_flash_attention", "flash_attention",
            "flash_attention_tiled", "pallas_paged_attention",
-           "pallas_latent_paged_attention", "pallas_grouped_matmul"]
+           "pallas_latent_paged_attention", "pallas_grouped_matmul",
+           "pallas_retention_update"]
 
 _NEG = -1e30
 
@@ -1082,6 +1083,125 @@ def pallas_grouped_matmul(rows, w, sizes, epilogue=None,
         interpret=interpret_mode(), name="mx_grouped_matmul")(
             *items, rows, w, *((w_b,) if pair else ()))
     return out[:m]
+
+
+# ------------------------------------------------------- retention update
+def retention_row_tile(n, dh):
+    """Rows of a K/V head's float32 ``[n, dh]`` state a step of the
+    retention update streams: the most that divide ``n``, are a multiple
+    of the 8 sublanes and whose ``[tile, dh]`` block fits
+    ``kernels.vmem_budget`` (the pipeline holds two coming in and two
+    going out), or None where no tile does."""
+    from .. import config as _config
+    tile = _row_block(n, dh * 4)
+    return tile if tile % _SUBLANES == 0 and tile * dh * 4 \
+        <= _config.get("kernels.vmem_budget") else None
+
+
+def _retention_update_kernel(r, g_ref, s_ref, vec_ref, v_ref, so_ref,
+                             num_ref):
+    """One ``[tile, Dh]`` block of one K/V head's float32 state, through
+    on-chip memory once: ``S <- g S + phi(k) v^T`` on the vector unit,
+    written back, and the query heads' read-outs of the NEW rows
+    accumulated over the head's tiles while the block is resident.
+
+    ``vec_ref`` ``[rows, tile]`` holds the tile's ``phi(q)`` (rows 0..r-1)
+    and ``phi(k)`` (row r) along the lanes.  The state's rows lie on the
+    sublanes, so ``phi(k)`` is turned once a tile (a transpose of the
+    small block) and broadcast along the lanes against ``v``'s row; the
+    read-outs are ``[rows, tile] x [tile, Dh]``, which the MXU takes at
+    float32 contract precision (the state is not rounded on the way: the
+    sum differs from the vector unit's by its order alone)."""
+    from jax.experimental import pallas as pl
+    t = pl.program_id(2)
+    g = g_ref[pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)]
+    vecs = vec_ref[...]
+    state = g * s_ref[...] + vecs.T[:, r:r + 1] * v_ref[...]
+    so_ref[...] = state
+    num = jax.lax.dot_general(vecs, state, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
+
+    @pl.when(t == 0)
+    def _():
+        num_ref[...] = num
+
+    @pl.when(t > 0)
+    def _():
+        num_ref[...] += num
+
+
+def pallas_retention_update(state, z, pk, pq, g, v):
+    """One decode step of a power-retention layer's state, every row and
+    K/V head: ``S <- g S + phi(k) v^T``, ``z <- g z + phi(k)`` and the
+    query heads' read-outs of the new state, ``num = phi(q)^T S`` and
+    ``den = phi(q) . z``, in float32.
+
+    state [B, KVH, N, Dh] f32; z, pk [B, KVH, N] f32; pq [B, KVH, R, N]
+    f32; g [B, KVH] f32; v [B, KVH, Dh].  Returns ``(state, z, num [B,
+    KVH, R, Dh], den [B, KVH, R])``.  The grid walks (row, K/V head,
+    state tile): a :func:`retention_row_tile` of the state crosses HBM
+    once in and once out, rewritten in place (``input_output_aliases``),
+    where two XLA fusions read it twice and write it once.  The
+    normaliser — 1/129 of the bytes — stays the elementwise update and the
+    reduction XLA makes of it: no multiple of the 128 lanes divides ``N =
+    Dh (Dh + 1) / 2``, so the vectors along ``N`` reach the kernel cut
+    into the state's tiles (``[B, KVH, tiles, rows, tile]``, a block its
+    array's whole last two axes).  ``Dh`` is a multiple of 128 and a tile
+    exists: routing/fallback policy lives in
+    ``mx.kernels.retention_update``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    b, kvh, n, dh = state.shape
+    r = pq.shape[2]
+    tile = retention_row_tile(n, dh)
+    if state.dtype != jnp.float32 or dh % _LANES or tile is None \
+            or pq.shape != (b, kvh, r, n) or pk.shape != (b, kvh, n) \
+            or z.shape != pk.shape or g.shape != (b, kvh) \
+            or v.shape != (b, kvh, dh):
+        raise ValueError(
+            "retention update takes a float32 state [B, KVH, N, Dh] with "
+            "Dh a multiple of %d and a row tile inside kernels.vmem_budget,"
+            " z and pk [B, KVH, N], pq [B, KVH, R, N], g [B, KVH] and v "
+            "[B, KVH, Dh], got %s %s, %s, %s, %s, %s and %s"
+            % (_LANES, state.dtype, state.shape, z.shape, pk.shape,
+               pq.shape, g.shape, v.shape))
+    f32 = jnp.float32
+    pk, pq, g = pk.astype(f32), pq.astype(f32), g.astype(f32)
+    rows = -(-(r + 1) // _SUBLANES) * _SUBLANES
+    vecs = jnp.concatenate(
+        [pq, pk[:, :, None], jnp.zeros((b, kvh, rows - r - 1, n), f32)],
+        axis=2)
+    vecs = jnp.moveaxis(vecs.reshape(b, kvh, rows, n // tile, tile), 3, 2)
+
+    def block(*shape):
+        return pl.BlockSpec((None, None) + shape,
+                            lambda i, j, t: (i, j, t, 0))
+
+    def resident(*shape):
+        return pl.BlockSpec((None, None) + shape,
+                            lambda i, j, t: (i, j, 0, 0))
+
+    state, num = pl.pallas_call(
+        functools.partial(_retention_update_kernel, r),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((b, kvh, rows, dh), f32)],
+        grid=(b, kvh, n // tile),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  block(tile, dh),
+                  pl.BlockSpec((None, None, None, rows, tile),
+                               lambda i, j, t: (i, j, t, 0, 0)),
+                  resident(1, dh)],
+        out_specs=[block(tile, dh), resident(rows, dh)],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret_mode(), name="mx_retention_update")(
+            g.reshape(-1), state, vecs, v.astype(f32)[:, :, None])
+    z = g[..., None] * z + pk
+    den = jnp.sum(pq * z[:, :, None], axis=-1)
+    return state, z, num[:, :, :r], den
 
 
 # ------------------------------------------------------- fused elementwise

@@ -88,6 +88,16 @@ def test_rehearsal_runs_every_phase_and_never_prints_the_success_line():
     assert retention["state_shape"] == [2, 2, 136, 16]
     assert max(retention["scan_err"], retention["update_err"]) \
         <= retention["tolerance"]
+    # the update's kernel (interpreted) against its twin at whole lanes;
+    # behind the server the toy's heads of 16 take the twin, counted once
+    # an R block a decode dispatch
+    assert retention["kernel_route"] == "retention"
+    assert retention["kernel_err"] <= retention["kernel_tolerance"]
+    assert retention["served_routes"] == {"decode-w1": "xla"}
+    assert retention["counters"] == {
+        "kernels.retention_update": 0,
+        "kernels.retention_fallback": retention["decode_iterations"]}
+    assert retention["decode_iterations"] > 0
     serve = by["serve"]
     assert serve["counters"]["kernels.paged_attention"] == \
         serve["decode_iterations"] > 0

@@ -692,6 +692,18 @@ def _grouped_site(infeasible=False):
             "kernels.grouped_matmul", "kernels.grouped_fallback", rows)
 
 
+def _retention_site(infeasible=False):
+    dh = 16 if infeasible else 128      # heads narrower than the lanes
+    n = dh * (dh + 1) // 2
+    state, z, pk, pq, g, v = (jnp.ones(shape, jnp.float32) for shape in (
+        (1, 1, n, dh), (1, 1, n), (1, 1, n), (1, 1, 2, n), (1, 1),
+        (1, 1, dh)))
+    return (lambda: kernels.retention_update(state, z, pk, pq, g, v),
+            "pallas_retention_update", ("mxnet_tpu.kernels",
+                                        "_retention_update_xla"),
+            "kernels.retention_update", "kernels.retention_fallback", state)
+
+
 #: knob setting, backend, shape -> implementation and the counter that moves
 ROUTES = {
     "default-interpreter": (None, True, False, "xla",
@@ -706,7 +718,8 @@ ROUTES = {
 
 
 @pytest.mark.parametrize("case", list(ROUTES))
-@pytest.mark.parametrize("site", ["attention", "paged", "grouped"])
+@pytest.mark.parametrize("site", ["attention", "paged", "grouped",
+                                  "retention"])
 def test_route_is_static(site, case, monkeypatch, tmp_path):
     """Which implementation a routed site takes follows from the knob,
     whether the backend interprets Pallas, and the shape — through the
@@ -717,7 +730,8 @@ def test_route_is_static(site, case, monkeypatch, tmp_path):
     knob, interpreted, infeasible, impl, moved = ROUTES[case]
     call, kernel_name, (twin_mod, twin_name), kernel_ctr, fallback_ctr, q = \
         {"attention": _attention_site, "paged": _paged_site,
-         "grouped": _grouped_site}[site](infeasible)
+         "grouped": _grouped_site,
+         "retention": _retention_site}[site](infeasible)
     if moved == "fallback":
         moved = fallback_ctr
     elif impl == "kernel":
@@ -767,8 +781,9 @@ def test_route_is_static(site, case, monkeypatch, tmp_path):
     assert asked == [1]
     counters = {n: telemetry.counter(n).value for n in (
         "kernels.flash_attention", "kernels.paged_attention",
-        "kernels.grouped_matmul", "kernels.fallback",
-        "kernels.paged_fallback", "kernels.grouped_fallback",
+        "kernels.grouped_matmul", "kernels.retention_update",
+        "kernels.fallback", "kernels.paged_fallback",
+        "kernels.grouped_fallback", "kernels.retention_fallback",
         "kernels.gated_fallback")}
     assert counters == {n: int(n == moved) for n in counters}
     assert opened == [] and not os.path.exists(cache)

@@ -328,21 +328,180 @@ def test_a_pattern_that_mixes_the_kinds_still_serves(tmp_path):
 
 @pytest.mark.parametrize("program,scopes", [
     ("decode", ("mx.retention_update", "mx.rope", "mx.mlp", "mx.qkv")),
-    ("prefill", ("mx.retention_scan", "mx.rope", "mx.mlp", "mx.qkv"))])
-def test_retention_programs_carry_their_scopes(program, scopes):
+    ("prefill", ("mx.retention_scan", "mx.rope", "mx.mlp", "mx.qkv")),
+    ("decode-kernel", ("mx.retention_update", "mx_retention_update",
+                       "mx.rope", "mx.mlp", "mx.qkv"))])
+def test_retention_programs_carry_their_scopes(program, scopes, monkeypatch,
+                                               kernel_knobs):
     """The device scopes the benchmark's readers look for are in the
-    lowered programs' operation names."""
-    model, params = _tiny()
-    kv = model.init_kv_pages(1, PAGE, slots=2)
+    lowered programs' operation names: on the twin's route (tiny heads),
+    and on the kernel's (heads of 128, lowered for the TPU with the tier
+    on), where the custom call lies under the update's scope."""
     i32 = jnp.int32
-    if program == "decode":
-        lowered = jax.jit(lambda p, c: model.decode_step(
+    if program == "decode-kernel":
+        from mxnet_tpu import rtc
+        monkeypatch.setattr(rtc, "interpret_mode", lambda: False)
+        mx.config.set("kernels.enabled", True)
+        model, params = _tiny(pattern="RF", num_heads=2, num_kv_heads=1,
+                              head_dim=128)
+        kv = model.init_kv_pages(1, PAGE, slots=2)
+        text = jax.jit(lambda p, c: model.decode_step(
             p, c, jnp.zeros((2,), i32), jnp.ones((2,), i32),
-            jnp.ones((2, 1), i32), PAGE)).lower(params, kv)
+            jnp.ones((2, 1), i32), PAGE)).trace(params, kv).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+        (call,) = [line for line in text.splitlines()
+                   if "@tpu_custom_call" in line]
+        where = call[call.rindex("loc(") + 4:].rstrip(")")
+        (named,) = [line for line in text.splitlines()
+                    if line.startswith(where + " = ")]
+        assert "mx.retention_update" in named \
+            and "mx_retention_update" in named, named
+        assert telemetry.counter("kernels.retention_update").value == 1
     else:
-        lowered = jax.jit(lambda p, c: model.prefill(
-            p, c, jnp.zeros((2, 8), i32), jnp.full((2,), 5, i32),
-            jnp.ones((2, 2), i32), PAGE)).lower(params, kv)
-    text = lowered.as_text(debug_info=True)
+        model, params = _tiny()
+        kv = model.init_kv_pages(1, PAGE, slots=2)
+        if program == "decode":
+            lowered = jax.jit(lambda p, c: model.decode_step(
+                p, c, jnp.zeros((2,), i32), jnp.ones((2,), i32),
+                jnp.ones((2, 1), i32), PAGE)).lower(params, kv)
+        else:
+            lowered = jax.jit(lambda p, c: model.prefill(
+                p, c, jnp.zeros((2, 8), i32), jnp.full((2,), 5, i32),
+                jnp.ones((2, 2), i32), PAGE)).lower(params, kv)
+        text = lowered.as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, scope
+
+
+# ------------------------------------------- the update's kernel and twin
+@pytest.fixture
+def kernel_knobs():
+    """The tier's knobs as the test found them, and fresh counters."""
+    telemetry.reset()
+    yield
+    mx.config.unset("kernels.enabled")
+    mx.config.unset("kernels.vmem_budget")
+
+
+def _update_case(dh, r, b, kvh, tokens=3, seed=0):
+    """q [b,S,kvh,r,dh], k, v [b,S,kvh,dh], log-gates [b,S,kvh]: entries
+    of one sign (``_retention_inputs``), and row 0's last token a padded
+    position, a zero key under a gate of one."""
+    rng = np.random.default_rng(seed)
+    q = np.abs(rng.normal(size=(b, tokens, kvh, r, dh))) / np.sqrt(dh)
+    k = np.abs(rng.normal(size=(b, tokens, kvh, dh))) / np.sqrt(dh)
+    v = rng.normal(size=(b, tokens, kvh, dh))
+    logg = np.log(rng.uniform(0.5, 0.999, size=(b, tokens, kvh)))
+    k[0, -1] = 0.0
+    logg[0, -1] = 0.0
+    return [jnp.asarray(a, jnp.float32) for a in (q, k, v, logg)]
+
+
+#: head width, query heads a K/V head, rows, K/V heads, state rows a step
+UPDATE_CASES = {
+    "one-query-head-3-tiles": (128, 1, 1, 1, 2752),
+    "five-heads-8-tiles-2x2": (128, 5, 2, 2, 1032),
+    "five-heads-2-tiles": (128, 5, 1, 1, 4128),
+    "three-heads-1-tile": (128, 3, 1, 2, 8256),
+    "nine-heads-of-256-4-tiles": (256, 9, 1, 1, 8224),
+}
+
+
+@pytest.mark.parametrize("case", list(UPDATE_CASES))
+def test_the_update_kernel_is_its_twin_and_the_reference(case, kernel_knobs):
+    """``pallas_retention_update`` (interpreted, under an explicit
+    ``kernels.enabled``) against ``_retention_update_xla`` token by token
+    from the zero state — state, normaliser and read-outs to float32
+    reassociation error — and its ``num / den`` against the reference's
+    recurrence; a padded position (zero key, gate one) leaves state and
+    normaliser as they were bit for bit.  The tile follows from the shapes
+    and ``kernels.vmem_budget`` alone."""
+    from mxnet_tpu import kernels
+    from mxnet_tpu.ops.pallas_kernels import retention_row_tile
+    dh, r, b, kvh, tile = UPDATE_CASES[case]
+    n = dh * (dh + 1) // 2
+    mx.config.set("kernels.enabled", True)
+    mx.config.set("kernels.vmem_budget", tile * dh * 4)
+    assert retention_row_tile(n, dh) == tile and n % tile == 0
+    q, k, v, logg = _update_case(dh, r, b, kvh)
+    state = jnp.zeros((b, kvh, n, dh), jnp.float32)
+    z = jnp.zeros((b, kvh, n), jnp.float32)
+    site, twin = jax.jit(kernels.retention_update), \
+        jax.jit(kernels._retention_update_xla)
+    ys = []
+    for t in range(q.shape[1]):
+        args = (state, z, hybrid._phi(k[:, t]), hybrid._phi(q[:, t]),
+                jnp.exp(logg[:, t]), v[:, t])
+        got, want = site(*args), twin(*args)
+        for a, w in zip(got, want):
+            assert a.shape == w.shape and a.dtype == jnp.float32
+            _close(a, w, 2e-6)
+        ys.append(got[2] / got[3][..., None])
+        if t == q.shape[1] - 1:
+            assert np.array_equal(got[0][0], state[0])
+            assert np.array_equal(got[1][0], z[0])
+            if b > 1:               # and the other rows moved
+                assert not np.array_equal(got[0][-1], state[-1])
+        state, z = got[:2]
+    assert telemetry.counter("kernels.retention_update").value == 1
+    assert telemetry.counter("kernels.retention_fallback").value == 0
+    y = jnp.stack(ys, axis=1)
+    for row in range(b):
+        _close(y[row], REF.retention_recurrent(
+            q[row], k[row], v[row], logg[row]), 2e-5)
+
+
+def test_the_update_routes_by_knob_backend_and_shape(kernel_knobs):
+    """Tier off -> the twin; the default knob on the cpu -> the twin and
+    ``kernels.gated_fallback``; explicit on -> the kernel and
+    ``kernels.retention_update``; a shape the check refuses (a bfloat16
+    state, heads of 16, a symbolic dim) -> the twin and
+    ``kernels.retention_fallback``, never an error."""
+    from jax import export as jexport
+    from mxnet_tpu import kernels
+
+    def case(dh=128, dtype=jnp.float32, b=1):
+        n = dh * (dh + 1) // 2
+        shapes = ((b, 1, n, dh), (b, 1, n), (b, 1, n), (b, 1, 2, n),
+                  (b, 1), (b, 1, dh))
+        return [jax.ShapeDtypeStruct(s, dtype if i == 0 else jnp.float32)
+                for i, s in enumerate(shapes)]
+
+    def count():
+        return {c: telemetry.counter("kernels." + c).value for c in (
+            "retention_update", "retention_fallback", "gated_fallback")}
+
+    def routed(*specs):
+        with kernels.record_retention_routes() as routes:
+            # (a fresh function: a cached trace would not ask again)
+            out = jax.eval_shape(
+                lambda *a: kernels.retention_update(*a), *specs)
+        assert [o.shape for o in out] == [
+            specs[0].shape, specs[1].shape,
+            specs[3].shape[:3] + specs[5].shape[2:], specs[3].shape[:3]]
+        return routes
+
+    mx.config.set("kernels.enabled", False)
+    assert routed(*case()) == [{"impl": "xla", "reason": "tier off"}]
+    mx.config.unset("kernels.enabled")
+    assert routed(*case()) == [{"impl": "xla", "reason": "interpreted"}]
+    assert count() == dict(retention_update=0, retention_fallback=0,
+                           gated_fallback=1)
+    mx.config.set("kernels.enabled", True)
+    assert routed(*case()) == [{"impl": "retention", "reason": None}]
+    assert count() == dict(retention_update=1, retention_fallback=0,
+                           gated_fallback=1)
+    for refused, why in ((case(dtype=jnp.bfloat16), "float32"),
+                         (case(dh=16), "multiple of 128")):
+        (route,) = routed(*refused)
+        assert route["impl"] == "xla" and why in route["reason"]
+    mx.config.set("kernels.vmem_budget", 1024)
+    (route,) = routed(*case())
+    assert route["impl"] == "xla" and "vmem budget" in route["reason"]
+    mx.config.unset("kernels.vmem_budget")
+    (rows,) = jexport.symbolic_shape("rows")
+    with kernels.record_retention_routes() as routes:
+        jexport.export(jax.jit(kernels.retention_update))(*case(b=rows))
+    assert routes[0]["impl"] == "xla" and "symbolic" in routes[0]["reason"]
+    assert count() == dict(retention_update=1, retention_fallback=4,
+                           gated_fallback=1)

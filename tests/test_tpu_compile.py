@@ -14,6 +14,7 @@ the TPU library, and every xdist worker imports this file), and every
 compile happens in the test's own process.
 """
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -533,6 +534,25 @@ def test_retention_programs_at_the_cells_sizes_compile_into_the_chip(
     text = decode.as_text()
     assert "mx.retention_update" in text and "mx.rope" in text
     assert "mx_paged_attention" not in text
+    # the update is the kernel, one an ``R`` block, under the scope the
+    # benchmark reads; and nothing makes a second copy of a layer's state:
+    # its only results of that size are the kernels' own, written over
+    # their operand
+    assert meta["retention"] == {"decode-w1": {
+        "impl": "retention", "reason": None, "sites": 8}}
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "mx_retention_update" in line]
+    assert len(calls) == 8
+    assert all("mx.retention_update" in line for line in calls)
+    state = "f32[%d,%d,8256,128]" % (rows, sz["lm"]["num_kv_heads"])
+    made = [line for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%\S+ = \(?" + re.escape(state), line)
+            and " parameter(" not in line and " get-tuple-element(" not in line
+            and " bitcast(" not in line]
+    assert made and all(
+        "mx_retention_update" in line
+        and "output_to_operand_aliasing={{0}: (1, {})}" in line
+        for line in made), [line[:200] for line in made]
     prefill = compiled(
         "%s-prefill-s%d.stablehlo" % (prefix, bucket),
         spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
