@@ -291,6 +291,9 @@ class GenerationEngine:
         self._kv = None
         self._prefill = {}    # prompt bucket -> compiled program
         self._decode = {}     # page-table width -> compiled program
+        # program -> what its ``*.dispatch`` span says of the call's host
+        # operands (known from the compiled shapes, never computed a step)
+        self._host_operands = {}
         self._iteration = 0   # engine.iteration spans, numbered from 1
         # request ids, from 1 (next() of a count is atomic: submit runs on
         # any thread)
@@ -322,6 +325,14 @@ class GenerationEngine:
                     jax.ShapeDtypeStruct((b, 2), _np.uint32))
 
         def compile_one(fn, arg_specs, label):
+            # parameters and pool live on the device; the rest of a call's
+            # operands are host arrays the runtime copies over each time
+            host = arg_specs[2:]
+            self._host_operands[label] = {
+                "host_args": len(host),
+                "host_bytes": sum(_math.prod(s.shape)
+                                  * _np.dtype(s.dtype).itemsize
+                                  for s in host)}
             t0 = _time.perf_counter()
             with _tracing.span("serving.compile", cat="serving",
                                model=self.name, program=label):
@@ -868,14 +879,23 @@ class GenerationEngine:
         # where the cache has a state region, the prompt's final state is
         # left in this request's slot, over whatever the slot held
         where = (_np.asarray([slot_idx], _np.int32),) if gp.state else ()
+        program = "prefill-s%d" % s_bucket
         # the span has the bounds of the serving.prefill_ms timer
         with _tracing.span("engine.prefill.device", cat="serving"):
             slot.t_prefill_start = t0 = _time.perf_counter()
             try:
-                self._kv, nxt = self._prefill[s_bucket](
-                    gp._params, self._kv, tokens,
-                    _np.asarray([req.plen], _np.int32), write_table,
-                    *where, temp, tk, tp, keys)
+                # the call into the exported program alone: host time
+                # inside JAX and the runtime, before the device starts
+                with _tracing.span(
+                        "engine.prefill.dispatch", cat="serving",
+                        **self._host_operands[program]):
+                    self._kv, nxt = self._prefill[s_bucket](
+                        gp._params, self._kv, tokens,
+                        _np.asarray([req.plen], _np.int32), write_table,
+                        *where, temp, tk, tp, keys)
+                # launch wait, execution and read-back all lie under the
+                # fetch: no block_until_ready splits them (a host round
+                # trip a step); the runtime's own events do, in a trace
                 with _tracing.span("engine.prefill.fetch", cat="serving"):
                     if req.want_replay:
                         nxt = _np.asarray(nxt)
@@ -899,7 +919,7 @@ class GenerationEngine:
         slot.ttft_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.prefill_ms").observe((t1 - t0) * 1e3)
         _telemetry.timer("serving.ttft_ms").observe(slot.ttft_ms)
-        self._count_program_routes("prefill-s%d" % s_bucket)
+        self._count_program_routes(program)
         self._count_tokens(1)
         self._maybe_finish(slot_idx)
         return True
@@ -989,14 +1009,17 @@ class GenerationEngine:
         if gp.state:
             sp.set(state_rows=len(active))
             _telemetry.gauge("serving.state_slots").set(len(active))
+        program = "decode-w%d" % width
         # the span has the bounds of the serving.decode_step_ms timer
         with _tracing.span("engine.decode.device", cat="serving"):
             t0 = _time.perf_counter()
             try:
-                self._kv, nxt = self._decode[width](
-                    gp._params, self._kv, token_ids, positions, table,
-                    temp, tk, tp, keys)
-                # what is left of .device outside .fetch is the dispatch
+                with _tracing.span(
+                        "engine.decode.dispatch", cat="serving",
+                        **self._host_operands[program]):
+                    self._kv, nxt = self._decode[width](
+                        gp._params, self._kv, token_ids, positions, table,
+                        temp, tk, tp, keys)
                 with _tracing.span("engine.decode.fetch", cat="serving"):
                     nxt = _np.asarray(nxt)
             except BaseException as exc:  # noqa: BLE001 — pool donated
@@ -1038,7 +1061,7 @@ class GenerationEngine:
                 took.inc()
             elif _kernels_enabled():
                 fell_back.inc()
-        self._count_program_routes("decode-w%d" % width)
+        self._count_program_routes(program)
         with _tracing.span("engine.decode.emit", cat="serving") as emit:
             self._count_tokens(len(active))
             gap = _telemetry.timer("serving.token_gap_ms")

@@ -359,6 +359,109 @@ def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
         s[0] == "engine.prefill.device" for s in spans) == 3
 
 
+def _calls_under(spans, kind):
+    """Per ``engine.<kind>.device`` span (in order) the ``.dispatch`` and
+    ``.fetch`` spans of its thread that lie inside it."""
+    out = []
+    for dev in sorted(s for s in spans if s[0] == "engine.%s.device" % kind):
+        inside = [s for s in spans if s[4] == dev[4]
+                  and dev[1] <= s[1] and s[2] <= dev[2]]
+        out.append((dev,
+                    [s for s in inside
+                     if s[0] == "engine.%s.dispatch" % kind],
+                    [s for s in inside if s[0] == "engine.%s.fetch" % kind]))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_one_dispatch_span_a_device_span_before_its_fetch(artifact, tmp_path,
+                                                          kind):
+    """The call into the exported program alone is a span of its own,
+    ``engine.<kind>.dispatch``: one inside every ``engine.<kind>.device``,
+    over before that span's ``.fetch`` begins, saying how many host arrays
+    the call hands over and how many bytes they hold (from the compiled
+    shapes: nothing is worked out a step)."""
+    from _util import profiled_spans
+    prefix, _, _ = artifact
+    pred = deploy.load_generator(prefix)
+    spans = profiled_spans(lambda: _three_requests(pred), tmp_path,
+                           ("engine.",))
+    calls = _calls_under(spans, kind)
+    assert calls
+    for dev, dispatches, fetches in calls:
+        assert len(dispatches) == 1 and len(fetches) == 1
+        assert dispatches[0][2] <= fetches[0][1]
+        # token ids / positions / table / temperature, top-k, top-p, keys
+        # (a prefill: tokens, prompt length, table and the same four)
+        assert int(dispatches[0][3]["host_args"]) == 7
+    sizes = {int(d[0][3]["host_bytes"]) for _, d, _ in calls}
+    if kind == "decode":
+        # 2 slots: 5 vectors of 4 B a row, key words 8 B, a table row of
+        # the width's page indices
+        parents = [s[3] for s in spans if s[0] == "engine.decode"]
+        assert sizes == {2 * (28 + 4 * int(a["width"])) for a in parents}
+    else:
+        # one row: the bucket's tokens, its length, its pages' indices
+        assert sizes == {4 * b + 4 + 4 * -(-b // PAGE) + 12 + 8
+                         for b in (4, 8)}
+
+
+def test_step_timer_keeps_the_bounds_of_the_device_span(artifact, tmp_path):
+    """``serving.decode_step_ms`` / ``serving.prefill_ms`` still read from
+    inside ``engine.*.device`` to its end: over the whole of the dispatch
+    and the fetch inside it, under the span that encloses both."""
+    from _util import profiled_spans
+    prefix, _, _ = artifact
+    pred = deploy.load_generator(prefix)
+    telemetry.reset()
+    spans = profiled_spans(lambda: _three_requests(pred), tmp_path,
+                           ("engine.",))
+    snap = telemetry.snapshot()["timers"]
+    for kind, timer in (("decode", "serving.decode_step_ms"),
+                        ("prefill", "serving.prefill_ms")):
+        calls = _calls_under(spans, kind)
+        assert snap[timer]["count"] == len(calls)
+        total_ms = snap[timer]["total"]
+        outer = sum(dev[2] - dev[1] for dev, _, _ in calls) / 1e6
+        inner = sum(f[0][2] - d[0][1] for _, d, f in calls) / 1e6
+        assert inner <= total_ms * 1.0001 and total_ms <= outer * 1.0001
+
+
+def test_dispatch_spans_leave_the_served_programs_as_they_were(
+        artifact, monkeypatch):
+    """The programs the engine compiles and calls are, instruction for
+    instruction, those of a build whose spans are all no-ops: a span is a
+    profiler annotation around a call, never part of what is called."""
+    import contextlib
+    prefix, _, _ = artifact
+
+    def compiled_texts():
+        pred = deploy.load_generator(prefix)
+        eng = generation.GenerationEngine("m", pred, num_pages=16,
+                                          decode_slots=2)
+        eng._compile_programs()
+        texts = {("prefill", k): p.as_text()
+                 for k, p in eng._prefill.items()}
+        texts.update({("decode", k): p.as_text()
+                      for k, p in eng._decode.items()})
+        return texts, dict(eng._host_operands)
+
+    with_spans, operands = compiled_texts()
+    assert sorted(operands) == sorted(
+        ["prefill-s%d" % k[1] if k[0] == "prefill" else "decode-w%d" % k[1]
+         for k in with_spans])
+
+    class _Off:
+        def set(self, **args):
+            pass
+
+    monkeypatch.setattr(
+        generation._tracing, "span",
+        lambda name, cat="host", **args: contextlib.nullcontext(_Off()))
+    without, _ = compiled_texts()
+    assert without == with_spans
+
+
 def test_decode_span_counts_what_the_in_place_route_reads(tmp_path):
     """Where the decode programs took the Pallas kernel (tier on, a
     concrete decode batch), ``window_tokens`` is the pages the rows' own
