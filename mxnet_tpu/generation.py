@@ -13,25 +13,43 @@ Architecture (one :class:`GenerationEngine` thread per generation model,
 run under the same restart supervisor as the one-shot batcher):
 
   submit ──► admission check ──► FIFO ──► engine loop, per iteration:
-             (bounded queue,              1. harvest expired deadlines
-              breaker state)              2. admit queue head into a free
+             (bounded queue,              1. emit what the last turn
+              breaker state)                 fetched: tokens reach their
+                                             rows, finished sequences
+                                             (EOS / max_new) resolve their
+                                             futures, and a row no queued
+                                             program carries any more
+                                             returns its pages and slot
+                                          2. harvest expired deadlines;
+                                             admit queue head into a free
                                              decode slot IF the page pool
                                              covers prompt+max_new pages
                                              (head-of-line wait otherwise:
                                              serving.kv_pool_exhausted)
-                                          3. PREFILL each new request
-                                             (B=1 program at its prompt
-                                             bucket) → first token (TTFT)
-                                          4. one DECODE step for all
-                                             active slots (B=slots
-                                             program at the page-table
-                                             width bucket) → next tokens
-                                          5. finished sequences (EOS /
-                                             max_new) resolve futures,
-                                             pages recycle immediately
+                                          3. dispatch a PREFILL for each
+                                             new request (B=1 program at
+                                             its prompt bucket)
+                                          4. dispatch one DECODE step for
+                                             every row with tokens left
+                                             (B=slots program at the
+                                             page-table width bucket)
+                                          5. fetch every program but that
+                                             decode step: the host waits
+                                             with the next step queued
+                                             behind the one it waits on
 
 Key properties:
 
+* **One decode step in flight ahead of the host** — step N+1 is
+  dispatched before step N's tokens are fetched, so the device runs N+1
+  while the host copies N's tokens out, emits them, admits and dispatches
+  the next programs.  A step's token ids never leave the device: N+1
+  reads them from N's output array (and a prefill's first token is put
+  into its row there) by two tiny programs compiled at ``start()``.  A
+  row whose ``max_new`` budget the dispatched steps already cover is left
+  out of the next step; a row that ends on an EOS rides one step more,
+  whose token is dropped.  Pages and state slots return to the pool only
+  once every dispatched program that carries the row has been fetched.
 * **Flat compiles** — programs are AOT-compiled at ``start()``: one
   prefill program per prompt bucket and one decode program per
   page-table width, all at fixed batch (1 and ``decode_slots``).  Ragged
@@ -39,9 +57,10 @@ Key properties:
   reaches the compiler (``tools/check_generation.py`` proves it).
 * **Paged KV memory** — position ``t`` of a sequence lives at slot
   ``t % page_size`` of page ``table[t // page_size]``; pages come from a
-  shared free list and return to it the iteration their sequence
-  finishes.  The pool dimension is symbolic in the v4 artifact, so
-  ``serving.kv_pages`` is a pure runtime choice.
+  shared free list and return to it once the programs that carry their
+  sequence's last step have been fetched.  The pool dimension is
+  symbolic in the v4 artifact, so ``serving.kv_pages`` is a pure runtime
+  choice.
 * **Oracle parity** — the token stream each request receives is that of
   the eager greedy oracle (``models.TransformerLM.greedy_decode``)
   regardless of what else is in flight: prefill runs the exact
@@ -85,7 +104,9 @@ Telemetry: ``serving.tokens_generated[.model]`` counters,
 ``serving.kv_pages_in_use.<model>`` gauge, ``serving.prefill_ms`` /
 ``serving.decode_step_ms`` / ``serving.ttft_ms`` /
 ``serving.generate_request_ms`` timers,
-``serving.kv_pool_exhausted[.model]`` counters, and one
+``serving.kv_pool_exhausted[.model]`` counters, ``serving.decode_ahead``
+(decode steps dispatched while an earlier program's tokens were not yet
+fetched), and one
 ``serving_generate`` JSONL record per finished request (prompt_len,
 new_tokens, ttft_ms, wall_ms — ``tools/telemetry_report.py`` folds these
 into per-model TTFT/tokens-per-second columns and the
@@ -140,6 +161,18 @@ def _access_ids(request_id, trace_id):
     if trace_id is None:
         return {"request_id": str(request_id)}
     return {"request_id": str(request_id), "trace_id": str(trace_id)}
+
+
+def _begin(name, **args):
+    """Open a detached ``serving`` span (it outlives the loop's turn)."""
+    sp = _tracing.detached_span(name, cat="serving", **args)
+    sp.__enter__()
+    return sp
+
+
+def _end(sp):
+    if sp is not None:
+        sp.__exit__(None, None, None)
 
 
 class _GenRequest:
@@ -198,12 +231,14 @@ class _Slot:
 
     __slots__ = ("req", "pages", "pos", "tokens", "ttft_ms",
                  "prefix_keys", "t_admit", "t_prefill_start", "token_t",
-                 "routed", "logprobs")
+                 "routed", "logprobs", "sent", "inflight", "ended", "src")
 
     def __init__(self, req, pages, t_admit, prefix_keys=()):
         self.req = req
         self.pages = pages
-        self.pos = req.plen      # tokens already in the cache
+        # tokens in the cache once the programs dispatched so far have
+        # run; the tokens emitted
+        self.pos = req.plen
         self.tokens = []
         self.ttft_ms = None
         self.prefix_keys = tuple(prefix_keys)
@@ -217,6 +252,65 @@ class _Slot:
         # a fed token; a log-probability a produced token
         self.routed = []
         self.logprobs = []
+        # ahead of the host: tokens the dispatched programs produce for
+        # the row, how many programs that carry it are not yet emitted,
+        # whether its last token has been (future resolved), and (device
+        # array, index) of the token its next decode step is fed
+        self.sent = 0
+        self.inflight = 0
+        self.ended = False
+        self.src = None
+
+
+class _Step:
+    """One dispatched program whose tokens are not yet emitted: the rows
+    it carries, its output on the device (then on the host), the stamps
+    of its timer and the spans that stay open across the loop's turns —
+    ``span`` (``engine.decode`` / ``engine.prefill``) from its dispatch
+    to its emit, ``device`` (``engine.<kind>.device``) while the host
+    waits on it."""
+
+    __slots__ = ("step", "kind", "program", "rows", "out", "span",
+                 "device", "t0", "t1", "route")
+
+    def __init__(self, step, kind, program, rows, span, route=None):
+        self.step = step
+        self.kind = kind
+        self.program = program
+        self.rows = rows            # [(slot index, _Slot)]
+        self.out = None             # jax.Array, then its numpy copy
+        self.span = span
+        self.device = None
+        self.t0 = self.t1 = None    # the host began waiting / fetched
+        self.route = route          # a decode width's paged route
+
+
+def _token_programs(batch, decode_outs, outs):
+    """The two programs that keep a decode step's token ids on the
+    device, by program output ``(shape, dtype)``: ``feed[shape](out,
+    keep)`` for each of ``decode_outs`` — the first ``batch`` entries of a
+    decode step's output where ``keep``, 0 elsewhere (the padding rows'
+    id, as a host-built operand had it) — and ``put[shape](tok, out, row,
+    at)`` for each of ``outs`` — ``tok`` with ``out[at]`` in ``row``.
+    Neither donates."""
+    import jax.numpy as jnp
+    tok = jax.ShapeDtypeStruct((batch,), _np.int32)
+    keep = jax.ShapeDtypeStruct((batch,), _np.bool_)
+    index = jax.ShapeDtypeStruct((), _np.int32)
+
+    def feed(out, keep):
+        return jnp.where(keep, out[:batch], 0).astype(_np.int32)
+
+    def put(tok, out, row, at):
+        return tok.at[row].set(out[at].astype(_np.int32))
+
+    def compile_each(fn, specs, outs):
+        return {shape: jax.jit(fn).trace(
+            *specs(jax.ShapeDtypeStruct(shape, dtype))).lower().compile()
+            for shape, dtype in outs}
+
+    return (compile_each(feed, lambda out: (out, keep), decode_outs),
+            compile_each(put, lambda out: (tok, out, index, index), outs))
 
 
 class GenerationEngine:
@@ -295,6 +389,18 @@ class GenerationEngine:
         # operands (known from the compiled shapes, never computed a step)
         self._host_operands = {}
         self._iteration = 0   # engine.iteration spans, numbered from 1
+        # programs dispatched and not yet fetched, oldest first; then
+        # fetched and not yet emitted (_Step); the newest decode step's
+        # output (what the next step's token ids are read from)
+        self._ahead = deque()
+        self._fetched = []
+        self._last_out = None
+        self._steps = _itertools.count(1)   # every program call, from 1
+        # token ids on the device: zeros (padding rows' id) and the two
+        # programs of _token_programs, by program output shape
+        self._tok0 = None
+        self._feed = {}
+        self._put = {}
         # request ids, from 1 (next() of a count is atomic: submit runs on
         # any thread)
         self._request_ids = _itertools.count(1)
@@ -324,10 +430,11 @@ class GenerationEngine:
                     jax.ShapeDtypeStruct((b,), _np.float32),
                     jax.ShapeDtypeStruct((b, 2), _np.uint32))
 
-        def compile_one(fn, arg_specs, label):
-            # parameters and pool live on the device; the rest of a call's
-            # operands are host arrays the runtime copies over each time
-            host = arg_specs[2:]
+        def compile_one(fn, arg_specs, label, on_device=2):
+            # parameters and pool live on the device (and a decode step's
+            # token ids); the rest of a call's operands are host arrays the
+            # runtime copies over each time
+            host = arg_specs[on_device:]
             self._host_operands[label] = {
                 "host_args": len(host),
                 "host_bytes": sum(_math.prod(s.shape)
@@ -375,7 +482,15 @@ class GenerationEngine:
                  jax.ShapeDtypeStruct((self.decode_slots,), i32),
                  jax.ShapeDtypeStruct((self.decode_slots, width), i32))
                 + sample_specs(self.decode_slots),
-                "decode-w%d" % width)
+                "decode-w%d" % width, on_device=3)
+        if not self._put:
+            def outs(programs):
+                return {(tuple(p.out_info[1].shape), p.out_info[1].dtype)
+                        for p in programs}
+            decode = outs(self._decode.values())
+            self._feed, self._put = _token_programs(
+                self.decode_slots, decode,
+                decode | outs(self._prefill.values()))
 
     # --------------------------------------------------------- lifecycle
     def start(self):
@@ -384,6 +499,8 @@ class GenerationEngine:
                 return self
         self._compile_programs()
         self._kv = self._make_kv()
+        self._tok0 = jax.device_put(_np.zeros((self.decode_slots,),
+                                              _np.int32))
         with self._cond:
             self._stopping = False
             self._abort = False
@@ -598,7 +715,14 @@ class GenerationEngine:
     def _fail_active(self, exc):
         """Fail every in-flight sequence and recycle its pages (the pool
         arrays were donated into the failed dispatch, so their state is
-        gone — rebuild zeroed)."""
+        gone — rebuild zeroed); every program in flight is forgotten, its
+        spans closed."""
+        for st in (*self._fetched, *self._ahead):
+            _end(st.device)
+            _end(st.span)
+        self._ahead.clear()
+        self._fetched = []
+        self._last_out = None
         released = []
         outcome = _access_outcome(exc)
         err = ("%s: %s" % (type(exc).__name__, exc)
@@ -626,6 +750,10 @@ class GenerationEngine:
             self._prefix.clear()
             self._cond.notify_all()
         self._gauge_pages()
+        # let the old cache go before the new one is made: not donated
+        # into a failed call (a crash between calls), it still holds its
+        # device memory, and a near-full chip has no room for both
+        self._kv = None
         self._kv = self._make_kv()
 
     def _state_slots(self):
@@ -730,7 +858,8 @@ class GenerationEngine:
             with self._cond:
                 self._last_iteration = _time.perf_counter()
                 if not self._queue and not self._active():
-                    # nothing to harvest, admit or decode
+                    # nothing to emit, harvest, admit or decode (a row
+                    # holds its slot until its last program is emitted)
                     if self._stopping:
                         return
                     with _tracing.span("engine.wait", cat="serving"):
@@ -743,9 +872,15 @@ class GenerationEngine:
                     return
 
     def _iterate(self):
-        """One turn of the loop with work in sight: harvest, admit,
-        prefill the admitted, one decode step.  False = the engine is
-        done (stopped and drained, or aborted)."""
+        """One turn of the loop with work in sight: emit what the last
+        turn fetched, harvest, admit, dispatch the admitted prefills and
+        one decode step, fetch every program but that step.  False = the
+        engine is done (stopped and drained, or aborted)."""
+        if self._ahead:
+            # the oldest program in flight is the one the host waits on
+            # from the last fetch's return on
+            self._wait_on(self._ahead[0])
+        self._emit_fetched()
         now = _time.perf_counter()
         with self._cond:
             with _tracing.span("engine.admit", cat="serving") as sp:
@@ -789,8 +924,10 @@ class GenerationEngine:
         for req in admitted:
             if not self._dispatch_prefill(req):
                 return True
-        if self._active():
-            self._dispatch_decode()
+        rows = [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and not s.ended
+                and 0 < s.sent < s.req.max_new]
+        self._fetch(keep=self._dispatch_decode(rows) if rows else None)
         return True
 
     def _expire(self, reqs):
@@ -817,20 +954,63 @@ class GenerationEngine:
         self._fail_active(exc)
         return False
 
+    def _wait_on(self, st):
+        """From now until its fetch returns, ``st`` is the program the
+        host waits on: open its ``engine.<kind>.device`` span and start
+        its timer (the later of its dispatch and the previous fetch's
+        return)."""
+        if st.device is None:
+            st.device = _begin("engine.%s.device" % st.kind, step=st.step)
+            st.t0 = _time.perf_counter()
+
+    def _fetch(self, keep=None):
+        """Copy out the tokens of every program in flight but ``keep``
+        (the decode step this turn dispatched), oldest first: the host
+        blocks on one program with the next queued behind it.  A program
+        that raises fails the rows of every program in flight."""
+        while self._ahead and self._ahead[0] is not keep:
+            st = self._ahead[0]
+            self._wait_on(st)
+            try:
+                # launch wait, execution and read-back all lie under the
+                # fetch: no block_until_ready splits them (a host round
+                # trip a step); the runtime's own events do, in a trace
+                with _tracing.span("engine.%s.fetch" % st.kind,
+                                   cat="serving", step=st.step):
+                    st.out = _np.asarray(st.out)
+            except BaseException as exc:  # noqa: BLE001 — pool donated
+                # what was fetched before the fault still reaches its rows
+                self._emit_fetched()
+                self._dispatch_failed(exc)
+                return
+            st.t1 = _time.perf_counter()
+            _end(st.device)
+            st.device = None
+            self._ahead.popleft()
+            self._fetched.append(st)
+            if self.breaker is not None:
+                self.breaker.record_success()
+
+    def _emit_fetched(self):
+        """Hand the fetched programs' tokens to their rows, in dispatch
+        order, and close each program's span."""
+        fetched, self._fetched = self._fetched, []
+        for st in fetched:
+            if st.kind == "decode":
+                self._emit_decode(st)
+            else:
+                self._emit_prefill(st)
+            _end(st.span)
+
     def _dispatch_prefill(self, req):
-        """Run one admitted request's prompt through its bucket's prefill
-        program: seeds the shared pool (scatter touches only this
+        """Dispatch one admitted request's prompt through its bucket's
+        prefill program: seeds the shared pool (scatter touches only this
         request's pages, so in-flight sequences are untouched — the
-        mid-flight JOIN) and produces the first token (TTFT)."""
+        mid-flight JOIN); its first token (TTFT) is fetched once the
+        decode step that is fed it has been dispatched.  False = the
+        dispatch failed."""
         gp = self.predictor
         s_bucket = gp.prefill_bucket(req.plen)
-        with _tracing.span("engine.prefill", cat="serving",
-                           request_id=req.request_id, bucket=s_bucket,
-                           prompt_len=req.plen) as sp:
-            return self._prefill_one(req, s_bucket, sp)
-
-    def _prefill_one(self, req, s_bucket, sp):
-        gp = self.predictor
         slot_idx = next(i for i, s in enumerate(self._slots)
                         if s is not None and s.req is req)
         slot = self._slots[slot_idx]
@@ -850,6 +1030,11 @@ class GenerationEngine:
                     queue_ms=(_time.perf_counter() - req.t_submit) * 1e3,
                     **req.access_ids())
             return True   # engine itself is fine
+        step = next(self._steps)
+        program = "prefill-s%d" % s_bucket
+        sp = _begin("engine.prefill", request_id=req.request_id,
+                    bucket=s_bucket, prompt_len=req.plen, step=step)
+        st = _Step(step, "prefill", program, [(slot_idx, slot)], sp)
         w_s = _math.ceil(s_bucket / gp.page_size)
         sentinel = self.num_pages
         tokens = _np.zeros((1, s_bucket), _np.int32)
@@ -861,9 +1046,9 @@ class GenerationEngine:
         # be rewritten mid-decode — sentinel them so this prefill's
         # scatter drops those rows (the bytes are already there; the
         # attention gather still reads them through slot.pages).
-        # Populated-ness is decided here at dispatch time, not admission:
-        # if the registering request died before its prefill ran, the
-        # next sharer writes the pages itself.
+        # Populated-ness is decided at dispatch, in the order the device
+        # runs the programs: if the registering request died before its
+        # prefill was dispatched, the next sharer writes the pages itself.
         write_table = table
         if slot.prefix_keys:
             with self._cond:
@@ -879,50 +1064,56 @@ class GenerationEngine:
         # where the cache has a state region, the prompt's final state is
         # left in this request's slot, over whatever the slot held
         where = (_np.asarray([slot_idx], _np.int32),) if gp.state else ()
-        program = "prefill-s%d" % s_bucket
-        # the span has the bounds of the serving.prefill_ms timer
-        with _tracing.span("engine.prefill.device", cat="serving"):
-            slot.t_prefill_start = t0 = _time.perf_counter()
-            try:
-                # the call into the exported program alone: host time
-                # inside JAX and the runtime, before the device starts
-                with _tracing.span(
-                        "engine.prefill.dispatch", cat="serving",
-                        **self._host_operands[program]):
-                    self._kv, nxt = self._prefill[s_bucket](
-                        gp._params, self._kv, tokens,
-                        _np.asarray([req.plen], _np.int32), write_table,
-                        *where, temp, tk, tp, keys)
-                # launch wait, execution and read-back all lie under the
-                # fetch: no block_until_ready splits them (a host round
-                # trip a step); the runtime's own events do, in a trace
-                with _tracing.span("engine.prefill.fetch", cat="serving"):
-                    if req.want_replay:
-                        nxt = _np.asarray(nxt)
-                        slot.logprobs.append(nxt[1:2].view(_np.float32)[0])
-                        slot.routed.append(self._routed(nxt[2:], s_bucket)
-                                           [:, :req.plen])
-                    first = int(nxt[0])
-            except BaseException as exc:  # noqa: BLE001 — pool donated
-                return self._dispatch_failed(exc)
-            if slot.prefix_keys:
-                with self._cond:
-                    for key in slot.prefix_keys:
-                        entry = self._prefix.get(key)
-                        if entry is not None:
-                            entry[2] = True
-            t1 = _time.perf_counter()
-        if breaker is not None:
-            breaker.record_success()
-        slot.tokens.append(first)
-        slot.token_t.append(t1)
-        slot.ttft_ms = (t1 - req.t_submit) * 1e3
-        _telemetry.timer("serving.prefill_ms").observe((t1 - t0) * 1e3)
+        slot.t_prefill_start = _time.perf_counter()
+        try:
+            # the call into the exported program alone: host time inside
+            # JAX and the runtime, before the device starts (with nothing
+            # else in flight the host waits on this program from here)
+            with _tracing.span("engine.prefill.dispatch", cat="serving",
+                               step=step, **self._host_operands[program]):
+                if not self._ahead:
+                    self._wait_on(st)
+                self._kv, st.out = self._prefill[s_bucket](
+                    gp._params, self._kv, tokens,
+                    _np.asarray([req.plen], _np.int32), write_table,
+                    *where, temp, tk, tp, keys)
+        except BaseException as exc:  # noqa: BLE001 — pool donated
+            _end(st.device)
+            _end(sp)
+            return self._dispatch_failed(exc)
+        if slot.prefix_keys:
+            with self._cond:
+                for key in slot.prefix_keys:
+                    entry = self._prefix.get(key)
+                    if entry is not None:
+                        entry[2] = True
+        slot.sent = slot.inflight = 1
+        slot.src = (st.out, 0)
+        self._ahead.append(st)
+        return True
+
+    def _emit_prefill(self, st):
+        ((slot_idx, slot),) = st.rows
+        req = slot.req
+        nxt = st.out
+        slot.inflight -= 1
+        _telemetry.timer("serving.prefill_ms").observe(
+            (st.t1 - st.t0) * 1e3)
+        self._count_program_routes(st.program)
+        if slot.ended:       # failed while its prefill was in flight
+            self._retire(slot_idx)
+            return
+        if req.want_replay:
+            slot.logprobs.append(nxt[1:2].view(_np.float32)[0])
+            slot.routed.append(self._routed(
+                nxt[2:], self.predictor.prefill_bucket(req.plen))[
+                    :, :req.plen])
+        slot.tokens.append(int(nxt[0]))
+        slot.token_t.append(st.t1)
+        slot.ttft_ms = (st.t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.ttft_ms").observe(slot.ttft_ms)
-        self._count_program_routes(program)
         self._count_tokens(1)
         self._maybe_finish(slot_idx)
-        return True
 
     def _count_program_routes(self, program):
         """Serve-side mirror of the export-time verdict on a program's
@@ -945,22 +1136,13 @@ class GenerationEngine:
                 _telemetry.counter("kernels.retention_fallback").inc(
                     route["sites"])
 
-    def _dispatch_decode(self):
-        """One decode iteration for every active slot.  The page-table
-        width buckets to the widest need among active sequences; inactive
-        slots ride along on the all-sentinel row (writes drop, output
-        ignored) — that is what keeps the compiled set flat while
-        sequences EXIT and JOIN mid-flight."""
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
-        width = _io.pick_bucket(
-            self.predictor.decode_widths,
-            max(len(s.pages) for _, s in active))
-        with _tracing.span("engine.decode", cat="serving", width=width,
-                           rows=len(active)) as sp:
-            self._decode_once(active, width, sp)
-
-    def _decode_once(self, active, width, sp):
+    def _dispatch_decode(self, rows):
+        """Dispatch one decode step for ``rows``, every row with tokens
+        left to make.  The page-table width buckets to the widest need
+        among them; the other slots ride along on the all-sentinel row
+        (writes drop, output ignored) — that is what keeps the compiled
+        set flat while sequences EXIT and JOIN mid-flight.  Returns the
+        step (None where none was dispatched)."""
         gp = self.predictor
         B = self.decode_slots
         breaker = self.breaker
@@ -968,37 +1150,41 @@ class GenerationEngine:
             exc = CircuitOpenError(
                 "model %r circuit breaker is OPEN; in-flight decode "
                 "failed fast, retry after the cooldown" % (self.name,))
-            for i, _ in active:
-                self._slots[i] = None
-            for _, s in active:
+            for i, s in rows:
+                s.ended = True
                 if not s.req.future.done():
                     s.req.future.set_exception(exc)
                     _obs.log_access(
                         self.name, "breaker", ttft_ms=s.ttft_ms,
                         tokens=len(s.tokens), **s.req.access_ids())
-            with self._cond:
-                for _, s in active:
-                    self._release_pages_locked(s)
-            self._gauge_pages()
-            return
+                self._retire(i)
+            return None
+        width = _io.pick_bucket(gp.decode_widths,
+                                max(len(s.pages) for _, s in rows))
+        step = next(self._steps)
+        # an earlier program's tokens not yet fetched: this step runs
+        # while the host copies them out
+        ahead = int(bool(self._ahead))
+        program = "decode-w%d" % width
+        route = gp.paged_routes.get(str(width))
+        sp = _begin("engine.decode", width=width, rows=len(rows),
+                    step=step, ahead=ahead)
+        st = _Step(step, "decode", program, rows, sp, route)
         with _tracing.span("engine.decode.prepare", cat="serving"):
             sentinel = self.num_pages
-            token_ids = _np.zeros((B,), _np.int32)
             positions = _np.zeros((B,), _np.int32)
             table = _np.full((B, width), sentinel, _np.int32)
-            for i, s in active:
-                token_ids[i] = s.tokens[-1]
+            for i, s in rows:
                 positions[i] = s.pos
                 k = min(width, len(s.pages))
                 table[i, :k] = s.pages[:k]
-            temp, tk, tp, keys = self._sample_arrays(active, B, sp)
+            temp, tk, tp, keys = self._sample_arrays(rows, B, sp)
         # tokens the rows hold against token slots the routed program
-        # reads: each active row's own pages where the kernel reads them
-        # in place, the whole table's window where the twin gathers it
+        # reads: each row's own pages where the kernel reads them in
+        # place, the whole table's window where the twin gathers it
         psz = gp.page_size
-        route = gp.paged_routes.get(str(width))
         if route is not None and route.get("impl") in ("paged", "latent"):
-            window = sum(-(-(s.pos + 1) // psz) for _, s in active) * psz
+            window = sum(-(-(s.pos + 1) // psz) for _, s in rows) * psz
         else:
             window = B * width * psz
         sp.set(held_tokens=int(positions.sum()), window_tokens=window)
@@ -1007,46 +1193,78 @@ class GenerationEngine:
             sp.set(latent_kernel=int(route is not None
                                      and route.get("impl") == "latent"))
         if gp.state:
-            sp.set(state_rows=len(active))
-            _telemetry.gauge("serving.state_slots").set(len(active))
-        program = "decode-w%d" % width
-        # the span has the bounds of the serving.decode_step_ms timer
-        with _tracing.span("engine.decode.device", cat="serving"):
-            t0 = _time.perf_counter()
-            try:
-                with _tracing.span(
-                        "engine.decode.dispatch", cat="serving",
-                        **self._host_operands[program]):
-                    self._kv, nxt = self._decode[width](
-                        gp._params, self._kv, token_ids, positions, table,
-                        temp, tk, tp, keys)
-                with _tracing.span("engine.decode.fetch", cat="serving"):
-                    nxt = _np.asarray(nxt)
-            except BaseException as exc:  # noqa: BLE001 — pool donated
-                self._dispatch_failed(exc)
-                return
-            t1 = _time.perf_counter()
+            sp.set(state_rows=len(rows))
+            _telemetry.gauge("serving.state_slots").set(len(rows))
+        if ahead:
+            _telemetry.counter("serving.decode_ahead").inc()
+        try:
+            token_ids = self._token_ids(rows)
+            with _tracing.span("engine.decode.dispatch", cat="serving",
+                               step=step, **self._host_operands[program]):
+                if not self._ahead:
+                    self._wait_on(st)
+                self._kv, st.out = self._decode[width](
+                    gp._params, self._kv, token_ids, positions, table,
+                    temp, tk, tp, keys)
+        except BaseException as exc:  # noqa: BLE001 — pool donated
+            _end(st.device)
+            _end(sp)
+            self._dispatch_failed(exc)
+            return None
+        for i, s in rows:
+            s.pos += 1
+            s.sent += 1
+            s.inflight += 1
+            s.src = (st.out, i)
+        self._last_out = st.out
+        self._ahead.append(st)
+        return st
+
+    def _token_ids(self, rows):
+        """The decode step's token ids, on the device: each row's last
+        token where the program that made it left it — the newest decode
+        step's output (one ``feed``) or a prefill's (one ``put`` a row) —
+        and 0 in the padding rows.  No token id visits the host."""
+        last = self._last_out
+        keep = _np.zeros((self.decode_slots,), _np.bool_)
+        puts = []
+        for i, s in rows:
+            out, at = s.src
+            if out is last and at == i:
+                keep[i] = True
+            else:
+                puts.append((i, out, at))
+        tok = self._feed[last.shape](last, keep) if keep.any() \
+            else self._tok0
+        for i, out, at in puts:
+            tok = self._put[out.shape](tok, out, _np.int32(i),
+                                       _np.int32(at))
+        return tok
+
+    def _emit_decode(self, st):
+        gp = self.predictor
+        B = self.decode_slots
+        nxt = st.out
         n_stats = len(gp.decode_stats)
         if n_stats:
             # what the model counted in this step rode behind the tokens
             counts = dict(zip(gp.decode_stats,
                               nxt[B:B + n_stats].tolist()))
-            sp.set(**counts)
+            st.span.set(**counts)
             for stat, value in counts.items():
                 _telemetry.counter("serving." + stat).inc(value)
-        if any(s.req.want_replay for _, s in active):
+        live = [(i, s) for i, s in st.rows if not s.ended]
+        if any(s.req.want_replay for _, s in live):
             # ... and behind those what a replay needs
             logprobs = nxt[B + n_stats:2 * B + n_stats].view(_np.float32)
             routed = self._routed(nxt[2 * B + n_stats:], B)
-            for i, s in active:
+            for i, s in live:
                 if s.req.want_replay:
                     s.logprobs.append(logprobs[i])
                     s.routed.append(routed[:, i, None])
-        if breaker is not None:
-            breaker.record_success()
         _telemetry.timer("serving.decode_step_ms").observe(
-            (t1 - t0) * 1e3)
-        if route is not None:
+            (st.t1 - st.t0) * 1e3)
+        if st.route is not None:
             # serve-side mirror of the export-time routing verdict: every
             # decode iteration that ran through the Pallas paged kernel
             # (or fell back while the kernel tier was on) is counted
@@ -1057,20 +1275,24 @@ class GenerationEngine:
                 if self._latent else (
                 _telemetry.counter("kernels.paged_attention"),
                 _telemetry.counter("kernels.paged_fallback"))
-            if route.get("impl") in ("paged", "latent"):
+            if st.route.get("impl") in ("paged", "latent"):
                 took.inc()
             elif _kernels_enabled():
                 fell_back.inc()
-        self._count_program_routes(program)
-        with _tracing.span("engine.decode.emit", cat="serving") as emit:
-            self._count_tokens(len(active))
+        self._count_program_routes(st.program)
+        with _tracing.span("engine.decode.emit", cat="serving",
+                           step=st.step) as emit:
+            self._count_tokens(len(live))
             gap = _telemetry.timer("serving.token_gap_ms")
             finished = 0
-            for i, s in active:
+            for i, s in st.rows:
+                s.inflight -= 1
+                if s.ended:      # an EOS the host saw after this step left
+                    self._retire(i)
+                    continue
                 s.tokens.append(int(nxt[i]))
-                gap.observe((t1 - s.token_t[-1]) * 1e3)
-                s.token_t.append(t1)
-                s.pos += 1
+                gap.observe((st.t1 - s.token_t[-1]) * 1e3)
+                s.token_t.append(st.t1)
                 finished += self._maybe_finish(i)
             emit.set(finished=finished)
 
@@ -1109,9 +1331,21 @@ class GenerationEngine:
         _telemetry.counter(
             "serving.tokens_generated.%s" % self.name).inc(n)
 
+    def _retire(self, slot_idx):
+        """Return an ended row's pages and slot once no dispatched
+        program that carries it is left to emit: a program still queued
+        may write only into pages its row holds."""
+        slot = self._slots[slot_idx]
+        if slot.ended and not slot.inflight:
+            self._slots[slot_idx] = None
+            with self._cond:
+                self._release_pages_locked(slot)
+            self._gauge_pages()
+
     def _maybe_finish(self, slot_idx):
-        """Mid-flight EXIT: resolve the future and recycle the pages the
-        same iteration the sequence hits EOS or its token budget."""
+        """Mid-flight EXIT: resolve the future the emit that brings the
+        sequence's EOS or last budgeted token; the pages follow once no
+        program in flight carries the row (``_retire``)."""
         slot = self._slots[slot_idx]
         req = slot.req
         done = len(slot.tokens) >= req.max_new or (
@@ -1119,10 +1353,8 @@ class GenerationEngine:
             and slot.tokens[-1] == int(req.eos_id))
         if not done:
             return 0
-        self._slots[slot_idx] = None
-        with self._cond:
-            self._release_pages_locked(slot)
-        self._gauge_pages()
+        slot.ended = True
+        self._retire(slot_idx)
         t1 = _time.perf_counter()
         wall_ms = (t1 - req.t_submit) * 1e3
         _telemetry.timer("serving.generate_request_ms").observe(wall_ms)

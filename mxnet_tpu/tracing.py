@@ -55,11 +55,11 @@ from collections import deque
 
 import jax
 
-__all__ = ["span", "current_span", "wrap_context", "configure_sink",
-           "configure_watchdog", "configure_ring", "enabled", "sink_path",
-           "open_spans", "ring_events", "record_event", "notify_step",
-           "dump_watchdog_report", "load_trace", "validate_trace_events",
-           "validate_watchdog_report", "register_stall_probe",
+__all__ = ["span", "detached_span", "current_span", "wrap_context",
+           "configure_sink", "configure_watchdog", "configure_ring",
+           "enabled", "sink_path", "open_spans", "ring_events",
+           "record_event", "notify_step", "dump_watchdog_report",
+           "load_trace", "validate_trace_events", "validate_watchdog_report", "register_stall_probe",
            "unregister_stall_probe", "check_stall_probes",
            "last_step_age_s", "Span"]
 
@@ -236,9 +236,9 @@ class Span:
     """One timed causal span.  Use via ``tracing.span(name)``."""
 
     __slots__ = ("name", "cat", "args", "trace_id", "span_id", "parent_id",
-                 "thread", "_t0", "_token", "_ann")
+                 "thread", "detached", "_t0", "_token", "_ann")
 
-    def __init__(self, name, cat, args):
+    def __init__(self, name, cat, args, detached=False):
         self.name = name
         self.cat = cat
         self.args = args
@@ -246,6 +246,9 @@ class Span:
         self.span_id = None
         self.parent_id = None
         self.thread = None
+        # never the context's current span: it may end before or after the
+        # spans opened inside its bounds (``detached_span``)
+        self.detached = detached
         self._token = None
         self._ann = None
 
@@ -265,7 +268,8 @@ class Span:
             self.parent_id = None
         self.span_id = _new_id()
         self.thread = threading.current_thread().name
-        self._token = _CURRENT.set(self)
+        if not self.detached:
+            self._token = _CURRENT.set(self)
         with _OPEN_LOCK:
             _OPEN[self.span_id] = self
         # nest the framework phase inside the profiler's own trace; the
@@ -285,7 +289,9 @@ class Span:
         self._ann = None
         with _OPEN_LOCK:
             _OPEN.pop(self.span_id, None)
-        _CURRENT.reset(self._token)
+        if self._token is not None:
+            _CURRENT.reset(self._token)
+            self._token = None
         args = {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id}
         if self.args:
@@ -314,6 +320,18 @@ def span(name, cat="host", **args):
     if _SINK is None and _WD_DEADLINE is None:
         return _Annotation(name, **args)
     return Span(name, cat, args)
+
+
+def detached_span(name, cat="host", **args):
+    """:func:`span` for one stage of a pipelined loop, entered and exited
+    by hand (``__enter__()`` / ``__exit__(None, None, None)``) wherever the
+    loop's turns put its bounds: it is never the context's current span,
+    so it may end before spans opened inside it (the profiler annotation
+    keeps its own start and end whatever the order).  Spans opened while
+    it is open take the current span's parentage, not its."""
+    if _SINK is None and _WD_DEADLINE is None:
+        return _Annotation(name, **args)
+    return Span(name, cat, args, detached=True)
 
 
 def current_span():

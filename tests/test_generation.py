@@ -4,9 +4,14 @@ engine admission validation, KV knob validation, shared-prefix page
 refcount lifecycle (last-reader free, mid-flight sharer exit,
 page-granular copy-on-write, no double-counted pages), sampling
 admission gates, telemetry-report generation table + kv_pool_exhaustion
-anomaly, and the tools/check_generation.py smoke (bitwise streams under
-mid-flight exits/joins + flat compiles + pool exhaustion + Pallas paged
-kernel routing + sampling determinism + int8 KV drift) as a subprocess.
+anomaly, the engine one decode step ahead of the host (every cache kind's
+streams under joins, exits and EOS rides; sampled and replayed rows equal
+to their solo runs; the order of dispatch and fetch, the spans' arguments,
+pages held while a queued step carries their row, faults at a fetch, a
+draining stop), and the tools/check_generation.py smoke (bitwise streams
+under mid-flight exits/joins + flat compiles + pool exhaustion + Pallas
+paged kernel routing + sampling determinism + int8 KV drift) as a
+subprocess.
 """
 import json
 import os
@@ -291,8 +296,9 @@ def test_report_render_includes_generation(capsys):
 
 # ------------------------------------- spans, scopes, per-request record
 ENGINE_SPANS = ("engine.iteration", "engine.admit", "engine.prefill",
-                "engine.prefill.device", "engine.prefill.fetch",
-                "engine.decode", "engine.decode.prepare",
+                "engine.prefill.dispatch", "engine.prefill.device",
+                "engine.prefill.fetch", "engine.decode",
+                "engine.decode.prepare", "engine.decode.dispatch",
                 "engine.decode.device", "engine.decode.fetch",
                 "engine.decode.emit")
 
@@ -314,12 +320,33 @@ def _three_requests(pred, name="m"):
     return futs
 
 
+def _by_step(spans):
+    """``{step: {span name: span}}`` of the spans that carry a ``step``
+    argument: one program call's dispatch, device, fetch, emit and its
+    ``engine.decode`` / ``engine.prefill`` span."""
+    out = {}
+    for sp in spans:
+        if "step" in sp[3]:
+            got = out.setdefault(int(sp[3]["step"]), {})
+            assert sp[0] not in got, (sp[0], "twice for one step")
+            got[sp[0]] = sp
+    return out
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2] \
+        and inner[4] == outer[4]
+
+
 def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
     """A session started by ``jax.profiler.start_trace`` alone (no sink,
     no watchdog, no ``mx.profiler``) finds the engine loop's spans on the
-    host plane: children inside parents, the decode span carrying the
-    counts the per-layer metrics read, and as many ``engine.decode.device``
-    spans as the ``serving.decode_step_ms`` timer counted."""
+    host plane: the turn's own work inside ``engine.iteration``, every
+    span of one program call inside that call's ``engine.decode`` /
+    ``engine.prefill`` span (which outlive the turn: one step is in flight
+    ahead of the host), the decode span carrying the counts the per-layer
+    metrics read, and as many ``engine.decode.device`` spans as the
+    ``serving.decode_step_ms`` timer counted."""
     from _util import assert_spans_nest, profiled_spans
     prefix, _, _ = artifact
     pred = deploy.load_generator(prefix)
@@ -328,19 +355,25 @@ def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
                            ("engine.",))
     names = {s[0] for s in spans}
     assert set(ENGINE_SPANS) <= names, sorted(names)
-    for child, parent in (("engine.admit", "engine.iteration"),
-                          ("engine.prefill", "engine.iteration"),
-                          ("engine.decode", "engine.iteration"),
-                          ("engine.prefill.device", "engine.prefill"),
-                          ("engine.prefill.fetch", "engine.prefill.device"),
-                          ("engine.decode.fetch", "engine.decode.device"),
-                          ("engine.decode.prepare", "engine.decode"),
-                          ("engine.decode.device", "engine.decode"),
-                          ("engine.decode.emit", "engine.decode")):
-        assert_spans_nest(spans, child, parent)
+    for child in ("engine.admit", "engine.prefill.dispatch",
+                  "engine.prefill.fetch", "engine.decode.prepare",
+                  "engine.decode.dispatch", "engine.decode.fetch",
+                  "engine.decode.emit"):
+        assert_spans_nest(spans, child, "engine.iteration")
+    assert_spans_nest(spans, "engine.decode.prepare", "engine.decode")
+    for step, got in _by_step(spans).items():
+        kind = "decode" if "engine.decode" in got else "prefill"
+        parts = ["engine.%s.%s" % (kind, p)
+                 for p in ("dispatch", "device", "fetch")]
+        assert set(parts) <= set(got), (step, sorted(got))
+        for part in parts + (["engine.decode.emit"] if kind == "decode"
+                             else []):
+            assert _inside(got[part], got["engine." + kind]), (step, part)
+        assert _inside(got[parts[2]], got[parts[1]]), step
     decodes = [s[3] for s in spans if s[0] == "engine.decode"]
     for args in decodes:
-        assert {"width", "rows", "held_tokens", "window_tokens"} <= set(args)
+        assert {"width", "rows", "held_tokens", "window_tokens", "step",
+                "ahead"} <= set(args)
         assert 0 < int(args["held_tokens"]) <= int(args["window_tokens"])
         assert 1 <= int(args["rows"]) <= 2
         assert int(args["window_tokens"]) == 2 * int(args["width"]) * PAGE
@@ -354,52 +387,55 @@ def test_engine_spans_reach_a_bare_profiler_session(artifact, tmp_path):
     assert sum(int(a["finished"]) for a in emits) == 3
     snap = telemetry.snapshot()["timers"]
     assert snap["serving.decode_step_ms"]["count"] == sum(
-        s[0] == "engine.decode.device" for s in spans)
+        s[0] == "engine.decode.device" for s in spans) == len(decodes)
     assert snap["serving.prefill_ms"]["count"] == sum(
         s[0] == "engine.prefill.device" for s in spans) == 3
 
 
-def _calls_under(spans, kind):
-    """Per ``engine.<kind>.device`` span (in order) the ``.dispatch`` and
-    ``.fetch`` spans of its thread that lie inside it."""
-    out = []
-    for dev in sorted(s for s in spans if s[0] == "engine.%s.device" % kind):
-        inside = [s for s in spans if s[4] == dev[4]
-                  and dev[1] <= s[1] and s[2] <= dev[2]]
-        out.append((dev,
-                    [s for s in inside
-                     if s[0] == "engine.%s.dispatch" % kind],
-                    [s for s in inside if s[0] == "engine.%s.fetch" % kind]))
-    return out
+def _calls(spans, kind):
+    """Per program call of ``kind``, in step order: its dispatch, device
+    and fetch spans."""
+    return [(got["engine.%s.dispatch" % kind],
+             got["engine.%s.device" % kind], got["engine.%s.fetch" % kind])
+            for _, got in sorted(_by_step(spans).items())
+            if "engine.%s.dispatch" % kind in got]
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_one_dispatch_span_a_device_span_before_its_fetch(artifact, tmp_path,
                                                           kind):
     """The call into the exported program alone is a span of its own,
-    ``engine.<kind>.dispatch``: one inside every ``engine.<kind>.device``,
-    over before that span's ``.fetch`` begins, saying how many host arrays
-    the call hands over and how many bytes they hold (from the compiled
-    shapes: nothing is worked out a step)."""
+    ``engine.<kind>.dispatch``: one a call, with its call's ``step``, over
+    before that call's ``.fetch`` begins — and ``engine.<kind>.device``
+    opens at the later of the dispatch and the previous fetch's return
+    and holds the fetch.  It says how many host arrays the call hands
+    over and how many bytes they hold (from the compiled shapes: nothing
+    is worked out a step); a decode step's token ids are not among them:
+    they stay on the device."""
     from _util import profiled_spans
     prefix, _, _ = artifact
     pred = deploy.load_generator(prefix)
     spans = profiled_spans(lambda: _three_requests(pred), tmp_path,
                            ("engine.",))
-    calls = _calls_under(spans, kind)
+    calls = _calls(spans, kind)
     assert calls
-    for dev, dispatches, fetches in calls:
-        assert len(dispatches) == 1 and len(fetches) == 1
-        assert dispatches[0][2] <= fetches[0][1]
-        # token ids / positions / table / temperature, top-k, top-p, keys
-        # (a prefill: tokens, prompt length, table and the same four)
-        assert int(dispatches[0][3]["host_args"]) == 7
-    sizes = {int(d[0][3]["host_bytes"]) for _, d, _ in calls}
+    fetches = [s for s in spans if s[0].endswith(".fetch")]
+    for dispatch, device, fetch in calls:
+        assert dispatch[2] <= fetch[1]
+        assert _inside(fetch, device)
+        assert device[1] >= dispatch[1]
+        earlier = [f[2] for f in fetches if f[2] <= fetch[1]]
+        assert not earlier or device[1] >= max(earlier)
+        # positions / table / temperature, top-k, top-p, keys (a prefill:
+        # tokens, prompt length, table and the same four)
+        assert int(dispatch[3]["host_args"]) == (6 if kind == "decode"
+                                                 else 7)
+    sizes = {int(d[3]["host_bytes"]) for d, _, _ in calls}
     if kind == "decode":
-        # 2 slots: 5 vectors of 4 B a row, key words 8 B, a table row of
+        # 2 slots: 4 vectors of 4 B a row, key words 8 B, a table row of
         # the width's page indices
         parents = [s[3] for s in spans if s[0] == "engine.decode"]
-        assert sizes == {2 * (28 + 4 * int(a["width"])) for a in parents}
+        assert sizes == {2 * (24 + 4 * int(a["width"])) for a in parents}
     else:
         # one row: the bucket's tokens, its length, its pages' indices
         assert sizes == {4 * b + 4 + 4 * -(-b // PAGE) + 12 + 8
@@ -407,9 +443,11 @@ def test_one_dispatch_span_a_device_span_before_its_fetch(artifact, tmp_path,
 
 
 def test_step_timer_keeps_the_bounds_of_the_device_span(artifact, tmp_path):
-    """``serving.decode_step_ms`` / ``serving.prefill_ms`` still read from
-    inside ``engine.*.device`` to its end: over the whole of the dispatch
-    and the fetch inside it, under the span that encloses both."""
+    """``serving.decode_step_ms`` / ``serving.prefill_ms`` read from inside
+    ``engine.*.device`` to its end: from the later of the program's
+    dispatch and the previous fetch's return (the time the program is the
+    one the host waits on) over the whole of its fetch.  The device spans
+    of the engine thread follow one another and never overlap."""
     from _util import profiled_spans
     prefix, _, _ = artifact
     pred = deploy.load_generator(prefix)
@@ -419,12 +457,15 @@ def test_step_timer_keeps_the_bounds_of_the_device_span(artifact, tmp_path):
     snap = telemetry.snapshot()["timers"]
     for kind, timer in (("decode", "serving.decode_step_ms"),
                         ("prefill", "serving.prefill_ms")):
-        calls = _calls_under(spans, kind)
+        calls = _calls(spans, kind)
         assert snap[timer]["count"] == len(calls)
         total_ms = snap[timer]["total"]
-        outer = sum(dev[2] - dev[1] for dev, _, _ in calls) / 1e6
-        inner = sum(f[0][2] - d[0][1] for _, d, f in calls) / 1e6
+        outer = sum(dev[2] - dev[1] for _, dev, _ in calls) / 1e6
+        inner = sum(f[2] - f[1] for _, _, f in calls) / 1e6
         assert inner <= total_ms * 1.0001 and total_ms <= outer * 1.0001
+    devices = sorted((s for s in spans if s[0].endswith(".device")),
+                     key=lambda s: s[1])
+    assert all(a[2] <= b[1] for a, b in zip(devices, devices[1:]))
 
 
 def test_dispatch_spans_leave_the_served_programs_as_they_were(
@@ -586,6 +627,430 @@ def test_generation_programs_carry_scopes_as_metadata_only(
     if "mx.kv_gather" not in scopes and "mx.paged_attention" in scopes:
         assert "mx.kv_gather" not in text
         assert "mx_paged_attention" in text
+
+
+# ------------------------------- one decode step in flight ahead of the host
+KINDS = ("transformer", "hybrid", "retention", "latent")
+_ARTIFACTS = {}
+
+
+def _kind(kind, tmp_path_factory, **export):
+    """A toy of each cache kind, exported once a module: K/V pages
+    (``TransformerLM``), pages beside per-slot state rows (``HybridLM``
+    ``MEM*E``: Mamba-2, experts, attention), state rows alone (retention
+    ``RFRF``), latent pages (``LFLG``).  ``(model, params, predictor,
+    vocab, longest prompt, context)``."""
+    key = (kind,) + tuple(sorted(export.items()))
+    if key not in _ARTIFACTS:
+        prefix = str(tmp_path_factory.mktemp(kind) / "lm")
+        if kind == "transformer":
+            model, params = _tiny_lm()
+            deploy.export_generation(
+                model, params, prefix, page_size=PAGE, max_context=CTX,
+                prompt_buckets=(4, 8), **export)
+            vocab, longest, ctx = VOCAB, 8, CTX
+        else:
+            import test_hybrid_lm
+            import test_latent_attention
+            import test_retention
+            source = {"hybrid": test_hybrid_lm,
+                      "retention": test_retention,
+                      "latent": test_latent_attention}[kind]
+            over = {"hybrid": {"pattern": "MEM*E"}}.get(kind, {})
+            model, params = source._tiny(**over)
+            deploy.export_generation(
+                model, params, prefix, sampling=True, decode_batch=2,
+                prompt_buckets=[8, 16], max_context=32, page_size=PAGE,
+                **export)
+            vocab, longest, ctx = 96, 16, 32
+        _ARTIFACTS[key] = (model, params, deploy.load_generator(prefix),
+                           vocab, longest, ctx)
+    return _ARTIFACTS[key]
+
+
+def _watch_releases(eng):
+    """Wrap the engine's page release: every release checks that no
+    program in flight or fetched-not-emitted carries the row, and
+    ``rode`` counts rows that ended while a queued step still carried
+    them (an EOS the host saw after the next step left)."""
+    held = []
+    rode = []
+    release, retire = eng._release_pages_locked, eng._retire
+
+    def checked_release(slot):
+        carried = [st.step for st in (*eng._ahead, *eng._fetched)
+                   if any(s is slot for _, s in st.rows)]
+        held.append(carried)
+        return release(slot)
+
+    def watched_retire(i):
+        slot = eng._slots[i]
+        if slot.ended and slot.inflight:
+            rode.append(slot.req.request_id)
+        return retire(i)
+
+    eng._release_pages_locked = checked_release
+    eng._retire = watched_retire
+    return held, rode
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_served_streams_are_the_oracles_through_join_exit_and_eos(
+        kind, tmp_path_factory):
+    """Every cache kind, one step ahead of the host, serves each request
+    its solo greedy stream: seven requests over two slots join and exit
+    mid-flight (budgets differ, slots and pages are used again), and two
+    end on an EOS while the step after it is already queued — its token
+    is dropped, and no page or state slot returns to the pool while a
+    program in flight carries the row."""
+    model, params, pred, vocab, longest, ctx = _kind(kind, tmp_path_factory)
+    pages = 2 * -(-ctx // PAGE) if pred.paged else 1
+    eng = generation.GenerationEngine(kind, pred, num_pages=pages,
+                                      decode_slots=2, max_pending=32,
+                                      default_deadline_ms=0)
+    held, rode = _watch_releases(eng)
+    rng = np.random.default_rng(7)
+    plans = []
+    for n, plen in enumerate((3, longest, 5, 2, longest - 1, 4, 6)):
+        budget = min(ctx - plen, (5, 9, 3, 8, 4, 7, 6)[n])
+        prompt = rng.integers(0, vocab, size=plen).astype(np.int32)
+        want = np.asarray(model.greedy_decode(params, prompt, budget))
+        eos = int(want[2]) if n in (1, 3) else None
+        if eos is not None:
+            want = want[:list(want).index(eos) + 1]
+        plans.append((prompt, budget, eos, want))
+    ahead = telemetry.counter("serving.decode_ahead").value
+    eng.start()
+    try:
+        futs = [eng.submit(p, b, eos_id=e) for p, b, e, _ in plans]
+        for f, (_, _, _, want) in zip(futs, plans):
+            np.testing.assert_array_equal(f.result(timeout=120), want)
+    finally:
+        eng.stop()
+    assert eng.stats()["kv_pages_free"] == pages
+    assert held and not any(held)
+    assert rode, "no EOS row rode a queued step"
+    assert telemetry.counter("serving.decode_ahead").value > ahead
+    assert not eng._ahead and not eng._fetched
+
+
+def test_a_slot_an_eos_leaves_behind_a_queued_step_serves_the_next(
+        artifact):
+    """One slot and a pool of one request's pages: the first request ends
+    on an EOS while the step after it is queued; the second, waiting for
+    those very pages, is admitted once that step is fetched and served its
+    solo stream."""
+    prefix, model, params = artifact
+    pred = deploy.load_generator(prefix)
+    eng = generation.GenerationEngine("eos", pred, num_pages=4,
+                                      decode_slots=1, max_pending=8,
+                                      default_deadline_ms=0)
+    held, rode = _watch_releases(eng)
+    rng = np.random.default_rng(8)
+    first = rng.integers(0, VOCAB, size=4).astype(np.int32)
+    then = rng.integers(0, VOCAB, size=6).astype(np.int32)
+    solo = np.asarray(model.greedy_decode(params, first, 12))
+    eos = int(solo[3])
+    want_first = solo[:list(solo).index(eos) + 1]
+    want_then = np.asarray(model.greedy_decode(params, then, 10))
+    eng.start()
+    try:
+        fa = eng.submit(first, 12, eos_id=eos)
+        fb = eng.submit(then, 10)
+        np.testing.assert_array_equal(fa.result(timeout=60), want_first)
+        np.testing.assert_array_equal(fb.result(timeout=60), want_then)
+    finally:
+        eng.stop()
+    assert rode == [fa.request_id]
+    assert held and not any(held)
+    assert eng.stats()["kv_pages_free"] == 4
+
+
+def test_sampled_rows_are_their_solo_streams(tmp_path):
+    """A sampled request (its own seed, temperature, top-k and top-p)
+    served beside greedy and other sampled rows, one step ahead of the
+    host, gets the stream it gets alone, bit for bit: the same operands
+    reach the program in its row, and the key is folded with the
+    position inside it."""
+    model, params = _tiny_lm()
+    prefix = str(tmp_path / "lm")
+    deploy.export_generation(model, params, prefix, page_size=PAGE,
+                             max_context=CTX, prompt_buckets=(4, 8),
+                             sampling=True)
+    pred = deploy.load_generator(prefix)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, VOCAB, size=n).astype(np.int32)
+               for n in (5, 3, 7, 4)]
+    asks = [dict(temperature=0.9, top_k=7, top_p=0.9, seed=1234567),
+            dict(), dict(temperature=1.3, seed=2 ** 40 + 5),
+            dict(temperature=0.7, top_p=0.8, seed=99)]
+
+    def serve(which):
+        eng = generation.GenerationEngine("s", pred, num_pages=16,
+                                          decode_slots=2, max_pending=8,
+                                          default_deadline_ms=0).start()
+        try:
+            futs = {i: eng.submit(prompts[i], 8, **asks[i]) for i in which}
+            return {i: f.result(timeout=60) for i, f in futs.items()}
+        finally:
+            eng.stop()
+
+    together = serve(range(4))
+    for i in (0, 2, 3):
+        np.testing.assert_array_equal(serve([i])[i], together[i])
+    assert len({tuple(together[i]) for i in (0, 2, 3)}) == 3
+    np.testing.assert_array_equal(
+        together[1], model.greedy_decode(params, prompts[1], 8))
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "retention"])
+def test_the_replay_tail_is_the_solo_requests(kind, tmp_path_factory):
+    """``return_replay=True`` one step ahead of the host: a request that
+    ends on an EOS, served beside others, gets the replay it gets alone —
+    a log-probability for each token it returns, the experts of its
+    prompt and of every token but the last (none for a model without
+    experts) — nothing of the step it rode after its EOS; and told those
+    choices the plain reference makes every one of them and gives the
+    same log-probabilities."""
+    import test_hybrid_lm
+    model, params, pred, vocab, _, _ = _kind(kind, tmp_path_factory,
+                                            replay=True)
+    experts = pred.replay["layers"], pred.replay["top_k"]
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(0, vocab, size=n).astype(np.int32)
+               for n in (11, 5, 16)]
+    solo = np.asarray(model.greedy_decode(params, prompts[0], 9))
+    eos = int(solo[4])
+    want = solo[:list(solo).index(eos) + 1]
+
+    def serve(which):
+        eng = generation.GenerationEngine("r", pred, num_pages=16,
+                                          max_pending=8,
+                                          default_deadline_ms=0).start()
+        try:
+            futs = [eng.submit(prompts[i], 9, eos_id=eos if i == 0 else None,
+                               return_replay=True) for i in which]
+            return [f.result(timeout=120) for f in futs]
+        finally:
+            eng.stop()
+
+    (alone,) = serve([0])
+    beside = serve([1, 0, 2])[1]
+    for ids, replay in (alone, beside):
+        np.testing.assert_array_equal(ids, want)
+        assert replay["logprobs"].shape == (len(want),)
+        assert replay["routed_experts"].shape == (
+            experts[0], len(prompts[0]) + len(want) - 1, experts[1])
+    np.testing.assert_array_equal(alone[1]["routed_experts"],
+                                  beside[1]["routed_experts"])
+    np.testing.assert_array_equal(alone[1]["logprobs"],
+                                  beside[1]["logprobs"])
+    if kind != "hybrid":
+        return
+    gaps, _, missed, logprobs = test_hybrid_lm.REF.served_token_gaps(
+        params, prompts[0], want, 32, 9, lm=test_hybrid_lm.REF_LM,
+        routed=beside[1]["routed_experts"])
+    assert int(missed) == 0 and float(gaps.max()) == 0.0
+    test_hybrid_lm._close(beside[1]["logprobs"], logprobs)
+
+
+def _raw_spans(trace_dir, name):
+    """Every ``name`` event of the session as ``(start, end, stats)``,
+    its stats a ``[(key, value)]`` list: a key set twice shows twice."""
+    import glob
+    import jax
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    return [(ev.start_ns, ev.start_ns + ev.duration_ns, list(ev.stats))
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name == name]
+
+
+def test_next_step_is_dispatched_before_the_last_is_fetched(artifact,
+                                                            tmp_path):
+    """In steady state the decode step N+1 is dispatched before step N's
+    tokens are fetched (``engine.decode.dispatch`` of N+1 begins before
+    ``engine.decode.fetch`` of N ends), every step says so with ``ahead``
+    1, and ``serving.decode_ahead`` counts them; the first token of a
+    prefill is fetched after the decode step that is fed it has been
+    dispatched."""
+    import jax
+    prefix, model, params = artifact
+    pred = deploy.load_generator(prefix)
+    prompt = np.arange(1, 5, dtype=np.int32)
+    ahead = telemetry.counter("serving.decode_ahead").value
+    eng = generation.GenerationEngine("o", pred, num_pages=8,
+                                      decode_slots=2).start()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = eng.submit(prompt, 10).result(timeout=60)
+    finally:
+        eng.stop()             # every span closed before the session ends
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(got, model.greedy_decode(params, prompt,
+                                                           10))
+    spans = [(n, s, e, dict(args), "engine")
+             for n in ("engine.decode.dispatch", "engine.decode.fetch",
+                       "engine.prefill.fetch", "engine.decode")
+             for s, e, args in _raw_spans(tmp_path, n)]
+    steps = _by_step(spans)
+    decodes = sorted(k for k, v in steps.items() if "engine.decode" in v)
+    assert len(decodes) == 9
+    for n, m in zip(decodes, decodes[1:]):
+        assert steps[m]["engine.decode.dispatch"][1] \
+            < steps[n]["engine.decode.fetch"][2], (n, m)
+    assert all(int(steps[k]["engine.decode"][3]["ahead"]) == 1
+               for k in decodes)
+    assert telemetry.counter("serving.decode_ahead").value - ahead == 9
+    (prefill,) = [v for v in steps.values()
+                  if "engine.prefill.fetch" in v]
+    assert steps[decodes[0]]["engine.decode.dispatch"][2] \
+        <= prefill["engine.prefill.fetch"][1]
+    assert eng._host_operands["decode-w4"]["host_args"] == 6
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "latent"])
+def test_every_decode_span_carries_each_roofline_argument_once(
+        kind, tmp_path_factory, tmp_path):
+    """What the roofline readers average over ``engine.decode`` spans is
+    on every one of them exactly once — one step's rows, tokens held,
+    window, sample tier, latent route and the counts that step brought
+    back behind its tokens — though the step's span outlives the turn
+    that dispatched it."""
+    import jax
+    model, params, pred, vocab, _, _ = _kind(kind, tmp_path_factory)
+    eng = generation.GenerationEngine(kind, pred, num_pages=16,
+                                      max_pending=8,
+                                      default_deadline_ms=0).start()
+    rng = np.random.default_rng(12)
+    steps = telemetry.timer("serving.decode_step_ms").count
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        futs = [eng.submit(rng.integers(0, vocab, size=n).astype(np.int32),
+                           m) for n, m in ((5, 6), (9, 3), (4, 5))]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        eng.stop()
+        jax.profiler.stop_trace()
+    want = {"width", "rows", "step", "ahead", "sample_tier",
+            "held_tokens", "window_tokens", *model.decode_stats}
+    want |= {"state_rows"} if kind == "hybrid" else {"latent_kernel"}
+    decodes = _raw_spans(tmp_path, "engine.decode")
+    assert decodes
+    for _, _, stats in decodes:
+        keys = [k for k, _ in stats]
+        assert sorted(keys) == sorted(want), sorted(keys)
+    assert len(decodes) == telemetry.timer(
+        "serving.decode_step_ms").count - steps
+
+
+class _FetchFault:
+    """``generation``'s numpy, but the ``n``-th copy of a device array
+    out (a fetch) raises ``exc``."""
+
+    def __init__(self, n):
+        self.n = n
+        self.exc = RuntimeError("the chip lost the step")
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        import jax
+        if isinstance(a, jax.Array):
+            self.n -= 1
+            if self.n == 0:
+                raise self.exc
+        return np.asarray(a, *args, **kw)
+
+
+def test_a_fault_at_fetch_fails_every_row_in_flight(artifact, monkeypatch):
+    """A program that raises at its fetch, with the next step queued
+    behind it: every row of every program in flight fails with the causal
+    error, the breaker records one failure, the pool is rebuilt, every
+    page comes back — and the engine serves the request that waited."""
+    prefix, model, params = artifact
+    pred = deploy.load_generator(prefix)
+    breaker = serving._Breaker("fault", 2, 60.0)
+    eng = generation.GenerationEngine("fault", pred, num_pages=12,
+                                      decode_slots=2, breaker=breaker,
+                                      max_pending=8, default_deadline_ms=0)
+    eng.start()
+    fault = _FetchFault(4)        # two prefills, then the second decode
+    monkeypatch.setattr(generation, "_np", fault)
+    errors = telemetry.counter("serving.dispatch_errors").value
+    rng = np.random.default_rng(13)
+    prompts = [rng.integers(0, VOCAB, size=n).astype(np.int32)
+               for n in (4, 6, 5)]
+    try:
+        futs = [eng.submit(p, 8) for p in prompts]
+        for f in futs[:2]:
+            with pytest.raises(RuntimeError) as got:
+                f.result(timeout=60)
+            assert got.value is fault.exc
+        np.testing.assert_array_equal(
+            futs[2].result(timeout=60),
+            model.greedy_decode(params, prompts[2], 8))
+    finally:
+        eng.stop()
+    assert telemetry.counter("serving.dispatch_errors").value == errors + 1
+    assert breaker.failures == 0 and breaker.state == "closed"
+    assert eng.stats()["kv_pages_free"] == 12
+
+
+def test_a_fault_at_fetch_records_the_breaker(artifact, monkeypatch):
+    """The same fault against a breaker that opens at the first failure:
+    it opens, and the requests the engine still holds fail fast."""
+    prefix, _, _ = artifact
+    pred = deploy.load_generator(prefix)
+    breaker = serving._Breaker("fault1", 1, 60.0)
+    eng = generation.GenerationEngine("fault1", pred, num_pages=12,
+                                      decode_slots=2, breaker=breaker,
+                                      max_pending=8, default_deadline_ms=0)
+    eng.start()
+    fault = _FetchFault(4)
+    monkeypatch.setattr(generation, "_np", fault)
+    try:
+        futs = [eng.submit(np.arange(1, 1 + n, dtype=np.int32), 8)
+                for n in (4, 6, 5)]
+        outcomes = []
+        for f in futs:
+            try:
+                f.result(timeout=60)
+                outcomes.append(None)
+            except Exception as exc:  # noqa: BLE001
+                outcomes.append(exc)
+    finally:
+        eng.stop()
+    assert outcomes[0] is fault.exc and outcomes[1] is fault.exc
+    assert isinstance(outcomes[2], serving.CircuitOpenError)
+    assert breaker.state == "open" and breaker.failures == 1
+    assert eng.stats()["kv_pages_free"] == 12
+
+
+def test_drain_stop_resolves_every_future(artifact):
+    """``stop(drain=True)`` right after the submits: every program in
+    flight is fetched and every future resolves to its solo stream
+    before ``stop`` returns."""
+    prefix, model, params = artifact
+    pred = deploy.load_generator(prefix)
+    eng = generation.GenerationEngine("drain", pred, num_pages=16,
+                                      decode_slots=2, max_pending=8,
+                                      default_deadline_ms=0).start()
+    rng = np.random.default_rng(14)
+    prompts = [rng.integers(0, VOCAB, size=n).astype(np.int32)
+               for n in (3, 7, 5, 4, 8)]
+    futs = [eng.submit(p, 6) for p in prompts]
+    eng.stop(drain=True, timeout_s=60)
+    assert all(f.done() for f in futs)
+    for f, p in zip(futs, prompts):
+        np.testing.assert_array_equal(f.result(timeout=0),
+                                      model.greedy_decode(params, p, 6))
+    assert not eng._ahead and not eng._fetched
+    assert eng.stats()["kv_pages_free"] == 16
 
 
 # ------------------------------------------- the pool as the scan's carry
