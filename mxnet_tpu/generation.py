@@ -351,6 +351,9 @@ class GenerationEngine:
                 % (name, self.num_pages))
         #: a model of latent pages (one pool, the latent site's counters)
         self._latent = predictor.meta["kv"].get("page_layout") == "lanes"
+        #: a model whose latent blocks select their tokens (the sparse
+        #: site's counters)
+        self._sparse = bool(predictor.meta["kv"].get("sparse"))
         self._share = bool(_config.get("serving.shared_prefix"))
         if self._share and predictor.state:
             # a shared page skips the prefill that would have built the
@@ -1183,15 +1186,16 @@ class GenerationEngine:
         # reads: each row's own pages where the kernel reads them in
         # place, the whole table's window where the twin gathers it
         psz = gp.page_size
-        if route is not None and route.get("impl") in ("paged", "latent"):
+        kernel = route is not None \
+            and route.get("impl") in ("paged", "latent", "sparse")
+        if kernel:
             window = sum(-(-(s.pos + 1) // psz) for _, s in rows) * psz
         else:
             window = B * width * psz
         sp.set(held_tokens=int(positions.sum()), window_tokens=window)
         if self._latent:
             # a model of latent pages: did this iteration take the kernel
-            sp.set(latent_kernel=int(route is not None
-                                     and route.get("impl") == "latent"))
+            sp.set(latent_kernel=int(kernel))
         if gp.state:
             sp.set(state_rows=len(rows))
             _telemetry.gauge("serving.state_slots").set(len(rows))
@@ -1270,12 +1274,15 @@ class GenerationEngine:
             # (or fell back while the kernel tier was on) is counted
             # (a model of latent pages has the latent site's counters)
             took, fell_back = (
+                _telemetry.counter("kernels.sparse_latent"),
+                _telemetry.counter("kernels.sparse_latent_fallback")) \
+                if self._sparse else (
                 _telemetry.counter("kernels.latent_paged"),
                 _telemetry.counter("kernels.latent_fallback")) \
                 if self._latent else (
                 _telemetry.counter("kernels.paged_attention"),
                 _telemetry.counter("kernels.paged_fallback"))
-            if st.route.get("impl") in ("paged", "latent"):
+            if st.route.get("impl") in ("paged", "latent", "sparse"):
                 took.inc()
             elif _kernels_enabled():
                 fell_back.inc()

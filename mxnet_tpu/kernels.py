@@ -1,10 +1,11 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  The five routed sites
+this module alone decides WHEN they run.  The seven routed sites
 (:func:`attention`, :func:`paged_attention`,
-:func:`latent_paged_attention`, :func:`grouped_matmul`,
-:func:`retention_update`) ask one rule
+:func:`latent_paged_attention`, :func:`sparse_latent_attention`,
+:func:`index_scores`, :func:`grouped_matmul`, :func:`retention_update`)
+ask one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -17,13 +18,16 @@ time and nothing else — nothing is timed, persisted or remembered:
    there, which is how the parity tests run it;
 3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
    :func:`paged_unsupported_reason`, :func:`latent_unsupported_reason`,
+   :func:`sparse_unsupported_reason`, :func:`index_unsupported_reason`,
    :func:`grouped_unsupported_reason`,
    :func:`retention_unsupported_reason`) → the XLA lowering
    (``kernels.fallback`` / ``kernels.paged_fallback`` /
-   ``kernels.latent_fallback`` / ``kernels.grouped_fallback`` /
+   ``kernels.latent_fallback`` / ``kernels.sparse_latent_fallback`` /
+   ``kernels.index_fallback`` / ``kernels.grouped_fallback`` /
    ``kernels.retention_fallback``), never an error;
 4. else the kernel (``kernels.flash_attention`` /
    ``kernels.paged_attention`` / ``kernels.latent_paged`` /
+   ``kernels.sparse_latent`` / ``kernels.index_scores`` /
    ``kernels.grouped_matmul`` / ``kernels.retention_update``), at block
    sizes that are constants or functions of the shapes.
 
@@ -46,18 +50,23 @@ from .ops.pallas_kernels import (flash_attention, flash_attention_tiled,
                                  grouped_col_tile, pallas_grouped_matmul,
                                  pallas_latent_paged_attention,
                                  pallas_paged_attention,
+                                 pallas_index_scores,
                                  pallas_retention_update,
+                                 pallas_sparse_latent_attention,
                                  retention_row_tile)
 
 __all__ = ["enabled", "attention", "paged_attention",
-           "latent_paged_attention", "grouped_matmul", "retention_update",
+           "latent_paged_attention", "sparse_latent_attention",
+           "index_scores", "grouped_matmul", "retention_update",
            "flash_unsupported_reason", "tiled_unsupported_reason",
            "paged_unsupported_reason", "latent_unsupported_reason",
+           "sparse_unsupported_reason", "index_unsupported_reason",
            "grouped_unsupported_reason", "retention_unsupported_reason",
            "record_paged_routes", "record_grouped_routes",
            "record_retention_routes", "pallas_dynamic_shapes",
            "flash_attention", "flash_attention_tiled",
            "pallas_paged_attention", "pallas_latent_paged_attention",
+           "pallas_sparse_latent_attention", "pallas_index_scores",
            "pallas_grouped_matmul", "pallas_retention_update"]
 
 # one-row VMEM feasibility: a q block keeps its head's full K and V
@@ -300,8 +309,9 @@ def pallas_dynamic_shapes():
 # dispatch without re-tracing (the program is AOT; trace-time counters
 # fire at export).
 _ROUTE_SINKS = {"paged": [], "grouped": [], "retention": []}
-# (a latent site's route rides in the paged sink: a model keeps one kind
-# of page, so a decode program has one kind of paged site)
+# (a latent or sparse site's route rides in the paged sink: a model keeps
+# one kind of page, so a decode program has one kind of paged site; the
+# index-score site records no route)
 
 
 @contextlib.contextmanager
@@ -594,6 +604,180 @@ def latent_paged_attention(q, pages, page_table, lengths, scale,
     _note_route("paged", impl="xla", reason=reason, quantized=False)
     return _latent_paged_attention_xla(q, pages, page_table, lengths, scale,
                                        value_width, layer=layer)
+
+
+# ------------------------------------------------ sparse latent attention
+def sparse_unsupported_reason(q, pages, page_table, lengths, chosen,
+                              value_width, layer=None):
+    """Why the Pallas sparse latent kernel can NOT take this decode call,
+    or None if it can: the latent kernel's reasons, with the queries
+    scoring the first ``q.shape[2]`` rows of a page, and the selection
+    ``[B, W, psz]``.  A non-None reason routes to the XLA twin
+    (``kernels.sparse_latent_fallback``) and is surfaced in the export
+    route sink (:func:`record_paged_routes`)."""
+    if q.ndim != 3 or pages.ndim != (3 if layer is None else 4):
+        return "rank: q%s pages %s" % (q.ndim, pages.ndim)
+    kw, (width, psz) = q.shape[2], pages.shape[-2:]
+    if not isinstance(kw, int) or not isinstance(width, int) \
+            or not 0 < kw <= width:
+        return "queries %s wide over page rows %s" % (kw, width)
+    if tuple(chosen.shape) != (q.shape[0],) + tuple(page_table.shape[1:]) \
+            + (psz,):
+        return "chosen%s is not [batch, table width, psz]" % (
+            tuple(chosen.shape),)
+    wide = jax.ShapeDtypeStruct(tuple(q.shape[:2]) + (width,), q.dtype)
+    reason = latent_unsupported_reason(wide, pages, page_table, lengths,
+                                       value_width, layer=layer)
+    if reason is None and kw % (32 // q.dtype.itemsize):
+        reason = "query width %d is no multiple of the sublane packing" % kw
+    return reason
+
+
+def _sparse_latent_attention_xla(q, pages, page_table, lengths, chosen,
+                                 scale, value_width, layer=None):
+    """The XLA twin of the sparse latent kernel: the latent twin's gather
+    of the whole window (under ``mx.kv_gather``) and masked one-pass
+    softmax over the first ``q.shape[2]`` rows (under
+    ``mx.sparse_attention``), every token ``chosen`` does not mark masked
+    beside those past the length.  A row of length 0 answers 0."""
+    if layer is not None:
+        P = pages.shape[1]
+        page_table = jnp.clip(page_table, 0, P - 1) + layer * P
+        pages = pages.reshape((-1,) + pages.shape[2:])
+    B, H, kw = q.shape
+    W, psz = page_table.shape[1], pages.shape[2]
+    with jax.named_scope("mx.kv_gather"):
+        rows = pages[page_table][:, :, :kw]             # [B, W, kw, psz]
+    with jax.named_scope("mx.sparse_attention"):
+        valid = (jnp.arange(W * psz, dtype=jnp.int32)[None, :]
+                 < lengths[:, None]) & (chosen.reshape(B, W * psz) != 0)
+        s = jnp.einsum("bhc,bwcp->bhwp", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, :], s.reshape(B, H, W * psz), -1e30)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.einsum("bhwp,bwcp->bhc",
+                       e.astype(rows.dtype).reshape(B, H, W, psz),
+                       rows[:, :, :value_width],
+                       preferred_element_type=jnp.float32)
+        o = (o / jnp.sum(e, axis=-1, keepdims=True)).astype(q.dtype)
+        return jnp.where((lengths > 0)[:, None, None], o, 0)
+
+
+def sparse_latent_attention(q, pages, page_table, lengths, chosen, scale,
+                            value_width, layer=None):
+    """Decode-step attention over the cached tokens a selection keeps (an
+    ``S`` block's top-k, DeepSeek Sparse Attention): the absorbed latent
+    form of :func:`latent_paged_attention` with every token that
+    ``chosen`` ``[B, W, psz]`` (int32, in page-table order: token ``w *
+    psz + p`` of a row is lane p of its w-th page) leaves at 0 masked.
+    ``q`` ``[B, H, kw]`` scores the first ``kw`` rows of a page (rows below
+    them, index keys, are not scores' rows); values are the first
+    ``value_width``.  Returns ``[B, H, value_width]`` in q's dtype.
+
+    Routing as :func:`latent_paged_attention`: the Pallas kernel
+    (``ops.pallas_kernels.pallas_sparse_latent_attention``, named
+    ``mx_sparse_latent_attention``; counter ``kernels.sparse_latent``),
+    which walks a row's pages where they lie, copies them whole and masks
+    what is not chosen, or the XLA twin, which gathers the whole window
+    (``mx.kv_gather``): tier off, the default knob on an interpreted
+    backend (``kernels.gated_fallback``), or a shape the kernel cannot take
+    (``kernels.sparse_latent_fallback``).  Either runs under the
+    ``mx.sparse_attention`` scope; the decision lands in those counters
+    and, as ``impl`` "sparse" or "xla", in the export route sink of
+    :func:`record_paged_routes`."""
+    reason = _route_reason(
+        lambda: sparse_unsupported_reason(q, pages, page_table, lengths,
+                                          chosen, value_width, layer=layer),
+        _telemetry.counter("kernels.sparse_latent_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.sparse_latent").inc()
+        _note_route("paged", impl="sparse", reason=None, quantized=False)
+        with jax.named_scope("mx.sparse_attention"):
+            return pallas_sparse_latent_attention(
+                q, pages, page_table, lengths, chosen, scale, value_width,
+                layer=layer)
+    _note_route("paged", impl="xla", reason=reason, quantized=False)
+    return _sparse_latent_attention_xla(q, pages, page_table, lengths,
+                                        chosen, scale, value_width,
+                                        layer=layer)
+
+
+def index_unsupported_reason(q, w, pages, page_table, lengths, first_row,
+                             layer=None):
+    """Why the Pallas index-score kernel can NOT take this decode call, or
+    None if it can: concrete shapes, pages of whole 128-lane tiles whose
+    key rows start on a sublane tile, queries as wide as the key rows in
+    the pool's dtype.  A non-None reason routes to the XLA twin
+    (``kernels.index_fallback``)."""
+    if q.ndim != 3 or w.ndim != 2 or pages.ndim != (3 if layer is None
+                                                    else 4):
+        return "rank: q%s w%s pages %s" % (q.ndim, w.ndim, pages.ndim)
+    fixed = tuple(q.shape) + tuple(w.shape) + tuple(pages.shape[-2:]) \
+        + tuple(page_table.shape) + tuple(lengths.shape)
+    if not all(isinstance(d, int) for d in fixed):
+        return "symbolic shape (q%s table%s)" % (q.shape, page_table.shape)
+    if not isinstance(pages.shape[-3], int) \
+            and not _pallas_dynamic_shapes():
+        return "symbolic page count %s outside a dynamic-shape export" % (
+            pages.shape[-3],)
+    width, psz = pages.shape[-2:]
+    di = q.shape[2]
+    pack = 32 // pages.dtype.itemsize
+    if psz % 128 or di % pack or first_row % pack \
+            or not 0 <= first_row <= width - di:
+        return "key rows [%d, %d) of a page [%d, psz %d]: psz must be a " \
+            "multiple of 128, the rows of %d" % (first_row, first_row + di,
+                                                 width, psz, pack)
+    if q.dtype != pages.dtype or w.shape != q.shape[:2]:
+        return "q %s %s, w %s over %s pages" % (
+            q.dtype, tuple(q.shape), tuple(w.shape), pages.dtype)
+    return None
+
+
+def _index_scores_xla(q, w, pages, page_table, lengths, first_row,
+                      layer=None):
+    """The XLA twin of the index-score kernel: each page's key rows
+    gathered (a slice a page), every token of the table scored."""
+    L, P, width, psz = (1,) + tuple(pages.shape) if layer is None \
+        else pages.shape
+    di = q.shape[2]
+    ids = jnp.clip(page_table, 0, P - 1) + (0 if layer is None
+                                            else layer * P)
+    flat = pages.reshape((-1, width, psz))
+    keys = jax.vmap(jax.vmap(lambda i: lax.dynamic_slice(
+        flat, (i, first_row, 0), (1, di, psz))[0]))(ids)    # [B,W,Di,psz]
+    s = jnp.einsum("bhd,bwdp->bwhp", q, keys,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * w[:, None, :, None], axis=2)
+
+
+def index_scores(q, w, pages, page_table, lengths, first_row, layer=None):
+    """A decode step's index scores (an ``S`` block's indexer, DeepSeek
+    Sparse Attention) over every token the rows' pages hold: ``sum_j w_j
+    relu(q_j . k)`` with the index keys ``k`` in rows ``[first_row,
+    first_row + Di)`` of latent pages whose tokens lie on the lanes.  ``q``
+    ``[B, Hi, Di]``, ``w`` ``[B, Hi]`` float32; ``pages``, ``page_table``,
+    ``lengths`` and ``layer`` as :func:`latent_paged_attention`'s.  Returns
+    ``[B, W, psz]`` float32 in page-table order; what lies past a row's
+    length is the caller's to mask.
+
+    Routing by the module's rule: the Pallas kernel
+    (``ops.pallas_kernels.pallas_index_scores``, named ``mx_index_scores``;
+    counter ``kernels.index_scores``), which copies only the key rows of a
+    row's own pages, or the XLA twin, which gathers them for the whole
+    table (``kernels.index_fallback`` where the shape is refused).  The
+    call carries no scope of its own: the caller's (``mx.dsa_indexer``)
+    names its device time."""
+    reason = _route_reason(
+        lambda: index_unsupported_reason(q, w, pages, page_table, lengths,
+                                         first_row, layer=layer),
+        _telemetry.counter("kernels.index_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.index_scores").inc()
+        return pallas_index_scores(q, w, pages, page_table, lengths,
+                                   first_row, layer=layer)
+    return _index_scores_xla(q, w, pages, page_table, lengths, first_row,
+                             layer=layer)
 
 
 # ------------------------------------------------------- grouped product

@@ -31,6 +31,21 @@ own):
          arXiv:2412.19437 section 2.1): ``W_down(silu(W_gate x) * W_up
          x)`` an expert, chosen by ``E``'s sigmoid-scored, bias-selected,
          normalised top-k router, beside one shared gated expert
+  ``S``  ``L`` with learned SPARSE selection (DeepSeek Sparse Attention,
+         the DeepSeek-V3.2-Exp report section 2.1): an indexer scores
+         every earlier token, ``I_ts = sum_j w_tj relu(q^I_tj . k^I_s)``
+         (``index_heads`` queries from the query latent, one LayerNorm'd
+         key a token, rotary on ``rope_dim`` of their dims), and each
+         query attends, in the absorbed form, over its ``index_topk``
+         highest-scoring tokens only; a token's index key rides in its
+         page below its latent row
+  ``W``  ``L`` over a sliding WINDOW of the ``window`` latest positions,
+         with sizes of its own (``swa_*``) and no page: each decode slot
+         keeps the window's latent rows in a ring of ``ring`` columns
+         (position p at column p mod ring) in the state arrays
+
+``S`` and ``W`` blocks gate each head's output by one sigmoid of the
+block's normalised input (Qiu et al., arXiv:2505.06708).
 
 ``h <- h + Mixer(RMSNorm(h))`` per block, no positional table (the
 recurrences order the tokens; ``R`` and ``L`` rotate q and k by their
@@ -53,7 +68,9 @@ a page is component c of its tokens: a 576-wide minor axis would be padded
 to 640 lanes, or laid out this way by the compiler behind a copy into
 every kernel call); ``kv_spec()`` says so (``pools``, ``page_layout``) and
 ``deploy`` / the engine build the cache from that description.  A model
-keeps one kind of page: a pattern may not mix ``*`` and ``L``.
+keeps one kind of page: a pattern may not mix ``*``, ``L`` and ``S``.
+``S`` pages are ``L``'s with ``index_dim`` more rows a page, the index
+keys (``kv_spec()["index_rows"]``).
 
 One chip's share of an expert-parallel deployment is a configuration, not
 another code path: ``experts_held`` / ``expert_offset`` say which routed
@@ -84,10 +101,25 @@ from .transformer import TransformerLM, _norm
 
 __all__ = ["HybridLMConfig", "HybridLM"]
 
-KINDS = "ME*RFLG"
+KINDS = "ME*RFLGSW"
 #: what ``parallel.moe.dropless_experts`` counts, summed over the E and G
 #: blocks
 _STATS = ("pairs", "experts_hit", "max_load")
+#: ``S`` blocks' prefill: queries a step of its loop (each step holds the
+#: chunk's index scores over the whole prompt, float32)
+_DSA_QUERY_CHUNK = 64
+#: ... in this many causal segments, each reading the keys before its end
+_DSA_SEGMENTS = 4
+#: the indexer's LayerNorm (DeepSeek's default)
+_INDEX_LN_EPS = 1e-6
+#: the float32 pair products one expert layer may hold at once, a
+#: sixteenth of a 16 GiB chip: a layer whose products need more runs its
+#: token rows in chunks (:func:`_in_row_chunks`)
+_MOE_PAIR_BYTES = 1 << 30
+#: what the ``S`` and ``W`` blocks count in a decode step, after
+#: :data:`_STATS` (``decode_step(..., return_stats=True)``)
+_SPARSE_STATS = ("index_tokens", "selected_tokens")
+_RING_STATS = ("ring_tokens",)
 
 
 class HybridLMConfig:
@@ -99,7 +131,10 @@ class HybridLMConfig:
                  route_scale=1.0, experts_held=None, expert_offset=0,
                  max_len=2048, dtype=jnp.bfloat16, eps=1e-5, depth=None,
                  rope_theta=1e6, mlp_ff=1024, q_rank=128, kv_rank=64,
-                 nope_dim=32, rope_dim=16, v_dim=32):
+                 nope_dim=32, rope_dim=16, v_dim=32, index_heads=4,
+                 index_dim=16, index_topk=8, swa_heads=4, swa_q_rank=64,
+                 swa_kv_rank=32, swa_nope_dim=16, swa_rope_dim=8,
+                 swa_v_dim=16, swa_rope_theta=5e4, window=9):
         if not pattern or set(pattern) - set(KINDS):
             raise ValueError("pattern %r: one of %r per block"
                              % (pattern, KINDS))
@@ -158,10 +193,29 @@ class HybridLMConfig:
         self.nope_dim = nope_dim
         self.rope_dim = rope_dim
         self.v_dim = v_dim
-        if "L" in pattern and "*" in pattern:
+        #: ``S`` blocks (``L``'s sizes and rotary base; the index queries and
+        #: keys rotate their first ``rope_dim`` dims): the indexer's heads,
+        #: their width, and the tokens a query keeps
+        self.index_heads = index_heads
+        self.index_dim = index_dim
+        self.index_topk = index_topk
+        #: ``W`` blocks: ``L``'s sizes of their own, the positions a query
+        #: sees (its own among them) and the columns of a slot's ring (the
+        #: window in whole 128-lane tiles)
+        self.swa_heads = swa_heads
+        self.swa_q_rank = swa_q_rank
+        self.swa_kv_rank = swa_kv_rank
+        self.swa_nope_dim = swa_nope_dim
+        self.swa_rope_dim = swa_rope_dim
+        self.swa_v_dim = swa_v_dim
+        self.swa_rope_theta = float(swa_rope_theta)
+        self.window = int(window)
+        self.ring = -(-self.window // 128) * 128
+        if sum(k in pattern for k in "*LS") > 1:
             raise ValueError(
-                "pattern %r mixes '*' (K and V pages) and 'L' (latent "
-                "pages): a model keeps one kind of page" % (pattern,))
+                "pattern %r mixes '*' (K and V pages), 'L' (latent pages) "
+                "and 'S' (latent pages with index keys): a model keeps one "
+                "kind of page" % (pattern,))
 
 
 def _normal(key, shape, std, dtype):
@@ -180,8 +234,6 @@ def _relu2(x):
 class HybridLM:
     """Pattern-built hybrid decoder; see the module text."""
 
-    #: what ``decode_step(..., return_stats=True)`` appends, in order
-    decode_stats = tuple("moe_" + n for n in _STATS)
     #: ``prefill`` / ``decode_step`` can say what replaying a served
     #: sequence elsewhere needs (``return_replay``): the experts every
     #: token chose in every ``E`` block, and the log-probability of the
@@ -197,11 +249,22 @@ class HybridLM:
         self.names = tuple("%02d" % i for i in range(len(self.kinds)))
         # which attention layer (pool index) a block is
         self.attn_index = {n: i for i, n in enumerate(
-            n for n, k in zip(self.names, self.kinds) if k in "*L")}
-        self.latent = "L" in self.kinds
+            n for n, k in zip(self.names, self.kinds) if k in "*LS")}
+        self.latent = "L" in self.kinds or "S" in self.kinds
+        self.sparse = "S" in self.kinds
+        #: what ``decode_step(..., return_stats=True)`` appends, in order:
+        #: the experts' counts, then the rows the ``S`` blocks scored and
+        #: attended and the ring columns the ``W`` blocks attended, where
+        #: the pattern has them
+        self.decode_stats = tuple("moe_" + n for n in _STATS) \
+            + (_SPARSE_STATS if self.sparse else ()) \
+            + (_RING_STATS if "W" in self.kinds else ())
         #: ``L`` blocks: scores over a head's whole query/key width, in the
-        #: expanded and in the absorbed form alike
+        #: expanded and in the absorbed form alike (``S`` and ``W`` blocks:
+        #: over theirs)
         self._mla_scale = 1.0 / math.sqrt(config.nope_dim + config.rope_dim)
+        self._swa_scale = 1.0 / math.sqrt(config.swa_nope_dim
+                                          + config.swa_rope_dim)
 
     # -------------------------------------------------------------- params
     def init(self, key):
@@ -271,6 +334,20 @@ class HybridLM:
                       "w_uk": mk((Rkv, H, dn), Rkv),
                       "w_uv": mk((Rkv, H, dv), Rkv),
                       "wo": mk((H, dv, D), H * dv, out)}
+            elif kind in "SW":
+                H, Rq, Rkv, dn, dr, dv, _ = self._latent_sizes(kind == "W")
+                lp = {"ln": ones(D), "w_dq": mk((D, Rq), D),
+                      "q_norm": ones(Rq), "w_uq": mk((Rq, H, dn + dr), Rq),
+                      "w_dkv": mk((D, Rkv + dr), D), "kv_norm": ones(Rkv),
+                      "w_uk": mk((Rkv, H, dn), Rkv),
+                      "w_uv": mk((Rkv, H, dv), Rkv),
+                      "wo": mk((H, dv, D), H * dv, out)}
+                lp["w_hg"] = mk((D, H), D)
+                if kind == "S":
+                    Hi, Di = cfg.index_heads, cfg.index_dim
+                    lp.update(w_iq=mk((Rq, Hi, Di), Rq), w_ik=mk((D, Di), D),
+                              ik_w=ones(Di), ik_b=jnp.zeros((Di,), dt),
+                              w_iw=mk((D, Hi), D))
             elif kind == "G":
                 E, Eh = cfg.num_experts, cfg.experts_held
                 F, Fs = cfg.expert_ff, cfg.shared_ff
@@ -326,14 +403,27 @@ class HybridLM:
                                       causal=True)
 
     # ----------------------------------------------------- latent attention
-    def _mla_parts(self, x, lp, positions):
+    def _latent_sizes(self, window):
+        """(heads, query rank, latent rank, un-rotated, rotary and value
+        widths, rotary base) of ``L`` / ``S`` blocks, or of ``W`` ones."""
+        cfg = self.cfg
+        if window:
+            return (cfg.swa_heads, cfg.swa_q_rank, cfg.swa_kv_rank,
+                    cfg.swa_nope_dim, cfg.swa_rope_dim, cfg.swa_v_dim,
+                    cfg.swa_rope_theta)
+        return (cfg.num_heads, cfg.q_rank, cfg.kv_rank, cfg.nope_dim,
+                cfg.rope_dim, cfg.v_dim, cfg.rope_theta)
+
+    def _mla_parts(self, x, lp, positions, window=False, extras=False):
         """RMSNorm, the two low-rank paths with their norms, rotary at
         ``positions``: x [B,S,D], positions [B,S] -> q_nope [B,S,H,dn],
         q_rope [B,S,H,dr] (rotated), the normalised latent c [B,S,Rkv] and
         the rotated key k_rope [B,S,dr] that every head shares, in x's
-        dtype.  ``[c | k_rope]`` is the row a token keeps."""
+        dtype.  ``[c | k_rope]`` is the row a token keeps.  ``window``: a
+        ``W`` block's sizes; ``extras``: the normalised input and query
+        latent [B,S,Rq] follow (the gate and the indexer read them)."""
         cfg = self.cfg
-        Rkv, dn = cfg.kv_rank, cfg.nope_dim
+        _, _, Rkv, dn, _, _, theta = self._latent_sizes(window)
         f32 = jnp.float32
         n = _norm(x, lp["ln"], cfg.eps)
         cq = _norm(jnp.einsum("bsd,dr->bsr", n, lp["w_dq"],
@@ -344,10 +434,10 @@ class HybridLM:
         ckr = jnp.einsum("bsd,dr->bsr", n, lp["w_dkv"],
                          preferred_element_type=f32).astype(x.dtype)
         c = _norm(ckr[..., :Rkv], lp["kv_norm"], cfg.eps)
-        q_rope = _rope(q[..., dn:], positions, cfg.rope_theta)
-        k_rope = _rope(ckr[..., None, Rkv:], positions,
-                       cfg.rope_theta)[:, :, 0]
-        return q[..., :dn], q_rope, c, k_rope
+        q_rope = _rope(q[..., dn:], positions, theta)
+        k_rope = _rope(ckr[..., None, Rkv:], positions, theta)[:, :, 0]
+        out = (q[..., :dn], q_rope, c, k_rope)
+        return out + (n, cq) if extras else out
 
     def _mla_out(self, o, lp):
         """The heads' outputs [..., H, dv] through the output projection."""
@@ -406,6 +496,290 @@ class HybridLM:
                            preferred_element_type=jnp.float32
                            ).astype(ctx.dtype)
             return self._mla_out(o, lp)
+
+    def _write_latent_column(self, pool, a, page, slot, row, psz):
+        """One token's row into its page of layer ``a`` of a pool whose
+        pages hold their tokens on the lanes: the page is fetched, the
+        column put in and the page put back whole, so nothing is scattered
+        across a page's rows (a column scatter has the compiler turn the
+        whole pool round, twice a step)."""
+        with jax.named_scope("mx.kv_write"):
+            at = jnp.minimum(page[:, 0], pool.shape[1] - 1)
+            lane = jnp.arange(psz, dtype=jnp.int32)
+            pages = jnp.where(
+                lane[None, None, :] == slot[:, :, None],
+                row.astype(pool.dtype)[:, :, None], pool[a, at])
+            return pool.at[a, page[:, 0]].set(pages, mode="drop")
+
+    # ------------------------------------ sparse and windowed latent blocks
+    def _gate(self, o, n, lp):
+        """``S`` / ``W``: each head's output o [..., H, dv] times one
+        sigmoid of the block's normalised input n [..., D], before the
+        output projection (``L`` blocks have no gate)."""
+        with jax.named_scope("mx.attn_gate"):
+            g = jax.nn.sigmoid(jnp.einsum("...d,dh->...h", n, lp["w_hg"],
+                                          preferred_element_type=jnp.float32))
+            return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+
+    def _index_parts(self, n, cq, lp, positions):
+        """The indexer's inputs: n [B,S,D] (the block's normalised input),
+        cq [B,S,Rq] (the normalised query latent), positions [B,S] ->
+        index queries [B,S,Hi,Di] and keys [B,S,Di] in n's dtype (rotary on
+        their first ``rope_dim`` dims; the key LayerNorm'd), and each
+        query head's weight [B,S,Hi] float32, over sqrt(Hi) sqrt(Di): the
+        score of key s for query t is ``sum_j w_tj relu(q_tj . k_s)``."""
+        cfg = self.cfg
+        f32 = jnp.float32
+        r = cfg.rope_dim
+        qi = jnp.einsum("bsr,rhe->bshe", cq, lp["w_iq"],
+                        preferred_element_type=f32).astype(n.dtype)
+        ki = jnp.einsum("bsd,de->bse", n, lp["w_ik"],
+                        preferred_element_type=f32)
+        mean = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+        ki = ((ki - mean) * lax.rsqrt(var + _INDEX_LN_EPS)
+              * lp["ik_w"].astype(f32) + lp["ik_b"].astype(f32)
+              ).astype(n.dtype)
+        qi = jnp.concatenate([_rope(qi[..., :r], positions, cfg.rope_theta),
+                              qi[..., r:]], axis=-1)
+        ki = jnp.concatenate([_rope(ki[..., None, :r], positions,
+                                    cfg.rope_theta)[:, :, 0], ki[..., r:]],
+                             axis=-1)
+        wi = jnp.einsum("bsd,dh->bsh", n, lp["w_iw"],
+                        preferred_element_type=f32) \
+            / math.sqrt(cfg.index_heads * cfg.index_dim)
+        return qi, ki, wi
+
+    def _dsa_sequence(self, x, lp):
+        """``S`` over whole prompts: x [B,S,D] -> (out [B,S,D], the rows to
+        keep [B,S,Rkv+dr+Di]: latent, rotary key, index key).  The indexer
+        scores every key at or before each query, each query keeps the
+        ``index_topk`` best (all of them while there are fewer) and attends
+        over those alone in the ABSORBED form (128 heads share one latent
+        row, so the selected rows are gathered once for all heads), in
+        chunks of :data:`_DSA_QUERY_CHUNK` queries: a chunk's index scores
+        and its gathered rows are what a step holds.  The chunks run in
+        :data:`_DSA_SEGMENTS` causal segments, a segment's against the keys
+        before its end only (5/8 of the whole prompt's on average)."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        f32, dt = jnp.float32, x.dtype
+        Rkv = cfg.kv_rank
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        with jax.named_scope("mx.mla_proj"):
+            q_nope, q_rope, c, k_rope, n, cq = self._mla_parts(
+                x, lp, positions, extras=True)
+        with jax.named_scope("mx.dsa_indexer"):
+            qi, ki, wi = self._index_parts(n, cq, lp, positions)
+        keys = jnp.concatenate([c, k_rope], axis=-1)          # [B,S,Rkv+dr]
+        Q = min(_DSA_QUERY_CHUNK, S)
+        steps = -(-S // Q)
+        pad = ((0, 0), (0, steps * Q - S))
+        q_nope, q_rope, qi, wi = (
+            jnp.pad(a, pad + ((0, 0),) * (a.ndim - 2))
+            for a in (q_nope, q_rope, qi, wi))
+
+        def chunk(span, i):
+            # queries [i Q, (i + 1) Q) against the keys before ``span``
+            t0 = i * Q
+            part = functools.partial(lax.dynamic_slice_in_dim, start_index=t0,
+                                     slice_size=Q, axis=1)
+            t = t0 + jnp.arange(Q, dtype=jnp.int32)
+            with jax.named_scope("mx.dsa_indexer"):
+                score = jnp.einsum("bqhd,bsd->bqhs", part(qi), ki[:, :span],
+                                   preferred_element_type=f32)
+                score = jnp.sum(jax.nn.relu(score) * part(wi)[..., None],
+                                axis=2)                       # [B,Q,span]
+            with jax.named_scope("mx.dsa_select"):
+                score = jnp.where(jnp.arange(span)[None, None, :]
+                                  <= t[None, :, None], score, -jnp.inf)
+                _, idx = lax.top_k(score, min(cfg.index_topk, span))
+                kept = idx <= t[None, :, None]                # [B,Q,K]
+            with jax.named_scope("mx.sparse_attention"):
+                rows = jax.vmap(lambda k, j: k[j])(keys[:, :span], idx)
+                q = jnp.concatenate([jnp.einsum(
+                    "bqhe,rhe->bqhr", part(q_nope), lp["w_uk"],
+                    preferred_element_type=f32).astype(dt), part(q_rope)],
+                    axis=-1)
+                s = jnp.einsum("bqhc,bqkc->bqhk", q, rows,
+                               preferred_element_type=f32) * self._mla_scale
+                s = jnp.where(kept[:, :, None, :], s, -1e30)
+                e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                ctx = jnp.einsum("bqhk,bqkr->bqhr", e.astype(dt),
+                                 rows[..., :Rkv], preferred_element_type=f32)
+                ctx = (ctx / jnp.sum(e, axis=-1)[..., None]).astype(dt)
+            with jax.named_scope("mx.mla_proj"):
+                return jnp.einsum("bqhr,rhe->bqhe", ctx, lp["w_uv"],
+                                  preferred_element_type=f32).astype(dt)
+
+        # causal segments: a later segment's chunks read more keys, an
+        # earlier one's only those before its end (one loop a segment)
+        per = -(-steps // _DSA_SEGMENTS)
+        o = jnp.concatenate([
+            lax.map(functools.partial(chunk, min(S, (c0 + per) * Q)),
+                    jnp.arange(c0, min(c0 + per, steps), dtype=jnp.int32))
+            for c0 in range(0, steps, per)])
+        o = jnp.moveaxis(o, 0, 1).reshape((B, steps * Q) + o.shape[-2:])
+        o = self._gate(o[:, :S], n, lp)
+        with jax.named_scope("mx.mla_proj"):
+            out = self._mla_out(o, lp)
+        return out, jnp.concatenate([keys, ki], axis=-1)
+
+    def _swa_sequence(self, x, lp):
+        """``W`` over whole prompts, in the EXPANDED form: x [B,S,D] ->
+        (out [B,S,D], the rows a ring keeps [B,S,Rkv+dr]).  Position t sees
+        t-window+1 .. t, so a chunk of ``Q >= window - 1`` queries needs
+        only its own keys and the chunk's before it: banded causal
+        attention, one chunk a step."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        f32, dt = jnp.float32, x.dtype
+        H, _, _, dn, dr, dv, _ = self._latent_sizes(True)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        with jax.named_scope("mx.mla_proj"):
+            q_nope, q_rope, c, k_rope, n, _ = self._mla_parts(
+                x, lp, positions, window=True, extras=True)
+            k = jnp.concatenate([
+                jnp.einsum("bsr,rhe->bshe", c, lp["w_uk"],
+                           preferred_element_type=f32).astype(dt),
+                jnp.broadcast_to(k_rope[:, :, None], (B, S, H, dr))],
+                axis=-1)
+            v = jnp.einsum("bsr,rhe->bshe", c, lp["w_uv"],
+                           preferred_element_type=f32).astype(dt)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        Q = -(-(cfg.window - 1) // 8) * 8
+        steps = -(-S // Q)
+        q, k, v = (jnp.pad(a, ((0, 0), (Q * (a is not q), steps * Q - S),
+                               (0, 0), (0, 0))) for a in (q, k, v))
+
+        def chunk(i):
+            qc = lax.dynamic_slice_in_dim(q, i * Q, Q, axis=1)
+            kc, vc = (lax.dynamic_slice_in_dim(a, i * Q, 2 * Q, axis=1)
+                      for a in (k, v))
+            t = i * Q + jnp.arange(Q, dtype=jnp.int32)[:, None]
+            p = (i - 1) * Q + jnp.arange(2 * Q, dtype=jnp.int32)[None, :]
+            seen = (p <= t) & (p > t - cfg.window) & (p >= 0)
+            with jax.named_scope("mx.window_attention"):
+                s = jnp.einsum("bqhe,bkhe->bhqk", qc, kc,
+                               preferred_element_type=f32) * self._swa_scale
+                s = jnp.where(seen, s, -1e30)
+                e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                o = jnp.einsum("bhqk,bkhe->bqhe", e.astype(dt), vc,
+                               preferred_element_type=f32)
+                return (o / jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)[..., None]
+                        ).astype(dt)
+
+        o = lax.map(chunk, jnp.arange(steps, dtype=jnp.int32))
+        o = jnp.moveaxis(o, 0, 1).reshape(B, steps * Q, H, dv)[:, :S]
+        o = self._gate(o, n, lp)
+        with jax.named_scope("mx.mla_proj"):
+            out = self._mla_out(o, lp)
+        return out, jnp.concatenate([c, k_rope], axis=-1)
+
+    def _ring_of(self, rows, lengths):
+        """A ``W`` block's ring after a prompt: rows [B,S,w] (lengths [B] or
+        None: all S) -> [B, w, ring], column j holding the latest real
+        position p with ``p % ring == j`` (zeros where there is none)."""
+        B, S, _ = rows.shape
+        last = (jnp.full((B,), S, jnp.int32) if lengths is None
+                else lengths.astype(jnp.int32))[:, None] - 1
+        lane = jnp.arange(self.cfg.ring, dtype=jnp.int32)[None, :]
+        p = last - (last - lane) % self.cfg.ring                 # [B, ring]
+        got = jnp.take_along_axis(rows, jnp.clip(p, 0, S - 1)[..., None],
+                                  axis=1)
+        return jnp.swapaxes(jnp.where((p >= 0)[..., None], got, 0), 1, 2)
+
+    def _latent_absorb(self, x, lp, positions, window=False):
+        """One token a row of an ``S`` or ``W`` block: x [B,D] -> (each
+        head's absorbed query beside its rotary part [B,H,Rkv+dr], the row
+        to keep [B,Rkv+dr], the normalised input [B,D], the query latent
+        [B,Rq])."""
+        with jax.named_scope("mx.mla_proj"):
+            q_nope, q_rope, c, k_rope, n, cq = (
+                a[:, 0] for a in self._mla_parts(
+                    x[:, None], lp, positions[:, None], window=window,
+                    extras=True))
+            q_abs = jnp.einsum("bhe,rhe->bhr", q_nope, lp["w_uk"],
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+            return jnp.concatenate([q_abs, q_rope], axis=-1), \
+                jnp.concatenate([c, k_rope], axis=-1), n, cq
+
+    def _latent_finish(self, ctx, n, lp):
+        """Attended latents [B,H,Rkv] through the values' up-projection,
+        the gate and the output projection."""
+        with jax.named_scope("mx.mla_proj"):
+            o = jnp.einsum("bhr,rhe->bhe", ctx, lp["w_uv"],
+                           preferred_element_type=jnp.float32
+                           ).astype(ctx.dtype)
+        o = self._gate(o, n, lp)
+        with jax.named_scope("mx.mla_proj"):
+            return self._mla_out(o, lp)
+
+    def _dsa_step(self, x, lp, positions, pool, a, page, slot, page_table,
+                  psz):
+        """``S``, one token a row: its row (latent, rotary key, index key)
+        goes into its page; the indexer scores every token the row's pages
+        hold, the ``index_topk`` best are kept (ties to the earlier
+        position, as ``lax.top_k`` breaks them) and the absorbed query
+        attends over those alone (``kernels.sparse_latent_attention``).
+        Returns (out [B,D], the pool, each row's tokens scored and tokens
+        attended [2, B] int32)."""
+        cfg = self.cfg
+        B = x.shape[0]
+        q, row, n, cq = self._latent_absorb(x, lp, positions)
+        with jax.named_scope("mx.dsa_indexer"):
+            qi, ki, wi = (t[:, 0] for t in self._index_parts(
+                n[:, None], cq[:, None], lp, positions[:, None]))
+        pool = self._write_latent_column(
+            pool, a, page, slot, jnp.concatenate([row, ki], axis=-1), psz)
+        lengths = positions + 1
+        with jax.named_scope("mx.dsa_indexer"):
+            score = _kernels.index_scores(
+                qi, wi, pool, page_table, lengths,
+                cfg.kv_rank + cfg.rope_dim, layer=a).reshape(B, -1)
+        with jax.named_scope("mx.dsa_select"):
+            held = jnp.arange(score.shape[1], dtype=jnp.int32)[None, :] \
+                < lengths[:, None]
+            _, idx = lax.top_k(jnp.where(held, score, -jnp.inf),
+                               min(cfg.index_topk, score.shape[1]))
+            chosen = jnp.zeros(score.shape, jnp.int32).at[
+                jnp.arange(B)[:, None], idx].set(1) * held
+            counts = jnp.stack([jnp.sum(held, axis=1, dtype=jnp.int32),
+                                jnp.sum(chosen, axis=1)])
+            chosen = chosen.reshape(page_table.shape + (psz,))
+        ctx = _kernels.sparse_latent_attention(
+            q, pool, page_table, lengths, chosen, self._mla_scale,
+            cfg.kv_rank, layer=a)
+        return self._latent_finish(ctx, n, lp), pool, counts
+
+    def _swa_step(self, x, lp, positions, ring):
+        """``W``, one token a row: its row goes into column ``position %
+        ring`` of the slot's ring [B, Rkv+dr, ring] (every column rewritten
+        in place: the ring is donated), then the absorbed query attends
+        over the columns whose positions lie in the window.  Returns (out
+        [B,D], the ring, each row's columns attended [1, B] int32)."""
+        cfg = self.cfg
+        Rkv = cfg.swa_kv_rank
+        q, row, n, _ = self._latent_absorb(x, lp, positions, window=True)
+        R = ring.shape[-1]
+        lane = jnp.arange(R, dtype=jnp.int32)[None, :]
+        with jax.named_scope("mx.kv_write"):
+            ring = jnp.where((lane == (positions % R)[:, None])[:, None, :],
+                             row.astype(ring.dtype)[:, :, None], ring)
+        with jax.named_scope("mx.window_attention"):
+            p = positions[:, None] - (positions[:, None] - lane) % R
+            seen = (p >= 0) & (p > positions[:, None] - cfg.window)
+            s = jnp.einsum("bhc,bcp->bhp", q, ring,
+                           preferred_element_type=jnp.float32) \
+                * self._swa_scale
+            s = jnp.where(seen[:, None, :], s, -1e30)
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            ctx = jnp.einsum("bhp,bcp->bhc", e.astype(ring.dtype),
+                             ring[:, :Rkv], preferred_element_type=jnp.float32)
+            ctx = (ctx / jnp.sum(e, axis=-1)[..., None]).astype(x.dtype)
+        return self._latent_finish(ctx, n, lp), ring, \
+            jnp.sum(seen, axis=1, dtype=jnp.int32)[None]
 
     # -------------------------------------------------------------- Mamba-2
     def _ssm_split(self, x, lp):
@@ -689,15 +1063,26 @@ class HybridLM:
                     x = x + out
             elif kind == "F":
                 x = x + self._mlp(x, lp)
-            elif kind == "L":
-                out, rows = self._mla_sequence(x, lp)
+            elif kind in "LS":
+                out, rows = (self._mla_sequence if kind == "L"
+                             else self._dsa_sequence)(x, lp)
                 if kv_sink is not None:
                     kv_sink(name, rows)
+                x = x + out
+            elif kind == "W":
+                out, rows = self._swa_sequence(x, lp)
+                states["ring" + name] = self._ring_of(rows, lengths)
                 x = x + out
             else:
                 with jax.named_scope("mx.moe"):
                     mix = self._moe if kind == "E" else self._gated_moe
-                    out, st, chosen = mix(x.reshape(B * S, D), lp, valid)
+                    # a pair's widest float32 row: the experts' inner
+                    # width, or what they return (D for G, E's latent)
+                    wide = max(self.cfg.expert_ff, D if kind == "G"
+                               else self.cfg.moe_latent)
+                    out, st, chosen = _in_row_chunks(
+                        mix, x.reshape(B * S, D), lp, valid,
+                        self.cfg.top_k * wide * 4)
                     stats.append(st)
                     routed.append(chosen.reshape(B, S, -1))
                     x = x + out.reshape(B, S, D)
@@ -721,7 +1106,12 @@ class HybridLM:
         rank + the rotary key's width of which the first ``value_width``
         are the values, and ``page_layout`` ``"lanes"`` says a page is
         ``[row_width, page_size]``, its tokens on the lanes (absent: a page
-        is ``[page_size, row_width]``)."""
+        is ``[page_size, row_width]``).  A model of ``S`` blocks keeps the
+        index keys below the rows (``index_rows`` ``[first, end)``) and
+        says how many blocks select and how many tokens each keeps
+        (``sparse``); one of ``W`` blocks keeps a ring a block in ``state``
+        (``ring<block>`` ``[rows, columns]``) and says how many blocks, their
+        window and their columns (``rings``)."""
         if quantized:
             raise ValueError("HybridLM keeps no int8 K/V pages")
         cfg = self.cfg
@@ -747,6 +1137,11 @@ class HybridLM:
                               "shape": [KV, N, cfg.head_dim]})
                 state.append({"name": "retz" + name, "dtype": "float32",
                               "shape": [KV, N]})
+            elif kind == "W":
+                state.append({"name": "ring" + name,
+                              "dtype": jnp.dtype(cfg.dtype).name,
+                              "shape": [cfg.swa_kv_rank + cfg.swa_rope_dim,
+                                        cfg.ring]})
         spec = {"num_layers": len(self.attn_index),
                 "num_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
                 "row_width": cfg.num_kv_heads * cfg.head_dim,
@@ -756,6 +1151,15 @@ class HybridLM:
             spec.update(num_heads=1, head_dim=width, row_width=width,
                         value_width=cfg.kv_rank, pools=["kv"],
                         page_layout="lanes")
+        if self.sparse:
+            wide = width + cfg.index_dim
+            spec.update(head_dim=wide, row_width=wide,
+                        index_rows=[width, wide],
+                        sparse={"layers": self.kinds.count("S"),
+                                "top_k": cfg.index_topk})
+        if "W" in self.kinds:
+            spec["rings"] = {"layers": self.kinds.count("W"),
+                             "window": cfg.window, "columns": cfg.ring}
         return spec
 
     def init_kv_pages(self, num_pages, page_size, slots=1):
@@ -855,7 +1259,10 @@ class HybridLM:
         overwritten by the next prefill into it.  With ``return_stats``
         the int32 sums over the ``E`` blocks of :attr:`decode_stats` come
         next: pairs computed on held experts, distinct held experts hit,
-        the largest load on one expert; with ``return_replay`` the experts
+        the largest load on one expert, then, summed over the rows with a
+        request, the tokens the ``S`` blocks' indexer scored and the tokens
+        their selection kept, and the ring columns the ``W`` blocks
+        attended (where the pattern has them); with ``return_replay`` the experts
         every row chose ``[E blocks, B, top_k]`` int32 and the produced
         tokens' log-probabilities ``[B]`` float32 come last."""
         B = token_ids.shape[0]
@@ -867,6 +1274,7 @@ class HybridLM:
         x = params["embed"][token_ids].astype(self.cfg.dtype)   # [B,D]
         nkv = dict(kv)
         stats, routed = [], []
+        counts = {"S": [], "W": []}
         for name, kind in zip(self.names, self.kinds):
             lp = params["layers"][name]
             if kind == "*":
@@ -899,24 +1307,24 @@ class HybridLM:
             elif kind == "L":
                 a = self.attn_index[name]
                 q, row = self._mla_absorb(x, lp, positions)
-                # a row is a COLUMN of its page (tokens lie on the lanes):
-                # the page is fetched, the column put in and the page put
-                # back whole, so nothing is scattered across a page's rows
-                # (a column scatter has the compiler turn the whole pool
-                # round, twice a step)
-                with jax.named_scope("mx.kv_write"):
-                    pool = nkv["kv"]
-                    at = jnp.minimum(page[:, 0], pool.shape[1] - 1)
-                    lane = jnp.arange(psz, dtype=jnp.int32)
-                    pages = jnp.where(
-                        lane[None, None, :] == slot[:, :, None],
-                        row.astype(pool.dtype)[:, :, None], pool[a, at])
-                    nkv["kv"] = pool.at[a, page[:, 0]].set(pages,
-                                                           mode="drop")
+                # a row is a COLUMN of its page (tokens lie on the lanes)
+                nkv["kv"] = self._write_latent_column(nkv["kv"], a, page,
+                                                      slot, row, psz)
                 ctx = _kernels.latent_paged_attention(
                     q, nkv["kv"], page_table, positions + 1,
                     self._mla_scale, self.cfg.kv_rank, layer=a)
                 x = x + self._mla_unabsorb(ctx, lp)
+            elif kind == "S":
+                out, nkv["kv"], got = self._dsa_step(
+                    x, lp, positions, nkv["kv"], self.attn_index[name], page,
+                    slot, page_table, psz)
+                counts["S"].append(got)
+                x = x + out
+            elif kind == "W":
+                out, nkv["ring" + name], got = self._swa_step(
+                    x, lp, positions, kv["ring" + name])
+                counts["W"].append(got)
+                x = x + out
             else:
                 with jax.named_scope("mx.moe"):
                     mix = self._moe if kind == "E" else self._gated_moe
@@ -930,10 +1338,39 @@ class HybridLM:
             out += (logits,)
         if return_stats:
             st = _sum_stats(stats)
-            out += (jnp.stack([st[n] for n in _STATS]),)
+            # the attention blocks' counts over the rows that hold a request
+            seen = [jnp.sum(jnp.where(active, sum(c), 0), axis=1)
+                    for c in counts.values() if c]
+            st = jnp.stack([st[n] for n in _STATS])
+            out += (jnp.concatenate([st] + seen) if seen else st,)
         if return_replay:
             out += (_stack_routed(routed, (B,)), _logprob(logits, ids))
         return out
+
+
+def _in_row_chunks(mix, x, lp, valid, row_bytes):
+    """A mixture of experts over token rows x [T, D] (``valid`` [T] or
+    None), where a token row's pairs make ``row_bytes`` of float32 products
+    (its experts x the widest product row: 2.5 GB a product at 16,384
+    tokens x 8 of a 5,120-wide model): whole where the T rows' products fit
+    in ``_MOE_PAIR_BYTES``, else a chunk of the most rows that fit, a power
+    of two, a step.  A chunked layer's counts are the chunks' (pairs
+    summed, the others their largest)."""
+    T, D = x.shape
+    if T * row_bytes <= _MOE_PAIR_BYTES:
+        return mix(x, lp, valid)
+    size = 1 << (_MOE_PAIR_BYTES // row_bytes).bit_length() - 1
+    n = -(-T // size)
+    live = jnp.arange(n * size) < T
+    if valid is not None:
+        live = live & jnp.pad(valid, (0, n * size - T))
+    rows = jnp.pad(x, ((0, n * size - T), (0, 0)))
+    out, st, chosen = lax.map(lambda a: mix(a[0], lp, a[1]), (
+        rows.reshape(n, size, D), live.reshape(n, size)))
+    st = {k: jnp.sum(v) if k == "pairs" else jnp.max(v)
+          for k, v in st.items()}
+    return out.reshape(-1, D)[:T], st, \
+        chosen.reshape((-1,) + chosen.shape[2:])[:T]
 
 
 def _logprob(logits, ids):

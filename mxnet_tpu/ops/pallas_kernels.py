@@ -791,10 +791,13 @@ def latent_tile_pages(psz, width, itemsize, table_width):
     return max(1, min(_LATENT_TILE_TOKENS // psz, fit, table_width))
 
 
-def _latent_attn_kernel(scale, tile, dv, lengths_ref, table_ref, q_ref,
-                        pool_hbm, o_ref, buf, sems):
+def _latent_attn_kernel(scale, tile, dv, sparse, lengths_ref, table_ref,
+                        q_ref, *refs):
     """One decode row of absorbed latent attention over its pages, read
-    where they lie.
+    where they lie; with ``sparse`` over the tokens ``chosen_ref`` ``[W,
+    psz]`` marks (nonzero) alone, the scores over the first ``q_ref``-width
+    rows of a page (the rows below them, index keys, are copied and not
+    read).
 
     ``pool_hbm`` is a pool of latent pages ``[P, width, psz]`` left in HBM
     (one layer's, or every layer's viewed as ``L*P`` pages with the ids
@@ -813,6 +816,8 @@ def _latent_attn_kernel(scale, tile, dv, lengths_ref, table_ref, q_ref,
     length 0 reads nothing and answers 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    chosen_ref, pool_hbm, o_ref, buf, sems = refs if sparse \
+        else (None,) + refs
     b = pl.program_id(0)
     _, width, psz = pool_hbm.shape
     W = table_ref.shape[0] // lengths_ref.shape[0]
@@ -853,11 +858,24 @@ def _latent_attn_kernel(scale, tile, dv, lengths_ref, table_ref, q_ref,
             each_page(t + 1, 1 - slot, lambda cp: cp.start())
 
         each_page(t, slot, lambda cp: cp.wait())
-        s = jnp.concatenate([jax.lax.dot_general(
-            q, buf[slot, j], (((1,), (0,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32) for j in range(tile)],
-            axis=1) * scale                                 # [H, tokens]
-        s = jnp.where(t * tokens + col < length, s, _NEG)
+        if chosen_ref is None:
+            s = jnp.concatenate([jax.lax.dot_general(
+                q, buf[slot, j], (((1,), (0,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32) for j in range(tile)],
+                axis=1) * scale                             # [H, tokens]
+            s = jnp.where(t * tokens + col < length, s, _NEG)
+        else:
+            kw = q.shape[1]
+            s = jnp.concatenate([jax.lax.dot_general(
+                q, buf[slot, j, :kw, :], (((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=jnp.float32)
+                for j in range(tile)], axis=1) * scale
+            # (a page past the table's width is masked by the length)
+            chosen = jnp.concatenate([chosen_ref[pl.ds(
+                jnp.minimum(t * tile + j, W - 1), 1), :]
+                for j in range(tile)], axis=1)              # [1, tokens]
+            s = jnp.where((t * tokens + col < length) & (chosen != 0), s,
+                          _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         e = jnp.exp(s - m_new)
@@ -913,7 +931,8 @@ def pallas_latent_paged_attention(q, pages, page_table, lengths, scale,
     if layer is not None:
         ids = ids + layer * P
     return pl.pallas_call(
-        functools.partial(_latent_attn_kernel, float(scale), tile, dv),
+        functools.partial(_latent_attn_kernel, float(scale), tile, dv,
+                          False),
         out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,),
@@ -926,6 +945,179 @@ def pallas_latent_paged_attention(q, pages, page_table, lengths, scale,
             dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(), name="mx_latent_paged_attention")(
             jnp.asarray(lengths, jnp.int32), ids.reshape(-1), q, pages)
+
+
+_INDEX_TILE_TOKENS = 2048
+
+
+def index_tile_pages(psz, rows, itemsize, table_width):
+    """Pages the index-score kernel copies per step: 2,048 tokens' index
+    keys where the double-buffered tile fits the ``kernels.vmem_budget``
+    knob, fewer where it does not or the table is narrower, at least one."""
+    from .. import config as _config
+    fit = int(_config.get("kernels.vmem_budget")) \
+        // (2 * psz * rows * itemsize)
+    return max(1, min(_INDEX_TILE_TOKENS // psz, fit, table_width))
+
+
+def _index_scores_kernel(tile, first, lengths_ref, table_ref, q_ref, w_ref,
+                         pool_hbm, o_ref, buf, sems):
+    """One decode row's index scores over its pages, read where they lie:
+    only the index-key rows ``[first, first + Di)`` of each page are copied
+    (``Di`` x ``psz``, tokens on the lanes), ``tile`` pages a step through a
+    double-buffered tile; a page's scores are ``sum_j w_j relu(q_j .
+    key)``: one product ``q [Hi, Di] . keys [Di, psz]``, the rectified
+    products weighted by ``w_ref`` ``[Hi, 1]`` (float32) and summed over
+    the heads.  Row w of ``o_ref`` ``[W, psz]`` is page w's scores; the
+    pages past a row's last read 0."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b = pl.program_id(0)
+    di, psz = buf.shape[2:]
+    W = table_ref.shape[0] // lengths_ref.shape[0]
+    n_pages = jnp.minimum((lengths_ref[b] + psz - 1) // psz, W)
+    n_tiles = (n_pages + tile - 1) // tile
+
+    def each_page(t, slot, act):
+        for j in range(tile):
+            i = t * tile + j
+
+            @pl.when(i < n_pages)
+            def _(i=i, j=j):
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[table_ref[b * W + i], pl.ds(first, di)],
+                    buf.at[slot, j], sems.at[slot]))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tiles > 0)
+    def _():
+        each_page(0, 0, lambda cp: cp.start())
+
+    q = q_ref[...].astype(buf.dtype)                        # [Hi, Di]
+    w = w_ref[...]                                          # [Hi, 1]
+    prec = _mxu_precision(q)
+
+    def body(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            each_page(t + 1, 1 - slot, lambda cp: cp.start())
+
+        each_page(t, slot, lambda cp: cp.wait())
+        for j in range(tile):
+            s = jax.lax.dot_general(
+                q, buf[slot, j], (((1,), (0,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32)         # [Hi, psz]
+            score = jnp.sum(jnp.maximum(s, 0.0) * w, axis=0, keepdims=True)
+
+            @pl.when(t * tile + j < n_pages)
+            def _(j=j, score=score):
+                o_ref[pl.ds(t * tile + j, 1), :] = score
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, body, 0)
+
+
+def pallas_index_scores(q, w, pages, page_table, lengths, first_row,
+                        layer=None):
+    """Decode-step index scores over the pages a page table names (an
+    indexer's keys held in rows ``[first_row, first_row + Di)`` of latent
+    pages whose tokens lie on the lanes): ``q`` ``[B, Hi, Di]`` the index
+    queries, ``w`` ``[B, Hi]`` float32 their weights, ``pages`` ``[P,
+    width, psz]`` (or with ``layer`` every layer's ``[L, P, width, psz]``
+    handed over whole), ``page_table`` ``[B, W]``, ``lengths`` ``[B]``.
+    Returns ``[B, W, psz]`` float32, ``sum_j w_j relu(q_j . k)`` of each
+    token in page-table order (0 in the pages past a row's last; what its
+    last page holds past the length is scored).  Routing/fallback
+    policy lives in ``mx.kernels.index_scores``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    q = jnp.asarray(q)
+    B, Hi, di = q.shape
+    P, width, psz = pages.shape[-3:]
+    W = page_table.shape[1]
+    if not 0 <= first_row <= width - di or w.shape != (B, Hi) \
+            or pages.ndim != (3 if layer is None else 4):
+        raise ValueError("index scores take q [B, Hi, Di], w [B, Hi] and "
+                         "pages [%sP, width, psz] holding the keys in rows "
+                         "[%d, %d), got %s, %s and %s"
+                         % ("" if layer is None else "L, ", first_row,
+                            first_row + di, q.shape, w.shape, pages.shape))
+    if layer is not None:
+        pages = pages.reshape((-1, width, psz))
+    tile = index_tile_pages(psz, di, pages.dtype.itemsize, W)
+    ids = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+    if layer is not None:
+        ids = ids + layer * P
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, tile, int(first_row)),
+        out_shape=jax.ShapeDtypeStruct((B, W, psz), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((None, Hi, di), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, Hi, 1), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, W, psz), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, tile, di, psz), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(), name="mx_index_scores")(
+            jnp.asarray(lengths, jnp.int32), ids.reshape(-1), q,
+            jnp.asarray(w, jnp.float32)[..., None], pages)
+
+
+def pallas_sparse_latent_attention(q, pages, page_table, lengths, chosen,
+                                   scale, value_width, layer=None):
+    """The absorbed latent decode kernel over the tokens a selection keeps:
+    :func:`pallas_latent_paged_attention`'s walk of a row's pages (whole
+    pages are copied, one tile of them a step), with every token that
+    ``chosen`` ``[B, W, psz]`` int32 does not mark (0) pinned to ``-1e30``
+    beside those past the length.  ``q`` ``[B, H, kw]`` scores the first
+    ``kw`` rows of a page (``kw`` <= its width: the rows below, an
+    indexer's keys, are copied and not read); values are its first
+    ``value_width`` rows.  Returns ``[B, H, value_width]`` in q's dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    q = jnp.asarray(q)
+    B, H, kw = q.shape
+    P, width, psz = pages.shape[-3:]
+    W = page_table.shape[1]
+    dv = int(value_width)
+    if not 0 < dv <= kw <= width or chosen.shape != (B, W, psz) \
+            or pages.ndim != (3 if layer is None else 4):
+        raise ValueError("sparse latent attention takes q [B, H, kw], pages "
+                         "[%sP, width >= kw, psz], chosen [B, W, psz] and 0 "
+                         "< value_width <= kw, got %s, %s, %s and %d"
+                         % ("" if layer is None else "L, ", q.shape,
+                            pages.shape, chosen.shape, dv))
+    if layer is not None:
+        pages = pages.reshape((-1, width, psz))
+    tile = latent_tile_pages(psz, width, pages.dtype.itemsize, W)
+    ids = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, P - 1)
+    if layer is not None:
+        ids = ids + layer * P
+    return pl.pallas_call(
+        functools.partial(_latent_attn_kernel, float(scale), tile, dv,
+                          True),
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, kw), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, W, psz), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, dv), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, tile, width, psz), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(), name="mx_sparse_latent_attention")(
+            jnp.asarray(lengths, jnp.int32), ids.reshape(-1), q,
+            jnp.asarray(chosen, jnp.int32), pages)
 
 
 # ------------------------------------------------------- grouped product

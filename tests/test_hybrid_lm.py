@@ -161,11 +161,16 @@ def test_router_and_held_experts_are_the_references(k):
         np.asarray(experts)[on_held]).max()
 
 
-def test_four_shares_of_an_expert_layer_add_up_to_the_whole():
-    """One ``E`` layer cut four ways (4 of 16 experts a share, the router
-    whole): the shares' routed parts, summed in the latent, through
-    ``w_up`` ONCE, plus the shared expert ONCE, equal the uncut
-    reference; and each share alone is the reference given that share."""
+@pytest.mark.parametrize("kind", ["E", "G"])
+def test_four_shares_of_an_expert_layer_add_up_to_the_whole(kind):
+    """One expert layer cut four ways (4 of 16 experts a share, the router
+    whole): the shares' routed parts — an ``E`` layer's summed in the
+    latent, through ``w_up`` ONCE; a ``G`` layer's at full width — plus
+    the shared expert ONCE, equal the uncut reference; and each share
+    alone is the reference given that share."""
+    if kind == "G":
+        _gated_shares_add_up()
+        return
     lp, x = _expert_layer(seed=3)
     model, _ = _tiny()
     h = hybrid._norm(x, lp["ln"], 1e-5)
@@ -186,6 +191,29 @@ def test_four_shares_of_an_expert_layer_add_up_to_the_whole():
     whole = dict(REF.PUBLISHED, top_k=3, route_scale=2.5, expert_offset=0)
     shared = jnp.square(jax.nn.relu(h @ lp["v1"])) @ lp["v2"]
     _close(latent @ lp["w_up"] + shared, REF._experts(x, lp, whole, None))
+
+
+def _gated_shares_add_up():
+    joy = manifest.load_module("reference", "joyai_flash_pp8")
+    _, params = _tiny(pattern="G", experts_held=16, expert_offset=0)
+    lp = params["layers"]["00"]
+    x = jax.random.normal(jax.random.PRNGKey(103), (9, 32))
+    h = hybrid._norm(x, lp["ln"], 1e-5)
+    shared = (jax.nn.silu(h @ lp["v_gate"]) * (h @ lp["v_up"])) \
+        @ lp["v_down"]
+    lm = dict(joy.PUBLISHED, top_k=3, route_scale=2.5, eps=1e-5)
+    routed = 0.0
+    for share in range(4):
+        lo = 4 * share
+        part = dict(lp, **{k: lp[k][lo:lo + 4]
+                           for k in ("w_gate", "w_up", "w_down")})
+        model, _ = _tiny(pattern="G", experts_held=4, expert_offset=lo)
+        y, _, _ = model._gated_moe(x, part)
+        routed = routed + (y - shared)
+        _close(y, joy._experts_routed(x, part, dict(lm, expert_offset=lo),
+                                      None, None)[0])
+    _close(routed + shared, joy._experts_routed(
+        x, lp, dict(lm, expert_offset=0), None, None)[0])
 
 
 def test_rows_without_a_request_route_to_no_expert():

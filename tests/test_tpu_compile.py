@@ -670,3 +670,100 @@ def test_latent_programs_at_the_cells_sizes_compile_into_the_chip(
         need.append(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
     assert max(need) < 15.5 * 2 ** 30, need     # 15.75 less what is reserved
     assert min(need) > 0.85 * 15.75 * 2 ** 30   # and the chip is filled
+
+
+# ------------------------------------ the sparse / windowed decoder's cell
+def test_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``benchmarks/configs/dots3_note_ep8.json`` as its driver exports it
+    — published widths, layers 0-4 (``SF`` + ``SG`` + ``WG`` x 3), 32 of
+    256 gated experts, 64 decode slots over 9,216 pages of 128 tokens (a
+    latent row and an index key a token) and a 640-column ring a slot for
+    each window layer — lowered for the TPU, reloaded and compiled for the
+    described chip: the decode program holds the sparse kernel at both
+    ``S`` layers and no gather of whole pages, the pool and the rings are
+    rewritten in place, and weights, cache and the temporaries of a decode
+    step and of the largest bucket's prefill (16,384: its query chunks)
+    fit one chip's 15.75 GiB.  (Weights are shapes only.)"""
+    import json
+    import os
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "dots3_note_ep8.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "offline_sparsedoc_s64.json")) as f:
+        traffic = json.load(f)
+    sz = cell["sizes"]
+    bucket = traffic["prompt_buckets"][-1]
+    model = HybridLM(HybridLMConfig(dtype=BF16, **sz["lm"]))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    with jax.default_matmul_precision(None):
+        deploy.export_generation(
+            model, shapes, prefix, sampling=True,
+            decode_batch=sz["decode_batch"], prompt_buckets=[bucket],
+            max_context=traffic["max_context"],
+            page_size=sz["page_tokens"], decode_widths=sz["decode_widths"],
+            include_params=False, replay=sz["replay"])
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    width = sz["decode_widths"][-1]
+    assert meta["decode_widths"] == [width] == [144]
+    assert meta["paged"] == {str(width): {
+        "impl": "sparse", "reason": None, "quantized": False}}
+    assert meta["kv"]["row_width"] == 704
+    spec = compile_for_chip.spec
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows, pages = sz["decode_batch"], cell["knobs"]["serving.kv_pages"]
+    cache = on_chip(deploy._kv_pool_specs(meta["kv"], pages, rows))
+    assert [c.shape for c in cache] == [(2, 9216, 704, 128)] \
+        + [(64, 1088, 640)] * 3
+
+    def sample(b):
+        return (spec((b,), F32), spec((b,), jnp.int32), spec((b,), F32),
+                spec((b, 2), jnp.uint32))
+
+    def compiled(path, *specs):
+        with open(path, "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return jax.jit(exp.call, donate_argnums=(1,)).lower(
+            on_chip(shapes), cache, *specs).compile()
+
+    decode = compiled("%s-decode-w%d.stablehlo" % (prefix, width),
+                      spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                      spec((rows, width), jnp.int32), *sample(rows))
+    text = decode.as_text()
+    assert text.count("mx_sparse_latent_attention") >= 2
+    assert text.count("mx_index_scores") >= 2
+    assert "mx.kv_gather" not in text
+    # the index keys are read where they lie: no copy of the pool turns it
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (
+        "[2,9216,704,128]" in ln or "[18432,704,128]" in ln)]
+    prefill = compiled(
+        "%s-prefill-s%d.stablehlo" % (prefix, bucket),
+        spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
+        spec((1, -(-bucket // sz["page_tokens"])), jnp.int32),
+        spec((1,), jnp.int32), *sample(1))
+    pool = "bf16[2,9216,704,128]"
+    for program in (text, prefill.as_text()):
+        assert pool + "{3,2,1,0" in program
+        assert not [ln for ln in program.splitlines()
+                    if pool in ln.split("=")[0] and " copy(" in ln]
+    cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
+    assert round(cache_bytes / 2 ** 30, 2) == 3.34
+    need = []
+    for program in (decode, prefill):
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes       # in place
+        need.append((mem.argument_size_in_bytes, mem.temp_size_in_bytes))
+    print("sizes_analysis", [(round(a / 2 ** 30, 3), round(t / 2 ** 30, 3))
+                             for a, t in need])
+    assert max(a + t for a, t in need) < 15.5 * 2 ** 30, need
+    assert min(a for a, _ in need) > 0.6 * 15.75 * 2 ** 30
