@@ -590,20 +590,24 @@ def export_generation(model, params, prefix, page_size=None,
     paged_routes = {}
     grouped_routes = {}
     retention_routes = {}
+    sparse_prefill_routes = {}
 
     def _export_one(fn, arg_specs, program, route_key=None):
         # the pool's page count stays symbolic inside the paged kernel too
         with _kernels.record_paged_routes() as routes, \
                 _kernels.record_grouped_routes() as grouped, \
                 _kernels.record_retention_routes() as retention, \
+                _kernels.record_sparse_prefill_routes() as sparse, \
                 _kernels.pallas_dynamic_shapes():
             exp = jexport.export(jax.jit(fn))(*arg_specs)
         # a program's grouped products (two an expert block) share their
         # shapes' verdict, as its retention updates (one an ``R`` block)
-        # do; one that fell back names the program
+        # and its sparse prefills (one an ``S`` block) do; one that fell
+        # back names the program
         for sites, kernel, by_program in (
                 (grouped, "grouped", grouped_routes),
-                (retention, "retention", retention_routes)):
+                (retention, "retention", retention_routes),
+                (sparse, "masked", sparse_prefill_routes)):
             if sites:
                 refused = [r for r in sites if r["impl"] != kernel]
                 by_program[program] = dict((refused or sites)[0],
@@ -727,6 +731,7 @@ def export_generation(model, params, prefix, page_size=None,
         "paged": paged_routes,
         "grouped": grouped_routes,
         "retention": retention_routes,
+        "sparse_prefill": sparse_prefill_routes,
     }
     if v5:
         meta["sampling"] = True
@@ -803,6 +808,9 @@ class GenerationPredictor:
         self.grouped_routes = dict(self.meta.get("grouped", {}))
         #: and for the retention updates (a decode program's alone)
         self.retention_routes = dict(self.meta.get("retention", {}))
+        #: and for the ``S`` blocks' attention (a prefill program's alone)
+        self.sparse_prefill_routes = dict(
+            self.meta.get("sparse_prefill", {}))
         self._v5 = self.format_version >= SAMPLING_FORMAT_VERSION
         #: the cache's state region (per-slot arrays beside the pages) and
         #: the names of the counts a decode step returns behind its tokens

@@ -1036,7 +1036,8 @@ class GenerationEngine:
         step = next(self._steps)
         program = "prefill-s%d" % s_bucket
         sp = _begin("engine.prefill", request_id=req.request_id,
-                    bucket=s_bucket, prompt_len=req.plen, step=step)
+                    bucket=s_bucket, prompt_len=req.plen, step=step,
+                    **self._sparse_prefill_args(program))
         st = _Step(step, "prefill", program, [(slot_idx, slot)], sp)
         w_s = _math.ceil(s_bucket / gp.page_size)
         sentinel = self.num_pages
@@ -1118,12 +1119,27 @@ class GenerationEngine:
         self._count_tokens(1)
         self._maybe_finish(slot_idx)
 
+    def _sparse_prefill_args(self, program):
+        """The ``engine.prefill`` arguments of a model with ``S`` blocks, by
+        the export-time verdict on the prefill program: ``sparse_layers``
+        (the blocks it has) and ``sparse_kernel_layers`` (how many of them
+        attend through the masked K/V-tiled kernel); none for another
+        model."""
+        route = self.predictor.sparse_prefill_routes.get(program)
+        if route is None:
+            return {}
+        return {"sparse_layers": route["sites"],
+                "sparse_kernel_layers": route["sites"]
+                if route.get("impl") == "masked" else 0}
+
     def _count_program_routes(self, program):
         """Serve-side mirror of the export-time verdict on a program's
         grouped products, once a dispatch: the Pallas kernel ran them, or
-        ``lax.ragged_dot`` did while the kernel tier was on; and on its
+        ``lax.ragged_dot`` did while the kernel tier was on; on its
         retention updates, once an ``R`` block a dispatch
-        (``kernels.retention_update`` / ``kernels.retention_fallback``)."""
+        (``kernels.retention_update`` / ``kernels.retention_fallback``);
+        and on a prefill's sparse attention, once an ``S`` block a dispatch
+        (``kernels.sparse_prefill`` / ``kernels.sparse_prefill_fallback``)."""
         route = self.predictor.grouped_routes.get(program)
         if route is not None:
             if route.get("impl") == "grouped":
@@ -1137,6 +1153,14 @@ class GenerationEngine:
                     route["sites"])
             elif _kernels_enabled():
                 _telemetry.counter("kernels.retention_fallback").inc(
+                    route["sites"])
+        route = self.predictor.sparse_prefill_routes.get(program)
+        if route is not None:
+            if route.get("impl") == "masked":
+                _telemetry.counter("kernels.sparse_prefill").inc(
+                    route["sites"])
+            elif _kernels_enabled():
+                _telemetry.counter("kernels.sparse_prefill_fallback").inc(
                     route["sites"])
 
     def _dispatch_decode(self, rows):

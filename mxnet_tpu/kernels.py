@@ -1,11 +1,11 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  The seven routed sites
+this module alone decides WHEN they run.  The eight routed sites
 (:func:`attention`, :func:`paged_attention`,
 :func:`latent_paged_attention`, :func:`sparse_latent_attention`,
-:func:`index_scores`, :func:`grouped_matmul`, :func:`retention_update`)
-ask one rule
+:func:`sparse_prefill_route`, :func:`index_scores`,
+:func:`grouped_matmul`, :func:`retention_update`) ask one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -18,18 +18,21 @@ time and nothing else — nothing is timed, persisted or remembered:
    there, which is how the parity tests run it;
 3. a shape the kernel cannot take (:func:`flash_unsupported_reason`,
    :func:`paged_unsupported_reason`, :func:`latent_unsupported_reason`,
-   :func:`sparse_unsupported_reason`, :func:`index_unsupported_reason`,
-   :func:`grouped_unsupported_reason`,
+   :func:`sparse_unsupported_reason`,
+   :func:`sparse_prefill_unsupported_reason`,
+   :func:`index_unsupported_reason`, :func:`grouped_unsupported_reason`,
    :func:`retention_unsupported_reason`) → the XLA lowering
    (``kernels.fallback`` / ``kernels.paged_fallback`` /
    ``kernels.latent_fallback`` / ``kernels.sparse_latent_fallback`` /
-   ``kernels.index_fallback`` / ``kernels.grouped_fallback`` /
-   ``kernels.retention_fallback``), never an error;
+   ``kernels.sparse_prefill_fallback`` / ``kernels.index_fallback`` /
+   ``kernels.grouped_fallback`` / ``kernels.retention_fallback``), never
+   an error;
 4. else the kernel (``kernels.flash_attention`` /
    ``kernels.paged_attention`` / ``kernels.latent_paged`` /
-   ``kernels.sparse_latent`` / ``kernels.index_scores`` /
-   ``kernels.grouped_matmul`` / ``kernels.retention_update``), at block
-   sizes that are constants or functions of the shapes.
+   ``kernels.sparse_latent`` / ``kernels.sparse_prefill`` /
+   ``kernels.index_scores`` / ``kernels.grouped_matmul`` /
+   ``kernels.retention_update``), at block sizes that are constants or
+   functions of the shapes.
 
 The decision is trace-time python, so a jitted program contains one
 path only; a knob change retraces (``config.epoch()`` in the program
@@ -53,17 +56,20 @@ from .ops.pallas_kernels import (flash_attention, flash_attention_tiled,
                                  pallas_index_scores,
                                  pallas_retention_update,
                                  pallas_sparse_latent_attention,
-                                 retention_row_tile)
+                                 retention_row_tile, _TILED_BLOCK)
 
 __all__ = ["enabled", "attention", "paged_attention",
            "latent_paged_attention", "sparse_latent_attention",
+           "sparse_prefill_route", "sparse_prefill_attention",
            "index_scores", "grouped_matmul", "retention_update",
            "flash_unsupported_reason", "tiled_unsupported_reason",
            "paged_unsupported_reason", "latent_unsupported_reason",
-           "sparse_unsupported_reason", "index_unsupported_reason",
+           "sparse_unsupported_reason", "sparse_prefill_unsupported_reason",
+           "index_unsupported_reason",
            "grouped_unsupported_reason", "retention_unsupported_reason",
            "record_paged_routes", "record_grouped_routes",
-           "record_retention_routes", "pallas_dynamic_shapes",
+           "record_retention_routes", "record_sparse_prefill_routes",
+           "pallas_dynamic_shapes",
            "flash_attention", "flash_attention_tiled",
            "pallas_paged_attention", "pallas_latent_paged_attention",
            "pallas_sparse_latent_attention", "pallas_index_scores",
@@ -302,13 +308,15 @@ def pallas_dynamic_shapes():
 
 # Export-time route capture: deploy.export_generation traces every
 # program under record_paged_routes() / record_grouped_routes() /
-# record_retention_routes() and lands the impl/reason of the routed
-# sites in the artifact meta — the serve path then counts
-# kernels.paged_attention / paged_fallback, kernels.grouped_matmul /
-# grouped_fallback and kernels.retention_update / retention_fallback per
-# dispatch without re-tracing (the program is AOT; trace-time counters
-# fire at export).
-_ROUTE_SINKS = {"paged": [], "grouped": [], "retention": []}
+# record_retention_routes() / record_sparse_prefill_routes() and lands
+# the impl/reason of the routed sites in the artifact meta — the serve
+# path then counts kernels.paged_attention / paged_fallback,
+# kernels.grouped_matmul / grouped_fallback, kernels.retention_update /
+# retention_fallback and kernels.sparse_prefill / sparse_prefill_fallback
+# per dispatch without re-tracing (the program is AOT; trace-time
+# counters fire at export).
+_ROUTE_SINKS = {"paged": [], "grouped": [], "retention": [],
+                "sparse_prefill": []}
 # (a latent or sparse site's route rides in the paged sink: a model keeps
 # one kind of page, so a decode program has one kind of paged site; the
 # index-score site records no route)
@@ -342,6 +350,13 @@ def record_retention_routes():
     "xla") for every retention-update route decision made while tracing
     under this context."""
     return _record_routes("retention")
+
+
+def record_sparse_prefill_routes():
+    """Collect ``{"impl", "reason"}`` dicts (``impl`` "masked" or "xla")
+    for every ``S`` block's prefill route decided while tracing under
+    this context (:func:`sparse_prefill_route`)."""
+    return _record_routes("sparse_prefill")
 
 
 def _note_route(site, **route):
@@ -700,6 +715,79 @@ def sparse_latent_attention(q, pages, page_table, lengths, chosen, scale,
     return _sparse_latent_attention_xla(q, pages, page_table, lengths,
                                         chosen, scale, value_width,
                                         layer=layer)
+
+
+# ------------------------------------------------ sparse prefill attention
+def sparse_prefill_unsupported_reason(q, k, v, mask):
+    """Why the masked K/V-tiled flash kernel can NOT take an ``S`` block's
+    prefill, or None if it can: q, k ``[B, H, S, Dqk]`` and v ``[B, H, S,
+    Dv]`` of one float dtype, an int8 selection ``[B, S, S]``, concrete
+    shapes, heads no wider than ``_MAX_HEAD_DIM``, and a length the int8
+    tile cuts into blocks (one whole block, or a multiple of 128).
+    Arrays or shapes (``jax.ShapeDtypeStruct``): the caller asks before it
+    builds them.  A non-None reason routes to the XLA twin
+    (``kernels.sparse_prefill_fallback``)."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4 or mask.ndim != 3:
+        return "rank: q%s k%s v%s mask%s, want 4, 4, 4 and 3" % (
+            q.ndim, k.ndim, v.ndim, mask.ndim)
+    dims = tuple(q.shape) + tuple(k.shape) + tuple(v.shape) \
+        + tuple(mask.shape)
+    if not all(isinstance(d, int) for d in dims):
+        return "symbolic shape (q%s mask%s)" % (q.shape, mask.shape)
+    B, H, S, D = q.shape
+    if tuple(k.shape) != (B, H, S, D) or tuple(v.shape[:3]) != (B, H, S) \
+            or tuple(mask.shape) != (B, S, S):
+        return "q%s k%s v%s mask%s are not [B,H,S,Dqk] twice, [B,H,S,Dv] " \
+            "and [B,S,S]" % (tuple(q.shape), tuple(k.shape),
+                             tuple(v.shape), tuple(mask.shape))
+    if not (q.dtype == k.dtype == v.dtype) \
+            or q.dtype not in (jnp.float32, jnp.bfloat16):
+        return "operands %s/%s/%s, want all float32 or all bfloat16" % (
+            q.dtype, k.dtype, v.dtype)
+    if mask.dtype != jnp.int8:
+        return "the selection is %s, want int8" % mask.dtype
+    if max(D, v.shape[3]) > _MAX_HEAD_DIM:
+        return "head dim %d > %d" % (max(D, v.shape[3]), _MAX_HEAD_DIM)
+    if S > _TILED_BLOCK and S % 128:
+        return "length %d over one block of %d is no multiple of 128" % (
+            S, _TILED_BLOCK)
+    return None
+
+
+def sparse_prefill_route(q, k, v, mask):
+    """How an ``S`` block's prefill attends over what its indexer selected,
+    by the module's rule: None where the masked K/V-tiled flash kernel
+    takes it (:func:`sparse_prefill_attention`: one pass over the EXPANDED
+    form, the selection as a mask; counter ``kernels.sparse_prefill``),
+    else why the XLA twin does — the caller's own gather of each query's
+    selected latent rows, attended in the absorbed form a chunk of queries
+    at a time: tier off, the default knob on an interpreted backend
+    (``kernels.gated_fallback``), or a shape the kernel cannot take
+    (:func:`sparse_prefill_unsupported_reason`;
+    ``kernels.sparse_prefill_fallback``).  The caller asks before it
+    builds either route's operands, so ``q``, ``k``, ``v`` and ``mask``
+    may be shapes.  The decision lands, as ``impl`` "masked" or "xla", in
+    the export route sink of :func:`record_sparse_prefill_routes`."""
+    reason = _route_reason(
+        lambda: sparse_prefill_unsupported_reason(q, k, v, mask),
+        _telemetry.counter("kernels.sparse_prefill_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.sparse_prefill").inc()
+    _note_route("sparse_prefill", impl="xla" if reason else "masked",
+                reason=reason)
+    return reason
+
+
+def sparse_prefill_attention(q, k, v, mask, scale):
+    """Causal attention of a whole prompt over the pairs ``mask`` holds
+    at non-zero, on the route :func:`sparse_prefill_route` gave the kernel:
+    ``ops.pallas_kernels.flash_attention_tiled`` with the selection
+    (``mx_attention_tiled_masked``; K/V blocks above the diagonal skipped
+    and never fetched).  q, k ``[B, H, S, Dqk]``, v ``[B, H, S, Dv]``,
+    mask int8 ``[B, S, S]`` -> ``[B, H, S, Dv]``.  No scope of its own:
+    the caller's (``mx.sparse_attention``) names its device time."""
+    return flash_attention_tiled(q, k, v, causal=True, scale=scale,
+                                 mask=mask)
 
 
 def index_unsupported_reason(q, w, pages, page_table, lengths, first_row,

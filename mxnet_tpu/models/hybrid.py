@@ -36,9 +36,11 @@ own):
          every earlier token, ``I_ts = sum_j w_tj relu(q^I_tj . k^I_s)``
          (``index_heads`` queries from the query latent, one LayerNorm'd
          key a token, rotary on ``rope_dim`` of their dims), and each
-         query attends, in the absorbed form, over its ``index_topk``
-         highest-scoring tokens only; a token's index key rides in its
-         page below its latent row
+         query attends over its ``index_topk`` highest-scoring tokens
+         only — a decode step in the absorbed form over the pages, a whole
+         prompt in the expanded form, one flash pass masked to the
+         selection (``kernels.sparse_prefill_route``); a token's index
+         key rides in its page below its latent row
   ``W``  ``L`` over a sliding WINDOW of the ``window`` latest positions,
          with sizes of its own (``swa_*``) and no page: each decode slot
          keeps the window's latent rows in a ring of ``ring`` columns
@@ -451,27 +453,34 @@ class HybridLM:
         each head's own, one causal flash pass at query/key width ``dn +
         dr`` and value width ``dv``.  x [B,S,D] -> (out [B,S,D], the rows
         to keep [B,S,Rkv+dr])."""
-        cfg = self.cfg
         B, S, _ = x.shape
-        f32 = jnp.float32
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         with jax.named_scope("mx.mla_proj"):
             q_nope, q_rope, c, k_rope = self._mla_parts(x, lp, positions)
-            k_nope = jnp.einsum("bsr,rhe->bhse", c, lp["w_uk"],
-                                preferred_element_type=f32).astype(x.dtype)
-            v = jnp.einsum("bsr,rhe->bhse", c, lp["w_uv"],
-                           preferred_element_type=f32).astype(x.dtype)
-            q = jnp.transpose(jnp.concatenate([q_nope, q_rope], axis=-1),
-                              (0, 2, 1, 3))
-            k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                k_rope[:, None], k_nope.shape[:3] + k_rope.shape[-1:])],
-                axis=-1)
+            q, k, v = self._mla_expand(q_nope, q_rope, c, k_rope, lp)
         with jax.named_scope("mx.attention"):
             o = _kernels.attention(q, k, v, causal=True,
                                    scale=self._mla_scale)
         with jax.named_scope("mx.mla_proj"):
             out = self._mla_out(jnp.transpose(o, (0, 2, 1, 3)), lp)
         return out, jnp.concatenate([c, k_rope], axis=-1)
+
+    def _mla_expand(self, q_nope, q_rope, c, k_rope, lp):
+        """The EXPANDED form's operands from :meth:`_mla_parts`' outputs:
+        q, k ``[B,H,S,dn+dr]`` (each head's key up-projected from the
+        latent, the shared rotary key beside it) and v ``[B,H,S,dv]``, in
+        the queries' dtype."""
+        f32, dt = jnp.float32, q_nope.dtype
+        k_nope = jnp.einsum("bsr,rhe->bhse", c, lp["w_uk"],
+                            preferred_element_type=f32).astype(dt)
+        v = jnp.einsum("bsr,rhe->bhse", c, lp["w_uv"],
+                       preferred_element_type=f32).astype(dt)
+        q = jnp.transpose(jnp.concatenate([q_nope, q_rope], axis=-1),
+                          (0, 2, 1, 3))
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, None], k_nope.shape[:3] + k_rope.shape[-1:])],
+            axis=-1)
+        return q, k, v
 
     def _mla_absorb(self, x, lp, positions):
         """One token a row, the ABSORBED form's first half: x [B,D],
@@ -553,18 +562,23 @@ class HybridLM:
     def _dsa_sequence(self, x, lp):
         """``S`` over whole prompts: x [B,S,D] -> (out [B,S,D], the rows to
         keep [B,S,Rkv+dr+Di]: latent, rotary key, index key).  The indexer
-        scores every key at or before each query, each query keeps the
-        ``index_topk`` best (all of them while there are fewer) and attends
-        over those alone in the ABSORBED form (128 heads share one latent
-        row, so the selected rows are gathered once for all heads), in
-        chunks of :data:`_DSA_QUERY_CHUNK` queries: a chunk's index scores
-        and its gathered rows are what a step holds.  The chunks run in
-        :data:`_DSA_SEGMENTS` causal segments, a segment's against the keys
-        before its end only (5/8 of the whole prompt's on average)."""
+        scores every key at or before each query and each query keeps the
+        ``index_topk`` best (all of them while there are fewer), in chunks
+        of :data:`_DSA_QUERY_CHUNK` queries (a chunk's index scores are
+        what a step holds) run in :data:`_DSA_SEGMENTS` causal segments, a
+        segment's against the keys before its end only (5/8 of the whole
+        prompt's on average).  How the queries then attend over what they
+        kept is ``kernels.sparse_prefill_route``'s to say: on the kernel's
+        route each chunk emits its selection as an int8 mask over key
+        positions, and one causal flash pass over the EXPANDED form
+        (:meth:`_mla_expand`) attends where the mask holds; on the XLA
+        twin's each chunk gathers its queries' selected rows and attends
+        over them in the ABSORBED form (128 heads share one latent row, so
+        the rows are gathered once for all heads)."""
         cfg = self.cfg
         B, S, _ = x.shape
         f32, dt = jnp.float32, x.dtype
-        Rkv = cfg.kv_rank
+        H, _, Rkv, dn, dr, dv, _ = self._latent_sizes(False)
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         with jax.named_scope("mx.mla_proj"):
             q_nope, q_rope, c, k_rope, n, cq = self._mla_parts(
@@ -572,12 +586,18 @@ class HybridLM:
         with jax.named_scope("mx.dsa_indexer"):
             qi, ki, wi = self._index_parts(n, cq, lp, positions)
         keys = jnp.concatenate([c, k_rope], axis=-1)          # [B,S,Rkv+dr]
+        wide = functools.partial(jax.ShapeDtypeStruct, dtype=dt)
+        masked = _kernels.sparse_prefill_route(
+            wide((B, H, S, dn + dr)), wide((B, H, S, dn + dr)),
+            wide((B, H, S, dv)),
+            jax.ShapeDtypeStruct((B, S, S), jnp.int8)) is None
         Q = min(_DSA_QUERY_CHUNK, S)
         steps = -(-S // Q)
         pad = ((0, 0), (0, steps * Q - S))
-        q_nope, q_rope, qi, wi = (
-            jnp.pad(a, pad + ((0, 0),) * (a.ndim - 2))
-            for a in (q_nope, q_rope, qi, wi))
+        # (the twin's queries too: it attends a chunk at a time)
+        qi, wi, *q_abs = (jnp.pad(a, pad + ((0, 0),) * (a.ndim - 2))
+                          for a in (qi, wi) + (() if masked
+                                               else (q_nope, q_rope)))
 
         def chunk(span, i):
             # queries [i Q, (i + 1) Q) against the keys before ``span``
@@ -593,13 +613,15 @@ class HybridLM:
             with jax.named_scope("mx.dsa_select"):
                 score = jnp.where(jnp.arange(span)[None, None, :]
                                   <= t[None, :, None], score, -jnp.inf)
-                _, idx = lax.top_k(score, min(cfg.index_topk, span))
+                top, idx = lax.top_k(score, min(cfg.index_topk, span))
+                if masked:
+                    return _selection_mask(score, top, idx, t, S)
                 kept = idx <= t[None, :, None]                # [B,Q,K]
             with jax.named_scope("mx.sparse_attention"):
                 rows = jax.vmap(lambda k, j: k[j])(keys[:, :span], idx)
                 q = jnp.concatenate([jnp.einsum(
-                    "bqhe,rhe->bqhr", part(q_nope), lp["w_uk"],
-                    preferred_element_type=f32).astype(dt), part(q_rope)],
+                    "bqhe,rhe->bqhr", part(q_abs[0]), lp["w_uk"],
+                    preferred_element_type=f32).astype(dt), part(q_abs[1])],
                     axis=-1)
                 s = jnp.einsum("bqhc,bqkc->bqhk", q, rows,
                                preferred_element_type=f32) * self._mla_scale
@@ -619,8 +641,15 @@ class HybridLM:
             lax.map(functools.partial(chunk, min(S, (c0 + per) * Q)),
                     jnp.arange(c0, min(c0 + per, steps), dtype=jnp.int32))
             for c0 in range(0, steps, per)])
-        o = jnp.moveaxis(o, 0, 1).reshape((B, steps * Q) + o.shape[-2:])
-        o = self._gate(o[:, :S], n, lp)
+        # the heads' outputs [B,S,H,dv], or the selection [B,S,S]
+        o = jnp.moveaxis(o, 0, 1).reshape((B, steps * Q) + o.shape[3:])[:, :S]
+        if masked:
+            with jax.named_scope("mx.mla_proj"):
+                q, k, v = self._mla_expand(q_nope, q_rope, c, k_rope, lp)
+            with jax.named_scope("mx.sparse_attention"):
+                o = jnp.transpose(_kernels.sparse_prefill_attention(
+                    q, k, v, o, self._mla_scale), (0, 2, 1, 3))
+        o = self._gate(o, n, lp)
         with jax.named_scope("mx.mla_proj"):
             out = self._mla_out(o, lp)
         return out, jnp.concatenate([keys, ki], axis=-1)
@@ -1346,6 +1375,23 @@ class HybridLM:
         if return_replay:
             out += (_stack_routed(routed, (B,)), _logprob(logits, ids))
         return out
+
+
+def _selection_mask(score, top, idx, t, length):
+    """The set ``lax.top_k`` kept, as an int8 mask over key positions, with
+    no scatter: score [B,Q,span] (-inf past each query's position t [Q]),
+    top / idx [B,Q,K] its top-k values and positions -> [B,Q,length],
+    zeros past ``span``.  A key is kept where it scores above the k-th
+    value, or equals it at or before the last tied position the top-k kept
+    (its ties go to the lower position), and lies at or before t."""
+    span = score.shape[-1]
+    kth = top[..., -1:]
+    last = jnp.max(jnp.where(top == kth, idx, -1), axis=-1, keepdims=True)
+    p = jnp.arange(span, dtype=jnp.int32)
+    keep = ((score > kth) | ((score == kth) & (p <= last))) \
+        & (p[None, :] <= t[:, None])
+    return jnp.pad(keep.astype(jnp.int8),
+                   ((0, 0), (0, 0), (0, length - span)))
 
 
 def _in_row_chunks(mix, x, lp, valid, row_bytes):

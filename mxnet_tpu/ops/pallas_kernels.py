@@ -422,8 +422,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None, block_q=128,
 _TILED_BLOCK = 512           # q rows and K/V rows a step: four MXU passes
 
 
-def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref):
+def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs):
     """One q block against one K/V block of its (batch, head) slice; the
     K/V blocks are the grid's last axis and an online softmax (``m``,
     ``l``, ``acc`` in float32 scratch) carries across them, so neither the
@@ -431,8 +430,14 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, o_ref,
     may be narrower or wider than the query/key rows.  Under ``causal`` a
     K/V block wholly above the diagonal is skipped (its block index is
     clamped to the last one the q block needs, so nothing is fetched for
-    it either)."""
+    it either).  With a selection (``refs`` five long: an int8 ``[bq,
+    bk]`` mask block first) a pair the mask leaves at 0 is masked beside
+    those above the diagonal; a row none of whose pairs so far is kept
+    carries ``m`` at the floor, and its first kept pair's ``alpha`` of 0
+    clears what it summed."""
     from jax.experimental import pallas as pl
+    mask_ref = refs[0] if len(refs) == 5 else None
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -446,10 +451,16 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, o_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), precision=_mxu_precision(q, k),
             preferred_element_type=jnp.float32) * scale       # [bq, bk]
+        keep = None
         if causal:
             q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG)
+            keep = k_pos <= q_pos
+        if mask_ref is not None:
+            chosen = mask_ref[0] != 0
+            keep = chosen if keep is None else keep & chosen
+        if keep is not None:
+            s = jnp.where(keep, s, _NEG)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -471,7 +482,7 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_attention_tiled(q, k, v, causal=False, scale=None,
-                          block=_TILED_BLOCK):
+                          block=_TILED_BLOCK, mask=None):
     """Flash attention FORWARD with the keys and values tiled as well as
     the queries: q, k ``[B, H, S, Dqk]``, v ``[B, H, Skv, Dv]`` (``Dv``
     need not be ``Dqk``) -> ``[B, H, S, Dv]``.  The grid walks (batch x
@@ -481,40 +492,60 @@ def flash_attention_tiled(q, k, v, causal=False, scale=None,
     :func:`flash_attention` keeps a head's whole K/V resident.  A causal
     call computes the blocks on and under the diagonal only.  No backward
     of its own: ``mx.kernels.attention`` routes here and differentiates
-    through the XLA lowering."""
+    through the XLA lowering.
+
+    ``mask`` (int8 ``[B, S, Skv]``, every head's): only the pairs it holds
+    at non-zero are attended (named ``mx_attention_tiled_masked``; the
+    query blocks a multiple of 32 rows and the K/V blocks of 128, the int8
+    tile, or whole).  Every row must keep at least one pair: a row that
+    keeps none reads the mean of the values its blocks visited."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ..rtc import interpret_mode
     B, H, S, D = q.shape
     Skv, Dv = v.shape[2], v.shape[3]
     if k.shape != (B, H, Skv, D) or v.shape[:2] != (B, H) \
-            or (causal and Skv != S):
+            or (causal and Skv != S) \
+            or (mask is not None and mask.shape != (B, S, Skv)):
         raise ValueError("tiled flash attention takes q [B,H,S,D], k "
-                         "[B,H,Skv,D], v [B,H,Skv,Dv] (Skv == S if causal),"
-                         " got %s, %s, %s" % (q.shape, k.shape, v.shape))
+                         "[B,H,Skv,D], v [B,H,Skv,Dv] (Skv == S if causal)"
+                         " and a mask [B,S,Skv], got %s, %s, %s, %s"
+                         % (q.shape, k.shape, v.shape,
+                            None if mask is None else mask.shape))
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
-    bq = _row_block(S, 1, budget=min(block, S))
-    bk = _row_block(Skv, 1, budget=min(block, Skv))
+    if mask is None:
+        bq = _row_block(S, 1, budget=min(block, S))
+        bk = _row_block(Skv, 1, budget=min(block, Skv))
+    else:
+        bq = _row_block(S, 1, budget=min(block, S), align=4 * _SUBLANES)
+        bk = _row_block(Skv, 1, budget=min(block, Skv), align=_LANES)
     # (under the diagonal's clamp a skipped step names the block before it)
     last = (lambda i: (i * bq + bq - 1) // bk) if causal \
         else (lambda i: Skv // bk - 1)
     kv_at = lambda b, i, j: (b, jnp.minimum(j, last(i)), 0)   # noqa: E731
+    in_specs = [pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, D), kv_at),
+                pl.BlockSpec((1, bk, Dv), kv_at)]
+    operands = (q.reshape(B * H, S, D), k.reshape(B * H, Skv, D),
+                v.reshape(B * H, Skv, Dv))
+    if mask is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, bq, bk), lambda b, i, j: (b // H, i, jnp.minimum(j, last(i)))))
+        operands += (mask,)
     out = pl.pallas_call(
         functools.partial(_flash_tiled_kernel, scale, bool(causal), bq, bk),
         out_shape=jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
         grid=(B * H, S // bq, Skv // bk),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-                  pl.BlockSpec((1, bk, D), kv_at),
-                  pl.BlockSpec((1, bk, Dv), kv_at)],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(), name="mx_attention_tiled")(
-            q.reshape(B * H, S, D), k.reshape(B * H, Skv, D),
-            v.reshape(B * H, Skv, Dv))
+        interpret=interpret_mode(),
+        name="mx_attention_tiled" if mask is None
+        else "mx_attention_tiled_masked")(*operands)
     return out.reshape(B, H, S, Dv)
 
 

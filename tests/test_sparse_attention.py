@@ -160,16 +160,31 @@ def test_a_decode_step_keeps_the_references_top_k():
     assert (seen.sum(1) == np.minimum(np.arange(24) + 1, 8)).all()
 
 
-@pytest.mark.parametrize("length", [40, 300])
-def test_apply_is_the_reference_forward(length):
+@pytest.mark.parametrize("length,route", [
+    pytest.param(40, "xla", id="40"), pytest.param(300, "xla", id="300"),
+    pytest.param(40, "masked", id="40-masked"),
+    pytest.param(300, "masked", id="300-masked"),
+    pytest.param(640, "masked", id="640-masked")])
+def test_apply_is_the_reference_forward(length, route):
     """The cache-free forward at 40 positions (one chunk of queries) and at
     300 (five chunks of 64 in three causal segments, each reading the keys
-    before its end) is the reference's, float32 to 1e-6."""
-    model, params = _tiny(max_len=512)
+    before its end) is the reference's, float32 to 1e-6 — on the XLA twin
+    (the default knob on this interpreted backend: each query's selected
+    rows gathered) and on the masked K/V-tiled kernel (the tier on
+    explicitly: one pass over the expanded form, at 640 positions in 320 x
+    128 blocks)."""
+    model, params = _tiny(max_len=1024)
     toks = jnp.asarray(np.random.default_rng(10).integers(0, 96, (length,)),
                        jnp.int32)
-    _close(model.apply(params, toks[None])[0],
-           REF.logits(params, toks, lm=REF_LM))
+    if route == "masked":
+        mx.config.set("kernels.enabled", True)
+    try:
+        with kernels.record_sparse_prefill_routes() as routes:
+            got = model.apply(params, toks[None])[0]
+    finally:
+        mx.config.unset("kernels.enabled")
+    assert [r["impl"] for r in routes] == [route] * 2
+    _close(got, REF.logits(params, toks, lm=REF_LM))
 
 
 @pytest.mark.parametrize("window,prompt,steps", [(9, 150, 6), (513, 700, 4)],
@@ -289,6 +304,106 @@ def test_the_sparse_route_says_why_it_refuses(change, says):
     assert reason is not None and says in reason, reason
 
 
+def _selections(kind, rng, B, S, K):
+    """int8 [B, S, S] masks, causal: ``random`` K of each query's earlier
+    positions (all of them while there are fewer), ``diagonal`` only the
+    query's own position, ``causal`` every position up to it."""
+    t = np.arange(S)
+    if kind == "diagonal":
+        mask = np.broadcast_to(np.eye(S, dtype=np.int8), (B, S, S))
+    elif kind == "causal":
+        mask = np.broadcast_to((t[None, :] <= t[:, None]).astype(np.int8),
+                               (B, S, S))
+    else:
+        score = np.where(t[None, None, :] <= t[None, :, None],
+                         rng.uniform(size=(B, S, S)), -1.0)
+        kth = -np.sort(-score, axis=-1)[..., K - 1:K]
+        mask = ((score >= kth) & (score >= 0)).astype(np.int8)
+    return jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("block", [128, 256])
+@pytest.mark.parametrize("kind", ["random", "diagonal", "causal"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)])
+def test_the_masked_tiled_kernel_is_a_dense_masked_softmax(dtype, tol, kind,
+                                                           block):
+    """The K/V-tiled flash kernel handed a selection (interpreted) against a
+    dense softmax over the same masked causal scores, at query/key width
+    192 and value width 128 (an ``S`` block's expanded heads), 384 positions
+    in 3 x 3 blocks of 128, or 2 x 3 of 192 x 128 where a block of 256
+    divides neither axis: selections of 24 earlier positions a query, of
+    the diagonal alone, of every earlier position."""
+    rng = np.random.default_rng(11)
+    B, H, S = 2, 2, 384
+    q, k = (jnp.asarray(rng.normal(size=(B, H, S, 192)), dtype)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(B, H, S, 128)), dtype)
+    mask = _selections(kind, rng, B, S, 24)
+    assert kernels.sparse_prefill_unsupported_reason(q, k, v, mask) is None
+    got = pk.flash_attention_tiled(q, k, v, causal=True, scale=0.1,
+                                   block=block, mask=mask)
+    f32 = [np.asarray(a, np.float32) for a in (q, k, v)]
+    s = np.einsum("bhqd,bhkd->bhqk", f32[0], f32[1]) * 0.1
+    s = np.where(np.asarray(mask)[:, None] != 0, s, -np.inf)
+    e = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", e / e.sum(-1, keepdims=True), f32[2])
+    assert got.shape == (B, H, S, 128) and got.dtype == dtype
+    _close(got, want, tol)
+
+
+@pytest.mark.parametrize("scores", ["tied", "random", "tied_at_the_edge"])
+def test_the_selection_mask_is_the_set_top_k_kept(scores):
+    """``_selection_mask`` (no scatter: above the k-th value, or equal to it
+    no later than the last tie the top-k kept) against the positions
+    ``lax.top_k`` returns, scattered: equal scores everywhere (the earliest
+    positions must win), random scores, and scores tied across the k-th
+    place; -inf past each query's position, zeros past the span."""
+    from mxnet_tpu.models.hybrid import _selection_mask
+    rng = np.random.default_rng(12)
+    B, Q, span, K, length = 2, 16, 40, 8, 48
+    t = jnp.asarray(np.arange(24, 24 + Q), jnp.int32)
+    if scores == "tied":
+        score = np.zeros((B, Q, span), np.float32)
+    elif scores == "random":
+        score = rng.normal(size=(B, Q, span)).astype(np.float32)
+    else:
+        score = rng.integers(0, 4, (B, Q, span)).astype(np.float32)
+    score = jnp.where(jnp.arange(span)[None, None, :] <= t[None, :, None],
+                      jnp.asarray(score), -jnp.inf)
+    top, idx = jax.lax.top_k(score, K)
+    got = np.asarray(_selection_mask(score, top, idx, t, length))
+    want = np.zeros((B, Q, length), np.int8)
+    np.put_along_axis(want, np.asarray(idx), 1, axis=-1)
+    assert got.dtype == np.int8 and (got == want).all()
+    assert (got.sum(-1) == K).all()
+    if scores == "tied":
+        assert (np.flatnonzero(got[0, 0]) == np.arange(K)).all()
+
+
+@pytest.mark.parametrize("change,says", [
+    (dict(S=600), "multiple of 128"),
+    (dict(mask_dtype=jnp.int32), "int8"),
+    (dict(mask_S=64), "[B,S,S]"),
+    (dict(v_dtype=jnp.float32), "bfloat16"),
+    (dict(D=576), "head dim"),
+    (dict(rank=3), "rank")])
+def test_the_sparse_prefill_route_says_why_it_refuses(change, says):
+    """A shape the masked kernel cannot take routes an ``S`` block's prefill
+    to the XLA twin with its reason, never an error; the shapes the cell
+    runs (12,288 and 16,384 positions, 128 heads of 192 / 128) qualify."""
+    def shapes(S=384, D=192, v_dtype=jnp.bfloat16, mask_dtype=jnp.int8,
+               mask_S=None, rank=4):
+        sds = jax.ShapeDtypeStruct
+        q = sds((1, 128, S, D)[4 - rank:], jnp.bfloat16)
+        return (q, q, sds((1, 128, S, 128), v_dtype),
+                sds((1, S, mask_S or S), mask_dtype))
+    for S in (12288, 16384):
+        assert kernels.sparse_prefill_unsupported_reason(*shapes(S)) is None
+    reason = kernels.sparse_prefill_unsupported_reason(*shapes(**change))
+    assert reason is not None and says in reason, reason
+
+
 def test_the_sparse_site_counts_and_records_its_route():
     mx.config.set("kernels.enabled", True)
     try:
@@ -305,6 +420,17 @@ def test_the_sparse_site_counts_and_records_its_route():
                 q, jnp.ones((3, 64, 4), jnp.float32), table, lengths,
                 jnp.ones((2, 2, 4), jnp.int32), 0.1, 32)
         assert [r["impl"] for r in routes] == ["sparse", "xla"]
+        sds = jax.ShapeDtypeStruct
+        before.update({n: telemetry.counter("kernels." + n).value
+                       for n in ("sparse_prefill", "sparse_prefill_fallback")})
+        with kernels.record_sparse_prefill_routes() as routes:
+            for S in (512, 600):
+                q = sds((1, 4, S, 192), jnp.bfloat16)
+                kernels.sparse_prefill_route(
+                    q, q, sds((1, 4, S, 128), jnp.bfloat16),
+                    sds((1, S, S), jnp.int8))
+        assert [r["impl"] for r in routes] == ["masked", "xla"]
+        assert routes[0]["reason"] is None and "128" in routes[1]["reason"]
         for name in before:
             assert telemetry.counter("kernels." + name).value \
                 == before[name] + 1
@@ -398,6 +524,10 @@ def test_a_sparse_stack_serves_the_oracles_tokens(served):
     assert meta["kv"]["rings"]["columns"] == 128
     width = str(meta["decode_widths"][-1])
     assert meta["paged"][width]["impl"] == "xla"
+    # both S blocks of both prefill programs attend through the kernel
+    assert meta["sparse_prefill"] == {
+        "prefill-s%d" % b: {"impl": "masked", "reason": None, "sites": 2}
+        for b in (16, 32)}
     assert [tuple(a.shape) for a in engine._kv] == [(2, 30, 28, PAGE),
                                                     (2, 28, 128)]
     rng = np.random.default_rng(1)
@@ -406,11 +536,18 @@ def test_a_sparse_stack_serves_the_oracles_tokens(served):
     oracle = [model.greedy_decode(params, p, 9) for p in prompts]
     fell_back = telemetry.counter("kernels.sparse_latent_fallback").value
     latent = telemetry.counter("kernels.latent_fallback").value
+    prefills = {n: telemetry.counter("kernels." + n).value
+                for n in ("sparse_prefill", "sparse_prefill_fallback")}
     futures = [srv.submit_generate("lm", p, 9) for p in prompts]
     for want, f in zip(oracle, futures):
         assert (f.result(timeout=300) == want).all()
     assert telemetry.counter("kernels.sparse_latent_fallback").value \
         > fell_back
+    # once an S block a prefill dispatch, from the export's verdict
+    assert telemetry.counter("kernels.sparse_prefill").value \
+        == prefills["sparse_prefill"] + 2 * len(prompts)
+    assert telemetry.counter("kernels.sparse_prefill_fallback").value \
+        == prefills["sparse_prefill_fallback"]
     assert telemetry.counter("kernels.latent_fallback").value == latent
     assert engine.stats()["kv_pages_free"] == 30
 
@@ -439,8 +576,12 @@ def test_decode_spans_say_what_the_selection_and_the_rings_read(served):
         def __exit__(self, *exc):
             return self.sp.__exit__(*exc)
 
+    prefills = []
+
     def spying(name, **args):
         sp = begin(name, **args)
+        if name == "engine.prefill":
+            prefills.append(args)
         if name != "engine.decode":
             return sp
         seen.append(Recorded(sp, args))
@@ -452,6 +593,9 @@ def test_decode_spans_say_what_the_selection_and_the_rings_read(served):
         srv.submit_generate("lm", prompt, 6).result(timeout=300)
     finally:
         generation._begin = begin
+    # the prefill says how many S blocks it has and how many took the kernel
+    assert [(a["sparse_layers"], a["sparse_kernel_layers"])
+            for a in prefills] == [(2, 2)]
     assert seen
     for sp in seen:
         a = sp.args
@@ -460,16 +604,19 @@ def test_decode_spans_say_what_the_selection_and_the_rings_read(served):
         assert a["selected_tokens"] == 2 * 8 and a["ring_tokens"] == 9
 
 
+_SPARSE_SCOPES = ("mx.mla_proj", "mx.dsa_indexer", "mx.dsa_select",
+                  "mx.sparse_attention", "mx.window_attention",
+                  "mx.attn_gate", "mx.kv_write", "mx.moe_experts")
+
+
 @pytest.mark.parametrize("program,scopes", [
-    ("decode", ("mx.mla_proj", "mx.dsa_indexer", "mx.dsa_select",
-                "mx.sparse_attention", "mx.window_attention", "mx.attn_gate",
-                "mx.kv_write", "mx.moe_experts")),
-    ("prefill", ("mx.mla_proj", "mx.dsa_indexer", "mx.dsa_select",
-                 "mx.sparse_attention", "mx.window_attention",
-                 "mx.attn_gate", "mx.kv_write", "mx.moe_experts"))])
+    ("decode", _SPARSE_SCOPES), ("prefill", _SPARSE_SCOPES),
+    ("prefill-masked", _SPARSE_SCOPES + ("mx_attention_tiled_masked",))])
 def test_sparse_programs_carry_their_scopes(program, scopes):
     """The device scopes the benchmark's readers look for are in the
-    lowered programs' operation names."""
+    lowered programs' operation names; a prefill on the masked kernel's
+    route (the tier on) holds the kernel and no gather of each query's
+    selected latent rows (``[B, Q, K, Rkv+dr]``: the twin's alone)."""
     model, params = _tiny()
     kv = model.init_kv_pages(16, PAGE, slots=2)
     i32 = jnp.int32
@@ -478,12 +625,19 @@ def test_sparse_programs_carry_their_scopes(program, scopes):
             p, c, jnp.zeros((2,), i32), jnp.ones((2,), i32),
             jnp.ones((2, 4), i32), PAGE)).lower(params, kv)
     else:
-        lowered = jax.jit(lambda p, c: model.prefill(
-            p, c, jnp.zeros((2, 16), i32), jnp.full((2,), 13, i32),
-            jnp.ones((2, 4), i32), PAGE)).lower(params, kv)
+        if program == "prefill-masked":
+            mx.config.set("kernels.enabled", True)
+        try:
+            lowered = jax.jit(lambda p, c: model.prefill(
+                p, c, jnp.zeros((2, 16), i32), jnp.full((2,), 13, i32),
+                jnp.ones((2, 4), i32), PAGE)).lower(params, kv)
+        finally:
+            mx.config.unset("kernels.enabled")
     text = lowered.as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, scope
+    rows = "tensor<2x16x8x20xf32>"          # [B, Q, index_topk, 16 + 4]
+    assert (rows in text) == (program == "prefill"), program
 
 
 @pytest.mark.parametrize("layer", [None, 1])

@@ -681,10 +681,13 @@ def test_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
     latent row and an index key a token) and a 640-column ring a slot for
     each window layer — lowered for the TPU, reloaded and compiled for the
     described chip: the decode program holds the sparse kernel at both
-    ``S`` layers and no gather of whole pages, the pool and the rings are
-    rewritten in place, and weights, cache and the temporaries of a decode
-    step and of the largest bucket's prefill (16,384: its query chunks)
-    fit one chip's 15.75 GiB.  (Weights are shapes only.)"""
+    ``S`` layers and no gather of whole pages, the prefill at the largest
+    bucket attends through the masked K/V-tiled kernel at both and gathers
+    no query's selected rows, the pool and the rings are rewritten in
+    place, and weights, cache and the temporaries of a decode step and of
+    the largest bucket's prefill (16,384: its query chunks' selections,
+    the expanded heads) fit one chip's 15.75 GiB.  (Weights are shapes
+    only.)"""
     import json
     import os
     from jax import export as jexport
@@ -718,6 +721,8 @@ def test_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
     assert meta["paged"] == {str(width): {
         "impl": "sparse", "reason": None, "quantized": False}}
     assert meta["kv"]["row_width"] == 704
+    assert meta["sparse_prefill"] == {"prefill-s%d" % bucket: {
+        "impl": "masked", "reason": None, "sites": 2}}
     spec = compile_for_chip.spec
     on_chip = functools.partial(jax.tree_util.tree_map,
                                 lambda a: spec(a.shape, a.dtype))
@@ -751,6 +756,9 @@ def test_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
         spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
         spec((1, -(-bucket // sz["page_tokens"])), jnp.int32),
         spec((1,), jnp.int32), *sample(1))
+    assert prefill.as_text().count("mx_attention_tiled_masked") >= 2
+    # no query chunk's gathered rows [1, 64, 2,048, 576] (the twin's)
+    assert "[1,64,2048,576]" not in prefill.as_text()
     pool = "bf16[2,9216,704,128]"
     for program in (text, prefill.as_text()):
         assert pool + "{3,2,1,0" in program
