@@ -21,7 +21,8 @@ one process; nothing here starts a child once jax is touched):
            and its decode update, compiled, against the quadratic form
            that defines the layer; the update's kernel against its twin,
            and behind the server, its route counted
-  kernels  every Pallas kernel on those paths, compiled, against its XLA twin
+  kernels  every Pallas kernel on those paths, compiled, against its XLA
+           twin, and the block-sparse cell's three kernel sites at its widths
 
 ``--chips 4`` runs ``device`` and, instead of the phases above, the
 paths that exist only across chips: ResNet-50 on a dp=4 mesh and one
@@ -134,6 +135,9 @@ def _sizes(rehearse):
                            page=4)),
             flash=((2, 4, 32, 16), (1, 2, 24, 16)), paged_k=(32, 64),
             grouped=(200, 8, 128, 256), softmax=(16, 48), sbr=(16, 40),
+            block_sparse=dict(decode=(4, 2, 4, 128, 16, 8, 64),
+                              prefill=(256, 4, 2, 128, 16),
+                              update=(2, 4, 128)),
             dp_batch=16, lm_batch=4, lm_seq=32)
     return dict(
         vision="resnet50_v1", classes=1000, image=224, batch=128,
@@ -173,6 +177,14 @@ def _sizes(rehearse):
         # hybrid cell's held experts, at their widths
         grouped=(2816, 32, 1024, 2688), softmax=(4096, 1024),
         sbr=(4096, 768),
+        # the block-sparse cell's widths (minicpm_sala_pp8): 64 rows over 2
+        # K/V heads of 16 query heads of 128, pages of 64 tokens, 128 kept
+        # pages at most, 5,120 pages; a prompt of 16,384 (two 128-lane
+        # blocks of its block mask) at 32 heads over 2; 64 rows of 32
+        # lightning heads of 128
+        block_sparse=dict(decode=(64, 2, 16, 128, 64, 128, 5120),
+                          prefill=(16384, 32, 2, 128, 64),
+                          update=(64, 32, 128)),
         dp_batch=512, lm_batch=4, lm_seq=1024)
 
 
@@ -839,6 +851,51 @@ def phase_kernels(args, sz):
                   x, w, s, None, f32)[:held],
               (rand((m, k), dt), rand((e, k, n), dt),
                jnp.asarray(sizes, jnp.int32)), MXU)
+
+    # the block-sparse cell's sites: the paged kernel over each (row, K/V
+    # head)'s kept pages (one head a page, the partial current one last,
+    # some rows dense: every page up to the table's width), the masked
+    # K/V-tiled pass under a mask of blocks (each query's own block kept),
+    # the retention update with the key and query themselves in the
+    # expanded ones' place (a lightning state, no normaliser)
+    bs = sz["block_sparse"]
+    b, kvh, g, dh, psz, width, pool = bs["decode"]
+    kept = rng.randint(width // 4, width // 2 + 1, (b * kvh,))
+    kept[::7] = width
+    table = np.stack([np.sort(rng.permutation(pool * kvh)[:width])
+                      for _ in range(b * kvh)])
+    lens = (kept - 1) * psz + rng.randint(1, psz + 1, (b * kvh,))
+    check("block_sparse_attention",
+          lambda q, k, v, t, n: pk.pallas_paged_attention(
+              q, k, v, t, n, layer=0),
+          lambda q, k, v, t, n: kernels._paged_attention_xla(
+              q, k, v, t, n, layer=0),
+          (rand((b * kvh, g, 1, dh), bf16),
+           rand((1, pool * kvh, psz, dh), bf16),
+           rand((1, pool * kvh, psz, dh), bf16),
+           jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)),
+          MXU)
+    S, H, kvh, dh, blk = bs["prefill"]
+    nb = S // blk
+    own = np.arange(S)[:, None] // blk == np.arange(nb)[None, :]
+    mask = (rng.uniform(size=(kvh, S, nb)) < 0.25) | own[None]
+    scale = dh ** -0.5
+    check("block_sparse_prefill",
+          lambda q, k, v, m: pk.flash_attention_tiled(
+              q, k, v, causal=True, scale=scale, mask=m, mask_block=blk),
+          lambda q, k, v, m: kernels._block_sparse_prefill_xla(
+              q, k, v, m, scale, blk),
+          (rand((1, H, S, dh), bf16), rand((1, kvh, S, dh), bf16),
+           rand((1, kvh, S, dh), bf16), jnp.asarray(mask, bf16)), MXU)
+    b, h, dh = bs["update"]
+    check("lightning_update",
+          lambda st, k, q, lam, v: pk.pallas_retention_update(
+              st, jnp.zeros(st.shape[:3], f32), k, q, lam, v)[::2],
+          lambda st, k, q, lam, v: kernels._retention_update_xla(
+              st, jnp.zeros(st.shape[:3], f32), k, q, lam, v)[::2],
+          (rand((b, h, dh, dh), f32), rand((b, h, dh), f32),
+           rand((b, h, 1, dh), f32), rand((b, h), f32, lo=0.5),
+           rand((b, h, dh), bf16)), POINTWISE)
 
     x = rand(sz["softmax"], f32)
     check("pallas_row_softmax", pk.pallas_row_softmax,

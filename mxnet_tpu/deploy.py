@@ -418,11 +418,13 @@ def _kv_pool_specs(kv, num_pages, slots=None):
     device; ``row_width`` is K/V heads x head size) or, where
     ``kv["page_layout"]`` is ``"lanes"``, ``[L, num_pages, row_width,
     page_size]`` (a page's tokens on the lanes: latent pages, whose row
-    width is no multiple of the lanes), plus the ``[L, num_pages,
-    page_size, H]`` f32 scale pools of an int8 pool.  Then its STATE
-    region, if the model keeps one (``kv["state"]``: ``{"name", "shape",
-    "dtype"}`` each): one ``[slots, *shape]`` array apiece, a row per
-    decode slot.  ``num_pages`` and ``slots`` may be symbolic
+    width is no multiple of the lanes) or, where it is ``"heads"``, ``[L,
+    num_pages, num_heads, page_size, head_dim]`` (each K/V head's rows of
+    a page apart: a kernel reads one head's page whole), plus the ``[L,
+    num_pages, page_size, H]`` f32 scale pools of an int8 pool.  Then its
+    STATE region, if the model keeps one (``kv["state"]``: ``{"name",
+    "shape", "dtype"}`` each): one ``[slots, *shape]`` array apiece, a row
+    per decode slot.  ``num_pages`` and ``slots`` may be symbolic
     dimensions."""
     import jax
     import jax.numpy as jnp
@@ -430,6 +432,8 @@ def _kv_pool_specs(kv, num_pages, slots=None):
     wide = rows + (kv["row_width"],)
     if kv.get("page_layout") == "lanes":
         wide = rows[:2] + (kv["row_width"], kv["page_size"])
+    elif kv.get("page_layout") == "heads":
+        wide = rows[:2] + (kv["num_heads"], kv["page_size"], kv["head_dim"])
     if kv.get("quantized"):
         return (jax.ShapeDtypeStruct(wide, jnp.int8),
                 jax.ShapeDtypeStruct(wide, jnp.int8),

@@ -354,6 +354,9 @@ class GenerationEngine:
         #: a model whose latent blocks select their tokens (the sparse
         #: site's counters)
         self._sparse = bool(predictor.meta["kv"].get("sparse"))
+        #: a model whose blocks attend over the pages their compressed keys
+        #: chose (the block-sparse sites' counters)
+        self._block_sparse = bool(predictor.meta["kv"].get("block_sparse"))
         self._share = bool(_config.get("serving.shared_prefix"))
         if self._share and predictor.state:
             # a shared page skips the prefill that would have built the
@@ -1139,7 +1142,9 @@ class GenerationEngine:
         retention updates, once an ``R`` block a dispatch
         (``kernels.retention_update`` / ``kernels.retention_fallback``);
         and on a prefill's sparse attention, once an ``S`` block a dispatch
-        (``kernels.sparse_prefill`` / ``kernels.sparse_prefill_fallback``)."""
+        (``kernels.sparse_prefill`` / ``kernels.sparse_prefill_fallback``)
+        or a ``B`` block's (``kernels.block_sparse_prefill`` /
+        ``kernels.block_sparse_prefill_fallback``)."""
         route = self.predictor.grouped_routes.get(program)
         if route is not None:
             if route.get("impl") == "grouped":
@@ -1156,12 +1161,16 @@ class GenerationEngine:
                     route["sites"])
         route = self.predictor.sparse_prefill_routes.get(program)
         if route is not None:
+            # (a ``B`` block's masked pass has the block-sparse names)
+            took, fell_back = (
+                "kernels.block_sparse_prefill",
+                "kernels.block_sparse_prefill_fallback") \
+                if self._block_sparse else (
+                "kernels.sparse_prefill", "kernels.sparse_prefill_fallback")
             if route.get("impl") == "masked":
-                _telemetry.counter("kernels.sparse_prefill").inc(
-                    route["sites"])
+                _telemetry.counter(took).inc(route["sites"])
             elif _kernels_enabled():
-                _telemetry.counter("kernels.sparse_prefill_fallback").inc(
-                    route["sites"])
+                _telemetry.counter(fell_back).inc(route["sites"])
 
     def _dispatch_decode(self, rows):
         """Dispatch one decode step for ``rows``, every row with tokens
@@ -1210,8 +1219,8 @@ class GenerationEngine:
         # reads: each row's own pages where the kernel reads them in
         # place, the whole table's window where the twin gathers it
         psz = gp.page_size
-        kernel = route is not None \
-            and route.get("impl") in ("paged", "latent", "sparse")
+        kernel = route is not None and route.get("impl") in (
+            "paged", "latent", "sparse", "block_sparse")
         if kernel:
             window = sum(-(-(s.pos + 1) // psz) for _, s in rows) * psz
         else:
@@ -1298,6 +1307,9 @@ class GenerationEngine:
             # (or fell back while the kernel tier was on) is counted
             # (a model of latent pages has the latent site's counters)
             took, fell_back = (
+                _telemetry.counter("kernels.block_sparse"),
+                _telemetry.counter("kernels.block_sparse_fallback")) \
+                if self._block_sparse else (
                 _telemetry.counter("kernels.sparse_latent"),
                 _telemetry.counter("kernels.sparse_latent_fallback")) \
                 if self._sparse else (
@@ -1306,7 +1318,8 @@ class GenerationEngine:
                 if self._latent else (
                 _telemetry.counter("kernels.paged_attention"),
                 _telemetry.counter("kernels.paged_fallback"))
-            if st.route.get("impl") in ("paged", "latent", "sparse"):
+            if st.route.get("impl") in ("paged", "latent", "sparse",
+                                        "block_sparse"):
                 took.inc()
             elif _kernels_enabled():
                 fell_back.inc()

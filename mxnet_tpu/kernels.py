@@ -1,11 +1,12 @@
 """mx.kernels — routing tier for the hand-written Pallas kernels.
 
 The raw kernels live in ``ops/pallas_kernels.py`` and stay policy-free;
-this module alone decides WHEN they run.  The eight routed sites
+this module alone decides WHEN they run.  The ten routed sites
 (:func:`attention`, :func:`paged_attention`,
 :func:`latent_paged_attention`, :func:`sparse_latent_attention`,
 :func:`sparse_prefill_route`, :func:`index_scores`,
-:func:`grouped_matmul`, :func:`retention_update`) ask one rule
+:func:`grouped_matmul`, :func:`retention_update`,
+:func:`block_sparse_attention`, :func:`block_sparse_prefill`) ask one rule
 (:func:`_route_reason`), which reads three things it can see at trace
 time and nothing else — nothing is timed, persisted or remembered:
 
@@ -21,18 +22,21 @@ time and nothing else — nothing is timed, persisted or remembered:
    :func:`sparse_unsupported_reason`,
    :func:`sparse_prefill_unsupported_reason`,
    :func:`index_unsupported_reason`, :func:`grouped_unsupported_reason`,
-   :func:`retention_unsupported_reason`) → the XLA lowering
+   :func:`retention_unsupported_reason`,
+   :func:`block_sparse_prefill_unsupported_reason`) → the XLA lowering
    (``kernels.fallback`` / ``kernels.paged_fallback`` /
    ``kernels.latent_fallback`` / ``kernels.sparse_latent_fallback`` /
    ``kernels.sparse_prefill_fallback`` / ``kernels.index_fallback`` /
-   ``kernels.grouped_fallback`` / ``kernels.retention_fallback``), never
-   an error;
+   ``kernels.grouped_fallback`` / ``kernels.retention_fallback`` /
+   ``kernels.block_sparse_fallback`` /
+   ``kernels.block_sparse_prefill_fallback``), never an error;
 4. else the kernel (``kernels.flash_attention`` /
    ``kernels.paged_attention`` / ``kernels.latent_paged`` /
    ``kernels.sparse_latent`` / ``kernels.sparse_prefill`` /
    ``kernels.index_scores`` / ``kernels.grouped_matmul`` /
-   ``kernels.retention_update``), at block sizes that are constants or
-   functions of the shapes.
+   ``kernels.retention_update`` / ``kernels.block_sparse`` /
+   ``kernels.block_sparse_prefill``), at block sizes that are constants
+   or functions of the shapes.
 
 The decision is trace-time python, so a jitted program contains one
 path only; a knob change retraces (``config.epoch()`` in the program
@@ -62,6 +66,8 @@ __all__ = ["enabled", "attention", "paged_attention",
            "latent_paged_attention", "sparse_latent_attention",
            "sparse_prefill_route", "sparse_prefill_attention",
            "index_scores", "grouped_matmul", "retention_update",
+           "block_sparse_attention", "block_sparse_prefill",
+           "block_sparse_prefill_unsupported_reason",
            "flash_unsupported_reason", "tiled_unsupported_reason",
            "paged_unsupported_reason", "latent_unsupported_reason",
            "sparse_unsupported_reason", "sparse_prefill_unsupported_reason",
@@ -715,6 +721,141 @@ def sparse_latent_attention(q, pages, page_table, lengths, chosen, scale,
     return _sparse_latent_attention_xla(q, pages, page_table, lengths,
                                         chosen, scale, value_width,
                                         layer=layer)
+
+
+# ------------------------------------------------ block-sparse attention
+def block_sparse_attention(q, k_pages, v_pages, page_table, lengths, scale,
+                           layer=None):
+    """Decode-step attention of a ``B`` block over the pages its selection
+    kept: one row a (sequence, K/V head), its query heads ``q`` ``[B KVH,
+    G, 1, Dh]`` against pools of one head a page (``[L, P KVH, psz, Dh]``:
+    page ``p`` of head ``g`` is ``p KVH + g``), ``page_table`` ``[B KVH,
+    K]`` the kept pages in order with the partial current page last and
+    ``lengths`` the tokens they hold.  The paged kernel's shapes and
+    rule (:func:`paged_unsupported_reason`): the Pallas kernel
+    (``mx_paged_attention``, reading each row's ``ceil(length / psz)``
+    kept pages where they lie; counter ``kernels.block_sparse``) or the
+    XLA twin, which gathers the table's whole width
+    (``kernels.block_sparse_fallback``).  The decision lands, as ``impl``
+    "block_sparse" or "xla", in the export route sink of
+    :func:`record_paged_routes`.  No scope of its own: the caller's
+    (``mx.block_sparse_attention``) names its device time."""
+    reason = _route_reason(
+        lambda: paged_unsupported_reason(q, k_pages, v_pages, page_table,
+                                         lengths, layer=layer),
+        _telemetry.counter("kernels.block_sparse_fallback"))
+    if reason is None:
+        _telemetry.counter("kernels.block_sparse").inc()
+        _note_route("paged", impl="block_sparse", reason=None,
+                    quantized=False)
+        return pallas_paged_attention(q, k_pages, v_pages, page_table,
+                                      lengths, scale=scale, layer=layer)
+    _note_route("paged", impl="xla", reason=reason, quantized=False)
+    return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
+                                scale=scale, layer=layer)
+
+
+def block_sparse_prefill_unsupported_reason(q, k, v, mask, block):
+    """Why the masked K/V-tiled flash kernel can NOT take a ``B`` block's
+    prefill, or None if it can: q ``[B, H, S, D]``, k and v ``[B, KVH, S,
+    D]`` of one float dtype (H a multiple of KVH), a bfloat16 mask of
+    whole blocks ``[B KVH, S, S / block]``, concrete shapes, a length of
+    one K/V tile or a multiple of it, a block that divides the tile, and a
+    mask no wider than the 128 lanes or a multiple of them."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4 or mask.ndim != 3:
+        return "rank: q%s k%s v%s mask%s, want 4, 4, 4 and 3" % (
+            q.ndim, k.ndim, v.ndim, mask.ndim)
+    dims = tuple(q.shape) + tuple(k.shape) + tuple(v.shape) \
+        + tuple(mask.shape)
+    if not all(isinstance(d, int) for d in dims):
+        return "symbolic shape (q%s mask%s)" % (q.shape, mask.shape)
+    B, H, S, D = q.shape
+    kvh = k.shape[1]
+    if tuple(k.shape) != (B, kvh, S, D) or tuple(v.shape) != (B, kvh, S, D) \
+            or H % kvh or S % block \
+            or tuple(mask.shape) != (B * kvh, S, S // block):
+        return "q%s k%s v%s mask%s are not [B,H,S,D], [B,KVH,S,D] twice " \
+            "and [B KVH, S, S/%d]" % (tuple(q.shape), tuple(k.shape),
+                                      tuple(v.shape), tuple(mask.shape),
+                                      block)
+    if not (q.dtype == k.dtype == v.dtype) \
+            or q.dtype not in (jnp.float32, jnp.bfloat16):
+        return "operands %s/%s/%s, want all float32 or all bfloat16" % (
+            q.dtype, k.dtype, v.dtype)
+    if mask.dtype != jnp.bfloat16:
+        return "the mask is %s, want bfloat16" % mask.dtype
+    if D > _MAX_HEAD_DIM:
+        return "head dim %d > %d" % (D, _MAX_HEAD_DIM)
+    tile = min(_TILED_BLOCK, S)
+    if S % tile or tile % block or block & (block - 1):
+        return "length %d is no whole number of %d-row tiles of whole " \
+            "blocks of %d (a power of two)" % (S, tile, block)
+    nb = S // block
+    if nb > 128 and nb % 128:
+        return "%d blocks: over 128 and no multiple of 128" % nb
+    return None
+
+
+def _block_sparse_prefill_xla(q, k, v, mask, scale, block):
+    """The XLA twin of the block-masked prefill: a chunk of queries a step
+    against every key, the block mask widened to the keys and the causal
+    one beside it, a one-pass softmax (a row keeps its own block, so no row
+    is empty)."""
+    B, H, S, D = q.shape
+    kvh = k.shape[1]
+    G = H // kvh
+    Q = min(128, S)
+    steps = -(-S // Q)
+    qg = jnp.pad(q, ((0, 0), (0, 0), (0, steps * Q - S), (0, 0))).reshape(
+        B, kvh, G, steps * Q, D)
+    mg = jnp.pad(mask, ((0, 0), (0, steps * Q - S), (0, 0))).reshape(
+        B, kvh, steps * Q, S // block)
+    pos = jnp.arange(S, dtype=jnp.int32)
+
+    def chunk(i):
+        qc = lax.dynamic_slice_in_dim(qg, i * Q, Q, axis=3)
+        mc = lax.dynamic_slice_in_dim(mg, i * Q, Q, axis=2)
+        t = i * Q + jnp.arange(Q, dtype=jnp.int32)
+        keep = (jnp.repeat(mc, block, axis=-1) != 0) \
+            & (pos[None, :] <= t[:, None])                   # [B,KVH,Q,S]
+        s = jnp.einsum("bgrqd,bgsd->bgrqs", qc, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep[:, :, None], s, -1e30)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.einsum("bgrqs,bgsd->bgrqd", e.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return (o / jnp.sum(e, axis=-1)[..., None]).astype(q.dtype)
+
+    o = lax.map(chunk, jnp.arange(steps, dtype=jnp.int32))
+    return jnp.moveaxis(o, 0, 3).reshape(B, H, steps * Q, D)[:, :, :S]
+
+
+def block_sparse_prefill(q, k, v, mask, scale, block):
+    """Causal attention of a ``B`` block's whole prompt over the blocks its
+    selection kept: q ``[B, H, S, D]``, k and v ``[B, KVH, S, D]`` (query
+    head h reads K/V head ``h // (H / KVH)``), ``mask`` bfloat16 ``[B KVH,
+    S, S / block]`` (1 where query t keeps block b of its K/V head) ->
+    ``[B, H, S, D]``.  Routing by the module's rule: the masked K/V-tiled
+    flash kernel (``flash_attention_tiled`` with the mask of blocks,
+    ``mx_attention_tiled_masked``: K/V tiles above the diagonal skipped,
+    the mask widened to a tile's keys on the MXU; counter
+    ``kernels.block_sparse_prefill``) or the XLA twin
+    (:func:`block_sparse_prefill_unsupported_reason`;
+    ``kernels.block_sparse_prefill_fallback``).  The decision lands, as
+    ``impl`` "masked" or "xla", in the export route sink of
+    :func:`record_sparse_prefill_routes`.  No scope of its own: the
+    caller's (``mx.block_sparse_prefill``) names its device time."""
+    reason = _route_reason(
+        lambda: block_sparse_prefill_unsupported_reason(q, k, v, mask,
+                                                        block),
+        _telemetry.counter("kernels.block_sparse_prefill_fallback"))
+    _note_route("sparse_prefill", impl="xla" if reason else "masked",
+                reason=reason)
+    if reason is None:
+        _telemetry.counter("kernels.block_sparse_prefill").inc()
+        return flash_attention_tiled(q, k, v, causal=True, scale=scale,
+                                     mask=mask, mask_block=block)
+    return _block_sparse_prefill_xla(q, k, v, mask, scale, block)
 
 
 # ------------------------------------------------ sparse prefill attention

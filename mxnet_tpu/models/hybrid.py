@@ -1,4 +1,4 @@
-"""HybridLM — a decoder whose blocks are each ONE mixer, of seven kinds.
+"""HybridLM — a decoder whose blocks are each ONE mixer, of eleven kinds.
 
 The stack is a pattern string, one letter a block (the ``nemotron_h``
 family's ``hybrid_override_pattern``, and two letters of this module's
@@ -46,8 +46,40 @@ own):
          keeps the window's latent rows in a ring of ``ring`` columns
          (position p at column p mod ring) in the state arrays
 
-``S`` and ``W`` blocks gate each head's output by one sigmoid of the
+  ``B``  grouped-query softmax attention over BLOCKS chosen by compressed
+         keys (InfLLM-v2, the MiniCPM4 report arXiv:2506.07900 section
+         2.1): per-head RMSNorm of q and k, no positional encoding; the
+         mean key of every ``kernel_size`` positions at stride
+         ``kernel_stride`` scores each query's relevance per K/V head
+         (softmax over the compressed keys, summed over the group's query
+         heads, the largest over the kernels a ``block_size`` block
+         overlaps), and each query attends over the first
+         ``init_blocks`` blocks, the blocks of its ``local_window`` latest
+         positions and the best of the rest, ``block_topk`` blocks in all
+         (every block while the position is under ``dense_len``).  A page
+         is a block: a decode step attends over the row's selected pages
+         through the paged kernel (``kernels.block_sparse_attention``), a
+         prompt through one causal flash pass masked a block at a time
+         (``kernels.block_sparse_prefill``); the compressed keys live in
+         the state arrays, one row a ``kernel_stride`` positions, written
+         as each kernel completes
+  ``N``  lightning attention (Qin et al., arXiv:2401.04658; MiniMax-01,
+         arXiv:2501.08313 section 2.2): linear attention under a FIXED
+         per-head decay, ``S_t = lambda_h S_{t-1} + k_t^T v_t``, ``o_t =
+         q_t S_t / sqrt(Dh)``, float32 state ``[heads, Dh, Dh]`` a slot;
+         SiLU on q, k and v, per-head RMSNorm and rotary on q and k,
+         RMSNorm over the heads' concatenated output, an elementwise
+         sigmoid gate; ``lambda_h = exp(-2^(-8(h+1)/H) (1 - l/(L-1) +
+         1e-5))`` of the block's PUBLISHED layer ``l`` of ``L``
+
+``S``, ``W`` and ``B`` blocks gate each head's output by one sigmoid of the
 block's normalised input (Qiu et al., arXiv:2505.06708).
+
+The MiniCPM family's muP scalars are configuration: the embedding times
+``scale_emb``, every block's output times ``residual_scale`` before it
+joins the residual stream, the final normalised state times
+``logit_scale`` before the head; at their defaults of 1 no operation is
+added.
 
 ``h <- h + Mixer(RMSNorm(h))`` per block, no positional table (the
 recurrences order the tokens; ``R`` and ``L`` rotate q and k by their
@@ -70,9 +102,14 @@ a page is component c of its tokens: a 576-wide minor axis would be padded
 to 640 lanes, or laid out this way by the compiler behind a copy into
 every kernel call); ``kv_spec()`` says so (``pools``, ``page_layout``) and
 ``deploy`` / the engine build the cache from that description.  A model
-keeps one kind of page: a pattern may not mix ``*``, ``L`` and ``S``.
-``S`` pages are ``L``'s with ``index_dim`` more rows a page, the index
-keys (``kv_spec()["index_rows"]``).
+keeps one kind of page: a pattern may not mix ``*``, ``L``, ``S`` and
+``B``.  ``S`` pages are ``L``'s with ``index_dim`` more rows a page, the
+index keys (``kv_spec()["index_rows"]``).  ``B`` pages are K and V pages
+of ``block_size`` tokens with each K/V head's rows apart (``page_layout``
+``"heads"``: ``[L layers, pages, KVH, page_size, Dh]``, so a page of one
+head is one contiguous copy), and per decode slot the state region holds
+each ``B`` block's compressed keys and the running key sums that complete
+the next one, and each ``N`` block's float32 ``[H, Dh, Dh]`` state.
 
 One chip's share of an expert-parallel deployment is a configuration, not
 another code path: ``experts_held`` / ``expert_offset`` say which routed
@@ -103,7 +140,7 @@ from .transformer import TransformerLM, _norm
 
 __all__ = ["HybridLMConfig", "HybridLM"]
 
-KINDS = "ME*RFLGSW"
+KINDS = "ME*RFLGSWBN"
 #: what ``parallel.moe.dropless_experts`` counts, summed over the E and G
 #: blocks
 _STATS = ("pairs", "experts_hit", "max_load")
@@ -122,6 +159,12 @@ _MOE_PAIR_BYTES = 1 << 30
 #: :data:`_STATS` (``decode_step(..., return_stats=True)``)
 _SPARSE_STATS = ("index_tokens", "selected_tokens")
 _RING_STATS = ("ring_tokens",)
+#: ... and what the ``B`` blocks count: compressed keys scored and tokens
+#: attended (a query head's, averaged over the K/V heads)
+_BLOCK_STATS = ("compressed_keys", "selected_tokens")
+#: ``B`` blocks' prefill: queries a step of the selection's loop (each step
+#: holds the chunk's relevance over every compressed key, float32)
+_BLOCK_QUERY_CHUNK = 512
 
 
 class HybridLMConfig:
@@ -136,7 +179,12 @@ class HybridLMConfig:
                  nope_dim=32, rope_dim=16, v_dim=32, index_heads=4,
                  index_dim=16, index_topk=8, swa_heads=4, swa_q_rank=64,
                  swa_kv_rank=32, swa_nope_dim=16, swa_rope_dim=8,
-                 swa_v_dim=16, swa_rope_theta=5e4, window=9):
+                 swa_v_dim=16, swa_rope_theta=5e4, window=9,
+                 kernel_size=32, kernel_stride=16, block_size=64,
+                 block_topk=64, init_blocks=1, local_window=2048,
+                 dense_len=8192, first_layer=0,
+                 published_layers=None, scale_emb=1.0, residual_scale=1.0,
+                 logit_scale=1.0):
         if not pattern or set(pattern) - set(KINDS):
             raise ValueError("pattern %r: one of %r per block"
                              % (pattern, KINDS))
@@ -213,11 +261,43 @@ class HybridLMConfig:
         self.swa_rope_theta = float(swa_rope_theta)
         self.window = int(window)
         self.ring = -(-self.window // 128) * 128
-        if sum(k in pattern for k in "*LS") > 1:
+        #: ``B`` blocks (``num_heads`` over ``num_kv_heads`` of
+        #: ``head_dim``): a compressed key is the mean of ``kernel_size``
+        #: keys, one every ``kernel_stride`` positions; a block (and a page)
+        #: is ``block_size`` positions; a query keeps ``block_topk`` blocks
+        #: (the first ``init_blocks`` and those of its ``local_window``
+        #: latest positions among them) or, under ``dense_len``, every one
+        self.kernel_size = int(kernel_size)
+        self.kernel_stride = int(kernel_stride)
+        self.block_size = int(block_size)
+        self.block_topk = int(block_topk)
+        self.init_blocks = int(init_blocks)
+        self.local_window = int(local_window)
+        self.dense_len = int(dense_len)
+        if "B" in pattern and (
+                kernel_size % kernel_stride or block_size % kernel_stride
+                or max_len % block_size or max_len % kernel_stride):
             raise ValueError(
-                "pattern %r mixes '*' (K and V pages), 'L' (latent pages) "
-                "and 'S' (latent pages with index keys): a model keeps one "
-                "kind of page" % (pattern,))
+                "B blocks: kernel %d and block %d must be whole strides of "
+                "%d, and max_len %d whole blocks" % (
+                    kernel_size, block_size, kernel_stride, max_len))
+        #: ``N`` blocks: the published index of the pattern's first layer
+        #: and the published depth the decay reads (a layer is a mixer
+        #: block and what follows it up to the next mixer)
+        self.first_layer = int(first_layer)
+        self.published_layers = int(
+            published_layers if published_layers is not None
+            else sum(k != "F" for k in pattern))
+        #: muP: the embedding's, every block output's and the final
+        #: state's multipliers (1: no operation)
+        self.scale_emb = float(scale_emb)
+        self.residual_scale = float(residual_scale)
+        self.logit_scale = float(logit_scale)
+        if sum(k in pattern for k in "*LSB") > 1:
+            raise ValueError(
+                "pattern %r mixes '*' (K and V pages), 'L' (latent pages), "
+                "'S' (latent pages with index keys) and 'B' (K and V pages "
+                "a head apart): a model keeps one kind of page" % (pattern,))
 
 
 def _normal(key, shape, std, dtype):
@@ -251,22 +331,33 @@ class HybridLM:
         self.names = tuple("%02d" % i for i in range(len(self.kinds)))
         # which attention layer (pool index) a block is
         self.attn_index = {n: i for i, n in enumerate(
-            n for n, k in zip(self.names, self.kinds) if k in "*LS")}
+            n for n, k in zip(self.names, self.kinds) if k in "*LSB")}
         self.latent = "L" in self.kinds or "S" in self.kinds
         self.sparse = "S" in self.kinds
+        #: K and V pages a head apart, one a block (``B`` blocks)
+        self.block_sparse = "B" in self.kinds
         #: what ``decode_step(..., return_stats=True)`` appends, in order:
         #: the experts' counts, then the rows the ``S`` blocks scored and
         #: attended and the ring columns the ``W`` blocks attended, where
         #: the pattern has them
         self.decode_stats = tuple("moe_" + n for n in _STATS) \
             + (_SPARSE_STATS if self.sparse else ()) \
-            + (_RING_STATS if "W" in self.kinds else ())
+            + (_RING_STATS if "W" in self.kinds else ()) \
+            + (_BLOCK_STATS if self.block_sparse else ())
         #: ``L`` blocks: scores over a head's whole query/key width, in the
         #: expanded and in the absorbed form alike (``S`` and ``W`` blocks:
         #: over theirs)
         self._mla_scale = 1.0 / math.sqrt(config.nope_dim + config.rope_dim)
         self._swa_scale = 1.0 / math.sqrt(config.swa_nope_dim
                                           + config.swa_rope_dim)
+        #: ``N`` blocks' log-decays [heads] by block name (constants)
+        self._lit_log_decay = {}
+        layer = config.first_layer
+        for name, kind in zip(self.names, self.kinds):
+            if kind == "N":
+                self._lit_log_decay[name] = _lightning_log_decay(
+                    config.num_heads, layer, config.published_layers)
+            layer += kind != "F"
 
     # -------------------------------------------------------------- params
     def init(self, key):
@@ -281,8 +372,11 @@ class HybridLM:
 
         # the family's ``rescale_prenorm_residual``: every block's last
         # matrix over sqrt(depth), so the whole stack moves a unit-variance
-        # embedding by a fraction of its norm
-        out = 1.0 / math.sqrt(cfg.depth)
+        # embedding by a fraction of its norm (a muP stack's residual
+        # multiplier is its depth scaling: its matrices are drawn whole,
+        # and its embedding so that the scaled one has unit variance)
+        out = 1.0 / math.sqrt(cfg.depth) if cfg.residual_scale == 1.0 \
+            else 1.0
 
         ones = lambda n: jnp.ones((n,), dt)   # noqa: E731
         layers = {}
@@ -350,6 +444,24 @@ class HybridLM:
                     lp.update(w_iq=mk((Rq, Hi, Di), Rq), w_ik=mk((D, Di), D),
                               ik_w=ones(Di), ik_b=jnp.zeros((Di,), dt),
                               w_iw=mk((D, Hi), D))
+            elif kind == "B":
+                H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+                # q and k norm weights of 2: at 1, rows of unit RMS score
+                # with std 1 and a query over tens of thousands of tokens
+                # spreads so evenly that no choice of blocks moves it (a
+                # trained qk-norm sharpens attention)
+                qk = jnp.full((Dh,), 2.0, dt)
+                lp = {"ln": ones(D), "wq": mk((D, H, Dh), D),
+                      "wk": mk((D, KV, Dh), D), "wv": mk((D, KV, Dh), D),
+                      "qn": qk, "kn": qk, "w_hg": mk((D, H), D),
+                      "wo": mk((H, Dh, D), H * Dh, out)}
+            elif kind == "N":
+                H, Dh = cfg.num_heads, cfg.head_dim
+                lp = {"ln": ones(D), "wq": mk((D, H, Dh), D),
+                      "wk": mk((D, H, Dh), D), "wv": mk((D, H, Dh), D),
+                      "qn": ones(Dh), "kn": ones(Dh), "onorm": ones(H * Dh),
+                      "wg": mk((D, H * Dh), D),
+                      "wo": mk((H, Dh, D), H * Dh, out)}
             elif kind == "G":
                 E, Eh = cfg.num_experts, cfg.experts_held
                 F, Fs = cfg.expert_ff, cfg.shared_ff
@@ -373,7 +485,8 @@ class HybridLM:
                       "w1": mk((Eh, Z, F), Z), "w2": mk((Eh, F, Z), F),
                       "v1": mk((D, Fs), D), "v2": mk((Fs, D), Fs, out)}
             layers[name] = lp
-        return {"embed": _normal(next(keys), (cfg.vocab_size, D), 1.0, dt),
+        return {"embed": _normal(next(keys), (cfg.vocab_size, D),
+                                 1.0 / cfg.scale_emb, dt),
                 "head": mk((cfg.vocab_size, D), D),
                 "final_norm": ones(D), "layers": layers}
 
@@ -442,7 +555,8 @@ class HybridLM:
         return out + (n, cq) if extras else out
 
     def _mla_out(self, o, lp):
-        """The heads' outputs [..., H, dv] through the output projection."""
+        """The heads' outputs [..., H, dv] through the output projection
+        (``wo`` ``[H, dv, D]``: every kind whose heads are a leading axis)."""
         return jnp.einsum("...he,hed->...d", o, lp["wo"],
                           preferred_element_type=jnp.float32
                           ).astype(o.dtype)
@@ -810,6 +924,310 @@ class HybridLM:
         return self._latent_finish(ctx, n, lp), ring, \
             jnp.sum(seen, axis=1, dtype=jnp.int32)[None]
 
+    # ------------------------------------------ block-sparse attention (B)
+    def _block_qkv(self, x, lp):
+        """RMSNorm, the projections and the per-head q / k norms: x
+        [B,S,D] -> (n [B,S,D], q [B,S,H,Dh], k and v [B,S,KVH,Dh]) in x's
+        dtype; no positional encoding."""
+        cfg = self.cfg
+        with jax.named_scope("mx.qkv"):
+            n = _norm(x, lp["ln"], cfg.eps)
+            q, k, v = (jnp.einsum("bsd,dhe->bshe", n, lp[w],
+                                  preferred_element_type=jnp.float32
+                                  ).astype(x.dtype)
+                       for w in ("wq", "wk", "wv"))
+            return n, _norm(q, lp["qn"], cfg.eps), \
+                _norm(k, lp["kn"], cfg.eps), v
+
+    def _block_out(self, o, n, lp):
+        """The heads' outputs [..., H, Dh], gated a head, through the
+        output projection."""
+        o = self._gate(o, n, lp)
+        with jax.named_scope("mx.attn_out"):
+            return self._mla_out(o, lp)
+
+    def _block_scores(self, q, ckeys, t, blocks):
+        """Each query's score of every block per K/V head: q [B,T,KVH,G,Dh]
+        (the G query heads of each K/V head), ckeys [B,KVH,NC,Dh] (row j the
+        mean key of positions ``j stride .. j stride + kernel - 1``), t
+        [B,T] the queries' positions -> [B,KVH,T,blocks] float32: the
+        softmax of ``q . c / sqrt(Dh)`` over the compressed keys complete
+        at or before t, summed over the group, the largest over the kernels
+        a block overlaps (-inf where none is complete)."""
+        cfg = self.cfg
+        st, per = cfg.kernel_stride, cfg.block_size // cfg.kernel_stride
+        ks = cfg.kernel_size // cfg.kernel_stride
+        nc = ckeys.shape[2]
+        s = jnp.einsum("btghe,bgje->bgthj", q, ckeys,
+                       preferred_element_type=jnp.float32) \
+            / math.sqrt(cfg.head_dim)
+        done = jnp.arange(nc, dtype=jnp.int32) * st + cfg.kernel_size - 1 \
+            <= t[:, None, :, None, None]                  # [B,1,T,1,NC]
+        s = jnp.where(done, s, -jnp.inf)
+        top = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0))
+        r = jnp.sum(e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True),
+                                    1e-30), axis=3)       # [B,KVH,T,NC]
+        r = jnp.where(done[:, :, :, 0], r, -jnp.inf)
+        # block b's kernels are j in [b per - ks + 1, b per + per - 1]
+        want = blocks * per + per
+        r = jnp.pad(r, ((0, 0),) * 3 + ((ks - 1, max(0, want - nc)),),
+                    constant_values=-jnp.inf)
+        return functools.reduce(jnp.maximum, [
+            r[..., o:o + blocks * per:per] for o in range(per + ks - 1)])
+
+    def _block_choose(self, score, t):
+        """The blocks a query keeps: score [..., blocks] (the query's, at
+        position t [...]) -> bool [..., blocks].  Every block at or before
+        t's own while t is under ``dense_len``; else the first
+        ``init_blocks``, the blocks of the ``local_window`` latest
+        positions, and the highest-scoring of the rest (ties to the lower
+        block) up to ``block_topk`` in all."""
+        cfg = self.cfg
+        nb = score.shape[-1]
+        b = jnp.arange(nb, dtype=jnp.int32)
+        t = t[..., None]
+        held = b * cfg.block_size <= t
+        forced = held & ((b < cfg.init_blocks) | (
+            (b + 1) * cfg.block_size > t - cfg.local_window + 1))
+        k = min(cfg.block_topk, nb)
+        rest = jnp.where(held & ~forced, score, -jnp.inf)
+        top, idx = lax.top_k(rest, k)
+        room = cfg.block_topk - jnp.sum(forced, axis=-1, keepdims=True)
+        # the room-th best of the rest, and the last tied block it kept
+        at = jnp.clip(room - 1, 0, k - 1)
+        kth = jnp.take_along_axis(top, at, axis=-1)
+        rank = jnp.arange(k, dtype=jnp.int32)
+        last = jnp.max(jnp.where((top == kth) & (rank < room), idx, -1),
+                       axis=-1, keepdims=True)
+        scored = held & ~forced & (room > 0) & (
+            (rest > kth) | ((rest == kth) & (b <= last)))
+        return jnp.where(t < cfg.dense_len, held, forced | scored)
+
+    def _compressed(self, k, lengths):
+        """A prompt's compressed keys and the running sums a decode step
+        carries on: k [B,S,KVH,Dh] (S whole strides), lengths [B] or None
+        -> (ckeys [B,KVH,NC,Dh] in k's dtype, the state's max_len /
+        stride rows, zeros past the prompt's complete kernels; sums
+        [B,ks,KVH,Dh] float32: the last ``ks - 1`` whole strides' key sums
+        before the one the next position falls in, then that one's so
+        far)."""
+        cfg = self.cfg
+        B, S, KV, Dh = k.shape
+        st, ks = cfg.kernel_stride, cfg.kernel_size // cfg.kernel_stride
+        f32 = jnp.float32
+        if lengths is not None:
+            real = jnp.arange(S)[None, :] < lengths[:, None]
+            k = jnp.where(real[..., None, None], k, 0)
+        part = jnp.sum(k.astype(f32).reshape(B, S // st, st, KV, Dh), axis=2)
+        n = S // st - ks + 1
+        ck = sum(part[:, o:o + n] for o in range(ks)) / cfg.kernel_size
+        if lengths is not None:
+            done = jnp.arange(n) * st + cfg.kernel_size <= lengths[:, None]
+            ck = jnp.where(done[..., None, None], ck, 0.0)
+        nc = cfg.max_len // st
+        ck = jnp.pad(jnp.moveaxis(ck, 1, 2).astype(k.dtype),
+                     ((0, 0), (0, 0), (0, nc - n), (0, 0)))
+        m = (jnp.full((B,), S, jnp.int32) if lengths is None
+             else lengths.astype(jnp.int32)) // st
+        pad = jnp.pad(part, ((0, 0), (ks - 1, 1), (0, 0), (0, 0)))
+        sums = jnp.take_along_axis(
+            pad, (m[:, None] + jnp.arange(ks))[..., None, None], axis=1)
+        return ck, sums
+
+    def _block_sequence(self, x, lp, lengths=None):
+        """``B`` over whole prompts: x [B,S,D] -> (out [B,S,D], k and v
+        [B,KVH,S,Dh] for the pages, the compressed keys [B,KVH,NC,Dh] and
+        running sums for the state).  The selection runs a chunk of
+        :data:`_BLOCK_QUERY_CHUNK` queries a step, each a mask over the
+        prompt's blocks per K/V head; one causal flash pass a prompt then
+        attends where the mask holds (``kernels.block_sparse_prefill``)."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        G = H // KV
+        n, q, k, v = self._block_qkv(x, lp)
+        with jax.named_scope("mx.compressed_key_write"):
+            ck, sums = self._compressed(k, lengths)
+        nb = -(-S // cfg.block_size)
+        Q = min(_BLOCK_QUERY_CHUNK, S)
+        steps = -(-S // Q)
+        qg = jnp.pad(q, ((0, 0), (0, steps * Q - S), (0, 0), (0, 0))
+                     ).reshape(B, steps * Q, KV, G, Dh)
+        # (only the compressed keys a prompt of this bucket can complete)
+        used = ck[:, :, :S // cfg.kernel_stride]
+
+        def chunk(i):
+            t = i * Q + jnp.arange(Q, dtype=jnp.int32)
+            tb = jnp.broadcast_to(t, (B, Q))
+            score = self._block_scores(
+                lax.dynamic_slice_in_dim(qg, i * Q, Q, axis=1), used, tb, nb)
+            keep = self._block_choose(score, tb[:, None])
+            return keep.astype(jnp.bfloat16)
+
+        with jax.named_scope("mx.block_select"):
+            mask = lax.map(chunk, jnp.arange(steps, dtype=jnp.int32))
+            # [steps, B, KVH, Q, nb] -> [B KVH, S, nb]
+            mask = jnp.moveaxis(mask, 0, 2).reshape(
+                B * KV, steps * Q, nb)[:, :S]
+        qt, kt, vt = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+        with jax.named_scope("mx.block_sparse_prefill"):
+            o = _kernels.block_sparse_prefill(
+                qt, kt, vt, mask, 1.0 / math.sqrt(Dh), cfg.block_size)
+        out = self._block_out(jnp.transpose(o, (0, 2, 1, 3)), n, lp)
+        return out, kt, vt, ck, sums
+
+    def _block_step(self, x, lp, positions, kv, a, name, page, slot,
+                    page_table):
+        """``B``, one token a row: its K and V go into their page, its key
+        into the running sums (and, where it completes a kernel, the
+        kernel's mean into the compressed keys); the compressed keys score
+        the row's blocks, each K/V head keeps its blocks and the group's
+        query heads attend over those pages alone, the partial current one
+        last (``kernels.block_sparse_attention``).  Returns (out [B,D], the
+        cache arrays it rewrote by name, [compressed keys scored, tokens
+        attended a query head] [2, B] int32)."""
+        cfg = self.cfg
+        B = x.shape[0]
+        H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        G, psz = H // KV, cfg.block_size
+        st, ks = cfg.kernel_stride, cfg.kernel_size // cfg.kernel_stride
+        n, q, k, v = (t[:, 0] for t in self._block_qkv(x[:, None], lp))
+        out = {}
+        with jax.named_scope("mx.kv_write"):
+            # the page is fetched, the row put in and the page put back
+            # whole: a scatter of one row across a page's heads has the
+            # compiler turn the whole pool round, twice a step
+            at = jnp.minimum(page[:, 0], kv["k"].shape[1] - 1)
+            row = jnp.arange(psz, dtype=jnp.int32)[None, None, :, None] \
+                == slot[:, :1, None, None]
+            for key, val in (("k", k), ("v", v)):
+                pages = jnp.where(row, val.astype(kv[key].dtype)[:, :, None],
+                                  kv[key][a, at])
+                out[key] = kv[key].at[a, page[:, 0]].set(pages, mode="drop")
+        with jax.named_scope("mx.compressed_key_write"):
+            sums, ck = kv["cks" + name], kv["ck" + name]
+            cur = sums[:, -1] + k.astype(jnp.float32)
+            whole = (positions % st == st - 1)[:, None, None, None]
+            j = (positions - cfg.kernel_size + 1) // st
+            mean = (jnp.sum(sums[:, :-1], axis=1) + cur) / cfg.kernel_size
+            at = jnp.where((positions % st == st - 1) & (j >= 0), j,
+                           ck.shape[2])
+            out["ck" + name] = ck.at[jnp.arange(B), :, at].set(
+                mean.astype(ck.dtype), mode="drop")
+            out["cks" + name] = jnp.where(
+                whole, jnp.concatenate(
+                    [sums[:, 1:-1], cur[:, None], jnp.zeros_like(cur)[:, None]]
+                    if ks > 1 else [jnp.zeros_like(cur)[:, None]], axis=1),
+                jnp.concatenate([sums[:, :-1], cur[:, None]], axis=1))
+        W = page_table.shape[1]
+        with jax.named_scope("mx.block_select"):
+            used = out["ck" + name][:, :, :W * psz // st]
+            score = self._block_scores(q.reshape(B, 1, KV, G, Dh), used,
+                                       positions[:, None], W)[:, :, 0]
+            keep = self._block_choose(score, positions[:, None])  # [B,KVH,W]
+            # the kept blocks in order (the current one, the highest, last)
+            width = min(W, max(cfg.block_topk, -(-cfg.dense_len // psz),
+                               cfg.init_blocks + cfg.local_window // psz + 1))
+            blk = jnp.arange(W, dtype=jnp.int32)
+            _, order = lax.top_k(jnp.where(keep, -blk, -W - blk), width)
+            kept = jnp.sum(keep, axis=-1, dtype=jnp.int32)        # [B,KVH]
+            pages = jnp.take_along_axis(page_table[:, None, :], order, axis=2)
+            pages = jnp.minimum(pages, kv["k"].shape[1] - 1) * KV \
+                + jnp.arange(KV, dtype=jnp.int32)[None, :, None]
+            lengths = (kept - 1) * psz + (positions % psz + 1)[:, None]
+            done = jnp.clip((positions - cfg.kernel_size + 1) // st + 1, 0,
+                            used.shape[2])
+        pool = [kv_.reshape((kv_.shape[0], kv_.shape[1] * KV)
+                            + kv_.shape[3:]) for kv_ in (out["k"], out["v"])]
+        with jax.named_scope("mx.block_sparse_attention"):
+            o = _kernels.block_sparse_attention(
+                q.reshape(B * KV, G, 1, Dh), pool[0], pool[1],
+                pages.reshape(B * KV, width), lengths.reshape(-1),
+                1.0 / math.sqrt(Dh), layer=a)
+        res = self._block_out(o.reshape(B, H, Dh), n, lp)
+        return res, out, jnp.stack(
+            [done, jnp.sum(lengths, axis=1, dtype=jnp.int32) // KV])
+
+    # ------------------------------------------------ lightning attention (N)
+    def _lit_parts(self, x, lp, positions):
+        """RMSNorm, the projections, SiLU, per-head RMSNorm of q and k and
+        rotary at ``positions``: x [B,S,D] -> (n [B,S,D], q over sqrt(Dh),
+        k and v [B,S,H,Dh] in x's dtype)."""
+        cfg = self.cfg
+        with jax.named_scope("mx.qkv"):
+            n = _norm(x, lp["ln"], cfg.eps)
+            q, k, v = (jax.nn.silu(jnp.einsum(
+                "bsd,dhe->bshe", n, lp[w],
+                preferred_element_type=jnp.float32)).astype(x.dtype)
+                for w in ("wq", "wk", "wv"))
+        with jax.named_scope("mx.rope"):
+            q = _rope(_norm(q, lp["qn"], cfg.eps), positions, cfg.rope_theta)
+            k = _rope(_norm(k, lp["kn"], cfg.eps), positions, cfg.rope_theta)
+        return n, q, k, v
+
+    def _lit_out(self, o, n, lp):
+        """The heads' outputs o [..., H, Dh] (float32): RMSNorm over their
+        concatenation, the elementwise sigmoid gate of the block's
+        normalised input n, the output projection."""
+        cfg = self.cfg
+        dt = n.dtype
+        with jax.named_scope("mx.out_gate"):
+            flat = o.reshape(o.shape[:-2] + (-1,))
+            flat = flat * lax.rsqrt(jnp.mean(jnp.square(flat), axis=-1,
+                                             keepdims=True) + cfg.eps)
+            g = jax.nn.sigmoid(jnp.einsum("...d,de->...e", n, lp["wg"],
+                                          preferred_element_type=jnp.float32))
+            y = (flat.astype(dt) * lp["onorm"]).astype(jnp.float32) * g
+            y = y.astype(dt).reshape(o.shape)
+        with jax.named_scope("mx.attn_out"):
+            return self._mla_out(y, lp)
+
+    def _lit_sequence(self, x, lp, name, lengths=None):
+        """``N`` over whole prompts from the zero state, in chunks: x
+        [B,S,D] -> (out [B,S,D], final state [B,H,Dh,Dh] float32).  A
+        position at or past ``lengths`` has a zero key and no decay: the
+        state leaves it as it came."""
+        cfg = self.cfg
+        B, S, _ = x.shape
+        Q = min(cfg.chunk, S)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        n, q, k, v = self._lit_parts(x, lp, positions)
+        with jax.named_scope("mx.lightning_scan"):
+            logd = jnp.broadcast_to(self._lit_log_decay[name],
+                                    (B, S, cfg.num_heads))
+            if lengths is not None:
+                real = positions < lengths[:, None]
+                k = jnp.where(real[..., None, None], k, 0)
+                logd = jnp.where(real[..., None], logd, 0.0)
+            pad = -S % Q
+            if pad:
+                q, k, v, logd = (
+                    jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                    for a in (q, k, v, logd))
+            y, state = _lightning_scan(q, k, v, logd, Q)
+            y = y[:, :S] / math.sqrt(cfg.head_dim)
+        return self._lit_out(y, n, lp), state
+
+    def _lit_step(self, x, lp, name, positions, state):
+        """One token a row: x [B,D], state [B,H,Dh,Dh] float32 -> (out
+        [B,D], new state): ``S <- lambda S + k^T v`` and ``q S`` in float32
+        through ``kernels.retention_update`` (its expanded key and query
+        the key and query themselves, its normaliser a zero it drops)."""
+        cfg = self.cfg
+        B = x.shape[0]
+        n, q, k, v = (a[:, 0] for a in self._lit_parts(
+            x[:, None], lp, positions[:, None]))
+        f32 = jnp.float32
+        with jax.named_scope("mx.lightning_update"):
+            decay = jnp.broadcast_to(jnp.exp(self._lit_log_decay[name]),
+                                     (B, cfg.num_heads))
+            state, _, num, _ = _kernels.retention_update(
+                state, jnp.zeros(state.shape[:3], f32), k.astype(f32),
+                q.astype(f32)[:, :, None] / math.sqrt(cfg.head_dim), decay,
+                v)
+        return self._lit_out(num[:, :, 0], n, lp), state
+
     # -------------------------------------------------------------- Mamba-2
     def _ssm_split(self, x, lp):
         """RMSNorm + the input projection: x [..., D] -> z [..., I], the
@@ -1056,11 +1474,33 @@ class HybridLM:
     def apply(self, params, tokens):
         """tokens [B, S] int32 -> logits [B, S, V] (fp32): the whole
         sequence from empty state, no cache."""
-        x = params["embed"][tokens].astype(self.cfg.dtype)
+        x = self._embed(params, tokens)
         x, _, _, _ = self._run(params, x)
-        x = _norm(x, params["final_norm"], self.cfg.eps)
+        x = self._final(params, x)
         return jnp.einsum("bsd,vd->bsv", x, params["head"],
                           preferred_element_type=jnp.float32)
+
+    def _embed(self, params, tokens):
+        """The tokens' rows of the embedding, times ``scale_emb``."""
+        x = params["embed"][tokens].astype(self.cfg.dtype)
+        if self.cfg.scale_emb != 1.0:
+            x = (x.astype(jnp.float32) * self.cfg.scale_emb).astype(x.dtype)
+        return x
+
+    def _add(self, x, out):
+        """A block's output into the residual stream, times
+        ``residual_scale``."""
+        if self.cfg.residual_scale == 1.0:
+            return x + out
+        return x + (out.astype(jnp.float32) * self.cfg.residual_scale
+                    ).astype(x.dtype)
+
+    def _final(self, params, x):
+        """The final norm, times ``logit_scale``: what the head reads."""
+        x = _norm(x, params["final_norm"], self.cfg.eps)
+        if self.cfg.logit_scale != 1.0:
+            x = (x.astype(jnp.float32) * self.cfg.logit_scale).astype(x.dtype)
+        return x
 
     def _run(self, params, x, lengths=None, kv_sink=None):
         """The blocks over whole sequences x [B,S,D].  Returns (x, the
@@ -1079,29 +1519,40 @@ class HybridLM:
                 q, k, v = self._qkv(x, lp)
                 if kv_sink is not None:
                     kv_sink(name, k, v)
-                x = x + self._attn_out(self._attend(q, k, v), lp)
+                x = self._add(x, self._attn_out(self._attend(q, k, v), lp))
             elif kind == "M":
                 with jax.named_scope("mx.ssm"):
                     out, states["ssm" + name], states["conv" + name] = \
                         self._ssm_sequence(x, lp, lengths)
-                    x = x + out
+                    x = self._add(x, out)
             elif kind == "R":
                 with jax.named_scope("mx.retention"):
                     out, states["ret" + name], states["retz" + name] = \
                         self._ret_sequence(x, lp, lengths)
-                    x = x + out
+                    x = self._add(x, out)
             elif kind == "F":
-                x = x + self._mlp(x, lp)
+                x = self._add(x, self._mlp(x, lp))
             elif kind in "LS":
                 out, rows = (self._mla_sequence if kind == "L"
                              else self._dsa_sequence)(x, lp)
                 if kv_sink is not None:
                     kv_sink(name, rows)
-                x = x + out
+                x = self._add(x, out)
             elif kind == "W":
                 out, rows = self._swa_sequence(x, lp)
                 states["ring" + name] = self._ring_of(rows, lengths)
-                x = x + out
+                x = self._add(x, out)
+            elif kind == "B":
+                out, k, v, states["ck" + name], states["cks" + name] = \
+                    self._block_sequence(x, lp, lengths)
+                if kv_sink is not None:
+                    kv_sink(name, k, v)
+                x = self._add(x, out)
+            elif kind == "N":
+                with jax.named_scope("mx.lightning"):
+                    out, states["lit" + name] = self._lit_sequence(
+                        x, lp, name, lengths)
+                    x = self._add(x, out)
             else:
                 with jax.named_scope("mx.moe"):
                     mix = self._moe if kind == "E" else self._gated_moe
@@ -1114,7 +1565,7 @@ class HybridLM:
                         self.cfg.top_k * wide * 4)
                     stats.append(st)
                     routed.append(chosen.reshape(B, S, -1))
-                    x = x + out.reshape(B, S, D)
+                    x = self._add(x, out.reshape(B, S, D))
         return x, states, _sum_stats(stats), _stack_routed(routed, (B, S))
 
     # --------------------------------------- generation (pages and state)
@@ -1140,7 +1591,14 @@ class HybridLM:
         says how many blocks select and how many tokens each keeps
         (``sparse``); one of ``W`` blocks keeps a ring a block in ``state``
         (``ring<block>`` ``[rows, columns]``) and says how many blocks, their
-        window and their columns (``rings``)."""
+        window and their columns (``rings``).  A model of ``B`` blocks keeps
+        K and V pages a head apart (``page_layout`` ``"heads"``: a page is
+        ``[num_heads, page_size, head_dim]``), per block in ``state`` its
+        compressed keys (``ck<block>`` ``[KVH, max_len / kernel_stride,
+        Dh]``) and running key sums (``cks<block>`` ``[kernel_size /
+        kernel_stride, KVH, Dh]`` float32), and says what the selection
+        keeps (``block_sparse``); one of ``N`` blocks keeps a float32
+        ``lit<block>`` ``[H, Dh, Dh]`` state a block."""
         if quantized:
             raise ValueError("HybridLM keeps no int8 K/V pages")
         cfg = self.cfg
@@ -1171,6 +1629,18 @@ class HybridLM:
                               "dtype": jnp.dtype(cfg.dtype).name,
                               "shape": [cfg.swa_kv_rank + cfg.swa_rope_dim,
                                         cfg.ring]})
+            elif kind == "B":
+                state.append({"name": "ck" + name,
+                              "dtype": jnp.dtype(cfg.dtype).name,
+                              "shape": [KV, cfg.max_len // cfg.kernel_stride,
+                                        cfg.head_dim]})
+                state.append({"name": "cks" + name, "dtype": "float32",
+                              "shape": [cfg.kernel_size // cfg.kernel_stride,
+                                        KV, cfg.head_dim]})
+            elif kind == "N":
+                state.append({"name": "lit" + name, "dtype": "float32",
+                              "shape": [cfg.num_heads, cfg.head_dim,
+                                        cfg.head_dim]})
         spec = {"num_layers": len(self.attn_index),
                 "num_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
                 "row_width": cfg.num_kv_heads * cfg.head_dim,
@@ -1189,6 +1659,10 @@ class HybridLM:
         if "W" in self.kinds:
             spec["rings"] = {"layers": self.kinds.count("W"),
                              "window": cfg.window, "columns": cfg.ring}
+        if self.block_sparse:
+            spec.update(page_layout="heads", block_sparse={
+                "layers": self.kinds.count("B"), "block": cfg.block_size,
+                "top_k": cfg.block_topk})
         return spec
 
     def init_kv_pages(self, num_pages, page_size, slots=1):
@@ -1204,7 +1678,7 @@ class HybridLM:
 
     def _logits_last(self, params, x):
         with jax.named_scope("mx.lm_head"):
-            x = _norm(x, params["final_norm"], self.cfg.eps)
+            x = self._final(params, x)
             return jnp.einsum("bd,vd->bv", x, params["head"],
                               preferred_element_type=jnp.float32)
 
@@ -1218,12 +1692,13 @@ class HybridLM:
                 return_replay=False):
         """Whole prompts: tokens [B,S] (padded past ``lengths``), page_table
         [B,W].  Every attention block's K/V goes into the pages as in
-        ``TransformerLM.prefill`` (every latent block's rows a whole page
-        at a time: the expanded K and V are never kept); every ``M`` and
-        ``R`` block runs from
+        ``TransformerLM.prefill`` (every latent block's rows, and every
+        ``B`` block's K and V, a whole page at a time: the expanded K and V
+        are never kept); every ``M``, ``R`` and ``N`` block runs from
         the zero state in its chunked form and leaves the prompt's final
         state (and convolution tail, or normaliser) in row ``slots[b]``
-        (default: row b) of its state arrays, whatever that row held.
+        (default: row b) of its state arrays, whatever that row held, as
+        every ``B`` block leaves its compressed keys and running sums.
         Padded positions take no step and route to no expert.  Returns ``(new_kv, next_token [B])``;
         with ``return_replay`` (:attr:`replay`) two more come last: the
         experts every position chose ``[E blocks, B, S, top_k]`` int32 and
@@ -1240,6 +1715,21 @@ class HybridLM:
         def sink(name, k, v=None):
             with jax.named_scope("mx.kv_write"):
                 a = self.attn_index[name]
+                if self.block_sparse:
+                    # K and V [B,KVH,S,Dh] go in a page (a block) at a
+                    # time, each head's rows apart: [KVH, psz, Dh] a page
+                    n = -(-S // psz)
+                    ids = jnp.where(
+                        jnp.arange(n)[None, :] * psz < lengths[:, None],
+                        page_table[:, :n], pool)
+                    for key, val in (("k", k), ("v", v)):
+                        rows = jnp.pad(val, ((0, 0), (0, 0),
+                                             (0, n * psz - S), (0, 0)))
+                        rows = jnp.moveaxis(rows.reshape(
+                            B, val.shape[1], n, psz, -1), 2, 1)
+                        nkv[key] = nkv[key].at[a, ids].set(
+                            rows.astype(nkv[key].dtype), mode="drop")
+                    return
                 if v is None:
                     # latent rows [B,S,width] go in a page at a time, its
                     # tokens onto the lanes; a page with no real token
@@ -1260,7 +1750,7 @@ class HybridLM:
                     nkv[key] = nkv[key].at[a, pages, offs].set(
                         rows.astype(nkv[key].dtype), mode="drop")
 
-        x = params["embed"][tokens].astype(self.cfg.dtype)
+        x = self._embed(params, tokens)
         x, states, _, routed = self._run(params, x, lengths, sink)
         rows = jnp.arange(B, dtype=jnp.int32) if slots is None else slots
         for key, state in states.items():
@@ -1290,8 +1780,11 @@ class HybridLM:
         next: pairs computed on held experts, distinct held experts hit,
         the largest load on one expert, then, summed over the rows with a
         request, the tokens the ``S`` blocks' indexer scored and the tokens
-        their selection kept, and the ring columns the ``W`` blocks
-        attended (where the pattern has them); with ``return_replay`` the experts
+        their selection kept, the ring columns the ``W`` blocks attended,
+        and the compressed keys the ``B`` blocks scored and the tokens a
+        query head of theirs attended (where the pattern has them); ``N``
+        blocks advance their float32 state through
+        ``kernels.retention_update``; with ``return_replay`` the experts
         every row chose ``[E blocks, B, top_k]`` int32 and the produced
         tokens' log-probabilities ``[B]`` float32 come last."""
         B = token_ids.shape[0]
@@ -1300,10 +1793,10 @@ class HybridLM:
                                    axis=1)
         slot = (positions % psz)[:, None]
         active = positions > 0
-        x = params["embed"][token_ids].astype(self.cfg.dtype)   # [B,D]
+        x = self._embed(params, token_ids)                      # [B,D]
         nkv = dict(kv)
         stats, routed = [], []
-        counts = {"S": [], "W": []}
+        counts = {"S": [], "W": [], "B": []}
         for name, kind in zip(self.names, self.kinds):
             lp = params["layers"][name]
             if kind == "*":
@@ -1318,21 +1811,21 @@ class HybridLM:
                 o = _kernels.paged_attention(q, nkv["k"], nkv["v"],
                                              page_table, positions + 1,
                                              layer=a)
-                x = x + self._attn_out(o, lp)[:, 0]
+                x = self._add(x, self._attn_out(o, lp)[:, 0])
             elif kind == "M":
                 with jax.named_scope("mx.ssm"):
                     out, nkv["ssm" + name], tail = self._ssm_step(
                         x, lp, kv["ssm" + name], kv["conv" + name])
                     nkv["conv" + name] = tail
-                    x = x + out
+                    x = self._add(x, out)
             elif kind == "R":
                 with jax.named_scope("mx.retention"):
                     out, nkv["ret" + name], nkv["retz" + name] = \
                         self._ret_step(x, lp, positions, kv["ret" + name],
                                        kv["retz" + name])
-                    x = x + out
+                    x = self._add(x, out)
             elif kind == "F":
-                x = x + self._mlp(x, lp)
+                x = self._add(x, self._mlp(x, lp))
             elif kind == "L":
                 a = self.attn_index[name]
                 q, row = self._mla_absorb(x, lp, positions)
@@ -1342,25 +1835,37 @@ class HybridLM:
                 ctx = _kernels.latent_paged_attention(
                     q, nkv["kv"], page_table, positions + 1,
                     self._mla_scale, self.cfg.kv_rank, layer=a)
-                x = x + self._mla_unabsorb(ctx, lp)
+                x = self._add(x, self._mla_unabsorb(ctx, lp))
             elif kind == "S":
                 out, nkv["kv"], got = self._dsa_step(
                     x, lp, positions, nkv["kv"], self.attn_index[name], page,
                     slot, page_table, psz)
                 counts["S"].append(got)
-                x = x + out
+                x = self._add(x, out)
             elif kind == "W":
                 out, nkv["ring" + name], got = self._swa_step(
                     x, lp, positions, kv["ring" + name])
                 counts["W"].append(got)
-                x = x + out
+                x = self._add(x, out)
+            elif kind == "B":
+                out, wrote, got = self._block_step(
+                    x, lp, positions, nkv, self.attn_index[name], name, page,
+                    slot, page_table)
+                nkv.update(wrote)
+                counts["B"].append(got)
+                x = self._add(x, out)
+            elif kind == "N":
+                with jax.named_scope("mx.lightning"):
+                    out, nkv["lit" + name] = self._lit_step(
+                        x, lp, name, positions, kv["lit" + name])
+                    x = self._add(x, out)
             else:
                 with jax.named_scope("mx.moe"):
                     mix = self._moe if kind == "E" else self._gated_moe
                     out, st, chosen = mix(x, lp, active)
                     stats.append(st)
                     routed.append(chosen)
-                    x = x + out
+                    x = self._add(x, out)
         ids, logits = self._sample_last(params, x, positions + 1, sample)
         out = (nkv, ids)
         if return_logits:
@@ -1585,3 +2090,56 @@ def _ssd(X, Bm, Cm, step, rate, Q):
                        preferred_element_type=f32) \
         * jnp.exp(cum)[..., None]
     return Y.reshape(B, S, G, R, P), state
+
+
+def _lightning_log_decay(heads, layer, layers):
+    """An ``N`` block's log-decays [heads] float32: ``-s_h (1 - l / (L -
+    1) + 1e-5)``, ``s_h = 2^(-8 (h + 1) / heads)`` (the ALiBi slopes), ``l``
+    the block's published layer of ``L``."""
+    slope = 2.0 ** (-8.0 * (np.arange(heads) + 1) / heads)
+    return (-slope * (1.0 - layer / max(layers - 1, 1) + 1e-5)).astype(
+        np.float32)
+
+
+def _lightning_scan(q, k, v, logd, Q):
+    """Linear attention under a per-head decay from an empty state, in
+    chunks of ``Q``: ``S_t = exp(logd_t) S_{t-1} + k_t^T v_t``, ``y_t = q_t
+    S_t``.  Inside a chunk the masked, decayed ``q k^T`` product; between
+    chunks the state, carried in float32 by a scan whose products keep
+    float32 (``Precision.HIGHEST``: the state is not rounded on the way).
+
+    q, k, v [B,S,H,Dh]; logd [B,S,H] f32 (<= 0; 0 with a zero key where
+    nothing is to happen).  S a multiple of Q.  Returns ``(y [B,S,H,Dh]
+    f32, final state [B,H,Dh,Dh] f32)``."""
+    B, S, H, Dh = q.shape
+    c = S // Q
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    qc, kc, vc = (a.reshape(B, c, Q, H, Dh) for a in (q, k, v))
+    cum = jnp.cumsum(logd.reshape(B, c, Q, H), axis=2)          # [B,c,Q,H]
+    # inside a chunk: position i hears j <= i, decayed by what lies between
+    seg = jnp.moveaxis(cum, 3, 2)                                # [B,c,H,Q]
+    low = jnp.tril(jnp.ones((Q, Q), bool))
+    mix = jnp.einsum("bcihe,bcjhe->bchij", qc, kc,
+                     preferred_element_type=f32) * jnp.exp(jnp.where(
+                         low, seg[..., :, None] - seg[..., None, :], -jnp.inf))
+    y = jnp.einsum("bchij,bcjhe->bcihe", mix.astype(v.dtype), vc,
+                   preferred_element_type=f32)
+
+    def chunk(state, xs):
+        qx, kx, vx, into, to_end, whole = xs
+        # what the state this chunk enters with adds at each position ...
+        y_in = jnp.einsum("bihe,bhef->bihf", qx.astype(f32) * into[..., None],
+                          state, precision=hi)
+        # ... and what the chunk adds to the state by its end
+        state = whole[..., None, None] * state + jnp.einsum(
+            "bjhe,bjhf->bhef", kx.astype(f32) * to_end[..., None],
+            vx.astype(f32), precision=hi)
+        return state, y_in
+
+    state, y_in = lax.scan(
+        chunk, jnp.zeros((B, H, Dh, Dh), f32),
+        tuple(jnp.moveaxis(a, 1, 0) for a in (
+            qc, kc, vc, jnp.exp(cum), jnp.exp(cum[:, :, -1:] - cum),
+            jnp.exp(cum[:, :, -1]))))
+    y = y + jnp.moveaxis(y_in, 0, 1)
+    return y.reshape(B, S, H, Dh), state

@@ -422,7 +422,24 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None, block_q=128,
 _TILED_BLOCK = 512           # q rows and K/V rows a step: four MXU passes
 
 
-def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs):
+def _widened(blocks, j, bk, mask_block, lanes):
+    """A ``[bq, lanes]`` bfloat16 block of a mask of blocks -> the ``[bq,
+    bk]`` bool mask of K/V block ``j``'s keys: one MXU product against a
+    0/1 ``[lanes, bk]`` widening (column ``(j bk / mask_block) mod lanes +
+    c`` to keys ``[c mask_block, (c + 1) mask_block)``)."""
+    first = (j * (bk // mask_block)) % lanes
+    col = jax.lax.broadcasted_iota(jnp.int32, (lanes, bk), 0)
+    key = jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, (lanes, bk), 1),
+        mask_block.bit_length() - 1)
+    widen = jnp.where(col == key + first, 1.0, 0.0).astype(jnp.bfloat16)
+    return jax.lax.dot_general(
+        blocks, widen, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) > 0.5
+
+
+def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs,
+                        blocks=None):
     """One q block against one K/V block of its (batch, head) slice; the
     K/V blocks are the grid's last axis and an online softmax (``m``,
     ``l``, ``acc`` in float32 scratch) carries across them, so neither the
@@ -434,7 +451,10 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs):
     bk]`` mask block first) a pair the mask leaves at 0 is masked beside
     those above the diagonal; a row none of whose pairs so far is kept
     carries ``m`` at the floor, and its first kept pair's ``alpha`` of 0
-    clears what it summed."""
+    clears what it summed.  A mask of BLOCKS (``blocks``: ``(mask_block,
+    lanes)``, ``mask_block`` keys a column) arrives as a bfloat16 ``[bq,
+    lanes]`` block that holds this K/V block's ``bk / mask_block`` columns
+    (:func:`_widened` makes it the ``[bq, bk]`` mask)."""
     from jax.experimental import pallas as pl
     mask_ref = refs[0] if len(refs) == 5 else None
     o_ref, m_ref, l_ref, acc_ref = refs[-4:]
@@ -457,7 +477,8 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs):
             k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             keep = k_pos <= q_pos
         if mask_ref is not None:
-            chosen = mask_ref[0] != 0
+            chosen = mask_ref[0] != 0 if blocks is None \
+                else _widened(mask_ref[0], j, bk, *blocks)
             keep = chosen if keep is None else keep & chosen
         if keep is not None:
             s = jnp.where(keep, s, _NEG)
@@ -482,7 +503,7 @@ def _flash_tiled_kernel(scale, causal, bq, bk, q_ref, k_ref, v_ref, *refs):
 
 
 def flash_attention_tiled(q, k, v, causal=False, scale=None,
-                          block=_TILED_BLOCK, mask=None):
+                          block=_TILED_BLOCK, mask=None, mask_block=1):
     """Flash attention FORWARD with the keys and values tiled as well as
     the queries: q, k ``[B, H, S, Dqk]``, v ``[B, H, Skv, Dv]`` (``Dv``
     need not be ``Dqk``) -> ``[B, H, S, Dv]``.  The grid walks (batch x
@@ -498,12 +519,22 @@ def flash_attention_tiled(q, k, v, causal=False, scale=None,
     at non-zero are attended (named ``mx_attention_tiled_masked``; the
     query blocks a multiple of 32 rows and the K/V blocks of 128, the int8
     tile, or whole).  Every row must keep at least one pair: a row that
-    keeps none reads the mean of the values its blocks visited."""
+    keeps none reads the mean of the values its blocks visited.
+
+    ``mask_block`` > 1 (a power of two): the mask is of BLOCKS of that many
+    keys, bfloat16 ``[B KVH, S, Skv / mask_block]``, and k and v may hold
+    fewer heads ``KVH`` than q (query head h reads K/V head ``h // (H /
+    KVH)``, and its mask's row ``b KVH + h // (H / KVH)``): a
+    ``[S, Skv]`` mask of pairs never stands, and the mask is read a
+    128-lane block at a time (K/V blocks of 512 keys)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ..rtc import interpret_mode
     B, H, S, D = q.shape
     Skv, Dv = v.shape[2], v.shape[3]
+    if mask_block > 1:
+        return _flash_tiled_block_masked(q, k, v, causal, scale, block,
+                                         mask, mask_block)
     if k.shape != (B, H, Skv, D) or v.shape[:2] != (B, H) \
             or (causal and Skv != S) \
             or (mask is not None and mask.shape != (B, S, Skv)):
@@ -546,6 +577,66 @@ def flash_attention_tiled(q, k, v, causal=False, scale=None,
         interpret=interpret_mode(),
         name="mx_attention_tiled" if mask is None
         else "mx_attention_tiled_masked")(*operands)
+    return out.reshape(B, H, S, Dv)
+
+
+def _flash_tiled_block_masked(q, k, v, causal, scale, block, mask, mb):
+    """:func:`flash_attention_tiled` under a mask of blocks of ``mb`` keys
+    (its text): the grid walks (batch x query heads, q blocks, K/V blocks),
+    the K/V and mask blocks of a query head's own K/V head."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..rtc import interpret_mode
+    B, H, S, D = q.shape
+    kvh, Skv, Dv = k.shape[1], v.shape[2], v.shape[3]
+    nb = Skv // mb if mb and Skv % mb == 0 else 0
+    if k.shape != (B, kvh, Skv, D) or v.shape[:2] != (B, kvh) or H % kvh \
+            or not nb or mb & (mb - 1) \
+            or mask is None or mask.shape != (B * kvh, S, nb) \
+            or (causal and Skv != S):
+        raise ValueError("tiled flash attention under a mask of %d-key "
+                         "blocks takes q [B,H,S,D], k [B,KVH,Skv,D], v "
+                         "[B,KVH,Skv,Dv] and a mask [B KVH, S, Skv/%d], got "
+                         "%s, %s, %s, %s" % (mb, mb, q.shape, k.shape,
+                                             v.shape, None if mask is None
+                                             else mask.shape))
+    scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
+    group = H // kvh
+    bq = _row_block(S, 1, budget=min(block, S), align=2 * _SUBLANES)
+    bk = _row_block(Skv, 1, budget=min(block, Skv), align=max(mb, _LANES)) \
+        if Skv > block else Skv
+    lanes = nb if nb <= _LANES else _LANES
+    per = bk // mb                                 # mask columns a K/V block
+    if bk % mb or lanes % per or nb % lanes:
+        raise ValueError("K/V blocks of %d keys do not hold whole %d-key "
+                         "mask blocks inside %d lanes" % (bk, mb, lanes))
+    last = (lambda i: (i * bq + bq - 1) // bk) if causal \
+        else (lambda i: Skv // bk - 1)
+
+    def kv_at(b, i, j):
+        return ((b // H) * kvh + (b % H) // group, jnp.minimum(j, last(i)), 0)
+
+    def mask_at(b, i, j):
+        return (b // group, i, jnp.minimum(j, last(i)) * per // lanes)
+
+    out = pl.pallas_call(
+        functools.partial(_flash_tiled_kernel, scale, bool(causal), bq, bk,
+                          blocks=(mb, lanes)),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
+        grid=(B * H, S // bq, Skv // bk),
+        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, bk, D), kv_at),
+                  pl.BlockSpec((1, bk, Dv), kv_at),
+                  pl.BlockSpec((1, bq, lanes), mask_at)],
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret_mode(), name="mx_attention_tiled_masked")(
+            q.reshape(B * H, S, D), k.reshape(B * kvh, Skv, D),
+            v.reshape(B * kvh, Skv, Dv), mask.astype(jnp.bfloat16))
     return out.reshape(B, H, S, Dv)
 
 

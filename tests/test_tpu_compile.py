@@ -775,3 +775,101 @@ def test_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
                              for a, t in need])
     assert max(a + t for a, t in need) < 15.5 * 2 ** 30, need
     assert min(a for a, _ in need) > 0.6 * 15.75 * 2 ** 30
+
+
+def test_block_sparse_programs_at_the_cells_sizes_compile_into_the_chip(
+        compile_for_chip, kernel_tier_on, monkeypatch, tmp_path):
+    """``benchmarks/configs/minicpm_sala_pp8.json`` as its driver exports it
+    — published widths, layers 0-3 (``BF`` + ``NF`` x 3), 64 decode slots
+    over 40,960 pages of 64 tokens (K and V of two heads, a head apart), a
+    compressed-key row every 16 positions and a float32 ``[32, 128, 128]``
+    lightning state a slot for each ``N`` layer — lowered for the TPU,
+    reloaded and compiled for the described chip: the decode program
+    attends through the paged kernel over the kept pages and updates the
+    three lightning states through the retention kernel, the prefill at
+    its one bucket (32,768) attends through the masked K/V-tiled kernel
+    under a mask of blocks, the pools and the states are rewritten in
+    place, and weights, cache and the temporaries of a decode step and of
+    the prefill fit one chip's 15.75 GiB.  (Weights are shapes only.)"""
+    import json
+    import os
+    from jax import export as jexport
+    from mxnet_tpu import deploy
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    monkeypatch.setattr(jexport, "export", functools.partial(
+        jexport.export, platforms=["tpu"]))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "minicpm_sala_pp8.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "offline_longdoc_s64.json")) as f:
+        traffic = json.load(f)
+    sz = cell["sizes"]
+    bucket = traffic["prompt_buckets"][-1]
+    model = HybridLM(HybridLMConfig(dtype=BF16, **sz["lm"]))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    prefix = str(tmp_path / "lm")
+    with jax.default_matmul_precision(None):
+        deploy.export_generation(
+            model, shapes, prefix, sampling=True,
+            decode_batch=sz["decode_batch"], prompt_buckets=[bucket],
+            max_context=traffic["max_context"],
+            page_size=sz["page_tokens"], decode_widths=sz["decode_widths"],
+            include_params=False, replay=sz["replay"])
+    with open(prefix + "-meta.json") as f:
+        meta = json.load(f)
+    width = sz["decode_widths"][-1]
+    assert meta["decode_widths"] == [width] == [640]
+    assert meta["paged"] == {str(width): {
+        "impl": "block_sparse", "reason": None, "quantized": False}}
+    assert meta["retention"]["decode-w%d" % width] == {
+        "impl": "retention", "reason": None, "sites": 3}
+    assert meta["sparse_prefill"] == {"prefill-s%d" % bucket: {
+        "impl": "masked", "reason": None, "sites": 1}}
+    spec = compile_for_chip.spec
+    on_chip = functools.partial(jax.tree_util.tree_map,
+                                lambda a: spec(a.shape, a.dtype))
+    rows, pages = sz["decode_batch"], cell["knobs"]["serving.kv_pages"]
+    cache = on_chip(deploy._kv_pool_specs(meta["kv"], pages, rows))
+    assert [c.shape for c in cache] == [(1, 40960, 2, 64, 128)] * 2 + [
+        (64, 2, 2560, 128), (64, 2, 2, 128)] + [(64, 32, 128, 128)] * 3
+
+    def sample(b):
+        return (spec((b,), F32), spec((b,), jnp.int32), spec((b,), F32),
+                spec((b, 2), jnp.uint32))
+
+    def compiled(path, *specs):
+        with open(path, "rb") as f:
+            exp = jexport.deserialize(f.read())
+        return jax.jit(exp.call, donate_argnums=(1,)).lower(
+            on_chip(shapes), cache, *specs).compile()
+
+    decode = compiled("%s-decode-w%d.stablehlo" % (prefix, width),
+                      spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                      spec((rows, width), jnp.int32), *sample(rows))
+    text = decode.as_text()
+    assert text.count("mx_paged_attention") >= 1
+    assert text.count("mx_retention_update") >= 3
+    assert "mx.kv_gather" not in text
+    prefill = compiled(
+        "%s-prefill-s%d.stablehlo" % (prefix, bucket),
+        spec((1, bucket), jnp.int32), spec((1,), jnp.int32),
+        spec((1, -(-bucket // sz["page_tokens"])), jnp.int32),
+        spec((1,), jnp.int32), *sample(1))
+    assert prefill.as_text().count("mx_attention_tiled_masked") >= 1
+    pool = "bf16[1,40960,2,64,128]"
+    for program in (text, prefill.as_text()):
+        assert not [ln for ln in program.splitlines()
+                    if pool in ln.split("=")[0] and " copy(" in ln]
+    cache_bytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in cache)
+    assert round(cache_bytes / 2 ** 30, 2) == 2.95
+    need = []
+    for program in (decode, prefill):
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes       # in place
+        need.append((mem.argument_size_in_bytes, mem.temp_size_in_bytes))
+    print("sizes_analysis", [(round(a / 2 ** 30, 3), round(t / 2 ** 30, 3))
+                             for a, t in need])
+    assert max(a + t for a, t in need) < 15.5 * 2 ** 30, need
+    assert min(a for a, _ in need) > 0.35 * 15.75 * 2 ** 30
